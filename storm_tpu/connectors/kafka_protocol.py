@@ -644,7 +644,7 @@ class _Conn:
 
         def b64(s: str, what: str) -> bytes:
             # keep malformed-server failures inside the module's error
-            # taxonomy (KafkaProtocolError/OSError — what callers and the
+            # classes (KafkaProtocolError/OSError — what callers and the
             # retry paths catch), never a bare binascii/ValueError
             try:
                 return base64.b64decode(s, validate=True)

@@ -36,13 +36,15 @@ from storm_tpu.dist.transport import WorkerClient
 log = logging.getLogger("storm_tpu.dist.controller")
 
 
-def _probe_raw_spouts(cfg, builder: str) -> list:
-    """Build the recipe against a throwaway MemoryBroker and return the
-    component ids of any raw-scheme spouts. Best-effort: a custom builder
-    may inspect the broker at build time (partitions_for, wire-broker type
-    checks) and fail against the probe broker — that must not fail submit
-    for a valid topology (advice r4), so a probe failure skips the static
-    check and leaves the transport-level TypeError as the backstop."""
+def _probe_topology(cfg, builder: str):
+    """Build the recipe against a throwaway MemoryBroker, exactly as each
+    worker will, for the static submit checks. Best-effort: a custom
+    builder may inspect the broker at build time (partitions_for,
+    wire-broker type checks) and fail against the probe broker — that
+    must not fail submit for a valid topology (advice r4), so a probe
+    failure returns None, the static checks are skipped, and the run-time
+    errors they anticipate (transport encode TypeError, a second process
+    opening the TPU) stay as the backstop."""
     from storm_tpu.connectors import MemoryBroker
     from storm_tpu.dist.worker import _resolve_builder
 
@@ -51,12 +53,19 @@ def _probe_raw_spouts(cfg, builder: str) -> list:
     # best-effort.
     build_fn = _resolve_builder(builder)
     try:
-        probe_topo = build_fn(cfg, MemoryBroker())
+        return build_fn(cfg, MemoryBroker())
     except Exception as exc:  # noqa: BLE001 — builder is user code
         log.warning(
-            "raw-scheme static check skipped: builder %r could not be "
-            "probed against a MemoryBroker (%s); a raw-scheme spout "
-            "would fail at transport encode instead", builder, exc)
+            "static submit checks skipped: builder %r could not be "
+            "probed against a MemoryBroker (%s)", builder, exc)
+        return None
+
+
+def _probe_raw_spouts(cfg, builder: str) -> list:
+    """Component ids of the recipe's raw-scheme spouts ([] when the
+    builder cannot be probed)."""
+    probe_topo = _probe_topology(cfg, builder)
+    if probe_topo is None:
         return []
     return sorted(
         cid for cid, spec in probe_topo.specs.items()
@@ -414,6 +423,7 @@ class DistCluster:
         bad = {c: w for c, w in placement.items() if w >= len(self.clients)}
         if bad:
             raise ValueError(f"placement onto unknown workers: {bad}")
+        self._check_one_process_per_chip(cfg, builder, placement)
         with self._lock:
             self._placement = placement
             self._recipe = {
@@ -551,13 +561,54 @@ class DistCluster:
         placement: Dict[str, int] = {}
         n = len(self.clients)
         rr = 1 % n
+        # Every engine goes to ONE worker: a TPU belongs to one process at
+        # a time, and shared_engine keeps co-resident models in one HBM.
+        engine_worker: Optional[int] = None
         for spec in topo.specs.values():
             if spec.is_spout:
                 placement[spec.component_id] = 0
-            else:
-                placement[spec.component_id] = rr
-                rr = (rr + 1) % n or (1 % n)
+                continue
+            if getattr(spec.obj, "opens_device", False):
+                if engine_worker is not None:
+                    placement[spec.component_id] = engine_worker
+                    continue
+                engine_worker = rr
+            placement[spec.component_id] = rr
+            rr = (rr + 1) % n or (1 % n)
         return placement
+
+    def _check_one_process_per_chip(self, cfg: Config, builder: str,
+                                    placement: Dict[str, int]) -> None:
+        """Refuse a placement that spreads engines over several workers of
+        one host when more than one of them could open the accelerator. A
+        TPU belongs to one process at a time: the second worker to build
+        an engine would fail at start_bolts with libtpu's lockfile error,
+        after the first already serves. Workers started with
+        ``JAX_PLATFORMS=cpu`` cannot take the chip and are exempt."""
+        topo = _probe_topology(cfg, builder)
+        if topo is None:
+            return
+        engines: Dict[int, List[str]] = {}
+        for cid, spec in topo.specs.items():
+            if getattr(spec.obj, "opens_device", False):
+                engines.setdefault(placement.get(cid, 0), []).append(cid)
+        if len(engines) < 2:
+            return
+        reports = self.state_reports()
+        by_host: Dict[str, List[int]] = {}
+        for w in sorted(engines):
+            rep = reports[w]
+            if "host" in rep and rep.get("jax_platforms") != "cpu":
+                by_host.setdefault(rep["host"], []).append(w)
+        for host, ws in by_host.items():
+            if len(ws) > 1:
+                raise ValueError(
+                    "one process per chip: placement puts engines on "
+                    f"workers {ws} of host {host!r} "
+                    f"({ {w: engines[w] for w in ws} }), and each would "
+                    "open the accelerator. Place them on one worker "
+                    "(auto-placement does), or start the workers that "
+                    "must not take the chip with JAX_PLATFORMS=cpu.")
 
     def _worker_capacities(self) -> "List[dict]":
         return [dict(self._worker_resources) for _ in self.clients]

@@ -100,6 +100,13 @@ class InferenceBolt(Bolt):
         self.cascade = cascade if (cascade is not None
                                    and cascade.enabled) else None
 
+    @property
+    def opens_device(self) -> bool:
+        """True when ``prepare`` builds an in-process engine, so the
+        process hosting this component opens the accelerator. The dist
+        controller's one-process-per-chip placement rule reads it."""
+        return self._engine is None
+
     def clone(self) -> "InferenceBolt":
         return InferenceBolt(
             self.model_cfg, self.batch_cfg, self.sharding_cfg, self._engine,

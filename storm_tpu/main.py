@@ -1285,6 +1285,9 @@ def main(argv=None) -> int:
                 "are ignored",
                 file=sys.stderr,
             )
+        from storm_tpu.infer.engine import enable_compile_cache
+
+        enable_compile_cache()
         asyncio.run(_run_daemon(args.name, cfg, args.duration,
                                 args.autoscale_target_ms, args.ui_port,
                                 args.metrics_file, args.metrics_interval,
@@ -1493,8 +1496,10 @@ def main(argv=None) -> int:
         cfg = _load_config(args)
         if args.model:
             cfg.model.name = args.model
+        from storm_tpu.infer.engine import enable_compile_cache
         from storm_tpu.serve import InferenceWorker
 
+        enable_compile_cache()
         worker = InferenceWorker(cfg.model, cfg.sharding, cfg.batch, port=args.port,
                                  cross_batch_ms=args.cross_batch_ms)
         worker.start()
@@ -1506,18 +1511,18 @@ def main(argv=None) -> int:
         return 0
 
     if args.cmd == "info":
-        import jax
-
         from storm_tpu.models import registry_names
+        from storm_tpu.parallel.mesh import device_info, open_devices
 
-        dev = jax.devices()[0]
+        devices = open_devices()
         mem = None
         try:
-            mem = dev.memory_stats()
+            mem = devices[0].memory_stats()
         except Exception:
             pass
         print(json.dumps({
-            "devices": [str(d) for d in jax.devices()],
+            **device_info(),
+            "devices": [str(d) for d in devices],
             "memory_stats": mem,
             "models": registry_names(),
             "version": __import__("storm_tpu").__version__,

@@ -3,9 +3,12 @@
 The reference's hot-path marshalling was Jackson JSON parse + JNI float-array
 copies (InferenceBolt.java:76-86). Here the equivalent is a C++ shared library
 (``libstormtpu.so``) that parses ``{"instances": ...}`` payloads straight into
-a contiguous float32 buffer handed to NumPy zero-copy. If the library has not
-been built (``make -C storm_tpu/native``), every entry point degrades to a
-pure-Python implementation — functionality is identical, only slower.
+a contiguous float32 buffer handed to NumPy zero-copy. The library is built
+from the sources beside this file (``make -C storm_tpu/native``; the ``.so``
+is not tracked), so a library that loads has every symbol. Where it has not
+been built, every entry point takes the pure-Python implementation —
+functionality is identical, only slower; ``native_available()`` says which
+one runs, and ``chip_smoke.py`` builds before it measures.
 """
 
 from __future__ import annotations
@@ -49,46 +52,37 @@ def _load() -> Optional[ctypes.CDLL]:
         ]
         lib.stpu_free.restype = None
         lib.stpu_free.argtypes = [ctypes.c_void_p]
-        try:
-            lib.stpu_format_predictions.restype = ctypes.c_void_p
-            lib.stpu_format_predictions.argtypes = [
-                ctypes.c_void_p,  # float* data
-                ctypes.c_int64,  # n
-                ctypes.c_int64,  # k
-                ctypes.POINTER(ctypes.c_size_t),  # out length
-            ]
-        except AttributeError:  # stale .so without the serializer
-            pass
-        try:
-            lib.stpu_tensor_encode.restype = ctypes.c_void_p
-            lib.stpu_tensor_encode.argtypes = [
-                ctypes.c_void_p,  # data
-                ctypes.c_int,  # dtype code
-                ctypes.c_int,  # ndim
-                ctypes.POINTER(ctypes.c_int64),  # shape
-                ctypes.POINTER(ctypes.c_size_t),  # out length
-            ]
-            lib.stpu_tensor_decode.restype = ctypes.c_int
-            lib.stpu_tensor_decode.argtypes = [
-                ctypes.c_void_p,  # buf (address; caller keeps the buffer alive)
-                ctypes.c_size_t,  # len
-                ctypes.POINTER(ctypes.c_int),  # out dtype
-                ctypes.POINTER(ctypes.c_int),  # out ndim
-                ctypes.POINTER(ctypes.c_int64),  # out shape[_MAX_RANK]
-                ctypes.POINTER(ctypes.c_size_t),  # out body offset
-                ctypes.POINTER(ctypes.c_size_t),  # out body length
-            ]
-        except AttributeError:  # stale .so without the tensor marshaller
-            pass
-        try:
-            lib.stpu_crc32c.restype = ctypes.c_uint32
-            lib.stpu_crc32c.argtypes = [
-                ctypes.c_char_p,
-                ctypes.c_size_t,
-                ctypes.c_uint32,
-            ]
-        except AttributeError:  # stale .so without crc32c
-            pass
+        lib.stpu_format_predictions.restype = ctypes.c_void_p
+        lib.stpu_format_predictions.argtypes = [
+            ctypes.c_void_p,  # float* data
+            ctypes.c_int64,  # n
+            ctypes.c_int64,  # k
+            ctypes.POINTER(ctypes.c_size_t),  # out length
+        ]
+        lib.stpu_tensor_encode.restype = ctypes.c_void_p
+        lib.stpu_tensor_encode.argtypes = [
+            ctypes.c_void_p,  # data
+            ctypes.c_int,  # dtype code
+            ctypes.c_int,  # ndim
+            ctypes.POINTER(ctypes.c_int64),  # shape
+            ctypes.POINTER(ctypes.c_size_t),  # out length
+        ]
+        lib.stpu_tensor_decode.restype = ctypes.c_int
+        lib.stpu_tensor_decode.argtypes = [
+            ctypes.c_void_p,  # buf (address; caller keeps the buffer alive)
+            ctypes.c_size_t,  # len
+            ctypes.POINTER(ctypes.c_int),  # out dtype
+            ctypes.POINTER(ctypes.c_int),  # out ndim
+            ctypes.POINTER(ctypes.c_int64),  # out shape[_MAX_RANK]
+            ctypes.POINTER(ctypes.c_size_t),  # out body offset
+            ctypes.POINTER(ctypes.c_size_t),  # out body length
+        ]
+        lib.stpu_crc32c.restype = ctypes.c_uint32
+        lib.stpu_crc32c.argtypes = [
+            ctypes.c_char_p,
+            ctypes.c_size_t,
+            ctypes.c_uint32,
+        ]
         _lib = lib
     except OSError:
         _lib = None
@@ -164,7 +158,7 @@ def encode_tensor_native(x: np.ndarray) -> Optional[bytes]:
     ``None`` when the native library is unavailable or the dtype is outside
     Arrow's tensor element types (caller falls back to pyarrow)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "stpu_tensor_encode"):
+    if lib is None:
         return None
     code = _DTYPE_TO_CODE.get(x.dtype)
     if code is None or x.ndim < 1 or x.ndim > _MAX_RANK:
@@ -195,7 +189,7 @@ def decode_tensor_native(buf) -> Optional[np.ndarray]:
     (e.g. Fortran-order strides) — callers fall back to pyarrow. Raises
     ``ValueError`` on genuinely malformed input."""
     lib = _load()
-    if lib is None or not hasattr(lib, "stpu_tensor_decode"):
+    if lib is None:
         return None
     # frombuffer accepts any buffer object without copying and keeps `buf`
     # alive via the returned array's .base chain.
@@ -229,7 +223,7 @@ def format_predictions_native(arr: np.ndarray) -> Optional[str]:
     the C++ writer. Returns ``None`` when unavailable (caller falls back to
     the Python path)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "stpu_format_predictions"):
+    if lib is None:
         return None
     a = np.ascontiguousarray(arr, dtype=np.float32)
     if a.ndim == 1:
@@ -274,6 +268,6 @@ def _crc32c_py(data: bytes, crc: int = 0) -> int:
 def crc32c(data: bytes, crc: int = 0) -> int:
     """CRC32C over ``data`` (incremental: pass a previous result as crc)."""
     lib = _load()
-    if lib is not None and hasattr(lib, "stpu_crc32c"):
+    if lib is not None:
         return lib.stpu_crc32c(data, len(data), crc)
     return _crc32c_py(data, crc)

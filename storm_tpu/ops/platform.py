@@ -1,9 +1,11 @@
 """Shared Pallas-vs-reference dispatch predicate for the ops package.
 
 Kernels (flash attention, w8a16 dequant-matmul) run as Pallas on TPU and
-fall back to jnp reference paths elsewhere (CPU tests, unsupported
-shapes). ``STORM_TPU_NO_PALLAS`` forces the reference paths everywhere —
-the escape hatch for debugging numeric diffs.
+take the jnp reference paths elsewhere (CPU tests, unsupported shapes).
+``STORM_TPU_NO_PALLAS`` forces the reference paths everywhere — the
+escape hatch for debugging numeric diffs. A backend that cannot be
+opened (chip missing, or held by another process) is an error, never a
+quiet switch to the reference path.
 """
 
 from __future__ import annotations
@@ -16,7 +18,4 @@ import jax
 def use_pallas() -> bool:
     if os.environ.get("STORM_TPU_NO_PALLAS"):
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.devices()[0].platform == "tpu"

@@ -18,6 +18,35 @@ import numpy as np
 from jax.sharding import Mesh
 
 
+def open_devices() -> list:
+    """``jax.devices()``, with the one start-up failure a serving host
+    really meets spelled out. A TPU belongs to one process at a time; the
+    second process to open it gets libtpu's lockfile error, which tells
+    the operator to delete the lock instead of naming the holder."""
+    try:
+        return jax.devices()
+    except RuntimeError as e:
+        msg = str(e)
+        if "lockfile" in msg or "already in use" in msg:
+            raise RuntimeError(
+                "cannot open the TPU: another process on this host holds "
+                "it, and a chip belongs to one process at a time. Place "
+                "every engine of this host on ONE worker process and start "
+                "processes that host no engine with JAX_PLATFORMS=cpu. "
+                f"(libtpu: {msg})") from e
+        raise
+
+
+def device_info() -> dict:
+    """The devices this process runs on, as JAX reports them. Every result
+    a bench script prints carries all three keys: a bare device count says
+    nothing of the platform, and a CPU run must never read as a chip."""
+    devs = open_devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
 def make_mesh(
     data_parallel: int = 0,
     tensor_parallel: int = 1,
@@ -31,7 +60,7 @@ def make_mesh(
     adjacency along the trailing (model) axis, where tensor-parallel
     collectives are most bandwidth-hungry.
     """
-    devs = list(devices if devices is not None else jax.devices())
+    devs = list(devices if devices is not None else open_devices())
     n = len(devs)
     if tensor_parallel < 1 or n % tensor_parallel:
         raise ValueError(f"tensor_parallel={tensor_parallel} must divide device count {n}")

@@ -40,6 +40,8 @@ class RemoteInferenceBolt(InferenceBolt):
                          passthrough=passthrough)
         self.target = target
 
+    opens_device = False  # the engine lives in the serve worker's process
+
     def clone(self) -> "RemoteInferenceBolt":
         return RemoteInferenceBolt(self.target, self.batch_cfg, self._warmup,
                                    self.qos, self.passthrough)

@@ -1,9 +1,15 @@
-"""Test env: force JAX onto CPU with 8 virtual devices BEFORE jax imports,
+"""Test env: JAX on the CPU with 8 virtual devices, set BEFORE jax imports,
 so sharding/mesh tests run without TPU hardware (SURVEY.md §4 build
-obligation: fake/CPU backend for multi-device simulation)."""
+obligation: fake/CPU backend for multi-device simulation).
+
+``JAX_PLATFORMS`` is the one platform switch. The suite defaults it to
+``cpu``; a caller that names another platform keeps it, which is how the
+compiled-kernel tests run on the chip:
+``JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_kernels.py``."""
 
 import os
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -12,19 +18,7 @@ if "xla_force_host_platform_device_count" not in flags:
 
 import jax
 
-# config.update, not the env var: the dev environment pins JAX_PLATFORMS to
-# the real TPU platform in a way that survives os.environ edits; tests must
-# run on the virtual 8-device CPU backend. STORM_TPU_TEST_PLATFORM=default
-# keeps whatever jax resolves (the real chip) so the compiled-on-TPU tests
-# (tests/test_tpu_kernels.py) can run un-skipped on hardware.
-_plat = os.environ.get("STORM_TPU_TEST_PLATFORM", "cpu")
-if _plat not in ("cpu", "default"):
-    raise RuntimeError(
-        f"STORM_TPU_TEST_PLATFORM={_plat!r}: must be 'cpu' (forced 8-device "
-        "CPU mesh, the default) or 'default' (keep whatever jax resolves — "
-        "the real chip, for tests/test_tpu_kernels.py)")
-if _plat == "cpu":
-    jax.config.update("jax_platforms", "cpu")
+if os.environ["JAX_PLATFORMS"] == "cpu":
     assert jax.devices()[0].platform == "cpu", "tests require the CPU backend"
     assert len(jax.devices()) == 8, "tests require 8 virtual CPU devices"
 

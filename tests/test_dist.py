@@ -142,7 +142,7 @@ def test_dist_three_workers_end_to_end():
         # auth_token on the full e2e: proves worker->worker Deliver/Ack
         # (peer clients read STORM_TPU_CONTROL_TOKEN from the spawn env)
         # carries the token under real traffic, not just Control pings.
-        with DistCluster(3, env={"JAX_PLATFORMS": "cpu", "STORM_TPU_PLATFORM": "cpu"},
+        with DistCluster(3, env={"JAX_PLATFORMS": "cpu"},
                          auth_token="e2e-secret") as cluster:
             used = cluster.submit("dist-e2e", cfg, placement)
             assert used == placement
@@ -251,7 +251,7 @@ def test_dist_auto_placement_single_worker():
         cfg.topology.inference_parallelism = 1
         cfg.topology.sink_parallelism = 1
 
-        with DistCluster(1, env={"JAX_PLATFORMS": "cpu", "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(1, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             placement = cluster.submit("dist-one", cfg)
             assert set(placement.values()) == {0}
 
@@ -308,7 +308,7 @@ def test_dist_worker_failure_recovery():
             "dlq-bolt": 2,
         }
         rng = np.random.RandomState(7)
-        with DistCluster(3, env={"JAX_PLATFORMS": "cpu", "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(3, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("hb-e2e", cfg, placement)
             cluster.start_monitor(interval_s=0.5, misses=2)
 
@@ -403,8 +403,7 @@ def test_dist_chaos_frame_corruption_replays():
         }
         n_msgs = 8
         rng = np.random.RandomState(3)
-        with DistCluster(2, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(2, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("crc-e2e", cfg, placement)
             # Two one-shot corruptions on worker 0's outbound frames (the
             # spout->inference deliveries; budget, not pct, so the test is
@@ -492,8 +491,7 @@ def test_dist_eos_no_duplicates_across_worker_kill():
         }
         n_msgs = 12
         rng = np.random.RandomState(5)
-        with DistCluster(3, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(3, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("eosk", cfg, placement)
             cluster.start_monitor(interval_s=0.5, misses=2)
 
@@ -583,7 +581,7 @@ def test_dist_live_model_swap():
         cfg.topology.inference_parallelism = 1
         cfg.topology.sink_parallelism = 1
 
-        with DistCluster(1, env={"JAX_PLATFORMS": "cpu", "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(1, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("dist-swap", cfg)
 
             from storm_tpu.connectors.kafka_protocol import KafkaWireBroker
@@ -716,8 +714,7 @@ def test_dist_exactly_once_offsets_in_transaction():
         }
         n_msgs = 10
         rng = np.random.RandomState(1)
-        with DistCluster(3, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(3, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("dist-eos", cfg, placement)
 
             from storm_tpu.connectors.kafka_protocol import KafkaWireBroker
@@ -885,8 +882,7 @@ def test_dist_control_plane_auth():
 
     from storm_tpu.dist import DistCluster
 
-    with DistCluster(1, env={"JAX_PLATFORMS": "cpu",
-                             "STORM_TPU_PLATFORM": "cpu"},
+    with DistCluster(1, env={"JAX_PLATFORMS": "cpu"},
                      auth_token="cluster-secret") as cluster:
         target = cluster.clients[0].target
         # the controller's own token-carrying client works (wait_ready in
@@ -917,8 +913,7 @@ def test_dist_control_plane_auth():
     prev = os.environ.get(transport.TOKEN_ENV)
     os.environ[transport.TOKEN_ENV] = "stale-from-previous-cluster"
     try:
-        with DistCluster(1, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"},
+        with DistCluster(1, env={"JAX_PLATFORMS": "cpu"},
                          auth_token="") as cluster:
             cluster.clients[0].control("ping")
     finally:
@@ -1212,8 +1207,7 @@ def test_dist_binary_wire_raw_scheme_matches_local():
         cfg_d = make_cfg("dst")
         placement = {"kafka-spout": 0, "inference-bolt": 1,
                      "kafka-bolt": 2, "dlq-bolt": 2}
-        with DistCluster(3, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(3, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             # every worker advertises the binary wire version
             for c in cluster.clients:
                 assert c.control("ping").get("wire", 0) >= 1
@@ -1265,7 +1259,7 @@ def test_dist_controller_reattach_and_rolling_restart(tmp_path):
     cfg.topology.message_timeout_s = 60.0
     placement = {"kafka-spout": 0, "inference-bolt": 1,
                  "kafka-bolt": 1, "dlq-bolt": 1}
-    env = {"JAX_PLATFORMS": "cpu", "STORM_TPU_PLATFORM": "cpu"}
+    env = {"JAX_PLATFORMS": "cpu"}
     rng = np.random.RandomState(0)
 
     def feed(producer, n):
@@ -1363,7 +1357,7 @@ def test_dist_drain_worker_pauses_and_resumes_intake(tmp_path):
     cfg.batch.max_wait_ms = 20
     cfg.batch.buckets = (8,)
     cfg.topology.message_timeout_s = 60.0
-    env = {"JAX_PLATFORMS": "cpu", "STORM_TPU_PLATFORM": "cpu"}
+    env = {"JAX_PLATFORMS": "cpu"}
     rng = np.random.RandomState(1)
     try:
         with DistCluster(1, env=env) as cluster:

@@ -48,8 +48,7 @@ def test_dist_ui_status_and_admin(run):
         cfg.topology.inference_parallelism = 2
         cfg.topology.sink_parallelism = 1
 
-        with DistCluster(2, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(2, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("dist-ui", cfg, builder="standard")
 
             import asyncio
@@ -179,8 +178,7 @@ def test_dist_ui_profile_routes_to_worker(run, tmp_path):
         cfg.topology.inference_parallelism = 1
         cfg.topology.sink_parallelism = 1
 
-        with DistCluster(1, env={"JAX_PLATFORMS": "cpu",
-                                 "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+        with DistCluster(1, env={"JAX_PLATFORMS": "cpu"}) as cluster:
             cluster.submit("dist-prof", cfg, builder="standard")
 
             import asyncio

@@ -304,39 +304,71 @@ def test_int8_fused_moe_model_runs():
     np.testing.assert_allclose(out.sum(axis=1), 1.0, atol=1e-4)
 
 
-def test_compile_cache_dir_populated(tmp_path):
-    """compile_cache_dir wires up jax's persistent compilation cache: a
-    fresh engine writes executables there on warmup."""
-    import jax
-    import numpy as np
+_CACHE_PROBE = """
+import json, numpy as np
+from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
+from storm_tpu.infer.engine import InferenceEngine, enable_compile_cache
+resolved = enable_compile_cache()
+if {warm}:
+    eng = InferenceEngine(
+        ModelConfig(name="lenet5", input_shape=(28, 28, 1), dtype="float32"),
+        ShardingConfig(data_parallel=0),
+        BatchConfig(max_batch=4, buckets=(4,)))
+    eng.warmup()
+print(json.dumps({{"dir": resolved}}))
+"""
 
-    from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
-    from storm_tpu.infer.engine import InferenceEngine
 
-    from jax._src import compilation_cache
+def _cache_probe(env_dir, warm):
+    """Resolve (and optionally fill) the compile cache in a fresh process:
+    jax latches the directory at the first compile, so every case needs
+    its own interpreter."""
+    import json
+    import os
+    import subprocess
+    import sys
 
-    from storm_tpu.infer import engine as eng_mod
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE.format(warm=warm)],
+                       env=env, capture_output=True, text=True, timeout=100)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])["dir"]
 
-    cache = tmp_path / "xla-cache"
-    prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        eng = InferenceEngine(
-            ModelConfig(name="lenet5", input_shape=(28, 28, 1),
-                        dtype="float32", compile_cache_dir=str(cache)),
-            ShardingConfig(data_parallel=0),
-            BatchConfig(max_batch=4, buckets=(4,)),
-        )
-        eng.predict(np.zeros((4, 28, 28, 1), np.float32))
-        assert cache.exists() and any(cache.iterdir())
-    finally:
-        # Un-latch both jax's cache object and the engine's once-guard so
-        # later tests neither read a deleted tmp dir nor skip their own dir.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prior_min)
-        jax.config.update("jax_compilation_cache_dir", None)
-        compilation_cache.reset_cache()
-        eng_mod._COMPILE_CACHE_DIR = None
+
+def _listing(path):
+    import os
+
+    return sorted(os.listdir(path)) if os.path.isdir(path) else []
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["env_places_it", "fixed_default"])
+def test_compile_cache_dir_populated(tmp_path, placed):
+    """The persistent compile cache is placed from outside. With
+    JAX_COMPILATION_CACHE_DIR set, the engine's warm-up fills that
+    directory and no other; unset, the cache resolves to one fixed path
+    inside the checkout, equal across processes (the path is part of the
+    cache key: a directory that moves never hits)."""
+    import os
+
+    from storm_tpu.infer.engine import DEFAULT_COMPILE_CACHE_DIR
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    if placed:
+        cache = tmp_path / "xla-cache"
+        default_before = _listing(DEFAULT_COMPILE_CACHE_DIR)
+        assert _cache_probe(cache, warm=True) == str(cache)
+        assert any(cache.iterdir()), "warm-up wrote nothing to the cache"
+        assert _listing(DEFAULT_COMPILE_CACHE_DIR) == default_before
+    else:
+        first = _cache_probe(None, warm=False)
+        assert first == DEFAULT_COMPILE_CACHE_DIR
+        assert _cache_probe(None, warm=False) == first
 
 
 def test_live_model_swap_under_traffic(run):

@@ -167,3 +167,56 @@ def test_fused_residual_layernorm_grads():
     for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gr)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ---- Pallas-vs-reference dispatch predicate (ops/platform.py) ---------------
+
+
+class _FakeDevice:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+def _raising_devices():
+    raise RuntimeError("Unable to initialize backend 'tpu': ABORTED: Internal "
+                       "error when accessing libtpu multi-process lockfile.")
+
+
+@pytest.mark.parametrize("devices,no_pallas,want", [
+    (lambda: [_FakeDevice("cpu")], "", False),
+    (lambda: [_FakeDevice("tpu")], "", True),
+    (lambda: [_FakeDevice("tpu")], "1", False),
+    # the escape hatch is honoured before the backend is asked
+    (_raising_devices, "1", False),
+    # a chip that cannot be opened is an error, never the jnp path
+    (_raising_devices, "", RuntimeError),
+], ids=["cpu", "tpu", "tpu_forced_off", "forced_off_no_backend",
+        "backend_error_propagates"])
+def test_use_pallas_dispatch(monkeypatch, devices, no_pallas, want):
+    from storm_tpu.ops import platform
+
+    monkeypatch.setattr(platform.jax, "devices", devices)
+    monkeypatch.setenv("STORM_TPU_NO_PALLAS", no_pallas)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="lockfile"):
+            platform.use_pallas()
+    else:
+        assert platform.use_pallas() is want
+
+
+def test_open_devices_names_a_held_chip(monkeypatch):
+    """libtpu's lockfile error tells the operator to delete the lock; the
+    serving path says what really happened: another process holds the chip."""
+    from storm_tpu.parallel import mesh
+
+    monkeypatch.setattr(mesh.jax, "devices", _raising_devices)
+    with pytest.raises(RuntimeError,
+                       match="a chip belongs to one process at a time"):
+        mesh.make_mesh()
+
+    def other_error():
+        raise RuntimeError("Unknown backend 'tpu'")
+
+    monkeypatch.setattr(mesh.jax, "devices", other_error)
+    with pytest.raises(RuntimeError, match="Unknown backend"):
+        mesh.open_devices()

@@ -172,3 +172,64 @@ def test_unknown_resource_key_rejected():
     cfg.topology.component_resources = {"inference-bolt": {"mem_mb": 400}}
     with pytest.raises(ValueError, match="unknown keys"):
         cluster._auto_place(cfg, "standard")
+
+
+# ---- one process per chip (a TPU belongs to one process at a time) ----------
+
+
+def _two_engine_cluster(n_workers, reports):
+    """A controller with fake clients (plan only, no worker processes) and
+    a two-pipeline config: two inference bolts, each building an engine."""
+    from storm_tpu.config import Config, PipelineConfig
+
+    class FakeClient:
+        def __init__(self, target):
+            self.target = target
+
+    cluster = DistCluster.__new__(DistCluster)
+    cluster.clients = [FakeClient(f"w:{i}") for i in range(n_workers)]
+    cluster._worker_resources = {"memory_mb": 4096.0, "cpu": 400.0}
+    cluster.state_reports = lambda: reports
+    cfg = Config()
+    cfg.pipelines = [
+        PipelineConfig(name=name, input_topic=f"{name}-in",
+                       output_topic=f"{name}-out",
+                       dead_letter_topic=f"{name}-dlq")
+        for name in ("mnist", "cifar")]
+    return cluster, cfg
+
+
+def test_dist_auto_place_colocates_engines():
+    """build_multi_model_topology under dist-run: round-robin used to put
+    the two inference bolts on two workers, and the second one to build
+    its engine found the chip taken. Every engine now shares one worker;
+    the other bolts still spread."""
+    cluster, cfg = _two_engine_cluster(3, {})
+    placement = cluster._auto_place(cfg, "multi")
+    assert placement["mnist-inference"] == placement["cifar-inference"] != 0
+    assert placement["mnist-spout"] == placement["cifar-spout"] == 0
+    assert len(set(placement.values())) == 3
+
+
+@pytest.mark.parametrize("platforms,hosts,refused", [
+    (("tpu,cpu", "tpu,cpu"), ("h", "h"), True),   # both could open the chip
+    (("", ""), ("h", "h"), True),                 # unset: jax picks the TPU
+    (("tpu", "cpu"), ("h", "h"), False),          # the other is pinned off it
+    (("tpu", "tpu"), ("h1", "h2"), False),        # a chip each
+], ids=["same_host", "platform_unset", "one_pinned_to_cpu", "two_hosts"])
+def test_dist_submit_refuses_engines_on_two_workers_of_a_host(
+        platforms, hosts, refused):
+    reports = {i: {"host": hosts[i], "jax_platforms": platforms[i]}
+               for i in range(2)}
+    cluster, cfg = _two_engine_cluster(2, reports)
+    split = {"mnist-spout": 0, "cifar-spout": 0,
+             "mnist-inference": 0, "cifar-inference": 1,
+             "mnist-sink": 0, "cifar-sink": 1, "mnist-dlq": 0, "cifar-dlq": 1}
+    if refused:
+        with pytest.raises(ValueError, match="one process per chip"):
+            cluster._check_one_process_per_chip(cfg, "multi", split)
+    else:
+        cluster._check_one_process_per_chip(cfg, "multi", split)
+    # engines on one worker are always fine, whatever the platform
+    together = dict(split, **{"cifar-inference": 0})
+    cluster._check_one_process_per_chip(cfg, "multi", together)

@@ -57,7 +57,7 @@ def test_readme_quickstart_run_daemon_smoke():
     argv = shlex.split(cmd) + ["--duration", "5"]
     assert argv[0] == "python"
     argv[0] = sys.executable
-    env = dict(os.environ, JAX_PLATFORMS="cpu", STORM_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(argv, cwd=REPO, env=env, capture_output=True,
                          text=True, timeout=360)
     assert out.returncode == 0, out.stderr[-3000:]

@@ -25,7 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.mark.slow
 def test_cpu_soak_exactly_once():
-    env = dict(os.environ, JAX_PLATFORMS="cpu", STORM_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "soak_harness.py",
          "--seconds", "45", "--rate", "20", "--out", "-", "--chaos"],

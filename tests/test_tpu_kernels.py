@@ -3,11 +3,10 @@
 These are the non-interpret twins of tests/test_ops.py's kernel checks:
 the same parity functions (storm_tpu/ops/parity_checks.py) with
 ``interpret=False``, which requires Mosaic — i.e. a real TPU. Under the
-suite's forced-CPU conftest they SKIP (not pass); run them on the chip
-with ``python -m pytest tests/test_tpu_kernels.py --no-header -q -p
-no:cacheprovider`` after exporting STORM_TPU_TEST_PLATFORM=default, or
-via the artifact runner ``python tpu_kernel_parity.py`` (repo root),
-which records KERNEL_TPU_r{N}.json.
+suite's CPU default they SKIP (not pass); run them on the chip with
+``JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_kernels.py --no-header
+-q -p no:cacheprovider``, or via the artifact runner ``python
+tpu_kernel_parity.py`` (repo root), which records KERNEL_TPU_r{N}.json.
 """
 
 import jax

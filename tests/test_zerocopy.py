@@ -425,8 +425,7 @@ def test_dist_frames_bit_identical_and_shm_engaged():
             placement = {"kafka-spout": 0, "inference-bolt": 1,
                          "kafka-bolt": 1, "dlq-bolt": 1}
             n = 12
-            with DistCluster(2, env={"JAX_PLATFORMS": "cpu",
-                                     "STORM_TPU_PLATFORM": "cpu"}) as cluster:
+            with DistCluster(2, env={"JAX_PLATFORMS": "cpu"}) as cluster:
                 cluster.submit("zc-dist", cfg, placement)
                 producer = KafkaWireBroker(cfg.broker.bootstrap)
                 for i in range(n):

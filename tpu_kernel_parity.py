@@ -7,7 +7,7 @@ this runner proves the *Mosaic-compiled* kernels on the real chip — the
 configuration that actually serves — against the same jnp references, and
 writes KERNEL_TPU_r{N}.json with per-case max-abs error vs tolerance.
 
-Run on the chip (default platform resolves to the TPU plugin):
+Run on a machine that holds the chip (JAX then picks the TPU by itself):
   python tpu_kernel_parity.py --out KERNEL_TPU_r05.json
 """
 
