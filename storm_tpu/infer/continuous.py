@@ -523,15 +523,18 @@ class ContinuousBatcher:
         }
 
 
-# ---- per-engine registry ------------------------------------------------------
+# ---- one queue per live engine -------------------------------------------------
 
-# One ContinuousBatcher per live engine object: replicas, the serve path,
-# and cascade tiers sharing an engine (via the shared_engine cache) get the
-# SAME queue — that identity is what makes them co-batch. Entries hold the
-# engine weakly (a finalizer closes the queue when the engine is evicted),
-# so the cache's orphan-refcount eviction keeps working.
-_REGISTRY: Dict[int, ContinuousBatcher] = {}
-_REGISTRY_LOCK = threading.Lock()
+# The queue lives ON the engine it serves (attribute ``_continuous_queue``),
+# so replicas, the serve path, and cascade tiers sharing an engine (via the
+# shared_engine cache) get the SAME queue — that identity is what makes them
+# co-batch. The queue holds its engine weakly, so the cache's orphan-refcount
+# eviction keeps working, and the engine's finalizer closes the queue. A
+# finalizer runs wherever the collector does — on any thread, inside any
+# allocation — so no lock guards this: ``close`` takes only the queue's own
+# re-entrant condition, and each step on ``_LIVE`` (weak references to the
+# queues, for ``registry_stats``) is one atomic set operation.
+_LIVE: set = set()
 
 
 def continuous_for(engine, cfg: BatchConfig,
@@ -539,36 +542,29 @@ def continuous_for(engine, cfg: BatchConfig,
     """The engine's continuous queue, created on first use. ``cfg``/``qos``
     apply on creation only (first caller wins) — all sources sharing an
     engine share one formation policy, like they share its buckets."""
-    key = id(engine)
-    with _REGISTRY_LOCK:
-        cb = _REGISTRY.get(key)
-        if cb is not None and cb._engine_ref() is engine:
-            return cb
-        cb = ContinuousBatcher(engine, cfg, qos)
-        _REGISTRY[key] = cb
-
-        def _drop(k=key):
-            with _REGISTRY_LOCK:
-                dead = _REGISTRY.pop(k, None)
-            if dead is not None:
-                dead.close()
-
-        weakref.finalize(engine, _drop)
-        return cb
+    cb = vars(engine).get("_continuous_queue")
+    if cb is None:
+        new = ContinuousBatcher(engine, cfg, qos)
+        cb = vars(engine).setdefault("_continuous_queue", new)
+        if cb is new:  # this caller won the race to create it
+            _LIVE.add(weakref.ref(new, _LIVE.discard))
+            weakref.finalize(engine, new.close)
+    return cb
 
 
 def registry_stats() -> List[dict]:
     """Stats for every live continuous queue (the qos UI route)."""
-    with _REGISTRY_LOCK:
-        cbs = [cb for cb in _REGISTRY.values()
-               if cb._engine_ref() is not None]
-    return [cb.stats() for cb in cbs]
+    cbs = [ref() for ref in tuple(_LIVE)]
+    return [cb.stats() for cb in cbs
+            if cb is not None and cb._engine_ref() is not None]
 
 
 def _reset_registry() -> None:
-    """Test hook: close and drop every queue."""
-    with _REGISTRY_LOCK:
-        cbs = list(_REGISTRY.values())
-        _REGISTRY.clear()
-    for cb in cbs:
-        cb.close()
+    """Test hook: close every queue and detach it from its engine."""
+    for ref in tuple(_LIVE):
+        cb = ref()
+        engine = None if cb is None else cb._engine_ref()
+        if engine is not None:
+            del engine._continuous_queue
+            cb.close()
+    _LIVE.clear()

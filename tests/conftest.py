@@ -23,7 +23,9 @@ if os.environ["JAX_PLATFORMS"] == "cpu":
     assert len(jax.devices()) == 8, "tests require 8 virtual CPU devices"
 
 import asyncio
+import faulthandler
 import signal
+import sys
 
 import pytest
 
@@ -34,7 +36,9 @@ def _per_test_timeout(request):
     FAIL with a traceback pointing at the hang, not stall the whole run.
     Defaults: 120s, 420s for ``slow``-marked tests; override with
     ``@pytest.mark.timeout(seconds)``. SIGALRM only fires on the main
-    thread, which is where pytest runs test bodies."""
+    thread, which is where pytest runs test bodies; every thread's stack
+    goes to stderr first, so the log names the line that waited even when
+    it is on another thread."""
     if not hasattr(signal, "SIGALRM"):  # pragma: no cover - non-unix
         yield
         return
@@ -44,6 +48,14 @@ def _per_test_timeout(request):
         limit = int(m.args[0])
 
     def _on_alarm(signum, frame):
+        # Past pytest's capture, straight to the run's own stderr: the log
+        # has the stacks at once, also when the run is cut before its report.
+        capture = request.config.pluginmanager.getplugin("capturemanager")
+        with capture.global_and_fixture_disabled():
+            sys.stderr.write(f"\nper-test timeout ({limit}s) in "
+                             f"{request.node.nodeid}; all threads:\n")
+            sys.stderr.flush()
+            faulthandler.dump_traceback(all_threads=True)
         raise TimeoutError(
             f"per-test timeout: exceeded {limit}s (tests/conftest.py)")
 

@@ -377,6 +377,41 @@ def test_registry_identity_and_close_on_eviction():
         cb.submit(_rows())
 
 
+def test_engine_finalizer_inside_continuous_for_does_not_deadlock():
+    """An engine's finalizer runs wherever the collector does — also on a
+    thread that is in the middle of ``continuous_for`` for ANOTHER engine
+    (building the queue allocates, and allocation lets the collector run).
+    The finalizer must need nothing that thread holds: with a registry lock
+    held around the build, this wedged the thread for good."""
+    cfg = BatchConfig(max_batch=8, buckets=(8,), continuous=True)
+    doomed = [_SlotEngine()]
+    dead_cb = continuous_for(doomed[0], cfg)
+
+    class _CollectingEngine(_SlotEngine):
+        @property
+        def ring_capacity(self):  # read while the queue is being built
+            doomed.clear()  # last reference: the finalizer runs right here
+            gc.collect()
+            return 1
+
+        @ring_capacity.setter
+        def ring_capacity(self, value):
+            pass
+
+    eng = _CollectingEngine()
+    got = []
+    t = threading.Thread(
+        target=lambda: got.append(continuous_for(eng, cfg)), daemon=True)
+    t.start()
+    t.join(5.0)
+    assert got, "continuous_for deadlocked against an engine's finalizer"
+    assert not doomed, "the hook ran: the doomed engine died mid-build"
+    assert got[0] is continuous_for(eng, cfg)
+    assert len(registry_stats()) == 1, "only the live engine's queue is left"
+    with pytest.raises(RuntimeError):
+        dead_cb.submit(_rows())
+
+
 # ---- batch_fill / coalesced_sources on BOTH paths ----------------------------
 
 
