@@ -29,19 +29,23 @@ class BatchConfig:
     """
 
     max_batch: int = 256
+    # How long the first row waits for company on an IDLE device. While
+    # the device works, the default path forms a batch when a ring slot
+    # frees, whatever the clock says.
     max_wait_ms: float = 5.0
     # Padding buckets (ascending). Batches are padded to the smallest bucket
     # >= their size; the final entry must equal max_batch.
     buckets: tuple = (8, 32, 128, 256)
-    # Batches allowed in flight per operator instance: one computing on
-    # device while the next accumulates/pads. Deeper pipelining amortizes
-    # per-launch dispatch latency at the cost of tail latency.
+    # Per operator task. On the default path (continuous): the task's
+    # bound on rows it has outstanding in the engine's queue,
+    # max_inflight * max_batch. On the per-task path: batches in flight
+    # per task, one computing on device while the next accumulates/pads.
     max_inflight: int = 2
-    # Work-conserving dispatch: flush the pending batch whenever an
-    # in-flight slot is free instead of waiting out max_wait_ms. Batch
-    # size then adapts to load (idle device -> tiny batches, low latency;
-    # saturated device -> slots stay busy, batches fill toward max_batch
-    # while waiting). The deadline still applies as a fallback bound.
+    # On the default path: an idle device dispatches on arrival instead
+    # of ageing the first row to max_wait_ms (a busy one refills a freed
+    # slot at once either way). On the per-task path: flush the pending
+    # batch whenever one of the TASK's slots is free; under load none is,
+    # so it does not fill buckets there (PERF.md, PR 26).
     eager: bool = False
     # Split-phase device pipeline depth: batches allowed inside the ENGINE
     # between dispatch (stage -> device_put -> async jit launch) and fetch
@@ -58,14 +62,18 @@ class BatchConfig:
     # buffer from dispatch until its fetch completes. 0 = auto
     # (pipeline_depth + 1, so a dispatch never waits on a recycling fetch).
     staging_pool: int = 0
-    # Per-engine continuous batching: batch formation moves out of the
-    # operator into one slot-level queue per shared engine
-    # (storm_tpu/infer/continuous.py). All replicas, the serve
-    # cross-batcher, and cascade escalations co-batch; a dispatcher
-    # refills a pipeline-ring slot the moment it frees instead of
-    # waiting for a per-bolt deadline tick. False keeps the legacy
-    # per-operator MicroBatcher/LaneBatcher path.
-    continuous: bool = False
+    # Where batches form. True (the default since ISSUE 26): every
+    # decoded record goes to the ONE queue of the engine its bolt shares
+    # (storm_tpu/infer/continuous.py) — all replicas, the serve
+    # cross-batcher and cascade escalations co-batch there — and a batch
+    # is cut from that queue when the engine's pipeline ring has a free
+    # slot (or max_batch rows are pending); max_wait_ms bounds only the
+    # wait on an idle device. False keeps the per-operator-task
+    # MicroBatcher/LaneBatcher on the deadline clock (max_inflight
+    # batches a task, each formed when the task's slot frees): the path
+    # whose batches were fixed about twelve device steps before they ran
+    # (PERF.md, PR 26), kept for its tests until Design 1 deletes it.
+    continuous: bool = True
     # Fairness starvation bound for the continuous queue's weighted
     # round-robin: a tenant:lane key passed over for this many batch
     # formations is served first in the next one.

@@ -1,12 +1,22 @@
-"""Deadline-based micro-batcher.
+"""The per-operator-task micro-batcher of the ``continuous=False`` path.
+
+NOT the default path any more (ISSUE 26): ``InferenceBolt`` submits each
+decoded record to the one queue of the engine it shares
+(:mod:`storm_tpu.infer.continuous`), and a batch is cut there when the
+engine's ring has a free slot. This module stays for ``continuous=False``
+and its tests until ROADMAP Design 1 deletes it; ``Batch``/``BatchItem``
+are also what the lane batcher and the cascade router pass around.
 
 The reference runs one ``session.run`` per Kafka record at batch 1
 (InferenceBolt.java:80-86, SURVEY.md §3.3 "no micro-batching, no cross-tuple
 amortization") — the single biggest performance defect to fix for TPU, where
 throughput comes from large MXU-friendly batches. Policy (BatchConfig):
 dispatch when ``max_batch`` instances are waiting OR the oldest instance has
-waited ``max_wait_ms`` — bounding the latency cost of batching so the p50
-Kafka->Kafka target holds at low rates too.
+waited ``max_wait_ms``. With four tasks each holding ``max_inflight`` batches
+ahead of a two-slot ring, "the oldest has waited" is true of every record
+under any load, so a batch's size is fixed about twelve device steps before
+it runs: buckets two thirds empty under a backlog, a standing half second at
+100 records/s (PERF.md §6, PR 26).
 
 Pure accumulation logic, no asyncio here (the operator owns timing/tasks):
 easy to unit-test, like the reference's mkProducer seam philosophy.

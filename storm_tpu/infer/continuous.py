@@ -1,17 +1,18 @@
-"""Per-engine continuous batching: one slot-level queue feeds the device.
+"""Per-engine batching: one slot-level queue feeds the device.
 
-The deadline micro-batchers (:class:`~storm_tpu.infer.batcher.MicroBatcher`,
-:class:`~storm_tpu.qos.lanes.LaneBatcher`) form batches PER OPERATOR TASK:
-under parallelism the device sees each replica's fragment — the measured
-cause of the 8-bolts-slower-than-1 inversion (ROADMAP item 3). BatchGen
-(PAPERS.md) argues batch formation must be decoupled from operator topology
-and run continuously at the device. This module is that decoupling: every
-replica of an inference bolt, the gRPC serve path's cross-batcher, and
-cascade escalation residues all ``submit`` rows into ONE queue per shared
-engine, and a dedicated dispatcher thread refills a pipeline-ring slot the
-moment it frees (extending the split-phase ring of
-:mod:`storm_tpu.infer.engine`) instead of waiting for a per-bolt deadline
-tick.
+The default path since ISSUE 26: every replica of an inference bolt, the
+gRPC serve path's cross-batcher, ``DecodeBolt`` and cascade escalation
+residues all ``submit`` rows into ONE queue per shared engine, and a
+dedicated dispatcher thread cuts a batch from it the moment a slot of the
+engine's pipeline ring frees (extending the split-phase ring of
+:mod:`storm_tpu.infer.engine`) — late binding: a batch's size is decided
+when the device can take it, from everything that has arrived by then.
+The per-task deadline batchers (:class:`~storm_tpu.infer.batcher.MicroBatcher`,
+:class:`~storm_tpu.qos.lanes.LaneBatcher`) decide it PER OPERATOR TASK, on a
+5 ms clock, about twelve device steps ahead: under parallelism the device
+sees each replica's fragment (BatchGen, PAPERS.md, argues batch formation
+must be decoupled from operator topology and run continuously at the
+device; PERF.md §6, PR 26 has the chip's numbers for both).
 
 Dispatch rule (work-conserving slot refill):
 
@@ -23,6 +24,13 @@ Dispatch rule (work-conserving slot refill):
 - the device is fully idle -> ``eager`` dispatches on arrival, otherwise
   the oldest row ages to ``max_wait_ms`` (the deadline batcher's latency
   floor is preserved for trickle traffic).
+
+How many rows a batch takes: ``max_batch``, or — where the engine's
+measured step time (``engine.step_ms``, the least step seen) grows
+with the bucket — the largest FULL bucket under the pending rows, when
+serving them in full buckets takes no longer than one step padded up to the
+next bucket (:meth:`ContinuousBatcher._rows_to_take_locked`). A model whose
+step costs the same whatever its bucket pads as before.
 
 Fairness moves here from the LaneBatcher: rows queue per ``tenant:lane``
 key, batch formation orders keys earliest-deadline-first (lane deadlines
@@ -40,6 +48,7 @@ its own tuples independently; nothing is shared but the device round trip.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 import weakref
@@ -52,6 +61,8 @@ import numpy as np
 from storm_tpu.config import BatchConfig, QosConfig
 from storm_tpu.runtime.tracing import DEVICE_SUBSTAGES
 
+logger = logging.getLogger(__name__)
+
 
 class Submission:
     """One submitted record inside the continuous queue.
@@ -60,14 +71,18 @@ class Submission:
     ``(n, K)`` prediction rows — or to the exception that failed the
     coalesced batch it rode in. ``batch_span`` carries the shared device
     span id of the batch that served it (None untraced), so a cascade
-    escalation can link the next tier's spans back."""
+    escalation can link the next tier's spans back. ``notify``, when given,
+    is called once per device batch (after the futures resolve, on the
+    thread that finished the batch) with the list of the submissions of
+    that batch that share it, in batch order — how a source learns which
+    of its records rode one batch together."""
 
     __slots__ = ("data", "payload", "ts", "enq", "lane", "tenant", "source",
-                 "deadline", "future", "batch_span")
+                 "deadline", "future", "batch_span", "notify")
 
     def __init__(self, data, payload, ts: float, enq: float,
                  lane: Optional[str], tenant: Optional[str], source: str,
-                 deadline: float) -> None:
+                 deadline: float, notify: Optional[Callable] = None) -> None:
         self.data = data
         self.payload = payload
         self.ts = ts
@@ -78,10 +93,27 @@ class Submission:
         self.deadline = deadline
         self.future: Future = Future()
         self.batch_span: Optional[str] = None
+        self.notify = notify
 
     @property
     def rows(self) -> int:
         return int(self.data.shape[0])
+
+
+def _drain_ms(rows: int, steps: Dict[int, float]) -> float:
+    """Least device time to serve ``rows`` in steps of the measured
+    buckets: at each point one step padded up to the next bucket, or the
+    largest full bucket now and the rest after it, whichever is less."""
+    if rows <= 0:
+        return 0.0
+    top = max(steps)
+    if rows >= top:
+        return steps[top] + _drain_ms(rows - top, steps)
+    pad = steps[min(b for b in steps if b >= rows)]
+    lo = max((b for b in steps if b <= rows), default=None)
+    if lo is None:
+        return pad
+    return min(pad, steps[lo] + _drain_ms(rows - lo, steps))
 
 
 class ContinuousBatcher:
@@ -141,13 +173,13 @@ class ContinuousBatcher:
              trace_of: Optional[Callable] = None,
              link_of: Optional[Callable] = None,
              span_name: str = "device_execute") -> None:
-        """Attach the observability surfaces. Idempotent with first-binder-
-        wins semantics: replicas sharing one engine all call this; the
-        queue is per engine, so its metrics land once, under the first
-        binder's component id."""
+        """Attach the observability surfaces. The latest binder wins:
+        replicas sharing one engine bind the same registry and component
+        id, so among them the call is idempotent and the queue's metrics
+        land once; a topology submitted later in the same process (the
+        engine cache outlives a topology) takes the queue over from one
+        that is gone."""
         with self._cond:
-            if self._metrics is not None:
-                return
             self._metrics = metrics
             self._cid = component_id
             self._tracer = tracer
@@ -183,7 +215,8 @@ class ContinuousBatcher:
     def submit(self, data: np.ndarray, payload=None,
                ts: Optional[float] = None, lane: Optional[str] = None,
                tenant: Optional[str] = None,
-               source: str = "anon") -> Submission:
+               source: str = "anon",
+               notify: Optional[Callable] = None) -> Submission:
         """Enqueue one record's rows; returns a :class:`Submission` whose
         future resolves to this record's own prediction slice. Never
         blocks — per-source backpressure (``max_inflight``) is the
@@ -192,7 +225,7 @@ class ContinuousBatcher:
         base = ts if ts is not None else now
         sub = Submission(
             data, payload, base, now, lane, tenant, source,
-            base + self._deadline_ms(lane) / 1e3)
+            base + self._deadline_ms(lane) / 1e3, notify)
         with self._cond:
             if self._closed:
                 raise RuntimeError("continuous batcher is closed")
@@ -200,8 +233,25 @@ class ContinuousBatcher:
                 self._key(tenant, lane), deque()).append(sub)
             self._pending_rows += sub.rows
             self._ensure_thread_locked()
-            self._cond.notify_all()
+            if (self._inflight < self.capacity
+                    or self._pending_rows >= self.cfg.max_batch):
+                # Otherwise the dispatcher sleeps until a slot frees
+                # (_loop's untimed wait) and one more row changes nothing:
+                # under a backlog that spares a wake-up per record.
+                self._cond.notify_all()
         return sub
+
+    def configure(self, cfg: BatchConfig,
+                  qos: Optional[QosConfig] = None) -> None:
+        """Take the formation policy of the latest caller of
+        :func:`continuous_for` (deadline, eagerness, starvation bound; the
+        buckets and ``max_batch`` are the engine's identity and cannot
+        differ). A caller without QoS leaves the lanes of one with."""
+        with self._cond:
+            self.cfg = cfg
+            if qos is not None and qos.enabled:
+                self.qos = qos
+            self._cond.notify_all()
 
     def flush(self) -> None:
         """Force-dispatch everything pending (graceful drain): the force
@@ -279,14 +329,46 @@ class ContinuousBatcher:
         # weight = n_lanes - lane_index (highest lane = n, lowest = 1).
         return len(self.qos.lanes) - self.qos.lane_index(key[1])
 
+    def _rows_to_take_locked(self) -> int:
+        """The row limit of the next batch: ``max_batch``, or the largest
+        FULL bucket under the pending rows where cutting there serves them
+        sooner than padding them up to the next bucket. Which it is
+        depends on how the engine's step time grows with the bucket, and
+        the engine measures that (``step_ms``: the least step seen of each
+        bucket that traffic has used): where a step
+        costs about the same per padded row (a large model), 9 rows padded
+        to 32 cost four 8-row steps and serve one, and the long step
+        gathers the next over-full batch; where a step costs the same
+        whatever its bucket (a launch-bound model), padding is free and
+        two steps are twice one. Where either bucket is unmeasured the
+        batch pads, which is how a bucket gets its first reading."""
+        max_rows = max(1, self.cfg.max_batch)
+        pending = self._pending_rows
+        if pending >= max_rows:
+            return max_rows
+        # a copy: the engine's fetch thread writes the original
+        steps = dict(getattr(self._engine_ref(), "step_ms", None) or ())
+        if not steps:
+            return max_rows
+        lo = max((b for b in steps if b <= pending), default=None)
+        hi = min((b for b in steps if b >= pending), default=None)
+        if lo is None or hi is None or lo == hi:
+            return max_rows
+        if steps[lo] + _drain_ms(pending - lo, steps) <= steps[hi]:
+            # no row finishes later than under the padded step, and the
+            # first ``lo`` finish sooner
+            return lo
+        return max_rows
+
     def _form_locked(self) -> List[Submission]:
-        """Take up to ``max_batch`` rows across keys. Key order: starved
+        """Take up to ``max_batch`` rows across keys (fewer where
+        :meth:`_rows_to_take_locked` cuts at a full bucket). Key order: starved
         keys first (passed over >= starvation_rounds formations, most
         starved first), then earliest head-of-line deadline — EDF across
         tenants and lanes, so LaneBatcher's preemption semantics hold.
         Within the order, rows are taken weighted-round-robin so one
         flooding key cannot monopolize a batch while others wait."""
-        max_rows = max(1, self.cfg.max_batch)
+        max_rows = self._rows_to_take_locked()
         rounds = max(1, int(getattr(self.cfg, "starvation_rounds", 4)))
         keys = [k for k, q in self._queues.items() if q]
         starved = sorted(
@@ -407,6 +489,7 @@ class ContinuousBatcher:
             # batch's exception and each source replays ITS OWN tuples.
             for it in items:
                 it.future.set_exception(exc)
+            self._notify(items)
             return
         padded = rows
         if handle is not None:
@@ -432,6 +515,9 @@ class ContinuousBatcher:
             self._m["device_ms"].observe((t_done - t_disp) * 1e3)
             self._m["infer"].inc(rows)
             self._m["coalesced"].inc(len(sources))
+            # Device steps by padded bucket: with batch_size's count, the
+            # share of steps each program of the ladder ran.
+            self._metrics.counter(self._cid, f"steps_bucket_{padded}").inc()
             timings = getattr(handle, "timings", None) if handle else None
             if timings:
                 for key, _ in DEVICE_SUBSTAGES:
@@ -450,6 +536,21 @@ class ContinuousBatcher:
             n = it.rows
             it.future.set_result(out[ofs:ofs + n])
             ofs += n
+        self._notify(items)
+
+    @staticmethod
+    def _notify(items: List[Submission]) -> None:
+        """Tell each source which of its records rode this batch together
+        (``Submission.notify``), once a source, in batch order."""
+        groups: Dict[int, List[Submission]] = {}
+        for it in items:
+            if it.notify is not None:
+                groups.setdefault(id(it.notify), []).append(it)
+        for members in groups.values():
+            try:
+                members[0].notify(members)
+            except Exception:  # one source's callback must not cost another's
+                logger.exception("continuous batch notify failed")
 
     def _trace(self, items, t0, t1, handle, fill, n_sources):
         """Continuous-mode analogue of the operator's ``_trace_batch``:
@@ -539,9 +640,11 @@ _LIVE: set = set()
 
 def continuous_for(engine, cfg: BatchConfig,
                    qos: Optional[QosConfig] = None) -> ContinuousBatcher:
-    """The engine's continuous queue, created on first use. ``cfg``/``qos``
-    apply on creation only (first caller wins) — all sources sharing an
-    engine share one formation policy, like they share its buckets."""
+    """The engine's continuous queue, created on first use. All sources
+    sharing an engine share one formation policy, like they share its
+    buckets: the latest caller's (:meth:`ContinuousBatcher.configure`) —
+    the engine cache outlives a topology, and the next one's deadline
+    must not be the last one's."""
     cb = vars(engine).get("_continuous_queue")
     if cb is None:
         new = ContinuousBatcher(engine, cfg, qos)
@@ -549,6 +652,8 @@ def continuous_for(engine, cfg: BatchConfig,
         if cb is new:  # this caller won the race to create it
             _LIVE.add(weakref.ref(new, _LIVE.discard))
             weakref.finalize(engine, new.close)
+            return cb
+    cb.configure(cfg, qos)
     return cb
 
 

@@ -184,7 +184,7 @@ class InflightBatch:
     """
 
     __slots__ = ("future", "n", "padded", "timings", "profile_key", "_out",
-                 "_buf", "_t_launched", "watchdog_ms", "on_done")
+                 "_buf", "_t_put", "_t_launched", "watchdog_ms", "on_done")
 
     def __init__(self, n: int, padded: int) -> None:
         self.future: Future = Future()
@@ -196,6 +196,9 @@ class InflightBatch:
         self.profile_key: Optional[str] = None
         self._out = None  # device array, dropped after fetch
         self._buf = None  # staging buffer, recycled after fetch
+        # staged on the host, device_put + launch next; 0.0 = not to be
+        # timed (a program's first call compiles or loads it)
+        self._t_put = 0.0
         self._t_launched = 0.0
         # Watchdog contract (set by dispatch): fetch waits at most
         # watchdog_ms (0 = forever) and reports the outcome to on_done —
@@ -209,20 +212,40 @@ class InflightBatch:
 
 
 def _fetch_loop(fetch_q: "queue.SimpleQueue", ring: threading.Semaphore,
-                staging: StagingPool) -> None:
+                staging: StagingPool, step_ms: Dict[int, float]) -> None:
     """Dedicated fetch thread: completes in-flight batches in dispatch
     order. Blocking here is the point — one batch's device->host RTT
     overlaps the NEXT batch's staging/H2D (dispatch holds the lock, fetch
     never does) and the one-after's device compute. Module-level so the
     thread never references the engine (see _ensure_fetch_thread); a None
-    sentinel (engine finalizer, tests) shuts it down."""
+    sentinel (engine finalizer, tests) shuts it down.
+
+    It also keeps ``step_ms`` (the engine's dict, not the engine): the
+    device time of one step of each padded bucket, as the least seen of
+    "ready, less the later of this batch's hand-over to the device (its
+    ``device_put``; a backend that computes inside the launch call has
+    computed by the launch's return) and the batch before becoming ready"
+    — with a batch queued behind another that is the step itself,
+    queueing excluded. Only moments this thread SAW count: a batch
+    that was ready before the thread came to it (a stalled host) would
+    read as late as the thread was, and the next one as short, and a
+    least-seen never forgets a reading that was too short."""
+    prev_ready, prev_seen = 0.0, True
     while True:
         handle = fetch_q.get()
         if handle is None:
             return
+        seen = False
         try:
+            seen = _not_ready_yet(handle._out)
             _watchdog_wait(handle)
             t1 = time.perf_counter()
+            if seen and handle._t_put and (
+                    prev_seen or handle._t_put >= prev_ready):
+                step = (t1 - max(handle._t_put, prev_ready)) * 1e3
+                if step < step_ms.get(handle.padded, float("inf")):
+                    step_ms[handle.padded] = step
+            prev_ready = t1
             res = np.asarray(handle._out)
             t2 = time.perf_counter()
             handle.timings["compute_ms"] = (t1 - handle._t_launched) * 1e3
@@ -249,13 +272,25 @@ def _fetch_loop(fetch_q: "queue.SimpleQueue", ring: threading.Semaphore,
             handle._out = None
             handle.future.set_exception(e)
             _notify_done(handle, e)
+            # when the device is done with it nobody saw
+            prev_ready, seen = time.perf_counter(), False
         else:
             _notify_done(handle, None)
         finally:
+            prev_seen = seen
             buf, handle._buf = handle._buf, None
             if buf is not None:
                 staging.release(buf)
             ring.release()
+
+
+def _not_ready_yet(out) -> bool:
+    """Whether the fetch thread will SEE this result become ready. A
+    function of its own so that no local of ``_fetch_loop`` keeps the
+    device array: the loop's frame lives until interpreter exit, and a
+    device array freed from a daemon thread then aborts the process."""
+    is_ready = getattr(out, "is_ready", None)
+    return is_ready is not None and not is_ready()
 
 
 def _watchdog_wait(handle: InflightBatch) -> None:
@@ -466,6 +501,15 @@ class InferenceEngine:
         # Dispatch slots visible to the continuous batcher: ring depth when
         # pipelined, else the single serialized predict slot.
         self.ring_capacity = max(1, self.pipeline_depth)
+        # Device milliseconds of one step of each padded bucket: what the
+        # continuous queue's formation rule reads to tell a model whose
+        # step grows with its bucket from a launch-bound one
+        # (infer/continuous.py). The fetch thread keeps it, as the least
+        # step seen of each compiled program (_fetch_loop); a program's
+        # first run is not read (even with the compile taken out it reads
+        # high: 81 ms for ViT-g/14's 35 ms 8-row step on the v5e, PERF.md
+        # PR 26), so a bucket has no entry until traffic has used it once.
+        self.step_ms: Dict[int, float] = {}
         # Watchdog / quarantine state (batch.watchdog_ms, watchdog_trips):
         # consecutive fetch-deadline trips counted on the fetch thread via
         # the handle's on_done hook; at the threshold the engine flips to
@@ -803,6 +847,7 @@ class InferenceEngine:
             _copyledger.record("staging", f32.nbytes + buf.nbytes,
                                copies=2, records=n,
                                engine=self.profile_key or "-")
+            handle._t_put = 0.0 if cold else time.perf_counter()
             with self._lock:
                 xd = jax.device_put(buf, self._x_sharding)
                 out = self._fwd_q(self.params, self.state, xd, scale, offset)
@@ -815,6 +860,7 @@ class InferenceEngine:
             # dispatch phase (pad + cast into the pooled buffer).
             _copyledger.record("staging", buf.nbytes, copies=1,
                                records=n, engine=self.profile_key or "-")
+            handle._t_put = 0.0 if cold else time.perf_counter()
             with self._lock:
                 xd = jax.device_put(buf, self._x_sharding)
                 out = self._fwd(self.params, self.state, xd)
@@ -904,7 +950,8 @@ class InferenceEngine:
                 # params — and a finalizer stops it when the engine dies.
                 t = threading.Thread(
                     target=_fetch_loop,
-                    args=(self._fetch_q, self._ring, self._staging),
+                    args=(self._fetch_q, self._ring, self._staging,
+                          self.step_ms),
                     daemon=True,
                     name=f"storm-tpu-fetch-{self.model_cfg.name}")
                 t.start()

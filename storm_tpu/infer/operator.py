@@ -9,12 +9,16 @@ Per-tuple flow, redesigned for the async device boundary:
    emits a :class:`DeadLetter` on the ``dead_letter`` stream and acks
    (the reference emitted ``null`` and acked, :92-99 — poison input should
    never wedge the stream, but it should also never masquerade as output);
-3. feed the micro-batcher; a full batch (or deadline flush) dispatches to
-   the shared :class:`InferenceEngine` on a worker thread — the event loop
-   keeps consuming while the TPU computes (the reference blocked its
-   executor thread in ``session.run`` at batch 1);
+3. submit the decoded rows to the one queue of the shared
+   :class:`InferenceEngine` (:mod:`storm_tpu.infer.continuous`), where a
+   batch is cut when the engine's ring has a free slot, on the queue's own
+   thread — the event loop keeps consuming while the TPU computes (the
+   reference blocked its executor thread in ``session.run`` at batch 1).
+   ``batch.continuous=False`` feeds a per-task micro-batcher instead, whose
+   full batch (or deadline flush) dispatches on a worker thread;
 4. when the batch returns, emit one ``{"predictions": ...}`` tuple per
-   input record (anchored) and ack — acks are *deferred* until the device
+   input record (records of one ``RecordFrame`` that rode one batch share
+   one), anchored, and ack — acks are *deferred* until the device
    round-trip completes, preserving at-least-once across the async boundary
    (SURVEY.md §7 "Hard parts").
 
@@ -24,6 +28,7 @@ replay (the reference swallowed inference errors)."""
 from __future__ import annotations
 
 import asyncio
+import functools
 import time
 from typing import Optional, Sequence, Set
 
@@ -35,6 +40,7 @@ from storm_tpu.cascade.policy import CascadeConfig
 from storm_tpu.cascade.router import CascadeRouter, Escalated
 from storm_tpu.config import BatchConfig, Config, ModelConfig, ShardingConfig
 from storm_tpu.infer.batcher import Batch, MicroBatcher
+from storm_tpu.infer.continuous import continuous_for
 from storm_tpu.infer.engine import InferenceEngine, shared_engine
 from storm_tpu.obs import copyledger as _copyledger
 from storm_tpu.runtime.base import Bolt, OutputCollector, TopologyContext
@@ -66,6 +72,18 @@ class _ChunkHandle:
         self.remaining -= 1
         if self.remaining == 0:
             (collector.fail if self.failed else collector.ack)(self.tuple)
+
+
+# What an engine's queue asks of a payload this operator submitted. Module
+# functions, not closures: the queue lives as long as its engine and must
+# not keep a bolt of a topology that is gone.
+
+def _trace_of(payload):
+    return InferenceBolt._anchor_of(payload).trace
+
+
+def _link_of(payload):
+    return payload.link_span if isinstance(payload, Escalated) else None
 
 
 class InferenceBolt(Bolt):
@@ -293,34 +311,23 @@ class InferenceBolt(Bolt):
             self.engine.on_quarantine = self._engine_quarantined
         except AttributeError:
             pass  # slotted test double
-        # Continuous batching (BatchGen, ROADMAP item 3): batch formation
-        # moves OFF this task into the engine's shared slot-level queue —
-        # every replica, the serve cross-batcher, and cascade residues
-        # co-batch there. The per-task batchers above stay as admission
-        # shims (shed/lane classification still happens here); they just
-        # never accumulate.
-        self._continuous = bool(getattr(self.batch_cfg, "continuous", False))
+        # The default path (ISSUE 26): batch formation lives OFF this task,
+        # in the one queue of the engine it shares — every replica, the
+        # serve cross-batcher and cascade residues co-batch there, and a
+        # batch is cut from the queue when the engine's ring has a free
+        # slot (infer/continuous.py), not per task on the deadline clock.
+        # The per-task batchers above stay as admission shims (shed/lane
+        # classification still happens here); they just never accumulate.
+        # ``continuous=False`` keeps the per-task deadline path.
+        self._continuous = bool(getattr(self.batch_cfg, "continuous", True))
         self._cbs = {}
+        self._cb_notify = {}
         if self._continuous:
-            from storm_tpu.infer.continuous import continuous_for
-
-            trace_of = lambda p: self._anchor_of(p).trace  # noqa: E731
-            link_of = (  # noqa: E731
-                lambda p: p.link_span if isinstance(p, Escalated) else None)
             if self._router is not None:
                 for rt in self._router.tiers:
-                    tcb = continuous_for(rt.engine, self.batch_cfg, self.qos)
-                    tcb.bind(m, cid, tracer=self._tracer,
-                             flight=self._flight, trace_of=trace_of,
-                             link_of=link_of,
-                             span_name=f"cascade_tier{rt.index}")
-                    self._cbs[rt.index] = tcb
+                    self._bind_queue(rt.index, rt.engine)
             else:
-                cb = continuous_for(self.engine, self.batch_cfg, self.qos)
-                cb.bind(m, cid, tracer=self._tracer, flight=self._flight,
-                        trace_of=trace_of, link_of=link_of,
-                        span_name="device_execute")
-                self._cbs[None] = cb
+                self._bind_queue(None, self.engine)
             # Per-task backpressure: the dispatch semaphore bounded
             # BATCHES in flight; here the queue owns batching, so the
             # task bounds its outstanding ROWS at the equivalent
@@ -331,6 +338,21 @@ class InferenceBolt(Bolt):
             self._cb_room = asyncio.Event()
             self._cb_room.set()
             self._cb_source = f"{cid}#{context.task_index}"
+            self._loop = None  # the event loop, known from the first record
+
+    def _bind_queue(self, tier: Optional[int], engine) -> None:
+        """Aim this task at ``engine``'s continuous queue (``tier`` None:
+        the one engine of a bolt without a cascade) and take the queue's
+        observability over for this topology."""
+        cb = continuous_for(engine, self.batch_cfg, self.qos)
+        cb.bind(self.context.metrics, self.context.component_id,
+                tracer=self._tracer, flight=self._flight,
+                trace_of=_trace_of, link_of=_link_of,
+                span_name=("device_execute" if tier is None
+                           else f"cascade_tier{tier}"))
+        self._cbs[tier] = cb
+        self._cb_notify.setdefault(
+            tier, functools.partial(self._on_batch, tier))
 
     # ---- quarantine -> replacement -------------------------------------------
 
@@ -363,18 +385,10 @@ class InferenceBolt(Bolt):
                 except AttributeError:
                     pass
                 self.engine = eng
-                # Re-aim the continuous batcher (it holds the engine it
-                # dispatches to) at the replacement.
+                # Re-aim at the replacement's queue (a queue holds the
+                # engine it dispatches to).
                 if getattr(self, "_cbs", None) and None in self._cbs:
-                    from storm_tpu.infer.continuous import continuous_for
-
-                    cb = continuous_for(eng, self.batch_cfg, self.qos)
-                    m = self.context.metrics
-                    cb.bind(m, self.context.component_id,
-                            tracer=self._tracer, flight=self._flight,
-                            trace_of=lambda p: self._anchor_of(p).trace,
-                            span_name="device_execute")
-                    self._cbs[None] = cb
+                    self._bind_queue(None, eng)
                 self._m_quarantined.set(0)
                 if self._flight is not None:
                     self._flight.event(
@@ -665,80 +679,100 @@ class InferenceBolt(Bolt):
     # ---- continuous batching path --------------------------------------------
 
     async def _submit_record(self, item, data, ts, lane, entry) -> None:
-        """Hand one record to its tier's shared continuous queue and
-        complete it from a per-record task. Backpressure is row-counted
-        per task (``max_inflight * max_batch`` outstanding rows — the
+        """Hand one record to its tier's shared continuous queue; the
+        queue calls back once a device batch with this task's records of
+        it (``_on_batch``). Backpressure is row-counted per task
+        (``max_inflight * max_batch`` outstanding rows — the
         row-equivalent of the dispatch semaphore, which bounded whole
         batches); the engine's pipeline ring stays the device-side
         bound."""
-        n = int(data.shape[0])
         while self._cb_rows >= self._cb_cap:
             self._cb_room.clear()
             await self._cb_room.wait()
-        self._cb_rows += n
+        if self._loop is None:
+            self._loop = asyncio.get_running_loop()
         tenant = (self._anchor_of(item).get("qos_tenant", None)
                   if self.qos is not None else None)
-        sub = self._cbs[entry].submit(
+        self._enqueue(entry, data, item, ts, lane, tenant)
+
+    def _enqueue(self, tier, data, item, ts, lane, tenant) -> None:
+        """Count the rows as this task's and submit them; never waits (an
+        escalation to the next tier must not park behind the row bound its
+        own completion frees)."""
+        self._cb_rows += int(data.shape[0])
+        self._cbs[tier].submit(
             data, payload=item, ts=ts, lane=lane, tenant=tenant,
-            source=self._cb_source)
-        task = asyncio.get_running_loop().create_task(
-            self._finish_record(sub, entry, n))
+            source=self._cb_source, notify=self._cb_notify[tier])
+
+    def _on_batch(self, tier, members) -> None:
+        """The queue's callback, on the thread that finished the device
+        batch: hand this task's records of that batch to the event loop
+        as one group."""
+        try:
+            self._loop.call_soon_threadsafe(self._spawn_group, tier, members)
+        except RuntimeError:
+            pass  # loop closed under a shutdown: nobody is left to emit to
+
+    def _spawn_group(self, tier, members) -> None:
+        task = self._loop.create_task(self._finish_group(tier, members))
         self._inflight.add(task)
         task.add_done_callback(self._inflight.discard)
 
-    async def _finish_record(self, sub, tier, n_rows: int) -> None:
-        """Await one submission through as many cascade tiers as it
-        needs, then emit + complete — the continuous analogue of
-        ``_run_batch``'s emit/escalate block at record granularity.
-        A queue/device failure at ANY tier fails the ORIGINAL tuple
-        (``_complete`` unwraps ``Escalated``), so the record replays
-        from tier 0 — exactly-once semantics identical to the batch
-        path."""
-        item = sub.payload
+    async def _finish_group(self, tier, members) -> None:
+        """Emit + complete this task's records of one device batch — the
+        continuous analogue of ``_run_batch``'s emit/escalate block. A
+        record a cascade tier is unsure of goes on to the next tier's
+        queue and completes from that batch's group. A queue/device
+        failure at ANY tier fails the ORIGINAL tuple (``_complete``
+        unwraps ``Escalated``), so the record replays from tier 0 —
+        at-least-once exactly as on the batch path, each source failing
+        its own tuples only."""
+        emit = []
         try:
-            while True:
-                out = await asyncio.wrap_future(sub.future)
-                if tier is None:
-                    preds = out
-                    break
-                level = (int(self._shed_gauge.value)
-                         if self.qos is not None else 0)
-                merged, residue, info = self._router.decide_item(
-                    item, sub.data, out, sub.lane, tier, level, ts=sub.ts)
-                if residue is None:
-                    preds = merged
-                    break
-                wrapper = residue.payload
-                # Chain the trace: the next tier's queue_wait span links
-                # back to the span of the batch that escalated this row.
-                wrapper.link_span = sub.batch_span
-                if self._flight is not None:
-                    self._flight.event(
-                        "cascade_escalation", throttle_s=1.0,
-                        component=self.context.component_id,
-                        tier=tier, model=self._router.tiers[tier].name,
-                        escalation_rate=round(
-                            self._router.escalation_rate(), 4), **info)
-                item = wrapper
-                tier += 1
-                sub = self._cbs[tier].submit(
-                    residue.data, payload=wrapper, ts=residue.ts,
-                    lane=residue.lane, tenant=sub.tenant,
-                    source=self._cb_source)
-            anchor = self._anchor_of(item)
-            with span(self.context.metrics, self.context.component_id,
-                      "encode"):
-                msg = self._encode_ledgered(preds)
-            await self.collector.emit(
-                Values([msg, *self._extras(anchor)]), anchors=[anchor])
-            self._complete(item, True)
-        except Exception as e:
-            self.collector.report_error(e)
-            self._complete(item, False)
+            reported = False
+            for sub in members:
+                item = sub.payload
+                try:
+                    preds = sub.future.result()
+                    if tier is not None:
+                        preds = self._decide_record(sub, preds, tier)
+                        if preds is None:
+                            continue  # escalated
+                    emit.append((item, preds))
+                except Exception as e:
+                    if not reported:
+                        self.collector.report_error(e)
+                        reported = True
+                    self._complete(item, False)
+            await self._emit_groups(emit)
         finally:
-            self._cb_rows -= n_rows
+            self._cb_rows -= sum(sub.rows for sub in members)
             if self._cb_rows < self._cb_cap:
                 self._cb_room.set()
+
+    def _decide_record(self, sub, out, tier: int):
+        """One record's cascade decision after ``tier`` served it: its
+        merged predictions, or None once its unsure rows are on their way
+        to the next tier's queue."""
+        level = int(self._shed_gauge.value) if self.qos is not None else 0
+        merged, residue, info = self._router.decide_item(
+            sub.payload, sub.data, out, sub.lane, tier, level, ts=sub.ts)
+        if residue is None:
+            return merged
+        wrapper = residue.payload
+        # Chain the trace: the next tier's queue_wait span links back to
+        # the span of the batch that escalated this row.
+        wrapper.link_span = sub.batch_span
+        if self._flight is not None:
+            self._flight.event(
+                "cascade_escalation", throttle_s=1.0,
+                component=self.context.component_id,
+                tier=tier, model=self._router.tiers[tier].name,
+                escalation_rate=round(
+                    self._router.escalation_rate(), 4), **info)
+        self._enqueue(tier + 1, residue.data, wrapper, residue.ts,
+                      residue.lane, sub.tenant)
+        return None
 
     async def _dead_letter(self, t: Tuple, payload: str, error: str) -> None:
         """Poison input: route to the dead-letter stream and ack (replaying
@@ -925,6 +959,8 @@ class InferenceBolt(Bolt):
             fill = batch.size / max(padded, 1)
             self._m_fill.observe(fill)
             self._m_coalesced.inc()
+            self.context.metrics.counter(
+                self.context.component_id, f"steps_bucket_{padded}").inc()
             batch_span = None
             if self._tracer is not None and self._tracer.active:
                 batch_span = self._trace_batch(batch, t0, t1, timings,
@@ -949,36 +985,7 @@ class InferenceBolt(Bolt):
                          if self.qos is not None else 0)
                 emit, escalated, info = self._router.decide(
                     batch, out, tier, level)
-            # Batch egress: records that arrived together as a RecordFrame
-            # leave together — their predictions concatenate into ONE
-            # payload per (frame, dispatched batch), killing the
-            # per-record json_encode fan-out (r19 zero-copy plan). Other
-            # items keep the one-payload-per-record contract.
-            for handle, group in self._egress_groups(emit):
-                if handle is None:
-                    item, preds = group[0]
-                    anchor = self._anchor_of(item)
-                    with span(self.context.metrics,
-                              self.context.component_id, "encode"):
-                        msg = self._encode_ledgered(preds)
-                    await self.collector.emit(
-                        Values([msg, *self._extras(anchor)]),
-                        anchors=[anchor],
-                    )
-                    self._complete(item, True)
-                    continue
-                anchor = handle.tuple
-                preds = (group[0][1] if len(group) == 1 else
-                         np.concatenate([p for _, p in group], axis=0))
-                with span(self.context.metrics, self.context.component_id,
-                          "encode"):
-                    msg = self._encode_ledgered(preds, records=len(group))
-                await self.collector.emit(
-                    Values([msg, *self._extras(anchor)]),
-                    anchors=[anchor],
-                )
-                for item, _ in group:
-                    self._complete(item, True)
+            await self._emit_groups(emit)
             if escalated:
                 if self._flight is not None:
                     self._flight.event(
@@ -997,6 +1004,32 @@ class InferenceBolt(Bolt):
             self._dispatch_sem.release()
             # Freed a slot: eagerly pull whatever queued while we ran.
             self._kick_flush()
+
+    async def _emit_groups(self, emit) -> None:
+        """Batch egress, both paths: records that arrived together as a
+        RecordFrame and rode one device batch leave together — their
+        predictions concatenate into ONE payload per (frame, device
+        batch), killing the per-record json_encode fan-out (r19 zero-copy
+        plan). Other items keep the one-payload-per-record contract. A
+        payload that cannot be encoded or emitted fails its own records."""
+        for handle, group in self._egress_groups(emit):
+            try:
+                anchor = (self._anchor_of(group[0][0]) if handle is None
+                          else handle.tuple)
+                preds = (group[0][1] if len(group) == 1 else
+                         np.concatenate([p for _, p in group], axis=0))
+                with span(self.context.metrics, self.context.component_id,
+                          "encode"):
+                    msg = self._encode_ledgered(preds, records=len(group))
+                await self.collector.emit(
+                    Values([msg, *self._extras(anchor)]), anchors=[anchor])
+            except Exception as e:
+                self.collector.report_error(e)
+                for item, _ in group:
+                    self._complete(item, False)
+            else:
+                for item, _ in group:
+                    self._complete(item, True)
 
     async def _escalate(self, items, tier: int, link_span) -> None:
         """Re-batch the low-confidence residue into the next tier's
@@ -1041,14 +1074,21 @@ class InferenceBolt(Bolt):
 
         old_engine = self.engine
         new_engine = await asyncio.to_thread(build)
+        continuous = getattr(self, "_continuous", False)
         if getattr(self, "_router", None) is not None:
             # The cascade tier serving the flagship follows the swap (the
             # tiers sharing the old engine object by identity — normally
-            # just the last one).
+            # just the last one), and so does its queue.
             for rt in self._router.tiers:
                 if rt.engine is old_engine:
                     rt.engine = new_engine
                     rt.model_cfg = model_cfg
+                    if continuous:
+                        self._bind_queue(rt.index, new_engine)
+        elif continuous:
+            # Rows already queued finish on the old engine's queue; later
+            # records go to the new engine's.
+            self._bind_queue(None, new_engine)
         self.engine = new_engine
         self.model_cfg = model_cfg
 
@@ -1065,14 +1105,18 @@ class InferenceBolt(Bolt):
         because finishing a cascade tier's batches can re-fill a LATER
         tier's batcher with escalated residue."""
         if getattr(self, "_continuous", False):
-            # Force the shared queues to dispatch and wait for this
-            # task's per-record completions. Re-flush on a short period:
-            # a record escalating mid-drain enqueues into a LATER tier's
-            # queue after its flush already drained.
-            while self._inflight:
+            # Force the shared queues to dispatch and wait until none of
+            # this task's rows is outstanding and every group has been
+            # emitted. Re-flush on a short period: a record escalating
+            # mid-drain enqueues into a LATER tier's queue after its
+            # flush already drained.
+            while self._cb_rows or self._inflight:
                 for cb in set(self._cbs.values()):
                     cb.flush()
-                await asyncio.wait(list(self._inflight), timeout=0.05)
+                if self._inflight:
+                    await asyncio.wait(list(self._inflight), timeout=0.05)
+                else:
+                    await asyncio.sleep(0.005)
             return
         while True:
             for tier, b in self._sources:

@@ -194,9 +194,13 @@ def test_deterministic_accept_escalate_split(run, monkeypatch):
     async def go():
         cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
                             thresholds=(0.5,))
+        # continuous=False: the per-task path, whose batch-level decide()
+        # re-batches one batch's residue as ONE flagship batch — the call
+        # sizes asserted below (the default path's residues join the next
+        # tier's shared queue one record at a time, test_continuous.py)
         bolt, coll, engines = _cascade_bolt(
             monkeypatch, cas, max_batch=4, max_wait_ms=10_000,
-            max_inflight=1)
+            max_inflight=1, continuous=False)
         # Two confident records (u = 1-0.9 = 0.1 < 0.5: accept at tier 0)
         # and two unconfident (u = 0.8: escalate to the flagship).
         for c in (0.9, 0.2, 0.9, 0.2):
@@ -242,9 +246,11 @@ def test_threshold_zero_is_flagship_only(run, monkeypatch):
     async def go():
         cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
                             thresholds=(0.0,))
+        # continuous=False: one flagship call of 4 is the per-task path's
+        # batch-level escalation (see the split test above)
         bolt, coll, engines = _cascade_bolt(
             monkeypatch, cas, max_batch=4, max_wait_ms=10_000,
-            max_inflight=1)
+            max_inflight=1, continuous=False)
         for c in (0.999, 0.999, 0.999, 0.999):  # max confidence, still out
             await bolt.execute(_tuple(_conf_payload(c)))
         await bolt.flush()

@@ -421,7 +421,7 @@ def test_legacy_path_observes_batch_fill(run):
     async def go():
         eng = _SlotEngine(pad_to=8)
         bolt, coll = _bolt(eng, max_batch=8, buckets=(8,),
-                           max_wait_ms=10_000)
+                           max_wait_ms=10_000, continuous=False)
         assert not getattr(bolt, "_continuous", True)
         for _ in range(3):
             await bolt.execute(_tuple(_payload()))

@@ -330,8 +330,11 @@ def test_operator_completes_tuples_from_fetch_futures(run):
 def test_operator_flush_drains_ring_and_pending(run):
     async def go():
         eng = _ManualEngine()
+        # continuous=False: the partial batch parked in the task's OWN
+        # MicroBatcher is what this drain is about (the default path keeps
+        # nothing per task; its drain is test_continuous.py's)
         bolt, coll = _prepared_bolt(eng, max_batch=2, max_wait_ms=10_000,
-                                    max_inflight=4)
+                                    max_inflight=4, continuous=False)
         for _ in range(5):  # two full batches in flight + one pending
             await bolt.execute(_tuple(_payload()))
         await asyncio.sleep(0.05)
@@ -356,11 +359,14 @@ def test_operator_flush_drains_ring_and_pending(run):
     run(go(), timeout=60)
 
 
-def test_operator_staging_no_extra_host_copies(run, monkeypatch):
+@pytest.mark.parametrize("continuous", [False, True],
+                         ids=["per_task", "engine_queue"])
+def test_operator_staging_no_extra_host_copies(run, monkeypatch, continuous):
     """Alloc-count guard: on the split-phase path the operator hands
     per-record arrays straight to the engine's pooled staging write — no
     ``Batch.stack`` concatenate, and zero new staging allocations per
-    batch at steady state."""
+    batch at steady state. Both paths: the per-task batcher and the
+    engine's queue (the default) stage through the same pool."""
 
     async def go():
         eng = InferenceEngine(
@@ -374,7 +380,8 @@ def test_operator_staging_no_extra_host_copies(run, monkeypatch):
             Batch, "stack",
             lambda self: pytest.fail("pipelined path must not stack()"))
         bolt, coll = _prepared_bolt(eng, max_batch=8, buckets=(8,),
-                                    max_wait_ms=10_000, pipeline_depth=2)
+                                    max_wait_ms=10_000, pipeline_depth=2,
+                                    continuous=continuous)
         # Warm the pool to steady state: with depth 2 up to two batches
         # overlap, so the pool legitimately grows to two buffers — but
         # never beyond, however many batches follow.
@@ -387,7 +394,9 @@ def test_operator_staging_no_extra_host_copies(run, monkeypatch):
             await bolt.execute(_tuple(_payload()))
         await bolt.flush()
         assert len(coll.acked) == 64 and not coll.failed
-        assert eng._staging.allocated == before, \
+        # the queue's dispatcher thread may first overlap two batches only
+        # in the second phase; the ring still bounds the pool at two
+        assert eng._staging.allocated <= (2 if continuous else before), \
             "full-batch host buffers must come from the pool, not fresh"
 
     run(go(), timeout=120)

@@ -287,8 +287,8 @@ def test_fetch_loop_watchdog_trips_and_releases_ring():
     handle.watchdog_ms = 40.0
     handle.on_done = outcomes.append
 
-    t = threading.Thread(target=_fetch_loop, args=(fetch_q, ring, staging),
-                         daemon=True)
+    t = threading.Thread(target=_fetch_loop,
+                         args=(fetch_q, ring, staging, {}), daemon=True)
     t.start()
     try:
         fetch_q.put(handle)
@@ -324,7 +324,8 @@ def test_fetch_loop_no_watchdog_blocks_normally():
     handle._out = Ready()
     handle._t_launched = time.perf_counter()
     t = threading.Thread(target=_fetch_loop,
-                         args=(fetch_q, ring, StagingPool(1)), daemon=True)
+                         args=(fetch_q, ring, StagingPool(1), {}),
+                         daemon=True)
     t.start()
     try:
         fetch_q.put(handle)
