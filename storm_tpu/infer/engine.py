@@ -35,6 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
 from storm_tpu.models.registry import ModelDef, build_model, load_or_init
 from storm_tpu.obs import copyledger as _copyledger
+from storm_tpu.ops.platform import dispatch_notes
 from storm_tpu.parallel.mesh import make_mesh
 from storm_tpu.parallel.sharding import (
     batch_sharding,
@@ -619,14 +620,22 @@ class InferenceEngine:
         sp = self.sp
         mesh_ref = self.mesh
 
+        # Which form each op's shape rule chose (ops/platform.py
+        # ``dispatch_notes``), by padded batch. Observation only: written as
+        # a bucket's program is traced (once a bucket, jit keeps the trace),
+        # read by :func:`engine_inventory`; nothing in the program reads it.
+        forms = self.program_forms = {}
+
         def fwd(params, state, x):
             if w8:
                 params = dequantize_params(params, dtype, keep_dense=w8_fused)
-            if sp > 1:
-                logits, _ = apply_sp(params, state, x, mesh_ref, "seq",
-                                     train=False)
-            else:
-                logits, _ = apply(params, state, x, train=False)
+            with dispatch_notes() as seen:
+                if sp > 1:
+                    logits, _ = apply_sp(params, state, x, mesh_ref, "seq",
+                                         train=False)
+                else:
+                    logits, _ = apply(params, state, x, train=False)
+            forms[x.shape[0]] = ", ".join(seen)
             logits = logits.astype(jnp.float32)
             return jax.nn.softmax(logits, axis=-1) if softmax else logits
 
@@ -734,6 +743,10 @@ class InferenceEngine:
                 continue
             x = np.zeros((n, *self.input_shape), self.dtype)
             np.asarray(self.predict(x))
+        if any(self.program_forms.values()):
+            logger.info("engine %s programs by bucket: %s", self.model_cfg.name,
+                        "; ".join(f"{b}: {f}" for b, f
+                                  in sorted(self.program_forms.items())))
 
     # ---- the hot call --------------------------------------------------------
 
@@ -1329,6 +1342,11 @@ def engine_inventory() -> dict:
             # What one chip actually holds (≈ param_bytes/tp when sharded)
             # — the figure the 85% HBM warning and cache budget use.
             "param_bytes_per_device": e.param_bytes_per_device(),
+            # Which kernels each compiled bucket's program was built with
+            # ("attention=rows" / "attention=xla"; empty for a model whose
+            # ops have no shape rule).
+            "programs": {str(b): f for b, f
+                         in sorted(e.program_forms.items())},
         }
         for e in engines
     ]

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import layers as L
-from storm_tpu.ops.fused_norm import residual_layernorm
 
 
 def _mlp_init(rng, dim, hidden):
@@ -48,9 +47,8 @@ def _block(p, x):
     y = jnp.swapaxes(y, 1, 2)
     y = _mlp(p["token"], y)
     y = jnp.swapaxes(y, 1, 2)
-    # token-mix residual add + channel-mix LN fused (Pallas on TPU)
-    x, n2 = residual_layernorm(p["ln2"], y, x)
-    return x + _mlp(p["channel"], n2)
+    x = x + y
+    return x + _mlp(p["channel"], L.layernorm(p["ln2"], x))
 
 
 def _build_mixer(name, num_classes, input_shape, patch, dim, depth,

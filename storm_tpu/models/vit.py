@@ -2,10 +2,10 @@
 
 Standard Vision Transformer: 16x16 patch embedding (as a strided conv, MXU
 friendly), learned position embeddings + CLS token, pre-LN encoder blocks,
-attention via :func:`storm_tpu.ops.attention.multi_head_attention` (Pallas
-flash-attention kernel on TPU). Stateless (LayerNorm only) — which also
-makes it the flagship for the sharded train step (no BN cross-replica
-stats needed).
+attention via :func:`storm_tpu.ops.attention.multi_head_attention` (XLA's
+attention or a Pallas kernel, by the traced shapes). Stateless (LayerNorm
+only) — which also makes it the flagship for the sharded train step (no BN
+cross-replica stats needed).
 """
 
 from __future__ import annotations
@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import layers as L
 from storm_tpu.ops.attention import mha_init, multi_head_attention
-from storm_tpu.ops.fused_norm import residual_layernorm
 
 
 def _block_init(rng, dim, mlp_dim, num_heads):
@@ -31,11 +30,13 @@ def _block_init(rng, dim, mlp_dim, num_heads):
 
 
 def _block(p, x, num_heads):
-    attn = multi_head_attention(p["attn"], L.layernorm(p["ln1"], x), num_heads)
-    # Residual add + LN2 fused in one Pallas kernel on TPU (one HBM round
-    # trip for the (tokens, dim) activation instead of two).
-    y, n2 = residual_layernorm(p["ln2"], attn, x)
-    h = L.gelu(L.dense(p["mlp_in"], n2))
+    y = x + multi_head_attention(p["attn"], L.layernorm(p["ln1"], x), num_heads)
+    # Keep the stream (batch, tokens, dim) through both norms: XLA makes each
+    # residual add and the next norm's row sum the epilogue of the projection
+    # before it and folds the normalise into the projection after it; flattened
+    # to (rows, dim), a program of many rows pays a float32 copy of the stream
+    # a block (PERF.md §6, PR 29).
+    h = L.gelu(L.dense(p["mlp_in"], L.layernorm(p["ln2"], y)))
     return y + L.dense(p["mlp_out"], h)
 
 

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 
 
+from storm_tpu.ops.platform import note as _note
+from storm_tpu.ops.platform import one_device as _one_device
 from storm_tpu.ops.platform import use_pallas as _use_pallas
 
 
@@ -57,6 +59,37 @@ def scaled_dot_attention(
     return attention_reference(q, k, v, scale=scale)
 
 
+# Scores (batch x heads x tokens x tokens) from which a program's attention is
+# the row kernel of ops/short_attention.py. Measured on the v5e (PERF.md §5-6,
+# PR 29; ``attention_bench.py`` gives the table): under it XLA keeps the
+# scores of a block in fast memory and its own attention is 6-8 % ahead
+# (ViT-B/16 at batch 8 and 16: 3.7 and 7.5 million scores); over it XLA writes
+# them to HBM twice and reads them twice, transposes q, k, v and the result,
+# and the kernel is ahead by 14-31 % of the whole program (ViT-g/14 from batch
+# 8, 8.5 million; ViT-B/16 from batch 32).
+_ROW_KERNEL_MIN_SCORES = 8_000_000
+
+
+def attention_form(b: int, s: int, c: int, num_heads: int,
+                   itemsize: int) -> str:
+    """Which attention a program over ``[b, s, c]`` activations is built
+    with: ``"flash"`` (long sequences), ``"rows"`` (many rows of a short
+    sequence), or ``"xla"``. A function of the traced shapes and of what the
+    process runs on: a TPU or not, and one device or several (the row kernel
+    has no partitioning rule, ops/platform.py ``one_device``). Off TPU
+    always ``"xla"``."""
+    from storm_tpu.ops.short_attention import fits
+
+    if not _use_pallas():
+        return "xla"
+    if s >= _flash_min_seq():
+        return "flash"
+    if (b * num_heads * s * s >= _ROW_KERNEL_MIN_SCORES
+            and fits(s, c, itemsize) and _one_device()):
+        return "rows"
+    return "xla"
+
+
 def mha_init(rng, dim: int, num_heads: int, dtype=jnp.float32) -> dict:
     from storm_tpu.ops.layers import dense_init
 
@@ -69,17 +102,31 @@ def mha_init(rng, dim: int, num_heads: int, dtype=jnp.float32) -> dict:
     }
 
 
+def split_heads(y: jnp.ndarray, num_heads: int) -> jnp.ndarray:
+    """(B, S, H*D) -> (B, H, S, D)."""
+    b, s, c = y.shape
+    return y.reshape(b, s, num_heads, c // num_heads).transpose(0, 2, 1, 3)
+
+
+def merge_heads(y: jnp.ndarray) -> jnp.ndarray:
+    """(B, H, S, D) -> (B, S, H*D)."""
+    b, h, s, d = y.shape
+    return y.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
 def multi_head_attention(p: dict, x: jnp.ndarray, num_heads: int) -> jnp.ndarray:
     """Self-attention over (B, S, C) activations."""
     from storm_tpu.ops.layers import dense
 
     b, s, c = x.shape
-    d = c // num_heads
+    q, k, v = dense(p["q"], x), dense(p["k"], x), dense(p["v"], x)
+    form = attention_form(b, s, c, num_heads, x.dtype.itemsize)
+    _note("attention", form)
+    if form == "rows":
+        from storm_tpu.ops.short_attention import short_attention
 
-    def split(y):
-        return y.reshape(b, s, num_heads, d).transpose(0, 2, 1, 3)
-
-    q, k, v = split(dense(p["q"], x)), split(dense(p["k"], x)), split(dense(p["v"], x))
-    out = scaled_dot_attention(q, k, v)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, c)
+        out = short_attention(q, k, v, num_heads)
+    else:
+        out = merge_heads(scaled_dot_attention(
+            *(split_heads(y, num_heads) for y in (q, k, v))))
     return dense(p["o"], out)

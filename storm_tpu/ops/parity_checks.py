@@ -97,29 +97,37 @@ def check_flash_attention(interpret: bool = False) -> List[dict]:
     return rows
 
 
-def check_fused_norm(interpret: bool = False) -> List[dict]:
-    """Compiled fused residual-add+LayerNorm vs the unfused jnp reference.
+def check_short_attention(interpret: bool = False) -> List[dict]:
+    """Compiled row-kernel attention vs the jnp reference on the same
+    ``[B, S, H*D]`` operands.
 
-    Covers lane padding (d=100), multi-row-block grids, and the ViT dim.
-    Both outputs (residual stream y and the normed tensor) are checked."""
+    Cases: ViT-g/14's tokens and head width (257 x 16 heads of 88: neither a
+    sublane nor a lane multiple), ViT-B/16's (197 x 12 heads of 64) in the
+    serving dtype, and a toy width in float32."""
+    import jax
     import jax.numpy as jnp
-    import numpy as np_mod
 
-    from storm_tpu.ops.fused_norm import _fused_fwd_pallas, _reference
+    from storm_tpu.ops.short_attention import _forward, reference
 
-    rng = np_mod.random.RandomState(0)
     rows = []
-    for rows_n, d in [(6, 64), (300, 100), (1024, 768)]:
-        x = jnp.asarray(rng.randn(rows_n, d), jnp.float32)
-        r = jnp.asarray(rng.randn(rows_n, d), jnp.float32)
-        g = jnp.asarray(rng.randn(d), jnp.float32)
-        b = jnp.asarray(rng.randn(d), jnp.float32)
-        wy, wo = _reference(x, r, g, b, 1e-6)
-        gy, go = _fused_fwd_pallas(x, r, g, b, eps=1e-6, interpret=interpret)
-        rows.append(_row("fused_norm.y", f"{rows_n}x{d}", "float32",
-                         np.asarray(gy), np.asarray(wy), abs_tol=1e-5))
-        rows.append(_row("fused_norm.ln", f"{rows_n}x{d}", "float32",
-                         np.asarray(go), np.asarray(wo), abs_tol=1e-4))
+    cases = [
+        ("g14_S257_H16_D88", (2, 257, 1408), 16, jnp.bfloat16),
+        ("b16_S197_H12_D64", (2, 197, 768), 12, jnp.bfloat16),
+        ("toy_S33_H4_D24", (3, 33, 96), 4, jnp.float32),
+    ]
+    for case, shape, heads, dt in cases:
+        q, k, v = (
+            jax.random.normal(jax.random.PRNGKey(i), shape, jnp.float32)
+            .astype(dt) for i in range(3))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(reference(
+                q.astype(jnp.float32), k.astype(jnp.float32),
+                v.astype(jnp.float32), heads), np.float32)
+        got = np.asarray(_forward(q, k, v, heads=heads, interpret=interpret),
+                         np.float32)
+        rel_tol = 1e-2 if dt == jnp.bfloat16 else 5e-3
+        rows.append(_row("short_attention", case, np.dtype(dt).name, got,
+                         want, rel_tol=rel_tol))
     return rows
 
 
@@ -178,5 +186,5 @@ def check_w8a16(interpret: bool = False) -> List[dict]:
 
 def run_all(interpret: bool = False) -> List[dict]:
     return (check_flash_attention(interpret)
-            + check_fused_norm(interpret)
+            + check_short_attention(interpret)
             + check_w8a16(interpret))

@@ -459,6 +459,31 @@ def test_engine_inventory_tracks_coresident_models():
         assert r["param_bytes"] > 0
 
 
+def test_engine_inventory_names_each_programs_kernels():
+    """Per compiled bucket, the inventory says which form the ops' shape
+    rules built the program with (on the CPU always the jnp path)."""
+    from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
+    from storm_tpu.infer.engine import engine_inventory, shared_engine
+
+    eng = shared_engine(
+        ModelConfig(name="vit_tiny", input_shape=(32, 32, 3), dtype="float32"),
+        ShardingConfig(data_parallel=0),
+        BatchConfig(max_batch=8, buckets=(2, 8)))
+    eng.warmup()
+    row = next(r for r in engine_inventory()["engines"]
+               if r["model"] == "vit_tiny")
+    dp = eng.mesh.shape[eng.data_axis]
+    assert row["programs"] == {str(eng.pad_batch(b)): "attention=xla"
+                               for b in (2, 8)}, (row, dp)
+    lenet = shared_engine(
+        ModelConfig(name="lenet5", input_shape=(28, 28, 1), dtype="float32"),
+        ShardingConfig(data_parallel=0), BatchConfig(max_batch=4, buckets=(4,)))
+    lenet.warmup()
+    row = next(r for r in engine_inventory()["engines"]
+               if r["model"] == "lenet5")
+    assert set(row["programs"].values()) == {""}  # no op with a shape rule
+
+
 def test_eager_dispatch_low_latency_and_batching_under_load(run):
     """eager=True: an idle device gets records immediately (no max_wait
     aging); when all slots are busy, arrivals accumulate into one batch."""

@@ -73,6 +73,79 @@ def test_vit_tiny_shapes():
     assert bool(jnp.all(jnp.isfinite(logits)))
 
 
+@pytest.mark.parametrize("sizes", [
+    pytest.param(dict(input_shape=(32, 32, 3), patch=8, dim=64, depth=2,
+                      num_heads=4, mlp_dim=128), id="vit_tiny"),
+    # the benchmark's widths and tokens (ViT-g/14), two blocks, float32, CPU
+    pytest.param(dict(input_shape=(224, 224, 3), patch=14, dim=1408, depth=2,
+                      num_heads=16, mlp_dim=6144), id="g14-two-blocks"),
+])
+def test_vit_forward_matches_the_plain_composition(sizes):
+    """``build_vit``'s apply against the encoder written out in plain jnp
+    (the benchmark's reference: add, then LayerNorm, nothing flattened or
+    fused), which is what ``_block`` must keep computing."""
+    from benchmarks.references import vit as plain
+    from storm_tpu.models.vit import build_vit
+
+    model = build_vit("probe", 10, **sizes)
+    params, state = init_params(model, seed=0)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, *model.input_shape))
+    published = {"patch_size": sizes["patch"], "hidden_size": sizes["dim"],
+                 "num_attention_heads": sizes["num_heads"],
+                 "num_hidden_layers": sizes["depth"]}
+    with jax.default_matmul_precision("highest"):
+        got = jax.nn.softmax(model.apply(params, state, x, train=False)[0], -1)
+        want = plain.forward(published, params, state, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_vit_block_keeps_its_signature_and_composes_to_apply():
+    """``_block(p, x, num_heads) -> x`` is what parallel/pipeline.py, longseq
+    and the vmapped expert tests call: same name, same three arguments, and
+    looping it is the encoder."""
+    import inspect
+
+    from storm_tpu.models.vit import _block
+    from storm_tpu.ops import layers as L
+
+    assert list(inspect.signature(_block).parameters) == ["p", "x", "num_heads"]
+    model = build_model("vit_tiny")
+    params, state = init_params(model, seed=0)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, *model.input_shape))
+    want, _ = model.apply(params, state, x, train=False)
+    tok = L.conv2d(params["embed"], x, stride=8, padding="VALID").reshape(2, 16, 64)
+    tok = jnp.concatenate(
+        [jnp.broadcast_to(params["cls"], (2, 1, 64)), tok], axis=1) + params["pos"]
+    for p in params["blocks"]:
+        tok = _block(p, tok, 4)
+        assert tok.shape == (2, 17, 64)
+    got = L.dense(params["head"], L.layernorm(params["ln"], tok)[:, 0])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+
+def test_vit_g14_program_names_the_shapes_the_roofline_reader_needs():
+    """``benchmarks/ops/vit.py rows_per_step`` reads a program's batch off
+    operation shapes of the form ``[B,257,1408]``. Lowered shape-only (no
+    parameter is allocated) at the benchmark's sizes and batch 8, the
+    program must still carry the stream three-dimensional."""
+    import re
+
+    from storm_tpu.models.vit import build_vit
+
+    model = build_vit("probe", 1000, (224, 224, 3), patch=14, dim=1408,
+                      depth=40, num_heads=16, mlp_dim=6144)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params, state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), shapes)
+    x = jax.ShapeDtypeStruct((8, 224, 224, 3), jnp.bfloat16)
+    text = jax.jit(
+        lambda p, s, x: model.apply(p, s, x, train=False)[0]
+    ).lower(params, state, x).as_text(dialect="hlo")
+    found = re.findall(r"\[(\d+),257,1408\]", text)
+    assert found and max(set(found), key=found.count) == "8"
+    assert len(found) >= 40 * 4  # every block's stream, not one stray shape
+
+
 def test_vit_patch_divisibility():
     with pytest.raises(ValueError):
         build_model("vit_tiny", input_shape=(30, 30, 3))

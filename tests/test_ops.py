@@ -122,51 +122,139 @@ def test_dense_dispatches_on_quantized_weights():
     assert np.max(np.abs(np.asarray(got - want))) < 0.05
 
 
-def test_fused_residual_layernorm_kernel_matches_reference():
-    """Pallas fused add+LN (interpreter) vs the jnp reference, including
-    padded dims and row blocks."""
-    from storm_tpu.ops.fused_norm import _fused_fwd_pallas, _reference
+# ---- attention for many rows of a short sequence (ops/short_attention.py) ---
 
-    rng = np.random.RandomState(0)
-    for rows, d in [(6, 64), (300, 100), (5, 768)]:
-        x = jnp.asarray(rng.randn(rows, d), jnp.float32)
-        r = jnp.asarray(rng.randn(rows, d), jnp.float32)
-        g = jnp.asarray(rng.randn(d), jnp.float32)
-        b = jnp.asarray(rng.randn(d), jnp.float32)
-        wy, wo = _reference(x, r, g, b, 1e-6)
-        gy, go = _fused_fwd_pallas(x, r, g, b, eps=1e-6, interpret=True)
-        np.testing.assert_allclose(np.asarray(gy), np.asarray(wy), atol=1e-5)
-        np.testing.assert_allclose(np.asarray(go), np.asarray(wo), atol=1e-4)
+_SHORT_CASES = [
+    # the benchmark cells' tokens and width: 257 is no sublane multiple,
+    # the 88-wide heads are lane slices at offsets that are no lane multiple
+    pytest.param((2, 257, 1408), 16, jnp.bfloat16, 2e-2, id="g14-257x1408-bf16"),
+    pytest.param((1, 197, 768), 12, jnp.bfloat16, 2e-2, id="b16-197x768-bf16"),
+    pytest.param((3, 33, 96), 4, jnp.float32, 1e-5, id="toy-33x96-f32"),
+    pytest.param((2, 8, 40), 5, jnp.float32, 1e-5, id="toy-8x40-f32"),
+]
 
 
-@pytest.mark.slow
-def test_fused_residual_layernorm_grads():
-    """custom_vjp backward must match autodiff through the unfused ops —
-    the training path (pjit/pipeline dryruns) differentiates blocks that
-    use this kernel."""
-    from storm_tpu.ops import layers as L
-    from storm_tpu.ops.fused_norm import residual_layernorm
+def _qkv(shape, dtype):
+    return tuple(
+        jax.random.normal(jax.random.PRNGKey(i), shape, jnp.float32).astype(dtype)
+        for i in range(3))
 
-    rng = np.random.RandomState(1)
-    p = {"scale": jnp.asarray(rng.randn(32), jnp.float32),
-         "bias": jnp.asarray(rng.randn(32), jnp.float32)}
-    x = jnp.asarray(rng.randn(4, 7, 32), jnp.float32)
-    br = jnp.asarray(rng.randn(4, 7, 32), jnp.float32)
 
-    def fused_loss(p, br, x):
-        y, out = residual_layernorm(p, br, x)
-        return jnp.sum(out ** 2) + jnp.sum(y ** 3)
+@pytest.mark.parametrize("shape,heads,dtype,atol", _SHORT_CASES)
+def test_short_attention_kernel_matches_reference(shape, heads, dtype, atol):
+    """The Pallas row kernel (interpreter) vs the jnp attention on the same
+    [B, S, H*D] operands, float32 reference."""
+    from storm_tpu.ops.short_attention import _forward, reference
 
-    def ref_loss(p, br, x):
-        y = x + br
-        return jnp.sum(L.layernorm(p, y) ** 2) + jnp.sum(y ** 3)
+    q, k, v = _qkv(shape, dtype)
+    got = _forward(q, k, v, heads=heads, interpret=True)
+    assert got.shape == shape and got.dtype == dtype
+    want = reference(*(a.astype(jnp.float32) for a in (q, k, v)), heads)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=atol)
 
-    lf, gf = jax.value_and_grad(fused_loss, argnums=(0, 1, 2))(p, br, x)
-    lr, gr = jax.value_and_grad(ref_loss, argnums=(0, 1, 2))(p, br, x)
-    np.testing.assert_allclose(float(lf), float(lr), rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gr)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-4)
+
+@pytest.mark.parametrize("shape,heads", [((2, 33, 96), 4), ((1, 257, 176), 2)])
+def test_short_attention_gradient_is_the_references(shape, heads, monkeypatch):
+    """custom_vjp: a backward needs the scores again and ``pallas_call`` has
+    none, so under ``jax.grad`` both passes are jax's own of the jnp path: the
+    differentiated program holds no kernel (training pays for no forward
+    twice) and its values and gradients are the reference's."""
+    import functools
+
+    from storm_tpu.ops import short_attention as sa
+
+    monkeypatch.setattr(sa, "_forward",
+                        functools.partial(sa._forward, interpret=True))
+    q, k, v = _qkv(shape, jnp.float32)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v, heads) ** 2)
+
+    through_kernel = jax.value_and_grad(
+        functools.partial(loss, sa.short_attention), argnums=(0, 1, 2))
+    assert "pallas_call" in str(jax.make_jaxpr(
+        functools.partial(loss, sa.short_attention))(q, k, v))
+    assert "pallas_call" not in str(jax.make_jaxpr(through_kernel)(q, k, v))
+    lk, gk = through_kernel(q, k, v)
+    lr, gr = jax.value_and_grad(functools.partial(loss, sa.reference),
+                                argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(lk), float(lr), rtol=1e-5)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,heads,itemsize,on_tpu,devices,want", [
+    ((8, 257, 1408), 16, 2, True, 1, "rows"),    # the paced cell's program
+    ((32, 257, 1408), 16, 2, True, 1, "rows"),
+    ((128, 257, 1408), 16, 2, True, 1, "rows"),
+    ((256, 257, 1408), 16, 2, True, 1, "rows"),  # the backlog cell's program
+    ((7, 257, 1408), 16, 2, True, 1, "xla"),     # 7.4 million scores: under
+    ((16, 197, 768), 12, 2, True, 1, "xla"),     # ViT-B/16: XLA ahead, measured
+    ((32, 197, 768), 12, 2, True, 1, "rows"),    # ViT-B/16: kernel ahead, measured
+    ((8, 2048, 256), 2, 2, True, 1, "flash"),    # long sequences stay flash's
+    ((64, 900, 2048), 16, 4, True, 1, "xla"),    # many scores, no room in VMEM
+    ((256, 257, 1408), 16, 2, False, 1, "xla"),  # off TPU: always the jnp path
+    ((64, 65, 64), 4, 4, True, 1, "xla"),        # toy widths (vit_tiny)
+    # a host with several chips: a program may be split over them, and the
+    # kernel has no partitioning rule (engines, train steps, dry runs alike)
+    ((256, 257, 1408), 16, 2, True, 4, "xla"),
+    ((8, 2048, 256), 2, 2, True, 4, "flash"),    # as before this rule
+])
+def test_attention_form_is_a_function_of_the_traced_shapes_and_the_devices(
+        shape, heads, itemsize, on_tpu, devices, want, monkeypatch):
+    from storm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: on_tpu)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    assert attention.attention_form(*shape, heads, itemsize) == want
+
+
+def test_dispatch_notes_observe_and_decide_nothing(monkeypatch):
+    """The notes are what a trace saw, nested traces restore what they found,
+    and no rule reads them: the same shapes give the same form inside and
+    outside a ``dispatch_notes`` block."""
+    from storm_tpu.ops import attention
+    from storm_tpu.ops.platform import dispatch_notes, note, one_device
+
+    assert not one_device()  # conftest gives the CPU backend eight devices
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert one_device()
+    outside = attention.attention_form(256, 257, 1408, 16, 2)
+    with dispatch_notes() as outer:
+        note("attention", "rows")
+        with dispatch_notes() as inner:
+            assert attention.attention_form(256, 257, 1408, 16, 2) == outside
+            note("attention", "xla")
+            note("attention", "xla")
+        note("norm", "xla")
+    note("attention", "flash")  # no block open: dropped
+    assert inner == ["attention=xla"]
+    assert outer == ["attention=rows", "norm=xla"]
+
+
+def test_mha_through_the_row_kernel_matches_the_xla_path(monkeypatch):
+    """multi_head_attention built with the row kernel (forced here, in the
+    test, as a chip would choose it at many rows) against the jnp path, and
+    the choice lands in the dispatch notes the engine reads."""
+    import functools
+
+    from storm_tpu.ops import attention, short_attention as sa
+    from storm_tpu.ops.platform import dispatch_notes
+
+    p = mha_init(jax.random.PRNGKey(0), 96, 4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 33, 96))
+    with dispatch_notes() as seen:
+        want = multi_head_attention(p, x, 4)
+    assert seen == ["attention=xla"]
+    monkeypatch.setattr(sa, "_forward",
+                        functools.partial(sa._forward, interpret=True))
+    monkeypatch.setattr(attention, "attention_form", lambda *a: "rows")
+    with dispatch_notes() as seen:
+        got = multi_head_attention(p, x, 4)
+    assert seen == ["attention=rows"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
 # ---- Pallas-vs-reference dispatch predicate (ops/platform.py) ---------------

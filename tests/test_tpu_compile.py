@@ -20,15 +20,20 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One device of a described v5e 2x2 host, as a sharding."""
+def topo():
+    """A described v5e 2x2 host: four devices, none attached."""
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or it cannot describe a v5e
         pytest.skip(f"TPU topology cannot be described: {e!r}")
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
+    """One device of that host, as a sharding."""
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -64,14 +69,103 @@ def test_flash_attention_compiles_for_v5e(v5e, shape):
     assert "tpu_custom_call" in text
 
 
-def test_fused_norm_compiles_for_v5e(v5e):
-    """vit_b16's residual + LayerNorm at batch 64: 64 * 197 tokens x 768."""
-    from storm_tpu.ops.fused_norm import _fused_fwd_pallas
+@pytest.mark.parametrize("shape,heads", [
+    ((256, 257, 1408), 16),   # ViT-g/14, the benchmark's largest bucket
+    ((128, 197, 768), 12),    # ViT-B/16 at batch 128
+    ((4, 512, 1024), 8),      # about the longest sequence that still fits
+])
+def test_short_attention_compiles_for_v5e(v5e, shape, heads):
+    """Heads are lane slices at offsets that are no multiple of 128, and the
+    token count is no multiple of 8: Mosaic has to take both."""
+    from storm_tpu.ops.short_attention import _forward, fits
 
-    x = _spec((12608, 768), jnp.bfloat16, v5e)
-    g = _spec((768,), jnp.float32, v5e)
-    text = _fused_fwd_pallas.lower(x, x, g, g, eps=1e-6).compile().as_text()
+    assert fits(shape[1], shape[2], 2)
+    q = _spec(shape, jnp.bfloat16, v5e)
+    text = _forward.lower(q, q, q, heads=heads).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_vit_g14_block_compiles_with_the_row_kernel(v5e, monkeypatch):
+    """Two blocks of ViT-g/14 at the backlog cell's bucket of 256, as the chip
+    builds them: the kernel is in the program, the transposes XLA made around
+    its own attention are not, and the shapes the benchmark's roofline reader
+    looks for (``[256,257,1408]``) are still named."""
+    import re
+
+    import storm_tpu.ops.attention as attention
+    from storm_tpu.models.vit import build_vit
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "_one_device", lambda: True)
+    model = build_vit("probe", 1000, (224, 224, 3), patch=14, dim=1408,
+                      depth=2, num_heads=16, mlp_dim=6144)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params, state = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e), shapes)
+    x = _spec((256, 224, 224, 3), jnp.bfloat16, v5e)
+    text = jax.jit(
+        lambda p, s, x: model.apply(p, s, x, train=False)[0]
+    ).lower(params, state, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "[256,16,257,257]" not in text  # no score tensor in HBM
+    found = re.findall(r"\[(\d+),257,1408\]", text)
+    assert found and max(set(found), key=found.count) == "256"
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_train_step_holds_no_row_kernel(topo, chips, monkeypatch):
+    """``parallel/train.py``'s step for one ViT-B/16 block at batch 32 (8.7
+    million scores, over the row kernel's threshold), as a TPU host would
+    build it (float32, as the repo trains). On four chips (dp 2 x tp 2,
+    GSPMD) the process has several devices, so no program of it takes the
+    kernel: jax refuses to lower a Mosaic call in a partitioned program, as
+    the last lines show. On one chip the forward alone takes it, and the
+    differentiated step does not: both passes are jax's own of the jnp path
+    (ops/short_attention.py)."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import storm_tpu.ops.attention as attention
+    from storm_tpu.models.vit import build_vit
+    from storm_tpu.parallel.sharding import tp_param_specs
+    from storm_tpu.parallel.train import make_train_step
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    # What jax.device_count() would say in a process on such a host (here it
+    # counts the CPU backend's devices).
+    monkeypatch.setattr(jax, "device_count", lambda: chips)
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(-1, min(chips, 2)),
+                ("data", "model"))
+    model = build_vit("probe", 1000, (224, 224, 3), patch=16, dim=768,
+                      depth=1, num_heads=12, mlp_dim=3072)
+    train_step, opt = make_train_step(model, optax.sgd(1e-3))
+    shapes, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda a, spec: _spec(a.shape, a.dtype, NamedSharding(mesh, spec)),
+        shapes, tp_param_specs(shapes))
+    opt_state = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, NamedSharding(mesh, P())),
+        jax.eval_shape(opt.init, params))
+    rows = NamedSharding(mesh, P("data"))
+    x = _spec((32, 224, 224, 3), jnp.float32, rows)
+    y = _spec((32,), jnp.int32, rows)
+
+    def forward(p, s, x):
+        return model.apply(p, s, x, train=False)[0]
+
+    text = jax.jit(forward).lower(params, state, x).compile().as_text()
+    assert ("tpu_custom_call" in text) == (chips == 1)
+    # The kernel would show in the lowered text already; compiling the step
+    # takes half a minute and adds nothing to that.
+    step = train_step.lower(params, opt_state, state, x, y).as_text()
+    assert "tpu_custom_call" not in step
+    if chips > 1:
+        assert "all-reduce" in text  # the program is split, not replicated
+        monkeypatch.setattr(attention, "_one_device", lambda: True)
+        with pytest.raises(NotImplementedError, match="partitioned"):
+            # a new function: jit keeps the trace of ``forward``
+            jax.jit(lambda *a: forward(*a)).lower(params, state, x)
 
 
 def test_w8a16_matmul_compiles_for_v5e(v5e):

@@ -29,12 +29,12 @@ def test_flash_attention_compiled_parity():
 
 
 @pytest.mark.slow
-def test_fused_norm_compiled_parity():
-    from storm_tpu.ops.parity_checks import check_fused_norm
+def test_short_attention_compiled_parity():
+    from storm_tpu.ops.parity_checks import check_short_attention
 
-    rows = check_fused_norm(interpret=False)
+    rows = check_short_attention(interpret=False)
     bad = [r for r in rows if not r["pass"]]
-    assert not bad, f"compiled fused_norm parity failures: {bad}"
+    assert not bad, f"compiled short_attention parity failures: {bad}"
 
 
 @pytest.mark.slow
