@@ -123,12 +123,14 @@ class Run:
         self.seed, self.seconds = seed, seconds
         self.setup_s = None
         self.delivered_in_window = 0
+        self.delivery_times = ()  # sorted broker stamps inside the window
         self.latencies_ms = None
         self.late_ms = None
         self.registry_before, self.registry_after = {}, {}
         self.compile_at_window_start = {}
         self.trace = None
         self.roofline_bound = None
+        self.notes = {}  # what a reader found beside its number; logged
         self.device = {}
         self.bytes_per_value = {"bfloat16": 2, "float16": 2,
                                 "float32": 4}[config["model"]["dtype"]]
@@ -313,8 +315,9 @@ def run_cell(args, t_start: float) -> int:
     in_window = (req_due >= t0) & (req_due < t1)
     # a traced run counts up to where the profiler started
     run.seconds = counted_to - t0
-    run.delivered_in_window = int(
-        ((out_ts >= t0) & (out_ts < counted_to)).sum())
+    run.delivery_times = np.sort(
+        out_ts[(out_ts >= t0) & (out_ts < counted_to)])
+    run.delivered_in_window = len(run.delivery_times)
     if schedule is not None:
         waited = np.where(np.isnan(delivered_at), drained_at, delivered_at)
         run.latencies_ms = (waited - req_due)[in_window] * 1e3
@@ -327,28 +330,30 @@ def run_cell(args, t_start: float) -> int:
             last_quarter_p50_ms=pairing.quantile(last, 0.5),
             first_quarter_iqr_ms=pairing.quantile(first, 0.75)
             - pairing.quantile(first, 0.25),
-            p50_ms=pairing.quantile(run.latencies_ms, 0.5),
-            p95_ms=pairing.quantile(run.latencies_ms, 0.95),
+            **{f"p{round(q * 100)}_ms": pairing.quantile(run.latencies_ms, q)
+               for q in (0.5, 0.75, 0.9, 0.95, 0.99)},
             unanswered=unanswered)
     if traced:
         path = xplane.find_trace(trace_dir)
         run.trace = xplane.reduce(xplane.load(path)) if path else {}
     worst = float(err[matched >= 0].max()) if (matched >= 0).any() else 0.0
     spout = registry_end.get("kafka-spout", {})
-    problems = []
-    if wrong:
-        problems.append(f"{wrong} outputs within tolerance of no pool row")
-    if spout.get("tree_failed", 0):
-        problems.append(f"ack ledger: {spout['tree_failed']} trees failed")
-    if errors:
-        problems.append(f"cluster.errors(): {errors[:3]}")
-    if not settled:
-        problems.append("the topology did not settle inside the drain")
-    if compiles_in_window:
-        problems.append(f"{compiles_in_window} compilations inside the "
-                        "window")
-    if run.seconds < SPAN_MIN_S:
-        problems.append("window shorter than a host clock can time")
+    # Every number compared, beside its limit, as [number, limit]; a run is
+    # correct where none is over its limit.
+    checks = {
+        "farthest_output": [float(err.max()) if len(err) else 0.0, tol],
+        "outputs_of_no_row": [wrong, 0],
+        "unanswered": [unanswered, 0],
+        "dead_lettered": [int(dead), 0],
+        "trees_failed": [int(spout.get("tree_failed", 0)), 0],
+        "cluster_errors": [len(errors), 0],
+        "unsettled": [int(not settled), 0],
+        "compiles_in_window": [int(compiles_in_window), 0],
+        # a window shorter than a host clock can time
+        "window_short_by_s": [max(0.0, SPAN_MIN_S - run.seconds), 0],
+    }
+    problems = [f"{name}: {number} over its limit {limit}"
+                for name, (number, limit) in checks.items() if number > limit]
     failed = unanswered + wrong + dead
     say(phase="counts", appended=gen.appended, outputs=len(out_ts),
         dead_lettered=dead, wrong=wrong, unanswered=unanswered,
@@ -362,9 +367,14 @@ def run_cell(args, t_start: float) -> int:
         rate_while_traced=(int(((out_ts >= counted_to) & (out_ts < t1)).sum())
                            / (t1 - counted_to)) if traced else None,
         trace_lines=(run.trace or {}).get("lines"),
+        trace_executions={
+            "whole": {n: len(ds) for n, ds in
+                      (run.trace or {}).get("modules", {}).items()},
+            "left_out": (run.trace or {}).get("cut_modules")}
+        if traced else None,
         delivered_each_second=np.histogram(
             out_ts, bins=np.arange(t0, t1 + 0.5, 1.0))[0].tolist(),
-        **meter.row(), problems=problems)
+        **meter.row(), problems=problems, errors=[str(e) for e in errors[:3]])
     say(phase="times", pool_and_reference_s=t_pool - t_start,
         submit_s=t_submit - t_pool, warmup_s=warmup_s,
         drain_s=drained_at - t1, close_s=t_closed - drained_at,
@@ -373,10 +383,11 @@ def run_cell(args, t_start: float) -> int:
     every = {g: read_metrics(run, spec.metrics_for(bench, g, cell))
              for g in ("end_to_end", "per_layer")}
     say(phase="all_metrics", roofline_bound=run.roofline_bound,
+        notes=run.notes,
         **{g: {k: v["value"] for k, v in ms.items()}
            for g, ms in every.items()})
     device = dict(run.device, memory_peak_bytes=memory_peak_bytes())
-    result = {"correct": not problems and failed == 0,
+    result = {"correct": not problems,
               "attempted": int(in_window.sum()), "failed": failed,
               "metrics": every["per_layer" if args.trace else "end_to_end"],
               "device": device}
@@ -386,5 +397,8 @@ def run_cell(args, t_start: float) -> int:
         device["window_s"] = trace.get("window_s", traced[1] - traced[0])
         result["breakdown"] = {"device_ops": trace.get("device_ops", []),
                                "idle_gaps": trace.get("idle_gaps", [])}
+    result["checks"] = checks  # last in the line, and last on stderr
     say(**result)
+    for name, (number, limit) in checks.items():
+        print(f"check {name}: {number} limit {limit}", file=sys.stderr)
     return 0

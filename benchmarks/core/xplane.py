@@ -61,12 +61,41 @@ def _line(lines: list, name: str) -> list:
     return []
 
 
+def executions(mods: list, starts: list) -> list:
+    """One device's ``XLA Modules`` events in order of start, each as
+    ``(name, start, duration, first_op, end_op, whole)``: its operations
+    are ``first_op:end_op`` of the ``XLA Ops`` events whose sorted starts are
+    ``starts``, and ``whole`` says whether the trace holds all of it.
+
+    The profiler cuts the execution that is running when the trace starts
+    and the one running when it stops, and writes each as an event of what
+    is left of it. Only the line's first and last events can be such, and
+    no time tells: the span is theirs. Their operations do: a whole execution
+    holds as many ``XLA Ops`` events as the program's executions between
+    them. So the first and the last are whole only where they hold as many
+    as another execution of their program that is neither; a program seen at
+    an edge alone has nothing to show that it is whole."""
+    spans = []
+    for name, start, d in sorted(mods, key=lambda e: e[1]):
+        spans.append((name, start, d, bisect.bisect_left(starts, start),
+                      bisect.bisect_left(starts, start + d)))
+    inner: dict = {}
+    for name, _s, _d, a, b in spans[1:-1]:
+        inner[name] = max(inner.get(name, 0), b - a)
+    return [sp + (0 < i < len(spans) - 1
+                  or 0 < inner.get(sp[0], 0) <= sp[4] - sp[3],)
+            for i, sp in enumerate(spans)]
+
+
 def reduce(planes: list) -> dict:
     """Busy time, program times, the costliest operations and the longest
     idle gaps of the device planes. The traced window spans every event of
     every plane (the harness traces the device's planes only, so that is
-    from the first operation to the end of the last). Returns ``{}`` where
-    no device plane has an event: nothing to read."""
+    from the first operation to the end of the last). Program times
+    (``modules``) are of whole executions only (``executions``;
+    ``cut_modules`` counts those left out); busy time, operations and gaps
+    are of every event. Returns ``{}`` where no device plane has an event:
+    nothing to read."""
     devices = [(n, ls) for n, ls in planes if n.startswith(DEVICE_PREFIX)]
     if not any(evs for _, ls in devices for _, evs in ls):
         return {}
@@ -77,6 +106,7 @@ def reduce(planes: list) -> dict:
     busy_ns = []
     modules: dict = {}
     module_ops: dict = {}
+    cut: dict = {}
     ops: dict = {}
     gaps = []
     for _, lines in devices:
@@ -86,14 +116,15 @@ def reduce(planes: list) -> dict:
                         if s < w1 and s + d > w0])
         busy_ns.append(sum(e - s for s, e in merged))
         all_ops = sorted(_line(lines, OP_LINE), key=lambda e: e[1])
-        starts = [e[1] for e in all_ops]
-        for name, start, d in mods:
+        for name, _s, d, a, b, whole in executions(
+                mods, [e[1] for e in all_ops]):
+            if not whole:
+                cut[name] = cut.get(name, 0) + 1
+                continue
             if name not in modules:  # the operations of its first execution
-                a = bisect.bisect_left(starts, start)
-                b = bisect.bisect_right(starts, start + d)
                 module_ops[name] = [e[0] for e in all_ops[a:b]]
             modules.setdefault(name, []).append(d / 1e9)
-        for name, _s, d in _line(lines, OP_LINE):
+        for name, _s, d in all_ops:
             ops[name] = ops.get(name, 0.0) + d / 1e9
         edges = [w0] + [x for pair in merged for x in pair] + [w1]
         ends = sorted((s + d, name) for name, s, d in mods)
@@ -115,6 +146,7 @@ def reduce(planes: list) -> dict:
         "window_s": (w1 - w0) / 1e9,
         "devices": len(devices),
         "modules": modules,
+        "cut_modules": cut,
         "module_ops": module_ops,
         "device_ops": [[name[:120], secs] for name, secs in top_ops],
         "idle_gaps": idle_gaps,
@@ -122,7 +154,7 @@ def reduce(planes: list) -> dict:
 
 
 def module_times(reduced: dict, prefix: str) -> list:
-    """Durations in seconds of every execution of the programs whose name
-    starts with ``prefix``."""
+    """Durations in seconds of every whole execution of the programs whose
+    name starts with ``prefix``."""
     return [d for name, ds in reduced.get("modules", {}).items()
             if name.startswith(prefix) for d in ds]

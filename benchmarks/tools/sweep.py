@@ -6,10 +6,14 @@
 
 Doubles the rate from ``--start`` until a run is not sustained, then bisects
 twice between the last sustained rate and the first that was not. A run is
-sustained when every record was answered and the median latency of the
-window's last quarter exceeds that of its first quarter by no more than the
-first quarter's own spread (the distance between its quartiles) or 1 ms,
-whichever is more: above capacity the queue grows all through the run. The
+sustained when every record was answered, the median latency of the window's
+last quarter exceeds that of its first quarter by no more than the first
+quarter's own spread (the distance between its quartiles) or 1 ms, whichever
+is more, and the first quarter's median is under the length of the warm-up
+before it: above capacity the queue grows all through the run, and a record
+that waits longer than the system has run met a queue that grew from the
+first record on (at three times this cell's rate the first quarter's own
+spread was 5 s, and hid a growth of 1 s; PERF.md, PR 31). The
 cell's traffic file then gets half the knee, rounded to two figures, as a
 number; the sweep is recorded in PERF.md. Each probe is a new process (this
 parent never imports JAX)."""
@@ -37,11 +41,13 @@ def probe(args, rate: float, out) -> bool:
         except ValueError:
             pass
     drift = next((r for r in rows if r.get("phase") == "drift"), None)
+    times = next((r for r in rows if r.get("phase") == "times"), None)
     last = rows[-1] if rows else {}
     ok = bool(
-        proc.returncode == 0 and drift and last.get("failed") == 0
+        proc.returncode == 0 and drift and times and last.get("failed") == 0
         and drift["last_quarter_p50_ms"] - drift["first_quarter_p50_ms"]
-        <= max(drift["first_quarter_iqr_ms"], 1.0))
+        <= max(drift["first_quarter_iqr_ms"], 1.0)
+        and drift["first_quarter_p50_ms"] < times["warmup_s"] * 1e3)
     row = {"rate": rate, "sustained": ok, "rc": proc.returncode,
            "drift": drift, "correct": last.get("correct"),
            "attempted": last.get("attempted"), "failed": last.get("failed")}

@@ -37,6 +37,65 @@ def test_command_prints_the_contracts_line_at_toy_size():
     assert set(row["device"]) >= {"platform", "kind", "count",
                                   "memory_peak_bytes"}
     assert row["device"]["platform"] == "cpu"
+    # every number compared stands beside its limit, last in the line and
+    # last on standard error
+    assert list(row)[-1] == "checks"
+    assert all(number <= limit for number, limit in row["checks"].values())
+    assert 0 < row["checks"]["farthest_output"][0]
+    said = [line for line in proc.stderr.splitlines()
+            if line.startswith("check ")]
+    assert proc.stderr.strip().splitlines()[-len(said):] == said
+    assert [line.split()[1].rstrip(":") for line in said] == \
+        list(row["checks"])
+
+
+BROKEN = """
+import os, runpy, sys
+from concurrent.futures import Future
+import numpy as np
+sys.path.insert(0, {root!r})
+from storm_tpu.infer import engine
+
+class Altered(Future):
+    # the first answer of every batch, altered where the engine hands it on
+    def set_result(self, res):
+        res = np.array(res)
+        res[0] = 0.0
+        res[0, 0] = 1.0
+        super().set_result(res)
+
+made = engine.InflightBatch.__init__
+def init(self, n, padded):
+    made(self, n, padded)
+    self.future = Altered()
+engine.InflightBatch.__init__ = init
+sys.argv = ["run.py", "--workload", "vit_tiny.tensor_backlog", "--seed",
+            "3000000021", "--seconds", "2", "--trace", "0", "--rehearse"]
+runpy.run_path(os.path.join({root!r}, "benchmarks", "run.py"),
+               run_name="__main__")
+"""
+
+
+@pytest.mark.timeout(110)
+def test_an_answer_altered_where_it_is_produced_is_not_correct():
+    """The whole of a run but the look for a chip, with the timed path
+    broken underneath: one row of every batch the engine returns is another
+    answer. The run ends and says so: ``correct`` false, the altered rows
+    counted as failed, the number over its limit named."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", BROKEN.format(root=ROOT)],
+                          cwd=ROOT, env=env, capture_output=True, text=True,
+                          timeout=100)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    row = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert row["correct"] is False and row["failed"] > 0
+    number, limit = row["checks"]["outputs_of_no_row"]
+    assert number > 0 and limit == 0
+    far, tol = row["checks"]["farthest_output"]
+    assert far > 3 * tol
+    # the request an altered row should have answered has no answer
+    assert row["checks"]["unanswered"] == [number, 0]
+    assert row["failed"] == 2 * number
 
 
 @pytest.mark.timeout(60)
