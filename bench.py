@@ -309,8 +309,8 @@ def sample_stats(samples) -> dict:
 
 
 def run_interleaved(arms, repeats, run_cell) -> dict:
-    """The interleaved-A/B cell driver shared by --wire-compare,
-    --cascade-compare, and --parallelism-compare: repeats are interleaved
+    """The interleaved-A/B cell driver shared by --wire-compare and
+    --cascade-compare: repeats are interleaved
     at CELL level (arm1, arm2, ..., arm1, arm2, ...) so host drift
     hits every arm equally instead of biasing whichever ran last
     (BENCH_NOTES honesty protocol). Returns {arm: [run_cell(arm, rep),
@@ -1920,199 +1920,6 @@ def run_cascade_compare(args) -> dict:
                     "accuracy artifact",
         **device_info(),
         "config": "cascade-compare",
-        "capture_session": _new_capture_session(),
-        "code_version": _code_version(),
-    }
-
-
-def run_parallelism_compare(args) -> dict:
-    """``--parallelism-compare``: the continuous-batching claim as one
-    artifact (ROADMAP item 3). Four arms over the same lenet5 topology —
-    {deadline, continuous} x {1, 8 inference bolts} — at the operating
-    point where the measured 8-bolts-slower inversion lives: small
-    bucket, short per-task deadline, so 8 deadline batchers fragment the
-    stream into partial buckets while the continuous queue coalesces all
-    replicas (they share one engine via the process cache, hence ONE
-    slot-level queue) into full ones.
-
-    Protocol (BENCH_NOTES honesty rules, shared helpers with
-    wire-/cascade-compare): repeats interleaved at cell level; backlog
-    pre-produced; ack-gated warm->last windows; median-of-N with raw
-    samples in the artifact. A second, PACED phase offers the same
-    common rate (half the slowest arm's measured capacity) to the two
-    8-bolt modes and reports batch_fill — fragmentation must be read at
-    equal offered rate, not equal pressure, because a full-speed drain
-    keeps even per-task batchers full."""
-    import jax
-
-    from storm_tpu.config import BatchConfig
-    from storm_tpu.connectors import MemoryBroker
-    from storm_tpu.infer.continuous import _reset_registry, registry_stats
-    from storm_tpu.runtime.cluster import LocalCluster
-
-    cfg = CONFIGS["lenet5"]
-    n_dev = len(jax.devices())
-    repeats = max(1, args.repeats)
-    # The timed backlog must well exceed the 8-bolt continuous path's
-    # aggregate outstanding-row cap (8 tasks x max_inflight*max_batch =
-    # 1024 rows): below that, nothing ever blocks the consume loop, the
-    # whole backlog enqueues before the first emit flushes, and the
-    # warm->last window collapses to the final burst (measured 60k+
-    # "msg/s" on a ~2.5k msg/s topology).
-    n_msgs = min(args.messages, 4096)
-    warm = max(1024, n_msgs // 4)
-    total = warm + n_msgs
-    ipm = args.instances_per_msg
-    payloads = make_payloads(cfg, instances_per_msg=ipm)
-
-    def batch_cfg(continuous: bool) -> BatchConfig:
-        return BatchConfig(max_batch=64, max_wait_ms=5.0, buckets=(64,),
-                           max_inflight=args.inflight or 2,
-                           continuous=continuous)
-
-    arms = ("deadline-1", "deadline-8", "continuous-1", "continuous-8")
-
-    def arm_params(arm):
-        mode, bolts = arm.rsplit("-", 1)
-        return mode == "continuous", int(bolts)
-
-    cluster = LocalCluster()
-    fills = {}
-    try:
-        def run_cell(arm, rep) -> float:
-            continuous, bolts = arm_params(arm)
-            # Fresh continuous queue per cell: the per-engine registry
-            # outlives topologies (the engine cache does too), and a
-            # stale queue would hold the PREVIOUS cell's metrics binding.
-            _reset_registry()
-            c = dict(cfg, bolts=bolts)
-            broker = MemoryBroker(default_partitions=4)
-            run_cfg, topo = build_topology(c, broker, batch_cfg(continuous))
-            for i in range(total):
-                broker.produce("input", payloads[i % len(payloads)])
-            name = f"pc-{arm}-{rep}"
-            cluster.submit_topology(name, run_cfg, topo)
-            elapsed, done = timed_drain_window(
-                lambda: broker.topic_size("output"), warm, total)
-            h = cluster.metrics(name).get(
-                "inference-bolt", {}).get("batch_fill") or {}
-            cluster.kill_topology(name, wait_secs=2)
-            if elapsed != elapsed or done < total:
-                raise RuntimeError(f"{name}: only {done}/{total} outputs "
-                                   "before deadline")
-            rate = n_msgs / elapsed
-            log(f"  {arm} rep{rep}: {rate:.1f} msg/s "
-                f"(drain batch_fill p50={h.get('p50')})")
-            return rate
-
-        samples = run_interleaved(arms, repeats, run_cell)
-        med = {arm: sample_stats(samples[arm])["value"] for arm in arms}
-
-        # ---- paced common-rate phase: batch_fill at equal offered rate ---
-        paced_s = max(args.latency_seconds, 8.0)
-
-        def paced_cell(mode, rate) -> dict:
-            _reset_registry()
-            c = dict(cfg, bolts=8)
-            broker = MemoryBroker(default_partitions=4)
-            run_cfg, topo = build_topology(
-                c, broker, batch_cfg(mode == "continuous"))
-            name = f"pc-fill-{mode}"
-            cluster.submit_topology(name, run_cfg, topo)
-            # Warm outside the fill window (compile + first batches).
-            base = broker.topic_size("output")
-            for i in range(64):
-                broker.produce("input", payloads[i % len(payloads)])
-            if not await_outputs(
-                    lambda: broker.topic_size("output") - base, 64,
-                    grace_s=120.0):
-                cluster.kill_topology(name, wait_secs=2)
-                raise RuntimeError(f"{name}: fill warmup never drained")
-            cluster.reset_histogram(name, "inference-bolt", "batch_fill")
-            base = broker.topic_size("output")
-            sent, aborted = offer_load(
-                lambda i: broker.produce("input",
-                                         payloads[i % len(payloads)]),
-                rate, paced_s,
-                backlog_fn=lambda s: s - (broker.topic_size("output")
-                                          - base))
-            drained = await_outputs(
-                lambda: broker.topic_size("output") - base, sent,
-                grace_s=60.0)
-            h = cluster.metrics(name).get(
-                "inference-bolt", {}).get("batch_fill") or {}
-            queue = registry_stats() if mode == "continuous" else []
-            cluster.kill_topology(name, wait_secs=2)
-            out = {
-                "offered_msg_s": round(rate, 1),
-                "batch_fill_p50": h.get("p50"),
-                "batch_fill_mean": h.get("mean"),
-                "batches": h.get("count"),
-                "valid": bool(not aborted and drained),
-            }
-            if queue:
-                out["continuous_queue"] = queue[0]
-            log(f"  paced {mode} @ {rate:.0f} msg/s: "
-                f"batch_fill p50={h.get('p50')} over {h.get('count')} "
-                f"batches{'' if out['valid'] else ' [backlog/abort]'}")
-            return out
-
-        # Both modes must see the SAME offered rate (fragmentation is a
-        # function of arrival rate, not of pressure) — so on a backlog
-        # abort in EITHER mode, halve and rerun BOTH at the new rate.
-        # 0.7x the slower 8-BOLT arm's capacity: both paced cells run 8
-        # bolts, so the 1-bolt medians have no business in the floor.
-        paced_rate = max(4.0, 0.7 * min(med["deadline-8"],
-                                        med["continuous-8"]))
-        for _attempt in range(3):
-            fills = {mode: paced_cell(mode, paced_rate)
-                     for mode in ("deadline", "continuous")}
-            if all(f["valid"] for f in fills.values()):
-                break
-            paced_rate = max(4.0, paced_rate / 2)
-            log(f"  paced phase oversaturated; retrying both modes "
-                f"@ {paced_rate:.0f} msg/s")
-    finally:
-        cluster.shutdown()
-
-    rows = []
-    for arm in arms:
-        continuous, bolts = arm_params(arm)
-        rows.append(dict(
-            {"arm": arm,
-             "mode": "continuous" if continuous else "deadline",
-             "bolts": bolts},
-            **arm_stats(samples[arm])))
-    d1, d8 = med["deadline-1"], med["deadline-8"]
-    c1, c8 = med["continuous-1"], med["continuous-8"]
-    fill_d = fills["deadline"].get("batch_fill_p50")
-    fill_c = fills["continuous"].get("batch_fill_p50")
-    return {
-        "metric": "parallelism_compare_lenet5",
-        "value": round(c8 / d8, 3) if d8 else None,
-        "unit": ("continuous-8 / deadline-8 msgs/s (medians of "
-                 "interleaved ack-gated drains; records/s = msgs/s * "
-                 "instances_per_msg)"),
-        "rows": rows,
-        "medians_msgs_per_sec": {k: round(v, 1) for k, v in med.items()},
-        "scaling_deadline_8v1": round(d8 / d1, 3) if d1 else None,
-        "scaling_continuous_8v1": round(c8 / c1, 3) if c1 else None,
-        "continuous8_ge_continuous1": bool(c8 >= c1),
-        "batch_fill_paced": fills,
-        "continuous_fill_gt_deadline": bool(
-            fill_c is not None and fill_d is not None and fill_c > fill_d),
-        "messages_timed": n_msgs,
-        "warmup_messages": warm,
-        "instances_per_msg": ipm,
-        "max_batch": 64,
-        "max_wait_ms": 5.0,
-        "repeats": repeats,
-        "protocol": ("interleaved A/B per cell; median-of-N; ack-gated "
-                     "warm->last window over a pre-produced backlog; "
-                     "paced common-rate phase (0.5x slowest arm's "
-                     "capacity) for batch_fill at equal offered rate"),
-        **device_info(),
-        "config": "parallelism-compare",
         "capture_session": _new_capture_session(),
         "code_version": _code_version(),
     }
@@ -3987,7 +3794,7 @@ def run_fleet_matrix(args) -> dict:
     """``--fleet``: the trace-driven scenario x pattern matrix
     (storm_tpu/loadgen). Each cell replays a seeded trace — heavy-tailed
     tenants, a diurnal wave, or a flash crowd — against one serving
-    scenario (classify, cascade, continuous, serve-path) with the full
+    scenario (classify, cascade, serve-path, decode) with the full
     protection stack live, and is scored on goodput, per-lane p99, SLO
     burn, and shed fraction against declared targets. The committed
     ``SCORECARD_r<N>.json`` is the regression surface future PRs diff
@@ -4378,13 +4185,11 @@ def run_plan(args) -> dict:
        offered rate under the backlog guard:
 
        - ``default``: what you run without a planner — stock
-         ``BatchConfig()`` (legacy 5 ms deadline batcher, multi-bucket
-         padding) at the stock ``TopologyConfig`` inference
-         parallelism (4), i.e. the stream fragmented 4 ways at the
-         measured fragmentation cliff (BENCH_NOTES round 2);
+         ``BatchConfig()`` (5 ms idle deadline, multi-bucket padding)
+         at the stock ``TopologyConfig`` inference parallelism (4);
        - ``planned``: the solver's knobs verbatim via
-         ``Plan.to_overrides()`` — one pinned bucket, continuous
-         co-batching, solved deadline, solved replica count;
+         ``Plan.to_overrides()`` — one pinned bucket, solved deadline,
+         solved replica count;
        - ``worstcase``: the planned batching at ACCEL_MAX_PARALLELISM
          replicas — provision-for-peak, the replica cost a solver-less
          operator pays to be safe.
@@ -4454,9 +4259,9 @@ def run_plan(args) -> dict:
     pipe_ms = max(model.stage_ms(engine_key, 64, st) or 0.0
                   for st in ("h2d_ms", "compute_ms", "d2h_ms"))
     cap64 = 64 * 1e3 / max(pipe_ms, 1e-6)
-    # 0.55x: past the fragmented default's knee (4 legacy batchers split
-    # this into tiny padded buckets and recompile mid-stream) while the
-    # planned single-bucket config still has ~2x headroom.
+    # 0.55x: BENCH_PLAN_r13's operating point (the default arm pads
+    # into several buckets and compiles the small ones mid-stream) while
+    # the planned single-bucket config still has ~2x headroom.
     rate = float(args.plan_rate) if args.plan_rate else round(0.55 * cap64)
     # SLO derived from the same curve (absolute ms are host-relative on a
     # shared CPU box): 3x the bucket-64 device p95, floored at 250 ms and
@@ -4476,12 +4281,12 @@ def run_plan(args) -> dict:
     log(f"[plan] target {rate:.0f} rows/s @ p99 <= {slo:.0f} ms "
         f"(bucket-64 pipelined capacity ~{cap64:.0f} rows/s); solved: "
         f"parallelism={plan.parallelism} bucket={plan.bucket} "
-        f"deadline={plan.deadline_ms:g}ms continuous={plan.continuous} "
+        f"deadline={plan.deadline_ms:g}ms "
         f"-> predicted p99 {pred['p99_ms']:.1f} ms, util {pred['util']:.2f}")
 
     planned_bcfg = BatchConfig(
         max_batch=over["max_batch"], buckets=tuple(over["buckets"]),
-        max_wait_ms=over["max_wait_ms"], continuous=over["continuous"],
+        max_wait_ms=over["max_wait_ms"],
         pipeline_depth=over["pipeline_depth"],
         max_inflight=over["max_inflight"], eager=over["eager"])
     arm_setup = {
@@ -5253,13 +5058,6 @@ def main() -> None:
                          "ack-gated windows, operating point from "
                          "ACCURACY_CASCADE_r09.json) + a sampled run "
                          "capturing the escalation evidence")
-    ap.add_argument("--parallelism-compare", action="store_true",
-                    help="continuous-batching evidence: {deadline,"
-                         "continuous} x {1,8 bolts} on lenet5 at the "
-                         "fragmentation operating point (small bucket, "
-                         "short deadline), interleaved median-of-N, plus "
-                         "a paced equal-rate batch_fill phase -> "
-                         "BENCH_CONTBATCH artifact")
     ap.add_argument("--chaos-recovery", action="store_true",
                     help="resilience evidence run (BENCH_CHAOS): worker "
                          "SIGKILL + wire brownout under steady load on a "
@@ -5332,7 +5130,7 @@ def main() -> None:
                          "(ragged 8/24/48-token budgets)")
     ap.add_argument("--fleet", action="store_true",
                     help="trace-driven fleet matrix: every scenario "
-                         "(classify/cascade/continuous/serve-path) x every "
+                         "(classify/cascade/serve-path/decode) x every "
                          "traffic pattern (heavy-tail/diurnal/flash-crowd) "
                          "scored on goodput, per-lane p99, SLO burn, and "
                          "shed fraction -> SCORECARD artifact")
@@ -5409,9 +5207,6 @@ def main() -> None:
         return
     if args.chaos_recovery:
         print(json.dumps(run_chaos_recovery(args)))
-        return
-    if args.parallelism_compare:
-        print(json.dumps(run_parallelism_compare(args)))
         return
     if args.slo_sweep:
         print(json.dumps(run_slo_sweep(args)))

@@ -100,7 +100,7 @@ JOURNAL_EMITTED = frozenset({
 #: literal flight-event name -> fields every site provides
 FLIGHT_EVENTS = {
     'autoscale_decision': ('bottleneck', 'capacity', 'component', 'direction', 'inbox_frac', 'p50_ms', 'parallelism'),
-    'batch_formed': ('component', 'continuous', 'device_ms', 'fill', 'records', 'size', 'sources'),
+    'batch_formed': ('component', 'device_ms', 'fill', 'records', 'size', 'sources'),
     'bottleneck_shift': ('capacity', 'component', 'device_frac', 'e2e_p95_ms', 'inflow_growth_per_s', 'previous', 'reasons', 'score'),
     'cascade_escalation': (),
     'chaos_injection': ('target',),

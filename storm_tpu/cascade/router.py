@@ -1,20 +1,20 @@
-"""Cascade runtime: one shared engine + batcher per tier.
+"""Cascade runtime: one shared engine per tier.
 
 The :class:`CascadeRouter` owns the per-tier state the inference operator
 drives: tier engines (built through the process-level ``shared_engine``
 cache, so two bolts cascading over the same models share params in HBM),
-per-tier micro-batchers for the escalated residue, the accept/escalate
-decision (confidence math from :mod:`storm_tpu.cascade.policy`), and the
-escalation-budget window.
+the accept/escalate decision (confidence math from
+:mod:`storm_tpu.cascade.policy`), and the escalation-budget window.
+Batches form in each tier engine's own queue
+(:mod:`storm_tpu.infer.continuous`), escalated residue included.
 
 Division of labor with the operator: the operator keeps owning tasks,
-the dispatch semaphore (``max_inflight`` backpressure now bounds device
-round trips ACROSS tiers), deferred acks, and replay — the router never
-touches a tuple's lifecycle. A record's original payload (runtime tuple or
-chunk handle) rides every tier inside an :class:`Escalated` wrapper that
-ack/fail unwrap, so exactly-once semantics are identical to the
-single-engine path: a tier failure fails the original tuples -> replay
-from tier 0.
+the row bound (``max_inflight * max_batch`` outstanding rows ACROSS
+tiers), deferred acks, and replay — the router never touches a tuple's
+lifecycle. A record's original payload (runtime tuple or chunk handle)
+rides every tier inside an :class:`Escalated` wrapper that ack/fail
+unwrap, so exactly-once semantics are identical to the single-engine
+path: a tier failure fails the original tuples -> replay from tier 0.
 """
 
 from __future__ import annotations
@@ -25,8 +25,7 @@ from typing import List, Optional
 import numpy as np
 
 from storm_tpu.cascade.policy import CascadeConfig, uncertainty
-from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
-from storm_tpu.infer.batcher import Batch, MicroBatcher
+from storm_tpu.config import ModelConfig
 
 
 class Escalated:
@@ -57,8 +56,8 @@ class Escalated:
 
 
 class _Residue:
-    """The escalated rows of one record, shaped like a BatchItem for the
-    next tier's ``batcher.add`` (payload/data/ts/lane)."""
+    """The escalated rows of one record, as the next tier's queue takes
+    them (payload/data/ts/lane)."""
 
     __slots__ = ("payload", "data", "ts", "lane")
 
@@ -70,15 +69,12 @@ class _Residue:
 
 
 class _Tier:
-    __slots__ = ("index", "model_cfg", "engine", "batcher", "m_device",
-                 "m_accepted")
+    __slots__ = ("index", "model_cfg", "engine", "m_accepted")
 
     def __init__(self, index: int, model_cfg: ModelConfig) -> None:
         self.index = index
         self.model_cfg = model_cfg
         self.engine = None
-        self.batcher = None
-        self.m_device = None
         self.m_accepted = None
 
     @property
@@ -114,14 +110,12 @@ class CascadeRouter:
             return base
         return dataclasses.replace(base, name=name, checkpoint=ckpt)
 
-    def build(self, base: ModelConfig, sharding: ShardingConfig,
-              batch_cfg: BatchConfig, build_engine, flagship=None,
+    def build(self, base: ModelConfig, build_engine, flagship=None,
               warmup: bool = False) -> None:
         """Build/fetch one engine per tier via ``build_engine`` (the
-        operator's ``shared_engine`` closure) plus one batcher per tier
-        for escalated residue. ``flagship`` (the operator's already-built
-        engine) is reused for the tier whose config matches it — injected
-        test/bench engines included."""
+        operator's ``shared_engine`` closure). ``flagship`` (the
+        operator's already-built engine) is reused for the tier whose
+        config matches it — injected test/bench engines included."""
         for tier in self.tiers:
             mc = self.tier_model(tier.index, base)
             tier.model_cfg = mc
@@ -131,12 +125,6 @@ class CascadeRouter:
                 tier.engine = build_engine(mc)
                 if warmup:
                     tier.engine.warmup()
-            if self.qos is not None:
-                from storm_tpu.qos.lanes import LaneBatcher
-
-                tier.batcher = LaneBatcher(batch_cfg, self.qos)
-            else:
-                tier.batcher = MicroBatcher(batch_cfg)
         shapes = {tuple(t.engine.input_shape) for t in self.tiers}
         if len(shapes) > 1:
             raise ValueError(
@@ -148,8 +136,6 @@ class CascadeRouter:
         self._m = metrics
         self._cid = component_id
         for tier in self.tiers:
-            tier.m_device = metrics.histogram(
-                component_id, f"tier{tier.index}_device_ms")
             tier.m_accepted = metrics.counter(
                 component_id, f"cascade_accepted_tier{tier.index}")
         self._m_escalations = metrics.counter(
@@ -216,9 +202,8 @@ class CascadeRouter:
         tier. Counters (``cascade_accepted_tier{i}``,
         ``cascade_escalations``, lane counters, the budget window) all
         count ROWS, which for single-instance records is identical to
-        counting records. This is the unit both dispatch paths share:
-        the batch path (:meth:`decide`) loops it over a fetched batch;
-        the continuous path calls it per resolved submission."""
+        counting records. The operator calls this once per resolved
+        submission of a tier's device batch."""
         tier = self.tiers[tier_idx]
         n = int(data.shape[0])
         wrapper = payload if isinstance(payload, Escalated) else None
@@ -282,38 +267,6 @@ class CascadeRouter:
                 "pinned": pinned, "budget_capped": capped}
         return merged, residue, info
 
-    def decide(self, batch: Batch, out, tier_idx: int, shed_level: int):
-        """Split one fetched tier output into accepts and escalations.
-
-        Returns ``(accepted, escalated, info)``: ``accepted`` is
-        ``[(payload, merged_preds)]`` ready for the operator's emit+ack
-        loop, ``escalated`` the per-record residue items (original
-        data/ts/lane preserved, data sliced to the uncertain rows) to
-        re-batch into tier ``tier_idx + 1``, and ``info`` the decision
-        stats for the flight-recorder event. Each record's decision is
-        one :meth:`decide_item` call — the same unit the continuous
-        batcher drives per resolved submission."""
-        accepted, escalated = [], []
-        agg = {"accepted": 0, "escalated": 0, "pinned": 0,
-               "budget_capped": 0}
-        ofs = 0
-        for it in batch.items:
-            n = it.data.shape[0]
-            preds = out[ofs:ofs + n]
-            ofs += n
-            merged, residue, info = self.decide_item(
-                it.payload, it.data, preds, it.lane, tier_idx, shed_level,
-                ts=it.ts)
-            if residue is None:
-                accepted.append((it.payload, merged))
-            else:
-                escalated.append(residue)
-            for k in agg:
-                agg[k] += info[k]
-        info = {"tier": tier_idx, "model": self.tiers[tier_idx].name,
-                **agg, "escalation_rate": round(self.escalation_rate(), 4)}
-        return accepted, escalated, info
-
     def _charge(self, tier_idx: int, escalate: bool) -> None:
         # Budget window counts TIER-0 decisions only: the budget caps how
         # much of the ingress stream may leave tier 0; records already
@@ -346,14 +299,16 @@ class CascadeRouter:
         rows = []
         for tier in self.tiers:
             eng = tier.engine
+            # rows waiting in the tier engine's queue (none before the
+            # first bolt binds it: infer/continuous.py continuous_for)
+            queue = getattr(eng, "_continuous_queue", None)
             row = {
                 "tier": tier.index,
                 "model": tier.name,
                 "checkpoint": tier.model_cfg.checkpoint,
                 "threshold": (None if tier.index == self.last_tier
                               else self.cfg.thresholds[tier.index]),
-                "pending_records": len(tier.batcher)
-                if tier.batcher is not None else 0,
+                "pending_records": len(queue) if queue is not None else 0,
                 "cost": store.cost_of(
                     getattr(eng, "profile_key", tier.name)),
             }

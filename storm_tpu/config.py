@@ -20,32 +20,30 @@ from storm_tpu.cascade.policy import CascadeConfig
 
 @dataclass
 class BatchConfig:
-    """Micro-batching policy for the inference operator.
+    """Batch-formation policy of an engine's queue
+    (:mod:`storm_tpu.infer.continuous`).
 
     The reference runs batch=1 per ``session.run`` (InferenceBolt.java:80-86);
-    here batches are formed up to ``max_batch`` or until ``max_wait_ms``
-    elapses, and padded up to the nearest of ``buckets`` so XLA compiles a
-    small, fixed set of shapes.
+    here a batch of up to ``max_batch`` rows is cut from the engine's one
+    queue when a slot of its pipeline ring frees (on an idle device: once
+    the first row has waited ``max_wait_ms``), and padded up to the nearest
+    of ``buckets`` so XLA compiles a small, fixed set of shapes.
     """
 
     max_batch: int = 256
     # How long the first row waits for company on an IDLE device. While
-    # the device works, the default path forms a batch when a ring slot
-    # frees, whatever the clock says.
+    # the device works, a batch is cut when a ring slot frees, whatever
+    # the clock says.
     max_wait_ms: float = 5.0
     # Padding buckets (ascending). Batches are padded to the smallest bucket
     # >= their size; the final entry must equal max_batch.
     buckets: tuple = (8, 32, 128, 256)
-    # Per operator task. On the default path (continuous): the task's
-    # bound on rows it has outstanding in the engine's queue,
-    # max_inflight * max_batch. On the per-task path: batches in flight
-    # per task, one computing on device while the next accumulates/pads.
+    # Per operator task: the task's bound on rows it has outstanding in
+    # the engine's queue, max_inflight * max_batch.
     max_inflight: int = 2
-    # On the default path: an idle device dispatches on arrival instead
-    # of ageing the first row to max_wait_ms (a busy one refills a freed
-    # slot at once either way). On the per-task path: flush the pending
-    # batch whenever one of the TASK's slots is free; under load none is,
-    # so it does not fill buckets there (PERF.md, PR 26).
+    # An idle device dispatches on arrival instead of ageing the first
+    # row to max_wait_ms (a busy one refills a freed slot at once either
+    # way).
     eager: bool = False
     # Split-phase device pipeline depth: batches allowed inside the ENGINE
     # between dispatch (stage -> device_put -> async jit launch) and fetch
@@ -53,7 +51,7 @@ class BatchConfig:
     # H2D of batch N+1 overlaps the compute of batch N and the D2H of
     # batch N-1. 0 disables the pipeline entirely and restores the fully
     # serialized pad/put/fwd/fetch predict (the pre-pipeline engine).
-    # Distinct from ``max_inflight``, which bounds batches per OPERATOR
+    # Distinct from ``max_inflight``, which bounds rows per OPERATOR
     # task; the ring bounds batches per shared engine across all tasks.
     pipeline_depth: int = 2
     # Preallocated host staging buffers per padded bucket shape (the
@@ -62,19 +60,7 @@ class BatchConfig:
     # buffer from dispatch until its fetch completes. 0 = auto
     # (pipeline_depth + 1, so a dispatch never waits on a recycling fetch).
     staging_pool: int = 0
-    # Where batches form. True (the default since ISSUE 26): every
-    # decoded record goes to the ONE queue of the engine its bolt shares
-    # (storm_tpu/infer/continuous.py) — all replicas, the serve
-    # cross-batcher and cascade escalations co-batch there — and a batch
-    # is cut from that queue when the engine's pipeline ring has a free
-    # slot (or max_batch rows are pending); max_wait_ms bounds only the
-    # wait on an idle device. False keeps the per-operator-task
-    # MicroBatcher/LaneBatcher on the deadline clock (max_inflight
-    # batches a task, each formed when the task's slot frees): the path
-    # whose batches were fixed about twelve device steps before they ran
-    # (PERF.md, PR 26), kept for its tests until Design 1 deletes it.
-    continuous: bool = True
-    # Fairness starvation bound for the continuous queue's weighted
+    # Fairness starvation bound for the engine queue's weighted
     # round-robin: a tenant:lane key passed over for this many batch
     # formations is served first in the next one.
     starvation_rounds: int = 4

@@ -1,18 +1,15 @@
 """Per-engine batching: one slot-level queue feeds the device.
 
-The default path since ISSUE 26: every replica of an inference bolt, the
-gRPC serve path's cross-batcher, ``DecodeBolt`` and cascade escalation
-residues all ``submit`` rows into ONE queue per shared engine, and a
-dedicated dispatcher thread cuts a batch from it the moment a slot of the
-engine's pipeline ring frees (extending the split-phase ring of
+Where every batch is formed: each replica of an inference bolt, the gRPC
+serve worker, ``DecodeBolt`` and cascade escalation residues all
+``submit`` rows into ONE queue per shared engine, and a dedicated
+dispatcher thread cuts a batch from it the moment a slot of the engine's
+pipeline ring frees (extending the split-phase ring of
 :mod:`storm_tpu.infer.engine`) — late binding: a batch's size is decided
-when the device can take it, from everything that has arrived by then.
-The per-task deadline batchers (:class:`~storm_tpu.infer.batcher.MicroBatcher`,
-:class:`~storm_tpu.qos.lanes.LaneBatcher`) decide it PER OPERATOR TASK, on a
-5 ms clock, about twelve device steps ahead: under parallelism the device
-sees each replica's fragment (BatchGen, PAPERS.md, argues batch formation
-must be decoupled from operator topology and run continuously at the
-device; PERF.md §6, PR 26 has the chip's numbers for both).
+when the device can take it, from everything that has arrived by then,
+whatever operator task it arrived through (BatchGen, PAPERS.md, argues
+batch formation must be decoupled from operator topology and run
+continuously at the device; PERF.md §6, PR 26 has the chip's numbers).
 
 Dispatch rule (work-conserving slot refill):
 
@@ -22,21 +19,21 @@ Dispatch rule (work-conserving slot refill):
   whatever coalesced while the device worked, exactly BatchGen's
   continuous former);
 - the device is fully idle -> ``eager`` dispatches on arrival, otherwise
-  the oldest row ages to ``max_wait_ms`` (the deadline batcher's latency
-  floor is preserved for trickle traffic).
+  the oldest row ages to ``max_wait_ms`` (trickle traffic waits that long
+  for company, no longer).
 
 How many rows a batch takes: ``max_batch``, or — where the engine's
 measured step time (``engine.step_ms``, the least step seen) grows
 with the bucket — the largest FULL bucket under the pending rows, when
 serving them in full buckets takes no longer than one step padded up to the
 next bucket (:meth:`ContinuousBatcher._rows_to_take_locked`). A model whose
-step costs the same whatever its bucket pads as before.
+step costs the same whatever its bucket takes ``max_batch`` and pads.
 
-Fairness moves here from the LaneBatcher: rows queue per ``tenant:lane``
-key, batch formation orders keys earliest-deadline-first (lane deadlines
-from :class:`~storm_tpu.config.QosConfig`, so a fresh high-priority record
-still preempts queued best-effort), takes rows weighted-round-robin across
-keys (weight = lane priority), and a key passed over
+Fairness: rows queue per ``tenant:lane`` key, batch formation orders keys
+earliest-deadline-first (lane deadlines from
+:class:`~storm_tpu.config.QosConfig`, so a fresh high-priority record
+preempts queued best-effort), takes rows weighted-round-robin across keys
+(weight = lane priority), and a key passed over
 ``BatchConfig.starvation_rounds`` consecutive formations is promoted to the
 front of the next batch regardless of deadline order.
 
@@ -365,7 +362,8 @@ class ContinuousBatcher:
         :meth:`_rows_to_take_locked` cuts at a full bucket). Key order: starved
         keys first (passed over >= starvation_rounds formations, most
         starved first), then earliest head-of-line deadline — EDF across
-        tenants and lanes, so LaneBatcher's preemption semantics hold.
+        tenants and lanes, so a fresh high-priority record preempts
+        queued best-effort ones.
         Within the order, rows are taken weighted-round-robin so one
         flooding key cannot monopolize a batch while others wait."""
         max_rows = self._rows_to_take_locked()
@@ -394,9 +392,9 @@ class ContinuousBatcher:
                         break
                     n = q[0].rows
                     if items and size + n > max_rows:
-                        # Mirror the micro-batchers: leftovers stay pending
-                        # (an oversized single record still ships alone —
-                        # the engine pads per shape rather than crash).
+                        # Leftovers stay pending (an oversized single
+                        # record still ships alone — the engine pads per
+                        # shape rather than crash).
                         capped = True
                         break
                     items.append(q.popleft())
@@ -460,8 +458,7 @@ class ContinuousBatcher:
             return
         t1 = time.perf_counter()
         if self._m:
-            # Slot wait: time parked on the engine ring (the continuous
-            # analogue of the operator's dispatch-semaphore wait).
+            # Slot wait: time parked on the engine ring.
             self._m["disp_wait"].observe((t1 - t0) * 1e3)
         handle.future.add_done_callback(
             lambda f, its=items, h=handle, a=t0, b=t1:
@@ -529,8 +526,7 @@ class ContinuousBatcher:
                 component=self._cid or "continuous",
                 size=rows, records=len(items),
                 fill=round(fill, 3), sources=len(sources),
-                device_ms=round((t_done - t_disp) * 1e3, 3),
-                continuous=True)
+                device_ms=round((t_done - t_disp) * 1e3, 3))
         ofs = 0
         for it in items:
             n = it.rows
@@ -553,9 +549,13 @@ class ContinuousBatcher:
                 logger.exception("continuous batch notify failed")
 
     def _trace(self, items, t0, t1, handle, fill, n_sources):
-        """Continuous-mode analogue of the operator's ``_trace_batch``:
-        queue_wait per sampled record, one shared device span linked to
-        all members, with batch_fill/sources attrs."""
+        """Span bookkeeping for one device round trip: a ``queue_wait``
+        span per SAMPLED record (queue entry -> device start) and ONE
+        shared device span (``span_name``), same span id in every
+        participating trace and linked to all member record spans, so
+        the fan-in of N records into one batch is first-class in the
+        trace. Returns the shared span's id (None when no member record
+        is sampled) for escalation links."""
         tracer = self._tracer
         cid = self._cid or "continuous"
         traced = []
@@ -574,7 +574,7 @@ class ContinuousBatcher:
         links = tuple(qid for _, _, qid in traced)
         attrs = {"batch_size": sum(it.rows for it in items),
                  "records": len(items), "fill": round(fill, 3),
-                 "sources": n_sources, "continuous": True}
+                 "sources": n_sources}
         timings = getattr(handle, "timings", None) if handle else None
         if timings:
             for key, _ in DEVICE_SUBSTAGES:

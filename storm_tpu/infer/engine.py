@@ -499,11 +499,11 @@ class InferenceEngine:
             queue.SimpleQueue()
         self._fetch_thread: Optional[threading.Thread] = None
         self._fetch_thread_lock = threading.Lock()
-        # Dispatch slots visible to the continuous batcher: ring depth when
+        # Dispatch slots visible to the engine's queue: ring depth when
         # pipelined, else the single serialized predict slot.
         self.ring_capacity = max(1, self.pipeline_depth)
         # Device milliseconds of one step of each padded bucket: what the
-        # continuous queue's formation rule reads to tell a model whose
+        # queue's formation rule reads to tell a model whose
         # step grows with its bucket from a launch-bound one
         # (infer/continuous.py). The fetch thread keeps it, as the least
         # step seen of each compiled program (_fetch_loop); a program's
@@ -736,7 +736,7 @@ class InferenceEngine:
 
     def warmup(self, buckets: Optional[Tuple[int, ...]] = None) -> None:
         """Pre-compile the bucket shapes so first traffic doesn't hit XLA
-        compile latency (the deadline batcher depends on stable latencies)."""
+        compile latency (batch formation reads measured step times)."""
         for b in buckets or self.batch_cfg.buckets:
             n = self.pad_batch(b)
             if n in self.compiled_batches:
@@ -1018,7 +1018,7 @@ class InferenceEngine:
         if gathered is None:
             # single-process: the host fetch happens OUTSIDE the lock so
             # one batch's device->host RTT doesn't serialize the next
-            # batch's dispatch (max_inflight pipelining)
+            # batch's dispatch
             gathered = np.asarray(out)
         return gathered[:n]
 

@@ -13,9 +13,7 @@ Per-tuple flow, redesigned for the async device boundary:
    :class:`InferenceEngine` (:mod:`storm_tpu.infer.continuous`), where a
    batch is cut when the engine's ring has a free slot, on the queue's own
    thread — the event loop keeps consuming while the TPU computes (the
-   reference blocked its executor thread in ``session.run`` at batch 1).
-   ``batch.continuous=False`` feeds a per-task micro-batcher instead, whose
-   full batch (or deadline flush) dispatches on a worker thread;
+   reference blocked its executor thread in ``session.run`` at batch 1);
 4. when the batch returns, emit one ``{"predictions": ...}`` tuple per
    input record (records of one ``RecordFrame`` that rode one batch share
    one), anchored, and ack — acks are *deferred* until the device
@@ -38,14 +36,13 @@ from storm_tpu.api.schema import (
     DeadLetter, Overloaded, SchemaError, decode_instances, encode_predictions)
 from storm_tpu.cascade.policy import CascadeConfig
 from storm_tpu.cascade.router import CascadeRouter, Escalated
-from storm_tpu.config import BatchConfig, Config, ModelConfig, ShardingConfig
-from storm_tpu.infer.batcher import Batch, MicroBatcher
+from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
 from storm_tpu.infer.continuous import continuous_for
 from storm_tpu.infer.engine import InferenceEngine, shared_engine
 from storm_tpu.obs import copyledger as _copyledger
 from storm_tpu.runtime.base import Bolt, OutputCollector, TopologyContext
 from storm_tpu.runtime.frames import RecordFrame
-from storm_tpu.runtime.tracing import DEVICE_SUBSTAGES, NOT_SAMPLED, span
+from storm_tpu.runtime.tracing import NOT_SAMPLED, span
 from storm_tpu.runtime.tuples import Tuple, Values
 
 
@@ -64,7 +61,7 @@ class _ChunkHandle:
         self.failed = False
         # frame=True: the chunk arrived as a RecordFrame (batch-native
         # ingress) — egress coalesces this handle's records into ONE
-        # predictions payload per dispatched batch (see _run_batch).
+        # predictions payload per device batch (see _emit_groups).
         self.frame = frame
 
     def done(self, ok: bool, collector: OutputCollector) -> None:
@@ -87,6 +84,10 @@ def _link_of(payload):
 
 
 class InferenceBolt(Bolt):
+    # Flipped by execute() on the first raw-scheme payload: predictions
+    # then leave as utf-8 bytes.
+    _bytes_egress = False
+
     def __init__(
         self,
         model: Optional[ModelConfig] = None,
@@ -107,8 +108,8 @@ class InferenceBolt(Bolt):
         # streams). How a DRPC request id rides through the operator —
         # Storm's LinearDRPCTopologyBuilder threads return-info the same way.
         self.passthrough = tuple(passthrough)
-        # QosConfig (config.py) or None. When enabled: earliest-deadline-
-        # first batch formation (storm_tpu.qos.lanes) instead of FIFO, and
+        # QosConfig (config.py) or None. When enabled: the engine's queue
+        # orders lanes earliest-deadline-first instead of FIFO, and
         # shed-eligible tuples are degraded/rejected while the shed level
         # (gauge ("qos", "shed_level")) is raised.
         self.qos = qos if (qos is not None and qos.enabled) else None
@@ -176,9 +177,8 @@ class InferenceBolt(Bolt):
     def _cascade_cfg(self) -> Optional[CascadeConfig]:
         """The effective cascade: the explicit config when given, else a
         synthesized two-tier shed-only cascade for ``qos.degrade_model``
-        (the old cheaper-model-behind-a-semaphore degrade path, now just
-        a cascade whose tier 0 serves pinned shed traffic with normal
-        batching and ``max_inflight`` concurrency)."""
+        (a cascade whose tier 0 serves pinned shed traffic through its
+        engine's queue like any other)."""
         if self.cascade is not None:
             return self.cascade
         if self.qos is not None and self.qos.degrade_model:
@@ -207,76 +207,35 @@ class InferenceBolt(Bolt):
         if self._warmup and not prewarmed:
             self.engine.warmup()
         # Cascade (explicit config, or synthesized from qos.degrade_model):
-        # one shared engine + residue batcher per tier. The operator keeps
-        # owning tasks, acks, and the dispatch semaphore — max_inflight now
-        # bounds device round trips ACROSS tiers.
+        # one shared engine per tier. The operator keeps owning tasks, acks
+        # and the row bound — max_inflight bounds the task's outstanding
+        # rows ACROSS tiers.
         cas = self._cascade_cfg()
         if cas is not None:
             self._router = CascadeRouter(cas, qos=self.qos)
             self._router.build(
-                self.model_cfg, self.sharding_cfg, self.batch_cfg,
+                self.model_cfg,
                 build_engine=lambda mc: shared_engine(
                     mc, self.sharding_cfg, self.batch_cfg),
                 flagship=self.engine,
                 warmup=self._warmup and not prewarmed)
         else:
             self._router = None
-        if self.qos is not None:
-            from storm_tpu.qos.lanes import LaneBatcher
-
-            self.batcher = LaneBatcher(self.batch_cfg, self.qos)
-        else:
-            self.batcher = MicroBatcher(self.batch_cfg)
-        if self._router is not None:
-            # Cascade ingest goes through the tier batchers; self.batcher
-            # stays as an alias of the default entry tier's batcher so
-            # introspection (len, tests) keeps working.
-            entry0 = cas.last_tier if cas.shed_only else 0
-            self.batcher = self._router.tiers[entry0].batcher
-            self._sources = [
-                (t.index, t.batcher) for t in self._router.tiers]
-        else:
-            self._sources = [(None, self.batcher)]
-        self._flush_task: Optional[asyncio.Task] = None
         self._inflight: Set[asyncio.Task] = set()
-        self._dispatch_sem = asyncio.Semaphore(
-            max(1, self.batch_cfg.max_inflight))
-        self._eager = getattr(self.batch_cfg, "eager", False)
-        # Eager dispatches created but not yet through sem.acquire():
-        # locked() alone is optimistic (the task acquires a tick later),
-        # and two same-tick arrivals would otherwise each ship a tiny batch.
-        self._eager_pending = 0
         m = context.metrics
         cid = context.component_id
-        self._m_batch = m.histogram(cid, "batch_size")
-        self._m_device_ms = m.histogram(cid, "device_ms")
         self._m_dead = m.counter(cid, "dead_lettered")
-        self._m_infer = m.counter(cid, "instances_inferred")
-        # Latency-decomposition stages (bench.py --latency-breakdown): the
-        # e2e append->deliver clock attributed into where time actually
-        # goes. decode_ms/encode_ms come from span(); these cover the gaps.
-        self._m_ingest = m.histogram(cid, "ingest_lag_ms")  # append -> bolt
-        self._m_batch_wait = m.histogram(cid, "batch_wait_ms")  # in batcher
-        self._m_disp_wait = m.histogram(cid, "dispatch_wait_ms")  # sem queue
-        # Fragmentation metrics (both dispatch paths, so the continuous
-        # A/B has a baseline): rows dispatched / padded bucket capacity,
-        # and how many distinct sources each dispatched batch coalesced
-        # (always 1 on the per-task deadline path).
-        self._m_fill = m.histogram(cid, "batch_fill")
-        self._m_coalesced = m.counter(cid, "coalesced_sources")
-        # Split-phase pipeline substages (engine dispatch/fetch timings):
-        # together they decompose device_ms, so --latency-breakdown keeps
-        # them OUT of the stage sum (device_ms already counts that time).
-        self._m_substage = {
-            key: m.histogram(cid, key) for key, _ in DEVICE_SUBSTAGES}
+        # Stage 1 of the latency decomposition (broker append -> bolt
+        # arrival); the queue observes the batching and device stages
+        # under this component id (ContinuousBatcher.bind).
+        self._m_ingest = m.histogram(cid, "ingest_lag_ms")
         if self._router is not None:
             self._router.bind_metrics(m, cid)
         # QoS: the shed level is read per tuple, so cache the gauge (the
         # LoadShedController publishes through the same registry). The
-        # degrade path now lives in the cascade: qos.degrade_model
-        # synthesizes a shed-only cascade whose tier 0 serves pinned shed
-        # traffic — batched, under the normal max_inflight concurrency —
-        # replacing the old unbatched single-slot degrade semaphore.
+        # degrade path lives in the cascade: qos.degrade_model synthesizes
+        # a shed-only cascade whose tier 0 serves pinned shed traffic,
+        # batched like any other.
         if self.qos is not None:
             self._shed_gauge = m.gauge("qos", "shed_level")
             self._m_shed = m.counter(cid, "shed_rejected")
@@ -311,34 +270,27 @@ class InferenceBolt(Bolt):
             self.engine.on_quarantine = self._engine_quarantined
         except AttributeError:
             pass  # slotted test double
-        # The default path (ISSUE 26): batch formation lives OFF this task,
-        # in the one queue of the engine it shares — every replica, the
-        # serve cross-batcher and cascade residues co-batch there, and a
-        # batch is cut from the queue when the engine's ring has a free
-        # slot (infer/continuous.py), not per task on the deadline clock.
-        # The per-task batchers above stay as admission shims (shed/lane
-        # classification still happens here); they just never accumulate.
-        # ``continuous=False`` keeps the per-task deadline path.
-        self._continuous = bool(getattr(self.batch_cfg, "continuous", True))
+        # Batch formation lives OFF this task, in the one queue of each
+        # engine it shares — every replica, the serve path and cascade
+        # residues co-batch there, and a batch is cut from the queue when
+        # the engine's ring has a free slot (infer/continuous.py). Shed and
+        # lane classification stay here.
         self._cbs = {}
         self._cb_notify = {}
-        if self._continuous:
-            if self._router is not None:
-                for rt in self._router.tiers:
-                    self._bind_queue(rt.index, rt.engine)
-            else:
-                self._bind_queue(None, self.engine)
-            # Per-task backpressure: the dispatch semaphore bounded
-            # BATCHES in flight; here the queue owns batching, so the
-            # task bounds its outstanding ROWS at the equivalent
-            # max_inflight * max_batch.
-            self._cb_cap = (max(1, self.batch_cfg.max_inflight)
-                            * max(1, self.batch_cfg.max_batch))
-            self._cb_rows = 0
-            self._cb_room = asyncio.Event()
-            self._cb_room.set()
-            self._cb_source = f"{cid}#{context.task_index}"
-            self._loop = None  # the event loop, known from the first record
+        if self._router is not None:
+            for rt in self._router.tiers:
+                self._bind_queue(rt.index, rt.engine)
+        else:
+            self._bind_queue(None, self.engine)
+        # Per-task backpressure: the queue owns batching, so the task
+        # bounds its outstanding ROWS, at max_inflight * max_batch.
+        self._cb_cap = (max(1, self.batch_cfg.max_inflight)
+                        * max(1, self.batch_cfg.max_batch))
+        self._cb_rows = 0
+        self._cb_room = asyncio.Event()
+        self._cb_room.set()
+        self._cb_source = f"{cid}#{context.task_index}"
+        self._loop = None  # the event loop, known from the first record
 
     def _bind_queue(self, tier: Optional[int], engine) -> None:
         """Aim this task at ``engine``'s continuous queue (``tier`` None:
@@ -387,7 +339,7 @@ class InferenceBolt(Bolt):
                 self.engine = eng
                 # Re-aim at the replacement's queue (a queue holds the
                 # engine it dispatches to).
-                if getattr(self, "_cbs", None) and None in self._cbs:
+                if None in self._cbs:
                     self._bind_queue(None, eng)
                 self._m_quarantined.set(0)
                 if self._flight is not None:
@@ -517,77 +469,6 @@ class InferenceBolt(Bolt):
             stream="dead_letter", anchors=[anchor],
         )
 
-    def __getattr__(self, name):
-        # `_sources` is assigned in prepare(); bolts built without it
-        # (partial skeletons in tests, subclasses overriding prepare)
-        # see their plain `batcher` as the only drain source.
-        if name == "_sources":
-            return [(None, self.batcher)]
-        # Flipped lazily by execute() on the first raw-scheme payload;
-        # partial skeletons that never execute default to str egress.
-        if name == "_bytes_egress":
-            return False
-        raise AttributeError(name)
-
-    def _pending(self) -> int:
-        return sum(len(b) for _, b in self._sources)
-
-    def batcher_stats(self) -> dict:
-        """Aggregate depth/age of this task's admission batcher(s) — the
-        obs edge watermarks (EdgeLagTracker) read every batching mode
-        through this one shape. Continuous mode reports ~0 here by
-        design: batch formation lives in the shared engine queue, whose
-        depth/oldest-age surface via ``ContinuousBatcher.stats`` and
-        ``Observatory.occupancy``."""
-        rows = depth = 0
-        oldest_ms = 0.0
-        for _tier, b in self._sources:
-            stats_fn = getattr(b, "stats", None)
-            if stats_fn is None:
-                continue
-            st = stats_fn()
-            rows += st["pending_rows"]
-            depth += st["depth"]
-            oldest_ms = max(oldest_ms, st["oldest_ms"])
-        return {"pending_rows": rows, "depth": depth,
-                "oldest_ms": round(oldest_ms, 3),
-                "continuous": bool(getattr(self, "_continuous", False))}
-
-    def _kick_flush(self) -> None:
-        try:
-            asyncio.get_running_loop()
-        except RuntimeError:
-            return  # loop torn down mid-finalizer (cluster shutdown race)
-        if self._eager and self._pending() and \
-                not self._dispatch_sem.locked() and not self._eager_pending:
-            # Work-conserving: a device slot is free and records are
-            # waiting — dispatch now rather than age toward the deadline.
-            # Under load every slot is busy, this branch never fires, and
-            # batches fill toward max_batch while they queue.
-            batch, tier = None, None
-            for tier, b in self._sources:
-                batch = b.take_all()
-                if batch is not None:
-                    break
-            if batch is not None:
-                self._eager_pending += 1
-                task = asyncio.get_running_loop().create_task(
-                    self._dispatch(batch, tier))
-                self._inflight.add(task)
-                task.add_done_callback(self._inflight.discard)
-                # Decrement when the task finishes — however it finishes.
-                # A cancel BEFORE the coroutine's first step never enters
-                # _dispatch, so an in-body decrement would leak the counter
-                # and permanently disable eager dispatch for this bolt.
-                task.add_done_callback(
-                    lambda _t: setattr(
-                        self, "_eager_pending", self._eager_pending - 1))
-                return
-        if self._pending() and (self._flush_task is None or self._flush_task.done()):
-            self._flush_task = asyncio.get_running_loop().create_task(
-                self._deadline_flush()
-            )
-
     async def execute(self, t: Tuple) -> None:
         if t.root_ts:
             # Stage 1 of the decomposition: broker append -> bolt arrival
@@ -611,8 +492,8 @@ class InferenceBolt(Bolt):
                 await self._shed_tuple(t, payload, lane, level)
                 return
             # Cascade degrade: the record serves at tier 0 — pinned there
-            # by decide(), batched, under normal max_inflight concurrency —
-            # so fall through to the regular ingest path.
+            # by decide_item(), batched like any other — so fall through
+            # to the regular ingest path.
             n = (len(payload)
                  if isinstance(payload, (list, tuple, RecordFrame)) else 1)
             self._m_degraded.inc(n)
@@ -631,28 +512,8 @@ class InferenceBolt(Bolt):
         except SchemaError as e:
             await self._dead_letter(t, payload, str(e))
             return
-        await self._ingest(t, inst.data, t.root_ts or None, lane, entry)
-        self._kick_flush()
-
-    async def _ingest(self, item, data, ts, lane, entry) -> None:
-        """Add one record to its entry batcher (a cascade tier's when a
-        router is active, the plain operator batcher otherwise) and drain
-        every batch that comes due — add returns at most one batch per
-        call; a full one must not sit until the deadline."""
-        if getattr(self, "_continuous", False):
-            await self._submit_record(item, data, ts, lane, entry)
-            return
-        if entry is None:
-            b, tier = self.batcher, None
-        else:
-            b, tier = self._router.tiers[entry].batcher, entry
-        if self.qos is not None:
-            batch = b.add(item, data, ts=ts, lane=lane)
-        else:
-            batch = b.add(item, data, ts=ts)
-        while batch is not None:
-            await self._dispatch(batch, tier)
-            batch = b.take_ready()
+        await self._submit_record(t, inst.data, t.root_ts or None, lane,
+                                  entry)
 
     async def _execute_chunk(self, t: Tuple, payloads, lane=None,
                              entry=None) -> None:
@@ -672,20 +533,18 @@ class InferenceBolt(Bolt):
                 await self._emit_dead_letter(t, payload, str(e))
                 handle.done(True, self.collector)
                 continue
-            await self._ingest(handle, inst.data, t.root_ts or None, lane,
-                               entry)
-        self._kick_flush()
+            await self._submit_record(handle, inst.data, t.root_ts or None,
+                                      lane, entry)
 
-    # ---- continuous batching path --------------------------------------------
+    # ---- the engine's queue --------------------------------------------------
 
     async def _submit_record(self, item, data, ts, lane, entry) -> None:
-        """Hand one record to its tier's shared continuous queue; the
-        queue calls back once a device batch with this task's records of
-        it (``_on_batch``). Backpressure is row-counted per task
-        (``max_inflight * max_batch`` outstanding rows — the
-        row-equivalent of the dispatch semaphore, which bounded whole
-        batches); the engine's pipeline ring stays the device-side
-        bound."""
+        """Hand one record to the queue of its entry engine (a cascade
+        tier's when a router is active); the queue calls back once a
+        device batch with this task's records of it (``_on_batch``).
+        Backpressure is row-counted per task (``max_inflight * max_batch``
+        outstanding rows); the engine's pipeline ring stays the
+        device-side bound."""
         while self._cb_rows >= self._cb_cap:
             self._cb_room.clear()
             await self._cb_room.wait()
@@ -719,14 +578,12 @@ class InferenceBolt(Bolt):
         task.add_done_callback(self._inflight.discard)
 
     async def _finish_group(self, tier, members) -> None:
-        """Emit + complete this task's records of one device batch — the
-        continuous analogue of ``_run_batch``'s emit/escalate block. A
+        """Emit + complete this task's records of one device batch. A
         record a cascade tier is unsure of goes on to the next tier's
         queue and completes from that batch's group. A queue/device
         failure at ANY tier fails the ORIGINAL tuple (``_complete``
         unwraps ``Escalated``), so the record replays from tier 0 —
-        at-least-once exactly as on the batch path, each source failing
-        its own tuples only."""
+        at-least-once, each source failing its own tuples only."""
         emit = []
         try:
             reported = False
@@ -815,198 +672,8 @@ class InferenceBolt(Bolt):
                        "action": "reject"})
         self.collector.ack(t)
 
-    # ---- batching / dispatch -------------------------------------------------
-
-    async def _deadline_flush(self) -> None:
-        """Runs while records are pending; never cancelled mid-dispatch (a
-        cancel between take and dispatch would silently drop the batch), it
-        just exits when the batcher drains."""
-        while True:
-            oldest = min(
-                (b.oldest_ts for _, b in self._sources
-                 if b.oldest_ts is not None), default=None)
-            if oldest is None:
-                return
-            wait_s = self.batch_cfg.max_wait_ms / 1e3 - (time.perf_counter() - oldest)
-            if wait_s > 0:
-                await asyncio.sleep(wait_s)
-            for tier, b in self._sources:
-                batch = b.take_if_due()
-                while batch is not None:
-                    await self._dispatch(batch, tier)
-                    batch = b.take_ready()
-
-    def _spawn_dispatch(self, batch: Batch, tier: Optional[int]) -> None:
-        """Dispatch on a fresh task — for callers that must NOT await the
-        dispatch semaphore (``_escalate`` runs under ``_run_batch``, which
-        still HOLDS a semaphore slot: awaiting _dispatch there deadlocks
-        at max_inflight=1)."""
-        task = asyncio.get_running_loop().create_task(
-            self._dispatch(batch, tier))
-        self._inflight.add(task)
-        task.add_done_callback(self._inflight.discard)
-
-    async def _dispatch(self, batch: Batch, tier: Optional[int] = None) -> None:
-        # NB: _eager_pending is decremented by a done-callback on the eager
-        # task (see _kick_flush), NOT here — a cancel while parked on the
-        # semaphore (or before the first step) must still restore it.
-        t0 = time.perf_counter()
-        # Stage: accumulation in the batcher (deadline vs fill), per
-        # record from batcher entry to flush. Observed BEFORE the
-        # semaphore so batch_wait and dispatch_queue partition the clock
-        # instead of overlapping.
-        for it in batch.items:
-            if it.enq:
-                self._m_batch_wait.observe((t0 - it.enq) * 1e3)
-        await self._dispatch_sem.acquire()
-        # Stage: wait for a free device slot (max_inflight backpressure).
-        self._m_disp_wait.observe((time.perf_counter() - t0) * 1e3)
-        task = asyncio.get_running_loop().create_task(
-            self._run_batch(batch, tier))
-        self._inflight.add(task)
-        task.add_done_callback(self._inflight.discard)
-
-    def _trace_batch(self, batch: Batch, t0: float, t1: float,
-                     timings=None, tier: Optional[int] = None,
-                     fill: Optional[float] = None):
-        """Span bookkeeping for one device round trip: a ``queue_wait``
-        span per SAMPLED record (batcher entry -> device start) and ONE
-        shared device span — ``device_execute``, or ``cascade_tier{i}``
-        when a cascade tier served the batch — same span id in every
-        participating trace, linked to all member record spans — so the
-        fan-in of N records into one batch is first-class in the trace
-        (and queue-wait vs. device time separable per record). Escalated
-        records' queue_wait spans link back to the span of the tier that
-        escalated them, chaining a hard record's tier-to-tier journey.
-        Only called when the tracer is active; per-record work only for
-        sampled records. Returns the shared span's id (None when no
-        member record is sampled) for escalation links."""
-        tracer = self._tracer
-        cid = self.context.component_id
-        traced = []
-        for it in batch.items:
-            ctx = self._anchor_of(it.payload).trace
-            if ctx is not None:
-                links = ()
-                if isinstance(it.payload, Escalated) and it.payload.link_span:
-                    links = (it.payload.link_span,)
-                traced.append((ctx, tracer.record(
-                    ctx, "queue_wait", cid, it.enq or t0, t0, links=links)))
-        if not traced:
-            return None
-        batch_span = tracer.new_span_id()
-        links = tuple(qid for _, qid in traced)
-        name = "device_execute" if tier is None else f"cascade_tier{tier}"
-        attrs = {"batch_size": batch.size, "records": len(batch.items)}
-        if fill is not None:
-            attrs["fill"] = round(fill, 3)
-        if tier is not None:
-            attrs["tier"] = tier
-            attrs["model"] = self._router.tiers[tier].name
-        if timings:
-            # Split-phase decomposition of this span's wall time: where the
-            # device round trip went (staging+H2D vs compute vs D2H).
-            for key, _ in DEVICE_SUBSTAGES:
-                if key in timings:
-                    attrs[key] = round(timings[key], 3)
-        for ctx, qid in traced:
-            tracer.record(ctx, name, cid, t0, t1,
-                          span_id=batch_span, parent_id=qid,
-                          links=links, attrs=attrs)
-        return batch_span
-
-    async def _run_batch(self, batch: Batch,
-                         tier: Optional[int] = None) -> None:
-        rt = None if tier is None else self._router.tiers[tier]
-        engine = self.engine if rt is None else rt.engine
-        try:
-            dispatch = getattr(engine, "dispatch", None)
-            t0 = time.perf_counter()
-            timings = None
-            handle = None
-            if dispatch is not None:
-                # Split-phase path: dispatch (stage into the engine's
-                # pooled buffer + H2D + async launch) runs on a worker
-                # thread because it can park on the engine's bounded ring;
-                # the result future resolves from the engine's fetch
-                # thread. The dispatch semaphore stays held for the full
-                # round trip, so max_inflight backpressure and deferred
-                # acks keep their pre-pipeline semantics.
-                handle = await asyncio.to_thread(dispatch, batch.parts())
-                out = await asyncio.wrap_future(handle.future)
-                timings = handle.timings
-            else:
-                # Engines without the split-phase surface (custom test
-                # doubles): the serialized predict.
-                out = await asyncio.to_thread(engine.predict,
-                                              batch.stack())
-            t1 = time.perf_counter()
-            self._m_device_ms.observe((t1 - t0) * 1e3)
-            if rt is not None and rt.m_device is not None:
-                rt.m_device.observe((t1 - t0) * 1e3)
-            if timings:
-                for key, _ in DEVICE_SUBSTAGES:
-                    if key in timings:
-                        self._m_substage[key].observe(timings[key])
-            self._m_batch.observe(batch.size)
-            self._m_infer.inc(batch.size)
-            # Fragmentation: rows / padded bucket capacity. Per-task
-            # deadline batches are single-source by construction, so the
-            # coalesced counter advances by 1 — the baseline the
-            # continuous queue's multi-source batches compare against.
-            padded = (int(getattr(handle, "padded", 0) or 0)
-                      or self.batch_cfg.bucket_for(batch.size))
-            fill = batch.size / max(padded, 1)
-            self._m_fill.observe(fill)
-            self._m_coalesced.inc()
-            self.context.metrics.counter(
-                self.context.component_id, f"steps_bucket_{padded}").inc()
-            batch_span = None
-            if self._tracer is not None and self._tracer.active:
-                batch_span = self._trace_batch(batch, t0, t1, timings,
-                                               tier, fill)
-            if self._flight is not None:
-                # Sampled (throttled) batch-formed events: enough to see
-                # batch-size/device-time behavior in a post-mortem without
-                # a per-batch firehose at production rates.
-                self._flight.event(
-                    "batch_formed", throttle_s=1.0,
-                    component=self.context.component_id,
-                    size=batch.size, records=len(batch.items),
-                    fill=round(fill, 3), sources=1,
-                    device_ms=round((t1 - t0) * 1e3, 3),
-                    **({} if rt is None else {"tier": tier,
-                                              "model": rt.name}))
-            if rt is None:
-                emit = batch.split(out)
-                escalated, info = (), None
-            else:
-                level = (int(self._shed_gauge.value)
-                         if self.qos is not None else 0)
-                emit, escalated, info = self._router.decide(
-                    batch, out, tier, level)
-            await self._emit_groups(emit)
-            if escalated:
-                if self._flight is not None:
-                    self._flight.event(
-                        "cascade_escalation", throttle_s=1.0,
-                        component=self.context.component_id, **info)
-                await self._escalate(escalated, tier + 1, batch_span)
-        except Exception as e:
-            # Device/compile failure: fail every tuple in the batch ->
-            # spout replay (an escalation tier failure fails the ORIGINAL
-            # tuples — _complete unwraps Escalated — so the records replay
-            # from tier 0, never half-served).
-            self.collector.report_error(e)
-            for item in batch.items:
-                self._complete(item.payload, False)
-        finally:
-            self._dispatch_sem.release()
-            # Freed a slot: eagerly pull whatever queued while we ran.
-            self._kick_flush()
-
     async def _emit_groups(self, emit) -> None:
-        """Batch egress, both paths: records that arrived together as a
+        """Batch egress: records that arrived together as a
         RecordFrame and rode one device batch leave together — their
         predictions concatenate into ONE payload per (frame, device
         batch), killing the per-record json_encode fan-out (r19 zero-copy
@@ -1031,28 +698,6 @@ class InferenceBolt(Bolt):
                 for item, _ in group:
                     self._complete(item, True)
 
-    async def _escalate(self, items, tier: int, link_span) -> None:
-        """Re-batch the low-confidence residue into the next tier's
-        batcher, preserving each record's original data/deadline/lane.
-        Ready batches go through _spawn_dispatch (never awaited: this
-        coroutine runs under _run_batch, which holds a semaphore slot)."""
-        rt = self._router.tiers[tier]
-        b = rt.batcher
-        for it in items:
-            payload = it.payload
-            if isinstance(payload, Escalated):
-                payload.link_span = link_span
-            else:
-                payload = Escalated(payload, link_span)
-            if self.qos is not None:
-                batch = b.add(payload, it.data, ts=it.ts, lane=it.lane)
-            else:
-                batch = b.add(payload, it.data, ts=it.ts)
-            while batch is not None:
-                self._spawn_dispatch(batch, tier)
-                batch = b.take_ready()
-        self._kick_flush()
-
     async def swap_model(self, model_cfg: ModelConfig) -> None:
         """Zero-downtime model swap (the reference ships its model inside
         the application jar, InferenceBolt.java:49-57 — redeploying means a
@@ -1064,7 +709,7 @@ class InferenceBolt(Bolt):
         (swap back) at the cost of its HBM footprint.
 
         Swapping to a different ``input_shape`` may fail-and-replay tuples
-        decoded under the old shape that are still in the batcher —
+        decoded under the old shape that reach the new engine's queue —
         at-least-once delivery covers them."""
 
         def build() -> InferenceEngine:
@@ -1074,8 +719,7 @@ class InferenceBolt(Bolt):
 
         old_engine = self.engine
         new_engine = await asyncio.to_thread(build)
-        continuous = getattr(self, "_continuous", False)
-        if getattr(self, "_router", None) is not None:
+        if self._router is not None:
             # The cascade tier serving the flagship follows the swap (the
             # tiers sharing the old engine object by identity — normally
             # just the last one), and so does its queue.
@@ -1083,54 +727,24 @@ class InferenceBolt(Bolt):
                 if rt.engine is old_engine:
                     rt.engine = new_engine
                     rt.model_cfg = model_cfg
-                    if continuous:
-                        self._bind_queue(rt.index, new_engine)
-        elif continuous:
+                    self._bind_queue(rt.index, new_engine)
+        else:
             # Rows already queued finish on the old engine's queue; later
             # records go to the new engine's.
             self._bind_queue(None, new_engine)
         self.engine = new_engine
         self.model_cfg = model_cfg
 
-    async def tick(self) -> None:
-        for tier, b in self._sources:
-            batch = b.take_if_due()
-            while batch is not None:
-                await self._dispatch(batch, tier)
-                batch = b.take_ready()
-
     async def flush(self) -> None:
-        """Drain: dispatch whatever is pending and wait for in-flight
-        batches, so a graceful stop never strands undecoded acks. Loops
-        because finishing a cascade tier's batches can re-fill a LATER
-        tier's batcher with escalated residue."""
-        if getattr(self, "_continuous", False):
-            # Force the shared queues to dispatch and wait until none of
-            # this task's rows is outstanding and every group has been
-            # emitted. Re-flush on a short period: a record escalating
-            # mid-drain enqueues into a LATER tier's queue after its
-            # flush already drained.
-            while self._cb_rows or self._inflight:
-                for cb in set(self._cbs.values()):
-                    cb.flush()
-                if self._inflight:
-                    await asyncio.wait(list(self._inflight), timeout=0.05)
-                else:
-                    await asyncio.sleep(0.005)
-            return
-        while True:
-            for tier, b in self._sources:
-                batch = b.take_all()
-                while batch is not None:
-                    await self._dispatch(batch, tier)
-                    batch = b.take_all()
-            while self._inflight:
-                await asyncio.gather(
-                    *list(self._inflight), return_exceptions=True)
-            if not self._pending():
-                return
-
-    def cleanup(self) -> None:
-        if self._flush_task is not None and not self._flush_task.done():
-            self._flush_task.cancel()
-        self._flush_task = None
+        """Drain: force the engine queues to dispatch and wait until none
+        of this task's rows is outstanding and every group has been
+        emitted, so a graceful stop never strands undecoded acks. Re-flush
+        on a short period: a record escalating mid-drain enqueues into a
+        LATER tier's queue after its flush already drained."""
+        while self._cb_rows or self._inflight:
+            for cb in set(self._cbs.values()):
+                cb.flush()
+            if self._inflight:
+                await asyncio.wait(list(self._inflight), timeout=0.05)
+            else:
+                await asyncio.sleep(0.005)

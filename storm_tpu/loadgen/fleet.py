@@ -3,8 +3,8 @@ pattern, scored into one scorecard.
 
 ``run_fleet`` (wired to ``bench.py --fleet``) runs each scenario —
 classify (the reference lenet5 DAG), cascade (confidence-gated tiers on
-the committed digits checkpoints), continuous (per-engine continuous
-batching), serve-path (inference across the gRPC worker boundary) —
+the committed digits checkpoints), serve-path (inference across the
+gRPC worker boundary), decode —
 against each :mod:`storm_tpu.loadgen.trace` pattern (heavy-tail
 tenants, diurnal wave, flash crowd). One cell = one fresh topology +
 one seeded trace replayed against it, with the full protection stack
@@ -42,7 +42,7 @@ from storm_tpu.loadgen.trace import Trace, TraceSpec, generate, replay
 __all__ = ["run_fleet", "SCENARIOS", "PATTERNS"]
 
 PATTERNS = ("heavy_tail", "diurnal", "flash_crowd")
-SCENARIOS = ("classify", "cascade", "continuous", "serve_path", "decode")
+SCENARIOS = ("classify", "cascade", "serve_path", "decode")
 
 #: Offered load as a fraction of the scenario's probed OPEN-LOOP
 #: sustained capacity (see ``_probe_capacity``), where the pattern's
@@ -179,13 +179,11 @@ class _Scenario:
 
 
 class _StandardScenario(_Scenario):
-    """classify / continuous: the reference lenet5 DAG via
-    ``build_standard_topology`` — continuous flips the per-engine
-    continuous-batching queue on, nothing else."""
+    """classify: the reference lenet5 DAG via
+    ``build_standard_topology``."""
 
-    def __init__(self, name: str, continuous: bool) -> None:
-        self.name = name
-        self.continuous = continuous
+    def __init__(self) -> None:
+        self.name = "classify"
         self.payloads = {"s1": _noise_payloads((28, 28, 1), 1),
                          "s8": _noise_payloads((28, 28, 1), 8)}
 
@@ -199,7 +197,6 @@ class _StandardScenario(_Scenario):
         cfg.batch.max_batch = 256
         cfg.batch.max_wait_ms = 10.0
         cfg.batch.buckets = (64, 256)
-        cfg.batch.continuous = self.continuous
         cfg.topology.spout_parallelism = 2
         cfg.topology.inference_parallelism = 1
         cfg.topology.sink_parallelism = 1
@@ -229,7 +226,6 @@ class _CascadeScenario(_StandardScenario):
 
     def __init__(self) -> None:
         self.name = "cascade"
-        self.continuous = False
         root = _repo_root()
         self.ckpts = {n: os.path.join(root, "checkpoints", f"{tag}_digits")
                       for n, tag in (("lenet5", "lenet5_rgb"),
@@ -573,9 +569,7 @@ def _probe_capacity(cluster, sc: _Scenario, slo_ms: float,
 
 def _make_scenarios(which) -> List[_Scenario]:
     all_ = {
-        "classify": lambda: _StandardScenario("classify", continuous=False),
-        "continuous": lambda: _StandardScenario("continuous",
-                                                continuous=True),
+        "classify": _StandardScenario,
         "cascade": _CascadeScenario,
         "serve_path": _ServeScenario,
         "decode": _DecodeScenario,

@@ -811,7 +811,6 @@ def _render_solve(out: dict) -> int:
     print(f"PLAN engine={plan['engine']} bucket={plan['bucket']} "
           f"deadline={plan['deadline_ms']}ms "
           f"parallelism={plan['parallelism']} "
-          f"continuous={plan['continuous']} "
           f"pipeline_depth={plan['pipeline_depth']} "
           f"max_inflight={plan['max_inflight']} "
           f"(replica cost {plan['replica_cost']})")
@@ -1048,9 +1047,6 @@ def main(argv=None) -> int:
     servep.add_argument("--set", action="append", default=[])
     servep.add_argument("--model", default=None, help="model registry name")
     servep.add_argument("--port", type=int, default=50051)
-    servep.add_argument("--cross-batch-ms", type=float, default=0.0,
-                        help="coalesce concurrent Predict RPCs into one "
-                             "device dispatch within this window (0 = off)")
 
     sub.add_parser("info", help="print devices and registered models")
 
@@ -1500,8 +1496,8 @@ def main(argv=None) -> int:
         from storm_tpu.serve import InferenceWorker
 
         enable_compile_cache()
-        worker = InferenceWorker(cfg.model, cfg.sharding, cfg.batch, port=args.port,
-                                 cross_batch_ms=args.cross_batch_ms)
+        worker = InferenceWorker(cfg.model, cfg.sharding, cfg.batch,
+                                 port=args.port)
         worker.start()
         print(f"serving {cfg.model.name} on port {worker.port}", file=sys.stderr)
         try:

@@ -228,8 +228,7 @@ class Observatory:
 
     def occupancy(self) -> List[dict]:
         """Live occupancy per process engine: pipeline-ring slots in use,
-        staging-buffer utilization, and (when continuous batching is on)
-        the engine's queue depth/oldest-age."""
+        staging-buffer utilization, and the engine's queue depth/oldest-age."""
         from storm_tpu.infer.continuous import registry_stats
         from storm_tpu.infer.engine import live_engines
 

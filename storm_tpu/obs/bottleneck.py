@@ -180,7 +180,6 @@ class BottleneckAttributor:
             "leader": leader,
             "ranked": ranked,
             "edges": lag["edges"],
-            "queues": lag["queues"],
             "ingress": lag["ingress"],
             "transport": lag["transport"],
             "critical_path": self.critical_path(),

@@ -10,11 +10,10 @@ The measurement half of the bottleneck observatory (the fusion half is
   control command, and any bench sampler each advance their own cursor,
   so independent consumers never steal each other's deltas.
 - :class:`EdgeLagTracker` — inbox depth AND growth rate per (src -> dst)
-  edge from the routing table, oldest-queued-record age per batching
-  queue (LaneBatcher/MicroBatcher via ``InferenceBolt.batcher_stats``;
-  continuous mode via the engine-queue registry), dist transport
-  outbound depth per peer, and spout ingress lag (cursor vs. available)
-  from ``BrokerSpout.ingress_lag``.
+  edge from the routing table, dist transport outbound depth per peer,
+  and spout ingress lag (cursor vs. available) from
+  ``BrokerSpout.ingress_lag``. (An engine queue's depth and oldest age
+  are ``Observatory.occupancy``'s.)
 - :func:`utilization_snapshot` — the per-process entry point the dist
   worker's ``utilization`` control command calls; the controller merges
   the per-worker results (``dist/controller.merge_utilization``).
@@ -145,14 +144,11 @@ class EdgeLagTracker:
     ``sample()`` returns::
 
         {"edges":   [{edge, src, dst, stream, depth, growth_per_s}],
-         "queues":  [{component, task, pending_rows, oldest_ms}],
          "ingress": [{component, task, records_behind, partitions}],
          "transport": {peer_<idx>: outbound_depth}}
 
     Depth growth is a windowed delta (one cursor per edge; first sample
-    reports ``growth_per_s: None``). ``queues`` covers the per-task
-    admission batchers in BOTH batching modes — continuous engine queues
-    additionally surface through ``Observatory.occupancy``.
+    reports ``growth_per_s: None``).
     """
 
     def __init__(self, runtime, clock=time.monotonic) -> None:
@@ -192,20 +188,6 @@ class EdgeLagTracker:
         for ekey in [k for k in self._prev if k not in seen_edges]:
             del self._prev[ekey]
 
-        queues: List[dict] = []
-        for comp, execs in (getattr(self.rt, "bolt_execs", None) or {}).items():
-            for e in execs:
-                stats_fn = getattr(getattr(e, "bolt", None),
-                                   "batcher_stats", None)
-                if stats_fn is None:
-                    continue
-                try:
-                    st = stats_fn()
-                except Exception:
-                    continue
-                queues.append({"component": comp,
-                               "task": getattr(e, "task_index", 0), **st})
-
         ingress: List[dict] = []
         for comp, execs in (getattr(self.rt, "spout_execs", None) or {}).items():
             for e in execs:
@@ -220,7 +202,7 @@ class EdgeLagTracker:
                 ingress.append({"component": comp,
                                 "task": getattr(e, "task_index", 0), **lag})
 
-        out = {"edges": edges, "queues": queues, "ingress": ingress,
+        out = {"edges": edges, "ingress": ingress,
                "transport": transport_depths(self.rt)}
         self.last = out
         g = getattr(getattr(self.rt, "metrics", None), "gauge", None)

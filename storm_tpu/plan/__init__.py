@@ -7,7 +7,7 @@ observability PRs already measure:
   snapshot (the live singleton or a committed ``PROFILE_*.json``
   baseline) and predicts per-stage latency, throughput, and device
   utilization for one candidate config (bucket, batching deadline,
-  parallelism, continuous on/off, ``pipeline_depth``, ``max_inflight``),
+  parallelism, ``pipeline_depth``, ``max_inflight``),
   including compile-cost amortization for shapes not yet warm.
 - :mod:`storm_tpu.plan.solver` — :func:`solve`: deterministic search
   over candidates for the cheapest config (fewest replicas) meeting a

@@ -6,12 +6,9 @@ number decomposes into terms an operator can check against the live
 histograms with the same names:
 
 - ``batch_wait_ms``: batch-formation wait. A record waits for its batch
-  to fill or for the deadline, whichever ends first; with continuous
-  batching all replicas feed ONE queue (fill rate = offered rate), with
-  the legacy per-operator batcher the stream is split ``parallelism``
-  ways and fills that much slower — the measured fragmentation cliff
-  (BENCH_NOTES round 2, BENCH_CONTBATCH_r10) falls out of the model
-  instead of being a special case.
+  to fill or for the deadline, whichever ends first; all replicas feed
+  the engine's ONE queue, so the fill rate is the offered rate whatever
+  the parallelism.
 - device stages (``h2d_ms``/``compute_ms``/``d2h_ms``/``device_ms``):
   read straight off the profiled (engine, padded bucket) curve; linear
   interpolation between profiled buckets when asked about an unprofiled
@@ -86,7 +83,6 @@ class Candidate:
     bucket: int
     deadline_ms: float  # BatchConfig.max_wait_ms
     parallelism: int = 1  # TopologyConfig.inference_parallelism
-    continuous: bool = True  # BatchConfig.continuous
     pipeline_depth: int = 2  # BatchConfig.pipeline_depth
     max_inflight: int = 2  # BatchConfig.max_inflight
     eager: bool = False  # BatchConfig.eager
@@ -204,16 +200,14 @@ class CostModel:
             raise ValueError("target.rate_rows_s must be > 0")
         eng = cand.engine
         bucket = int(cand.bucket)
-        par = max(1, int(cand.parallelism))
 
-        # batch formation: continuous co-batches all replicas into one
-        # queue; legacy splits the stream and fills parallelism-x slower.
-        fill_rate = rate if cand.continuous else rate / par
-        fill_full_ms = bucket / fill_rate * 1e3
+        # batch formation: all replicas co-batch in the engine's one
+        # queue, so it fills at the offered rate whatever the parallelism
+        fill_full_ms = bucket / rate * 1e3
         window_ms = min(float(cand.deadline_ms), fill_full_ms)
         wait_mean_ms = window_ms / 2.0
         rows_per_batch = max(1.0, min(float(bucket),
-                                      fill_rate * cand.deadline_ms / 1e3))
+                                      rate * cand.deadline_ms / 1e3))
 
         stages = {}
         missing = []
@@ -302,7 +296,6 @@ class CostModel:
         return {"engine": cand.engine, "bucket": int(cand.bucket),
                 "deadline_ms": float(cand.deadline_ms),
                 "parallelism": int(cand.parallelism),
-                "continuous": bool(cand.continuous),
                 "pipeline_depth": int(cand.pipeline_depth),
                 "max_inflight": int(cand.max_inflight),
                 "eager": bool(cand.eager)}

@@ -43,7 +43,6 @@ class Plan:
     bucket: int
     deadline_ms: float
     parallelism: int
-    continuous: bool
     pipeline_depth: int
     max_inflight: int
     eager: bool = False
@@ -61,7 +60,6 @@ class Plan:
                 "max_batch": int(self.bucket),
                 "buckets": [int(self.bucket)],
                 "max_wait_ms": float(self.deadline_ms),
-                "continuous": bool(self.continuous),
                 "pipeline_depth": int(self.pipeline_depth),
                 "max_inflight": int(self.max_inflight),
                 "eager": bool(self.eager),
@@ -98,7 +96,6 @@ class Plan:
             "engine": self.engine, "bucket": int(self.bucket),
             "deadline_ms": float(self.deadline_ms),
             "parallelism": int(self.parallelism),
-            "continuous": bool(self.continuous),
             "pipeline_depth": int(self.pipeline_depth),
             "max_inflight": int(self.max_inflight),
             "eager": bool(self.eager),
@@ -200,34 +197,31 @@ def solve(snapshot: dict, target: Target, *, engine: Optional[str] = None,
         deadlines = sorted(set(DEADLINES_MS) | {round(fill_ms, 3)})
         for deadline in deadlines:
             for par in range(1, max(1, int(max_parallelism)) + 1):
-                for continuous in (True, False):
-                    for depth in (2, 0):
-                        for inflight in (2, 1):
-                            considered += 1
-                            cand = Candidate(
-                                engine=engine, bucket=bucket,
-                                deadline_ms=deadline, parallelism=par,
-                                continuous=continuous,
-                                pipeline_depth=depth,
-                                max_inflight=inflight)
-                            pred = model.evaluate(cand, target)
-                            if pred["feasible"]:
-                                key = (
-                                    par,
-                                    pred["amortized_compile_ms_per_row"] > 0,
-                                    pred["p99_ms"],
-                                    -pred["capacity_rows_s"],
-                                    bucket, deadline, not continuous,
-                                    depth, inflight)
-                                feasible.append((key, cand, pred))
-                            else:
-                                cap = pred.get("capacity_rows_s", 0.0) or 0.0
-                                p99 = pred.get("p99_ms")
-                                ikey = (-cap, p99 if p99 is not None
-                                        else float("inf"))
-                                if best_inf_key is None or ikey < best_inf_key:
-                                    best_inf_key = ikey
-                                    best_inf = pred
+                for depth in (2, 0):
+                    for inflight in (2, 1):
+                        considered += 1
+                        cand = Candidate(
+                            engine=engine, bucket=bucket,
+                            deadline_ms=deadline, parallelism=par,
+                            pipeline_depth=depth,
+                            max_inflight=inflight)
+                        pred = model.evaluate(cand, target)
+                        if pred["feasible"]:
+                            key = (
+                                par,
+                                pred["amortized_compile_ms_per_row"] > 0,
+                                pred["p99_ms"],
+                                -pred["capacity_rows_s"],
+                                bucket, deadline, depth, inflight)
+                            feasible.append((key, cand, pred))
+                        else:
+                            cap = pred.get("capacity_rows_s", 0.0) or 0.0
+                            p99 = pred.get("p99_ms")
+                            ikey = (-cap, p99 if p99 is not None
+                                    else float("inf"))
+                            if best_inf_key is None or ikey < best_inf_key:
+                                best_inf_key = ikey
+                                best_inf = pred
 
     if not feasible:
         why = (best_inf or {}).get("why") or (
@@ -242,7 +236,7 @@ def solve(snapshot: dict, target: Target, *, engine: Optional[str] = None,
     plan = Plan(
         engine=cand.engine, bucket=cand.bucket,
         deadline_ms=cand.deadline_ms, parallelism=cand.parallelism,
-        continuous=cand.continuous, pipeline_depth=cand.pipeline_depth,
+        pipeline_depth=cand.pipeline_depth,
         max_inflight=cand.max_inflight, eager=cand.eager,
         replica_cost=cand.parallelism, prediction=pred,
         target=target.to_dict())

@@ -8,14 +8,14 @@ layer in front of the engine that InferLine/BatchGen argue for
 (PAPERS.md): admission at the edge, priority-aware batch formation, and
 load shedding that fires *before* the autoscaler.
 
-Three pieces, wired by ``QosConfig`` (config.py):
+Wired by ``QosConfig`` (config.py):
 
 - :mod:`storm_tpu.qos.admission` — per-tenant token-bucket rate limiting
   and tenant/lane classification at the spout edge (records ride their
   broker key as ``tenant:lane``);
-- :mod:`storm_tpu.qos.lanes` — earliest-deadline-first batch formation
-  for the inference operator: high-priority records preempt queued
-  best-effort ones instead of FIFO-queuing behind them;
+- earliest-deadline-first batch formation lives in the engine's queue
+  (:mod:`storm_tpu.infer.continuous`): high-priority records preempt
+  queued best-effort ones instead of FIFO-queuing behind them;
 - :mod:`storm_tpu.qos.shedding` — hysteresis load-shed controller driven
   by inference inbox depth, batch-wait time, and the sink's SLO-breach
   rate; publishes its level through the metrics registry (gauge
@@ -24,7 +24,6 @@ Three pieces, wired by ``QosConfig`` (config.py):
 """
 
 from storm_tpu.qos.admission import AdmissionController, TokenBucket
-from storm_tpu.qos.lanes import LaneBatcher
 from storm_tpu.qos.shedding import LoadShedController, ShedPolicy
 
 #: The metrics-registry address every QoS participant reads/writes the
@@ -34,7 +33,6 @@ SHED_GAUGE = "shed_level"
 
 __all__ = [
     "AdmissionController",
-    "LaneBatcher",
     "LoadShedController",
     "SHED_COMPONENT",
     "SHED_GAUGE",

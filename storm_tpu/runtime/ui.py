@@ -416,10 +416,10 @@ class UIServer:
                     out["decisions"] = [
                         {"direction": d, "from": a, "to": b}
                         for d, a, b in shedder.decisions]
-                # Continuous-batching fairness: per-engine queue state with
+                # Batching fairness: per-engine queue state with
                 # fair_rows/fair_starved per tenant:lane key and the batch
                 # fill median — shed decisions and batching fairness read
-                # from one place. Empty when continuous batching is off.
+                # from one place.
                 from storm_tpu.infer.continuous import registry_stats
 
                 out["continuous"] = await asyncio.to_thread(registry_stats)

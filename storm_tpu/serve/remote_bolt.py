@@ -5,18 +5,14 @@ reference architecture a JVM Storm bolt) keeps tuple-ack semantics while
 batches cross a localhost gRPC + Arrow boundary to the TPU worker process.
 
 Identical streaming behavior to :class:`storm_tpu.infer.InferenceBolt`
-(micro-batching, deferred acks, dead-lettering); only the engine call is
-remote."""
+(batches cut from the engine facade's queue, deferred acks,
+dead-lettering); only the engine call is remote."""
 
 from __future__ import annotations
 
-import asyncio
-import time
-from typing import Optional, Set
+from typing import Optional
 
-from storm_tpu.api.schema import DeadLetter, SchemaError, decode_instances, encode_predictions
 from storm_tpu.config import BatchConfig
-from storm_tpu.infer.batcher import Batch, MicroBatcher
 from storm_tpu.infer.operator import InferenceBolt
 from storm_tpu.runtime.base import TopologyContext, OutputCollector
 from storm_tpu.serve.client import InferenceClient
@@ -32,7 +28,7 @@ class RemoteInferenceBolt(InferenceBolt):
         passthrough=(),
     ) -> None:
         # qos/passthrough forward unchanged: EDF lane formation and the
-        # qos_lane ride-through happen in the batcher/operator layer,
+        # qos_lane ride-through happen in the queue/operator layer,
         # which is identical on both sides of the gRPC boundary — the
         # fleet scorecard's serve-path cells need per-lane e2e histograms
         # from a remote topology too.
@@ -68,5 +64,4 @@ class RemoteInferenceBolt(InferenceBolt):
         super().prepare(context, collector)
 
     def cleanup(self) -> None:
-        super().cleanup()
         self.client.close()

@@ -32,6 +32,7 @@ import numpy as np
 
 from storm_tpu.api.schema import SchemaError, decode_instances, encode_predictions
 from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
+from storm_tpu.infer.continuous import continuous_for
 from storm_tpu.infer.engine import InferenceEngine, shared_engine
 from storm_tpu.serve.marshal import decode_tensor, encode_tensor
 
@@ -65,29 +66,18 @@ class InferenceWorker:
         engine: Optional[InferenceEngine] = None,
         port: int = 50051,
         max_workers: int = 8,
-        cross_batch_ms: float = 0.0,
     ) -> None:
         self.model_cfg = model or ModelConfig()
+        batch = batch or BatchConfig()
         self.engine = engine or shared_engine(
-            self.model_cfg, sharding or ShardingConfig(), batch or BatchConfig()
-        )
-        # cross_batch_ms > 0: coalesce concurrent Predict RPCs from different
-        # callers into one device dispatch (serve/batcher.py). Off by default
-        # — single-caller deployments shouldn't pay the window latency.
-        # batch.continuous routes RPCs into the engine's shared continuous
-        # queue instead, where they co-batch with topology traffic on the
-        # same slot schedule (no leader window at all).
-        self._batcher = None
-        bc = batch or BatchConfig()
-        if getattr(bc, "continuous", False):
-            from storm_tpu.serve.batcher import CrossCallerBatcher
-
-            self._batcher = CrossCallerBatcher(
-                self.engine, continuous=True, batch_cfg=bc)
-        elif cross_batch_ms > 0:
-            from storm_tpu.serve.batcher import CrossCallerBatcher
-
-            self._batcher = CrossCallerBatcher(self.engine, window_ms=cross_batch_ms)
+            self.model_cfg, sharding or ShardingConfig(), batch)
+        # Every RPC submits its rows into the engine's one queue
+        # (infer/continuous.py) and blocks on its own slice: concurrent
+        # callers — several JVM Storm executors dispatching to one
+        # co-located TPU worker, the north-star deployment — coalesce into
+        # one device batch there, with a topology's traffic if the process
+        # hosts one.
+        self._queue = continuous_for(self.engine, batch)
         self._server = grpc.server(
             futures.ThreadPoolExecutor(max_workers=max_workers),
             options=[
@@ -119,9 +109,7 @@ class InferenceWorker:
         return encode_tensor(out)
 
     def _run_predict(self, x: np.ndarray) -> np.ndarray:
-        if self._batcher is not None:
-            return self._batcher.predict(x)
-        return self.engine.predict(x)
+        return self._queue.submit(x, source="serve").future.result()
 
     def _predict_json(self, request: bytes, context: grpc.ServicerContext) -> bytes:
         try:

@@ -88,17 +88,19 @@ def test_merge_offsets_max_wins():
 
 
 def test_stage_list_matches_operator_histograms():
-    """bench.STAGES must reference histograms the operator/sink actually
-    record — a renamed metric would silently drop a stage from the
-    decomposition artifact."""
+    """bench.STAGES must reference histograms the operator, the engine's
+    queue or the sink actually record — a renamed metric would silently
+    drop a stage from the decomposition artifact."""
     import inspect
 
     from storm_tpu.connectors import sink as sink_mod
+    from storm_tpu.infer import continuous as queue_mod
     from storm_tpu.infer import operator as op_mod
 
     from storm_tpu.runtime.tracing import DEVICE_SUBSTAGES
 
-    source = inspect.getsource(op_mod) + inspect.getsource(sink_mod)
+    source = (inspect.getsource(op_mod) + inspect.getsource(queue_mod)
+              + inspect.getsource(sink_mod))
     substage_keys = {key for key, _ in DEVICE_SUBSTAGES}
     for comp, hist, _label in bench.STAGES:
         if hist in substage_keys:

@@ -209,24 +209,20 @@ def test_capacity_tracker_sums_tasks_and_drops_removed():
 # ---- EdgeLagTracker ----------------------------------------------------------
 
 
-def test_edge_lag_growth_and_queue_and_ingress_rows():
+def test_edge_lag_growth_and_ingress_rows():
     clock = FakeClock()
     edge = _edge("spout", "bolt", depth=10)
-    bolt = SimpleNamespace(batcher_stats=lambda: {
-        "pending_rows": 7, "depth": 3, "oldest_ms": 12.5,
-        "continuous": False})
     spout = SimpleNamespace(ingress_lag=lambda: {
         "records_behind": 100, "partitions": 4})
     rt = SimpleNamespace(
         metrics=MetricsRegistry(), router=FakeRouter([edge]),
-        bolt_execs={"bolt": [_fake_exec(bolt=bolt)]},
+        bolt_execs={},
         spout_execs={"spout": [_fake_exec(spout=spout)]})
     tr = EdgeLagTracker(rt, clock=clock)
 
     out = tr.sample()
     assert out["edges"][0]["depth"] == 10
     assert out["edges"][0]["growth_per_s"] is None  # first sample: no slope
-    assert out["queues"][0]["pending_rows"] == 7
     assert out["ingress"][0]["records_behind"] == 100
     assert out["transport"] == {}  # single-host: no peer senders
 
@@ -431,36 +427,34 @@ def test_utilization_snapshot_caches_tracker_on_runtime():
     assert "b" in out["components"]
 
 
-# ---- batcher stats parity (legacy LaneBatcher satellite) ---------------------
+# ---- the engine queue's stats: what obs/ and the /qos route read -------------
 
 
-def test_micro_and_lane_batcher_stats_share_one_shape():
-    from storm_tpu.infer.batcher import MicroBatcher
-    from storm_tpu.qos.lanes import LaneBatcher
+def test_engine_queue_stats_carry_the_keys_obs_reads():
+    from storm_tpu.infer.continuous import ContinuousBatcher
 
-    bcfg = BatchConfig(max_batch=64, max_wait_ms=1000.0)
-    fifo = MicroBatcher(bcfg)
-    lane = LaneBatcher(bcfg, QosConfig(enabled=True))
+    class _Engine:
+        ring_capacity = 2
 
-    empty_keys = {"kind", "pending_rows", "depth", "oldest_ms",
-                  "pending_by_lane"}
-    assert set(fifo.stats()) == empty_keys
-    assert set(lane.stats()) == empty_keys
-    assert fifo.stats()["oldest_ms"] == 0.0
-    assert lane.stats()["oldest_ms"] == 0.0
+    engine = _Engine()  # the queue holds its engine weakly
+    cb = ContinuousBatcher(
+        engine, BatchConfig(max_batch=64, max_wait_ms=1000.0),
+        QosConfig(enabled=True))
+    cb._ensure_thread_locked = lambda: None  # nothing dispatches here
+    st = cb.stats()
+    # Observatory.occupancy joins on "engine" and reads pending_rows and
+    # oldest_ms; the /qos route shows the rest
+    assert {"engine", "capacity", "inflight", "pending_rows", "oldest_ms",
+            "pending_by_key", "batches", "rows", "batch_fill_p50",
+            "fair_rows", "fair_starved", "last_batch"} <= set(st)
+    assert st["pending_rows"] == 0 and st["oldest_ms"] == 0.0
+    assert st["engine"] == "_Engine" and st["capacity"] == 2
 
-    fifo.add("p", np.zeros((2, 4), dtype=np.float32))
-    lane.add("p", np.zeros((2, 4), dtype=np.float32), lane="interactive")
-    lane.add("q", np.zeros((3, 4), dtype=np.float32))  # default lane
-
-    st = fifo.stats()
-    assert st["kind"] == "fifo" and st["pending_rows"] == 2
-    assert st["depth"] == 1 and st["oldest_ms"] >= 0.0
-
-    st = lane.stats()
-    assert st["kind"] == "lane" and st["pending_rows"] == 5
-    assert st["depth"] == 2
-    assert st["pending_by_lane"] == {"interactive": 2, "": 3}
+    cb.submit(np.zeros((2, 4), np.float32), lane="high", tenant="gold")
+    cb.submit(np.zeros((3, 4), np.float32))  # default tenant and lane
+    st = cb.stats()
+    assert st["pending_rows"] == 5 and st["oldest_ms"] >= 0.0
+    assert st["pending_by_key"] == {"gold:high": 2, "default:normal": 3}
 
 
 # ---- spout ingress lag -------------------------------------------------------

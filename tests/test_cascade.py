@@ -194,20 +194,18 @@ def test_deterministic_accept_escalate_split(run, monkeypatch):
     async def go():
         cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
                             thresholds=(0.5,))
-        # continuous=False: the per-task path, whose batch-level decide()
-        # re-batches one batch's residue as ONE flagship batch — the call
-        # sizes asserted below (the default path's residues join the next
-        # tier's shared queue one record at a time, test_continuous.py)
         bolt, coll, engines = _cascade_bolt(
             monkeypatch, cas, max_batch=4, max_wait_ms=10_000,
-            max_inflight=1, continuous=False)
+            max_inflight=1)
         # Two confident records (u = 1-0.9 = 0.1 < 0.5: accept at tier 0)
         # and two unconfident (u = 0.8: escalate to the flagship).
         for c in (0.9, 0.2, 0.9, 0.2):
             await bolt.execute(_tuple(_conf_payload(c)))
         await bolt.flush()
-        assert engines["lenet5"].calls == [4]
-        assert engines["resnet20"].calls == [2], \
+        # rows per tier, however each tier's queue cut them into batches
+        # (residues join the flagship's queue one record at a time)
+        assert sum(engines["lenet5"].calls) == 4
+        assert sum(engines["resnet20"].calls) == 2, \
             "only the low-confidence residue reaches the flagship"
         assert len(coll.acked) == 4 and not coll.failed
         assert sorted(_argmaxes(coll)) == [0, 0, 1, 1], \
@@ -216,8 +214,8 @@ def test_deterministic_accept_escalate_split(run, monkeypatch):
         assert m["cascade_accepted_tier0"] == 2
         assert m["cascade_accepted_tier1"] == 2
         assert m["cascade_escalations"] == 2
-        assert m["tier0_device_ms"]["count"] == 1
-        assert m["tier1_device_ms"]["count"] == 1
+        assert m["device_ms"]["count"] == len(
+            engines["lenet5"].calls + engines["resnet20"].calls)
         rate = bolt.context.metrics.snapshot()["cascade"]["escalation_rate"]
         assert rate == pytest.approx(0.5)
 
@@ -246,15 +244,13 @@ def test_threshold_zero_is_flagship_only(run, monkeypatch):
     async def go():
         cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
                             thresholds=(0.0,))
-        # continuous=False: one flagship call of 4 is the per-task path's
-        # batch-level escalation (see the split test above)
         bolt, coll, engines = _cascade_bolt(
             monkeypatch, cas, max_batch=4, max_wait_ms=10_000,
-            max_inflight=1, continuous=False)
+            max_inflight=1)
         for c in (0.999, 0.999, 0.999, 0.999):  # max confidence, still out
             await bolt.execute(_tuple(_conf_payload(c)))
         await bolt.flush()
-        assert engines["resnet20"].calls == [4]
+        assert sum(engines["resnet20"].calls) == 4
         assert len(coll.acked) == 4 and _argmaxes(coll) == [1, 1, 1, 1], \
             "threshold=0 must be identical to flagship-only"
 
@@ -370,8 +366,10 @@ def test_degrade_model_synthesizes_shed_only_cascade(run, monkeypatch):
 
 
 def test_escalation_survives_max_inflight_one(run, monkeypatch):
-    """Escalation dispatch happens while _run_batch still HOLDS the single
-    dispatch slot — it must spawn, not await, or tier 1 deadlocks."""
+    """An escalation never parks behind the row bound its own completion
+    frees: with ``max_inflight=1`` the task is AT its bound (one full
+    batch outstanding) when the residue of that batch goes on to tier 1 —
+    ``_enqueue`` must not wait, or tier 1 deadlocks."""
 
     async def go():
         cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
@@ -448,13 +446,73 @@ def test_router_inventory_attributes_tiers():
                         thresholds=(0.3,))
     router = CascadeRouter(cas)
     router.build(ModelConfig(name="resnet20", input_shape=SHAPE),
-                 None, BatchConfig(max_batch=4),
                  build_engine=lambda mc: _ConfEngine(0))
     inv = router.inventory()
     assert [r["model"] for r in inv] == ["lenet5", "resnet20"]
     assert inv[0]["threshold"] == pytest.approx(0.3)
     assert inv[1]["threshold"] is None  # the flagship always accepts
     assert all(r["pending_records"] == 0 for r in inv)
+
+
+def test_router_inventory_counts_rows_waiting_in_a_tiers_queue():
+    """``pending_records`` is what waits in the tier engine's own queue
+    (the router keeps no batcher of its own)."""
+    from storm_tpu.cascade.router import CascadeRouter
+    from storm_tpu.infer.continuous import continuous_for
+
+    cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
+                        thresholds=(0.3,))
+    router = CascadeRouter(cas)
+    router.build(ModelConfig(name="resnet20", input_shape=SHAPE),
+                 build_engine=lambda mc: _ConfEngine(0))
+    cb = continuous_for(router.tiers[1].engine,
+                        BatchConfig(max_batch=8, max_wait_ms=60_000))
+    cb._ensure_thread_locked = lambda: None  # nothing dispatches here
+    cb.submit(np.zeros((3, *SHAPE), np.float32))
+    assert [r["pending_records"] for r in router.inventory()] == [0, 3]
+
+
+def _conf_rows(cs, tag=0):
+    out = np.zeros((len(cs), 10), np.float32)
+    for i, c in enumerate(cs):
+        out[i] = (1.0 - c) / 9.0
+        out[i, tag] = c
+    return out
+
+
+@pytest.mark.parametrize("case,cfg_kw,lane,level,confs,want", [
+    ("accept", {}, None, 0, (0.9, 0.9),
+     {"accepted": 2, "escalated": 0, "pinned": 0, "budget_capped": 0}),
+    ("escalate", {}, None, 0, (0.9, 0.2, 0.2),
+     {"accepted": 1, "escalated": 2, "pinned": 0, "budget_capped": 0}),
+    ("shed_pinned", {}, "best_effort", 1, (0.2, 0.2),
+     {"accepted": 2, "escalated": 0, "pinned": 2, "budget_capped": 0}),
+    ("budget_capped", {"escalation_budget": 0.0}, None, 0, (0.2, 0.9),
+     {"accepted": 2, "escalated": 0, "pinned": 0, "budget_capped": 1}),
+])
+def test_decide_item_info_counts_rows(case, cfg_kw, lane, level, confs, want):
+    """``decide_item`` is the one cascade decision: its ``info`` counts
+    the record's ROWS by outcome, and a residue holds exactly the rows
+    that escalate."""
+    from storm_tpu.cascade.router import CascadeRouter
+
+    cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
+                        thresholds=(0.5,), **cfg_kw)
+    qos = QosConfig(enabled=True)
+    router = CascadeRouter(cas, qos=qos)
+    router.build(ModelConfig(name="resnet20", input_shape=SHAPE),
+                 build_engine=lambda mc: _ConfEngine(0))
+    data = np.stack([np.full(SHAPE, c, np.float32) for c in confs])
+    merged, residue, info = router.decide_item(
+        "record", data, _conf_rows(confs), lane, 0, level, ts=1.0)
+    assert info == want
+    if want["escalated"]:
+        assert merged is None
+        assert residue.data.shape[0] == want["escalated"]
+        assert (residue.lane, residue.ts) == (lane, 1.0)
+        assert residue.payload.payload == "record"
+    else:
+        assert residue is None and merged.shape == (len(confs), 10)
 
 
 def _make_conf_spout():

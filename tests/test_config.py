@@ -47,6 +47,19 @@ def test_unknown_key_rejected():
         Config.from_dict({"nope": {}})
 
 
+def test_removed_batch_continuous_is_an_unknown_key():
+    """The ``continuous`` key of ``[batch]`` is gone with the per-task
+    path it selected (docs/MIGRATION.md): a config that still sets it fails like any
+    unknown key, and no alias remains on the dataclass."""
+    from storm_tpu.config import BatchConfig
+
+    with pytest.raises(KeyError, match="unknown config key 'continuous'"):
+        Config.from_dict({"batch": {"continuous": False}})
+    with pytest.raises(TypeError):
+        BatchConfig(**{"continuous": True})
+    assert not hasattr(BatchConfig(), "continuous")
+
+
 def test_invalid_enum_values():
     with pytest.raises(ValueError):
         OffsetsConfig(policy="bogus")

@@ -1,15 +1,15 @@
-"""Per-engine continuous batching (ISSUE 6 tentpole + satellites).
+"""The engine's one queue, where every batch is formed.
 
 Covers the slot-level queue itself (refill-on-free dispatch, idle
-deadline aging, EDF + weighted-round-robin formation with the starvation
-bound, LaneBatcher preemption parity), the cross-source guarantees
-(serve + topology traffic co-batching into ONE dispatched batch,
-exactly-once per source when a coalesced batch fails), the cascade
-integration (escalation residues ride the next tier's continuous queue,
-per-tier counters intact), the per-engine registry lifecycle (identity,
-close-on-eviction), and the batch_fill/coalesced_sources fragmentation
-metrics on BOTH dispatch paths (the legacy deadline path needs the
-metric too — it is the A/B baseline).
+deadline aging and eager dispatch, the formation rules: ``max_batch``,
+first in first out within a key, EDF + weighted round-robin across keys
+with the starvation bound), a record's own rows coming back to it, the
+cross-source guarantees (serve + topology traffic co-batching into ONE
+dispatched batch, exactly-once per source when a coalesced batch fails),
+the operator's row bound, the cascade integration (escalation residues
+ride the next tier's queue, per-tier counters intact), the per-engine
+registry lifecycle (identity, close-on-eviction), and the
+batch_fill/coalesced_sources metrics.
 """
 
 from __future__ import annotations
@@ -31,10 +31,9 @@ from storm_tpu.infer.continuous import (
     registry_stats)
 from storm_tpu.infer.engine import InflightBatch
 from storm_tpu.infer.operator import InferenceBolt
-from storm_tpu.qos.lanes import LaneBatcher
 from storm_tpu.runtime.base import TopologyContext
 from storm_tpu.runtime.metrics import MetricsRegistry
-from storm_tpu.serve.batcher import CrossCallerBatcher
+from storm_tpu.serve.worker import InferenceWorker
 
 from tests.test_cascade import _cascade_bolt, _conf_payload, _argmaxes
 from tests.test_pipeline import _Collector, _payload, _tuple
@@ -110,8 +109,7 @@ def test_slot_refill_on_free_dispatches_immediately():
     10s deadline, only the refill path can explain the second batch."""
     eng = _SlotEngine(capacity=1)
     cb = continuous_for(eng, BatchConfig(
-        max_batch=8, buckets=(8,), max_wait_ms=10_000, eager=True,
-        continuous=True))
+        max_batch=8, buckets=(8,), max_wait_ms=10_000, eager=True))
     a = cb.submit(_rows(), source="s1")
     t0 = time.perf_counter()
     while len(eng.handles) < 1:
@@ -138,12 +136,11 @@ def test_slot_refill_on_free_dispatches_immediately():
 
 
 def test_idle_non_eager_ages_to_deadline():
-    """Trickle traffic on an idle device keeps the deadline batcher's
-    latency floor: no eager dispatch, the row ships at ~max_wait_ms."""
+    """Trickle traffic on an idle device waits ``max_wait_ms`` for
+    company: no eager dispatch, the row ships at about the deadline."""
     eng = _SlotEngine(capacity=1)
     cb = continuous_for(eng, BatchConfig(
-        max_batch=8, buckets=(8,), max_wait_ms=50.0, eager=False,
-        continuous=True))
+        max_batch=8, buckets=(8,), max_wait_ms=50.0, eager=False))
     sub = cb.submit(_rows(), source="s1")
     time.sleep(0.01)
     assert not eng.handles, "idle + non-eager must wait for the deadline"
@@ -155,7 +152,24 @@ def test_idle_non_eager_ages_to_deadline():
     assert sub.future.result(timeout=5).shape == (1, 10)
 
 
-# ---- formation: fairness, starvation, preemption parity ----------------------
+def test_idle_eager_dispatches_on_arrival():
+    """``eager=True``: an idle device takes the first row at once, a
+    minute before its deadline, and alone (nothing else had arrived)."""
+    eng = _SlotEngine(capacity=2)
+    cb = continuous_for(eng, BatchConfig(
+        max_batch=8, buckets=(8,), max_wait_ms=60_000, eager=True))
+    sub = cb.submit(_rows(), source="s1")
+    t0 = time.perf_counter()
+    while not eng.handles:
+        assert time.perf_counter() - t0 < 5.0, \
+            "an idle eager device must not age the row to its deadline"
+        time.sleep(0.002)
+    assert eng.sizes[0] == [1]
+    _resolve(eng.handles[0])
+    assert sub.future.result(timeout=5).shape == (1, 10)
+
+
+# ---- formation: fairness, starvation, preemption, max_batch ----------------------
 
 
 def _manual_cb(cfg, qos=None):
@@ -210,26 +224,68 @@ def test_tenant_fairness_starvation_bound():
     assert cb.fair_rows[("flood", "normal")] == 5  # 2 + 2 + 1
 
 
-def test_lane_preemption_parity_with_lane_batcher():
-    """Same arrivals, same formation order: a fresh high-priority record
-    preempts queued best-effort in the continuous queue exactly as it
-    did in the LaneBatcher's EDF heap."""
+def test_fresh_high_priority_record_preempts_queued_best_effort():
+    """EDF across lanes: a high-priority record that arrives last leads
+    the batch, ahead of the best-effort records queued before it, which
+    keep their own arrival order."""
     qos = QosConfig(enabled=True)
     t = time.perf_counter()
-    arrivals = [("p0", "best_effort"), ("p1", "best_effort"),
-                ("p2", "high")]
-    lb = LaneBatcher(BatchConfig(max_batch=3, buckets=(3,)), qos)
-    lb_batch = None
-    for name, lane in arrivals:
-        got = lb.add(name, _rows(), ts=t, lane=lane)
-        lb_batch = got or lb_batch
-    assert lb_batch is not None
     cb = _manual_cb(BatchConfig(max_batch=3, buckets=(3,)), qos)
-    for name, lane in arrivals:
+    for name, lane in (("p0", "best_effort"), ("p1", "best_effort"),
+                       ("p2", "high")):
         _enqueue(cb, 1, lane, None, t, payload=name)
-    cb_batch = cb._form_locked()
-    assert [it.payload for it in lb_batch.items] == \
-        [s.payload for s in cb_batch] == ["p2", "p0", "p1"]
+    assert [s.payload for s in cb._form_locked()] == ["p2", "p0", "p1"]
+
+
+@pytest.mark.parametrize("sizes,max_batch,batches", [
+    # a record of more rows than max_batch still ships, alone
+    ([20], 8, [[20]]),
+    # no batch exceeds max_batch: a record that would overshoot waits
+    ([6, 3, 20, 2], 8, [[6], [3], [20], [2]]),
+    # rows beyond max_batch stay for the next batch
+    ([1, 1, 1], 2, [[1, 1], [1]]),
+    # first in, first out within one tenant:lane key
+    ([2, 1, 3, 1, 1], 4, [[2, 1], [3, 1], [1]]),
+], ids=["oversized_ships_alone", "never_over_max_batch",
+        "leftovers_stay", "fifo_within_a_key"])
+def test_formation_respects_max_batch_and_arrival_order(
+        sizes, max_batch, batches):
+    cb = _manual_cb(BatchConfig(max_batch=max_batch, buckets=(max_batch,)))
+    t = time.perf_counter()
+    for i, rows in enumerate(sizes):
+        _enqueue(cb, rows, None, None, t, payload=i)
+    formed = []
+    while len(cb):
+        formed.append(cb._form_locked())
+    assert [[s.rows for s in b] for b in formed] == batches
+    assert [s.payload for b in formed for s in b] == list(range(len(sizes)))
+
+
+def test_records_of_unequal_rows_each_get_back_their_own_rows():
+    """One device batch, three records of 3, 1 and 4 rows from two
+    sources: every future resolves to exactly its record's slice of the
+    batch's output, in the record's own row order."""
+    eng = _SlotEngine(capacity=1)
+    cb = continuous_for(eng, BatchConfig(
+        max_batch=8, buckets=(8,), max_wait_ms=10_000, eager=True))
+    hold = cb.submit(_rows(), source="hold")  # occupies the only slot
+    t0 = time.perf_counter()
+    while len(eng.handles) < 1:
+        assert time.perf_counter() - t0 < 5.0
+        time.sleep(0.002)
+    subs = [cb.submit(_rows(n), source=src)
+            for n, src in ((3, "s1"), (1, "s2"), (4, "s1"))]
+    _resolve(eng.handles[0])
+    while len(eng.handles) < 2:
+        assert time.perf_counter() - t0 < 5.0
+        time.sleep(0.002)
+    assert eng.sizes[1] == [3, 1, 4]
+    out = np.arange(8 * 10, dtype=np.float32).reshape(8, 10)
+    eng.handles[1].future.set_result(out)
+    np.testing.assert_array_equal(subs[0].future.result(timeout=5), out[0:3])
+    np.testing.assert_array_equal(subs[1].future.result(timeout=5), out[3:4])
+    np.testing.assert_array_equal(subs[2].future.result(timeout=5), out[4:8])
+    hold.future.result(timeout=1)
 
 
 # ---- cross-source guarantees -------------------------------------------------
@@ -241,17 +297,16 @@ def test_serve_and_topology_traffic_cobatch(run):
     async def go():
         eng = _SlotEngine(capacity=1)
         bolt, coll = _bolt(eng, max_batch=8, buckets=(8,),
-                           max_wait_ms=10_000, eager=True, continuous=True)
+                           max_wait_ms=10_000, eager=True)
         cb = bolt._cbs[None]
         warm = cb.submit(_rows(), source="warm")  # occupy the only slot
         await _until(lambda: len(eng.handles) == 1)
         await bolt.execute(_tuple(_payload()))
-        serve = CrossCallerBatcher(eng, continuous=True,
-                                   batch_cfg=bolt.batch_cfg)
+        serve = InferenceWorker(engine=eng, batch=bolt.batch_cfg, port=0)
         out_box = {}
         th = threading.Thread(
             target=lambda: out_box.setdefault(
-                "out", serve.predict(_rows(1, 0.5))))
+                "out", serve._run_predict(_rows(1, 0.5))))
         th.start()
         await _until(lambda: len(cb) == 2,
                      msg="bolt + serve rows must both be queued")
@@ -283,11 +338,9 @@ def test_exactly_once_per_source_on_coalesced_batch_failure(run):
         eng = _SlotEngine(capacity=1)
         m = MetricsRegistry()
         b1, c1 = _bolt(eng, metrics=m, task_index=0, max_batch=8,
-                       buckets=(8,), max_wait_ms=10_000, eager=True,
-                       continuous=True)
+                       buckets=(8,), max_wait_ms=10_000, eager=True)
         b2, c2 = _bolt(eng, metrics=m, task_index=1, max_batch=8,
-                       buckets=(8,), max_wait_ms=10_000, eager=True,
-                       continuous=True)
+                       buckets=(8,), max_wait_ms=10_000, eager=True)
         assert b1._cbs[None] is b2._cbs[None], \
             "replicas sharing an engine share ONE queue"
         cb = b1._cbs[None]
@@ -327,20 +380,77 @@ def test_exactly_once_per_source_on_coalesced_batch_failure(run):
     run(go(), timeout=60)
 
 
+# ---- the operator's row bound -------------------------------------------------
+
+
+@pytest.mark.parametrize("how", ["finishes", "emit_raises", "emit_cancelled"])
+def test_row_bound_parks_the_task_and_every_group_gives_its_rows_back(
+        run, how):
+    """A task with ``max_inflight * max_batch`` rows outstanding parks its
+    next record and resumes when a group finishes — also when the group's
+    emit raises or its task is cancelled mid-emit: the rows come back in a
+    ``finally``, or the bound would shrink for good."""
+    async def go():
+        eng = _SlotEngine(capacity=1)
+        bolt, coll = _bolt(eng, max_batch=2, buckets=(2,),
+                           max_wait_ms=10_000, max_inflight=1)
+        first = [_tuple(_payload()), _tuple(_payload())]
+        for t in first:
+            await bolt.execute(t)
+        await _until(lambda: len(eng.handles) == 1)
+        assert bolt._cb_rows == bolt._cb_cap == 2
+        third = _tuple(_payload())
+        parked = asyncio.get_running_loop().create_task(bolt.execute(third))
+        await asyncio.sleep(0.05)
+        assert not parked.done(), "at its bound the task must park"
+        assert len(bolt._cbs[None]) == 0, "and submit nothing meanwhile"
+        plain_emit = coll.emit
+        entered = asyncio.Event()
+
+        async def bad_emit(*a, **kw):
+            entered.set()
+            if how == "emit_raises":
+                raise RuntimeError("collector fault")
+            await asyncio.Event().wait()  # held until cancelled
+
+        if how != "finishes":
+            coll.emit = bad_emit
+        _resolve(eng.handles[0])
+        if how == "emit_cancelled":
+            await asyncio.wait_for(entered.wait(), 5)
+            (group,) = bolt._inflight
+            group.cancel()
+        await asyncio.wait_for(parked, 5)  # the freed rows let it in
+        coll.emit = plain_emit
+        assert bolt._cb_rows == 1, "only the third record is outstanding"
+        flush = asyncio.get_running_loop().create_task(bolt.flush())
+        await _until(lambda: len(eng.handles) == 2)
+        _resolve(eng.handles[1])
+        await flush
+        assert bolt._cb_rows == 0 and not bolt._inflight
+        done = {"finishes": (first + [third], []),
+                "emit_raises": ([third], first),
+                # neither acked nor failed: the tree times out and replays
+                "emit_cancelled": ([third], [])}[how]
+        assert [id(t) for t in coll.acked] == [id(t) for t in done[0]]
+        assert [id(t) for t in coll.failed] == [id(t) for t in done[1]]
+
+    run(go(), timeout=60)
+
+
 # ---- cascade integration -----------------------------------------------------
 
 
 def test_cascade_residue_rides_continuous_queue(run, monkeypatch):
-    """Satellite: escalations enqueue into the NEXT tier's continuous
-    queue instead of a per-bolt micro-batcher; accepts/escalations,
-    per-tier counters, and which-tier-answered argmaxes match the
-    batch-path cascade test exactly."""
+    """Escalations enqueue into the NEXT tier's queue; accepts and
+    escalations, per-tier counters and which-tier-answered argmaxes are
+    those of tests/test_cascade.py's split test."""
     async def go():
         cas = CascadeConfig(enabled=True, tiers=("lenet5", "resnet20"),
                             thresholds=(0.5,))
         bolt, coll, engines = _cascade_bolt(
             monkeypatch, cas, max_batch=4, max_wait_ms=10_000,
-            max_inflight=4, eager=True, continuous=True)
+            max_inflight=4, eager=True)
         assert set(bolt._cbs) == {0, 1}
         for c in (0.9, 0.2, 0.9, 0.2):
             await bolt.execute(_tuple(_conf_payload(c)))
@@ -366,7 +476,7 @@ def test_cascade_residue_rides_continuous_queue(run, monkeypatch):
 
 def test_registry_identity_and_close_on_eviction():
     eng = _SlotEngine()
-    cfg = BatchConfig(max_batch=8, buckets=(8,), continuous=True)
+    cfg = BatchConfig(max_batch=8, buckets=(8,))
     cb = continuous_for(eng, cfg)
     assert continuous_for(eng, cfg) is cb
     assert len(registry_stats()) == 1
@@ -383,7 +493,7 @@ def test_engine_finalizer_inside_continuous_for_does_not_deadlock():
     (building the queue allocates, and allocation lets the collector run).
     The finalizer must need nothing that thread holds: with a registry lock
     held around the build, this wedged the thread for good."""
-    cfg = BatchConfig(max_batch=8, buckets=(8,), continuous=True)
+    cfg = BatchConfig(max_batch=8, buckets=(8,))
     doomed = [_SlotEngine()]
     dead_cb = continuous_for(doomed[0], cfg)
 
@@ -412,39 +522,14 @@ def test_engine_finalizer_inside_continuous_for_does_not_deadlock():
         dead_cb.submit(_rows())
 
 
-# ---- batch_fill / coalesced_sources on BOTH paths ----------------------------
+# ---- batch_fill / coalesced_sources ---------------------------------------------
 
 
-def test_legacy_path_observes_batch_fill(run):
-    """The deadline baseline records the fragmentation metric too — the
-    before/after comparison needs both sides instrumented."""
-    async def go():
-        eng = _SlotEngine(pad_to=8)
-        bolt, coll = _bolt(eng, max_batch=8, buckets=(8,),
-                           max_wait_ms=10_000, continuous=False)
-        assert not getattr(bolt, "_continuous", True)
-        for _ in range(3):
-            await bolt.execute(_tuple(_payload()))
-        flush = asyncio.get_running_loop().create_task(bolt.flush())
-        await _until(lambda: len(eng.handles) == 1)
-        _resolve(eng.handles[0])
-        await flush
-        assert len(coll.acked) == 3
-        m = bolt.context.metrics.snapshot()["inference-bolt"]
-        assert m["batch_fill"]["count"] == 1
-        assert m["batch_fill"]["p50"] == pytest.approx(3 / 8)
-        assert m["coalesced_sources"] == 1, \
-            "per-task deadline batches are single-source"
-
-    run(go(), timeout=60)
-
-
-def test_continuous_path_observes_batch_fill():
+def test_queue_observes_batch_fill():
     eng = _SlotEngine(pad_to=8)
     m = MetricsRegistry()
     cb = continuous_for(eng, BatchConfig(
-        max_batch=8, buckets=(8,), max_wait_ms=10_000, eager=True,
-        continuous=True))
+        max_batch=8, buckets=(8,), max_wait_ms=10_000, eager=True))
     cb.bind(m, "engine")
     subs = [cb.submit(_rows(), source=f"s{i}") for i in range(3)]
     # Resolve handles as the dispatcher produces them: with a 1-slot

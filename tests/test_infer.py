@@ -1,56 +1,13 @@
-"""Micro-batcher + engine tests (SURVEY.md §7 step 5)."""
-
-import time
+"""Engine tests (SURVEY.md §7 step 5); batch formation is tests/test_continuous.py."""
 
 import jax
 import numpy as np
 import pytest
 
 from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
-from storm_tpu.infer.batcher import MicroBatcher
 from storm_tpu.infer.engine import InferenceEngine
 from storm_tpu.models import build_model
 from storm_tpu.models.registry import init_params
-
-
-# ---- batcher -----------------------------------------------------------------
-
-
-def _data(n):
-    return np.zeros((n, 2, 2, 1), np.float32)
-
-
-def test_batcher_fills_to_max():
-    b = MicroBatcher(BatchConfig(max_batch=4, max_wait_ms=1000))
-    assert b.add("a", _data(2)) is None
-    batch = b.add("b", _data(2))
-    assert batch is not None
-    assert batch.size == 4
-    assert len(b) == 0
-
-
-def test_batcher_deadline():
-    b = MicroBatcher(BatchConfig(max_batch=100, max_wait_ms=5))
-    t0 = time.perf_counter()
-    b.add("a", _data(1), ts=t0)
-    assert b.take_if_due(now=t0 + 0.001) is None
-    batch = b.take_if_due(now=t0 + 0.006)
-    assert batch is not None and batch.size == 1
-
-
-def test_batcher_never_overshoots_max_batch():
-    """A record that would overshoot flushes the pending batch first
-    (reachable via multi-instance records, e.g. bench --instances-per-msg 3)."""
-    b = MicroBatcher(BatchConfig(max_batch=8, max_wait_ms=1000))
-    assert b.add("a", _data(6)) is None
-    flushed = b.add("b", _data(3))  # 6+3 > 8 -> flush the 6
-    assert flushed is not None and flushed.size == 6
-    assert len(b) == 3
-    # oversized newcomer flushes the pending 3; itself waits for the deadline
-    flushed2 = b.add("c", _data(20))
-    assert flushed2 is not None and flushed2.size == 3
-    assert len(b) == 20
-    assert b.take_all().size == 20
 
 
 def test_engine_handles_oversized_batch():
@@ -61,18 +18,6 @@ def test_engine_handles_oversized_batch():
     )
     out = eng.predict(np.zeros((11, 28, 28, 1), np.float32))  # > max_batch
     assert out.shape == (11, 10)
-
-
-def test_batcher_multi_instance_records_split():
-    b = MicroBatcher(BatchConfig(max_batch=8, max_wait_ms=1000))
-    b.add("r1", np.full((3, 2), 1.0, np.float32))
-    batch = b.add("r2", np.full((5, 2), 2.0, np.float32))
-    assert batch.size == 8
-    out = np.arange(8 * 4, dtype=np.float32).reshape(8, 4)
-    parts = batch.split(out)
-    assert parts[0][0] == "r1" and parts[0][1].shape == (3, 4)
-    assert parts[1][0] == "r2" and parts[1][1].shape == (5, 4)
-    np.testing.assert_array_equal(parts[1][1], out[3:])
 
 
 # ---- engine ------------------------------------------------------------------
@@ -581,60 +526,6 @@ def test_canary_swap_single_task(run):
         await cluster.shutdown()
 
     run(go(), timeout=120)
-
-
-def test_eager_pending_restored_on_cancelled_dispatch(run):
-    """An eager dispatch task cancelled during shutdown/drain — whether
-    parked on the device-slot semaphore OR before its first step — must
-    still decrement _eager_pending, or eager dispatch is permanently
-    disabled for the bolt instance. Regression for ADVICE r1
-    (operator.py:237) + review r2 (pre-first-step cancel window)."""
-    import asyncio
-
-    from storm_tpu.infer.operator import InferenceBolt
-
-    class FakeBatcher:
-        def __len__(self):
-            return 1
-
-        def take_all(self):
-            return "batch"  # never reaches the engine: task is cancelled
-
-    def skeleton(slots):
-        bolt = object.__new__(InferenceBolt)  # no engine needed: cancelled
-        bolt._eager = True
-        bolt._eager_pending = 0
-        bolt._dispatch_sem = asyncio.Semaphore(slots)
-        bolt._inflight = set()
-        bolt._flush_task = None
-        bolt.batcher = FakeBatcher()
-        return bolt
-
-    async def main():
-        # Window A: cancelled BEFORE the coroutine's first step (the task
-        # never enters _dispatch, so only a done-callback can decrement).
-        bolt = skeleton(slots=1)
-        bolt._kick_flush()
-        assert bolt._eager_pending == 1
-        task = next(iter(bolt._inflight))
-        task.cancel()
-        await asyncio.gather(task, return_exceptions=True)
-        assert bolt._eager_pending == 0
-
-        # Window B: cancelled while parked on the semaphore. Slot is free
-        # at kick time (eager branch fires), then stolen before the task's
-        # first step — the task parks on acquire.
-        bolt = skeleton(slots=1)
-        bolt._kick_flush()
-        assert bolt._eager_pending == 1
-        await bolt._dispatch_sem.acquire()  # steal the slot
-        task = next(iter(bolt._inflight))
-        await asyncio.sleep(0.01)  # let it park on the semaphore
-        task.cancel()
-        await asyncio.gather(task, return_exceptions=True)
-        assert bolt._eager_pending == 0
-
-    run(main(), timeout=10)
 
 
 def test_engine_cache_unload_and_lru_eviction():

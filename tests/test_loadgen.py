@@ -304,6 +304,22 @@ def test_capacity_tracker_drop_forgets_whole_key():
 # ---- scenario_phase flight event (satellite) ---------------------------------
 
 
+def test_fleet_classify_scenario_runs_the_default_batching():
+    """One way to form a batch: the ``continuous`` scenario, which
+    differed from ``classify`` by a flag, is gone, and ``classify``
+    pins nothing about where batches form."""
+    from storm_tpu.config import BatchConfig
+    from storm_tpu.loadgen import fleet
+
+    assert fleet.SCENARIOS == ("classify", "cascade", "serve_path", "decode")
+    with pytest.raises(KeyError):
+        fleet._make_scenarios(["continuous"])
+    (sc,) = fleet._make_scenarios(["classify"])
+    batch = sc._cfg(400.0).batch
+    assert (batch.max_inflight, batch.eager) == (
+        BatchConfig().max_inflight, BatchConfig().eager)
+
+
 def test_scenario_phase_flight_event_shape():
     fr = FlightRecorder()
     assert fr.event("scenario_phase", scenario="classify",

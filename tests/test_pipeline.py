@@ -4,9 +4,7 @@ Covers the ISSUE-3 tentpole contract: H2D of batch N+1 overlaps compute of
 batch N (bounded by ``pipeline_depth``), exceptions fail only their own
 batch, staging buffers recycle instead of growing per batch, the operator
 drains batches still in the ring on ``flush()``, and the staging path
-performs no extra full-batch host copies (allocation-count guard). Plus
-the satellite batcher fix: a full batch parked behind a flush is drained
-by ``take_ready()`` instead of aging to the deadline.
+performs no extra full-batch host copies (allocation-count guard).
 
 Device-overlap ordering is made deterministic with gated fake jit outputs
 (``block_until_ready``/``__array__`` wait on events the test controls) —
@@ -25,7 +23,6 @@ import pytest
 
 from storm_tpu.config import BatchConfig, Config, ModelConfig, QosConfig, \
     ShardingConfig
-from storm_tpu.infer.batcher import Batch, MicroBatcher
 from storm_tpu.infer.engine import InferenceEngine, InflightBatch, \
     NullEngine, StagingPool
 from storm_tpu.infer.operator import InferenceBolt
@@ -198,35 +195,6 @@ def test_batch_config_validates_pipeline_knobs():
         BatchConfig(staging_pool=-2)
 
 
-# ---- batcher satellite: take_ready ------------------------------------------
-
-
-def test_micro_batcher_take_ready_drains_parked_full_batch():
-    b = MicroBatcher(BatchConfig(max_batch=4, max_wait_ms=10_000))
-    assert b.add("a", np.zeros((3, 2), np.float32)) is None
-    flushed = b.add("b", np.zeros((4, 2), np.float32))
-    assert flushed is not None and flushed.size == 3  # the old batch
-    # The new record alone reached max_batch: it must be drainable NOW,
-    # not parked until the deadline.
-    ready = b.take_ready()
-    assert ready is not None and ready.size == 4
-    assert ready.items[0].payload == "b"
-    assert b.take_ready() is None and len(b) == 0
-
-
-def test_lane_batcher_take_ready_drains_leftovers():
-    from storm_tpu.qos.lanes import LaneBatcher
-
-    qos = QosConfig(enabled=True)
-    b = LaneBatcher(BatchConfig(max_batch=2, max_wait_ms=10_000), qos)
-    assert b.add("a", np.zeros((1, 2), np.float32), lane="high") is None
-    first = b.add("b", np.zeros((2, 2), np.float32), lane="best_effort")
-    assert first is not None and first.size == 1  # capped at max_batch
-    ready = b.take_ready()
-    assert ready is not None and ready.size == 2
-    assert b.take_ready() is None and len(b) == 0
-
-
 # ---- operator-level: futures, drain, alloc guard, prewarm --------------------
 
 
@@ -327,22 +295,21 @@ def test_operator_completes_tuples_from_fetch_futures(run):
     run(go(), timeout=60)
 
 
-def test_operator_flush_drains_ring_and_pending(run):
+def test_operator_flush_drains_ring_and_queue(run):
     async def go():
         eng = _ManualEngine()
-        # continuous=False: the partial batch parked in the task's OWN
-        # MicroBatcher is what this drain is about (the default path keeps
-        # nothing per task; its drain is test_continuous.py's)
+        eng.ring_capacity = 2
         bolt, coll = _prepared_bolt(eng, max_batch=2, max_wait_ms=10_000,
-                                    max_inflight=4, continuous=False)
-        for _ in range(5):  # two full batches in flight + one pending
+                                    max_inflight=4)
+        cb = bolt._cbs[None]
+        for _ in range(5):  # two full batches in the ring + one row queued
             await bolt.execute(_tuple(_payload()))
         await asyncio.sleep(0.05)
-        assert len(eng.handles) == 2 and len(bolt.batcher) == 1
+        assert len(eng.handles) == 2 and len(cb) == 1
 
         async def resolve():
-            # flush() first dispatches the pending partial batch (handle 3
-            # appears), then waits on all three futures.
+            # flush() forces the queued partial batch out (handle 3
+            # appears), then waits until every group is emitted.
             for _ in range(100):
                 if len(eng.handles) == 3:
                     break
@@ -354,19 +321,15 @@ def test_operator_flush_drains_ring_and_pending(run):
 
         _, _ = await asyncio.gather(bolt.flush(), resolve())
         assert len(coll.acked) == 5 and not coll.failed
-        assert len(bolt.batcher) == 0 and not bolt._inflight
+        assert len(cb) == 0 and not bolt._cb_rows and not bolt._inflight
 
     run(go(), timeout=60)
 
 
-@pytest.mark.parametrize("continuous", [False, True],
-                         ids=["per_task", "engine_queue"])
-def test_operator_staging_no_extra_host_copies(run, monkeypatch, continuous):
-    """Alloc-count guard: on the split-phase path the operator hands
-    per-record arrays straight to the engine's pooled staging write — no
-    ``Batch.stack`` concatenate, and zero new staging allocations per
-    batch at steady state. Both paths: the per-task batcher and the
-    engine's queue (the default) stage through the same pool."""
+def test_operator_staging_no_extra_host_copies(run):
+    """Alloc-count guard: the operator's per-record arrays go through the
+    engine's queue straight to the engine's pooled staging write — zero
+    new staging allocations per batch at steady state."""
 
     async def go():
         eng = InferenceEngine(
@@ -376,12 +339,8 @@ def test_operator_staging_no_extra_host_copies(run, monkeypatch, continuous):
             BatchConfig(max_batch=8, buckets=(8,), pipeline_depth=2),
         )
         eng.warmup()
-        monkeypatch.setattr(
-            Batch, "stack",
-            lambda self: pytest.fail("pipelined path must not stack()"))
         bolt, coll = _prepared_bolt(eng, max_batch=8, buckets=(8,),
-                                    max_wait_ms=10_000, pipeline_depth=2,
-                                    continuous=continuous)
+                                    max_wait_ms=10_000, pipeline_depth=2)
         # Warm the pool to steady state: with depth 2 up to two batches
         # overlap, so the pool legitimately grows to two buffers — but
         # never beyond, however many batches follow.
@@ -389,14 +348,13 @@ def test_operator_staging_no_extra_host_copies(run, monkeypatch, continuous):
             await bolt.execute(_tuple(_payload()))
         await bolt.flush()
         assert len(coll.acked) == 24
-        before = eng._staging.allocated
         for _ in range(40):  # five more full batches
             await bolt.execute(_tuple(_payload()))
         await bolt.flush()
         assert len(coll.acked) == 64 and not coll.failed
         # the queue's dispatcher thread may first overlap two batches only
         # in the second phase; the ring still bounds the pool at two
-        assert eng._staging.allocated <= (2 if continuous else before), \
+        assert eng._staging.allocated <= 2, \
             "full-batch host buffers must come from the pool, not fresh"
 
     run(go(), timeout=120)

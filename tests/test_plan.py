@@ -70,18 +70,17 @@ def test_evaluate_prediction_is_bounded_by_its_terms(snap):
     assert pred["stages"]["batch_wait_ms"] <= 50.0 / 2 + 1e-9
 
 
-def test_legacy_split_fills_slower_than_continuous(snap):
-    """The fragmentation cliff falls out of the model: splitting the
-    stream over 3 legacy batchers forms smaller batches (lower capacity)
-    than one continuous queue at the same offered rate."""
+def test_batches_fill_at_the_offered_rate_whatever_the_parallelism(snap):
+    """All replicas co-batch in the engine's one queue, so more bolt tasks
+    neither shrink the predicted batch nor the capacity."""
     m = CostModel(snap)
     t = Target(rate_rows_s=600.0, slo_p99_ms=1000.0)
-    cont = m.evaluate(Candidate(engine="lenet5", bucket=64, deadline_ms=25.0,
-                                parallelism=3, continuous=True), t)
-    legacy = m.evaluate(Candidate(engine="lenet5", bucket=64, deadline_ms=25.0,
-                                  parallelism=3, continuous=False), t)
-    assert legacy["rows_per_batch"] < cont["rows_per_batch"]
-    assert legacy["capacity_rows_s"] < cont["capacity_rows_s"]
+    one, three = (
+        m.evaluate(Candidate(engine="lenet5", bucket=64, deadline_ms=25.0,
+                             parallelism=par), t) for par in (1, 3))
+    assert three["rows_per_batch"] == one["rows_per_batch"] == 15.0
+    assert three["capacity_rows_s"] == one["capacity_rows_s"]
+    assert "continuous" not in one["candidate"]
 
 
 # ---- solver -------------------------------------------------------------------
@@ -107,7 +106,6 @@ def test_solve_validates_onto_real_config_knobs(snap):
     assert cfg.topology.inference_parallelism == plan.parallelism
     assert cfg.batch.bucket_for(1) == plan.bucket
     assert cfg.batch.max_wait_ms == pytest.approx(plan.deadline_ms)
-    assert cfg.batch.continuous == plan.continuous
     # the CLI form round-trips through --set parsing (section.key=json)
     assert any(arg.startswith("batch.max_batch=")
                for arg in plan.override_args())

@@ -1,6 +1,6 @@
 """Property-based tests (hypothesis) for the framework's algebraic cores:
 the XOR ack ledger, the Kafka varint/record-batch codec, the wire schema,
-and the micro-batcher — invariants that example-based tests undersample."""
+and the engine queue's batch formation — invariants that example-based tests undersample."""
 
 import json
 
@@ -184,39 +184,49 @@ def test_native_parser_matches_python_fallback(vals, indent):
     assert int(diff.max()) <= 1, (native, expected)
 
 
-# ---- micro-batcher -----------------------------------------------------------
+# ---- the engine's queue: batch formation --------------------------------------
 
 
 @settings(max_examples=100, deadline=None)
 @given(
-    sizes=st.lists(st.integers(min_value=1, max_value=7), min_size=1, max_size=30),
+    records=st.lists(
+        st.tuples(st.integers(min_value=1, max_value=7),   # rows
+                  st.integers(min_value=0, max_value=2)),  # source
+        min_size=1, max_size=30),
     max_batch=st.integers(min_value=4, max_value=32),
 )
-def test_batcher_conserves_records(sizes, max_batch):
-    """Every record added comes back out exactly once, in order, across
-    full-batch pops and the final take_all."""
-    from storm_tpu.config import BatchConfig
-    from storm_tpu.infer.batcher import MicroBatcher
+def test_queue_formation_conserves_records(records, max_batch):
+    """Any sequence of record sizes from any mix of sources: every record
+    leaves the queue exactly once with all its rows, in arrival order per
+    source, and no batch holds more than ``max_batch`` rows (a single
+    record of more rows than that ships alone)."""
+    from collections import deque
 
-    b = MicroBatcher(BatchConfig(max_batch=max_batch, max_wait_ms=1e9,
-                                 buckets=(max_batch,)))
+    from storm_tpu.config import BatchConfig
+    from storm_tpu.infer.continuous import ContinuousBatcher, Submission
+
+    class _Engine:
+        ring_capacity = 1
+
+    engine = _Engine()  # the queue holds its engine weakly
+    cb = ContinuousBatcher(engine, BatchConfig(
+        max_batch=max_batch, max_wait_ms=1e9, buckets=(max_batch,)))
+    for idx, (rows, source) in enumerate(records):
+        sub = Submission(np.full((rows, 2), idx, np.float32), idx, 0.0, 0.0,
+                         None, None, f"s{source}", 0.0)
+        cb._queues.setdefault(cb._key(None, None), deque()).append(sub)
+        cb._pending_rows += rows
     seen = []
-    idx = 0
-    for size in sizes:
-        data = np.full((size, 2), idx, np.float32)
-        batch = b.add(idx, data, ts=0.0)
-        idx += 1
-        if batch is not None:
-            for payload, rows in zip([i.payload for i in batch.items],
-                                     [i.data for i in batch.items]):
-                seen.append((payload, rows.shape[0]))
-    final = b.take_all()
-    if final is not None:
-        for item in final.items:
-            seen.append((item.payload, item.data.shape[0]))
-    assert [p for p, _ in seen] == list(range(len(sizes)))
-    assert [s for _, s in seen] == sizes
-    assert len(b) == 0
+    while len(cb):
+        batch = cb._form_locked()
+        assert batch, "rows pending but nothing formed"
+        assert len(batch) == 1 or sum(s.rows for s in batch) <= max_batch
+        seen.extend(batch)
+    assert sorted(s.payload for s in seen) == list(range(len(records)))
+    assert all(s.rows == records[s.payload][0] for s in seen)
+    for source in range(3):
+        mine = [s.payload for s in seen if s.source == f"s{source}"]
+        assert mine == sorted(mine), "order kept per source"
 
 
 @given(

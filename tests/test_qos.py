@@ -1,6 +1,6 @@
 """Admission control & QoS (storm_tpu/qos/, round-6 tentpole): token-bucket
 tenant quotas + lane classification at the spout edge, earliest-deadline-
-first batch formation in the operator, the hysteresis load-shed controller,
+first batch formation (tests/test_continuous.py), the hysteresis load-shed controller,
 shed-first/scale-second autoscaler coupling, and the typed ``Overloaded``
 degradation path — unit-level on the qos package, then e2e through the
 broker -> spout -> InferenceBolt -> sink slice, then the UI /qos route."""
@@ -19,8 +19,7 @@ from storm_tpu.config import (
 from storm_tpu.connectors import BrokerSink, BrokerSpout, MemoryBroker
 from storm_tpu.infer import InferenceBolt
 from storm_tpu.qos import (
-    AdmissionController, LaneBatcher, LoadShedController, ShedPolicy,
-    TokenBucket)
+    AdmissionController, LoadShedController, ShedPolicy, TokenBucket)
 from storm_tpu.runtime import Bolt, Spout, TopologyBuilder, Values
 from storm_tpu.runtime.autoscale import Autoscaler, AutoscalePolicy
 from storm_tpu.runtime.cluster import AsyncLocalCluster
@@ -148,73 +147,6 @@ def test_admit_sheds_lanes_at_raised_level():
     assert snap["shed_gold"] == 1
     assert snap["shed_lane_best_effort"] == 1
     assert snap["shed_lane_normal"] == 1
-
-
-# ---- EDF lane batcher --------------------------------------------------------
-
-
-def _lb(max_batch, qos=None):
-    return LaneBatcher(
-        BatchConfig(max_batch=max_batch, max_wait_ms=5.0,
-                    buckets=(max_batch,)),
-        qos or QosConfig(enabled=True))
-
-
-def test_lane_batcher_high_preempts_queued_best_effort():
-    lb = _lb(4)
-    x = np.zeros((1, 2), np.float32)
-    t0 = 1000.0
-    for i in range(3):
-        assert lb.add(f"be{i}", x, ts=t0, lane="best_effort") is None
-    # The 4th instance fills max_batch; the freshly-arrived high record
-    # (deadline t0+50ms) pops AHEAD of best_effort queued first (t0+1s).
-    batch = lb.add("hi", x, ts=t0, lane="high")
-    assert batch is not None and batch.size == 4
-    assert [it.lane for it in batch.items] == [
-        "high", "best_effort", "best_effort", "best_effort"]
-    assert [it.payload for it in batch.items] == ["hi", "be0", "be1", "be2"]
-    assert len(lb) == 0
-
-
-def test_lane_batcher_fifo_within_a_lane():
-    lb = _lb(8)
-    x = np.zeros((1, 2), np.float32)
-    for i in range(4):
-        lb.add(i, x, ts=1000.0, lane="normal")
-    batch = lb.take_all()
-    assert [it.payload for it in batch.items] == [0, 1, 2, 3]
-
-
-def test_lane_batcher_leftovers_stay_pending():
-    # Unlike the FIFO batcher, later-deadline items beyond max_batch stay
-    # queued for the next take instead of forcing an immediate flush.
-    lb = _lb(2)
-    x = np.zeros((1, 2), np.float32)
-    assert lb.add("a", x, ts=1000.0, lane="high") is None
-    batch = lb.add("b", x, ts=1000.0, lane="best_effort")
-    assert batch is not None and batch.size == 2
-    assert lb.add("c", x, ts=1000.0, lane="best_effort") is None
-    assert len(lb) == 1
-    rest = lb.take_all()
-    assert [it.payload for it in rest.items] == ["c"]
-    assert lb.take_all() is None
-
-
-def test_lane_batcher_take_if_due_is_age_based():
-    import time as _time
-
-    lb = _lb(64)
-    x = np.zeros((1, 2), np.float32)
-    old = _time.perf_counter() - 1.0
-    lb.add("stale", x, ts=old, lane="best_effort")
-    batch = lb.take_if_due()
-    assert batch is not None and batch.items[0].payload == "stale"
-
-
-def test_lane_batcher_oversized_record_still_ships():
-    lb = _lb(2)
-    batch = lb.add("big", np.zeros((5, 2), np.float32), ts=0.0, lane="high")
-    assert batch is not None and batch.size == 5  # never wedges
 
 
 # ---- load-shed controller ----------------------------------------------------

@@ -141,12 +141,11 @@ def test_cross_caller_batching_coalesces():
         ShardingConfig(data_parallel=1),
         BatchConfig(max_batch=64, buckets=(64,)),
         port=0,
-        cross_batch_ms=50.0,
     ).start()
     try:
         xs = [np.random.rand(2, 28, 28, 1).astype(np.float32) for _ in range(8)]
         want = [w.engine.predict(x) for x in xs]
-        w._batcher.dispatches = 0
+        before = w._queue.batches
 
         outs = [None] * 8
         errs = []
@@ -166,46 +165,115 @@ def test_cross_caller_batching_coalesces():
         assert not errs
         for got, exp in zip(outs, want):
             np.testing.assert_allclose(got, exp, rtol=1e-5, atol=1e-5)
-        assert 1 <= w._batcher.dispatches < 8
+        assert 1 <= w._queue.batches - before < 8
     finally:
         w.stop()
 
 
-def test_cross_caller_batcher_chunks_oversize():
-    from storm_tpu.serve.batcher import CrossCallerBatcher
+class _HeldEngine:
+    """dispatch-protocol engine whose batches the TEST finishes; a row's
+    answer is its own first value, so a caller can tell its rows."""
 
-    class FakeEngine:
-        class batch_cfg:
-            max_batch = 4
+    input_shape = (2,)
+    ring_capacity = 1
 
-        def __init__(self):
-            self.calls = []
+    def __init__(self):
+        self.batches = []  # (handle, parts)
 
-        def predict(self, x):
-            self.calls.append(x.shape[0])
-            return x.reshape(x.shape[0], -1)[:, :3]
+    def dispatch(self, parts):
+        from storm_tpu.infer.engine import InflightBatch
 
-    eng = FakeEngine()
-    b = CrossCallerBatcher(eng, window_ms=1.0)
-    x = np.random.rand(10, 2, 2, 1).astype(np.float32)
-    out = b.predict(x)
-    assert out.shape == (10, 3)
-    assert eng.calls == [4, 4, 2]
+        n = sum(int(p.shape[0]) for p in parts)
+        h = InflightBatch(n, n)
+        h.timings = {}
+        self.batches.append((h, parts))
+        return h
+
+    def finish(self, k, error=None):
+        h, parts = self.batches[k]
+        if error is not None:
+            h.future.set_exception(error)
+        else:
+            h.future.set_result(np.concatenate(parts)[:, :1].repeat(3, 1))
 
 
-def test_cross_caller_batcher_propagates_errors():
-    from storm_tpu.serve.batcher import CrossCallerBatcher
+def _wait(cond, what):
+    import time
 
-    class BoomEngine:
-        class batch_cfg:
-            max_batch = 8
+    t0 = time.perf_counter()
+    while not cond():
+        assert time.perf_counter() - t0 < 10.0, what
+        time.sleep(0.002)
 
-        def predict(self, x):
-            raise RuntimeError("boom")
 
-    b = CrossCallerBatcher(BoomEngine(), window_ms=1.0)
-    with pytest.raises(RuntimeError, match="boom"):
-        b.predict(np.zeros((2, 2), np.float32))
+@pytest.mark.parametrize("fault", [False, True],
+                         ids=["each_gets_its_rows", "error_reaches_every_caller"])
+def test_concurrent_callers_share_one_device_batch(fault):
+    """Two RPCs that arrive while the device works leave in ONE batch of
+    the engine's queue; each caller gets back exactly its own rows, or,
+    when the engine fails that batch, the engine's error."""
+    import threading
+
+    eng = _HeldEngine()
+    w = InferenceWorker(
+        engine=eng, port=0,
+        batch=BatchConfig(max_batch=8, buckets=(8,), max_wait_ms=10_000,
+                          eager=True))
+    hold = w._queue.submit(np.zeros((1, 2), np.float32), source="hold")
+    _wait(lambda: len(eng.batches) == 1, "the engine's one slot is taken")
+    got = {}
+
+    def call(i, rows):  # what both RPC handlers run
+        try:
+            got[i] = w._run_predict(np.full((rows, 2), i, np.float32))
+        except Exception as e:
+            got[i] = e
+
+    callers = [threading.Thread(target=call, args=(i, rows))
+               for i, rows in ((1, 2), (2, 3))]
+    for t in callers:
+        t.start()
+    _wait(lambda: len(w._queue) == 5, "both callers' rows are queued")
+    eng.finish(0)
+    _wait(lambda: len(eng.batches) == 2, "the freed slot refills")
+    assert sorted(p.shape[0] for p in eng.batches[1][1]) == [2, 3]
+    eng.finish(1, error=RuntimeError("boom") if fault else None)
+    for t in callers:
+        t.join(10)
+    hold.future.result(timeout=1)
+    assert len(eng.batches) == 2, "one device batch served both callers"
+    for i, rows in ((1, 2), (2, 3)):
+        if fault:
+            assert isinstance(got[i], RuntimeError) and "boom" in str(got[i])
+        else:
+            np.testing.assert_array_equal(
+                got[i], np.full((rows, 3), i, np.float32))
+
+
+def test_caller_with_more_rows_than_max_batch_gets_all_of_them():
+    """One RPC of 10 rows against ``max_batch`` 4: the record ships whole
+    through the engine's queue and every row is answered."""
+    w = InferenceWorker(
+        ModelConfig(name="lenet5", dtype="float32", input_shape=(28, 28, 1)),
+        ShardingConfig(data_parallel=1),
+        BatchConfig(max_batch=4, buckets=(4,)),
+        port=0)
+    x = np.random.rand(10, 28, 28, 1).astype(np.float32)
+    out = w._run_predict(x)
+    assert out.shape == (10, 10)
+    np.testing.assert_allclose(out, w.engine.predict(x), rtol=1e-5, atol=1e-5)
+
+
+def test_serve_cli_has_no_cross_batch_window(capsys):
+    """``--cross-batch-ms`` went with the leader window it set
+    (docs/MIGRATION.md): callers coalesce in the engine's queue with no
+    flag, and the old one is refused by the parser."""
+    from storm_tpu.main import main
+
+    with pytest.raises(SystemExit) as e:
+        main(["serve", "--cross-batch-ms", "5"])
+    assert e.value.code == 2
+    assert "--cross-batch-ms" in capsys.readouterr().err
 
 
 # ---- JVM-boundary conformance (VERDICT r1 next #7) ---------------------------
