@@ -25,15 +25,16 @@ class BatchConfig:
 
     The reference runs batch=1 per ``session.run`` (InferenceBolt.java:80-86);
     here a batch of up to ``max_batch`` rows is cut from the engine's one
-    queue when a slot of its pipeline ring frees (on an idle device: once
-    the first row has waited ``max_wait_ms``), and padded up to the nearest
+    queue into a free slot of its pipeline ring as the running step ends
+    (on an idle device: once the first row has waited ``max_wait_ms``),
+    and padded up to the nearest
     of ``buckets`` so XLA compiles a small, fixed set of shapes.
     """
 
     max_batch: int = 256
     # How long the first row waits for company on an IDLE device. While
-    # the device works, a batch is cut when a ring slot frees, whatever
-    # the clock says.
+    # the device works, a batch is cut into a free ring slot as the
+    # running step ends, whatever the clock says.
     max_wait_ms: float = 5.0
     # Padding buckets (ascending). Batches are padded to the smallest bucket
     # >= their size; the final entry must equal max_batch.
@@ -42,8 +43,8 @@ class BatchConfig:
     # the engine's queue, max_inflight * max_batch.
     max_inflight: int = 2
     # An idle device dispatches on arrival instead of ageing the first
-    # row to max_wait_ms (a busy one refills a freed slot at once either
-    # way).
+    # row to max_wait_ms (a busy one refills a free slot as the running
+    # step ends either way).
     eager: bool = False
     # Split-phase device pipeline depth: batches allowed inside the ENGINE
     # between dispatch (stage -> device_put -> async jit launch) and fetch

@@ -3,21 +3,35 @@
 Where every batch is formed: each replica of an inference bolt, the gRPC
 serve worker, ``DecodeBolt`` and cascade escalation residues all
 ``submit`` rows into ONE queue per shared engine, and a dedicated
-dispatcher thread cuts a batch from it the moment a slot of the engine's
-pipeline ring frees (extending the split-phase ring of
-:mod:`storm_tpu.infer.engine`) — late binding: a batch's size is decided
-when the device can take it, from everything that has arrived by then,
-whatever operator task it arrived through (BatchGen, PAPERS.md, argues
-batch formation must be decoupled from operator topology and run
-continuously at the device; PERF.md §6, PR 26 has the chip's numbers).
+dispatcher thread cuts a batch from it when the device is about to take it
+(extending the split-phase ring of :mod:`storm_tpu.infer.engine`) — late
+binding: a batch's size is decided when the device can take it, from
+everything that has arrived by then, whatever operator task it arrived
+through (BatchGen, PAPERS.md, argues batch formation must be decoupled
+from operator topology and run continuously at the device; PERF.md §6,
+PR 26 and PR 33 have the chip's numbers).
 
-Dispatch rule (work-conserving slot refill):
+Dispatch rule (work-conserving slot refill, cut as late as the device
+allows):
 
 - ``max_batch`` rows pending  -> dispatch (the ring provides backpressure);
-- a ring slot is free AND at least one batch is already in flight ->
-  dispatch immediately (the freed-slot refill — batches size themselves to
+- a ring slot is free AND at least one batch is already in flight -> hold
+  the cut until the running step is about to end, then dispatch everything
+  that has arrived (the freed-slot refill — batches size themselves to
   whatever coalesced while the device worked, exactly BatchGen's
-  continuous former);
+  continuous former). A batch cut the moment the slot frees would lie
+  staged behind the whole step that has just begun, and every record that
+  arrives during that step would miss it. The cut is at *expected end −
+  lead*: the work in flight ends at the start of the running step (the
+  later of its launch and the end of the batch before it) plus
+  ``engine.step_ms`` of each batch in flight; the lead is the longest of
+  the last ``_LEADS`` cuts of the bucket this cut would take, each read
+  from the moment the rule meant to cut to the batch's hand-over to the
+  device (the dispatcher waking late, formation, staging, ``device_put``,
+  launch), over cuts that did not park on the ring. Where a reading is
+  missing (a bucket's first step or first cut, an engine without
+  ``step_ms``, a ring of one slot) or the time left is already under the
+  lead (a launch-bound model, an overdue step) the cut is at once;
 - the device is fully idle -> ``eager`` dispatches on arrival, otherwise
   the oldest row ages to ``max_wait_ms`` (trickle traffic waits that long
   for company, no longer).
@@ -59,6 +73,11 @@ from storm_tpu.config import BatchConfig, QosConfig
 from storm_tpu.runtime.tracing import DEVICE_SUBSTAGES
 
 logger = logging.getLogger(__name__)
+
+# How many cuts of a bucket the lead of the hold looks back over (the module
+# docstring's dispatch rule): the longest of them is the lead, so about one
+# cut in ``_LEADS`` + 1 takes longer than the rule allowed for.
+_LEADS = 16
 
 
 class Submission:
@@ -145,6 +164,13 @@ class ContinuousBatcher:
         self._pending_rows = 0
         self._inflight = 0
         self._force = False  # flush(): dispatch regardless of deadline
+        # ---- the hold before the cut (module docstring) ----
+        # (handle, launched) of each batch on the device, in launch order;
+        # when the first of them began its step; and per padded bucket the
+        # last cuts' milliseconds from "meant to cut" to "on the device".
+        self._flying: deque = deque()
+        self._began = 0.0
+        self._leads: Dict[int, deque] = {}
         self._thread: Optional[threading.Thread] = None
         self._closed = False
         # ---- stats (read by the qos UI route / tests) ----
@@ -191,6 +217,8 @@ class ContinuousBatcher:
                 "device_ms": m.histogram(cid, "device_ms"),
                 "batch_wait": m.histogram(cid, "batch_wait_ms"),
                 "disp_wait": m.histogram(cid, "dispatch_wait_ms"),
+                "cut_hold": m.histogram(cid, "cut_hold_ms"),
+                "cuts_late": m.counter(cid, "cuts_late"),
                 "infer": m.counter(cid, "instances_inferred"),
                 "coalesced": m.counter(cid, "coalesced_sources"),
                 "substage": {key: m.histogram(cid, key)
@@ -234,7 +262,9 @@ class ContinuousBatcher:
                     or self._pending_rows >= self.cfg.max_batch):
                 # Otherwise the dispatcher sleeps until a slot frees
                 # (_loop's untimed wait) and one more row changes nothing:
-                # under a backlog that spares a wake-up per record.
+                # under a backlog that spares a wake-up per record. A
+                # dispatcher that holds a cut wakes, finds the hold not
+                # over and the batch not full, and goes on holding.
                 self._cond.notify_all()
         return sub
 
@@ -259,6 +289,9 @@ class ContinuousBatcher:
             self._cond.notify_all()
 
     def close(self) -> None:
+        """No more submissions; what is pending is dispatched at once (a
+        hold is released like ``flush`` releases it) and the dispatcher
+        thread ends."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
@@ -285,24 +318,42 @@ class ContinuousBatcher:
     def _loop(self) -> None:
         while True:
             with self._cond:
+                # the hold before this cut: since when, and until when the
+                # last timed wait meant to sleep
+                held_from = wake_at = None
                 while True:
-                    if self._closed:
-                        return
                     if self._pending_rows == 0:
+                        if self._closed:
+                            return
                         self._force = False
                         self._cond.wait()
                         continue
                     now = time.perf_counter()
                     full = self._pending_rows >= self.cfg.max_batch
                     slot_free = self._inflight < self.capacity
-                    due = (now - self._oldest_enq_locked()) * 1e3 >= \
-                        self.cfg.max_wait_ms
-                    if full or self._force or (slot_free and (
-                            self._inflight > 0 or self.cfg.eager or due)):
+                    if full or self._force or self._closed:
                         # full/forced batches may dispatch with every slot
                         # busy: engine.dispatch parks on the ring — that IS
                         # the backpressure, and the park happens on this
                         # thread, never the event loop.
+                        break
+                    if slot_free and (self._inflight > 0
+                                      or held_from is not None):
+                        # The refill: hold the cut until the running step
+                        # is about to end. A record's arrival wakes this
+                        # thread and changes nothing until the batch is
+                        # full; a step that ended meanwhile ends the hold.
+                        cut_at = self._cut_at_locked()
+                        if cut_at is None or cut_at <= now:
+                            break
+                        if held_from is None:
+                            held_from = now
+                        wake_at = cut_at
+                        self._cond.wait(timeout=cut_at - now)
+                        continue
+                    due = (now - self._oldest_enq_locked()) * 1e3 >= \
+                        self.cfg.max_wait_ms
+                    if slot_free and (self.cfg.eager or due):
                         break
                     if slot_free:
                         # Idle + non-eager: age toward the deadline.
@@ -313,9 +364,37 @@ class ContinuousBatcher:
                         # Every slot busy and not enough rows to force a
                         # park: wait for the next slot-free notify.
                         self._cond.wait()
+                # When the rule meant to cut (a timed wait that ran out
+                # meant its target, not the moment this thread got to
+                # run); None for a cut that parks on the ring.
+                meant = None
+                if slot_free:
+                    meant = now if wake_at is None else min(now, wake_at)
+                hold_ms = 0.0 if held_from is None else (now - held_from) * 1e3
                 items = self._form_locked()
                 self._inflight += 1
-            self._dispatch(items)
+            self._dispatch(items, meant, hold_ms)
+
+    def _cut_at_locked(self) -> Optional[float]:
+        """When to cut so that the batch reaches the device as the work in
+        flight ends (the module docstring's *expected end − lead*); None
+        where a reading is missing, and the cut is then at once."""
+        if not self._flying:
+            return None
+        engine = self._engine_ref()
+        steps = getattr(engine, "step_ms", None) or {}
+        rows = min(self._pending_rows, self._rows_to_take_locked())
+        leads = self._leads.get(
+            getattr(engine, "pad_batch", self.cfg.bucket_for)(rows))
+        if not leads:
+            return None
+        end = self._began
+        for handle, _ in self._flying:
+            step = steps.get(handle.padded)
+            if step is None:
+                return None
+            end += step / 1e3
+        return end - max(leads) / 1e3
 
     # ---- batch formation (EDF + weighted round-robin + starvation bound) -----
 
@@ -427,22 +506,26 @@ class ContinuousBatcher:
 
     # ---- device round trip ---------------------------------------------------
 
-    def _dispatch(self, items: List[Submission]) -> None:
+    def _dispatch(self, items: List[Submission], meant: Optional[float],
+                  hold_ms: float) -> None:
         """Runs on the dispatcher thread. ``engine.dispatch`` may park on
         the pipeline ring — bounded, and exactly the backpressure the
         split-phase engine defines. Every path (success, engine failure,
         evicted engine) funnels into :meth:`_finish`, which owns the
-        single slot decrement."""
+        single slot decrement. ``meant`` is when the rule meant to cut
+        (None: the cut parks on the ring, and says nothing of the lead),
+        ``hold_ms`` how long the cut was held for the running step."""
         t0 = time.perf_counter()
+        if self._m:
+            self._m["cut_hold"].observe(hold_ms)
+            for it in items:
+                self._m["batch_wait"].observe((t0 - it.enq) * 1e3)
         try:
             engine = self._engine_ref()
             if engine is None:
                 raise RuntimeError(
                     f"engine {self.engine_name!r} was evicted with rows "
                     "queued")
-            if self._m:
-                for it in items:
-                    self._m["batch_wait"].observe((t0 - it.enq) * 1e3)
             dispatch = getattr(engine, "dispatch", None)
             if dispatch is None:
                 # predict-only engines (plain test doubles): serialized.
@@ -457,9 +540,23 @@ class ContinuousBatcher:
             self._finish(items, None, e, None, t0, time.perf_counter())
             return
         t1 = time.perf_counter()
+        with self._cond:
+            # Nothing ahead of it on the device: its step begins now, and
+            # a cut that was held for the step before it came too late.
+            idle = not self._flying
+            late = idle and hold_ms > 0.0
+            if idle:
+                self._began = t1
+            self._flying.append((handle, t1))
+            if meant is not None:
+                self._leads.setdefault(
+                    handle.padded, deque(maxlen=_LEADS)).append(
+                        (t1 - meant) * 1e3)
         if self._m:
             # Slot wait: time parked on the engine ring.
             self._m["disp_wait"].observe((t1 - t0) * 1e3)
+            if late:
+                self._m["cuts_late"].inc()
         handle.future.add_done_callback(
             lambda f, its=items, h=handle, a=t0, b=t1:
             self._on_done(its, f, h, a, b))
@@ -478,6 +575,16 @@ class ContinuousBatcher:
                 t_disp=None) -> None:
         with self._cond:
             self._inflight -= 1
+            if handle is not None:
+                # The batch behind it began its step when this one's
+                # result was ready (the fetch thread then copied it out),
+                # or at its own launch where that came later.
+                first = self._flying[0][0] is handle
+                self._flying.remove((handle, t_disp))
+                if first and self._flying:
+                    ready = t_done - (getattr(handle, "timings", None)
+                                      or {}).get("d2h_ms", 0.0) / 1e3
+                    self._began = max(ready, self._flying[0][1])
             self._cond.notify_all()
         rows = sum(it.rows for it in items)
         t_disp = t_disp if t_disp is not None else t_form
@@ -605,6 +712,8 @@ class ContinuousBatcher:
                 oldest_ms = max(
                     0.0,
                     (time.perf_counter() - self._oldest_enq_locked()) * 1e3)
+            leads = {b: round(max(d), 3)
+                     for b, d in sorted(self._leads.items()) if d}
         med = self.fill_median()
         return {
             "engine": self.engine_name,
@@ -616,6 +725,7 @@ class ContinuousBatcher:
             "batches": self.batches,
             "rows": self.rows_dispatched,
             "batch_fill_p50": None if med is None else round(med, 4),
+            "lead_ms": leads,
             "fair_rows": {f"{k[0]}:{k[1]}": v
                           for k, v in self.fair_rows.items()},
             "fair_starved": {f"{k[0]}:{k[1]}": v

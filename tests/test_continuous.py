@@ -106,7 +106,13 @@ def _rows(n=1, c=0.0):
 def test_slot_refill_on_free_dispatches_immediately():
     """The tentpole behavior: rows arriving while the device works are
     dispatched the MOMENT a slot frees — not at a deadline tick. With a
-    10s deadline, only the refill path can explain the second batch."""
+    10s deadline, only the refill path can explain the second batch.
+
+    Since ISSUE 33 the refill cut is held until the running step is about
+    to end where the queue can tell when that is; this engine has one slot
+    and no ``step_ms``, so the rule falls back to the cut at once, and
+    this test stays as it was (``tests/test_late_binding.py`` (f) has the
+    hold)."""
     eng = _SlotEngine(capacity=1)
     cb = continuous_for(eng, BatchConfig(
         max_batch=8, buckets=(8,), max_wait_ms=10_000, eager=True))

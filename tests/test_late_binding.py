@@ -6,7 +6,8 @@ Everything runs on a fake engine with the real one's dispatch protocol: a
 ring of two slots that ``dispatch`` parks on, one device that runs the
 batches in ring order, each for the step time of its bucket (a few ms), and
 ``step_ms`` as the real engine's warm-up measures it. Counts are asserted,
-never times.
+never times: the tests of the hold before the cut (ISSUE 33) give the queue
+a clock that only the test moves.
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ import asyncio
 import json
 import queue
 import statistics
+import sys
 import threading
 import time
 
@@ -22,7 +24,8 @@ import numpy as np
 import pytest
 
 from storm_tpu.config import BatchConfig, Config, ModelConfig
-from storm_tpu.infer.continuous import _reset_registry
+from storm_tpu.infer import continuous
+from storm_tpu.infer.continuous import _reset_registry, continuous_for
 from storm_tpu.infer.engine import InflightBatch, StagingPool, _fetch_loop
 from storm_tpu.infer.operator import InferenceBolt
 from storm_tpu.runtime.base import TopologyContext
@@ -408,3 +411,260 @@ def test_fetch_thread_reads_the_step_of_each_bucket_without_the_queueing():
     # and still read as their own 12
     assert 4.0 <= step_ms[8] < 12.0
     assert 12.0 <= step_ms[32] < 24.0
+
+
+# ---- (f) the hold before the cut (ISSUE 33) ----------------------------------
+
+# One 8-row step lasts a minute of the test's clock, so that no real delay
+# can run a hold out: a hold ends when the test moves the clock, or never.
+MINUTE = {8: 60_000.0, 32: 60_000.0}
+
+
+class _Clock:
+    """Stands in for the ``time`` module in ``infer/continuous.py``."""
+
+    def __init__(self):
+        self.now = 1_000.0
+
+    def perf_counter(self):
+        return self.now
+
+
+def _wait(cond, timeout=10.0):
+    t0 = time.perf_counter()
+    while not cond():
+        assert time.perf_counter() - t0 < timeout, "condition not met in time"
+        time.sleep(0.002)
+
+
+def _row(tag, n=1):
+    return np.full((n, *SHAPE), tag, np.float32)
+
+
+def _held_queue(monkeypatch, step_ms=MINUTE, capacity=2, **batch_kw):
+    """A queue whose first batch (one row, tag 0) is on the device and
+    stays there until the test ends its step; the lead of bucket 8 is
+    known from that cut (0 ms: the clock stood still meanwhile)."""
+    clock = _Clock()
+    monkeypatch.setattr(continuous, "time", clock)
+    eng = _RingEngine(step_ms, capacity=capacity, manual=True)
+    metrics = MetricsRegistry()
+    batch_kw.setdefault("max_batch", 32)
+    cb = continuous_for(eng, BatchConfig(
+        buckets=(8, 32), eager=True, **batch_kw))
+    cb.bind(metrics, "inference-bolt")
+    first = cb.submit(_row(0))
+    _wait(lambda: len(eng.steps) == 1 and (
+        len(cb._flying) == 1 or capacity == 1))
+    return clock, eng, cb, metrics, first
+
+
+def _let_go(eng):
+    """The test's last word: the steps left end by themselves, and in no
+    time (``run_free`` lets each last its ``step_ms``)."""
+    eng.step_ms = dict.fromkeys(eng.step_ms, 1.0)
+    eng.run_free()
+
+
+def _poke(cb):
+    """What the timed wait's end does, for a clock that only the test
+    moves: wake the dispatcher to look at the time again."""
+    with cb._cond:
+        cb._cond.notify_all()
+
+
+def _hold_metrics(metrics):
+    m = metrics.snapshot()["inference-bolt"]
+    return m["cut_hold_ms"], m.get("cuts_late", 0)
+
+
+@pytest.mark.timeout(60)
+def test_refill_cut_is_held_until_the_running_step_is_about_to_end(
+        monkeypatch):
+    """A slot is free, a batch is on the device, its step time and the
+    lead are known and the rows do not fill ``max_batch``: nothing is cut
+    until the step is about to end, rows that arrive meanwhile do not cut
+    early, and all of them ride the one cut."""
+    clock, eng, cb, metrics, first = _held_queue(monkeypatch)
+    try:
+        subs = [cb.submit(_row(1))]
+        time.sleep(0.03)
+        assert len(eng.steps) == 1, "cut at once: the hold did not engage"
+        for tag in (2, 3, 4):  # each arrival wakes the dispatcher
+            subs.append(cb.submit(_row(tag)))
+            clock.now += 10.0
+        time.sleep(0.03)
+        assert len(eng.steps) == 1 and len(cb) == 4, \
+            "an arrival during the hold cut early"
+        clock.now += 29.0  # 59 s into a step of 60
+        _poke(cb)
+        time.sleep(0.03)
+        assert len(eng.steps) == 1, "cut before the step was about to end"
+        clock.now += 1.0
+        _poke(cb)
+        _wait(lambda: len(eng.steps) == 2)
+        assert eng.steps[1]["tags"] == [1.0, 2.0, 3.0, 4.0]
+        assert len(cb) == 0
+        hold, late = _hold_metrics(metrics)
+        assert hold["count"] == 2, "observed once a cut"
+        assert hold["sum"] == pytest.approx(60_000.0), \
+            "0.0 for the idle device's cut, the step for the held one"
+        assert late == 0
+        assert cb.stats()["lead_ms"] == {8: 0.0}
+    finally:
+        _let_go(eng)
+    for sub in [first] + subs:
+        assert sub.future.result(timeout=10).shape == (1, 10)
+
+
+@pytest.mark.timeout(60)
+def test_max_batch_rows_arriving_during_a_hold_cut_at_once(monkeypatch):
+    clock, eng, cb, metrics, first = _held_queue(monkeypatch, max_batch=8)
+    try:
+        subs = [cb.submit(_row(1)) for _ in range(7)]
+        time.sleep(0.03)
+        assert len(eng.steps) == 1, "seven rows of eight: still held"
+        subs.append(cb.submit(_row(1)))  # the clock has not moved
+        _wait(lambda: len(eng.steps) == 2)
+        assert eng.steps[1]["rows"] == 8
+        # a full batch may park on the ring, as before: both slots busy,
+        # eight more rows still cut
+        subs += [cb.submit(_row(2, n=8))]
+        _wait(lambda: len(cb) == 0)
+        assert cb.inflight == 3, "the full batch parks on the ring"
+    finally:
+        _let_go(eng)
+    for sub in [first] + subs:
+        sub.future.result(timeout=10)
+    assert [s["rows"] for s in eng.steps] == [1, 8, 8]
+    hold, late = _hold_metrics(metrics)
+    assert hold["count"] == 3 and hold["sum"] == 0.0 and late == 0
+
+
+@pytest.mark.timeout(60)
+@pytest.mark.parametrize("case", [
+    "no_step_time", "no_lead_for_the_bucket", "one_slot", "step_overdue"])
+def test_without_a_reading_or_with_no_time_left_the_cut_is_at_once(
+        monkeypatch, case):
+    """The rule before ISSUE 33 is what the hold falls back to: an engine
+    without ``step_ms``, a bucket that no cut has timed yet, a ring of one
+    slot, or a running step that should have ended already."""
+    clock, eng, cb, metrics, first = _held_queue(
+        monkeypatch,
+        step_ms={} if case == "no_step_time" else MINUTE,
+        capacity=1 if case == "one_slot" else 2)
+    try:
+        if case == "step_overdue":
+            clock.now += 61.0
+        # nine rows of a flat step pad to 32, which no cut has timed
+        rows = 9 if case == "no_lead_for_the_bucket" else 2
+        subs = [cb.submit(_row(1, n=rows))]
+        if case == "one_slot":
+            time.sleep(0.03)
+            assert len(eng.steps) == 1, "the one slot is taken"
+            eng.finish_step()
+        _wait(lambda: len(eng.steps) == 2)  # the clock never moved
+        assert eng.steps[1]["rows"] == rows
+    finally:
+        _let_go(eng)
+    for sub in [first] + subs:
+        sub.future.result(timeout=10)
+    hold, late = _hold_metrics(metrics)
+    assert hold["count"] == 2 and hold["sum"] == 0.0 and late == 0
+
+
+@pytest.mark.timeout(60)
+@pytest.mark.parametrize("release", ["flush", "close"])
+def test_flush_and_close_release_a_hold(monkeypatch, release):
+    clock, eng, cb, metrics, first = _held_queue(monkeypatch)
+    try:
+        subs = [cb.submit(_row(1)), cb.submit(_row(2))]
+        time.sleep(0.03)
+        assert len(eng.steps) == 1 and len(cb) == 2
+        getattr(cb, release)()
+        _wait(lambda: len(eng.steps) == 2)
+        assert eng.steps[1]["tags"] == [1.0, 2.0] and len(cb) == 0
+    finally:
+        _let_go(eng)
+    for sub in [first] + subs:
+        assert sub.future.result(timeout=10).shape == (1, 10)
+    if release == "close":
+        cb._thread.join(timeout=5)
+        assert not cb._thread.is_alive()
+        with pytest.raises(RuntimeError):
+            cb.submit(_row(3))
+
+
+@pytest.mark.timeout(60)
+def test_a_hold_that_outlives_its_step_counts_as_late(monkeypatch):
+    """The step ends while its successor is still held (a step shorter
+    than the least seen, or a dispatcher that woke late): the cut follows
+    at once, ``cut_hold_ms`` has the time it was held, ``cuts_late`` the
+    cut; the next cut, in time, does not count."""
+    clock, eng, cb, metrics, first = _held_queue(monkeypatch)
+    try:
+        second = cb.submit(_row(1))
+        time.sleep(0.03)
+        assert len(eng.steps) == 1
+        clock.now += 20.0
+        eng.finish_step()  # 40 s before the queue expected it
+        _wait(lambda: len(eng.steps) == 2)
+        hold, late = _hold_metrics(metrics)
+        assert hold["count"] == 2 and hold["sum"] == pytest.approx(20_000.0)
+        assert late == 1
+        _wait(lambda: len(cb._flying) == 1)
+        third = cb.submit(_row(2))
+        time.sleep(0.03)
+        assert len(eng.steps) == 2, "held again, behind the second step"
+        clock.now += 60.0
+        _poke(cb)
+        _wait(lambda: len(eng.steps) == 3)
+        hold, late = _hold_metrics(metrics)
+        assert hold["count"] == 3 and hold["sum"] == pytest.approx(80_000.0)
+        assert late == 1
+    finally:
+        _let_go(eng)
+    for sub in (first, second, third):
+        sub.future.result(timeout=10)
+
+
+@pytest.mark.timeout(60)
+def test_hold_bookkeeping_survives_many_submitters():
+    """Eight threads submit while the dispatcher holds and cuts and the
+    device thread lands batches, with the interpreter switching threads
+    every 10 us: every row is answered, every cut observed once, and
+    nothing is left on the queue's list of what the device holds."""
+    eng = _RingEngine({8: 10.0, 32: 10.0})  # about twenty rows a step
+    metrics = MetricsRegistry()
+    cb = continuous_for(eng, BatchConfig(buckets=(8, 32), max_batch=32))
+    cb.bind(metrics, "inference-bolt")
+    subs, lock = [], threading.Lock()
+
+    def feed(tag):
+        for _ in range(60):
+            sub = cb.submit(_row(tag))
+            with lock:
+                subs.append(sub)
+            time.sleep(0.004)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=feed, args=(t,), daemon=True)
+                   for t in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        cb.flush()
+        for sub in subs:
+            assert sub.future.result(timeout=30).shape == (1, 10)
+    finally:
+        sys.setswitchinterval(interval)
+        eng.run_free()
+    assert len(subs) == 8 * 60 and cb.rows_dispatched == len(subs)
+    assert len(cb) == 0 and cb.inflight == 0 and not cb._flying
+    hold, _ = _hold_metrics(metrics)
+    assert hold["count"] == cb.batches == len(eng.steps)
+    assert hold["sum"] > 0.0, "no cut was ever held"
