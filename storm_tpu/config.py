@@ -110,6 +110,16 @@ class BatchConfig:
                 self.max_batch,
             )
 
+    def clipped(self, max_rows: Optional[int]) -> "BatchConfig":
+        """This policy with no bucket over ``max_rows`` (a model's bound on
+        the rows of one step, ``ModelDef.max_rows``); itself where there is
+        no bound or nothing is over it."""
+        if max_rows is None or self.max_batch <= max_rows:
+            return self
+        return dataclasses.replace(
+            self, max_batch=int(max_rows),
+            buckets=tuple(b for b in self.buckets if b < max_rows))
+
     def bucket_for(self, n: int) -> int:
         for b in self.buckets:
             if n <= b:

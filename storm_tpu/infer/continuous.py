@@ -627,6 +627,9 @@ class ContinuousBatcher:
                 for key, _ in DEVICE_SUBSTAGES:
                     if key in timings:
                         self._m["substage"][key].observe(timings[key])
+            aux = getattr(handle, "aux", None) if handle else None
+            if aux:
+                self._observe_aux(aux)
         if self._flight is not None:
             self._flight.event(
                 "batch_formed", throttle_s=1.0,
@@ -640,6 +643,23 @@ class ContinuousBatcher:
             it.future.set_result(out[ofs:ofs + n])
             ofs += n
         self._notify(items)
+
+    def _observe_aux(self, aux: dict) -> None:
+        """What the model counted on the device during this step (its
+        ``new_state["aux"]``, fetched with the predictions), into the
+        registry. An expert layer reports the tokens routed to each expert
+        it holds (``expert_tokens``, a row a layer) and the assignments that
+        fell on experts held elsewhere (``expert_absent``): two counters,
+        and once a layer and step the busiest held expert over the mean."""
+        tokens, absent = aux.get("expert_tokens"), aux.get("expert_absent")
+        if tokens is None or absent is None:
+            return
+        m, cid = self._metrics, self._cid
+        m.counter(cid, "expert_assignments_held").inc(int(tokens.sum()))
+        m.counter(cid, "expert_assignments_absent").inc(int(absent.sum()))
+        load = m.histogram(cid, "expert_tokens_max_over_mean")
+        for layer in tokens:
+            load.observe(float(layer.max()) / max(float(layer.mean()), 1e-9))
 
     @staticmethod
     def _notify(items: List[Submission]) -> None:
@@ -755,6 +775,8 @@ def continuous_for(engine, cfg: BatchConfig,
     buckets: the latest caller's (:meth:`ContinuousBatcher.configure`) —
     the engine cache outlives a topology, and the next one's deadline
     must not be the last one's."""
+    # no batch over what the engine's model lets a step hold
+    cfg = cfg.clipped(getattr(engine, "max_rows", None))
     cb = vars(engine).get("_continuous_queue")
     if cb is None:
         new = ContinuousBatcher(engine, cfg, qos)

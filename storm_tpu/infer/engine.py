@@ -185,7 +185,8 @@ class InflightBatch:
     """
 
     __slots__ = ("future", "n", "padded", "timings", "profile_key", "_out",
-                 "_buf", "_t_put", "_t_launched", "watchdog_ms", "on_done")
+                 "_buf", "_t_put", "_t_launched", "watchdog_ms", "on_done",
+                 "aux")
 
     def __init__(self, n: int, padded: int) -> None:
         self.future: Future = Future()
@@ -196,6 +197,11 @@ class InflightBatch:
         # (set by dispatch; None = don't profile, e.g. test doubles).
         self.profile_key: Optional[str] = None
         self._out = None  # device array, dropped after fetch
+        # What the model counted on the device during this step (its
+        # ``new_state["aux"]``): device arrays from launch, host arrays once
+        # the fetch thread has brought them over with the result. None for
+        # a model that counts nothing.
+        self.aux = None
         self._buf = None  # staging buffer, recycled after fetch
         # staged on the host, device_put + launch next; 0.0 = not to be
         # timed (a program's first call compiles or loads it)
@@ -248,6 +254,8 @@ def _fetch_loop(fetch_q: "queue.SimpleQueue", ring: threading.Semaphore,
                     step_ms[handle.padded] = step
             prev_ready = t1
             res = np.asarray(handle._out)
+            if handle.aux is not None:
+                handle.aux = jax.tree.map(np.asarray, handle.aux)
             t2 = time.perf_counter()
             handle.timings["compute_ms"] = (t1 - handle._t_launched) * 1e3
             handle.timings["d2h_ms"] = (t2 - t1) * 1e3
@@ -426,14 +434,21 @@ class InferenceEngine:
     ) -> None:
         self.model_cfg = model_cfg
         self.sharding_cfg = sharding_cfg or ShardingConfig()
-        self.batch_cfg = batch_cfg or BatchConfig()
         self.model: ModelDef = build_model(
             model_cfg.name,
             num_classes=model_cfg.num_classes,
             input_shape=tuple(model_cfg.input_shape),
             **getattr(model_cfg, "extra", {}),
         )
+        # The configured buckets, none over the rows the model lets a step
+        # hold (None for most: the policy as given, the same object).
+        self.max_rows = self.model.max_rows
+        self.batch_cfg = (batch_cfg or BatchConfig()).clipped(self.max_rows)
         self.dtype = jnp.dtype(model_cfg.dtype)
+        # The type a staged batch reaches the device in: the compute type,
+        # unless the model's instances are not to be rounded to it (ids).
+        self.in_dtype = (jnp.dtype(self.model.input_dtype)
+                         if self.model.input_dtype else self.dtype)
         # Serving parallelism beyond DP: at most ONE of tp/sp/ep sizes the
         # mesh's second axis (composing them needs a 3D mesh — train-side
         # territory; serving keeps one knob per engine).
@@ -625,24 +640,32 @@ class InferenceEngine:
         # a bucket's program is traced (once a bucket, jit keeps the trace),
         # read by :func:`engine_inventory`; nothing in the program reads it.
         forms = self.program_forms = {}
+        # A model that counts on the device (tokens per expert) carries an
+        # ``"aux"`` entry in its state, in and out: its program then returns
+        # ``(predictions, aux)`` and the fetch thread brings both over. Any
+        # other model's program returns the predictions alone, as ever.
+        has_aux = self._has_aux = isinstance(state, dict) and "aux" in state
 
         def fwd(params, state, x):
             if w8:
                 params = dequantize_params(params, dtype, keep_dense=w8_fused)
             with dispatch_notes() as seen:
                 if sp > 1:
-                    logits, _ = apply_sp(params, state, x, mesh_ref, "seq",
-                                         train=False)
+                    logits, new_state = apply_sp(params, state, x, mesh_ref,
+                                                 "seq", train=False)
                 else:
-                    logits, _ = apply(params, state, x, train=False)
+                    logits, new_state = apply(params, state, x, train=False)
             forms[x.shape[0]] = ", ".join(seen)
             logits = logits.astype(jnp.float32)
-            return jax.nn.softmax(logits, axis=-1) if softmax else logits
+            out = jax.nn.softmax(logits, axis=-1) if softmax else logits
+            return (out, new_state["aux"]) if has_aux else out
 
+        out_shardings = ((out_shard, replicated(self.mesh)) if has_aux
+                         else out_shard)
         self._fwd = jax.jit(
             fwd,
             in_shardings=(p_shardings, replicated(self.mesh), x_shard),
-            out_shardings=out_shard,
+            out_shardings=out_shardings,
         )
         # uint8 transfer path: the wire carries affine-quantized bytes plus a
         # per-batch (scale, offset); dequantization runs on device inside the
@@ -662,7 +685,7 @@ class InferenceEngine:
                 replicated(self.mesh),
                 replicated(self.mesh),
             ),
-            out_shardings=out_shard,
+            out_shardings=out_shardings,
         )
         self._x_sharding = x_shard
         self._scalar_sharding = replicated(self.mesh)
@@ -741,7 +764,7 @@ class InferenceEngine:
             n = self.pad_batch(b)
             if n in self.compiled_batches:
                 continue
-            x = np.zeros((n, *self.input_shape), self.dtype)
+            x = np.zeros((n, *self.input_shape), self.in_dtype)
             np.asarray(self.predict(x))
         if any(self.program_forms.values()):
             logger.info("engine %s programs by bucket: %s", self.model_cfg.name,
@@ -866,7 +889,7 @@ class InferenceEngine:
                 out = self._fwd_q(self.params, self.state, xd, scale, offset)
         else:
             buf = self._staging.acquire((padded, *self.input_shape),
-                                        self.dtype)
+                                        self.in_dtype)
             handle._buf = buf
             self._stage(buf, parts, n)
             # Copy ledger: the ONE fused host-side write of the
@@ -892,6 +915,8 @@ class InferenceEngine:
                     self.on_compile(padded, (t1 - t0) * 1e3)
                 except Exception:
                     pass  # an observability hook must never fail a batch
+        if self._has_aux:
+            out, handle.aux = out
         hold = self._chaos_hang_s()
         if hold > 0:
             out = _HangingResult(out, time.monotonic() + hold)
@@ -996,15 +1021,19 @@ class InferenceEngine:
             with self._lock:
                 xd = jax.device_put(xw, self._x_sharding)
                 out = self._fwd_q(self.params, self.state, xd, scale, offset)
+                if self._has_aux:
+                    out = out[0]
                 gathered = self._gather_locked(out)
         else:
             # Cast on the HOST (ml_dtypes gives numpy a bfloat16) so the
             # host->device transfer ships half the bytes of f32.
-            if x.dtype != self.dtype:
-                x = x.astype(self.dtype)
+            if x.dtype != self.in_dtype:
+                x = x.astype(self.in_dtype)
             with self._lock:
                 xd = jax.device_put(x, self._x_sharding)
                 out = self._fwd(self.params, self.state, xd)
+                if self._has_aux:  # the serialized path reports none
+                    out = out[0]
                 gathered = self._gather_locked(out)
         self.compiled_batches.add(padded)
         if cold:

@@ -1,0 +1,286 @@
+"""Kimi-Linear (``model_type`` ``kimi_linear``) as a scorer of token records:
+a window of token ids in, the next-token distribution at its last position
+out, through the same engine and topology as every other model.
+
+Every block is ``x += mixer(RMSNorm(x)); x += ffn(RMSNorm(x))``. Three mixers
+in four are Kimi Delta Attention (:mod:`storm_tpu.ops.kda`: a gated
+delta-rule state per head, computed in chunks), the fourth is multi-head
+latent attention with no rotary embedding (``mla_use_nope``), causal,
+query/key heads of 128 + 64 against value heads of 128
+(:func:`storm_tpu.ops.attention.causal_attention`). The first
+``first_dense`` blocks have a dense SwiGLU; every later one the dropless
+top-k expert layer with a shared expert
+(:func:`storm_tpu.parallel.moe.topk_moe_layer`).
+
+**One chip's share.** The builder is told how many routed experts and how
+many rows of the vocabulary this chip holds (``experts_held`` from
+``first_expert``, ``num_classes`` rows of embedding and of head): what one of
+the chips that share each layer holds under expert parallelism. The router
+keeps its published width and its experts per token; what the experts held
+elsewhere would add is left out, and that partial result goes on to the next
+layer. Ids are taken from the held slice and the distribution is over it.
+
+The step's auxiliaries ride ``new_state["aux"]``: per expert layer the tokens
+routed to each held expert and the assignments that fell on absent ones. The
+engine fetches them with the predictions (``infer/engine.py``).
+
+What the published ``config.json`` does not fix is set as the released code
+sets it and listed under ``assumed`` in the benchmark's configuration file:
+the decay's parametrisation (``g = -exp(A_log) * softplus(W_up W_down x +
+dt_bias)``, rank ``head_dim``), the output gate's rank, the initialisers.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from storm_tpu.models.registry import ModelDef, register
+from storm_tpu.ops import kda
+from storm_tpu.ops import layers as L
+from storm_tpu.ops.attention import causal_attention
+from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
+
+
+def _w(rng, fan_in: int, fan_out: int):
+    return L.lecun_normal(rng, (fan_in, fan_out), fan_in)
+
+
+def kda_mixer_init(rng, dim: int, heads: int, head_dim: int,
+                   conv: int) -> dict:
+    wide = heads * head_dim
+    ks = jax.random.split(rng, 14)
+    # the decay as the released code starts it: A in [1, 16], a step of
+    # 0.001-0.1 through the softplus
+    step = jnp.exp(jax.random.uniform(ks[12], (wide,), jnp.float32,
+                                      math.log(1e-3), math.log(1e-1)))
+    return {
+        "q": _w(ks[0], dim, wide), "k": _w(ks[1], dim, wide),
+        "v": _w(ks[2], dim, wide),
+        "conv_q": kda.short_conv_init(ks[3], wide, conv),
+        "conv_k": kda.short_conv_init(ks[4], wide, conv),
+        "conv_v": kda.short_conv_init(ks[5], wide, conv),
+        "f_down": _w(ks[6], dim, head_dim), "f_up": _w(ks[7], head_dim, wide),
+        "a_log": jnp.log(jax.random.uniform(ks[8], (heads,), jnp.float32,
+                                            1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "beta": _w(ks[9], dim, heads),
+        "g_down": _w(ks[10], dim, head_dim),
+        "g_up": _w(ks[11], head_dim, wide),
+        "o_norm": L.rmsnorm_init(head_dim),
+        "o": _w(ks[13], wide, dim),
+    }
+
+
+def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
+              chunk: int, eps: float) -> jnp.ndarray:
+    b, s, _ = x.shape
+    f32 = jnp.float32
+
+    def heads_of(y):
+        return y.reshape(b, s, heads, head_dim)
+
+    def branch(name):
+        y = kda.short_conv(p["conv_" + name], L.matmul(x, p[name]))
+        return heads_of(jax.nn.silu(y))
+
+    q = kda.l2norm(branch("q")) * (head_dim ** -0.5)
+    k = kda.l2norm(branch("k"))
+    v = branch("v")
+    f = L.matmul(L.matmul(x, p["f_down"]), p["f_up"]).astype(f32)
+    g = -jnp.exp(p["a_log"].astype(f32))[:, None] * heads_of(
+        jax.nn.softplus(f + p["dt_bias"].astype(f32)))
+    beta = jax.nn.sigmoid(L.matmul(x, p["beta"]).astype(f32))
+    o = kda.kda_chunked(q.astype(x.dtype), k, v, g, beta, chunk=chunk)
+    gate = jax.nn.sigmoid(L.matmul(L.matmul(x, p["g_down"]), p["g_up"]))
+    o = L.rmsnorm(p["o_norm"], o, eps) * heads_of(gate)
+    return L.matmul(o.reshape(b, s, heads * head_dim), p["o"])
+
+
+def mla_mixer_init(rng, dim: int, heads: int, nope: int, rope: int,
+                   v_dim: int, kv_rank: int) -> dict:
+    ks = jax.random.split(rng, 4)
+    return {
+        "q": _w(ks[0], dim, heads * (nope + rope)),
+        "kv_a": _w(ks[1], dim, kv_rank + rope),
+        "kv_norm": L.rmsnorm_init(kv_rank),
+        "kv_b": _w(ks[2], kv_rank, heads * (nope + v_dim)),
+        "o": _w(ks[3], heads * v_dim, dim),
+    }
+
+
+def mla_mixer(p: dict, x: jnp.ndarray, heads: int, nope: int, rope: int,
+              v_dim: int, kv_rank: int, eps: float) -> jnp.ndarray:
+    """Latent attention without rotary: the ``rope`` channels are plain
+    query/key channels, the key's shared by every head."""
+    b, s, _ = x.shape
+    q = L.matmul(x, p["q"]).reshape(b, s, heads, nope + rope)
+    kv_a = L.matmul(x, p["kv_a"])
+    latent = L.rmsnorm(p["kv_norm"], kv_a[..., :kv_rank], eps)
+    k_shared = kv_a[..., kv_rank:]  # (B, S, rope)
+    kv = L.matmul(latent, p["kv_b"]).reshape(b, s, heads, nope + v_dim)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_shared[:, :, None, :], (b, s, heads, rope))], -1)
+    out = causal_attention(*(y.transpose(0, 2, 1, 3)
+                             for y in (q, k, kv[..., nope:])),
+                           scale=(nope + rope) ** -0.5)
+    return L.matmul(out.transpose(0, 2, 1, 3).reshape(b, s, heads * v_dim),
+                    p["o"])
+
+
+def build_kimi_linear(
+    name: str,
+    num_classes: int,
+    input_shape: tuple,
+    *,
+    dim: int,
+    layers: int,
+    kda_heads: int,
+    kda_head_dim: int,
+    conv: int,
+    mla_heads: int,
+    nope: int,
+    rope: int,
+    v_dim: int,
+    kv_rank: int,
+    dense_width: int,
+    expert_width: int,
+    n_experts: int,
+    top_k: int,
+    experts_held: int,
+    first_expert: int = 0,
+    first_dense: int = 1,
+    full_attention_every: int = 4,
+    routed_scale: float = 2.446,
+    eps: float = 1e-5,
+    chunk: int = 64,
+    expert_tile: int = 1024,
+    max_rows: int = 8,
+    published_layers: int = 27,
+) -> ModelDef:
+    """Layers ``1..layers`` of the published stack (layer ``i`` is latent
+    attention where ``i`` is a multiple of ``full_attention_every``, KDA
+    otherwise; dense feed-forward up to ``first_dense``, experts after) over
+    ``num_classes`` rows of the vocabulary."""
+    (seq,) = input_shape
+    vocab = num_classes
+    # Every residual branch's output projection starts smaller by the root of
+    # the number of branches in the whole published stack (two a layer), as
+    # GPT-2 and Megatron start a deep stack: the stream then keeps the scale
+    # of the embedding whatever the depth, and no one branch (nor one expert
+    # a rounding sent a token to) outweighs it.
+    branch = (2 * published_layers) ** -0.5
+
+    def is_mla(i):  # layers count from 1
+        return i % full_attention_every == 0
+
+    def init(rng):
+        ks = jax.random.split(rng, 2 * layers + 2)
+        blocks = []
+        for i in range(1, layers + 1):
+            km, kf = ks[2 * i], ks[2 * i + 1]
+            mixer = (mla_mixer_init(km, dim, mla_heads, nope, rope, v_dim,
+                                    kv_rank) if is_mla(i) else
+                     kda_mixer_init(km, dim, kda_heads, kda_head_dim, conv))
+            mixer["o"] = mixer["o"] * branch
+            if i <= first_dense:
+                ffn = L.swiglu_init(kf, dim, dense_width)
+                ffn["down"] = ffn["down"] * branch
+            else:
+                ffn = topk_moe_init(kf, dim, expert_width, n_experts,
+                                    experts_held)
+                for part in (ffn["experts"], ffn["shared"]):
+                    part["down"] = part["down"] * branch
+            blocks.append({"norm1": L.rmsnorm_init(dim), "mixer": mixer,
+                           "norm2": L.rmsnorm_init(dim), "ffn": ffn})
+        params = {
+            "embed": jax.random.normal(ks[0], (vocab, dim), jnp.float32),
+            "layers": blocks,
+            "norm": L.rmsnorm_init(dim),
+            "head": _w(ks[1], dim, vocab),
+        }
+        n_moe = max(0, layers - first_dense)
+        # what a step counts on the device, in the state in and out
+        aux = {"expert_tokens": jnp.zeros((n_moe, experts_held), jnp.int32),
+               "expert_absent": jnp.zeros((n_moe,), jnp.int32)}
+        return params, {"aux": aux} if n_moe else {}
+
+    def apply(params, state, x, train: bool = False):
+        # ids ride the float32 instance contract (exact under 2^24)
+        ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
+                       vocab - 1).astype(jnp.int32)
+        dtype = params["head"].dtype
+        # The stream is float32 whatever the compute type: a bfloat16 stream
+        # is rounded at each of its ten adds, and a router reading it sends
+        # three times as many tokens to another expert than the reference
+        # does. The branches compute in ``dtype``.
+        h = params["embed"][ids].astype(jnp.float32)
+        tokens, absent = [], []
+        for i, blk in enumerate(params["layers"], start=1):
+            y = L.rmsnorm(blk["norm1"], h, eps).astype(dtype)
+            if is_mla(i):
+                y = mla_mixer(blk["mixer"], y, mla_heads, nope, rope, v_dim,
+                              kv_rank, eps)
+            else:
+                y = kda_mixer(blk["mixer"], y, kda_heads, kda_head_dim,
+                              chunk, eps)
+            h = h + y.astype(jnp.float32)
+            y = L.rmsnorm(blk["norm2"], h, eps)
+            if "router" in blk["ffn"]:  # routes from the float32 stream
+                y, t, a = topk_moe_layer(
+                    blk["ffn"], y, top_k, first_expert=first_expert,
+                    router="sigmoid", renormalize=True, scale=routed_scale,
+                    tile=expert_tile)
+                tokens.append(t)
+                absent.append(a)
+            else:
+                y = L.swiglu(blk["ffn"], y.astype(dtype))
+            h = h + y.astype(jnp.float32)
+        last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
+        logits = L.matmul(last, params["head"])
+        if not tokens:
+            return logits, state
+        return logits, {**state, "aux": {
+            "expert_tokens": jnp.stack(tokens),
+            "expert_absent": jnp.stack(absent)}}
+
+    return ModelDef(
+        name, (seq,), vocab, init, apply, max_rows=max_rows,
+        input_dtype="float32",
+        hyper={"dim": dim, "layers": layers, "kda_heads": kda_heads,
+               "kda_head_dim": kda_head_dim, "mla_heads": mla_heads,
+               "n_experts": n_experts, "top_k": top_k,
+               "experts_held": experts_held, "first_expert": first_expert,
+               "chunk": chunk, "input_shape": (seq,),
+               "num_classes": vocab})
+
+
+@register("kimi_linear_48b")
+def build_kimi_linear_48b(num_classes: int = 20480,
+                          input_shape: tuple = (4096,)) -> ModelDef:
+    """Kimi-Linear-48B-A3B at its published widths, as one chip of the eight
+    that share each layer holds it: layers 1-5 (dense, then KDA, KDA, MLA,
+    KDA with experts), routed experts 0-31 of 256, an eighth of the
+    vocabulary; 1.28 B parameters here. The layers left out lie on further
+    pipeline stages."""
+    return build_kimi_linear(
+        "kimi_linear_48b", num_classes, tuple(input_shape), dim=2304,
+        layers=5, kda_heads=32, kda_head_dim=128, conv=4, mla_heads=32,
+        nope=128, rope=64, v_dim=128, kv_rank=512, dense_width=9216,
+        expert_width=1024, n_experts=256, top_k=8, experts_held=32)
+
+
+@register("kimi_linear_tiny")
+def build_kimi_linear_tiny(num_classes: int = 96,
+                           input_shape: tuple = (40,)) -> ModelDef:
+    """The same code at toy widths, all four kinds of layer: for the tests
+    and the benchmark's rehearsal on the CPU."""
+    return build_kimi_linear(
+        "kimi_linear_tiny", num_classes, tuple(input_shape), dim=64,
+        layers=5, kda_heads=2, kda_head_dim=16, conv=4, mla_heads=2,
+        nope=16, rope=8, v_dim=16, kv_rank=24, dense_width=128,
+        expert_width=32, n_experts=8, top_k=2, experts_held=4, chunk=16,
+        expert_tile=16, max_rows=8, published_layers=8)

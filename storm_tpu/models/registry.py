@@ -43,6 +43,15 @@ class ModelDef:
     # into a 2-head model and silently computes wrong outputs — ADVICE r3
     # medium). Saved alongside checkpoints and validated at load.
     hyper: Any = None
+    # The most rows one device step may hold; None: whatever the batch
+    # policy says. A model whose row is thousands of tokens states it, and
+    # the engine clips its buckets to it (``BatchConfig.clipped``): bucket
+    # 256 of 4,096-token rows would be a million tokens a step.
+    max_rows: Optional[int] = None
+    # The type instances reach the device in; None: the engine's compute
+    # type. Token ids ride the float32 instance contract exactly (under
+    # 2^24) and would not survive a cast to bfloat16.
+    input_dtype: Any = None
 
 
 _BUILDERS: Dict[str, Callable[..., ModelDef]] = {}
@@ -60,6 +69,7 @@ def _load_builtin() -> None:
     # Import model modules lazily so registration happens on demand.
     from storm_tpu.models import (  # noqa: F401
         chartiny,
+        kimi_linear,
         lenet,
         longseq,
         mixer,

@@ -163,6 +163,17 @@ def layernorm(p: dict, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def rmsnorm_init(dim: int, dtype=jnp.float32) -> dict:
+    return {"scale": jnp.ones((dim,), dtype)}
+
+
+def rmsnorm(p: dict, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
+    """x / rms(x) * scale over the last axis, the statistics in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * p["scale"]).astype(x.dtype)
+
+
 # ---- activations -------------------------------------------------------------
 
 relu = jax.nn.relu
@@ -199,3 +210,25 @@ def depthwise_conv2d(
         feature_group_count=c,
         preferred_element_type=jnp.float32,
     ).astype(x.dtype)
+
+
+# ---- gated feed-forward ------------------------------------------------------
+
+
+def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """``x @ w`` accumulated in float32 on the matrix unit, in x's type."""
+    return jnp.dot(x, w.astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def swiglu_init(rng, dim: int, hidden: int, dtype=jnp.float32) -> dict:
+    kg, ku, kd = jax.random.split(rng, 3)
+    return {"gate": lecun_normal(kg, (dim, hidden), dim, dtype),
+            "up": lecun_normal(ku, (dim, hidden), dim, dtype),
+            "down": lecun_normal(kd, (hidden, dim), hidden, dtype)}
+
+
+def swiglu(p: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """down(silu(gate x) * up x): the bias-free gated feed-forward."""
+    return matmul(jax.nn.silu(matmul(x, p["gate"])) * matmul(x, p["up"]),
+                  p["down"])

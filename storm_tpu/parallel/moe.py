@@ -17,11 +17,18 @@ Capacity semantics: each expert processes at most
 ``ceil(tokens / E * capacity_factor)``; overflow tokens are dropped (their
 output is 0 through the residual connection) — standard GShard/Switch
 behavior, deterministic and shape-static for XLA.
+
+:func:`topk_moe_layer` is the other kind (DeepSeek-V3 / Kimi style): top-k of
+a wide router, **no token dropped**, a shared expert beside the routed ones,
+and the layer is told which experts it holds: it routes over the router's
+whole width and computes the part of the result that its own experts give
+(what one chip of an expert-parallel group computes before the exchange).
 """
 
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +122,138 @@ def moe_layer(
     aux = aux_loss_weight * e * jnp.sum(frac_tokens * mean_prob)
 
     return y.astype(x.dtype).reshape(orig_shape), aux
+
+
+def topk_moe_init(rng, dim: int, hidden: int, n_experts: int,
+                  n_held: Optional[int] = None, shared: bool = True,
+                  dtype=jnp.float32) -> dict:
+    """A router over ``n_experts`` with its selection bias, ``n_held`` SwiGLU
+    experts of width ``hidden`` stacked on a leading axis (all of them where
+    ``n_held`` is None), and the shared expert."""
+    from storm_tpu.ops import layers as L
+
+    n_held = n_experts if n_held is None else n_held
+    kr, kb, kg, ku, kd, ks = jax.random.split(rng, 6)
+    p = {
+        "router": L.lecun_normal(kr, (dim, n_experts), dim, dtype),
+        "router_bias": jax.random.normal(kb, (n_experts,), dtype) * 0.05,
+        "experts": {
+            "gate": L.lecun_normal(kg, (n_held, dim, hidden), dim, dtype),
+            "up": L.lecun_normal(ku, (n_held, dim, hidden), dim, dtype),
+            "down": L.lecun_normal(kd, (n_held, hidden, dim), hidden, dtype),
+        },
+    }
+    if shared:
+        p["shared"] = L.swiglu_init(ks, dim, hidden, dtype)
+    return p
+
+
+def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
+               router: str = "sigmoid", renormalize: bool = True,
+               scale: float = 1.0):
+    """``(experts, weights)``, both ``(N, top_k)``: the ``top_k`` largest of
+    score + selection bias, weighted by the score alone (over their sum where
+    ``renormalize``) times ``scale``. Scores in float32 from a product at
+    ``highest`` precision: a tie broken the other way sends a token to
+    another expert."""
+    logits = jnp.dot(tokens.astype(jnp.float32),
+                     p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if router == "sigmoid":
+        score = jax.nn.sigmoid(logits)
+    elif router == "softmax":
+        score = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router {router!r}")
+    chosen = score
+    if "router_bias" in p:
+        chosen = score + p["router_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(chosen, top_k)
+    weights = jnp.take_along_axis(score, experts, axis=-1)
+    if renormalize:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return experts, weights * scale
+
+
+def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
+                   router: str = "sigmoid", renormalize: bool = True,
+                   scale: float = 1.0, tile: int = 1024):
+    """Dropless top-``top_k`` expert layer over ``(..., dim)`` activations.
+
+    The layer holds experts ``first_expert ..`` (as many as its stacked
+    weights have) of the router's width, and returns ``(y, tokens, absent)``:
+    ``y`` the held experts' part of the routed sum plus the shared expert,
+    ``tokens`` ``(held,)`` how many tokens went to each held expert, and
+    ``absent`` how many assignments fell on experts held elsewhere (their
+    part is another chip's to add).
+
+    The grouped product: assignments are sorted by expert, each expert's
+    run is cut into tiles of ``tile`` rows, and a loop over as many tiles as
+    the routing made gathers a tile's tokens, runs that expert's SwiGLU on
+    them and writes the weighted result to the tile's place in a buffer; a
+    token's result is then the sum of its assignments' rows there (a gather:
+    the chip scatters a row at a time, a thousand times slower). The loop's
+    length is the data's, so whatever the routing no token is dropped and no
+    padding up to a capacity is computed; the only waste is each run's last,
+    partly filled tile. The buffer alone has the worst case's size."""
+    from storm_tpu.ops import layers as L
+
+    shape = x.shape
+    dim = shape[-1]
+    w = p["experts"]
+    held = w["gate"].shape[0]
+    # the router reads ``x`` as it comes (float32 from a float32 stream: a
+    # rounded input breaks ties the other way); the experts compute in the
+    # type of their weights
+    experts, weights = route_topk(p, x.reshape(-1, dim), top_k, router,
+                                  renormalize, scale)
+    x = x.astype(w["gate"].dtype)
+    tokens = x.reshape(-1, dim)
+    n = tokens.shape[0]
+    tile = max(8, min(int(tile), -(-n // 8) * 8))
+    most_tiles = -(-n * top_k // tile) + held
+    local = experts - first_expert
+    local = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order = jnp.argsort(local, stable=True)  # by expert, tokens ascending
+    counts_all = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+    counts, absent = counts_all[:held], counts_all[held]
+    starts = jnp.cumsum(counts) - counts
+    tiles = -(-counts // tile)
+    tile_ends = jnp.cumsum(tiles)
+    # a slice of ``tile`` from any start inside the assignments stays inside
+    token_of = jnp.pad((order // top_k).astype(jnp.int32), (0, tile))
+    weight_of = jnp.pad(weights.reshape(-1)[order], (0, tile))
+    lane = jnp.arange(tile, dtype=jnp.int32)
+
+    def one_tile(i, out):
+        e = jnp.searchsorted(tile_ends, i, side="right").astype(jnp.int32)
+        j = i - (tile_ends[e] - tiles[e])  # this tile within its expert's run
+        start = starts[e] + j * tile
+        valid = lane < counts[e] - j * tile  # rows past the run's end: zero
+        ids = jax.lax.dynamic_slice(token_of, (start,), (tile,))
+        rows = tokens[jnp.where(valid, ids, 0)]
+        y = L.swiglu({"gate": w["gate"][e], "up": w["up"][e],
+                      "down": w["down"][e]}, rows)
+        gain = jnp.where(valid, jax.lax.dynamic_slice(
+            weight_of, (start,), (tile,)), 0.0)
+        y = (y.astype(jnp.float32) * gain[:, None]).astype(out.dtype)
+        return jax.lax.dynamic_update_slice(out, y, (i * tile, 0))
+
+    # one row more than the tiles can fill: where absent assignments point
+    out = jax.lax.fori_loop(
+        0, tile_ends[-1], one_tile,
+        jnp.zeros((most_tiles * tile + 1, dim), x.dtype))
+    # where each assignment's row is: its expert's first tile, and its place
+    # in the expert's run
+    place = jnp.argsort(order).astype(jnp.int32)  # assignment -> sorted place
+    first_row = jnp.append((tile_ends - tiles) * tile - starts,
+                           most_tiles * tile)
+    row_of = jnp.where(local < held, place + first_row[local],
+                       most_tiles * tile)
+    y = jnp.sum(out[row_of.reshape(n, top_k)], axis=1, dtype=jnp.float32)
+    if "shared" in p:
+        y = y + L.swiglu(p["shared"], tokens).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(shape), counts, absent
 
 
 def moe_block_init(rng, dim: int, mlp_dim: int, num_heads: int, n_experts: int):

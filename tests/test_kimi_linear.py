@@ -1,0 +1,337 @@
+"""Kimi-Linear at toy widths on the CPU (hidden 64, 2 heads, 8 experts top-2,
+all four kinds of layer): each mechanism against its plain form, the shares of
+an expert layer against the whole, and the model through ``InferenceEngine``
+against the benchmark's reference (``benchmarks/references/kimi_linear.py``,
+float32 at ``highest``) on seeded weights. Probabilities over the whole
+vocabulary are compared, never an argmax: with random weights the largest
+logit changes on rounding."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmarks.core import spec  # noqa: E402
+from storm_tpu.config import BatchConfig, ModelConfig  # noqa: E402
+from storm_tpu.infer.engine import InferenceEngine  # noqa: E402
+from storm_tpu.models import kimi_linear as K  # noqa: E402
+from storm_tpu.models.registry import build_model, load_or_init  # noqa: E402
+from storm_tpu.ops import kda  # noqa: E402
+from storm_tpu.ops import layers as L  # noqa: E402
+from storm_tpu.ops.attention import causal_attention  # noqa: E402
+from storm_tpu.parallel.moe import (route_topk, topk_moe_init,  # noqa: E402
+                                    topk_moe_layer)
+
+REFERENCE = spec.plugin("references", "kimi_linear")
+TINY = spec.config("kimi_linear_tiny")
+SIZES = TINY["published"]
+
+
+def _distance(got, want):
+    """Euclidean distance of each row from its reference row over that row's
+    length: the benchmark's measure (``core/pairing.py``)."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.sqrt(((got - want) ** 2).sum(-1) / (want ** 2).sum(-1))
+
+
+def _recurrence(q, k, v, g, beta):
+    """The layer's definition, token by token: decay, delta rule, read."""
+    b, s, h, dk = q.shape
+
+    def token(state, xs):
+        q_t, k_t, v_t, g_t, b_t = xs
+        state = jnp.exp(g_t)[..., None] * state
+        read = jnp.einsum("bhk,bhkv->bhv", k_t, state)
+        state = state + b_t[..., None, None] * k_t[..., :, None] \
+            * (v_t - read)[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    xs = tuple(jnp.moveaxis(y, 1, 0) for y in (q, k, v, g, beta))
+    _, o = lax.scan(token, jnp.zeros((b, h, dk, v.shape[-1])), xs)
+    return jnp.moveaxis(o, 0, 1)
+
+
+@pytest.mark.parametrize("decay", [0.1, 3.0, 30.0],
+                         ids=["slow", "fast", "within-a-token"])
+def test_chunked_kda_is_the_recurrence(decay):
+    """150 tokens are no multiple of the chunk (64) nor of the sub-chunk.
+    At the fastest decay ``exp(-G_s)`` overflows float32 inside one chunk:
+    the chunked form must not be built from it. Both sides float32 at
+    ``highest``: they differ by summation order alone, 1e-5 of the largest
+    output is a hundred times that."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    b, s, h, d = 2, 150, 3, 32
+    q = kda.l2norm(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+    k = kda.l2norm(jax.random.normal(ks[1], (b, s, h, d)))
+    v = jax.random.normal(ks[2], (b, s, h, d))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    with jax.default_matmul_precision("highest"):
+        want = _recurrence(q, k, v, g, beta)
+        got = kda.kda_chunked(q, k, v, g, beta, chunk=64, sub=16)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
+
+
+def test_short_convolution_is_causal_and_matches_the_plain_form():
+    p = kda.short_conv_init(jax.random.PRNGKey(1), 6, 4)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 9, 6))
+    y = kda.short_conv(p, x)
+    want = jnp.stack([REFERENCE._conv(p, row) for row in x])
+    np.testing.assert_allclose(y, want, atol=1e-6)
+    # a later token changes nothing before it
+    y2 = kda.short_conv(p, x.at[:, 5].add(1.0))
+    np.testing.assert_array_equal(np.asarray(y[:, :5]), np.asarray(y2[:, :5]))
+    assert float(jnp.abs(y2[:, 5:9] - y[:, 5:9]).min()) >= 0
+
+
+def test_causal_attention_of_unequal_widths_against_the_plain_form():
+    """Query/key heads of 24 against value heads of 16, 40 tokens in blocks
+    of 16 (the last block ragged). Float32 both sides: 1e-5."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (2, 2, 40, 24))
+    k = jax.random.normal(ks[1], (2, 2, 40, 24))
+    v = jax.random.normal(ks[2], (2, 2, 40, 16))
+    scores = jnp.einsum("bhsd,bhtd->bhst", q, k) * 24 ** -0.5
+    mask = jnp.tril(jnp.ones((40, 40), bool))
+    want = jnp.einsum("bhst,bhtd->bhsd", jax.nn.softmax(
+        jnp.where(mask, scores, -jnp.inf), -1), v)
+    got = causal_attention(q, k, v, block=16)
+    assert got.shape == (2, 2, 40, 16)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_mla_mixer_against_the_reference_row_by_row():
+    p = K.mla_mixer_init(jax.random.PRNGKey(4), 64, 2, 16, 8, 16, 24)
+    x = jax.random.normal(jax.random.PRNGKey(5), (3, 40, 64))
+    with jax.default_matmul_precision("highest"):
+        got = K.mla_mixer(p, x, 2, 16, 8, 16, 24, 1e-5)
+        want = jnp.stack([REFERENCE._mla(p, row, SIZES, 1e-5) for row in x])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_kda_mixer_against_the_reference_row_by_row():
+    p = K.kda_mixer_init(jax.random.PRNGKey(6), 64, 2, 16, 4)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 40, 64))
+    with jax.default_matmul_precision("highest"):
+        got = K.kda_mixer(p, x, 2, 16, 16, 1e-5)
+        want = jnp.stack([REFERENCE._kda(p, row, SIZES, 1e-5) for row in x])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def _plain_experts(p, x, top_k, first, scale):
+    """Every held expert on every token, weighted by what the router gave
+    it: nothing grouped, nothing dropped."""
+    t = x.reshape(-1, x.shape[-1])
+    chosen, weight = route_topk(p, t, top_k, scale=scale)
+    y = jnp.zeros_like(t)
+    for e in range(p["experts"]["gate"].shape[0]):
+        gain = jnp.sum(jnp.where(chosen == e + first, weight, 0.0), -1)
+        y = y + gain[:, None] * L.swiglu(
+            {n: w[e] for n, w in p["experts"].items()}, t)
+    return y.reshape(x.shape)
+
+
+def _moe(seed=0, skew=None):
+    p = topk_moe_init(jax.random.PRNGKey(seed), 32, 48, 8)
+    if skew is not None:  # the selection bias sends every token to one expert
+        p["router_bias"] = p["router_bias"].at[skew].set(10.0)
+    return p, jax.random.normal(jax.random.PRNGKey(seed + 1), (3, 37, 32))
+
+
+@pytest.mark.parametrize("skew", [None, 3], ids=["even", "most-to-one"])
+def test_expert_layer_drops_no_token(skew):
+    """111 tokens, top-2 of 8, tiles of 16 rows. Under the skewed routing
+    expert 3 takes every token (seven tiles, the last partly filled) where
+    a capacity of 1.25 would keep 35. Float32 both sides: 1e-5."""
+    p, x = _moe(skew=skew)
+    with jax.default_matmul_precision("highest"):
+        y, tokens, absent = jax.jit(lambda p, x: topk_moe_layer(
+            p, x, 2, scale=2.446, tile=16))(p, x)
+        want = _plain_experts(p, x, 2, 0, 2.446) + L.swiglu(p["shared"], x)
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    assert int(tokens.sum()) == 2 * 111 and int(absent) == 0
+    if skew is not None:
+        assert int(tokens[skew]) == 111
+
+
+def test_the_shares_add_up_to_the_whole_layer():
+    """Four chips hold two experts each. The parts they compute, with the
+    shared expert counted once, are the uncut layer; the assignments each
+    sees as absent are those the others hold."""
+    p, x = _moe(skew=3)
+    with jax.default_matmul_precision("highest"):
+        whole = _plain_experts(p, x, 2, 0, 2.446) + L.swiglu(p["shared"], x)
+        total, seen = jnp.zeros_like(x), 0
+        for first in range(0, 8, 2):
+            share = {"router": p["router"], "router_bias": p["router_bias"],
+                     "experts": {n: w[first:first + 2]
+                                 for n, w in p["experts"].items()}}
+            y, tokens, absent = topk_moe_layer(
+                share, x, 2, first_expert=first, scale=2.446, tile=16)
+            assert int(tokens.sum()) + int(absent) == 2 * 111
+            total, seen = total + y, seen + int(tokens.sum())
+        total = total + L.swiglu(p["shared"], x)
+    assert seen == 2 * 111
+    np.testing.assert_allclose(total, whole, atol=1e-5)
+
+
+def test_softmax_router_and_no_bias():
+    p, x = _moe()
+    del p["router_bias"]
+    chosen, weight = route_topk(p, x.reshape(-1, 32), 2, router="softmax",
+                                renormalize=False)
+    probs = jax.nn.softmax(x.reshape(-1, 32) @ p["router"], -1)
+    np.testing.assert_allclose(weight, jnp.sort(probs, -1)[:, :-3:-1],
+                               rtol=1e-5)
+    assert chosen.shape == (111, 2)
+
+
+# ---- the whole model through the engine --------------------------------------
+
+def _windows(n, seed=3):
+    return spec.plugin("inputs", "token_ids").make(
+        n, (40,), seed).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def reference_rows():
+    model = build_model("kimi_linear_tiny")
+    params, state = load_or_init(model, None, 5)
+    x = _windows(16)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda p, s, xx: REFERENCE.forward(SIZES, p, s, xx))(
+            params, state, x)
+    return x, np.asarray(want)
+
+
+def _engine(dtype):
+    return InferenceEngine(ModelConfig(
+        name="kimi_linear_tiny", dtype=dtype, num_classes=96,
+        input_shape=(40,), seed=5), batch_cfg=BatchConfig())
+
+
+FLOAT32_TOLERANCE = 1e-4  # summation order alone: reads about 2e-6
+
+
+def test_model_through_the_engine_in_float32(reference_rows):
+    x, want = reference_rows
+    eng = _engine("float32")
+    got = np.concatenate([eng.predict(x[a:a + 8]) for a in (0, 8)])
+    assert got.shape == (16, 96)
+    assert _distance(got, want).max() < FLOAT32_TOLERANCE
+
+
+def test_bfloat16_is_held_to_its_own_tolerance_and_fails_float32s(
+        reference_rows):
+    """The branches compute in bfloat16 (8 bits of mantissa) beside a float32
+    stream: a row reads 0.005-0.01 from the reference (the cell's reading at
+    the published widths is 0.0045-0.0056). Beyond that, at 8 experts of
+    width 32 a rounding now and then flips which expert a token goes to, and
+    the row whose last token it was moves by 0.1-0.3 (the cell: 0.026): so
+    the typical row is held to 0.03 and the worst to 0.5 (another window's
+    answer is 0.4-1.1 away). The float32 tolerance fails on every row: a
+    lower precision than the one asked for is seen."""
+    x, want = reference_rows
+    eng = _engine("bfloat16")
+    got = np.concatenate([eng.predict(x[a:a + 8]) for a in (0, 8)])
+    err = _distance(got, want)
+    assert np.median(err) < 0.03 and err.max() < 0.5
+    assert err.min() > FLOAT32_TOLERANCE
+
+
+def test_ids_reach_the_model_unrounded():
+    """bfloat16 holds whole numbers exactly only up to 256; ids are staged
+    in float32 whatever the compute type."""
+    eng = _engine("bfloat16")
+    assert eng.in_dtype == jnp.float32 and eng.dtype == jnp.bfloat16
+    model = build_model("kimi_linear_tiny", num_classes=600)
+    assert model.input_dtype == "float32"
+    big = InferenceEngine(ModelConfig(
+        name="kimi_linear_tiny", dtype="bfloat16", num_classes=600,
+        input_shape=(40,), seed=5))
+    x = np.full((2, 40), 7, np.float32)
+    x[0, -1], x[1, -1] = 514, 515  # one bfloat16 value
+    a, b = big.predict(x)
+    assert np.abs(a - b).max() > 1e-4
+
+
+def test_buckets_are_clipped_to_the_models_bound_and_only_those_warm():
+    eng = _engine("float32")
+    assert eng.model.max_rows == 8 and eng.max_rows == 8
+    assert eng.batch_cfg.buckets == (8,) and eng.batch_cfg.max_batch == 8
+    eng.warmup()
+    assert eng.compiled_batches == {8}
+    policy = BatchConfig(max_batch=16, buckets=(2, 4, 16))
+    clipped = policy.clipped(8)
+    assert clipped.buckets == (2, 4, 8) and clipped.max_batch == 8
+    assert clipped.max_wait_ms == policy.max_wait_ms
+    # the queue that forms the batches is held to the same bound
+    from storm_tpu.infer.continuous import continuous_for
+
+    assert continuous_for(eng, BatchConfig()).cfg.max_batch == 8
+
+
+def test_a_model_without_a_bound_keeps_the_policy_as_given():
+    policy = BatchConfig()
+    assert policy.clipped(None) is policy and policy.clipped(256) is policy
+    eng = InferenceEngine(ModelConfig(name="vit_tiny", dtype="float32",
+                                      input_shape=(32, 32, 3)),
+                          batch_cfg=policy)
+    assert eng.batch_cfg is policy and eng.max_rows is None
+    assert eng.batch_cfg.buckets == (8, 32, 128, 256)
+    assert eng.in_dtype == eng.dtype and eng._has_aux is False
+
+
+def test_device_counters_ride_the_result_into_the_registry():
+    """Four expert layers, four held experts of eight, top-2: a step of 8
+    windows of 40 tokens makes 640 assignments a layer."""
+    from storm_tpu.infer.continuous import ContinuousBatcher
+    from storm_tpu.runtime.metrics import MetricsRegistry
+
+    eng = _engine("float32")
+    handle = eng.dispatch((_windows(8),))
+    handle.future.result(60)
+    aux = handle.aux
+    assert aux["expert_tokens"].shape == (4, 4)
+    assert isinstance(aux["expert_tokens"], np.ndarray)
+    per_layer = aux["expert_tokens"].sum(1) + aux["expert_absent"]
+    assert per_layer.tolist() == [640] * 4
+    registry = MetricsRegistry()
+    queue = ContinuousBatcher(eng, eng.batch_cfg)
+    queue.bind(registry, "inference-bolt")
+    queue._observe_aux(aux)
+    got = registry.snapshot()["inference-bolt"]
+    assert got["expert_assignments_held"] + got[
+        "expert_assignments_absent"] == 4 * 640
+    assert got["expert_tokens_max_over_mean"]["count"] == 4
+    assert got["expert_tokens_max_over_mean"]["max"] >= 1.0
+    # a model that counts nothing: nothing fetched, nothing observed
+    plain = InferenceEngine(ModelConfig(name="lenet5", dtype="float32"))
+    h = plain.dispatch((np.zeros((2, 28, 28, 1), np.float32),))
+    h.future.result(60)
+    assert h.aux is None
+
+
+def test_registry_names_the_model_and_its_share():
+    model = build_model("kimi_linear_48b")
+    assert model.input_shape == (4096,) and model.num_classes == 20480
+    assert model.max_rows == 8
+    params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert len(params["layers"]) == 5
+    assert "router" not in params["layers"][0]["ffn"]
+    assert params["layers"][1]["ffn"]["experts"]["gate"].shape == (
+        32, 2304, 1024)
+    assert params["layers"][1]["ffn"]["router"].shape == (2304, 256)
+    assert "kv_a" in params["layers"][3]["mixer"]
+    assert [("conv_q" in blk["mixer"]) for blk in params["layers"]] == [
+        True, True, True, False, True]
+    assert sum(x.size for x in jax.tree.leaves(params)) == 1_281_911_680
+    assert state["aux"]["expert_tokens"].shape == (4, 32)
