@@ -37,15 +37,68 @@ blocks below by block substitution, all in float32 at ``highest`` precision.
 The large products (with ``S``, ``U``) take their operands in the type of
 ``q`` (bfloat16 when serving) and accumulate in float32; ``S`` itself stays
 float32 from chunk to chunk.
+
+**Two forms of the within-chunk part, one contract.** What a chunk needs
+that does not depend on the state before it (``G``, the tables ``A`` and
+``A_qk``, the solve for ``W`` and ``U0`` with ``U = U0 - W S_0``, ``Q *
+exp(G)``, ``K * exp(G_C - G)``, ``exp(G_C)``) is built by ``_within_chunks``,
+jnp operations that XLA compiles, or by ``within_chunks_kernel``, one Pallas
+TPU call. The rules above hold in both; they differ in the order of their
+sums. The kernel's grid walks (head, block of ``_KERNEL_CHUNKS`` chunks); a
+grid step's block is those chunks' tokens by one head's lanes of ``q, k, v,
+g`` as the layer holds them, ``(B, S, H * d)``, so nothing is copied to cut a
+row out or to bring the heads forward. Inside a step a chunk at a time: its
+``(C, d)`` operands, both ``C x C`` tables and the six results stay in VMEM,
+and HBM sees each input and each result once, where XLA's form makes some
+forty passes over a row's float32 arrays. A call writes its row into room
+for the whole batch (``empty_tables``) laid out as the chain over chunks
+reads it, chunks first, so the loop over rows stacks and transposes nothing.
+The kernel's sub-chunk is 8, one float32 sublane tile; its triangle is solved
+by forward substitution row by row on the vector unit in float32, on ``[W |
+U0]`` directly. It is bound by the unit that moves values across lanes (a sum
+over channels for every pair of a diagonal block; a column of ``A`` spread
+over the lanes for every row that leaves a sub-chunk below it), not by HBM or
+the matrix unit.
+
+``tables_form`` says which form a program is built with, from what the code
+can observe and no option: the kernel on a TPU (``STORM_TPU_NO_PALLAS`` off)
+in a process with one device (a Mosaic call has no partitioning rule), for
+heads of whole lane tiles (``dk`` and ``dv`` multiples of 128) and chunks of
+whole sub-chunks; XLA's form elsewhere (the CPU, a host with several chips,
+``kimi_linear_tiny``'s 16-wide heads). ``platform.note("kda_tables", form)``
+records the choice for ``engine_inventory()["programs"]``.
+
+**Both forms run a row of the batch at a time, under one loop** (``lax.map``;
+for the kernel ``lax.fori_loop``, which carries the room it writes into).
+For XLA's form that bounds the float32 temporaries to one row's. For both it
+is one ``while`` in the compiled program whose event in a device trace spans
+the tables' whole time: the benchmark's ``kda_scan_ms`` finds KDA as the
+loops that carry the stacked ``A_qk``, and with the kernel inside that loop
+(its grid over one row's heads and chunks, not over rows) the metric keeps
+reading tables plus chain.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from storm_tpu.ops.platform import note as _note
+from storm_tpu.ops.platform import one_device as _one_device
+from storm_tpu.ops.platform import use_pallas as _use_pallas
 
 _HI = lax.Precision.HIGHEST
+# The kernel's own sub-chunk: one float32 sublane tile (half the pair-by-pair
+# work of 16, and a sub-chunk's rows are then one register's).
+_KERNEL_SUB = 8
+# Chunks of one head a grid step of the kernel holds in VMEM: blocks of 128 KB
+# to 256 KB an operand; 16 measured the same on the v5e.
+_KERNEL_CHUNKS = 8
 
 
 def short_conv_init(rng, channels: int, width: int = 4,
@@ -165,6 +218,188 @@ def _within_chunks(q, k, v, g, beta, sub: int):
             a_qk.astype(cd), jnp.exp(total[..., 0, :]))
 
 
+def tables_form(dk: int, dv: int, chunk: int) -> str:
+    """Which form builds a row's within-chunk tables: ``"kernel"`` (the
+    Pallas kernel below) or ``"xla"`` (``_within_chunks``). A function of the
+    traced shapes and of what the process runs on, as ``ops/attention.py
+    attention_form``: the kernel on a TPU in a process with one device (a
+    Mosaic call has no partitioning rule, ops/platform.py ``one_device``),
+    for heads that fill whole lane tiles and chunks of whole sub-chunks."""
+    if (_use_pallas() and _one_device() and dk % 128 == 0 and dv % 128 == 0
+            and chunk % _KERNEL_SUB == 0):
+        return "kernel"
+    return "xla"
+
+
+def _chunk_tables(q, k, v, g, beta_row):
+    """One chunk of one head in VMEM: ``q, k: (C, dk)``, ``v: (C, dv)`` in
+    the compute type, ``g: (C, dk)`` float32, ``beta_row: (1, C)`` float32.
+    The same six results as ``_within_chunks``, by the same rules."""
+    cd = q.dtype
+    f32 = jnp.float32
+    c, dk = q.shape
+    sub, ns = _KERNEL_SUB, c // _KERNEL_SUB
+    qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # beta as a column: the diagonal of its row spread over the rows
+    bf = jnp.sum(jnp.where(row == col, beta_row, 0.0), axis=1, keepdims=True)
+    # the decay summed from the chunk's start, by doubling steps down the rows
+    big_g = g
+    tok = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
+    shift = 1
+    while shift < c:
+        big_g = big_g + jnp.where(tok >= shift,
+                                  pltpu.roll(big_g, shift, 0), 0.0)
+        shift *= 2
+    # blocks on the diagonal, pair by pair with the exponent of the
+    # difference: column j of every sub-chunk at once, summed over channels
+    shape = (ns, sub, dk)
+    gs, qs, ks = (y.reshape(shape) for y in (big_g, qf, kf))
+    t_in = lax.broadcasted_iota(jnp.int32, shape, 1)
+    here = col - (row // sub) * sub  # a column's place in its row's sub-chunk
+    a_qk = jnp.zeros((c, c), f32)
+    diag_kk = []
+    for j in range(sub):
+        pair = jnp.exp(jnp.where(t_in >= j, gs - gs[:, j:j + 1, :], -jnp.inf))
+        kj = ks[:, j:j + 1, :] * pair
+        diag_kk.append(jnp.sum(ks * kj, -1, keepdims=True))
+        a_qk = jnp.where(here == j, jnp.sum(qs * kj, -1, keepdims=True
+                                            ).reshape(c, 1), a_qk)
+    # blocks between different sub-chunks: both factors measured from the
+    # decay at the later sub-chunk's start. Keys after that start are not of
+    # these blocks: their exponent is held at zero and their columns dropped
+    below_kk, rows_qk = [None], [a_qk[:sub]]
+    for i in range(1, ns):
+        lo, hi = i * sub, (i + 1) * sub
+        ref = big_g[lo - 1:lo]
+        right = (kf * jnp.exp(jnp.minimum(ref - big_g, 0.0))).astype(cd)
+        decay = jnp.exp(big_g[lo:hi] - ref)
+        left = jnp.concatenate([kf[lo:hi] * decay, qf[lo:hi] * decay], 0)
+        both = lax.dot_general(left.astype(cd), right,
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=f32)  # (2 sub, C)
+        before = col[:sub] < lo
+        below_kk.append(jnp.where(before, bf[lo:hi] * both[:sub], 0.0))
+        rows_qk.append(jnp.where(before, both[sub:], a_qk[lo:hi]))
+    a_qk = jnp.concatenate(rows_qk, 0)
+    # (I + tril(beta A_kk, -1)) [W | U0] = beta [K exp(G) | V] by forward
+    # substitution on the vector unit, in float32: a row that is final leaves
+    # every row below it. A sub-chunk's rows are one sublane tile. Within it
+    # the coefficients are the sums above as they stand, a value a row
+    # (spreading a column of a table over the lanes is the costly step here,
+    # and these need none)
+    within = [bf * jnp.where(t_in[:, :, :1] > j, kk, 0.0).reshape(c, 1)
+              for j, kk in enumerate(diag_kk[:-1])]
+    decay_in = jnp.exp(big_g)
+    rhs_w, rhs_u = bf * kf * decay_in, bf * vf
+    w = [rhs_w[i * sub:(i + 1) * sub] for i in range(ns)]
+    u = [rhs_u[i * sub:(i + 1) * sub] for i in range(ns)]
+    for s in range(c - 1):
+        i0, j = divmod(s, sub)
+        w_s, u_s = w[i0][j:j + 1], u[i0][j:j + 1]
+        if j < sub - 1:
+            coef = within[j][i0 * sub:(i0 + 1) * sub]
+            w[i0], u[i0] = w[i0] - coef * w_s, u[i0] - coef * u_s
+        for i in range(i0 + 1, ns):
+            coef = below_kk[i][:, s:s + 1]
+            w[i], u[i] = w[i] - coef * w_s, u[i] - coef * u_s
+    w, u = jnp.concatenate(w, 0), jnp.concatenate(u, 0)
+    # the decay still to come after a token, summed up the rows as ``big_g``
+    # is down them (``G_C - G`` as a difference of two sums of a whole
+    # chunk's size would lose the small exponents near the chunk's end)
+    to_come, shift = jnp.where(tok < c - 1, pltpu.roll(g, c - 1, 0), 0.0), 1
+    while shift < c:
+        to_come = to_come + jnp.where(
+            tok < c - shift, pltpu.roll(to_come, c - shift, 0), 0.0)
+        shift *= 2
+    return (w.astype(cd), u,
+            (qf * decay_in).astype(cd), (kf * jnp.exp(to_come)).astype(cd),
+            jnp.where(row >= col, a_qk, 0.0).astype(cd),
+            jnp.exp(big_g[c - 1:c]))
+
+
+def _tables_kernel(row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, *refs,
+                   chunk):
+    del row_ref  # read by the block specs
+    outs = refs[len(refs) // 2:]  # after the buffers they are written into
+
+    def one(i, carry):
+        tokens = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
+        res = _chunk_tables(q_ref[tokens, :], k_ref[tokens, :],
+                            v_ref[tokens, :], g_ref[tokens, :],
+                            beta_ref[0, pl.ds(i, 1), :])
+        for ref, y in zip(outs[:5], res[:5]):
+            ref[i] = y
+        outs[5][pl.ds(i, 1), :] = res[5]
+        return carry
+
+    lax.fori_loop(0, beta_ref.shape[1], one, 0)
+
+
+def _chunks_a_step(n: int) -> int:
+    """Chunks of one head a grid step holds: ``_KERNEL_CHUNKS``, or all of a
+    short sequence's."""
+    return min(n, _KERNEL_CHUNKS)
+
+
+def empty_tables(b: int, n: int, h: int, c: int, dk: int, dv: int, cd):
+    """Unwritten room for a batch's six results as the chain over chunks
+    reads them, chunks first: ``W``, ``U0``, ``Q * exp(G)``, ``K * exp(G_C -
+    G)`` and ``tril(A_qk)`` as ``(N, B, H, C, .)``; ``exp(G_C)`` as ``(B, H,
+    N, dk)`` (a block of a kernel's result ends in whole tiles)."""
+    f32 = jnp.float32
+    return tuple(lax.empty((n, b, h, c, d), t) for d, t in (
+        (dk, cd), (dv, f32), (dk, cd), (dk, cd), (c, cd))) + (
+        lax.empty((b, h, n, dk), f32),)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "chunk", "interpret"))
+def within_chunks_kernel(row, tables, q, k, v, g, beta, *, heads: int,
+                         chunk: int, interpret: bool = False):
+    """What ``_within_chunks`` computes for row ``row`` of the batch, written
+    into ``tables`` (``empty_tables``; the other rows stay as they are), as
+    one Pallas TPU call on the arrays as the layer holds them: ``q, k, g:
+    (B, S, H * dk)``, ``v: (B, S, H * dv)`` (a head is a block of lanes, a
+    chunk a block of rows: no copy cuts the row out or brings heads to the
+    front) and ``beta: (B, H, N, C)``, ``S = N * C``, ``N`` a multiple of the
+    chunks a grid step holds. The grid walks (head, block of chunks); a
+    step's inputs, tables and results stay in VMEM, and HBM sees each input
+    and each result once: the results land where the chain over chunks reads
+    them, so the loop over rows stacks nothing."""
+    h, c = heads, chunk
+    n = q.shape[1] // c
+    nb = _chunks_a_step(n)
+    f32 = jnp.float32
+
+    def tokens(d):  # rows of a block of chunks, lanes of a head
+        return pl.BlockSpec((None, nb * c, d), lambda i, j, r: (r[0], j, i))
+
+    def by_chunk(d):
+        return pl.BlockSpec((nb, None, None, c, d),
+                            lambda i, j, r: (j, r[0], i, 0, 0))
+
+    widths = [y.shape[-1] for y in tables]
+    return tuple(pl.pallas_call(
+        functools.partial(_tables_kernel, chunk=c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h, n // nb),
+            in_specs=[tokens(y.shape[2] // h) for y in (q, k, v, g)]
+            + [pl.BlockSpec((None, 1, nb, c),
+                            lambda i, j, r: (r[0], i, j, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 6,
+            out_specs=[by_chunk(d) for d in widths[:5]]
+            + [pl.BlockSpec((None, None, nb, widths[5]),
+                            lambda i, j, r: (r[0], i, j, 0))]),
+        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype) for y in tables],
+        # each result is its buffer (operands count from the row's index)
+        input_output_aliases={6 + i: i for i in range(6)},
+        interpret=interpret,
+    )(jnp.asarray(row, jnp.int32).reshape(1), q, k, v, g.astype(f32),
+      beta.astype(f32), *tables))
+
+
 def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
     """The layer's output ``(B, S, H, dv)`` for ``q, k: (B, S, H, dk)`` (as
     the layer reads them: normalised, ``q`` already scaled), ``v: (B, S, H,
@@ -177,22 +412,43 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
     sub = min(sub, chunk)
     if chunk % sub:
         raise ValueError(f"chunk {chunk} is no multiple of sub-chunk {sub}")
+    form = tables_form(dk, dv, chunk)
+    _note("kda_tables", form)
     n = -(-s // chunk)
+    if form == "kernel":  # whole grid steps
+        step_chunks = _chunks_a_step(n)
+        n = -(-n // step_chunks) * step_chunks
     pad = n * chunk - s
     cd = q.dtype
     f32 = jnp.float32
 
+    def padded(y):
+        return jnp.pad(y, ((0, 0), (0, pad)) + ((0, 0),) * (y.ndim - 2))
+
     def chunks(y):  # (B, S, H, ...) -> (B, H, N, C, ...)
-        y = jnp.pad(y, ((0, 0), (0, pad)) + ((0, 0),) * (y.ndim - 2))
-        y = y.reshape(b, n, chunk, h, *y.shape[3:])
+        y = padded(y).reshape(b, n, chunk, h, *y.shape[3:])
         return jnp.moveaxis(y, 3, 1)
 
-    parts = lax.map(
-        lambda row: _within_chunks(*row, sub=sub),
-        tuple(chunks(y) for y in (q, k, v, g.astype(f32), beta)))
-    # the chain over chunks, every row and head at once
-    w, u0, q_in, k_out, a_qk, decay = (jnp.moveaxis(y, 2, 0) for y in parts)
+    # Either form a row at a time: one loop in the compiled program, whose
+    # device time a trace shows whole.
+    if form == "kernel":
+        # (B, S, H, d) as (B, N * C, H * d): no data moves, and the kernel
+        # reads its row where it lies and writes it where the chain reads it
+        whole = tuple(padded(y).reshape(b, n * chunk, -1)
+                      for y in (q, k, v, g.astype(f32))) + (chunks(beta),)
+        w, u0, q_in, k_out, a_qk, decay = lax.fori_loop(
+            0, b, lambda row, tables: within_chunks_kernel(
+                row, tables, *whole, heads=h, chunk=chunk),
+            empty_tables(b, n, h, chunk, dk, dv, cd))
+        decay = jnp.moveaxis(decay, 2, 0)
+    else:
+        parts = lax.map(
+            lambda row: _within_chunks(*row, sub=sub),
+            tuple(chunks(y) for y in (q, k, v, g.astype(f32), beta)))
+        w, u0, q_in, k_out, a_qk, decay = (jnp.moveaxis(y, 2, 0)
+                                           for y in parts)
 
+    # the chain over chunks, every row and head at once
     def step(state, xs):
         w_c, u0_c, q_c, k_c, a_c, d_c = xs
         sb = state.astype(cd)

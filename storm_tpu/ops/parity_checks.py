@@ -184,7 +184,65 @@ def check_w8a16(interpret: bool = False) -> List[dict]:
     return rows
 
 
+def check_kda_tables(interpret: bool = False) -> List[dict]:
+    """Compiled within-chunk tables of KDA (``ops/kda.py
+    within_chunks_kernel``) vs the jnp form (``_within_chunks``).
+
+    One row of the batch at the Kimi cell's shapes (32 heads of 128, 64 chunks
+    of 64 tokens, bfloat16 operands), at a typical decay and at one that
+    forgets within a token (``exp(-G)`` overflows float32 inside a chunk),
+    and a small float32 row. Each of the six results is a row of its own.
+    The reference is the jnp form in float32 at ``highest`` on the same
+    (dtype-rounded) inputs; the tolerance is the served type's rounding of
+    the products' operands, as for the attention kernels. (Under the
+    interpreter, this runner's smoke test, the heads and chunks are few.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from storm_tpu.ops import kda
+
+    f32 = jnp.float32
+    many = (2, 2) if interpret else (32, 64)
+    cases = [
+        ("kimi_decay1", many, 1.0, jnp.bfloat16),
+        ("kimi_decay30_within_a_token", many, 30.0, jnp.bfloat16),
+        ("toy_decay3", (2, 8), 3.0, f32),
+    ]
+    names = ("w", "u0", "q_in", "k_out", "a_qk", "decay")
+    c, d = 64, 128
+    rows = []
+    for case, (h, n), decay, dt in cases:
+        ks = jax.random.split(jax.random.PRNGKey(0), 5)
+        lead = (h, n, c)
+        q = (kda.l2norm(jax.random.normal(ks[0], lead + (d,)))
+             * d ** -0.5).astype(dt)
+        k = kda.l2norm(jax.random.normal(ks[1], lead + (d,))).astype(dt)
+        v = jax.random.normal(ks[2], lead + (d,)).astype(dt)
+        g = -decay * jax.nn.softplus(jax.random.normal(ks[3], lead + (d,)))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], lead))
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda *a: kda._within_chunks(*a, sub=16))(
+                q.astype(f32), k.astype(f32), v.astype(f32), g, beta)
+
+        def as_layer(y):  # (H, N, C, d) -> (1, N * C, H * d)
+            return jnp.moveaxis(y, 0, 2).reshape(1, n * c, h * d)
+
+        got = kda.within_chunks_kernel(
+            0, kda.empty_tables(1, n, h, c, d, d, dt),
+            *(as_layer(y) for y in (q, k, v, g)), beta[None], heads=h,
+            chunk=c, interpret=interpret)
+        # (N, 1, H, C, .) and (1, H, N, dk) -> (H, N, ...)
+        got = [jnp.moveaxis(y[:, 0], 0, 1) for y in got[:5]] + [got[5][0]]
+        rel_tol = 1e-2 if dt == jnp.bfloat16 else 5e-3
+        for name, x, y in zip(names, got, want):
+            rows.append(_row("kda_tables", f"{case}_H{h}_N{n}:{name}",
+                             np.dtype(dt).name, np.asarray(x, np.float32),
+                             np.asarray(y, np.float32), rel_tol=rel_tol))
+    return rows
+
+
 def run_all(interpret: bool = False) -> List[dict]:
     return (check_flash_attention(interpret)
             + check_short_attention(interpret)
-            + check_w8a16(interpret))
+            + check_w8a16(interpret)
+            + check_kda_tables(interpret))

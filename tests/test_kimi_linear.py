@@ -6,6 +6,7 @@ float32 at ``highest``) on seeded weights. Probabilities over the whole
 vocabulary are compared, never an argmax: with random weights the largest
 logit changes on rounding."""
 
+import functools
 import os
 import sys
 
@@ -58,26 +59,149 @@ def _recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
-@pytest.mark.parametrize("decay", [0.1, 3.0, 30.0],
-                         ids=["slow", "fast", "within-a-token"])
+def _kda_inputs(decay, shape, d, seed=0):
+    """``q, k, v, g: shape + (d,)`` and ``beta: shape`` as the layer makes
+    them: normalised keys, scaled queries, a log-decay of ``-decay`` times a
+    softplus."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = kda.l2norm(jax.random.normal(ks[0], shape + (d,))) * d ** -0.5
+    k = kda.l2norm(jax.random.normal(ks[1], shape + (d,)))
+    v = jax.random.normal(ks[2], shape + (d,))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], shape + (d,)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape))
+    return q, k, v, g, beta
+
+
+DECAYS = {"slow": 0.1, "fast": 3.0, "within-a-token": 30.0}
+
+
+@pytest.mark.parametrize("decay", DECAYS.values(), ids=DECAYS.keys())
 def test_chunked_kda_is_the_recurrence(decay):
     """150 tokens are no multiple of the chunk (64) nor of the sub-chunk.
     At the fastest decay ``exp(-G_s)`` overflows float32 inside one chunk:
     the chunked form must not be built from it. Both sides float32 at
     ``highest``: they differ by summation order alone, 1e-5 of the largest
     output is a hundred times that."""
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    b, s, h, d = 2, 150, 3, 32
-    q = kda.l2norm(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
-    k = kda.l2norm(jax.random.normal(ks[1], (b, s, h, d)))
-    v = jax.random.normal(ks[2], (b, s, h, d))
-    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h, d)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    q, k, v, g, beta = _kda_inputs(decay, (2, 150, 3), 32)
     with jax.default_matmul_precision("highest"):
         want = _recurrence(q, k, v, g, beta)
         got = kda.kda_chunked(q, k, v, g, beta, chunk=64, sub=16)
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
+
+
+TABLES = ("W", "U0", "Q*exp(G)", "K*exp(G_C-G)", "A_qk", "exp(G_C)")
+
+
+@functools.lru_cache(maxsize=None)
+def _tables_both_ways(decay):
+    """One row of 2 heads x 3 chunks of 64 tokens at 128-wide heads: the jnp
+    form on ``(H, N, C, d)`` and the kernel (under the Pallas interpreter) on
+    the same row as the layer holds it, ``(B, S, H * d)``, second of a batch
+    whose first row is zeros, written into room that holds sevens. Both
+    brought to ``(H, N, ...)``, and the room's first row beside them."""
+    q, k, v, g, beta = _kda_inputs(decay, (2, 3, 64), 128)
+
+    def as_layer(y):  # (H, N, C, d) -> (2, N * C, H * d), the row at index 1
+        y = jnp.moveaxis(y, 0, 2).reshape(3 * 64, -1)
+        return jnp.stack([jnp.zeros_like(y), y])
+
+    room = tuple(jnp.full(y.shape, 7, y.dtype) for y in kda.empty_tables(
+        2, 3, 2, 64, 128, 128, jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        want = kda._within_chunks(q, k, v, g, beta, sub=16)
+        got = kda.within_chunks_kernel(
+            1, room, *(as_layer(y) for y in (q, k, v, g)),
+            jnp.stack([jnp.zeros_like(beta), beta]), heads=2, chunk=64,
+            interpret=True)
+    # (N, B, H, C, .) and (B, H, N, dk): this row's, then the other's
+    rows = [[jnp.moveaxis(y[:, r], 0, 1) for y in got[:5]] + [got[5][r]]
+            for r in (1, 0)]
+    return tuple([np.asarray(y) for y in side] for side in (*rows, want))
+
+
+@pytest.mark.parametrize("table", range(6), ids=TABLES)
+@pytest.mark.parametrize("decay", DECAYS.values(), ids=DECAYS.keys())
+def test_the_tables_kernel_is_the_jnp_form(decay, table):
+    """Each of the six arrays the chain over chunks is handed, float32 both
+    sides (the products at ``highest``): 1e-5 of the array's largest value.
+    The kernel's sub-chunk is 8 where the jnp form's is 16, its triangle is
+    solved row by row where the jnp form's is inverted block by block, and
+    its decays are summed by doubling: summation order alone. A call writes
+    its own row of the batch and no other."""
+    got, other, want = (side[table] for side in _tables_both_ways(decay))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.isfinite(got).all() and (other == 7).all()
+    # (at the two faster decays ``exp(G_C)`` is zero to float32 on both sides)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+@pytest.fixture
+def tables_by_the_kernel(monkeypatch):
+    """What a process with one TPU would build: the shape rule's own answer
+    with the platform's two questions answered as there, and the kernel run
+    by the Pallas interpreter."""
+    monkeypatch.setattr(kda, "_use_pallas", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    monkeypatch.setattr(kda, "within_chunks_kernel", functools.partial(
+        kda.within_chunks_kernel, interpret=True))
+
+
+@pytest.mark.parametrize("decay", DECAYS.values(), ids=DECAYS.keys())
+def test_chunked_kda_through_the_kernel_is_the_recurrence(
+        decay, tables_by_the_kernel):
+    """As ``test_chunked_kda_is_the_recurrence`` at heads the kernel takes
+    (128 wide): 150 tokens are no multiple of the chunk, and the choice lands
+    in the dispatch notes the engine reads."""
+    from storm_tpu.ops.platform import dispatch_notes
+
+    q, k, v, g, beta = _kda_inputs(decay, (2, 150, 2), 128)
+    with jax.default_matmul_precision("highest"):
+        want = _recurrence(q, k, v, g, beta)
+        with dispatch_notes() as seen:
+            got = kda.kda_chunked(q, k, v, g, beta, chunk=64)
+    assert seen == ["kda_tables=kernel"]
+    assert got.shape == want.shape and bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
+
+
+def test_more_chunks_than_a_grid_step_holds(tables_by_the_kernel):
+    """600 tokens are 10 chunks where a grid step holds 8: padded to two
+    whole steps, the same output as the jnp form's."""
+    q, k, v, g, beta = _kda_inputs(3.0, (1, 600, 1), 128, seed=1)
+    with jax.default_matmul_precision("highest"):
+        got = kda.kda_chunked(q, k, v, g, beta, chunk=64)
+        want = _recurrence(q, k, v, g, beta)
+    assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
+
+
+class _Device:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+@pytest.mark.parametrize("dk,dv,chunk,platform,devices,no_pallas,want", [
+    (128, 128, 64, "tpu", 1, False, "kernel"),  # the cell's program
+    (256, 128, 64, "tpu", 1, False, "kernel"),
+    (128, 128, 16, "tpu", 1, False, "kernel"),
+    (16, 16, 16, "tpu", 1, False, "xla"),       # kimi_linear_tiny's heads
+    (128, 64, 64, "tpu", 1, False, "xla"),      # values of half a lane tile
+    (128, 128, 60, "tpu", 1, False, "xla"),     # no whole sub-chunks
+    # a host with several chips: a program may be split over them, and the
+    # kernel has no partitioning rule (this suite itself: 8 host devices)
+    (128, 128, 64, "tpu", 4, False, "xla"),
+    (128, 128, 64, "cpu", 1, False, "xla"),
+    (128, 128, 64, "tpu", 1, True, "xla"),      # STORM_TPU_NO_PALLAS
+])
+def test_tables_form_is_a_function_of_the_traced_shapes_and_the_devices(
+        dk, dv, chunk, platform, devices, no_pallas, want, monkeypatch):
+    monkeypatch.setattr(jax, "devices", lambda: [_Device(platform)])
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    if no_pallas:
+        monkeypatch.setenv("STORM_TPU_NO_PALLAS", "1")
+    else:
+        monkeypatch.delenv("STORM_TPU_NO_PALLAS", raising=False)
+    assert kda.tables_form(dk, dv, chunk) == want
 
 
 def test_short_convolution_is_causal_and_matches_the_plain_form():
@@ -277,6 +401,23 @@ def test_buckets_are_clipped_to_the_models_bound_and_only_those_warm():
     from storm_tpu.infer.continuous import continuous_for
 
     assert continuous_for(eng, BatchConfig()).cfg.max_batch == 8
+
+
+def test_the_inventory_names_the_form_of_the_tables():
+    """Per compiled bucket, which form built the KDA tables of its program:
+    here the jnp form by two rules at once (16-wide heads, off the TPU)."""
+    from storm_tpu.config import ShardingConfig
+    from storm_tpu.infer.engine import engine_inventory, shared_engine
+
+    eng = shared_engine(ModelConfig(
+        name="kimi_linear_tiny", dtype="float32", num_classes=96,
+        input_shape=(40,), seed=5), ShardingConfig(data_parallel=0),
+        BatchConfig())
+    eng.warmup()
+    row = next(r for r in engine_inventory()["engines"]
+               if r["model"] == "kimi_linear_tiny")
+    assert list(row["programs"]) == [str(eng.pad_batch(8))]
+    assert "kda_tables=xla" in row["programs"][str(eng.pad_batch(8))].split(", ")
 
 
 def test_a_model_without_a_bound_keeps_the_policy_as_given():

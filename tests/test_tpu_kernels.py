@@ -44,3 +44,12 @@ def test_w8a16_compiled_parity():
     rows = check_w8a16(interpret=False)
     bad = [r for r in rows if not r["pass"]]
     assert not bad, f"compiled w8a16_matmul parity failures: {bad}"
+
+
+@pytest.mark.slow
+def test_kda_tables_compiled_parity():
+    from storm_tpu.ops.parity_checks import check_kda_tables
+
+    rows = check_kda_tables(interpret=False)
+    bad = [r for r in rows if not r["pass"]]
+    assert not bad, f"compiled kda_tables parity failures: {bad}"
