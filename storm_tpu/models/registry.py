@@ -75,6 +75,7 @@ def _load_builtin() -> None:
         mixer,
         mobilenet,
         moe_vit,
+        nemotron_h,
         resnet,
         vit,
     )

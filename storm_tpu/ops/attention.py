@@ -93,36 +93,50 @@ def attention_form(b: int, s: int, c: int, num_heads: int,
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      scale: Optional[float] = None,
                      block: int = 512) -> jnp.ndarray:
-    """Causal ``softmax(q k^T * scale) v`` for ``q, k: (B, H, S, Dk)`` and
-    ``v: (B, H, S, Dv)``, where ``Dv`` may differ from ``Dk`` (latent
-    attention: 192 against 128). The scores of a whole batch are never
+    """Causal ``softmax(q k^T * scale) v`` for ``q: (B, Hq, S, Dk)``, ``k: (B,
+    Hkv, S, Dk)`` and ``v: (B, Hkv, S, Dv)``. ``Dv`` may differ from ``Dk``
+    (latent attention: 192 against 128), and ``Hq`` may be a multiple of
+    ``Hkv`` (grouped queries: query head ``i`` reads key head ``i // (Hq /
+    Hkv)``; the keys are read in place by their group's query heads, never
+    written out once a query head). The scores of a whole batch are never
     formed: one row of the batch at a time (``lax.map``: one loop in the
     compiled program, whose device time a trace shows whole), and within it a
     block of ``block`` queries against the keys up to that block's end, so
-    the upper triangle is not computed and at most ``H x block x S`` scores
+    the upper triangle is not computed and at most ``Hq x block x S`` scores
     exist at once. Softmax in float32. One form on every platform."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    s = q.shape[2]
-    _note("causal_attention", "blocked")
+    hq, s = q.shape[1], q.shape[2]
+    hkv = k.shape[1]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} key heads")
+    # with one query head a key head the group axis is left out altogether,
+    # so that such a program carries the shapes it always has
+    grouped = hq != hkv
+    _note("causal_attention", "blocked-grouped" if grouped else "blocked")
+    scores_of, values_of = (("grsd,gtd->grst", "grst,gtd->grsd") if grouped
+                            else ("hsd,htd->hst", "hst,htd->hsd"))
 
     def row(qkv):
         qr, kr, vr = qkv  # (H, S, D)
+        if grouped:
+            qr = qr.reshape(hkv, hq // hkv, s, qr.shape[-1])
         outs = []
         for lo in range(0, s, block):
             hi = min(lo + block, s)
-            scores = jnp.einsum("hsd,htd->hst", qr[:, lo:hi], kr[:, :hi],
+            scores = jnp.einsum(scores_of, qr[..., lo:hi, :], kr[:, :hi],
                                 preferred_element_type=jnp.float32) * scale
             later = jnp.arange(hi)[None, :] > jnp.arange(lo, hi)[:, None]
             scores = jnp.where(later, -jnp.inf, scores)
             # the weights go to the product unnormalised and the result is
             # divided by their sum: one pass less over the scores
             weights = jnp.exp(scores - scores.max(-1, keepdims=True))
-            out = jnp.einsum("hst,htd->hsd", weights.astype(vr.dtype),
+            out = jnp.einsum(values_of, weights.astype(vr.dtype),
                              vr[:, :hi], preferred_element_type=jnp.float32)
             outs.append((out / weights.sum(-1, keepdims=True)
                          ).astype(vr.dtype))
-        return jnp.concatenate(outs, 1)
+        out = jnp.concatenate(outs, -2)
+        return out.reshape(hq, s, out.shape[-1]) if grouped else out
 
     return jax.lax.map(row, (q, k, v))
 

@@ -102,20 +102,30 @@ _KERNEL_CHUNKS = 8
 
 
 def short_conv_init(rng, channels: int, width: int = 4,
-                    dtype=jnp.float32) -> dict:
+                    dtype=jnp.float32, bias: bool = False) -> dict:
     """A depthwise kernel ``[width, channels]``; the last tap is the current
-    token's."""
-    return {"w": jax.random.normal(rng, (width, channels), dtype)
+    token's. ``bias``: one more number a channel, U(-1, 1) over the root of
+    the width (as a framework's depthwise convolution starts it), so that it
+    matters."""
+    if not bias:
+        return {"w": jax.random.normal(rng, (width, channels), dtype)
+                * (1.0 / width) ** 0.5}
+    kw, kb = jax.random.split(rng)
+    return {**short_conv_init(kw, channels, width, dtype),
+            "b": jax.random.uniform(kb, (channels,), dtype, -1.0, 1.0)
             * (1.0 / width) ** 0.5}
 
 
 def short_conv(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Causal depthwise convolution over ``(B, S, C)``: ``y_t = sum_j w[j] *
-    x_{t - (width - 1) + j}``, tokens before the first read as zero."""
+    x_{t - (width - 1) + j}`` (plus the bias where the kernel has one),
+    tokens before the first read as zero."""
     w = p["w"].astype(jnp.float32)
     width, s = w.shape[0], x.shape[1]
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     y = sum(w[j] * xp[:, j:j + s] for j in range(width))
+    if "b" in p:
+        y = y + p["b"].astype(jnp.float32)
     return y.astype(x.dtype)
 
 

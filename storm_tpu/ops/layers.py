@@ -174,6 +174,18 @@ def rmsnorm(p: dict, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     return (y * p["scale"]).astype(x.dtype)
 
 
+def gated_group_rmsnorm(p: dict, x: jnp.ndarray, z: jnp.ndarray, groups: int,
+                        eps: float = 1e-5) -> jnp.ndarray:
+    """``RMSNorm(x * silu(z))`` with the statistics over each of ``groups``
+    equal runs of the last axis and one learned scale a channel (Mamba-2's
+    output norm: the gate first, then the norm)."""
+    y = x.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    grouped = y.reshape(*y.shape[:-1], groups, y.shape[-1] // groups)
+    grouped = grouped * lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
+    return (grouped.reshape(y.shape) * p["scale"]).astype(x.dtype)
+
+
 # ---- activations -------------------------------------------------------------
 
 relu = jax.nn.relu
@@ -212,7 +224,7 @@ def depthwise_conv2d(
     ).astype(x.dtype)
 
 
-# ---- gated feed-forward ------------------------------------------------------
+# ---- bias-free feed-forwards -------------------------------------------------
 
 
 def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -232,3 +244,20 @@ def swiglu(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     """down(silu(gate x) * up x): the bias-free gated feed-forward."""
     return matmul(jax.nn.silu(matmul(x, p["gate"])) * matmul(x, p["up"]),
                   p["down"])
+
+
+def relu2_init(rng, dim: int, hidden: int, dtype=jnp.float32) -> dict:
+    ku, kd = jax.random.split(rng)
+    return {"up": lecun_normal(ku, (dim, hidden), dim, dtype),
+            "down": lecun_normal(kd, (hidden, dim), hidden, dtype)}
+
+
+def relu2(p: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """down(relu(up x)^2): two matrices with a squared ReLU between."""
+    return matmul(jnp.square(jax.nn.relu(matmul(x, p["up"]))), p["down"])
+
+
+def feed_forward(p: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """The feed-forward its parameters describe: SwiGLU where they have a
+    ``gate``, squared ReLU over ``up`` and ``down`` where they do not."""
+    return swiglu(p, x) if "gate" in p else relu2(p, x)

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from storm_tpu.ops.platform import note as _note
+
 
 def moe_init(
     rng, dim: int, mlp_dim: int, n_experts: int, dtype=jnp.float32
@@ -126,25 +128,34 @@ def moe_layer(
 
 def topk_moe_init(rng, dim: int, hidden: int, n_experts: int,
                   n_held: Optional[int] = None, shared: bool = True,
-                  dtype=jnp.float32) -> dict:
-    """A router over ``n_experts`` with its selection bias, ``n_held`` SwiGLU
+                  dtype=jnp.float32, form: str = "swiglu",
+                  shared_hidden: Optional[int] = None) -> dict:
+    """A router over ``n_experts`` with its selection bias, ``n_held``
     experts of width ``hidden`` stacked on a leading axis (all of them where
-    ``n_held`` is None), and the shared expert."""
+    ``n_held`` is None), and the shared expert, of width ``shared_hidden``
+    (``hidden`` where None). ``form``: ``"swiglu"`` (``gate``, ``up``,
+    ``down``) or ``"relu2"`` (``up``, ``down``: a squared ReLU between);
+    the layer reads the form off the parameters."""
     from storm_tpu.ops import layers as L
 
+    if form not in ("swiglu", "relu2"):
+        raise ValueError(f"unknown feed-forward {form!r}")
     n_held = n_experts if n_held is None else n_held
     kr, kb, kg, ku, kd, ks = jax.random.split(rng, 6)
     p = {
         "router": L.lecun_normal(kr, (dim, n_experts), dim, dtype),
         "router_bias": jax.random.normal(kb, (n_experts,), dtype) * 0.05,
         "experts": {
-            "gate": L.lecun_normal(kg, (n_held, dim, hidden), dim, dtype),
             "up": L.lecun_normal(ku, (n_held, dim, hidden), dim, dtype),
             "down": L.lecun_normal(kd, (n_held, hidden, dim), hidden, dtype),
         },
     }
+    if form == "swiglu":
+        p["experts"]["gate"] = L.lecun_normal(kg, (n_held, dim, hidden), dim,
+                                              dtype)
     if shared:
-        p["shared"] = L.swiglu_init(ks, dim, hidden, dtype)
+        init = L.swiglu_init if form == "swiglu" else L.relu2_init
+        p["shared"] = init(ks, dim, shared_hidden or hidden, dtype)
     return p
 
 
@@ -189,8 +200,10 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
 
     The grouped product: assignments are sorted by expert, each expert's
     run is cut into tiles of ``tile`` rows, and a loop over as many tiles as
-    the routing made gathers a tile's tokens, runs that expert's SwiGLU on
-    them and writes the weighted result to the tile's place in a buffer; a
+    the routing made gathers a tile's tokens, runs that expert's feed-forward
+    on them (``ops/layers.py feed_forward``: SwiGLU or squared ReLU, as the
+    stacked parameters say; the shared expert likewise, at its own width)
+    and writes the weighted result to the tile's place in a buffer; a
     token's result is then the sum of its assignments' rows there (a gather:
     the chip scatters a row at a time, a thousand times slower). The loop's
     length is the data's, so whatever the routing no token is dropped and no
@@ -201,13 +214,14 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     shape = x.shape
     dim = shape[-1]
     w = p["experts"]
-    held = w["gate"].shape[0]
+    held = w["down"].shape[0]
+    _note("expert_ffn", "swiglu" if "gate" in w else "relu2")
     # the router reads ``x`` as it comes (float32 from a float32 stream: a
     # rounded input breaks ties the other way); the experts compute in the
     # type of their weights
     experts, weights = route_topk(p, x.reshape(-1, dim), top_k, router,
                                   renormalize, scale)
-    x = x.astype(w["gate"].dtype)
+    x = x.astype(w["down"].dtype)
     tokens = x.reshape(-1, dim)
     n = tokens.shape[0]
     tile = max(8, min(int(tile), -(-n // 8) * 8))
@@ -232,8 +246,7 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         valid = lane < counts[e] - j * tile  # rows past the run's end: zero
         ids = jax.lax.dynamic_slice(token_of, (start,), (tile,))
         rows = tokens[jnp.where(valid, ids, 0)]
-        y = L.swiglu({"gate": w["gate"][e], "up": w["up"][e],
-                      "down": w["down"][e]}, rows)
+        y = L.feed_forward({name: m[e] for name, m in w.items()}, rows)
         gain = jnp.where(valid, jax.lax.dynamic_slice(
             weight_of, (start,), (tile,)), 0.0)
         y = (y.astype(jnp.float32) * gain[:, None]).astype(out.dtype)
@@ -252,7 +265,7 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
                        most_tiles * tile)
     y = jnp.sum(out[row_of.reshape(n, top_k)], axis=1, dtype=jnp.float32)
     if "shared" in p:
-        y = y + L.swiglu(p["shared"], tokens).astype(jnp.float32)
+        y = y + L.feed_forward(p["shared"], tokens).astype(jnp.float32)
     return y.astype(x.dtype).reshape(shape), counts, absent
 
 
