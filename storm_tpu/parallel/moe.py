@@ -186,6 +186,68 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
     return experts, weights * scale
 
 
+# The combine's loop: a block is this many consecutive tokens, a tile this
+# many held assignments of one block. On a v5e, a quarter and an eighth of
+# the assignments held (PERF.md, PR 37): 256 x 512 and 512 x 1,024 read the
+# same, 512 x 512 and 128 x 256 a twentieth more (a tile gathers its empty
+# places too, and reads and writes its block's sums).
+_COMBINE_BLOCK = 256
+_COMBINE_ROWS = 512
+
+
+def _combine_held(out, row_of, n: int, top_k: int):
+    """``(n, dim)`` float32: each token's sum of its held assignments' rows
+    of ``out``, reading no other row (an absent assignment's ``row_of`` is
+    the zero row, the last). The grouped product's mirror image:
+    the held assignments are grouped by block of ``_COMBINE_BLOCK``
+    consecutive tokens (one sort keyed on the block, absent ones last, that
+    carries the row and the token along), each block's run is cut into tiles
+    of ``_COMBINE_ROWS``, and a loop over as many tiles as the data made
+    gathers a tile's rows and adds them to the block's tokens by a 0/1
+    matrix on the matrix unit (products with 0 and 1 are exact, the sum is
+    float32), so the order inside a block is immaterial."""
+    dim = out.shape[1]
+    is_held = row_of < out.shape[0] - 1
+    block = min(_COMBINE_BLOCK, -(-n // 8) * 8)
+    rows = min(_COMBINE_ROWS, block * top_k)
+    blocks = -(-n // block)
+    counts = jnp.sum(jnp.pad(is_held, (0, (blocks * block - n) * top_k))
+                     .reshape(blocks, block * top_k), axis=1, dtype=jnp.int32)
+    token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
+    _, row_at, token_at = jax.lax.sort(
+        (jnp.where(is_held, token // block, blocks), row_of, token),
+        num_keys=1, is_stable=False)
+    # a slice of ``rows`` from any start inside the assignments stays inside
+    row_at, token_at = jnp.pad(row_at, (0, rows)), jnp.pad(token_at, (0, rows))
+    starts = jnp.cumsum(counts) - counts
+    tiles = -(-counts // rows)
+    tile_ends = jnp.cumsum(tiles)
+    lane = jnp.arange(rows, dtype=jnp.int32)
+    slot = jnp.arange(block, dtype=jnp.int32)
+    # a float32 row times 1 stays float32 only at ``highest``
+    exact = None if out.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+
+    def one_tile(i, y):
+        b = jnp.searchsorted(tile_ends, i, side="right",
+                             method="compare_all").astype(jnp.int32)
+        j = i - (tile_ends[b] - tiles[b])  # this tile within its block's run
+        start = starts[b] + j * rows
+        valid = lane < counts[b] - j * rows  # past the run's end: the zero row
+        picked = out[jnp.where(valid, jax.lax.dynamic_slice(
+            row_at, (start,), (rows,)), out.shape[0] - 1)]
+        at = jax.lax.dynamic_slice(token_at, (start,), (rows,)) - b * block
+        mine = (slot[:, None] == at[None, :]).astype(out.dtype)
+        add = jnp.dot(mine, picked, precision=exact,
+                      preferred_element_type=jnp.float32)
+        corner = (b * block, 0)
+        return jax.lax.dynamic_update_slice(y, jax.lax.dynamic_slice(
+            y, corner, (block, dim)) + add, corner)
+
+    y = jax.lax.fori_loop(0, tile_ends[-1], one_tile,
+                          jnp.zeros((blocks * block, dim), jnp.float32))
+    return y[:n]
+
+
 def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
                    router: str = "sigmoid", renormalize: bool = True,
                    scale: float = 1.0, tile: int = 1024):
@@ -203,12 +265,18 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     the routing made gathers a tile's tokens, runs that expert's feed-forward
     on them (``ops/layers.py feed_forward``: SwiGLU or squared ReLU, as the
     stacked parameters say; the shared expert likewise, at its own width)
-    and writes the weighted result to the tile's place in a buffer; a
-    token's result is then the sum of its assignments' rows there (a gather:
-    the chip scatters a row at a time, a thousand times slower). The loop's
-    length is the data's, so whatever the routing no token is dropped and no
-    padding up to a capacity is computed; the only waste is each run's last,
-    partly filled tile. The buffer alone has the worst case's size."""
+    and writes the weighted result to the tile's place in a buffer. The
+    loop's length is the data's, so whatever the routing no token is dropped
+    and no padding up to a capacity is computed; the only waste is each
+    run's last, partly filled tile. The buffer alone has the worst case's
+    size.
+
+    A token's result is the float32 sum of its assignments' rows there, and
+    never a ``[tokens, top_k, dim]`` array (a scatter-add would do it in
+    place, but the chip scatters a row at a time, a thousand times slower
+    than it gathers). A second loop of the same kind reads the held rows
+    alone, a block of tokens at a time (:func:`_combine_held`): an absent
+    assignment's row is never read. Noted as ``expert_combine=held-rows``."""
     from storm_tpu.ops import layers as L
 
     shape = x.shape
@@ -263,7 +331,8 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
                            most_tiles * tile)
     row_of = jnp.where(local < held, place + first_row[local],
                        most_tiles * tile)
-    y = jnp.sum(out[row_of.reshape(n, top_k)], axis=1, dtype=jnp.float32)
+    _note("expert_combine", "held-rows")
+    y = _combine_held(out, row_of, n, top_k)
     if "shared" in p:
         y = y + L.feed_forward(p["shared"], tokens).astype(jnp.float32)
     return y.astype(x.dtype).reshape(shape), counts, absent
