@@ -59,6 +59,7 @@ its own tuples independently; nothing is shared but the device round trip.
 
 from __future__ import annotations
 
+import inspect
 import logging
 import threading
 import time
@@ -157,6 +158,11 @@ class ContinuousBatcher:
         self.capacity = max(1, int(getattr(engine, "ring_capacity",
                                            getattr(engine, "pipeline_depth",
                                                    1)) or 1))
+        # Whether ``engine.dispatch`` takes the queue's moments for the step
+        # log (``obs/profile.py new_step_row``); a test double's may not.
+        dispatch = getattr(engine, "dispatch", None)
+        self._logs_steps = dispatch is not None and "queued" in \
+            inspect.signature(dispatch).parameters
         self._cond = threading.Condition()
         # tenant:lane key -> FIFO of Submissions (deadlines monotone per key)
         self._queues: "OrderedDict[tuple, deque]" = OrderedDict()
@@ -535,7 +541,17 @@ class ContinuousBatcher:
                 self._finish(items, out, None, None, t0,
                              time.perf_counter())
                 return
-            handle = dispatch([it.data for it in items])
+            parts = [it.data for it in items]
+            if self._logs_steps:
+                # the step log's clock is ``time.time()``: this row's
+                # offset, read at its cut
+                wall = time.time() - time.perf_counter()
+                handle = dispatch(parts, queued={
+                    "t_first_enq": min(it.enq for it in items) + wall,
+                    "t_cut": t0 + wall,
+                    "sources": len({it.source for it in items})})
+            else:
+                handle = dispatch(parts)
         except BaseException as e:  # noqa: BLE001 - fail ONLY this batch
             self._finish(items, None, e, None, t0, time.perf_counter())
             return
@@ -642,6 +658,9 @@ class ContinuousBatcher:
             n = it.rows
             it.future.set_result(out[ofs:ofs + n])
             ofs += n
+        row = getattr(handle, "step", None)
+        if row is not None:
+            row["t_resolved"] = time.time()
         self._notify(items)
 
     def _observe_aux(self, aux: dict) -> None:
@@ -702,6 +721,9 @@ class ContinuousBatcher:
         attrs = {"batch_size": sum(it.rows for it in items),
                  "records": len(items), "fill": round(fill, 3),
                  "sources": n_sources}
+        row = getattr(handle, "step", None)
+        if row is not None:  # the step log's row of this batch
+            attrs["step"] = row["step"]
         timings = getattr(handle, "timings", None) if handle else None
         if timings:
             for key, _ in DEVICE_SUBSTAGES:

@@ -15,9 +15,11 @@ Outputs are softmax probabilities, matching the reference's fetch of
 from __future__ import annotations
 
 import gc
+import itertools
 import logging
 import os
 import queue
+import re
 import sys
 import threading
 import time
@@ -35,6 +37,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
 from storm_tpu.models.registry import ModelDef, build_model, load_or_init
 from storm_tpu.obs import copyledger as _copyledger
+from storm_tpu.obs.profile import new_step_row
+from storm_tpu.ops.parts import HEAD
 from storm_tpu.ops.platform import dispatch_notes
 from storm_tpu.parallel.mesh import make_mesh
 from storm_tpu.parallel.sharding import (
@@ -186,7 +190,7 @@ class InflightBatch:
 
     __slots__ = ("future", "n", "padded", "timings", "profile_key", "_out",
                  "_buf", "_t_put", "_t_launched", "watchdog_ms", "on_done",
-                 "aux")
+                 "aux", "step")
 
     def __init__(self, n: int, padded: int) -> None:
         self.future: Future = Future()
@@ -213,6 +217,10 @@ class InflightBatch:
         # this batch is in flight (the fetch THREAD still holds no ref).
         self.watchdog_ms = 0.0
         self.on_done = None
+        # This step's row of the step log (``obs/profile.py``; None: not
+        # logged). Built here and by the queue that cut the batch, appended
+        # once by the fetch thread to the profile sink's ring.
+        self.step: Optional[dict] = None
 
     def result(self, timeout: Optional[float] = None) -> np.ndarray:
         return self.future.result(timeout)
@@ -260,6 +268,13 @@ def _fetch_loop(fetch_q: "queue.SimpleQueue", ring: threading.Semaphore,
             handle.timings["compute_ms"] = (t1 - handle._t_launched) * 1e3
             handle.timings["d2h_ms"] = (t2 - t1) * 1e3
             handle._out = None
+            row = handle.step
+            if row is not None:
+                wall = time.time()
+                row["t_ready"], row["t_fetched"] = t1 + (wall - t2), wall
+                row["seen"] = seen
+            # the members' callbacks run inside: the queue's ``_finish``
+            # stamps ``t_resolved`` into the row before the append below
             handle.future.set_result(res[:handle.n])
             # Copy ledger: the blocking device->host materialization is
             # one full-result copy into a fresh host array.
@@ -274,7 +289,7 @@ def _fetch_loop(fetch_q: "queue.SimpleQueue", ring: threading.Semaphore,
             if sink is not None and handle.profile_key is not None:
                 try:
                     sink.record_batch(handle.profile_key, handle.padded,
-                                      handle.n, handle.timings)
+                                      handle.n, handle.timings, row)
                 except Exception:
                     pass
         except BaseException as e:  # noqa: BLE001 - fail ONLY this batch
@@ -403,6 +418,17 @@ DEFAULT_COMPILE_CACHE_DIR = str(
     Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
+def key_on_metadata() -> None:
+    """Make the compile cache's key cover the operations' metadata, and
+    keep that metadata to what the program is (``enable_compile_cache``
+    says why)."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(os.path.dirname(
+                          DEFAULT_COMPILE_CACHE_DIR)) + "/")
+
+
 def enable_compile_cache() -> str:
     """Turn on jax's persistent executable cache for this process and
     return its directory. Called once by each entry point (``main`` run /
@@ -415,11 +441,24 @@ def enable_compile_cache() -> str:
     the environment, so every process of a run shares one cache and a
     restarted daemon reloads its bucket shapes instead of recompiling
     them. The persistence gate drops from jax's 1.0 s default so the
-    small models in the zoo are cached too."""
+    small models in the zoo are cached too.
+
+    The key covers the operations' metadata. By default jax strips it from
+    the key, and a program that differs from a cached one in metadata alone
+    (the parts' names of ``ops/parts.py``) is handed the cached executable,
+    whose device trace then names the other program's parts (seen on the
+    CPU: an unscoped function loaded its scoped twin's executable). What
+    the metadata holds is kept to what the program is: an operation's own
+    source line, not the chain of calls that led there (two entry points
+    that build the same engine share its executables; the limit of one
+    frame, because without full tracebacks jax writes the names in a form
+    from which XLA drops the scopes), with paths written relative to the
+    checkout (the same tree at another path still hits)."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update(
             "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    key_on_metadata()
     return jax.config.jax_compilation_cache_dir
 
 
@@ -656,8 +695,9 @@ class InferenceEngine:
                 else:
                     logits, new_state = apply(params, state, x, train=False)
             forms[x.shape[0]] = ", ".join(seen)
-            logits = logits.astype(jnp.float32)
-            out = jax.nn.softmax(logits, axis=-1) if softmax else logits
+            with jax.named_scope(HEAD):  # a part's name in a device trace
+                logits = logits.astype(jnp.float32)
+                out = jax.nn.softmax(logits, axis=-1) if softmax else logits
             return (out, new_state["aux"]) if has_aux else out
 
         out_shardings = ((out_shard, replicated(self.mesh)) if has_aux
@@ -700,6 +740,8 @@ class InferenceEngine:
         ckpt = getattr(model_cfg, "checkpoint", None)
         self.profile_key = (f"{model_cfg.name}@{ckpt}" if ckpt
                             else model_cfg.name)
+        # numbers this engine's dispatches in the step log
+        self._step_count = itertools.count()
 
     # ---- occupancy telemetry (storm_tpu/obs) ---------------------------------
 
@@ -786,7 +828,8 @@ class InferenceEngine:
             return self._predict_serial(x)
         return self.dispatch((x,)).future.result()
 
-    def dispatch(self, parts: Sequence[np.ndarray]) -> InflightBatch:
+    def dispatch(self, parts: Sequence[np.ndarray],
+                 queued: Optional[dict] = None) -> InflightBatch:
         """Split-phase entry: stage ``parts`` (per-record arrays, already
         shape-validated) into a pooled staging buffer with one fused
         write, ship it to the device and launch the jit program
@@ -800,6 +843,10 @@ class InferenceEngine:
         call it from a worker thread, never the event loop. With the
         pipeline disabled it degrades to the serialized predict wrapped
         in an already-resolved handle.
+
+        ``queued``: the moments the caller's queue took before the cut
+        (``obs/profile.py new_step_row``), for the step log; they are on the handle
+        before the batch can be ready.
         """
         if self.quarantined:
             raise EngineQuarantined(
@@ -808,6 +855,10 @@ class InferenceEngine:
         n = sum(int(p.shape[0]) for p in parts)
         handle = InflightBatch(n, self.pad_batch(n))
         handle.profile_key = self.profile_key
+        if _profile_sink is not None:
+            handle.step = new_step_row(next(self._step_count),
+                                       self.profile_key, handle.padded, n,
+                                       queued)
         wd = float(getattr(self.batch_cfg, "watchdog_ms", 0.0) or 0.0)
         if wd > 0:
             handle.watchdog_ms = wd
@@ -883,7 +934,8 @@ class InferenceEngine:
             _copyledger.record("staging", f32.nbytes + buf.nbytes,
                                copies=2, records=n,
                                engine=self.profile_key or "-")
-            handle._t_put = 0.0 if cold else time.perf_counter()
+            t_put = time.perf_counter()
+            handle._t_put = 0.0 if cold else t_put
             with self._lock:
                 xd = jax.device_put(buf, self._x_sharding)
                 out = self._fwd_q(self.params, self.state, xd, scale, offset)
@@ -896,11 +948,16 @@ class InferenceEngine:
             # dispatch phase (pad + cast into the pooled buffer).
             _copyledger.record("staging", buf.nbytes, copies=1,
                                records=n, engine=self.profile_key or "-")
-            handle._t_put = 0.0 if cold else time.perf_counter()
+            t_put = time.perf_counter()
+            handle._t_put = 0.0 if cold else t_put
             with self._lock:
                 xd = jax.device_put(buf, self._x_sharding)
                 out = self._fwd(self.params, self.state, xd)
         t1 = time.perf_counter()
+        if handle.step is not None:
+            wall = time.time()
+            handle.step["t_staged"] = t_put + (wall - t1)
+            handle.step["t_launched"] = wall
         # Copy ledger: host->device transfer of the staged buffer (a CPU
         # backend may alias instead of copying, but the bytes handed to
         # device_put are the same either way). Recorded after t1 so the

@@ -561,6 +561,23 @@ def _profile_cmd(args) -> int:
         for shape, c in eng.get("compiles", {}).items():
             print(f"  compile bucket {shape}: n={c['count']} "
                   f"last={round(c['last_ms'], 1)}ms")
+    from storm_tpu.obs.profile import STEP_MOMENTS
+
+    steps = out.get("profile", {}).get("steps") or {}
+    for row in steps.get("last", []):
+        t0 = row.get("t_cut") or row.get("t_staged")
+        at = {k[2:]: (None if row.get(k) is None or t0 is None
+                      else round((row[k] - t0) * 1e3, 2))
+              for k in STEP_MOMENTS}
+        print(f"step {row['engine']} #{row['step']}: rows={row['rows']}/"
+              f"{row['padded']} sources={row.get('sources')} ms from the "
+              f"cut: " + " ".join(f"{k}={v}" for k, v in at.items()))
+    gap = steps.get("longest_gap")
+    if gap:
+        print(f"longest gap between steps ready, of {steps['count']} logged: "
+              f"{round(gap['gap_ms'], 2)}ms before {gap['after']['engine']} "
+              f"#{gap['after']['step']}; its {gap['interval']} is "
+              f"{round(gap['over_median_ms'] or 0.0, 2)}ms over the median")
     slo = out.get("slo")
     if slo:
         print(f"slo: fast_burn={slo.get('fast_burn')} "

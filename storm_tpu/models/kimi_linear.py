@@ -40,12 +40,24 @@ import jax.numpy as jnp
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import kda
 from storm_tpu.ops import layers as L
+from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import causal_attention
 from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
 
 
 def _w(rng, fan_in: int, fan_out: int):
     return L.lecun_normal(rng, (fan_in, fan_out), fan_in)
+
+
+def _proj(x, *ws):
+    """``x`` through the weights in turn, named a projection in a device
+    trace (ops/parts.py: the innermost name is the operation's, so a mixer
+    is ``mix.elementwise`` but for its products and the loops, which name
+    themselves)."""
+    with jax.named_scope(P.PROJ):
+        for w in ws:
+            x = L.matmul(x, w)
+        return x
 
 
 def kda_mixer_init(rng, dim: int, heads: int, head_dim: int,
@@ -83,20 +95,20 @@ def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
         return y.reshape(b, s, heads, head_dim)
 
     def branch(name):
-        y = kda.short_conv(p["conv_" + name], L.matmul(x, p[name]))
+        y = kda.short_conv(p["conv_" + name], _proj(x, p[name]))
         return heads_of(jax.nn.silu(y))
 
     q = kda.l2norm(branch("q")) * (head_dim ** -0.5)
     k = kda.l2norm(branch("k"))
     v = branch("v")
-    f = L.matmul(L.matmul(x, p["f_down"]), p["f_up"]).astype(f32)
+    f = _proj(x, p["f_down"], p["f_up"]).astype(f32)
     g = -jnp.exp(p["a_log"].astype(f32))[:, None] * heads_of(
         jax.nn.softplus(f + p["dt_bias"].astype(f32)))
-    beta = jax.nn.sigmoid(L.matmul(x, p["beta"]).astype(f32))
+    beta = jax.nn.sigmoid(_proj(x, p["beta"]).astype(f32))
     o = kda.kda_chunked(q.astype(x.dtype), k, v, g, beta, chunk=chunk)
-    gate = jax.nn.sigmoid(L.matmul(L.matmul(x, p["g_down"]), p["g_up"]))
+    gate = jax.nn.sigmoid(_proj(x, p["g_down"], p["g_up"]))
     o = L.rmsnorm(p["o_norm"], o, eps) * heads_of(gate)
-    return L.matmul(o.reshape(b, s, heads * head_dim), p["o"])
+    return _proj(o.reshape(b, s, heads * head_dim), p["o"])
 
 
 def mla_mixer_init(rng, dim: int, heads: int, nope: int, rope: int,
@@ -116,19 +128,19 @@ def mla_mixer(p: dict, x: jnp.ndarray, heads: int, nope: int, rope: int,
     """Latent attention without rotary: the ``rope`` channels are plain
     query/key channels, the key's shared by every head."""
     b, s, _ = x.shape
-    q = L.matmul(x, p["q"]).reshape(b, s, heads, nope + rope)
-    kv_a = L.matmul(x, p["kv_a"])
+    q = _proj(x, p["q"]).reshape(b, s, heads, nope + rope)
+    kv_a = _proj(x, p["kv_a"])
     latent = L.rmsnorm(p["kv_norm"], kv_a[..., :kv_rank], eps)
     k_shared = kv_a[..., kv_rank:]  # (B, S, rope)
-    kv = L.matmul(latent, p["kv_b"]).reshape(b, s, heads, nope + v_dim)
+    kv = _proj(latent, p["kv_b"]).reshape(b, s, heads, nope + v_dim)
     k = jnp.concatenate(
         [kv[..., :nope],
          jnp.broadcast_to(k_shared[:, :, None, :], (b, s, heads, rope))], -1)
     out = causal_attention(*(y.transpose(0, 2, 1, 3)
                              for y in (q, k, kv[..., nope:])),
                            scale=(nope + rope) ** -0.5)
-    return L.matmul(out.transpose(0, 2, 1, 3).reshape(b, s, heads * v_dim),
-                    p["o"])
+    return _proj(out.transpose(0, 2, 1, 3).reshape(b, s, heads * v_dim),
+                 p["o"])
 
 
 def build_kimi_linear(
@@ -209,26 +221,30 @@ def build_kimi_linear(
         return params, {"aux": aux} if n_moe else {}
 
     def apply(params, state, x, train: bool = False):
-        # ids ride the float32 instance contract (exact under 2^24)
-        ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
-                       vocab - 1).astype(jnp.int32)
-        dtype = params["head"].dtype
-        # The stream is float32 whatever the compute type: a bfloat16 stream
-        # is rounded at each of its ten adds, and a router reading it sends
-        # three times as many tokens to another expert than the reference
-        # does. The branches compute in ``dtype``.
-        h = params["embed"][ids].astype(jnp.float32)
+        with jax.named_scope(P.EMBED):
+            # ids ride the float32 instance contract (exact under 2^24)
+            ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
+                           vocab - 1).astype(jnp.int32)
+            dtype = params["head"].dtype
+            # The stream is float32 whatever the compute type: a bfloat16
+            # stream is rounded at each of its ten adds, and a router reading
+            # it sends three times as many tokens to another expert than the
+            # reference does. The branches compute in ``dtype``.
+            h = params["embed"][ids].astype(jnp.float32)
         tokens, absent = [], []
         for i, blk in enumerate(params["layers"], start=1):
-            y = L.rmsnorm(blk["norm1"], h, eps).astype(dtype)
-            if is_mla(i):
-                y = mla_mixer(blk["mixer"], y, mla_heads, nope, rope, v_dim,
-                              kv_rank, eps)
-            else:
-                y = kda_mixer(blk["mixer"], y, kda_heads, kda_head_dim,
-                              chunk, eps)
-            h = h + y.astype(jnp.float32)
-            y = L.rmsnorm(blk["norm2"], h, eps)
+            with jax.named_scope(P.NORM):
+                y = L.rmsnorm(blk["norm1"], h, eps).astype(dtype)
+            with jax.named_scope(P.MIX_ELEMENTWISE):  # but ``_proj``, loops
+                if is_mla(i):
+                    y = mla_mixer(blk["mixer"], y, mla_heads, nope, rope,
+                                  v_dim, kv_rank, eps)
+                else:
+                    y = kda_mixer(blk["mixer"], y, kda_heads, kda_head_dim,
+                                  chunk, eps)
+            with jax.named_scope(P.NORM):
+                h = h + y.astype(jnp.float32)
+                y = L.rmsnorm(blk["norm2"], h, eps)
             if "router" in blk["ffn"]:  # routes from the float32 stream
                 y, t, a = topk_moe_layer(
                     blk["ffn"], y, top_k, first_expert=first_expert,
@@ -237,10 +253,13 @@ def build_kimi_linear(
                 tokens.append(t)
                 absent.append(a)
             else:
-                y = L.swiglu(blk["ffn"], y.astype(dtype))
-            h = h + y.astype(jnp.float32)
-        last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
-        logits = L.matmul(last, params["head"])
+                with jax.named_scope(P.PROJ):
+                    y = L.swiglu(blk["ffn"], y.astype(dtype))
+            with jax.named_scope(P.NORM):
+                h = h + y.astype(jnp.float32)
+        with jax.named_scope(P.HEAD):
+            last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
+            logits = L.matmul(last, params["head"])
         if not tokens:
             return logits, state
         return logits, {**state, "aux": {

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import kda
 from storm_tpu.ops import layers as L
+from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import causal_attention
 from storm_tpu.ops.ssd import ssd_chunked
 from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
@@ -54,6 +55,15 @@ KINDS = "ME*"  # Mamba-2, experts, attention
 
 def _w(rng, fan_in: int, fan_out: int):
     return L.lecun_normal(rng, (fan_in, fan_out), fan_in)
+
+
+def _proj(x, w):
+    """A product with weights, named a projection in a device trace
+    (ops/parts.py: the innermost name is the operation's, so a mixer is
+    ``mix.elementwise`` but for its products and the loops, which name
+    themselves)."""
+    with jax.named_scope(P.PROJ):
+        return L.matmul(x, w)
 
 
 def mamba_mixer_init(rng, dim: int, heads: int, head_dim: int, groups: int,
@@ -83,7 +93,7 @@ def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
     b, s, _ = x.shape
     f32 = jnp.float32
     inner, gn = heads * head_dim, groups * state
-    zxbcdt = L.matmul(x, p["in_proj"])  # [z | x B C | dt]
+    zxbcdt = _proj(x, p["in_proj"])  # [z | x B C | dt]
     z = zxbcdt[..., :inner]
     xbc = jax.nn.silu(kda.short_conv(p["conv"],
                                      zxbcdt[..., inner:2 * inner + 2 * gn]))
@@ -97,7 +107,7 @@ def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
         p["d"], chunk=chunk)
     y = L.gated_group_rmsnorm(p["norm"], y.reshape(b, s, inner), z, groups,
                               eps)
-    return L.matmul(y, p["out_proj"])
+    return _proj(y, p["out_proj"])
 
 
 def gqa_mixer_init(rng, dim: int, heads: int, kv_heads: int,
@@ -116,13 +126,13 @@ def gqa_mixer(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
     b, s, _ = x.shape
 
     def split(name, n):
-        return L.matmul(x, p[name]).reshape(b, s, n, head_dim).transpose(
+        return _proj(x, p[name]).reshape(b, s, n, head_dim).transpose(
             0, 2, 1, 3)
 
     out = causal_attention(split("q", heads), split("k", kv_heads),
                            split("v", kv_heads), scale=head_dim ** -0.5)
-    return L.matmul(out.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim),
-                    p["o"])
+    return _proj(out.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim),
+                 p["o"])
 
 
 def build_nemotron_h(
@@ -210,23 +220,28 @@ def build_nemotron_h(
         return params, {"aux": aux} if n_moe else {}
 
     def apply(params, state_in, x, train: bool = False):
-        # ids ride the float32 instance contract (exact under 2^24)
-        ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
-                       vocab - 1).astype(jnp.int32)
-        dtype = params["head"].dtype
-        # a float32 stream whatever the compute type, and a router that
-        # reads it unrounded (models/kimi_linear.py says why); the mixers
-        # compute in ``dtype``
-        h = params["embed"][ids].astype(jnp.float32)
+        with jax.named_scope(P.EMBED):
+            # ids ride the float32 instance contract (exact under 2^24)
+            ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
+                           vocab - 1).astype(jnp.int32)
+            dtype = params["head"].dtype
+            # a float32 stream whatever the compute type, and a router that
+            # reads it unrounded (models/kimi_linear.py says why); the mixers
+            # compute in ``dtype``
+            h = params["embed"][ids].astype(jnp.float32)
         tokens, absent = [], []
         for letter, blk in zip(pattern, params["layers"]):
-            y = L.rmsnorm(blk["norm"], h, eps)
+            with jax.named_scope(P.NORM):
+                y = L.rmsnorm(blk["norm"], h, eps)
             if letter == "M":
-                y = mamba_mixer(blk["mixer"], y.astype(dtype), mamba_heads,
-                                mamba_head_dim, groups, state, chunk, eps)
+                with jax.named_scope(P.MIX_ELEMENTWISE):  # but ``_proj``, loop
+                    y = mamba_mixer(blk["mixer"], y.astype(dtype),
+                                    mamba_heads, mamba_head_dim, groups,
+                                    state, chunk, eps)
             elif letter == "*":
-                y = gqa_mixer(blk["mixer"], y.astype(dtype), heads, kv_heads,
-                              head_dim)
+                with jax.named_scope(P.MIX_ELEMENTWISE):
+                    y = gqa_mixer(blk["mixer"], y.astype(dtype), heads,
+                                  kv_heads, head_dim)
             else:
                 y, t, a = topk_moe_layer(
                     blk["mixer"], y, top_k, first_expert=first_expert,
@@ -234,9 +249,11 @@ def build_nemotron_h(
                     tile=expert_tile)
                 tokens.append(t)
                 absent.append(a)
-            h = h + y.astype(jnp.float32)
-        last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
-        logits = L.matmul(last, params["head"])
+            with jax.named_scope(P.NORM):
+                h = h + y.astype(jnp.float32)
+        with jax.named_scope(P.HEAD):
+            last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
+            logits = L.matmul(last, params["head"])
         if not tokens:
             return logits, state_in
         return logits, {**state_in, "aux": {

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import layers as L
+from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import mha_init, multi_head_attention
 
 
@@ -30,14 +31,24 @@ def _block_init(rng, dim, mlp_dim, num_heads):
 
 
 def _block(p, x, num_heads):
-    y = x + multi_head_attention(p["attn"], L.layernorm(p["ln1"], x), num_heads)
+    # Each part under its name in a device trace (ops/parts.py); the
+    # attention names its own inside (ops/attention.py).
+    with jax.named_scope(P.NORM):
+        y = L.layernorm(p["ln1"], x)
+    y = multi_head_attention(p["attn"], y, num_heads)
+    with jax.named_scope(P.NORM):
+        y = x + y
     # Keep the stream (batch, tokens, dim) through both norms: XLA makes each
     # residual add and the next norm's row sum the epilogue of the projection
     # before it and folds the normalise into the projection after it; flattened
     # to (rows, dim), a program of many rows pays a float32 copy of the stream
     # a block (PERF.md §6, PR 29).
-    h = L.gelu(L.dense(p["mlp_in"], L.layernorm(p["ln2"], y)))
-    return y + L.dense(p["mlp_out"], h)
+    with jax.named_scope(P.NORM):
+        h = L.layernorm(p["ln2"], y)
+    with jax.named_scope(P.PROJ):
+        h = L.dense(p["mlp_out"], L.gelu(L.dense(p["mlp_in"], h)))
+    with jax.named_scope(P.NORM):
+        return y + h
 
 
 def build_vit(
@@ -72,15 +83,19 @@ def build_vit(
 
     def apply(params, state, x, train: bool = False):
         b = x.shape[0]
-        # (B, H, W, C) -> (B, S, dim) patch tokens via strided conv.
-        tok = L.conv2d(params["embed"], x, stride=patch, padding="VALID")
-        tok = tok.reshape(b, n_patches, dim)
-        cls = jnp.broadcast_to(params["cls"].astype(tok.dtype), (b, 1, dim))
-        tok = jnp.concatenate([cls, tok], axis=1) + params["pos"].astype(tok.dtype)
+        with jax.named_scope(P.EMBED):
+            # (B, H, W, C) -> (B, S, dim) patch tokens via strided conv.
+            tok = L.conv2d(params["embed"], x, stride=patch, padding="VALID")
+            tok = tok.reshape(b, n_patches, dim)
+            cls = jnp.broadcast_to(params["cls"].astype(tok.dtype),
+                                   (b, 1, dim))
+            tok = (jnp.concatenate([cls, tok], axis=1)
+                   + params["pos"].astype(tok.dtype))
         for p_blk in params["blocks"]:
             tok = _block(p_blk, tok, num_heads)
-        tok = L.layernorm(params["ln"], tok)
-        return L.dense(params["head"], tok[:, 0]), state
+        with jax.named_scope(P.HEAD):
+            tok = L.layernorm(params["ln"], tok)
+            return L.dense(params["head"], tok[:, 0]), state
 
     return ModelDef(name, input_shape, num_classes, init, apply, flagship=True,
                     hyper={"num_heads": num_heads, "dim": dim, "depth": depth,

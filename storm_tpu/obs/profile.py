@@ -26,6 +26,7 @@ that file back as the regression sentinel's baseline
 from __future__ import annotations
 
 import threading
+from collections import deque
 from typing import Dict, List, Optional
 
 from storm_tpu.runtime.metrics import Histogram
@@ -39,6 +40,69 @@ STAGE_KEYS = ("h2d_ms", "compute_ms", "d2h_ms", "device_ms")
 # Reservoir per (engine, bucket, stage): small — a profile tracks the
 # recent cost distribution, not history (the artifact snapshots it).
 _RING = 512
+
+# The step log: the last this many steps of the process, one row a step,
+# whatever their engine.
+STEP_LOG = 4096
+
+# The moments of a step, in the order they pass (docs/OPERATIONS.md, "Reading
+# the step log"). All ``time.time()`` seconds, the clock the broker stamps
+# records with and a device trace is brought onto; one taken on
+# ``perf_counter`` is converted by an offset read beside it, on the same
+# thread for the same row. None where a path does not pass the moment.
+STEP_MOMENTS = ("t_first_enq", "t_cut", "t_staged", "t_launched", "t_ready",
+                "t_fetched", "t_resolved")
+# the intervals between them, as ``longest_gap`` names them: ``cut->staged``
+STEP_INTERVALS = tuple((f"{a[2:]}->{b[2:]}", a, b)
+                       for a, b in zip(STEP_MOMENTS, STEP_MOMENTS[1:]))
+
+
+def new_step_row(step: int, engine: str, padded: int, rows: int,
+                 queued: Optional[dict] = None) -> dict:
+    """One row of the step log, built by ``InferenceEngine.dispatch``:
+    ``step`` counts an engine's dispatches, ``queued`` is what the queue
+    that cut the batch knows of it (``t_first_enq``, ``t_cut``, ``sources``;
+    None for a direct ``predict``). ``seen``: whether the fetch thread
+    watched the result become ready (``t_ready`` is then when it did, else
+    when the thread came to it)."""
+    row = {"step": step, "engine": engine, "padded": padded, "rows": rows,
+           "sources": None, "seen": False}
+    row.update(dict.fromkeys(STEP_MOMENTS))
+    if queued:
+        row.update(queued)
+    return row
+
+
+def longest_gap(rows: List[dict]) -> Optional[dict]:
+    """Of ``rows`` (``ProfileStore.steps()``: any stretch of the log), the
+    two consecutive steps of one engine whose results became ready farthest
+    apart, and which interval of the later one exceeds its median over the
+    rows by most: where the time of a stall went. None under two steps
+    with ``t_ready``."""
+    done: Dict[str, List[dict]] = {}
+    for r in rows:
+        if r.get("t_ready") is not None:
+            done.setdefault(r["engine"], []).append(r)
+    best = None
+    for per in done.values():
+        per.sort(key=lambda r: r["t_ready"])
+        for a, b in zip(per, per[1:]):
+            gap = b["t_ready"] - a["t_ready"]
+            if best is None or gap > best[0]:
+                best = (gap, a, b, per)
+    if best is None:
+        return None
+    gap, a, b, per = best
+    over = {}
+    for name, t0, t1 in STEP_INTERVALS:
+        spans = sorted(r[t1] - r[t0] for r in per
+                       if r.get(t0) is not None and r.get(t1) is not None)
+        if spans and b.get(t0) is not None and b.get(t1) is not None:
+            over[name] = (b[t1] - b[t0]) - spans[len(spans) // 2]
+    worst = max(over, key=over.get) if over else None
+    return {"gap_ms": gap * 1e3, "before": dict(a), "after": dict(b),
+            "interval": worst,
+            "over_median_ms": over[worst] * 1e3 if worst else None}
 
 
 class _Bucket:
@@ -63,16 +127,21 @@ class ProfileStore:
         # engine key -> {padded: {"count": n, "sum_ms": s, "last_ms": x}}
         self._compiles: Dict[str, Dict[int, Dict[str, float]]] = {}
         self._baseline: Optional[dict] = None
+        self._steps: deque = deque(maxlen=STEP_LOG)
 
     # ---- the write path (engine layer) ---------------------------------------
 
     def record_batch(self, key: str, padded: int, rows: int,
-                     timings: Dict[str, float]) -> None:
+                     timings: Dict[str, float],
+                     step: Optional[dict] = None) -> None:
         """One completed device batch: ``timings`` is the engine's
-        per-phase dict (any subset of h2d/compute/d2h)."""
+        per-phase dict (any subset of h2d/compute/d2h), ``step`` its row of
+        the step log (kept as it is: the queue may still stamp it)."""
         if not timings:
             return
         with self._lock:
+            if step is not None:
+                self._steps.append(step)
             per = self._buckets.setdefault(key, {})
             b = per.get(int(padded))
             if b is None:
@@ -103,13 +172,24 @@ class ProfileStore:
         with self._lock:
             self._buckets.clear()
             self._compiles.clear()
+            self._steps.clear()
 
     # ---- the read path -------------------------------------------------------
+
+    def steps(self) -> List[dict]:
+        """The step log, oldest first: a copy of each of the last
+        ``STEP_LOG`` rows."""
+        with self._lock:
+            return [dict(r) for r in self._steps]
 
     def snapshot(self) -> dict:
         """JSON-safe curves: per engine, per padded bucket, per stage
         {count, mean, p50, p95, max} plus rows/s throughput; compile cost
-        per shape. Bucket keys are stringified ints (JSON round-trip)."""
+        per shape. Bucket keys are stringified ints (JSON round-trip).
+        ``steps``: how many rows the step log holds, its last eight, and
+        the longest gap between two steps with the interval it went to
+        (:func:`longest_gap`)."""
+        rows = self.steps()
         with self._lock:
             buckets = {k: dict(v) for k, v in self._buckets.items()}
             compiles = {k: {str(n): dict(c) for n, c in v.items()}
@@ -142,7 +222,9 @@ class ProfileStore:
                 }
             engines[key] = {"buckets": rows_out,
                             "compiles": compiles.get(key, {})}
-        return {"engines": engines}
+        return {"engines": engines,
+                "steps": {"count": len(rows), "last": rows[-8:],
+                          "longest_gap": longest_gap(rows)}}
 
     def cost_of(self, key: str,
                 min_samples: int = 1) -> Optional[dict]:

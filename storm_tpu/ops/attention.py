@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 
+from storm_tpu.ops import parts as P
 from storm_tpu.ops.platform import note as _note
 from storm_tpu.ops.platform import one_device as _one_device
 from storm_tpu.ops.platform import use_pallas as _use_pallas
@@ -138,7 +139,8 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out = jnp.concatenate(outs, -2)
         return out.reshape(hq, s, out.shape[-1]) if grouped else out
 
-    return jax.lax.map(row, (q, k, v))
+    with jax.named_scope(P.MIX_ATTENTION):
+        return jax.lax.map(row, (q, k, v))
 
 
 def mha_init(rng, dim: int, num_heads: int, dtype=jnp.float32) -> dict:
@@ -170,14 +172,20 @@ def multi_head_attention(p: dict, x: jnp.ndarray, num_heads: int) -> jnp.ndarray
     from storm_tpu.ops.layers import dense
 
     b, s, c = x.shape
-    q, k, v = dense(p["q"], x), dense(p["k"], x), dense(p["v"], x)
+    with jax.named_scope(P.PROJ):
+        q, k, v = dense(p["q"], x), dense(p["k"], x), dense(p["v"], x)
     form = attention_form(b, s, c, num_heads, x.dtype.itemsize)
     _note("attention", form)
     if form == "rows":
         from storm_tpu.ops.short_attention import short_attention
 
-        out = short_attention(q, k, v, num_heads)
+        out = short_attention(q, k, v, num_heads)  # names itself
     else:
-        out = merge_heads(scaled_dot_attention(
-            *(split_heads(y, num_heads) for y in (q, k, v))))
-    return dense(p["o"], out)
+        with jax.named_scope(P.MIX_ELEMENTWISE):
+            heads = tuple(split_heads(y, num_heads) for y in (q, k, v))
+        with jax.named_scope(P.MIX_ATTENTION):
+            out = scaled_dot_attention(*heads)
+        with jax.named_scope(P.MIX_ELEMENTWISE):
+            out = merge_heads(out)
+    with jax.named_scope(P.PROJ):
+        return dense(p["o"], out)

@@ -88,6 +88,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from storm_tpu.ops import parts as P
 from storm_tpu.ops.platform import note as _note
 from storm_tpu.ops.platform import one_device as _one_device
 from storm_tpu.ops.platform import use_pallas as _use_pallas
@@ -440,21 +441,22 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
         return jnp.moveaxis(y, 3, 1)
 
     # Either form a row at a time: one loop in the compiled program, whose
-    # device time a trace shows whole.
+    # device time a trace shows whole, under its name there (ops/parts.py).
     if form == "kernel":
         # (B, S, H, d) as (B, N * C, H * d): no data moves, and the kernel
         # reads its row where it lies and writes it where the chain reads it
         whole = tuple(padded(y).reshape(b, n * chunk, -1)
                       for y in (q, k, v, g.astype(f32))) + (chunks(beta),)
-        w, u0, q_in, k_out, a_qk, decay = lax.fori_loop(
-            0, b, lambda row, tables: within_chunks_kernel(
-                row, tables, *whole, heads=h, chunk=chunk),
-            empty_tables(b, n, h, chunk, dk, dv, cd))
+        with jax.named_scope(P.MIX_KDA_TABLES):
+            w, u0, q_in, k_out, a_qk, decay = lax.fori_loop(
+                0, b, lambda row, tables: within_chunks_kernel(
+                    row, tables, *whole, heads=h, chunk=chunk),
+                empty_tables(b, n, h, chunk, dk, dv, cd))
         decay = jnp.moveaxis(decay, 2, 0)
     else:
-        parts = lax.map(
-            lambda row: _within_chunks(*row, sub=sub),
-            tuple(chunks(y) for y in (q, k, v, g.astype(f32), beta)))
+        rows = tuple(chunks(y) for y in (q, k, v, g.astype(f32), beta))
+        with jax.named_scope(P.MIX_KDA_TABLES):
+            parts = lax.map(lambda row: _within_chunks(*row, sub=sub), rows)
         w, u0, q_in, k_out, a_qk, decay = (jnp.moveaxis(y, 2, 0)
                                            for y in parts)
 
@@ -473,8 +475,9 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
             "bhtk,bhtv->bhkv", k_c, ub, preferred_element_type=f32)
         return state, o.astype(cd)
 
-    _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
-                    (w, u0, q_in, k_out, a_qk, decay))
+    with jax.named_scope(P.MIX_KDA_SCAN):
+        _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
+                        (w, u0, q_in, k_out, a_qk, decay))
     # (N, B, H, C, dv) -> (B, S, H, dv)
     o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, n * chunk, h, dv)
     return o[:, :s]

@@ -1,0 +1,43 @@
+"""The names of a model's parts in a device trace, and nothing else.
+
+A model wraps each part in ``jax.named_scope(<one of these>)``; the name
+rides the operations' metadata (``op_name``: ``jit(fwd)/.../proj/dot_general``)
+into the compiled program and from there into a profiler trace. A reader of
+traces imports the same constants, so a model and a reader cannot drift
+apart. Flat, one level deep below the layer: a part entered inside another
+part (``mix.attention`` under ``mix.elementwise``) is the inner one's.
+
+A scope changes metadata and no value: the compiled code is the same. The
+compile cache therefore keys on metadata too (``infer/engine.py
+enable_compile_cache``), or a program cached without its names would be
+loaded in place of one with them.
+"""
+
+EMBED = "embed"  # patch/token embedding
+HEAD = "head"  # final norm, classifier or vocabulary product, softmax
+NORM = "norm"  # a block's pre-norms and residual adds
+PROJ = "proj"  # every dense product with weights that is not an expert's
+MIX_ELEMENTWISE = "mix.elementwise"  # conv, gates, norms, re-tiling, layout
+MIX_KDA_TABLES = "mix.kda_tables"  # ops/kda.py: the within-chunk tables
+MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
+MIX_SSD_SCAN = "mix.ssd_scan"  # ops/ssd.py: the loop over chunks
+MIX_ATTENTION = "mix.attention"  # causal_attention's loop, ViT's attention
+MOE_ROUTE = "moe.route"  # router, top-k, sorts, counts, starts, zeroed buffer
+MOE_EXPERTS = "moe.experts"  # topk_moe_layer's loop over tiles
+MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
+
+VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
+              MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MOE_ROUTE,
+              MOE_EXPERTS, MOE_COMBINE)
+
+
+def part_of(op_name: str):
+    """The part an operation's ``op_name`` path lies under: the innermost
+    component that is of the vocabulary, None where there is none."""
+    for piece in reversed(op_name.split("/")):
+        if piece in _KNOWN:
+            return piece
+    return None
+
+
+_KNOWN = frozenset(VOCABULARY)

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from storm_tpu.ops import parts as P
+
 # What one grid step may hold in VMEM: q, k, v and the output tile, each
 # double-buffered by the pipeline, and a few float32 score tiles of one head.
 _VMEM_BUDGET = 12 * 1024 * 1024
@@ -63,14 +65,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, heads, scale):
 def _forward(q, k, v, *, heads, interpret=False):
     b, s, c = q.shape
     row = pl.BlockSpec((1, s, c), lambda i: (i, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel, heads=heads, scale=(c // heads) ** -0.5),
-        grid=(b,),
-        in_specs=[row, row, row],
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(q, k, v)
+    with jax.named_scope(P.MIX_ATTENTION):
+        return pl.pallas_call(
+            functools.partial(_kernel, heads=heads,
+                              scale=(c // heads) ** -0.5),
+            grid=(b,),
+            in_specs=[row, row, row],
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=interpret,
+        )(q, k, v)
 
 
 def reference(q, k, v, heads):

@@ -36,9 +36,11 @@ scattered and no table is rewritten.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
+from storm_tpu.ops import parts as P
 from storm_tpu.ops.platform import note as _note
 
 
@@ -94,6 +96,7 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
             b_c, preferred_element_type=f32)
         return state, y.astype(cd)
 
-    _, y = lax.scan(one_chunk, jnp.zeros((bsz, g, r, p, n), f32), xs)
+    with jax.named_scope(P.MIX_SSD_SCAN):  # its name in a device trace
+        _, y = lax.scan(one_chunk, jnp.zeros((bsz, g, r, p, n), f32), xs)
     y = jnp.moveaxis(y, 0, 1).reshape(bsz, s + pad, h, p)[:, :s]
     return (y.astype(f32) + d.astype(f32)[:, None] * x.astype(f32)).astype(cd)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from storm_tpu.ops import parts
 from storm_tpu.ops.platform import note as _note
 
 
@@ -284,28 +285,36 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     w = p["experts"]
     held = w["down"].shape[0]
     _note("expert_ffn", "swiglu" if "gate" in w else "relu2")
-    # the router reads ``x`` as it comes (float32 from a float32 stream: a
-    # rounded input breaks ties the other way); the experts compute in the
-    # type of their weights
-    experts, weights = route_topk(p, x.reshape(-1, dim), top_k, router,
-                                  renormalize, scale)
-    x = x.astype(w["down"].dtype)
-    tokens = x.reshape(-1, dim)
-    n = tokens.shape[0]
-    tile = max(8, min(int(tile), -(-n // 8) * 8))
-    most_tiles = -(-n * top_k // tile) + held
-    local = experts - first_expert
-    local = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
-    order = jnp.argsort(local, stable=True)  # by expert, tokens ascending
-    counts_all = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
-    counts, absent = counts_all[:held], counts_all[held]
-    starts = jnp.cumsum(counts) - counts
-    tiles = -(-counts // tile)
-    tile_ends = jnp.cumsum(tiles)
-    # a slice of ``tile`` from any start inside the assignments stays inside
-    token_of = jnp.pad((order // top_k).astype(jnp.int32), (0, tile))
-    weight_of = jnp.pad(weights.reshape(-1)[order], (0, tile))
-    lane = jnp.arange(tile, dtype=jnp.int32)
+    # Its three parts under their names in a device trace (ops/parts.py):
+    # routing with everything that only orders and counts, the loop over
+    # tiles, the combine.
+    with jax.named_scope(parts.MOE_ROUTE):
+        # the router reads ``x`` as it comes (float32 from a float32 stream:
+        # a rounded input breaks ties the other way); the experts compute in
+        # the type of their weights
+        experts, weights = route_topk(p, x.reshape(-1, dim), top_k, router,
+                                      renormalize, scale)
+        x = x.astype(w["down"].dtype)
+        tokens = x.reshape(-1, dim)
+        n = tokens.shape[0]
+        tile = max(8, min(int(tile), -(-n // 8) * 8))
+        most_tiles = -(-n * top_k // tile) + held
+        local = experts - first_expert
+        local = jnp.where((local >= 0) & (local < held), local,
+                          held).reshape(-1)
+        order = jnp.argsort(local, stable=True)  # by expert, tokens ascending
+        counts_all = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+        counts, absent = counts_all[:held], counts_all[held]
+        starts = jnp.cumsum(counts) - counts
+        tiles = -(-counts // tile)
+        tile_ends = jnp.cumsum(tiles)
+        # a slice of ``tile`` from any start inside the assignments stays
+        # inside
+        token_of = jnp.pad((order // top_k).astype(jnp.int32), (0, tile))
+        weight_of = jnp.pad(weights.reshape(-1)[order], (0, tile))
+        lane = jnp.arange(tile, dtype=jnp.int32)
+        # one row more than the tiles can fill: where absent assignments point
+        empty = jnp.zeros((most_tiles * tile + 1, dim), x.dtype)
 
     def one_tile(i, out):
         e = jnp.searchsorted(tile_ends, i, side="right").astype(jnp.int32)
@@ -320,22 +329,26 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         y = (y.astype(jnp.float32) * gain[:, None]).astype(out.dtype)
         return jax.lax.dynamic_update_slice(out, y, (i * tile, 0))
 
-    # one row more than the tiles can fill: where absent assignments point
-    out = jax.lax.fori_loop(
-        0, tile_ends[-1], one_tile,
-        jnp.zeros((most_tiles * tile + 1, dim), x.dtype))
-    # where each assignment's row is: its expert's first tile, and its place
-    # in the expert's run
-    place = jnp.argsort(order).astype(jnp.int32)  # assignment -> sorted place
-    first_row = jnp.append((tile_ends - tiles) * tile - starts,
+    with jax.named_scope(parts.MOE_EXPERTS):
+        out = jax.lax.fori_loop(0, tile_ends[-1], one_tile, empty)
+    with jax.named_scope(parts.MOE_ROUTE):
+        # where each assignment's row is: its expert's first tile, and its
+        # place in the expert's run
+        place = jnp.argsort(order).astype(jnp.int32)  # assignment -> place
+        first_row = jnp.append((tile_ends - tiles) * tile - starts,
+                               most_tiles * tile)
+        row_of = jnp.where(local < held, place + first_row[local],
                            most_tiles * tile)
-    row_of = jnp.where(local < held, place + first_row[local],
-                       most_tiles * tile)
     _note("expert_combine", "held-rows")
-    y = _combine_held(out, row_of, n, top_k)
+    with jax.named_scope(parts.MOE_COMBINE):
+        y = _combine_held(out, row_of, n, top_k)
     if "shared" in p:
-        y = y + L.feed_forward(p["shared"], tokens).astype(jnp.float32)
-    return y.astype(x.dtype).reshape(shape), counts, absent
+        with jax.named_scope(parts.PROJ):
+            shared = L.feed_forward(p["shared"], tokens)
+        with jax.named_scope(parts.MOE_COMBINE):
+            y = y + shared.astype(jnp.float32)
+    with jax.named_scope(parts.MOE_COMBINE):
+        return y.astype(x.dtype).reshape(shape), counts, absent
 
 
 def moe_block_init(rng, dim: int, mlp_dim: int, num_heads: int, n_experts: int):

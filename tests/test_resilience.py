@@ -374,14 +374,16 @@ def test_engine_hang_injection_quarantines_real_engine():
     eng = InferenceEngine(
         ModelConfig(name="lenet5", dtype="float32", input_shape=(28, 28, 1)),
         ShardingConfig(data_parallel=1),
-        BatchConfig(max_batch=8, buckets=(8,), watchdog_ms=100.0,
+        # a deadline a loaded CPU's honest batch keeps (100 ms was missed by
+        # the warm-up once in two whole runs under six workers: PR 41)
+        BatchConfig(max_batch=8, buckets=(8,), watchdog_ms=400.0,
                     watchdog_trips=2),
     )
     eng.warmup()
     x = np.zeros((4, 28, 28, 1), np.float32)
     assert eng.dispatch((x,)).future.result(timeout=30).shape == (4, 10)
     inj = get_injector()
-    inj.configure(engine_hang_ms=600.0, engine_hang_next=2)
+    inj.configure(engine_hang_ms=1500.0, engine_hang_next=2)
     try:
         for _ in range(2):
             with pytest.raises(EngineWatchdogTimeout):
