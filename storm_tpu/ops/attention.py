@@ -1,11 +1,28 @@
-"""Multi-head attention with a Pallas flash-attention fast path.
+"""Attention, and the rules that say which form serves a program.
 
-The reference has no attention (CNN workloads only, SURVEY.md §5.7); the
-ViT-B/16 config in BASELINE.json adds it. On TPU the score/softmax/value
-contraction runs as a fused Pallas kernel (:mod:`storm_tpu.ops.flash_attention`)
-so the (S, S) score matrix never round-trips to HBM; on CPU (tests) and for
-shapes the kernel doesn't cover, a plain jnp reference path is used — both
-paths are numerically cross-checked in tests/test_ops.py.
+Two entry points, each a rule over the traced shapes and over what the
+process runs on (a TPU or not, one device or several: ops/platform.py), never
+an option or a model's name; each notes its choice (``platform.note``), so
+``engine_inventory()["programs"]`` says per bucket what a program was built
+with.
+
+* :func:`multi_head_attention` (the ViTs: full attention over ``(B, S, C)``),
+  by :func:`attention_form`: ``"rows"``, the Pallas row kernel of
+  ops/short_attention.py, for many rows of a short sequence (from eight
+  million scores, in a process with one device: ViT-g/14 at 8 to 256 rows);
+  ``"flash"``, ops/flash_attention.py, from 1,024 tokens (no model of the
+  registry is that long: the parity checks and ``attention_bench.py`` run
+  it); ``"xla"``, :func:`attention_reference`, elsewhere and on the CPU.
+* :func:`causal_attention` (Kimi-Linear's latent attention, Nemotron-H's
+  grouped queries: ``(B, H, S, D)``, ``Dv`` and ``Hkv`` of their own), by
+  :func:`causal_form`: ``"kernel"``, one call a row of the batch of
+  ops/flash_attention.py's kernel with ``causal`` (a block's scores stay in
+  VMEM, key blocks beyond the diagonal are never loaded), on a TPU in a
+  process with one device for whole tiles; ``"blocked"``,
+  :func:`causal_blocked`, XLA's form, elsewhere and on the CPU.
+
+The forms of one rule are cross-checked in tests/test_ops.py under the Pallas
+interpreter and compiled on the chip by ops/parity_checks.py.
 """
 
 from __future__ import annotations
@@ -91,6 +108,28 @@ def attention_form(b: int, s: int, c: int, num_heads: int,
     return "xla"
 
 
+def causal_form(hq: int, hkv: int, s: int, dk: int, dv: int) -> str:
+    """Which form a program's causal attention is built with: ``"kernel"``
+    (one call a row of ops/flash_attention.py with ``causal``: a block's
+    scores stay in VMEM) or ``"blocked"`` (XLA's, below). A function of the
+    traced shapes and of what the process runs on, as :func:`attention_form`
+    and ops/kda.py ``tables_form``: the kernel on a TPU in a process with one
+    device (a Mosaic call has no partitioning rule, ops/platform.py
+    ``one_device``), for a sequence of whole query and key tiles and head
+    widths the kernel reads as they lie (``causal_tiles``, ``lane_width``:
+    no copy pads an operand on its way in); the blocked form elsewhere (the
+    CPU, a host with several chips, the tiny presets' 40 tokens of 16
+    channels)."""
+    from storm_tpu.ops.flash_attention import causal_tiles, lane_width
+
+    block_q, block_k = causal_tiles(hq // hkv)
+    if (_use_pallas() and _one_device() and s % block_q == 0
+            and s % block_k == 0 and lane_width(dk) == dk
+            and lane_width(dv) == dv):
+        return "kernel"
+    return "blocked"
+
+
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      scale: Optional[float] = None,
                      block: int = 512) -> jnp.ndarray:
@@ -101,20 +140,45 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Hkv)``; the keys are read in place by their group's query heads, never
     written out once a query head). The scores of a whole batch are never
     formed: one row of the batch at a time (``lax.map``: one loop in the
-    compiled program, whose device time a trace shows whole), and within it a
-    block of ``block`` queries against the keys up to that block's end, so
-    the upper triangle is not computed and at most ``Hq x block x S`` scores
-    exist at once. Softmax in float32. One form on every platform."""
+    compiled program, whose device time a trace shows whole and which the
+    benchmark finds by the shapes of the q, k and v it carries). Softmax in
+    float32, the weights to the value product in the values' type and
+    unnormalised, the result divided by their float32 sum, in both forms.
+
+    Within a row, by :func:`causal_form`: the Pallas kernel of
+    ops/flash_attention.py, which keeps a block's scores in VMEM, stops at
+    the diagonal and reads the row where it lies in q, k and v (the loop
+    cuts nothing out of them); or :func:`causal_blocked`."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    hq, s = q.shape[1], q.shape[2]
-    hkv = k.shape[1]
+    hq, hkv, s = q.shape[1], k.shape[1], q.shape[2]
     if hq % hkv:
         raise ValueError(f"{hq} query heads over {hkv} key heads")
+    form = causal_form(hq, hkv, s, q.shape[-1], v.shape[-1])
+    _note("causal_attention", form + ("-grouped" if hq != hkv else ""))
+    if form == "blocked":
+        return causal_blocked(q, k, v, scale, block)
+
+    from storm_tpu.ops.flash_attention import causal_tiles, flash_attention
+
+    block_q, block_k = causal_tiles(hq // hkv)
+    with jax.named_scope(P.MIX_ATTENTION):
+        return jax.lax.map(lambda i: flash_attention(
+            q, k, v, scale=scale, block_q=block_q, block_k=block_k,
+            causal=True, row=i)[0], jnp.arange(q.shape[0]))
+
+
+def causal_blocked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                   scale: float, block: int = 512) -> jnp.ndarray:
+    """:func:`causal_attention` as XLA computes it, on every platform: within
+    a row a block of ``block`` queries against the keys up to that block's
+    end, so the upper triangle is not computed and at most ``Hq x block x S``
+    scores exist at once (XLA writes them to HBM between the two
+    products)."""
+    hq, hkv, s = q.shape[1], k.shape[1], q.shape[2]
     # with one query head a key head the group axis is left out altogether,
     # so that such a program carries the shapes it always has
     grouped = hq != hkv
-    _note("causal_attention", "blocked-grouped" if grouped else "blocked")
     scores_of, values_of = (("grsd,gtd->grst", "grst,gtd->grsd") if grouped
                             else ("hsd,htd->hst", "hst,htd->hsd"))
 

@@ -1,23 +1,48 @@
-"""Pallas TPU flash attention (fused scores/softmax/value contraction).
+"""Pallas TPU flash attention (fused scores/softmax/value contraction), full
+or causal.
 
 Why: the naive path materializes the (S, S) score matrix in HBM twice per
-layer; this kernel keeps the whole online-softmax accumulation in VMEM, so
-HBM traffic is just q/k/v in and o out. Dispatch is shape-aware
-(ops/attention.py): below ~1024 tokens XLA's own fused attention is
-faster on-chip and serves (e.g. ViT-B/16's S=197); at and above it this
-kernel wins 2-3x (measured — BENCH_NOTES.md round 2). The ring-attention
-sequence-parallel path computes its per-shard partials with its own
-online-softmax math (parallel/ring_attention.py), not this kernel.
+layer (XLA's blocked causal form: the float32 scores of 32 heads x 512
+queries, three to four passes, 35-47 ms a step of the language cells,
+PERF.md §6, PR 42); this kernel keeps the whole online-softmax accumulation
+in VMEM, so HBM traffic is just q/k/v in and o out. Dispatch is shape-aware
+(ops/attention.py): the full form serves ``multi_head_attention`` from 1,024
+tokens (below it XLA's own fused attention is faster on-chip, e.g. ViT-B/16's
+S=197; BENCH_NOTES.md round 2), the causal form serves ``causal_attention``,
+one call a row of the batch. The ring-attention sequence-parallel path
+computes its per-shard partials with its own online-softmax math
+(parallel/ring_attention.py), not this kernel.
 
-Layout: inputs (B, H, S, D) are flattened to (B*H, S, D); the grid is
-(B*H, Sq_blocks); each program owns one (block_q, D) query tile and loops
-KV chunks of ``block_k`` with the standard online-softmax carry
-(running max m, denominator l, accumulator acc — all f32 in registers/VMEM).
+Layout: ``q: (B, Hq, S, Dk)`` is read as ``(B * Hkv, G, S, Dk)``, ``G = Hq /
+Hkv`` query heads a key head (1 without grouping); the grid is (key head,
+query tile); a program owns the ``(G, block_q, Dk)`` tile of the same
+positions of a group's heads, stacked into one ``(G * block_q, Dk)`` left
+operand, and loops over its key head's blocks of ``block_k`` keys (the whole
+``(S, Dk)`` keys and ``(S, Dv)`` values of the head are resident in VMEM
+over its query tiles, so HBM hands each over once) with the standard
+online-softmax carry (running max m, denominator l, accumulator acc — all
+f32). Both products accumulate in float32, the exponentials are float32, the
+weights go to the value product in the values' type unnormalised and the
+result is divided by their float32 sum: what ``causal_blocked`` does.
 
-Shapes are padded: D to the 128-lane tile, S to block multiples; padded key
-positions are masked with a large negative before the softmax, padded query
-rows are sliced off on return. Masking uses -1e30 (not -inf: a fully-masked
-chunk would produce exp(-inf - -inf) = NaN in the carry).
+``causal`` (static) bounds that loop at the tile's diagonal: key blocks
+wholly before the tile's first query run without a mask, the blocks that
+hold the diagonal with one, blocks after the tile's last query are never
+loaded nor multiplied. A tile no wider than a key block (and dividing it)
+lies in one block, so its diagonal is one step after the loop and not a
+second loop: 1.2 ms a step of either language cell less (the compiler copies
+the carry from one loop to the next through VMEM; PERF.md §6, PR 42). ``Dv``
+may differ from ``Dk``.
+
+Shapes are padded: S to block multiples, a width to whole lane tiles unless
+it is whole tiles and a half (``lane_width``: Kimi-Linear's 192-wide keys are
+read as they lie, the block laid out as two lane tiles in VMEM by Mosaic,
+which masks the half tile inside the products; no copy of q or k pads them
+in HBM, inside a row's call or before it); padded key positions are masked
+with a large negative before the softmax (causal: they lie after every real
+query), padded query rows are sliced off on return. Masking uses -1e30 (not
+-inf: a fully-masked chunk would produce exp(-inf - -inf) = NaN in the
+carry).
 
 CPU/tests: ``interpret=True`` runs the same kernel under the Pallas
 interpreter — cross-checked against the jnp reference in tests/test_ops.py.
@@ -32,31 +57,79 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 _NEG = -1e30
+# What a grid step may hold (the v5e has 128 MiB): a head's keys and values
+# twice over (the pipeline's two buffers) and the float32 score tile's
+# temporaries.
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, s_valid, block_k):
-    q = q_ref[0]  # (BQ, Dp)
-    bq = q.shape[0]
-    sp = k_ref.shape[1]
-    nk = sp // block_k
+def lane_width(d: int) -> int:
+    """The width the kernel reads a head of ``d`` channels at: ``d`` itself
+    where it is whole lane tiles, or whole tiles and a half beyond the first
+    (192 = 128 + 64: Mosaic lays such a block out in whole tiles in VMEM and
+    masks the half tile in the products itself, so no copy of the operand is
+    made in HBM to pad it); the next whole tile for any other width."""
+    if d >= _LANE and d % (_LANE // 2) == 0:
+        return d
+    return -(-d // _LANE) * _LANE
 
-    m0 = jnp.full((bq, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, q.shape[1]), jnp.float32)
 
-    def body(i, carry):
+def causal_tiles(group: int) -> tuple:
+    """``(block_q, block_k)`` of the causal form for ``group`` query heads a
+    key head: the positions of a query tile (its ``group`` heads are stacked
+    into ``group * block_q`` rows of one left operand) and the keys of a
+    block."""
+    return max(512 // group, 16), 512
+
+
+def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
+                 block_k, causal):
+    """One tile of queries against its key head's keys, block by block.
+
+    ``q_ref: (1, G, BQ, Dk)`` holds the same ``BQ`` positions of the ``G``
+    query heads that read this key head (``G = 1`` without grouping); they are
+    stacked into one ``(G * BQ, Dk)`` left operand, so a key block is met by
+    the whole group at once. ``k_ref: (1, Sk, Dk)`` and ``v_ref: (1, Sk, Dv)``
+    are the key head's whole sequence, resident in VMEM over the head's
+    query tiles."""
+    del at_ref  # read by the block specs
+    g, bq, dk = q_ref.shape[1:]
+    rows = g * bq
+    q = q_ref[0].reshape(rows, dk)
+    first = pl.program_id(1) * bq  # the tile's first position
+    if causal:
+        # blocks wholly at or before the tile's first query need no mask;
+        # blocks that begin after its last query are never loaded
+        # (nor one past the padded keys, where the queries are padded
+        # further than the keys: those rows are padding themselves)
+        clear = first // block_k
+        end = jnp.minimum(pl.cdiv(first + bq, block_k),
+                          k_ref.shape[1] // block_k)
+    else:
+        clear, end = s_valid // block_k, k_ref.shape[1] // block_k
+
+    def step(masked, i, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(i * block_k, block_k), :]  # (BK, Dp)
-        v = v_ref[0, pl.ds(i * block_k, block_k), :]
-        # (BQ, BK) scores, f32 accumulation on the MXU.
+        at = pl.multiple_of(i * block_k, block_k)
+        k = k_ref[0, pl.ds(at, block_k), :]  # (BK, Dk)
+        v = v_ref[0, pl.ds(at, block_k), :]  # (BK, Dv)
+        # (G * BQ, BK) scores, f32 accumulation on the MXU.
         s = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        idx = lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * block_k
-        s = jnp.where(idx < s_valid, s, _NEG)
+        if masked:
+            key = at + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
+            if causal:  # padded keys lie after every real query
+                seen = key <= first + lax.broadcasted_iota(
+                    jnp.int32, (bq, block_k), 0)
+            else:
+                seen = key < s_valid
+            s = jnp.where(seen, s.reshape(g, bq, block_k), _NEG).reshape(
+                rows, block_k)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -67,8 +140,17 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, s_valid, block_k):
         )
         return m_new, l_new, acc_new
 
-    m, l, acc = lax.fori_loop(0, nk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    carry = (jnp.full((rows, 1), _NEG, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, v_ref.shape[2]), jnp.float32))
+    carry = lax.fori_loop(0, clear, functools.partial(step, False), carry)
+    if causal and block_k % bq == 0:
+        # a tile no wider than a key block lies in one block: the diagonal's
+        _, l, acc = step(True, clear, carry)
+    else:
+        _, l, acc = lax.fori_loop(clear, end, functools.partial(step, True),
+                                  carry)
+    o_ref[0] = (acc / l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -81,7 +163,17 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret"))
+def _rows_read(row, b: int, hkv: int) -> tuple:
+    """How many rows of the batch a call computes, and the index of their
+    first key head in the ``(B * Hkv, ...)`` arrays (the scalar the block
+    specs are prefetched): all ``b`` from 0, or the one row ``row``."""
+    if row is None:
+        return b, jnp.zeros((1,), jnp.int32)
+    return 1, jnp.asarray(row, jnp.int32).reshape(1) * hkv
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block_q", "block_k", "interpret", "causal"))
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -90,46 +182,69 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 2048,
     interpret: bool = False,
+    causal: bool = False,
+    row=None,
 ) -> jnp.ndarray:
-    """softmax(q k^T * scale) v for (B, H, S, D) inputs, fused on TPU.
+    """softmax(q k^T * scale) v, fused on TPU, for ``q: (B, Hq, S, Dk)``,
+    ``k: (B, Hkv, S, Dk)`` and ``v: (B, Hkv, S, Dv)``; with ``causal`` a query
+    reads the keys at and before its own position. With ``row`` (an index,
+    traced or not) only that row of the batch is computed, ``(1, Hq, S,
+    Dv)``, read where it lies in the whole arrays: a loop over rows cuts
+    nothing out of them.
 
-    Block defaults are the measured-fastest on v5e (BENCH_NOTES.md round
-    2 block sweep: bq=512/bk=2048 runs S=2048 in 0.52 ms vs 0.91 ms with
-    the round-1 128/512 tiles — 3.25x XLA's fused attention); both clamp
-    to the padded sequence so direct short-shape callers (tests, sweeps,
-    future kernels built on this one) never pad q 8x just to fill a tile.
-    (The serving dispatch, ops/attention.py, only routes here at
-    S >= _flash_min_seq; ring attention uses its own per-shard math.)"""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+    Block defaults are the measured-fastest on v5e for the non-causal form
+    (BENCH_NOTES.md round 2 block sweep: bq=512/bk=2048 runs S=2048 in
+    0.52 ms vs 0.91 ms with the round-1 128/512 tiles — 3.25x XLA's fused
+    attention); both clamp to the padded sequence so direct short-shape
+    callers (tests, sweeps) never pad q 8x just to fill a tile. The causal
+    form's come from :func:`causal_tiles`."""
+    b, hq, sq, dk = q.shape
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[3]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} key heads")
+    if causal and sq != sk:
+        raise ValueError(f"causal over {sq} queries and {sk} keys")
+    g = hq // hkv
     if scale is None:
-        scale = d**-0.5
+        scale = dk**-0.5
 
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
+    qf = q.reshape(b * hkv, g, sq, dk)
+    kf = k.reshape(b * hkv, sk, dk)
+    vf = v.reshape(b * hkv, sk, dv)
 
-    # Tile padding: D -> lane width; Sq -> block_q; Sk -> block_k, with
-    # both block sizes clamped to the (pow2-padded) sequence lengths.
+    # Tile padding: widths -> whole lane tiles; Sq -> block_q; Sk -> block_k,
+    # with both block sizes clamped to the (pow2-padded) sequence lengths.
     block_q = min(block_q, max(_LANE, 1 << (sq - 1).bit_length()))
-    qf = _pad_to(_pad_to(qf, 2, _LANE), 1, block_q)
+    qf = _pad_to(_pad_to(qf, 3, lane_width(dk)), 2, block_q)
     bk = min(block_k, max(_LANE, 1 << (sk - 1).bit_length()))
-    kf = _pad_to(_pad_to(kf, 2, _LANE), 1, bk)
-    vf = _pad_to(_pad_to(vf, 2, _LANE), 1, bk)
-    sq_p, d_p = qf.shape[1], qf.shape[2]
-    sk_p = kf.shape[1]
+    kf = _pad_to(_pad_to(kf, 2, lane_width(dk)), 1, bk)
+    vf = _pad_to(_pad_to(vf, 2, lane_width(dv)), 1, bk)
+    sq_p, dk_p = qf.shape[2], qf.shape[3]
+    sk_p, dv_p = kf.shape[1], vf.shape[2]
 
-    grid = (b * h, sq_p // block_q)
+    # the grid walks (key head, query tile) of the rows computed; ``at`` is
+    # the first key head of those rows in the whole arrays
+    rows, at = _rows_read(row, b, hkv)
     out = pl.pallas_call(
-        functools.partial(_attn_kernel, scale=scale, s_valid=sk, block_k=bk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d_p), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, sk_p, d_p), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, sk_p, d_p), lambda bh, qi: (bh, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d_p), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
+        functools.partial(_attn_kernel, scale=scale, s_valid=sk, block_k=bk,
+                          causal=causal),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows * hkv, sq_p // block_q),
+            in_specs=[
+                pl.BlockSpec((1, g, block_q, dk_p),
+                             lambda h, qi, at: (at[0] + h, 0, qi, 0)),
+                pl.BlockSpec((1, sk_p, dk_p),
+                             lambda h, qi, at: (at[0] + h, 0, 0)),
+                pl.BlockSpec((1, sk_p, dv_p),
+                             lambda h, qi, at: (at[0] + h, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g, block_q, dv_p),
+                                   lambda h, qi, at: (h, 0, qi, 0))),
+        out_shape=jax.ShapeDtypeStruct((rows * hkv, g, sq_p, dv_p), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(qf, kf, vf)
-    return out[:, :sq, :d].reshape(b, h, sq, d)
+    )(at, qf, kf, vf)
+    return out[:, :, :sq, :dv].reshape(rows, hq, sq, dv)

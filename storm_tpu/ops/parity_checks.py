@@ -241,8 +241,53 @@ def check_kda_tables(interpret: bool = False) -> List[dict]:
     return rows
 
 
+def check_causal_attention(interpret: bool = False) -> List[dict]:
+    """The compiled causal kernel, one row of a batch of two read where it
+    lies, vs the blocked form of ``ops/attention.py causal_attention`` on the
+    same operands.
+
+    Cases: the two language cells' own shapes in the serving dtype (Kimi's
+    latent attention, 32 heads of 4,096 tokens, 192-wide keys against
+    128-wide values: the width Mosaic must lay out as a tile and a half;
+    Nemotron's 32 query heads over 2 key heads of 128), each with the tiles
+    ``causal_form`` gives its program, and a small float32 one whose padded
+    tail (300 tokens into 512) and narrow widths go through the wrapper's
+    padding. Under the interpreter the cells' sequences are cut to 1,024
+    tokens (minutes otherwise); the tiles stay."""
+    import jax
+    import jax.numpy as jnp
+
+    from storm_tpu.ops import attention
+    from storm_tpu.ops.flash_attention import causal_tiles, flash_attention
+
+    window = 1024 if interpret else 4096
+    rows = []
+    blocked = jax.jit(lambda q, k, v: attention.causal_blocked(
+        q, k, v, q.shape[-1] ** -0.5))
+    cases = [
+        ("kimi_H32_D192_128", 32, 32, window, 192, 128, jnp.bfloat16),
+        ("nemotron_H32over2_D128", 32, 2, window, 128, 128, jnp.bfloat16),
+        ("toy_H4over2_S300_D24_16", 4, 2, 300, 24, 16, jnp.float32),
+    ]
+    for case, hq, hkv, s, dk, dv, dt in cases:
+        q, k, v = (
+            jax.random.normal(jax.random.PRNGKey(i), (2, h, s, d),
+                              jnp.float32).astype(dt)
+            for i, (h, d) in enumerate(((hq, dk), (hkv, dk), (hkv, dv))))
+        block_q, block_k = causal_tiles(hq // hkv)
+        got = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                              causal=True, row=1, interpret=interpret)
+        want = blocked(q[1:], k[1:], v[1:])
+        rel_tol = 1e-2 if dt == jnp.bfloat16 else 5e-3
+        rows.append(_row("causal_attention", case, np.dtype(dt).name,
+                         np.asarray(got, np.float32),
+                         np.asarray(want, np.float32), rel_tol=rel_tol))
+    return rows
+
+
 def run_all(interpret: bool = False) -> List[dict]:
     return (check_flash_attention(interpret)
             + check_short_attention(interpret)
             + check_w8a16(interpret)
-            + check_kda_tables(interpret))
+            + check_kda_tables(interpret)
+            + check_causal_attention(interpret))

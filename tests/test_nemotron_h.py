@@ -417,6 +417,40 @@ def test_the_inventory_names_the_three_forms():
                           "causal_attention=blocked-grouped"}
 
 
+def test_the_inventory_says_which_causal_form_a_program_was_built_with(
+        monkeypatch):
+    """With the rule answering ``kernel`` (as on one chip for whole tiles;
+    here the kernel runs under the interpreter, its tiles cut to the tiny
+    model's) the note reaches ``engine_inventory()["programs"]``, and the
+    program's predictions are the blocked program's."""
+    import functools
+
+    from storm_tpu.config import ShardingConfig
+    from storm_tpu.infer.engine import engine_inventory, shared_engine
+    from storm_tpu.ops import attention, flash_attention as fa
+
+    def engine(staging_pool):  # part of the cache's key: two engines
+        eng = shared_engine(ModelConfig(
+            name="nemotron_h_tiny", dtype="float32", num_classes=96,
+            input_shape=(44,), seed=5), ShardingConfig(data_parallel=0),
+            BatchConfig(staging_pool=staging_pool))
+        eng.warmup()
+        return eng
+
+    x = np.random.RandomState(0).randint(0, 96, (8, 44)).astype(np.float32)
+    want = engine(3).predict(x)
+    monkeypatch.setattr(attention, "causal_form", lambda *a: "kernel")
+    monkeypatch.setattr(fa, "causal_tiles", lambda group: (16, 128))
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    eng = engine(4)
+    row = next(r for r in engine_inventory()["engines"]
+               if "causal_attention=kernel" in str(r["programs"]))
+    forms = row["programs"][str(eng.pad_batch(8))].split(", ")
+    assert "causal_attention=kernel-grouped" in forms
+    np.testing.assert_allclose(eng.predict(x), want, atol=1e-5)
+
+
 def test_device_counters_ride_the_result_into_the_registry():
     """Two expert layers, four held experts of eight, top-2: a step of 8
     windows of 44 tokens makes 704 assignments a layer."""

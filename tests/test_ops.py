@@ -84,6 +84,137 @@ def test_flash_attention_matches_reference_interpret():
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3)
 
 
+def test_flash_attention_without_causal_gives_what_it_gave():
+    """The non-causal form through the shared kernel: 64-wide heads padded to
+    a lane tile, 150 keys padded into two blocks of 128 (the first needs no
+    mask, the second hides its tail), two query tiles."""
+    from storm_tpu.ops.flash_attention import flash_attention
+
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (2, 2, 150, 64))
+               for i in range(3))
+    got = flash_attention(q, k, v, interpret=True, block_q=128, block_k=128)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(got, attention_reference(q, k, v), atol=2e-6)
+
+
+def _plain_causal(q, k, v, scale):
+    """The whole masked float32 softmax, each key head written out for the
+    query heads that read it."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
+    scores = jnp.einsum("bhsd,bhtd->bhst", q, k) * scale
+    seen = jnp.tril(jnp.ones((q.shape[2],) * 2, bool))
+    return jnp.einsum("bhst,bhtd->bhsd", jax.nn.softmax(
+        jnp.where(seen, scores, -jnp.inf), -1), v)
+
+
+@pytest.mark.parametrize("hq,hkv,s,dk,dv,block_q,block_k,dtype,atol", [
+    # latent attention's widths: keys a lane tile and a half, values one
+    (4, 4, 384, 192, 128, 128, 128, jnp.float32, 5e-6),
+    # grouped: 4 query heads over 2 key heads, stacked 2 x 64 rows a tile;
+    # three key blocks, so tiles 2-5 skip blocks and tile 0 meets one only
+    (4, 2, 384, 128, 128, 64, 128, jnp.float32, 5e-6),
+    (4, 4, 384, 128, 128, 64, 128, jnp.float32, 5e-6),
+    # a query tile wider than a key block: two masked blocks on the diagonal
+    (2, 2, 512, 128, 128, 256, 128, jnp.float32, 5e-6),
+    # key blocks wider than a tile: the diagonal block is part of a wide one
+    (4, 1, 256, 128, 128, 16, 128, jnp.float32, 5e-6),
+    # 300 tokens padded into 512 and 24/16-wide heads padded to a lane tile
+    (4, 2, 300, 24, 16, 64, 128, jnp.float32, 5e-6),
+    # the queries padded further (512) than the keys (384): the last tile's
+    # loop ends with the keys
+    (2, 1, 300, 128, 128, 256, 128, jnp.float32, 5e-6),
+    # the serving type: weights go to the value product in bf16
+    (4, 2, 256, 192, 128, 32, 128, jnp.bfloat16, 2e-2),
+])
+def test_causal_kernel_against_the_plain_softmax_and_the_blocked_form(
+        hq, hkv, s, dk, dv, block_q, block_k, dtype, atol):
+    """The causal form of ops/flash_attention.py under the interpreter: the
+    mathematics (Mosaic's lowering is ops/parity_checks.py's, on the chip)."""
+    from storm_tpu.ops.attention import causal_blocked
+    from storm_tpu.ops.flash_attention import flash_attention
+
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (2, h, s, d)
+                                 ).astype(dtype)
+               for i, (h, d) in enumerate(((hq, dk), (hkv, dk), (hkv, dv))))
+    scale = dk ** -0.5
+    got = flash_attention(q, k, v, scale=scale, block_q=block_q,
+                          block_k=block_k, causal=True, interpret=True)
+    assert got.shape == (2, hq, s, dv) and got.dtype == dtype
+    f32 = [y.astype(jnp.float32) for y in (q, k, v)]
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               _plain_causal(*f32, scale), atol=atol)
+    np.testing.assert_allclose(
+        got.astype(jnp.float32),
+        causal_blocked(q, k, v, scale, block=64).astype(jnp.float32),
+        atol=atol)
+    # one row of the batch, read where it lies
+    one = flash_attention(q, k, v, scale=scale, block_q=block_q,
+                          block_k=block_k, causal=True, interpret=True, row=1)
+    np.testing.assert_array_equal(np.asarray(one, np.float32),
+                                  np.asarray(got[1:], np.float32))
+
+
+def test_a_key_in_the_padded_tail_never_receives_weight():
+    """Every real score is far below zero, a padded key's is zero: had one
+    leaked into a softmax it would take all the weight and the values' mean
+    would fall to the padding's zero."""
+    from storm_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.full((1, 2, 200, 128), 2.0)
+    k, v = -q, jnp.ones((1, 2, 200, 128))
+    for causal in (True, False):
+        got = flash_attention(q, k, v, block_q=128, block_k=128,
+                              causal=causal, interpret=True)
+        np.testing.assert_allclose(got, v, atol=1e-6)
+
+
+@pytest.mark.parametrize("hq,hkv,s,dk,dv,on_tpu,devices,want", [
+    (32, 32, 4096, 192, 128, True, 1, "kernel"),   # Kimi-Linear's cell
+    (32, 2, 4096, 128, 128, True, 1, "kernel"),    # Nemotron's cell
+    (32, 32, 4096, 192, 128, False, 1, "blocked"),  # off the TPU
+    # a host with several chips: the kernel has no partitioning rule
+    (32, 2, 4096, 128, 128, True, 4, "blocked"),
+    (32, 2, 4000, 128, 128, True, 1, "blocked"),   # no whole number of tiles
+    (4, 2, 1024, 24, 16, True, 1, "blocked"),      # widths it would pad
+    (4, 2, 1024, 128, 64, True, 1, "blocked"),
+])
+def test_causal_form_is_a_function_of_the_traced_shapes_and_the_devices(
+        hq, hkv, s, dk, dv, on_tpu, devices, want, monkeypatch):
+    from storm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: on_tpu)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    assert attention.causal_form(hq, hkv, s, dk, dv) == want
+
+
+@pytest.mark.parametrize("hq,hkv,want", [(4, 4, "kernel"),
+                                         (4, 2, "kernel-grouped")])
+def test_causal_attention_through_the_kernel_is_the_blocked_forms(
+        hq, hkv, want, monkeypatch):
+    """``causal_attention`` built with the kernel (the rule answered here, in
+    the test, as a chip would for whole tiles) against the blocked form it is
+    built with on the CPU, and what it notes either way."""
+    import functools
+
+    from storm_tpu.ops import attention, flash_attention as fa
+    from storm_tpu.ops.platform import dispatch_notes
+
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (3, h, 256, 128))
+               for i, h in enumerate((hq, hkv, hkv)))
+    with dispatch_notes() as seen:
+        blocked = attention.causal_attention(q, k, v, block=64)
+    assert seen == ["causal_attention=" + want.replace("kernel", "blocked")]
+    monkeypatch.setattr(attention, "causal_form", lambda *a: "kernel")
+    monkeypatch.setattr(fa, "causal_tiles", lambda group: (64 // group, 128))
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    with dispatch_notes() as seen:
+        got = jax.jit(attention.causal_attention)(q, k, v)
+    assert seen == ["causal_attention=" + want]
+    np.testing.assert_allclose(got, blocked, atol=2e-6)
+
+
 def test_w8a16_matmul_matches_dequant_reference():
     """Pallas fused dequant-matmul (interpreter on CPU) vs explicit
     dequantize-then-dot, over shapes that exercise M/N/K padding and

@@ -50,7 +50,15 @@ def _instances(engine, n, rows=1):
 def test_one_row_a_step_with_its_moments_in_order(name):
     engine, batch = _engine(name)
     store = profile.profile_store()
-    warm = len(store.steps())  # the warm-up's step: a direct predict
+    # the warm-up's step, a direct predict: its row is appended on the
+    # engine's fetch thread *after* the result that ``warmup`` waited for is
+    # handed over, so on a busy host it may not be there yet (it then read
+    # as a fourth of the queue's three batches)
+    deadline = time.time() + 10
+    while not store.steps():
+        assert time.time() < deadline
+        time.sleep(0.01)
+    warm = len(store.steps())
     queue = continuous_for(engine, batch)
     subs = []
     for i, x in enumerate(_instances(engine, 24)):

@@ -69,6 +69,37 @@ def test_flash_attention_compiles_for_v5e(v5e, shape):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("hq,hkv,dk,dv,carried", [
+    (32, 32, 192, 128, "bf16[8,32,4096,192]"),  # Kimi-Linear's cell
+    (32, 2, 128, 128, "bf16[8,2,4096,128]"),    # Nemotron's cell
+])
+def test_causal_attention_compiles_as_a_loop_of_kernel_calls(
+        v5e, monkeypatch, hq, hkv, dk, dv, carried):
+    """The language cells' attention at their bucket of 8 windows of 4,096,
+    as one chip builds it: the kernel is in the program (192-wide keys read
+    as they lie), the rows are one ``while`` that still carries q, k and v in
+    the shapes the benchmark's ``mla_attention_ms`` / ``gqa_attention_ms``
+    find it by, nothing is cut out of them inside it, and no block of
+    float32 scores is left in HBM."""
+    import re
+
+    import storm_tpu.ops.attention as attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "_one_device", lambda: True)
+    assert attention.causal_form(hq, hkv, 4096, dk, dv) == "kernel"
+    q, k, v = (_spec((8, 4096, h, d), jnp.bfloat16, v5e)
+               for h, d in ((hq, dk), (hkv, dk), (hkv, dv)))
+    text = jax.jit(lambda q, k, v: attention.causal_attention(
+        *(y.transpose(0, 2, 1, 3) for y in (q, k, v)), scale=dk ** -0.5
+    ).transpose(0, 2, 1, 3)).lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" in text
+    (loop,) = [line for line in text.splitlines() if " while(" in line]
+    assert loop.count(carried) >= 2
+    assert not re.search(r"bf16\[[\d,]+\]\S* dynamic-slice\(", text)
+    assert "f32[32,512," not in text and "f32[2,16,512," not in text
+
+
 @pytest.mark.parametrize("shape,heads", [
     ((256, 257, 1408), 16),   # ViT-g/14, the benchmark's largest bucket
     ((128, 197, 768), 12),    # ViT-B/16 at batch 128

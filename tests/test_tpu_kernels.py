@@ -53,3 +53,12 @@ def test_kda_tables_compiled_parity():
     rows = check_kda_tables(interpret=False)
     bad = [r for r in rows if not r["pass"]]
     assert not bad, f"compiled kda_tables parity failures: {bad}"
+
+
+@pytest.mark.slow
+def test_causal_attention_compiled_parity():
+    from storm_tpu.ops.parity_checks import check_causal_attention
+
+    rows = check_causal_attention(interpret=False)
+    bad = [r for r in rows if not r["pass"]]
+    assert not bad, f"compiled causal_attention parity failures: {bad}"
