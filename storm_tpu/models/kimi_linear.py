@@ -42,6 +42,7 @@ from storm_tpu.ops import kda
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import causal_attention
+from storm_tpu.ops.rope import rotate_halves
 from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
 
 
@@ -112,33 +113,58 @@ def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
 
 
 def mla_mixer_init(rng, dim: int, heads: int, nope: int, rope: int,
-                   v_dim: int, kv_rank: int) -> dict:
-    ks = jax.random.split(rng, 4)
-    return {
-        "q": _w(ks[0], dim, heads * (nope + rope)),
+                   v_dim: int, kv_rank: int, q_rank=None) -> dict:
+    """``q_rank`` None: one full query projection ``q``; a number: the
+    low-rank pair ``q_a`` (to ``q_rank``), an RMS norm, ``q_b``."""
+    ks = jax.random.split(rng, 4)  # as ever: the full-rank draw is unmoved
+    p = {
         "kv_a": _w(ks[1], dim, kv_rank + rope),
         "kv_norm": L.rmsnorm_init(kv_rank),
         "kv_b": _w(ks[2], kv_rank, heads * (nope + v_dim)),
         "o": _w(ks[3], heads * v_dim, dim),
     }
+    if q_rank is None:
+        p["q"] = _w(ks[0], dim, heads * (nope + rope))
+    else:
+        ka, kb = jax.random.split(ks[0])
+        p["q_a"] = _w(ka, dim, q_rank)
+        p["q_norm"] = L.rmsnorm_init(q_rank)
+        p["q_b"] = _w(kb, q_rank, heads * (nope + rope))
+    return p
 
 
 def mla_mixer(p: dict, x: jnp.ndarray, heads: int, nope: int, rope: int,
-              v_dim: int, kv_rank: int, eps: float) -> jnp.ndarray:
-    """Latent attention without rotary: the ``rope`` channels are plain
-    query/key channels, the key's shared by every head."""
+              v_dim: int, kv_rank: int, eps: float, rotary=None,
+              scale=None) -> jnp.ndarray:
+    """Latent attention. The queries are one projection (``q``) or low-rank
+    (``q_a``, an RMS norm, ``q_b``), as the parameters say. ``rotary`` None:
+    the ``rope`` channels are plain query/key channels, the key's shared by
+    every head. ``rotary = (cos, sin)`` (ops/rope.py ``rotary_tables``):
+    each query head's ``rope`` channels and the one shared key's are turned
+    by position, as halves: the loader has brought the checkpoint's
+    interleaved pairs there on the weights' columns (``halves_first``).
+    ``scale``: the softmax's (None: ``(nope + rope) ** -0.5``)."""
     b, s, _ = x.shape
-    q = _proj(x, p["q"]).reshape(b, s, heads, nope + rope)
+    if "q_a" in p:
+        q = _proj(L.rmsnorm(p["q_norm"], _proj(x, p["q_a"]), eps), p["q_b"])
+    else:
+        q = _proj(x, p["q"])
+    q = q.reshape(b, s, heads, nope + rope)
     kv_a = _proj(x, p["kv_a"])
     latent = L.rmsnorm(p["kv_norm"], kv_a[..., :kv_rank], eps)
     k_shared = kv_a[..., kv_rank:]  # (B, S, rope)
+    if rotary is not None:
+        cos, sin = rotary  # (S, rope / 2)
+        q = rotate_halves(q, cos[:, None], sin[:, None], first=nope)
+        k_shared = rotate_halves(k_shared, cos, sin)
     kv = _proj(latent, p["kv_b"]).reshape(b, s, heads, nope + v_dim)
     k = jnp.concatenate(
         [kv[..., :nope],
          jnp.broadcast_to(k_shared[:, :, None, :], (b, s, heads, rope))], -1)
     out = causal_attention(*(y.transpose(0, 2, 1, 3)
                              for y in (q, k, kv[..., nope:])),
-                           scale=(nope + rope) ** -0.5)
+                           scale=(nope + rope) ** -0.5 if scale is None
+                           else scale)
     return _proj(out.transpose(0, 2, 1, 3).reshape(b, s, heads * v_dim),
                  p["o"])
 

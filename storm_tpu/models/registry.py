@@ -69,6 +69,7 @@ def _load_builtin() -> None:
     # Import model modules lazily so registration happens on demand.
     from storm_tpu.models import (  # noqa: F401
         chartiny,
+        kimi_k2,
         kimi_linear,
         lenet,
         longseq,

@@ -22,12 +22,13 @@ MIX_KDA_TABLES = "mix.kda_tables"  # ops/kda.py: the within-chunk tables
 MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
 MIX_SSD_SCAN = "mix.ssd_scan"  # ops/ssd.py: the loop over chunks
 MIX_ATTENTION = "mix.attention"  # causal_attention's loop, ViT's attention
+MIX_ROPE = "mix.rope"  # ops/rope.py: the position tables and the turn
 MOE_ROUTE = "moe.route"  # router, top-k, sorts, counts, starts, zeroed buffer
 MOE_EXPERTS = "moe.experts"  # topk_moe_layer's loop over tiles
 MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
 
 VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
-              MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MOE_ROUTE,
+              MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
               MOE_EXPERTS, MOE_COMBINE)
 
 

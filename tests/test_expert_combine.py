@@ -129,9 +129,10 @@ def _assignments(seed, n, top_k, rows, share):
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("n,top_k,share", [
     (100, 2, 1.0), (100, 6, 1.0), (64, 8, 1.0), (100, 6, 0.25),
-    (37, 8, 0.125), (16, 6, 0.5), (100, 2, 0.0), (7, 6, 0.3)],
+    (37, 8, 0.125), (16, 6, 0.5), (100, 2, 0.0), (7, 6, 0.3),
+    (100, 8, 1 / 32)],
     ids=["top2-all", "top6-all", "top8-all", "top6-quarter", "top8-eighth",
-         "one-block", "none", "under-a-block"])
+         "one-block", "none", "under-a-block", "top8-a-thirty-second"])
 def test_the_loop_on_any_share_held(dtype, n, top_k, share):
     """The loop alone, whatever the share held and in either type of row:
     the 0/1 product is exact, so bfloat16 rows sum as their float32 values."""

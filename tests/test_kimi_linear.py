@@ -263,8 +263,8 @@ def _plain_experts(p, x, top_k, first, scale):
     return y.reshape(x.shape)
 
 
-def _moe(seed=0, skew=None):
-    p = topk_moe_init(jax.random.PRNGKey(seed), 32, 48, 8)
+def _moe(seed=0, skew=None, experts=8):
+    p = topk_moe_init(jax.random.PRNGKey(seed), 32, 48, experts)
     if skew is not None:  # the selection bias sends every token to one expert
         p["router_bias"] = p["router_bias"].at[skew].set(10.0)
     return p, jax.random.normal(jax.random.PRNGKey(seed + 1), (3, 37, 32))
@@ -286,24 +286,30 @@ def test_expert_layer_drops_no_token(skew):
         assert int(tokens[skew]) == 111
 
 
-def test_the_shares_add_up_to_the_whole_layer():
-    """Four chips hold two experts each. The parts they compute, with the
-    shared expert counted once, are the uncut layer; the assignments each
-    sees as absent are those the others hold."""
-    p, x = _moe(skew=3)
+@pytest.mark.parametrize("experts,top_k", [(8, 2), (64, 8)],
+                         ids=["a-quarter-held", "a-thirty-second-held"])
+def test_the_shares_add_up_to_the_whole_layer(experts, top_k):
+    """Four chips hold two experts each of eight, or 32 chips two each of 64
+    (Kimi K2's share: a thirty-second of the router's width, where most
+    tiles of the grouped product and of the combine are nearly empty). The
+    parts they compute, with the shared expert counted once, are the uncut
+    layer; the assignments each sees as absent are those the others hold."""
+    p, x = _moe(skew=3, experts=experts)
+    share_of = jax.jit(lambda share, first: topk_moe_layer(
+        share, x, top_k, first_expert=first, scale=2.446, tile=16))
     with jax.default_matmul_precision("highest"):
-        whole = _plain_experts(p, x, 2, 0, 2.446) + L.swiglu(p["shared"], x)
+        whole = _plain_experts(p, x, top_k, 0, 2.446) \
+            + L.swiglu(p["shared"], x)
         total, seen = jnp.zeros_like(x), 0
-        for first in range(0, 8, 2):
+        for first in range(0, experts, 2):
             share = {"router": p["router"], "router_bias": p["router_bias"],
                      "experts": {n: w[first:first + 2]
                                  for n, w in p["experts"].items()}}
-            y, tokens, absent = topk_moe_layer(
-                share, x, 2, first_expert=first, scale=2.446, tile=16)
-            assert int(tokens.sum()) + int(absent) == 2 * 111
+            y, tokens, absent = share_of(share, first)
+            assert int(tokens.sum()) + int(absent) == top_k * 111
             total, seen = total + y, seen + int(tokens.sum())
         total = total + L.swiglu(p["shared"], x)
-    assert seen == 2 * 111
+    assert seen == top_k * 111
     np.testing.assert_allclose(total, whole, atol=1e-5)
 
 
@@ -387,20 +393,38 @@ def test_ids_reach_the_model_unrounded():
     assert np.abs(a - b).max() > 1e-4
 
 
-def test_buckets_are_clipped_to_the_models_bound_and_only_those_warm():
-    eng = _engine("float32")
-    assert eng.model.max_rows == 8 and eng.max_rows == 8
-    assert eng.batch_cfg.buckets == (8,) and eng.batch_cfg.max_batch == 8
+@pytest.mark.parametrize("name,rows,clipped_to", [
+    ("kimi_linear_tiny", 8, (2, 4, 8)), ("kimi_k2_tiny", 4, (2, 4))])
+def test_buckets_are_clipped_to_the_models_bound_and_only_those_warm(
+        name, rows, clipped_to):
+    """A bound of 8 keeps ``BatchConfig()``'s first bucket; a bound of 4 is
+    under all of them and leaves the one bucket ``(4,)``."""
+    eng = InferenceEngine(ModelConfig(
+        name=name, dtype="float32", num_classes=96, input_shape=(40,),
+        seed=5), batch_cfg=BatchConfig())
+    assert eng.model.max_rows == rows and eng.max_rows == rows
+    assert eng.batch_cfg.buckets == (rows,)
+    assert eng.batch_cfg.max_batch == rows
     eng.warmup()
-    assert eng.compiled_batches == {8}
+    assert eng.compiled_batches == {rows}
     policy = BatchConfig(max_batch=16, buckets=(2, 4, 16))
-    clipped = policy.clipped(8)
-    assert clipped.buckets == (2, 4, 8) and clipped.max_batch == 8
+    clipped = policy.clipped(rows)
+    assert clipped.buckets == clipped_to and clipped.max_batch == rows
     assert clipped.max_wait_ms == policy.max_wait_ms
-    # the queue that forms the batches is held to the same bound
+    # the queue that forms the batches is held to the same bound: a backlog
+    # of one bucket and a half fills a step and is cut at the bound
     from storm_tpu.infer.continuous import continuous_for
 
-    assert continuous_for(eng, BatchConfig()).cfg.max_batch == 8
+    queue = continuous_for(eng, BatchConfig())
+    assert queue.cfg.max_batch == rows and queue.cfg.buckets == (rows,)
+    backlog = _windows(rows + rows // 2)
+    subs = [queue.submit(backlog[i:i + 1]) for i in range(len(backlog))]
+    got = np.concatenate([sub.future.result(60) for sub in subs])
+    assert got.shape == (len(backlog), 96)
+    np.testing.assert_allclose(got[:rows], eng.predict(backlog[:rows]),
+                               atol=1e-6)
+    assert queue.rows_dispatched == len(backlog) and queue.batches >= 2
+    assert eng.compiled_batches == {rows}
 
 
 def test_the_inventory_names_the_form_of_the_tables():

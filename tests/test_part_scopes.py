@@ -21,6 +21,7 @@ EXPECTED = {
     "kimi_linear_tiny": COMMON | MOE | {parts.MIX_KDA_TABLES,
                                         parts.MIX_KDA_SCAN},
     "nemotron_h_tiny": COMMON | MOE | {parts.MIX_SSD_SCAN},
+    "kimi_k2_tiny": COMMON | MOE | {parts.MIX_ROPE},
 }
 
 
@@ -84,7 +85,7 @@ def test_the_innermost_name_is_the_operations():
         "jit(fwd)/mix.elementwise/mix.attention/while/body/exp") == \
         parts.MIX_ATTENTION
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 12
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 13
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():
