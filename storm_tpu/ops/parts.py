@@ -23,7 +23,8 @@ MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
 MIX_SSD_SCAN = "mix.ssd_scan"  # ops/ssd.py: the loop over chunks
 MIX_ATTENTION = "mix.attention"  # causal_attention's loop, ViT's attention
 MIX_ROPE = "mix.rope"  # ops/rope.py: the position tables and the turn
-MOE_ROUTE = "moe.route"  # router, top-k, sorts, counts, starts, zeroed buffer
+MOE_ROUTE = "moe.route"  # router, top-k, the dispatch (one sort with its
+# payloads, a bisection for the counts, rows by comparison), zeroed buffer
 MOE_EXPERTS = "moe.experts"  # topk_moe_layer's loop over tiles
 MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
 

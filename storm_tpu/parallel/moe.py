@@ -196,44 +196,91 @@ _COMBINE_BLOCK = 256
 _COMBINE_ROWS = 512
 
 
-def _combine_held(out, row_of, n: int, top_k: int):
+def _runs_in_tiles(keys, n_runs: int, tile: int):
+    """The runs of equal keys ``0 .. n_runs - 1`` in ascending ``keys`` (a
+    larger key: none of them, and last), each cut into tiles of ``tile``
+    places: ``(counts, shift, n_tiles, tile_at)``. A run starts where a
+    bisection of the sorted keys finds its key, so counting costs
+    ``n_runs + 1`` queries whatever the keys' number (and neither a
+    scatter-add nor a pass over them). With the tiles laid end to end in
+    the runs' order, place ``q`` of run ``r`` lies at ``q + shift[r]`` among
+    the tiles' places; ``n_tiles`` is the tiles' number, and ``tile_at(i)``
+    gives tile ``i`` of them as ``(run, start, filled)``: its run, where
+    among ``keys`` it starts, and how many of its places lie inside the run
+    (``tile`` or more but in a run's last tile)."""
+    bounds = jnp.searchsorted(
+        keys, jnp.arange(n_runs + 1, dtype=keys.dtype)).astype(jnp.int32)
+    starts = bounds[:-1]
+    counts = bounds[1:] - starts
+    tiles = -(-counts // tile)
+    tile_ends = jnp.cumsum(tiles)
+    first_tile = tile_ends - tiles
+
+    def tile_at(i):
+        run = jnp.searchsorted(tile_ends, i, side="right",
+                               method="compare_all").astype(jnp.int32)
+        j = i - first_tile[run]  # this tile within its run
+        return run, starts[run] + j * tile, counts[run] - j * tile
+
+    return counts, first_tile * tile - starts, tile_ends[-1], tile_at
+
+
+def _dispatch(local, weights, held: int, tile: int):
+    """The grouped product's bookkeeping from ``local`` (every assignment's
+    held expert, ``held`` for one held elsewhere) and ``weights``, both flat
+    in the order of the assignments: ``(counts, number_at, weight_at, row_at,
+    zero_row, n_tiles, tile_at)``. One stable sort by expert (so tokens
+    ascend inside an expert, absent ones last) carries each assignment's
+    number and weight to its place: ``number_at`` and ``weight_at`` are in
+    that order, and so is ``row_at``, the assignment's row of the tiles'
+    buffer: its place moved by its expert's shift (which of the ``held``
+    shifts by comparing its key with every held expert's number, not by
+    indexing), ``zero_row`` for an absent one: one row behind all that the
+    most tiles any routing makes can fill. ``counts`` ``(held,)`` and the
+    tiles are :func:`_runs_in_tiles`'s. Nothing here gathers or scatters an
+    element an assignment."""
+    total = local.shape[0]
+    place = jnp.arange(total, dtype=jnp.int32)
+    expert_at, number_at, weight_at = jax.lax.sort(
+        (local, place, weights), num_keys=1, is_stable=True)
+    counts, shift, n_tiles, tile_at = _runs_in_tiles(expert_at, held, tile)
+    zero_row = (-(-total // tile) + held) * tile
+    mine = expert_at[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
+    moved = jnp.sum(jnp.where(mine, shift[:, None], 0), axis=0)
+    row_at = jnp.where(expert_at < held, place + moved, zero_row)
+    return counts, number_at, weight_at, row_at, zero_row, n_tiles, tile_at
+
+
+def _combine_held(out, row_at, token_at, n: int, top_k: int):
     """``(n, dim)`` float32: each token's sum of its held assignments' rows
-    of ``out``, reading no other row (an absent assignment's ``row_of`` is
-    the zero row, the last). The grouped product's mirror image:
-    the held assignments are grouped by block of ``_COMBINE_BLOCK``
-    consecutive tokens (one sort keyed on the block, absent ones last, that
-    carries the row and the token along), each block's run is cut into tiles
-    of ``_COMBINE_ROWS``, and a loop over as many tiles as the data made
-    gathers a tile's rows and adds them to the block's tokens by a 0/1
-    matrix on the matrix unit (products with 0 and 1 are exact, the sum is
-    float32), so the order inside a block is immaterial."""
+    of ``out``, reading no other row. The assignments come as pairs in any
+    order, ``row_at`` the row of ``out`` and ``token_at`` the token (an
+    absent assignment's row is the zero row, the last; its token is not
+    read). The grouped product's mirror image: the held pairs are grouped
+    by block of ``_COMBINE_BLOCK`` consecutive tokens (one sort keyed on the
+    block, absent ones last, that carries the pair along), each block's run
+    is cut into tiles of ``_COMBINE_ROWS``, and a loop over as many tiles as
+    the data made gathers a tile's rows and adds them to the block's tokens
+    by a 0/1 matrix on the matrix unit (products with 0 and 1 are exact, the
+    sum is float32), so the order inside a block is immaterial."""
     dim = out.shape[1]
-    is_held = row_of < out.shape[0] - 1
     block = min(_COMBINE_BLOCK, -(-n // 8) * 8)
     rows = min(_COMBINE_ROWS, block * top_k)
     blocks = -(-n // block)
-    counts = jnp.sum(jnp.pad(is_held, (0, (blocks * block - n) * top_k))
-                     .reshape(blocks, block * top_k), axis=1, dtype=jnp.int32)
-    token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
-    _, row_at, token_at = jax.lax.sort(
-        (jnp.where(is_held, token // block, blocks), row_of, token),
-        num_keys=1, is_stable=False)
+    block_at, row_at, token_at = jax.lax.sort(
+        (jnp.where(row_at < out.shape[0] - 1, token_at // block, blocks),
+         row_at, token_at), num_keys=1, is_stable=False)
+    _, _, n_tiles, tile_at = _runs_in_tiles(block_at, blocks, rows)
     # a slice of ``rows`` from any start inside the assignments stays inside
     row_at, token_at = jnp.pad(row_at, (0, rows)), jnp.pad(token_at, (0, rows))
-    starts = jnp.cumsum(counts) - counts
-    tiles = -(-counts // rows)
-    tile_ends = jnp.cumsum(tiles)
     lane = jnp.arange(rows, dtype=jnp.int32)
     slot = jnp.arange(block, dtype=jnp.int32)
     # a float32 row times 1 stays float32 only at ``highest``
     exact = None if out.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
 
     def one_tile(i, y):
-        b = jnp.searchsorted(tile_ends, i, side="right",
-                             method="compare_all").astype(jnp.int32)
-        j = i - (tile_ends[b] - tiles[b])  # this tile within its block's run
-        start = starts[b] + j * rows
-        valid = lane < counts[b] - j * rows  # past the run's end: the zero row
+        b, start, filled = tile_at(i)
+        valid = lane < filled  # past the run's end: the zero row
         picked = out[jnp.where(valid, jax.lax.dynamic_slice(
             row_at, (start,), (rows,)), out.shape[0] - 1)]
         at = jax.lax.dynamic_slice(token_at, (start,), (rows,)) - b * block
@@ -244,7 +291,7 @@ def _combine_held(out, row_of, n: int, top_k: int):
         return jax.lax.dynamic_update_slice(y, jax.lax.dynamic_slice(
             y, corner, (block, dim)) + add, corner)
 
-    y = jax.lax.fori_loop(0, tile_ends[-1], one_tile,
+    y = jax.lax.fori_loop(0, n_tiles, one_tile,
                           jnp.zeros((blocks * block, dim), jnp.float32))
     return y[:n]
 
@@ -261,9 +308,18 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     ``absent`` how many assignments fell on experts held elsewhere (their
     part is another chip's to add).
 
-    The grouped product: assignments are sorted by expert, each expert's
-    run is cut into tiles of ``tile`` rows, and a loop over as many tiles as
-    the routing made gathers a tile's tokens, runs that expert's feed-forward
+    The dispatch: the assignments are ordered once, by expert (one held
+    elsewhere last) and by token inside an expert, in a sort that carries
+    each assignment's number and weight; how many a held expert has is where
+    its run ends among the sorted keys (a bisection), and an assignment's
+    row of the tiles' buffer is its place in that order plus its expert's
+    offset, found by comparing its key with the held experts' numbers. No
+    gather or scatter runs over the assignments. Noted as
+    ``expert_dispatch=sorted``.
+
+    The grouped product: each expert's run is cut into tiles of ``tile``
+    rows, and a loop over as many tiles as the routing made gathers a tile's
+    tokens, runs that expert's feed-forward
     on them (``ops/layers.py feed_forward``: SwiGLU or squared ReLU, as the
     stacked parameters say; the shared expert likewise, at its own width)
     and writes the weighted result to the tile's place in a buffer. The
@@ -276,8 +332,9 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     never a ``[tokens, top_k, dim]`` array (a scatter-add would do it in
     place, but the chip scatters a row at a time, a thousand times slower
     than it gathers). A second loop of the same kind reads the held rows
-    alone, a block of tokens at a time (:func:`_combine_held`): an absent
-    assignment's row is never read. Noted as ``expert_combine=held-rows``."""
+    alone, a block of tokens at a time (:func:`_combine_held`, which takes
+    the (row, token) pairs in the dispatch's order): an absent assignment's
+    row is never read. Noted as ``expert_combine=held-rows``."""
     from storm_tpu.ops import layers as L
 
     shape = x.shape
@@ -285,6 +342,7 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     w = p["experts"]
     held = w["down"].shape[0]
     _note("expert_ffn", "swiglu" if "gate" in w else "relu2")
+    _note("expert_dispatch", "sorted")
     # Its three parts under their names in a device trace (ops/parts.py):
     # routing with everything that only orders and counts, the loop over
     # tiles, the combine.
@@ -298,50 +356,37 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         tokens = x.reshape(-1, dim)
         n = tokens.shape[0]
         tile = max(8, min(int(tile), -(-n // 8) * 8))
-        most_tiles = -(-n * top_k // tile) + held
         local = experts - first_expert
         local = jnp.where((local >= 0) & (local < held), local,
                           held).reshape(-1)
-        order = jnp.argsort(local, stable=True)  # by expert, tokens ascending
-        counts_all = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
-        counts, absent = counts_all[:held], counts_all[held]
-        starts = jnp.cumsum(counts) - counts
-        tiles = -(-counts // tile)
-        tile_ends = jnp.cumsum(tiles)
-        # a slice of ``tile`` from any start inside the assignments stays
-        # inside
-        token_of = jnp.pad((order // top_k).astype(jnp.int32), (0, tile))
-        weight_of = jnp.pad(weights.reshape(-1)[order], (0, tile))
+        (counts, number_at, weight_at, row_at, zero_row, n_tiles,
+         tile_at) = _dispatch(local, weights.reshape(-1), held, tile)
+        token_at = number_at // top_k
+        absent = n * top_k - jnp.sum(counts)
         lane = jnp.arange(tile, dtype=jnp.int32)
         # one row more than the tiles can fill: where absent assignments point
-        empty = jnp.zeros((most_tiles * tile + 1, dim), x.dtype)
+        empty = jnp.zeros((zero_row + 1, dim), x.dtype)
+        # a slice of ``tile`` from any start inside the assignments stays
+        # inside
+        token_in, weight_in = (jnp.pad(a, (0, tile))
+                               for a in (token_at, weight_at))
 
     def one_tile(i, out):
-        e = jnp.searchsorted(tile_ends, i, side="right").astype(jnp.int32)
-        j = i - (tile_ends[e] - tiles[e])  # this tile within its expert's run
-        start = starts[e] + j * tile
-        valid = lane < counts[e] - j * tile  # rows past the run's end: zero
-        ids = jax.lax.dynamic_slice(token_of, (start,), (tile,))
+        e, start, filled = tile_at(i)
+        valid = lane < filled  # rows past the run's end: zero
+        ids = jax.lax.dynamic_slice(token_in, (start,), (tile,))
         rows = tokens[jnp.where(valid, ids, 0)]
         y = L.feed_forward({name: m[e] for name, m in w.items()}, rows)
         gain = jnp.where(valid, jax.lax.dynamic_slice(
-            weight_of, (start,), (tile,)), 0.0)
+            weight_in, (start,), (tile,)), 0.0)
         y = (y.astype(jnp.float32) * gain[:, None]).astype(out.dtype)
         return jax.lax.dynamic_update_slice(out, y, (i * tile, 0))
 
     with jax.named_scope(parts.MOE_EXPERTS):
-        out = jax.lax.fori_loop(0, tile_ends[-1], one_tile, empty)
-    with jax.named_scope(parts.MOE_ROUTE):
-        # where each assignment's row is: its expert's first tile, and its
-        # place in the expert's run
-        place = jnp.argsort(order).astype(jnp.int32)  # assignment -> place
-        first_row = jnp.append((tile_ends - tiles) * tile - starts,
-                               most_tiles * tile)
-        row_of = jnp.where(local < held, place + first_row[local],
-                           most_tiles * tile)
+        out = jax.lax.fori_loop(0, n_tiles, one_tile, empty)
     _note("expert_combine", "held-rows")
     with jax.named_scope(parts.MOE_COMBINE):
-        y = _combine_held(out, row_of, n, top_k)
+        y = _combine_held(out, row_at, token_at, n, top_k)
     if "shared" in p:
         with jax.named_scope(parts.PROJ):
             shared = L.feed_forward(p["shared"], tokens)

@@ -66,6 +66,7 @@ CASES = {
     "top2-half-held": (8, 4, 4, 2, 96, None),
     "top6-quarter-held": (16, 4, 4, 6, 100, None),
     "top8-eighth-held": (32, 4, 0, 8, 64, None),
+    "top8-a-thirty-second-held": (32, 1, 7, 8, 64, None),
     "top6-all-held": (8, 8, 0, 6, 50, None),
     "none-held": (16, 2, 6, 2, 100, "none"),
     "every-token-to-one-held": (8, 2, 2, 2, 111, "to-held"),
@@ -95,7 +96,8 @@ def test_a_tokens_sum_is_the_plain_sum(case, ffn):
             y, tokens, absent = jax.jit(lambda p, x: topk_moe_layer(
                 p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
         want = _plain(p, x, top_k, first)
-    assert seen == [f"expert_ffn={ffn}", "expert_combine=held-rows"]
+    assert seen == [f"expert_ffn={ffn}", "expert_dispatch=sorted",
+                    "expert_combine=held-rows"]
     np.testing.assert_allclose(y, want, atol=1e-5 * max(1.0, float(
         jnp.abs(want).max())))
     assert int(tokens.sum()) + int(absent) == n * top_k
@@ -140,7 +142,11 @@ def test_the_loop_on_any_share_held(dtype, n, top_k, share):
     out = out.astype(dtype)
     want = np.asarray(out.astype(jnp.float32), np.float64)[
         np.asarray(row_of)].reshape(n, top_k, DIM).sum(1)
-    got = jax.jit(lambda o, r: moe._combine_held(o, r, n, top_k))(out, row_of)
+    token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
+    # the pairs in any order: the combine sorts them by block itself
+    mixed = jax.random.permutation(jax.random.PRNGKey(4), n * top_k)
+    got = jax.jit(lambda o, r, t: moe._combine_held(o, r, t, n, top_k))(
+        out, row_of[mixed], token[mixed])
     assert got.dtype == jnp.float32 and got.shape == (n, DIM)
     np.testing.assert_allclose(got, want, atol=2e-6)
 
@@ -197,3 +203,114 @@ def test_no_array_of_tokens_by_picks_by_width_is_formed():
                                        dtype=jnp.float32)).lower(
         jnp.zeros((100, DIM)), jnp.zeros((384,), jnp.int32)).as_text()
     assert "tensor<64x6x%d" % DIM in old
+
+
+# share held: (router width, held, first expert, top-k)
+SHARES = {
+    "a-thirty-second-held": (32, 1, 5, 8),
+    "an-eighth-held": (32, 4, 8, 8),
+    "a-quarter-held": (16, 4, 4, 6),
+    "all-held": (8, 8, 0, 2),
+}
+
+
+def _routed(share, routing, n=100):
+    """A layer, its tokens and the plain routing: every assignment's held
+    expert (``held`` where another chip holds it) and weight."""
+    width, held, first, top_k = SHARES[share]
+    p = _layer(width, held, "swiglu", seed=2)
+    if routing == "skewed":  # every token picks the first held expert
+        p = _biased(p, first, 10.0)
+    elif routing == "one-expert-empty":  # and no token the last
+        p = _biased(p, first + held - 1, -10.0)
+    x = jax.random.normal(jax.random.PRNGKey(3), (n, DIM))
+    chosen, weight = route_topk(p, x, top_k, scale=2.5)
+    local = np.asarray(chosen).reshape(-1) - first
+    local = np.where((local >= 0) & (local < held), local, held)
+    return p, x, local, np.asarray(weight).reshape(-1)
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed", "one-expert-empty"])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_counts_are_the_plain_routings(share, routing):
+    """``tokens`` and ``absent`` are ``numpy.bincount`` of the plain routing:
+    the same integers, counted where a run of sorted keys ends."""
+    width, held, first, top_k = SHARES[share]
+    p, x, local, _ = _routed(share, routing)
+    _, tokens, absent = jax.jit(lambda p, x: topk_moe_layer(
+        p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
+    want = np.bincount(local, minlength=held + 1)
+    assert tokens.dtype == jnp.int32 and tokens.shape == (held,)
+    assert tokens.tolist() == want[:held].tolist()
+    assert int(absent) == want[held]
+    if routing == "skewed":
+        assert int(tokens[0]) == 100
+    elif routing == "one-expert-empty":
+        assert int(tokens[held - 1]) == 0
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed", "one-expert-empty"])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_every_held_assignment_has_a_row_of_its_own(share, routing):
+    """The dispatch alone: the order is the stable order by expert with each
+    assignment's weight beside it, every held assignment's row of the buffer
+    is written by exactly one tile of the experts' loop, from that
+    assignment's place, no two share a row, and an absent one points at the
+    zero row behind them all."""
+    width, held, first, top_k = SHARES[share]
+    tile = 16
+    _, _, local, weight = _routed(share, routing)
+    counts, number_at, weight_at, row_at, zero_row, n_tiles, tile_at = (
+        moe._dispatch(jnp.asarray(local, jnp.int32), jnp.asarray(weight),
+                      held, tile))
+    order = np.argsort(local, kind="stable")
+    assert np.asarray(number_at).tolist() == order.tolist()
+    assert np.asarray(weight_at).tolist() == weight[order].tolist()
+    assert counts.tolist() == np.bincount(local, minlength=held + 1)[
+        :held].tolist()
+    written = {}  # a row of the buffer -> the place it is written from
+    for i in range(int(n_tiles)):
+        e, start, filled = (int(v) for v in tile_at(i))
+        assert 0 < filled and 0 <= e < held
+        for lane in range(min(filled, tile)):
+            assert local[order[start + lane]] == e
+            written[i * tile + lane] = start + lane
+    n_held = int(counts.sum())
+    assert sorted(written.values()) == list(range(n_held))
+    rows = np.asarray(row_at)
+    assert [written[r] for r in rows[:n_held].tolist()] == list(range(n_held))
+    assert len(set(rows[:n_held].tolist())) == n_held
+    assert (rows[n_held:] == zero_row).all() and zero_row > max(
+        written, default=-1)
+    assert zero_row == (-(-local.size // tile) + held) * tile
+
+
+def test_no_gather_or_scatter_runs_over_the_assignments():
+    """64 tokens, top-6, a quarter held: the lowered layer holds no scatter,
+    no gather that returns a value an assignment (but ``route_topk``'s own
+    ``take_along_axis``, shaped ``[tokens, top_k]``), reads the ordered
+    assignments only where a bisection probes them (``held + 1`` and
+    ``blocks + 1`` probes), and sorts twice beside the router's top-k."""
+    held, top_k, n = 4, 6, 64
+    text = _lowered(_layer(16, held), jax.random.normal(
+        jax.random.PRNGKey(2), (n, DIM)), top_k, 4)
+    assert "scatter" not in text
+    assert text.count("stablehlo.sort") == 2 and "chlo.top_k" in text
+    gathers = [line for line in text.splitlines() if "stablehlo.gather" in line]
+    assert gathers
+    flat = "tensor<%dx" % (n * top_k)
+    probes = {held + 1, n // 16 + 1}
+    for line in gathers:
+        operand, result = re.search(r": \((tensor<[^>]*>), .*\) -> "
+                                    r"(tensor<[^>]*>)", line).groups()
+        assert not result.startswith(flat), line
+        if operand.startswith(flat):
+            assert int(re.match(r"tensor<(\d+)x", result).group(1)) in probes
+    # and the pattern does find the old form's three
+    local = jnp.zeros((n * top_k,), jnp.int32)
+    old = jax.jit(lambda w, l: (
+        w[jnp.argsort(l)], jnp.zeros((held + 1,), jnp.int32).at[l].add(1))
+    ).lower(jnp.zeros((n * top_k,)), local).as_text()
+    assert "stablehlo.scatter" in old
+    assert any(re.search(r"-> %s" % flat, line) for line in old.splitlines()
+               if "stablehlo.gather" in line)

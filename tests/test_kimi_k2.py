@@ -353,7 +353,7 @@ def test_the_inventory_names_the_forms(float32_engine):
     assert list(row["programs"]) == [str(eng.pad_batch(4))]
     forms = row["programs"][str(eng.pad_batch(4))].split(", ")
     assert {"rotary=yarn", "causal_attention=blocked", "expert_ffn=swiglu",
-            "expert_combine=held-rows"} <= set(forms)
+            "expert_dispatch=sorted", "expert_combine=held-rows"} <= set(forms)
 
 
 def test_device_counters_ride_the_result():

@@ -441,7 +441,8 @@ def test_the_inventory_names_the_form_of_the_tables():
     row = next(r for r in engine_inventory()["engines"]
                if r["model"] == "kimi_linear_tiny")
     assert list(row["programs"]) == [str(eng.pad_batch(8))]
-    assert "kda_tables=xla" in row["programs"][str(eng.pad_batch(8))].split(", ")
+    forms = row["programs"][str(eng.pad_batch(8))].split(", ")
+    assert {"kda_tables=xla", "expert_dispatch=sorted"} <= set(forms)
 
 
 def test_a_model_without_a_bound_keeps_the_policy_as_given():

@@ -260,7 +260,8 @@ def test_relu2_expert_layer_drops_no_token(skew):
                 p, x, 2, scale=2.5, tile=16))(p, x)
         want = _plain_experts(p, x, 2, 0, 2.5) + REFERENCE._relu2(
             p["shared"], x)
-    assert seen == ["expert_ffn=relu2", "expert_combine=held-rows"]
+    assert seen == ["expert_ffn=relu2", "expert_dispatch=sorted",
+                    "expert_combine=held-rows"]
     np.testing.assert_allclose(y, want, atol=1e-5 * float(
         jnp.abs(want).max()))
     assert int(tokens.sum()) == 2 * 111 and int(absent) == 0
@@ -413,6 +414,7 @@ def test_the_inventory_names_the_three_forms():
     assert list(row["programs"]) == [str(eng.pad_batch(8))]
     forms = row["programs"][str(eng.pad_batch(8))].split(", ")
     assert set(forms) == {"ssd_scan=chunked", "expert_ffn=relu2",
+                          "expert_dispatch=sorted",
                           "expert_combine=held-rows",
                           "causal_attention=blocked-grouped"}
 

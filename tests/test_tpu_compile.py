@@ -235,3 +235,55 @@ def test_longseq_encoder_forward_compiles_with_flash_kernel(v5e, monkeypatch):
     # fits one chip's 16 GB with room to spare
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize(
+    "n,dim,top_k,held,width,hidden,form,tile,stacked,sums", [
+        (32768, 2304, 8, 32, 256, 1024, "swiglu", 1024,  # Kimi-Linear's cell
+         "bf16[32,2304,1024]", "f32[32768,2304]"),
+        (32768, 2688, 6, 32, 128, 1856, "relu2", 512,  # Nemotron's cell
+         "bf16[32,2688,1856]", "f32[32768,2688]"),
+        (16384, 7168, 8, 12, 384, 2048, "swiglu", 512,  # Kimi K2's cell
+         "bf16[12,7168,2048]", "f32[16384,7168]"),
+    ], ids=["kimi_linear_48b", "nemotron_3_nano_30b", "kimi_k2_6"])
+def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
+        v5e, n, dim, top_k, held, width, hidden, form, tile, stacked, sums):
+    """The language cells' expert layer at their real sizes, shapes only:
+    the tile loop still carries the experts' stacked weights and the
+    combine's loop the tokens' float32 sums (the benchmark's
+    ``*expert_matmul_ms`` and ``*expert_combine_ms`` find the loops in a
+    trace by those shapes, and a listed metric that reads nothing makes a
+    run malformed), each in one loop alone, and the dispatch around them
+    holds no scatter and no gather of a value an assignment but
+    ``route_topk``'s own."""
+    import math
+    import re
+
+    from storm_tpu.parallel import moe
+
+    p = jax.eval_shape(lambda: moe.topk_moe_init(
+        jax.random.PRNGKey(0), dim, hidden, width, held, form=form))
+    p = jax.tree.map(lambda a: _spec(a.shape, jnp.bfloat16, v5e), p)
+    x = _spec((n, dim), jnp.float32, v5e)
+    text = jax.jit(lambda p, x: moe.topk_moe_layer(
+        p, x, top_k, tile=tile)).lower(p, x).compile().as_text()
+    lines = [re.sub(r"\{[^}]*\}", "", line) for line in text.splitlines()]
+    loops = [line for line in lines if " while(" in line]
+    assert sum(stacked in line for line in loops) == 1
+    assert sum(sums in line for line in loops) == 1
+    assert not any(stacked in line and sums in line for line in loops)
+    assert "scatter" not in text
+    # how many slices a gather fetches: one an assignment only in
+    # ``route_topk``, elsewhere a tile's rows or a bisection's probes
+    fetched = {}
+    for line in text.splitlines():
+        found = re.search(r" = \w+\[([\d,]+)\]\S* gather\(.*"
+                          r"offset_dims=\{([\d,]*)\}", line)
+        if found:
+            dims = found.group(1).split(",")
+            offsets = set(found.group(2).split(","))
+            fetched.setdefault(math.prod(
+                int(d) for i, d in enumerate(dims) if str(i) not in offsets),
+                []).append("take_along_axis" in line)
+    assert max(fetched) == n * top_k and all(fetched[n * top_k])
+    assert sorted(fetched)[:-1] == sorted({held + 1, n // 256 + 1, tile, 512})
