@@ -73,6 +73,7 @@ def _load_builtin() -> None:
         kimi_linear,
         lenet,
         longseq,
+        minicpm_sala,
         mixer,
         mobilenet,
         moe_vit,

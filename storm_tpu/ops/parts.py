@@ -23,6 +23,9 @@ MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
 MIX_SSD_SCAN = "mix.ssd_scan"  # ops/ssd.py: the loop over chunks
 MIX_ATTENTION = "mix.attention"  # causal_attention's loop, ViT's attention
 MIX_ROPE = "mix.rope"  # ops/rope.py: the position tables and the turn
+MIX_SPARSE_SELECT = "mix.sparse_select"  # ops/sparse_attention.py: pooled
+# keys, the first pass over them, the blocks' scores, the top-k, the counts
+MIX_SPARSE_ATTENTION = "mix.sparse_attention"  # ... its second pass
 MOE_ROUTE = "moe.route"  # router, top-k, the dispatch (one sort with its
 # payloads, a bisection for the counts, rows by comparison), zeroed buffer
 MOE_EXPERTS = "moe.experts"  # topk_moe_layer's loop over tiles
@@ -30,7 +33,8 @@ MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
 
 VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
-              MOE_EXPERTS, MOE_COMBINE)
+              MOE_EXPERTS, MOE_COMBINE, MIX_SPARSE_SELECT,
+              MIX_SPARSE_ATTENTION)
 
 
 def part_of(op_name: str):

@@ -1,0 +1,314 @@
+"""Block-sparse causal attention that picks its blocks of keys a query
+(InfLLM v2, as the MiniCPM4 report describes it, arXiv:2506.07900): nothing
+is learned by the selection, it reads the keys themselves.
+
+For a group ``g`` of query heads over one key head and a query at position
+``t`` (0-based):
+
+1. **Pooled keys.** ``c_i = mean(k[stride * i : stride * i + kernel])``, one
+   every ``stride`` keys over windows of ``kernel``; ``c_i`` is visible to
+   ``t`` once its whole window is: ``stride * i + kernel - 1 <= t``.
+2. **First pass** (``mix.sparse_select``). ``p_h = softmax_i(q_h . c_i *
+   scale)`` over the visible ``i`` for each head of the group, ``r = sum_h
+   p_h``. Block ``j`` (keys ``block * j .. block * j + block - 1``) scores
+   ``max r_i`` over ``i`` in ``[ratio * j - 1, ratio * j + ratio - 1]``,
+   ``ratio = block / stride`` (a max-pool of ``ratio + 1`` at stride
+   ``ratio``, one of padding). The first ``init_blocks`` blocks and the
+   ``window / block`` blocks before the query's own, with its own, score
+   ``+inf``. ``J(g, t)``: the ``topk`` best among the blocks ``j <= t //
+   block`` (the lower index where two score alike), all of them where there
+   are fewer. The scores are float32 at ``highest`` from the same ``q`` and
+   pooled ``k``: the selection is a router, and a rounding in it sends a
+   query to another block (PERF.md section 6, PR 34).
+3. **Second pass** (``mix.sparse_attention``). ``o_h = softmax over {s <= t,
+   s // block in J(g, t)} of (q_h . k_s * scale)`` times ``v_s``.
+
+:func:`select_blocks` is the first pass, the same code on every platform; it
+hands ``J`` over as a mask a query, group and block. The second pass is the
+same mathematics in both of its forms (:func:`sparse_form`, a rule over the
+traced shapes and what the process runs on, noted for
+``engine_inventory()["programs"]`` as ``sparse_attention=<form>``): no
+selection is shared by a tile's queries, no block is dropped, nothing stops
+early on small scores.
+
+* ``"kernel"``: one Pallas call a row of the batch, ops/flash_attention.py's
+  causal layout (a tile is the same positions of a group's heads stacked, a
+  key head's keys and values stay in VMEM over its tiles) with the mask in
+  place of the diagonal's comparison: a tile walks every key block up to its
+  diagonal and each query masks the keys of the blocks it did not pick. A
+  TPU's matrix unit sees a group's heads, 16 rows a query, so a query that
+  fetched its own 64 blocks would move 60 GB a window; the tiles' keys are
+  read once and the scores a query does not want are computed and thrown
+  away (PERF.md section 6, PR 45, says what that costs).
+* ``"blocked"``: XLA's form, ops/attention.py ``causal_blocked`` with the
+  same mask, elsewhere and on the CPU.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from storm_tpu.ops import parts as P
+from storm_tpu.ops.flash_attention import (_NEG, _VMEM_LIMIT, causal_tiles,
+                                           lane_width)
+from storm_tpu.ops.platform import note as _note
+from storm_tpu.ops.platform import one_device as _one_device
+from storm_tpu.ops.platform import use_pallas as _use_pallas
+
+F32 = jnp.float32
+
+
+def pooled_keys(k: jnp.ndarray, kernel: int, stride: int) -> jnp.ndarray:
+    """``k: (..., S, D)`` -> ``(..., (S - kernel) // stride + 1, D)`` float32:
+    the mean of every window of ``kernel`` keys, one a ``stride`` (``kernel``
+    a multiple of ``stride``: a window is whole runs of ``stride`` keys)."""
+    s, d = k.shape[-2:]
+    if kernel % stride or s % stride or s < kernel:
+        raise ValueError(f"pooling windows of {kernel} at stride {stride} "
+                         f"over {s} keys")
+    runs = k.astype(F32).reshape(*k.shape[:-2], s // stride, stride, d).sum(-2)
+    n = s // stride - kernel // stride + 1
+    return sum(runs[..., a:a + n, :]
+               for a in range(kernel // stride)) / kernel
+
+
+def select_blocks(q: jnp.ndarray, k: jnp.ndarray, *, scale: float,
+                  kernel_size: int, kernel_stride: int, block_size: int,
+                  topk: int, init_blocks: int, window_size: int,
+                  tile: int = 1024) -> jnp.ndarray:
+    """The first pass: ``q: (B, Hq, S, D)``, ``k: (B, Hkv, S, D)`` -> ``(B,
+    Hkv, S, S / block_size)`` bool, true where block ``j`` is in ``J(g, t)``.
+    A row of the batch at a time, a tile of ``tile`` queries at a time
+    against the pooled keys its last query sees, so at most ``Hq x tile x S /
+    stride`` scores exist at once."""
+    _, hq, s, d = q.shape
+    hkv = k.shape[1]
+    g, ratio = hq // hkv, block_size // kernel_stride
+    if hq % hkv or block_size % kernel_stride or s % block_size:
+        raise ValueError(f"{hq} heads over {hkv}, blocks of {block_size} at "
+                         f"stride {kernel_stride} over {s} positions")
+    nb = s // block_size
+    local = window_size // block_size
+
+    def row(qk):
+        qr, kr = qk  # (Hq, S, D), (Hkv, S, D)
+        pooled = pooled_keys(kr, kernel_size, kernel_stride)
+        out = []
+        for lo in range(0, s, tile):
+            hi = min(lo + tile, s)
+            t = jnp.arange(lo, hi)
+            seen = max(0, (hi - kernel_size) // kernel_stride + 1)
+            blocks = -(-hi // block_size)  # those the tile's last query has
+            if seen:
+                scores = jnp.einsum(
+                    "grtd,gid->grti",
+                    qr[:, lo:hi].astype(F32).reshape(hkv, g, hi - lo, d),
+                    pooled[:, :seen], precision=lax.Precision.HIGHEST) * scale
+                visible = (kernel_stride * jnp.arange(seen) + kernel_size - 1
+                           <= t[:, None])
+                top = jnp.max(jnp.where(visible, scores, -jnp.inf), -1,
+                              keepdims=True)
+                e = jnp.where(visible, jnp.exp(
+                    scores - jnp.where(jnp.isfinite(top), top, 0.0)), 0.0)
+                total = e.sum(-1, keepdims=True)
+                r = (e / jnp.where(total > 0, total, 1.0)).sum(1)
+            else:
+                r = jnp.zeros((hkv, hi - lo, 0), F32)
+            # r_i at index i + 1: block j reads indices ratio*j .. ratio*j+ratio
+            r = jnp.pad(r, ((0, 0), (0, 0), (1, ratio * blocks - seen)))
+            score = jnp.maximum(
+                r[..., :ratio * blocks].reshape(hkv, hi - lo, blocks,
+                                                ratio).max(-1),
+                r[..., ratio::ratio])
+            j, own = jnp.arange(blocks), (t // block_size)[:, None]
+            forced = (j < init_blocks) | ((j >= own - local) & (j <= own))
+            score = jnp.where(forced, jnp.inf,
+                              jnp.where(j <= own, score, -jnp.inf))
+            # the k-th best and where it lies: equal scores go by index
+            best, at = lax.top_k(score, min(topk, blocks))
+            picked = (score > best[..., -1:]) | (
+                (score == best[..., -1:]) & (j <= at[..., -1:]))
+            out.append(jnp.pad(picked & (j <= own),
+                               ((0, 0), (0, 0), (0, nb - blocks))))
+        return jnp.concatenate(out, 1)
+
+    with jax.named_scope(P.MIX_SPARSE_SELECT):
+        return lax.map(row, (q, k))
+
+
+def keys_read(picked: jnp.ndarray, block_size: int) -> tuple:
+    """``(read, skipped)`` of a selection ``(..., S, S / block_size)``, int32:
+    the keys the picked blocks hold up to each query's position, summed, and
+    the causal keys they leave out."""
+    s, nb = picked.shape[-2:]
+    t, j = jnp.arange(s)[:, None], jnp.arange(nb)
+    held = jnp.clip(t + 1 - j * block_size, 0, block_size)  # (S, nb)
+    with jax.named_scope(P.MIX_SPARSE_SELECT):
+        read = jnp.sum(jnp.where(picked, held, 0), dtype=jnp.int32)
+        every = picked.size // (s * nb) * (s * (s + 1) // 2)
+        return read, jnp.int32(every) - read
+
+
+def sparse_form(hq: int, hkv: int, s: int, dk: int, dv: int,
+                block_size: int) -> str:
+    """Which form the second pass is built with: ``"kernel"`` on a TPU in a
+    process with one device, for sequences of whole tiles, key blocks of
+    whole selection blocks and head widths the kernel reads as they lie (as
+    ops/attention.py ``causal_form``); ``"blocked"`` elsewhere."""
+    block_q, block_k = causal_tiles(hq // hkv)
+    # 32 positions a tile at least: the mask's int8 tile is 32 sublanes
+    if (_use_pallas() and _one_device() and s % block_q == 0
+            and s % block_k == 0 and block_k % block_size == 0
+            and block_q % 32 == 0 and lane_width(dk) == dk
+            and lane_width(dv) == dv):
+        return "kernel"
+    return "blocked"
+
+
+def sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     picked: jnp.ndarray, scale: float,
+                     block_size: int, block: int = 512) -> jnp.ndarray:
+    """The second pass: ``q: (B, Hq, S, Dk)``, ``k: (B, Hkv, S, Dk)``, ``v:
+    (B, Hkv, S, Dv)``, ``picked: (B, Hkv, S, S / block_size)`` ->
+    ``(B, Hq, S, Dv)``. One row of the batch at a time, one loop in the
+    compiled program; softmax in float32, the weights to the value product
+    in the values' type and unnormalised, the result divided by their
+    float32 sum, in both forms."""
+    hq, hkv, s = q.shape[1], k.shape[1], q.shape[2]
+    form = sparse_form(hq, hkv, s, q.shape[-1], v.shape[-1], block_size)
+    _note("sparse_attention", form)
+    with jax.named_scope(P.MIX_SPARSE_ATTENTION):
+        if form == "kernel":
+            return lax.map(lambda i: _kernel_row(
+                q, k, v, _wanted(picked[i], block_size), scale, i),
+                jnp.arange(q.shape[0]))
+        return lax.map(lambda a: _blocked_row(*a, scale, block_size, block),
+                       (q, k, v, picked))
+
+
+def _wanted(picked: jnp.ndarray, block_size: int,
+            lo: int = 0, hi=None) -> jnp.ndarray:
+    """``picked: (Hkv, S, nb)`` -> ``(Hkv, hi - lo, hi)`` bool: whether query
+    ``lo + t`` reads key ``s`` (its block is picked and ``s <= lo + t``)."""
+    hi = picked.shape[1] if hi is None else hi
+    of_block = jnp.repeat(picked[:, lo:hi, :-(-hi // block_size)],
+                          block_size, axis=-1)[..., :hi]
+    return of_block & (jnp.arange(hi) <= jnp.arange(lo, hi)[:, None])
+
+
+def _blocked_row(q, k, v, picked, scale, block_size, block):
+    """One row as XLA computes it: a block of ``block`` queries against the
+    keys up to its end, as ``causal_blocked``."""
+    hq, s, hkv = q.shape[0], q.shape[1], k.shape[0]
+    q = q.reshape(hkv, hq // hkv, s, q.shape[-1])
+    outs = []
+    for lo in range(0, s, block):
+        hi = min(lo + block, s)
+        scores = jnp.einsum("grsd,gtd->grst", q[:, :, lo:hi], k[:, :hi],
+                            preferred_element_type=F32) * scale
+        scores = jnp.where(_wanted(picked, block_size, lo, hi)[:, None],
+                           scores, -jnp.inf)
+        weights = jnp.exp(scores - scores.max(-1, keepdims=True))
+        out = jnp.einsum("grst,gtd->grsd", weights.astype(v.dtype), v[:, :hi],
+                         preferred_element_type=F32)
+        outs.append((out / weights.sum(-1, keepdims=True)).astype(v.dtype))
+    return jnp.concatenate(outs, -2).reshape(hq, s, v.shape[-1])
+
+
+def _mask_kernel(at_ref, q_ref, k_ref, v_ref, m_ref, o_ref, *, scale,
+                 block_k):
+    """One tile of queries against its key head's keys up to the tile's
+    diagonal, block by block, as ops/flash_attention.py ``_attn_kernel``
+    with ``causal``; ``m_ref: (1, BQ, S)`` int8 says which keys each of the
+    tile's positions reads (the same for the ``G`` heads stacked in the
+    tile). A query's own block is always among them and is met last, so
+    whatever a wholly masked block left in the carry is scaled away."""
+    del at_ref  # read by the block specs
+    g, bq, dk = q_ref.shape[1:]
+    rows = g * bq
+    q = q_ref[0].reshape(rows, dk)
+    first = pl.program_id(1) * bq
+
+    def step(i, carry):
+        m, l, acc = carry
+        at = pl.multiple_of(i * block_k, block_k)
+        keys = k_ref[0, pl.ds(at, block_k), :]
+        values = v_ref[0, pl.ds(at, block_k), :]
+        s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32) * scale
+        wanted = m_ref[0, :, pl.ds(at, block_k)].astype(jnp.int32) != 0
+        s = jnp.where(wanted, s.reshape(g, bq, block_k), _NEG).reshape(
+            rows, block_k)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
+                acc * alpha + lax.dot_general(
+                    p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+                    preferred_element_type=F32))
+
+    _, l, acc = lax.fori_loop(
+        0, pl.cdiv(first + bq, block_k), step,
+        (jnp.full((rows, 1), _NEG, F32), jnp.zeros((rows, 1), F32),
+         jnp.zeros((rows, v_ref.shape[2]), F32)))
+    o_ref[0] = (acc / l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _kernel_row(q, k, v, wanted, scale, row, interpret=False):
+    """Row ``row`` of ``q: (B, Hq, S, Dk)``, ``k``, ``v`` (read where it lies
+    in the whole arrays, as ``flash_attention(row=...)``) under ``wanted:
+    (Hkv, S, S)`` bool -> ``(Hq, S, Dv)``."""
+    b, hq, s, dk = q.shape
+    hkv, dv = k.shape[1], v.shape[-1]
+    g = hq // hkv
+    block_q, block_k = causal_tiles(g)
+    out = pl.pallas_call(
+        functools.partial(_mask_kernel, scale=scale, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(hkv, s // block_q),
+            in_specs=[
+                pl.BlockSpec((1, g, block_q, dk),
+                             lambda h, qi, at: (at[0] + h, 0, qi, 0)),
+                pl.BlockSpec((1, s, dk), lambda h, qi, at: (at[0] + h, 0, 0)),
+                pl.BlockSpec((1, s, dv), lambda h, qi, at: (at[0] + h, 0, 0)),
+                pl.BlockSpec((1, block_q, s), lambda h, qi, at: (h, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g, block_q, dv),
+                                   lambda h, qi, at: (h, 0, qi, 0))),
+        out_shape=jax.ShapeDtypeStruct((hkv, g, s, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(jnp.asarray(row, jnp.int32).reshape(1) * hkv,
+      q.reshape(b * hkv, g, s, dk), k.reshape(b * hkv, s, dk),
+      v.reshape(b * hkv, s, dv), wanted.astype(jnp.int8))
+    return out.reshape(hq, s, dv)
+
+
+def block_sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                           scale: float, *, dense_len: int, **selection):
+    """The whole mixer's attention: ``(out, keys read, keys skipped)``. A
+    window of ``dense_len`` positions or fewer is plain causal attention
+    (ops/attention.py ``causal_attention``: every causal key read); a longer
+    one selects (``selection``: :func:`select_blocks`'s sizes) and reads
+    its blocks."""
+    from storm_tpu.ops.attention import causal_attention
+
+    b, _, s, _ = q.shape
+    if s <= dense_len:
+        every = b * k.shape[1] * (s * (s + 1) // 2)
+        return (causal_attention(q, k, v, scale=scale), jnp.int32(every),
+                jnp.int32(0))
+    picked = select_blocks(q, k, scale=scale, **selection)
+    read, skipped = keys_read(picked, selection["block_size"])
+    return (sparse_attention(q, k, v, picked, scale,
+                             selection["block_size"]), read, skipped)

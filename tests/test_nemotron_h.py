@@ -100,13 +100,30 @@ def test_chunked_scan_is_the_recurrence(step, chunk):
     assert float(jnp.abs(got - want).max()) < 5e-5 * float(jnp.abs(want).max())
 
 
-def test_scan_shorter_than_a_chunk_and_one_group_a_head():
-    """40 tokens under a chunk of 128; as many groups as heads (no sharing)."""
-    args = _ssd_inputs(0.05, shape=(1, 40), heads=2, groups=2, seed=3)
+def _lightning_inputs():
+    """The scan as a linear-attention layer calls it (models/minicpm_sala.py):
+    ``x = v``, a step of 1, one fixed decay a head between 0.43 and 0.996 a
+    token, ``b = k``, ``c = q / sqrt(N)``, no skip, a group a head, ``N = P``;
+    150 tokens, two chunks of 128, the last ragged."""
+    x, _, _, b, c, _ = _ssd_inputs(1.0, heads=4, p=16, groups=4, n=16, seed=5)
+    slopes = 2.0 ** (-8.0 * (jnp.arange(4) + 1) / 4)
+    return (x, jnp.ones(x.shape[:3]), -slopes, b, c * 16 ** -0.5,
+            jnp.zeros((4,)))
+
+
+@pytest.mark.parametrize("inputs,bound", [
+    # 40 tokens under a chunk of 128; as many groups as heads (no sharing)
+    (lambda: _ssd_inputs(0.05, shape=(1, 40), heads=2, groups=2, seed=3),
+     1e-5),
+    (_lightning_inputs, 1e-5),
+], ids=["shorter-than-a-chunk", "lightning-G=H-dt=1-D=0"])
+def test_scan_with_one_group_a_head(inputs, bound):
+    args = inputs()
     with jax.default_matmul_precision("highest"):
         got = ssd.ssd_chunked(*args, chunk=128)
         want = _recurrence(*args)
-    assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) < bound * float(
+        jnp.abs(want).max())
 
 
 def test_scan_compiles_to_one_loop_and_no_scatter():

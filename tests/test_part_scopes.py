@@ -22,6 +22,10 @@ EXPECTED = {
                                         parts.MIX_KDA_SCAN},
     "nemotron_h_tiny": COMMON | MOE | {parts.MIX_SSD_SCAN},
     "kimi_k2_tiny": COMMON | MOE | {parts.MIX_ROPE},
+    # its window of 96 selects: the dense form's ``mix.attention`` is not run
+    "minicpm_sala_tiny": (COMMON - {parts.MIX_ATTENTION}) | {
+        parts.MIX_SPARSE_SELECT, parts.MIX_SPARSE_ATTENTION,
+        parts.MIX_SSD_SCAN, parts.MIX_ROPE},
 }
 
 
@@ -84,8 +88,11 @@ def test_the_innermost_name_is_the_operations():
     assert parts.part_of(
         "jit(fwd)/mix.elementwise/mix.attention/while/body/exp") == \
         parts.MIX_ATTENTION
+    assert parts.part_of(
+        "jit(fwd)/mix.elementwise/mix.sparse_select/while/body/top_k") == \
+        parts.MIX_SPARSE_SELECT
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 13
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 15
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():

@@ -287,3 +287,68 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
                 []).append("take_along_axis" in line)
     assert max(fetched) == n * top_k and all(fetched[n * top_k])
     assert sorted(fetched)[:-1] == sorted({held + 1, n // 256 + 1, tile, 512})
+
+
+def _metric_pattern(name):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "metrics", name + ".json")) as f:
+        return json.load(f)["args"]["pattern"]
+
+
+def _loops(text):
+    """A compiled program's ``while`` operations as a trace names them."""
+    return [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+            if " while(" in line]
+
+
+def test_sparse_attention_compiles_as_the_loops_the_metrics_look_for(
+        v5e, monkeypatch):
+    """``minicpm_sala``'s attention at its cell's step, 4 windows of 16,384
+    with 32 query heads over 2 key heads of 128, as one chip builds it: the
+    mask kernel is in the program, the second pass is one ``while`` that
+    carries the rows' indices, the selection and q where they lie (the
+    benchmark's ``sparse_attention_ms`` finds it so), and the first pass is
+    another that the pattern does not take for it."""
+    import re
+
+    import storm_tpu.ops.sparse_attention as sa
+    from storm_tpu.models.minicpm_sala import INFLLM_V2
+
+    monkeypatch.setattr(sa, "_use_pallas", lambda: True)
+    monkeypatch.setattr(sa, "_one_device", lambda: True)
+    assert sa.sparse_form(32, 2, 16384, 128, 128, 64) == "kernel"
+    assert sa.sparse_form(32, 2, 16384 + 64, 128, 128, 64) == "blocked"
+    q, k = (_spec((4, h, 16384, 128), jnp.bfloat16, v5e) for h in (32, 2))
+    text = jax.jit(lambda q, k, v: sa.block_sparse_attention(
+        q, k, v, 128 ** -0.5, **INFLLM_V2)).lower(q, k, k).compile().as_text()
+    assert "tpu_custom_call" in text
+    wanted = re.compile(_metric_pattern("sparse_attention_ms"))
+    found = [bool(wanted.search(line)) for line in _loops(text)]
+    assert len(found) == 2 and sum(found) == 1
+    (second,) = [line for line in _loops(text) if wanted.search(line)]
+    assert "bf16[4,32,16384,128]" in second and "s32[4]" in second
+    # the picked blocks' mask a row (int8, 537 MB) is the largest thing made
+    assert "f32[32,16384," not in text and "f32[2,16,16384," not in text
+
+
+def test_lightning_scan_compiles_to_the_loop_its_metric_looks_for(v5e):
+    """``ssd_chunked`` as ``minicpm_sala``'s lightning layers call it (a
+    group a head, 32 states of 128 x 128 a row): one ``while`` that carries
+    ``f32[4,32,1,128,128]``, which ``lightning_scan_ms`` finds and Nemotron's
+    ``ssd_scan_ms`` does not."""
+    import re
+
+    from storm_tpu.ops.ssd import ssd_chunked
+
+    x = _spec((4, 16384, 32, 128), jnp.bfloat16, v5e)
+    dt = _spec((4, 16384, 32), jnp.float32, v5e)
+    a = _spec((32,), jnp.float32, v5e)
+    (loop,) = _loops(jax.jit(lambda x, dt, a, b, c, d: ssd_chunked(
+        x, dt, a, b, c, d, chunk=128)).lower(x, dt, a, x, x, a)
+        .compile().as_text())
+    assert "f32[4,32,1,128,128]" in loop
+    assert re.search(_metric_pattern("lightning_scan_ms"), loop)
+    assert not re.search(_metric_pattern("ssd_scan_ms"), loop)
