@@ -89,27 +89,27 @@ def kda_mixer_init(rng, dim: int, heads: int, head_dim: int,
 
 def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
               chunk: int, eps: float) -> jnp.ndarray:
-    b, s, _ = x.shape
+    """Between the input projections and the output's, every branch is
+    ``(B, S, heads * head_dim)``, a head a block of lanes: the form ``_proj``
+    yields and ``ops/kda.py``'s kernel reads. Only a norm's statistic is a
+    number a head."""
     f32 = jnp.float32
 
-    def heads_of(y):
-        return y.reshape(b, s, heads, head_dim)
-
     def branch(name):
-        y = kda.short_conv(p["conv_" + name], _proj(x, p[name]))
-        return heads_of(jax.nn.silu(y))
+        return jax.nn.silu(kda.short_conv(p["conv_" + name],
+                                          _proj(x, p[name])))
 
-    q = kda.l2norm(branch("q")) * (head_dim ** -0.5)
-    k = kda.l2norm(branch("k"))
+    q = kda.l2norm_heads(branch("q"), heads) * (head_dim ** -0.5)
+    k = kda.l2norm_heads(branch("k"), heads)
     v = branch("v")
     f = _proj(x, p["f_down"], p["f_up"]).astype(f32)
-    g = -jnp.exp(p["a_log"].astype(f32))[:, None] * heads_of(
-        jax.nn.softplus(f + p["dt_bias"].astype(f32)))
+    g = -jnp.repeat(jnp.exp(p["a_log"].astype(f32)), head_dim) \
+        * jax.nn.softplus(f + p["dt_bias"].astype(f32))
     beta = jax.nn.sigmoid(_proj(x, p["beta"]).astype(f32))
-    o = kda.kda_chunked(q.astype(x.dtype), k, v, g, beta, chunk=chunk)
+    o = kda.kda_chunked(q.astype(x.dtype), k, v, g, beta, heads, chunk=chunk,
+                        out=lambda o: L.rmsnorm(p["o_norm"], o, eps))
     gate = jax.nn.sigmoid(_proj(x, p["g_down"], p["g_up"]))
-    o = L.rmsnorm(p["o_norm"], o, eps) * heads_of(gate)
-    return _proj(o.reshape(b, s, heads * head_dim), p["o"])
+    return _proj(o * gate, p["o"])
 
 
 def mla_mixer_init(rng, dim: int, heads: int, nope: int, rope: int,

@@ -60,6 +60,18 @@ over channels for every pair of a diagonal block; a column of ``A`` spread
 over the lanes for every row that leaves a sub-chunk below it), not by HBM or
 the matrix unit.
 
+**A head is a block of lanes, in and out.** ``kda_chunked`` takes ``q, k, v,
+g`` as ``(B, S, H * d)``, the form the layer's projections yield and the
+kernel reads, and hands its result back so. On a TPU that is no matter of
+notation: ``[.., H * d]`` is tiled eight positions by 128 lanes and ``[.., H,
+d]`` eight *heads* by 128 lanes, so a reshape from one to the other moves
+every element (1.6 ms for a branch of ``f32[8, 4096, 4096]`` on a v5e).
+Hence also ``l2norm_heads``, whose statistic a head is a product with a 0/1
+matrix, and ``out``, the layer's norm of a head's output rows done where the
+chain leaves them, ``dv`` the minor axis. XLA's form cuts heads out itself,
+where it pays forty passes anyway. The one pass left is the result's way
+from chunks-first to lanes, in the compute type.
+
 ``tables_form`` says which form a program is built with, from what the code
 can observe and no option: the kernel on a TPU (``STORM_TPU_NO_PALLAS`` off)
 in a process with one device (a Mosaic call has no partitioning rule), for
@@ -134,6 +146,53 @@ def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
     return (xf * lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + eps)
             ).astype(x.dtype)
+
+
+def _head_of_lane(wide: int, heads: int, cols: int, dtype) -> jnp.ndarray:
+    """``(H * d, cols)``: one where the lane is of the head its column
+    counts, zero elsewhere (and in every column past the heads)."""
+    lane = lax.broadcasted_iota(jnp.int32, (wide, cols), 0)
+    col = lax.broadcasted_iota(jnp.int32, (wide, cols), 1)
+    return (lane // (wide // heads) == col).astype(dtype)
+
+
+def head_sums(x: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """The sums of ``x: (..., H * d)`` over each head's block of ``d`` lanes,
+    ``(..., H)``, as a product with a 0/1 matrix: ``x`` is read where it
+    lies. (A sum over the view ``(..., H, d)`` re-tiles ``x`` on a TPU: eight
+    heads to a tile there, eight positions here.) At ``highest`` every bit of
+    a float32 ``x`` reaches the sum, as in the view's. The matrix is whole
+    lane tiles of columns wide, the heads' first: the v5e's compiler then
+    makes it once a program and gives the product the windows that read
+    0.40 ms a branch of ``[8, 4096, 4096]``; ``H`` columns wide it builds it
+    inside the product, whose windows shrink in a whole model's program
+    (1.38 ms)."""
+    wide = x.shape[-1]
+    cols = -(-heads // 128) * 128
+    return jnp.dot(x, _head_of_lane(wide, heads, cols, x.dtype),
+                   precision=_HI,
+                   preferred_element_type=jnp.float32)[..., :heads]
+
+
+def over_heads(stat: jnp.ndarray, d: int) -> jnp.ndarray:
+    """A number a head ``(..., H)`` spread over its head's ``d`` lanes,
+    ``(..., H * d)``, by the same matrix: exact at ``highest`` (a lane's sum
+    has one term), and the compiler fuses what is done with it into the
+    product's output, where a broadcast to ``(..., H, d)`` and a reshape
+    write the spread array to HBM twice."""
+    heads = stat.shape[-1]
+    return jnp.dot(stat, _head_of_lane(heads * d, heads, heads, stat.dtype).T,
+                   precision=_HI, preferred_element_type=jnp.float32)
+
+
+def l2norm_heads(x: jnp.ndarray, heads: int,
+                 eps: float = 1e-6) -> jnp.ndarray:
+    """``l2norm`` of each head of ``x: (..., S, H * d)``, a head a block of
+    ``d`` lanes: ``x`` stays in lanes, only the statistic is a number a
+    head."""
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(head_sums(xf * xf, heads) + eps)
+    return (xf * over_heads(inv, x.shape[-1] // heads)).astype(x.dtype)
 
 
 def _unit_lower_inverse(n: jnp.ndarray, sub: int) -> jnp.ndarray:
@@ -411,15 +470,22 @@ def within_chunks_kernel(row, tables, q, k, v, g, beta, *, heads: int,
       beta.astype(f32), *tables))
 
 
-def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
-    """The layer's output ``(B, S, H, dv)`` for ``q, k: (B, S, H, dk)`` (as
-    the layer reads them: normalised, ``q`` already scaled), ``v: (B, S, H,
-    dv)``, log-decay ``g: (B, S, H, dk)`` (float32, at most zero) and
-    ``beta: (B, S, H)``. A sequence that is no multiple of ``chunk`` is padded
-    with tokens that write nothing (``beta`` 0) and forget nothing (``g``
-    0)."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+def kda_chunked(q, k, v, g, beta, heads: int, chunk: int = 64,
+                sub: int = 16, out=None):
+    """The layer's output ``(B, S, H * dv)`` for ``q, k: (B, S, H * dk)`` (as
+    the layer reads them: normalised, ``q`` already scaled), ``v: (B, S, H *
+    dv)``, log-decay ``g: (B, S, H * dk)`` (float32, at most zero) and
+    ``beta: (B, S, H)``, a head being a block of lanes of ``H = heads``: the
+    form the projections yield and the kernel reads, so the layer around
+    this call re-tiles nothing. ``out``: what the layer does to a head's
+    output rows ``(..., dv)`` on their own (its norm), done where the chain
+    leaves them, a head's ``dv`` the minor axis, before the one pass that
+    brings them to lanes. A sequence that is no multiple of ``chunk`` is
+    padded with tokens that write nothing (``beta`` 0) and forget nothing
+    (``g`` 0)."""
+    b, s, _ = q.shape
+    h = heads
+    dk, dv = q.shape[-1] // h, v.shape[-1] // h
     sub = min(sub, chunk)
     if chunk % sub:
         raise ValueError(f"chunk {chunk} is no multiple of sub-chunk {sub}")
@@ -434,19 +500,18 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
     f32 = jnp.float32
 
     def padded(y):
-        return jnp.pad(y, ((0, 0), (0, pad)) + ((0, 0),) * (y.ndim - 2))
+        return jnp.pad(y, ((0, 0), (0, pad), (0, 0)))
 
-    def chunks(y):  # (B, S, H, ...) -> (B, H, N, C, ...)
-        y = padded(y).reshape(b, n, chunk, h, *y.shape[3:])
-        return jnp.moveaxis(y, 3, 1)
+    def chunks(y, *d):  # (B, S, H * d) -> (B, H, N, C, d); beta has no d
+        return jnp.moveaxis(padded(y).reshape(b, n, chunk, h, *d), 3, 1)
 
     # Either form a row at a time: one loop in the compiled program, whose
     # device time a trace shows whole, under its name there (ops/parts.py).
     if form == "kernel":
-        # (B, S, H, d) as (B, N * C, H * d): no data moves, and the kernel
-        # reads its row where it lies and writes it where the chain reads it
-        whole = tuple(padded(y).reshape(b, n * chunk, -1)
-                      for y in (q, k, v, g.astype(f32))) + (chunks(beta),)
+        # the kernel reads its row where the layer left it, a head a block of
+        # lanes, and writes it where the chain reads it
+        whole = tuple(padded(y) for y in (q, k, v, g.astype(f32))) + (
+            chunks(beta),)
         with jax.named_scope(P.MIX_KDA_TABLES):
             w, u0, q_in, k_out, a_qk, decay = lax.fori_loop(
                 0, b, lambda row, tables: within_chunks_kernel(
@@ -454,7 +519,10 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
                 empty_tables(b, n, h, chunk, dk, dv, cd))
         decay = jnp.moveaxis(decay, 2, 0)
     else:
-        rows = tuple(chunks(y) for y in (q, k, v, g.astype(f32), beta))
+        # heads are cut out here: on the CPU a reshape is free, and on a TPU
+        # this form pays forty passes over a row's float32 arrays anyway
+        rows = (chunks(q, dk), chunks(k, dk), chunks(v, dv),
+                chunks(g.astype(f32), dk), chunks(beta))
         with jax.named_scope(P.MIX_KDA_TABLES):
             parts = lax.map(lambda row: _within_chunks(*row, sub=sub), rows)
         w, u0, q_in, k_out, a_qk, decay = (jnp.moveaxis(y, 2, 0)
@@ -478,6 +546,13 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
     with jax.named_scope(P.MIX_KDA_SCAN):
         _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
                         (w, u0, q_in, k_out, a_qk, decay))
-    # (N, B, H, C, dv) -> (B, S, H, dv)
-    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, n * chunk, h, dv)
-    return o[:, :s]
+    if out is not None:
+        o = out(o)
+    # (N, B, H, C, dv) -> (B, S, H * dv). A tile of the TPU's memory holds
+    # eight positions by 128 lanes: with a chunk's positions cut into eights
+    # the transposition moves whole tiles, one pass in the compute type, and
+    # the reshape after it moves nothing. To (B, S, H, dv) and from there to
+    # lanes is two passes (and in float32, the type of what reads it).
+    lead = (chunk,) if chunk % 8 else (chunk // 8, 8)
+    o = jnp.moveaxis(o.reshape(n, b, h, *lead, dv), (0, 2), (1, 2 + len(lead)))
+    return o.reshape(b, n * chunk, h * dv)[:, :s]

@@ -285,9 +285,53 @@ def check_causal_attention(interpret: bool = False) -> List[dict]:
     return rows
 
 
+def check_kda_mixer(interpret: bool = False) -> List[dict]:
+    """Kimi-Linear's KDA mixer (``models/kimi_linear.py kda_mixer``) whole,
+    as a process builds it (on the chip: the tables' kernel, every branch a
+    head a block of lanes from the projections to it), vs the benchmark's
+    plain reference (``benchmarks/references/kimi_linear.py _kda``: heads an
+    axis, the state read token by token) in float32 at ``highest``.
+
+    One row at the published widths (4,096 positions into 2,304, 32 heads of
+    128) in bfloat16; the reference sees the same (bfloat16-rounded) weights
+    and input. The measure is the root mean square of the difference over
+    the reference's: the branches are rounded to bfloat16 at five points and
+    the chain's products take bfloat16 operands, which reads 0.5 %; a head
+    or a chunk out of place reads 1. (Under the interpreter, this runner's
+    smoke test, 128 positions and 2 heads in float32, whatever form the
+    tables take there.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.core import spec  # the checkout's root is on the path
+    from storm_tpu.models import kimi_linear as K
+
+    reference = spec.plugin("references", "kimi_linear")
+    s, dim, heads, d, dt, tol = ((128, 64, 2, 128, jnp.float32, 1e-4)
+                                 if interpret else
+                                 (4096, 2304, 32, 128, jnp.bfloat16, 2e-2))
+    sizes = {"linear_attn_config": {"num_heads": heads, "head_dim": d}}
+    p = jax.tree.map(lambda a: a.astype(dt),
+                     K.kda_mixer_init(jax.random.PRNGKey(0), dim, heads, d, 4))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, s, dim)).astype(dt)
+    got = np.asarray(jax.jit(lambda p, x: K.kda_mixer(
+        p, x, heads, d, 64, 1e-5))(p, x)[0], np.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(lambda p, x: reference._kda(
+            p, x, sizes, 1e-5))(jax.tree.map(
+                lambda a: a.astype(jnp.float32), p),
+            x[0].astype(jnp.float32)))
+    rms = float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+    return [{**_row("kda_mixer", f"S{s}_H{heads}_D{d}", np.dtype(dt).name,
+                    got, want, rel_tol=tol),
+             "rms_rel_err": round(rms, 8), "metric": "rms",
+             "pass": bool(rms <= tol)}]
+
+
 def run_all(interpret: bool = False) -> List[dict]:
     return (check_flash_attention(interpret)
             + check_short_attention(interpret)
             + check_w8a16(interpret)
             + check_kda_tables(interpret)
+            + check_kda_mixer(interpret)
             + check_causal_attention(interpret))

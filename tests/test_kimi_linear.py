@@ -75,6 +75,19 @@ def _kda_inputs(decay, shape, d, seed=0):
 DECAYS = {"slow": 0.1, "fast": 3.0, "within-a-token": 30.0}
 
 
+def _lanes(y):
+    """``(B, S, H, d)`` as the layer holds it, a head a block of lanes."""
+    return y.reshape(*y.shape[:2], -1)
+
+
+def _chunked(q, k, v, g, beta, **kw):
+    """The four-dimensional call ``kda_chunked`` took before it was handed
+    its operands as the kernel reads them: heads an axis, in and out."""
+    o = kda.kda_chunked(*(_lanes(y) for y in (q, k, v, g)), beta,
+                        heads=q.shape[2], **kw)
+    return o.reshape(*v.shape)
+
+
 @pytest.mark.parametrize("decay", DECAYS.values(), ids=DECAYS.keys())
 def test_chunked_kda_is_the_recurrence(decay):
     """150 tokens are no multiple of the chunk (64) nor of the sub-chunk.
@@ -85,7 +98,7 @@ def test_chunked_kda_is_the_recurrence(decay):
     q, k, v, g, beta = _kda_inputs(decay, (2, 150, 3), 32)
     with jax.default_matmul_precision("highest"):
         want = _recurrence(q, k, v, g, beta)
-        got = kda.kda_chunked(q, k, v, g, beta, chunk=64, sub=16)
+        got = _chunked(q, k, v, g, beta, chunk=64, sub=16)
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
 
@@ -159,10 +172,80 @@ def test_chunked_kda_through_the_kernel_is_the_recurrence(
     with jax.default_matmul_precision("highest"):
         want = _recurrence(q, k, v, g, beta)
         with dispatch_notes() as seen:
-            got = kda.kda_chunked(q, k, v, g, beta, chunk=64)
+            got = _chunked(q, k, v, g, beta, chunk=64)
     assert seen == ["kda_tables=kernel"]
     assert got.shape == want.shape and bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+@pytest.mark.parametrize("decay", DECAYS.values(), ids=DECAYS.keys())
+def test_heads_as_blocks_of_lanes_are_the_heads_alone_to_the_bit(
+        decay, form, request):
+    """The hand-over in three dimensions cuts a head out as a block of
+    lanes, in and out: three heads of 128 side by side give, to the bit in
+    float32, what each gives handed over alone, the four-dimensional view
+    cut by this test. 150 tokens are padded by both forms; a norm on the way
+    out is a head's own."""
+    if form == "kernel":
+        request.getfixturevalue("tables_by_the_kernel")
+    q, k, v, g, beta = _kda_inputs(decay, (2, 150, 3), 128)
+    with jax.default_matmul_precision("highest"):
+        got = kda.kda_chunked(*(_lanes(y) for y in (q, k, v, g)), beta,
+                              heads=3, chunk=64, out=kda.l2norm)
+        alone = [kda.kda_chunked(q[:, :, h], k[:, :, h], v[:, :, h],
+                                 g[:, :, h], beta[:, :, h:h + 1], heads=1,
+                                 chunk=64, out=kda.l2norm) for h in range(3)]
+    assert got.shape == (2, 150, 3 * 128)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.concatenate(alone, -1)))
+
+
+@pytest.mark.parametrize("heads,d", [(3, 128), (2, 16)])
+def test_lane_block_norm_is_the_norm_of_each_head(heads, d):
+    """``l2norm_heads`` on ``(B, S, H * d)``, its statistic a product with a
+    0/1 matrix, against ``l2norm`` on the four-dimensional view: float32
+    both, another order of the sum alone. Values up to 1e4 and down to 1e-4
+    in one head: every bit of a square reaches the sum."""
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 150, heads * d))
+    x = x * 10.0 ** jax.random.randint(jax.random.PRNGKey(9), x.shape, -4, 5)
+    want = _lanes(kda.l2norm(x.reshape(2, 150, heads, d)))
+    got = kda.l2norm_heads(x, heads)
+    assert got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-12)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_the_mixer_hands_the_kernel_lanes_and_brings_no_branch_back(
+        tables_by_the_kernel):
+    """2 rows of 128 positions, 2 heads of 128, the kernel's form: q, k, v
+    and the decay reach the Pallas call as ``(B, S, heads * head_dim)``, the
+    form the projections yield, and no branch goes to ``(B, S, heads,
+    head_dim)`` and is reshaped back (on the chip each way re-tiles the whole
+    array: eight heads to a tile there, eight positions here). A statistic's
+    view by tiles of positions is no such reshape."""
+    b, s, heads, d = 2, 128, 2, 128
+    p = K.kda_mixer_init(jax.random.PRNGKey(6), 64, heads, d, 4)
+    x = jax.ShapeDtypeStruct((b, s, 64), jnp.float32)
+    eqns = list(_equations(jax.make_jaxpr(
+        lambda p, x: K.kda_mixer(p, x, heads, d, 64, 1e-5))(p, x).jaxpr))
+    (call,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [v.aval.shape for v in call.invars[1:5]] == [(b, s, heads * d)] * 4
+    assert [str(v.aval.dtype) for v in call.invars[1:5]] == ["float32"] * 4
+    back = [e for e in eqns if e.primitive.name == "reshape"
+            and e.invars[0].aval.shape == (b, s, heads, d)
+            and e.outvars[0].aval.shape == (b, s, heads * d)]
+    assert not back
 
 
 def test_more_chunks_than_a_grid_step_holds(tables_by_the_kernel):
@@ -170,7 +253,7 @@ def test_more_chunks_than_a_grid_step_holds(tables_by_the_kernel):
     whole steps, the same output as the jnp form's."""
     q, k, v, g, beta = _kda_inputs(3.0, (1, 600, 1), 128, seed=1)
     with jax.default_matmul_precision("highest"):
-        got = kda.kda_chunked(q, k, v, g, beta, chunk=64)
+        got = _chunked(q, k, v, g, beta, chunk=64)
         want = _recurrence(q, k, v, g, beta)
     assert float(jnp.abs(got - want).max()) < 1e-5 * float(jnp.abs(want).max())
 
@@ -248,6 +331,17 @@ def test_kda_mixer_against_the_reference_row_by_row():
         got = K.kda_mixer(p, x, 2, 16, 16, 1e-5)
         want = jnp.stack([REFERENCE._kda(p, row, SIZES, 1e-5) for row in x])
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_chips_mixer_check_runs_here_at_toy_size():
+    """``ops/parity_checks.py check_kda_mixer`` holds the mixer on the chip
+    to the reference at the published widths; its smoke form (128 positions,
+    2 heads of 128, float32) runs here and reads summation order alone."""
+    from storm_tpu.ops.parity_checks import check_kda_mixer
+
+    (row,) = check_kda_mixer(interpret=True)
+    assert row["pass"] and row["metric"] == "rms"
+    assert row["rms_rel_err"] < 1e-5
 
 
 def _plain_experts(p, x, top_k, first, scale):

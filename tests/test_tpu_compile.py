@@ -352,3 +352,36 @@ def test_lightning_scan_compiles_to_the_loop_its_metric_looks_for(v5e):
     assert "f32[4,32,1,128,128]" in loop
     assert re.search(_metric_pattern("lightning_scan_ms"), loop)
     assert not re.search(_metric_pattern("ssd_scan_ms"), loop)
+
+
+def test_kda_mixer_compiles_with_every_branch_in_lanes(v5e, monkeypatch):
+    """Kimi-Linear's KDA mixer at its cell's step (8 windows of 4,096 into
+    2,304, 32 heads of 128, bfloat16) as one chip builds it: the tables'
+    kernel is in the program, both loops carry the 64 x 64 table
+    ``kda_scan_ms`` finds them by, and outside the loops nothing of a
+    branch's size has heads for an axis (``[.., 32, 128]`` puts eight heads
+    in a tile where ``[.., 4096]`` puts eight positions: each way between
+    them re-tiles the whole array) nor is re-tiled by a ``reshape``: the one
+    pass left is the copy that brings the chain's result to lanes, in
+    bfloat16."""
+    import re
+
+    from storm_tpu.models import kimi_linear as K
+    from storm_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_use_pallas", lambda: True)
+    monkeypatch.setattr(kda, "_one_device", lambda: True)
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: K.kda_mixer_init(
+            jax.random.PRNGKey(0), 2304, 32, 128, 4)))
+    x = _spec((8, 4096, 2304), jnp.bfloat16, v5e)
+    text = jax.jit(lambda p, x: K.kda_mixer(p, x, 32, 128, 64, 1e-5)).lower(
+        p, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    wanted = re.compile(_metric_pattern("kda_scan_ms"))
+    assert [bool(wanted.search(line)) for line in _loops(text)] == [True] * 2
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r"\[8,4096,32,128\]|\[4096,8,32,128\]", entry)
+    assert not re.search(r"\[8,4096,4096\]\S* reshape\(", entry)
+    assert re.findall(r"= (\w+)\[64,8,32,[\d,]+\]\S* copy\(", entry) == ["bf16"]

@@ -56,6 +56,15 @@ def test_kda_tables_compiled_parity():
 
 
 @pytest.mark.slow
+def test_kda_mixer_compiled_parity():
+    from storm_tpu.ops.parity_checks import check_kda_mixer
+
+    rows = check_kda_mixer(interpret=False)
+    bad = [r for r in rows if not r["pass"]]
+    assert not bad, f"compiled kda_mixer parity failures: {bad}"
+
+
+@pytest.mark.slow
 def test_causal_attention_compiled_parity():
     from storm_tpu.ops.parity_checks import check_causal_attention
 
