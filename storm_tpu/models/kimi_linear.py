@@ -96,8 +96,8 @@ def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
     f32 = jnp.float32
 
     def branch(name):
-        return jax.nn.silu(kda.short_conv(p["conv_" + name],
-                                          _proj(x, p[name])))
+        return kda.conv_silu(p["conv_" + name],
+                             _proj(x, p[name]))
 
     q = kda.l2norm_heads(branch("q"), heads) * (head_dim ** -0.5)
     k = kda.l2norm_heads(branch("k"), heads)

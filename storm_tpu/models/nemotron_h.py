@@ -93,11 +93,11 @@ def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
     b, s, _ = x.shape
     f32 = jnp.float32
     inner, gn = heads * head_dim, groups * state
-    zxbcdt = _proj(x, p["in_proj"])  # [z | x B C | dt]
-    z = zxbcdt[..., :inner]
-    xbc = jax.nn.silu(kda.short_conv(p["conv"],
-                                     zxbcdt[..., inner:2 * inner + 2 * gn]))
-    dt = jax.nn.softplus(zxbcdt[..., 2 * inner + 2 * gn:].astype(f32)
+    # in_proj's columns are [z | x B C | dt]
+    z = _proj(x, p["in_proj"][:, :inner])
+    xbcdt = _proj(x, p["in_proj"][:, inner:])
+    xbc = kda.conv_silu(p["conv"], xbcdt)
+    dt = jax.nn.softplus(xbcdt[..., inner + 2 * gn:].astype(f32)
                          + p["dt_bias"].astype(f32))
     y = ssd_chunked(
         xbc[..., :inner].reshape(b, s, heads, head_dim), dt,

@@ -80,6 +80,16 @@ whole sub-chunks; XLA's form elsewhere (the CPU, a host with several chips,
 ``kimi_linear_tiny``'s 16-wide heads). ``platform.note("kda_tables", form)``
 records the choice for ``engine_inventory()["programs"]``.
 
+**The short convolution and the SiLU after it** are likewise two forms of
+one contract, chosen by ``conv_form``: ``short_conv`` and the activation as
+XLA fuses them, or ``conv_silu_kernel``, one Pallas TPU call that reads the
+projection's result once, in the type the projection wrote, where it lies
+(all of Kimi-Linear's branch; the first 6,144 columns of Nemotron's ``[x B C
+| dt]``, with the bias), each block of positions with the few rows before it, taps,
+bias and activation in float32 in VMEM and one rounding on the way out.
+(XLA's fusion has the projection write float32 and takes that array four
+times, once a tap.) ``platform.note("short_conv", form)``.
+
 **Both forms run a row of the batch at a time, under one loop** (``lax.map``;
 for the kernel ``lax.fori_loop``, which carries the room it writes into).
 For XLA's form that bounds the float32 temporaries to one row's. For both it
@@ -93,6 +103,7 @@ reading tables plus chain.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +123,17 @@ _KERNEL_SUB = 8
 # Chunks of one head a grid step of the kernel holds in VMEM: blocks of 128 KB
 # to 256 KB an operand; 16 measured the same on the v5e.
 _KERNEL_CHUNKS = 8
+# The convolution's kernel: the positions of a grid step's block (the least,
+# which a sequence must be whole blocks of, and the most: 2,048 x 128 read
+# 1.00 ms for ``[8, 4096, 4096]`` on the v5e, 512 x 512 1.09, 512 x 128
+# 1.38), its lanes (one tile: channels need be no more than whole tiles), the
+# positions it convolves at a time, and the float32 rows (one sublane tile) a
+# block hands the next, which bounds the taps at nine.
+_CONV_ROWS_LEAST = 512
+_CONV_ROWS_MOST = 2048
+_CONV_LANES = 128
+_CONV_STEP = 64
+_CONV_CARRY = 8
 
 
 def short_conv_init(rng, channels: int, width: int = 4,
@@ -140,6 +162,105 @@ def short_conv(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     if "b" in p:
         y = y + p["b"].astype(jnp.float32)
     return y.astype(x.dtype)
+
+
+def conv_form(channels: int, seq: int, width: int) -> str:
+    """Which form runs the convolution with its activation: ``"kernel"``
+    (``conv_silu_kernel``) or ``"xla"`` (``short_conv``, then the activation,
+    as XLA fuses them). A function of the traced shapes and of what the
+    process runs on, as ``tables_form``: the kernel on a TPU in a process
+    with one device, for channels of whole lane tiles, sequences of whole
+    blocks of positions and taps that reach back no further than the eight
+    rows a block hands the next."""
+    if (_use_pallas() and _one_device() and channels % _CONV_LANES == 0
+            and seq % _CONV_ROWS_LEAST == 0 and width - 1 <= _CONV_CARRY):
+        return "kernel"
+    return "xla"
+
+
+def _conv_kernel(*refs, rows: int, step: int, bias: bool, activation):
+    """One block of positions by one block of lanes of one row of the batch.
+    ``wide`` is float32 room for the block under ``_CONV_CARRY`` rows of
+    what came before it: the block is widened into it once, and a tap's
+    operand is that room read ``j`` rows up. The head rows are zero at a
+    row's first block and the previous block's last rows after it (the grid
+    walks a row's positions innermost, in order)."""
+    x_ref, w_ref = refs[:2]
+    b_ref = refs[2] if bias else None
+    o_ref, wide = refs[-2:]
+    head = _CONV_CARRY
+    width = w_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        wide[:head] = jnp.zeros((head, wide.shape[1]), jnp.float32)
+
+    taps = [w_ref[j:j + 1, :] for j in range(width)]
+    for lo in range(0, rows, step):
+        xf = x_ref[lo:lo + step, :].astype(jnp.float32)
+        wide[head + lo:head + lo + step] = xf
+        back = head + lo - (width - 1)
+        terms = [taps[j] * wide[back + j:back + j + step]
+                 for j in range(width - 1)] + [taps[-1] * xf]
+        y = sum(terms[1:], terms[0])  # short_conv's order: the oldest first
+        if bias:
+            y = y + b_ref[...]
+        o_ref[lo:lo + step, :] = activation(y).astype(o_ref.dtype)
+    wide[:head] = wide[rows:]
+
+
+def conv_silu_kernel(w, b, x, *, activation=jax.nn.silu, rows: int = None,
+                     step: int = None, interpret: bool = False):
+    """``activation(short_conv({"w": w, "b": b}, x[..., :C]))`` for ``w:
+    (width, C)``, ``b: (C,)`` or None and ``x: (B, S, wide)``, ``wide >=
+    C``, as one Pallas TPU call that reads those columns once, in their own
+    type and where they lie: taps, bias and activation in float32 in VMEM,
+    one rounding on the way out. The grid walks (row of the batch, lane
+    tile, block of ``rows`` positions), positions innermost and in order: a
+    block hands its last ``_CONV_CARRY`` rows to the next in float32 scratch
+    (zeros at a row's first block: tokens before the first read as zero, and
+    nothing crosses from one row to the next). ``C`` is whole lane tiles
+    (``wide`` need not be), ``S`` a multiple of ``rows`` and ``rows`` of
+    ``step``, the positions widened and convolved at a time (what stays in
+    registers between a tap and the rounding)."""
+    width, channels = w.shape
+    bsz, seq, _ = x.shape
+    lanes = _CONV_LANES
+    rows = rows or math.gcd(seq, _CONV_ROWS_MOST)
+    step = step or min(rows, _CONV_STEP)
+    f32 = jnp.float32
+    operands = [x, w.astype(f32)]
+    specs = [pl.BlockSpec((None, rows, lanes), lambda i, c, s: (i, s, c)),
+             pl.BlockSpec((width, lanes), lambda i, c, s: (0, c))]
+    if b is not None:
+        operands.append(b.astype(f32).reshape(1, channels))
+        specs.append(pl.BlockSpec((1, lanes), lambda i, c, s: (0, c)))
+    return pl.pallas_call(
+        functools.partial(_conv_kernel, rows=rows, step=step,
+                          bias=b is not None, activation=activation),
+        grid=(bsz, channels // lanes, seq // rows),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((None, rows, lanes),
+                               lambda i, c, s: (i, s, c)),
+        out_shape=jax.ShapeDtypeStruct((bsz, seq, channels), x.dtype),
+        scratch_shapes=[pltpu.VMEM((_CONV_CARRY + rows, lanes), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*operands)
+
+
+def conv_silu(p: dict, x: jnp.ndarray,
+              activation=jax.nn.silu) -> jnp.ndarray:
+    """``activation(short_conv(p, x[..., :C]))`` for the ``C`` channels the
+    kernel ``p`` has, the first columns of a projection's result ``x: (B, S,
+    wide)``, in the form ``conv_form`` gives this program."""
+    width, channels = p["w"].shape
+    form = conv_form(channels, x.shape[1], width)
+    _note("short_conv", form)
+    if form == "kernel":
+        return conv_silu_kernel(p["w"], p.get("b"), x, activation=activation)
+    return activation(short_conv(p, x[..., :channels]))
 
 
 def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:

@@ -328,10 +328,57 @@ def check_kda_mixer(interpret: bool = False) -> List[dict]:
              "pass": bool(rms <= tol)}]
 
 
+def check_short_conv(interpret: bool = False) -> List[dict]:
+    """The compiled convolution-and-SiLU kernel (``ops/kda.py
+    conv_silu_kernel``) vs the plain form (``short_conv``, then
+    ``jax.nn.silu``) in float32 on the same (bfloat16-rounded) input.
+
+    Both published shapes in bfloat16, with the blocks their programs take:
+    Kimi-Linear's branch (all of ``[8, 4096, 4096]``, no bias) and
+    Nemotron's (the first 6,144 columns of ``[x B C | dt]``, ``[8, 4096,
+    6208]``, whose last lane tile is partial, with the bias). The kernel
+    rounds once, so its worst element may lie one bfloat16 step from the
+    reference: the measure is the difference in steps of the reference's
+    size (of 2^-7 under it: where the taps cancel, the two forms' float32
+    sums differ by more than a step of what is left). Under the interpreter
+    a small float32 case, three blocks of positions by two lane tiles out
+    of a wider array, to 1e-6."""
+    import jax
+    import jax.numpy as jnp
+
+    from storm_tpu.ops import kda
+
+    f32 = jnp.float32
+    cases = ([("toy", (2, 48, 444), 256, True, f32, dict(rows=16, step=8))]
+             if interpret else
+             [("kimi_branch", (8, 4096, 4096), 4096, False, jnp.bfloat16, {}),
+              ("nemotron_xbc", (8, 4096, 6208), 6144, True, jnp.bfloat16, {})])
+    plain = jax.jit(lambda p, x: jax.nn.silu(kda.short_conv(p, x)))
+    rows = []
+    for case, shape, channels, bias, dt, blocks in cases:
+        kp, kx = jax.random.split(jax.random.PRNGKey(0))
+        p = kda.short_conv_init(kp, channels, 4, bias=bias)
+        x = jax.random.normal(kx, shape, f32).astype(dt)
+        got = np.asarray(kda.conv_silu_kernel(
+            p["w"], p.get("b"), x, interpret=interpret, **blocks), np.float32)
+        want = np.asarray(plain(p, x[..., :channels].astype(f32)))
+        row = _row("short_conv", f"{case}_C{channels}_of{shape[-1]}",
+                   np.dtype(dt).name, got, want, abs_tol=1e-6)
+        if dt == jnp.bfloat16:
+            size = np.maximum(np.abs(want), 2.0 ** -7)
+            steps = float((np.abs(got - want)
+                           / 2.0 ** (np.floor(np.log2(size)) - 7)).max())
+            row.update(bf16_steps=round(steps, 4), metric="bf16_steps",
+                       tol=1.0, **{"pass": bool(steps <= 1.0)})
+        rows.append(row)
+    return rows
+
+
 def run_all(interpret: bool = False) -> List[dict]:
     return (check_flash_attention(interpret)
             + check_short_attention(interpret)
             + check_w8a16(interpret)
             + check_kda_tables(interpret)
             + check_kda_mixer(interpret)
+            + check_short_conv(interpret)
             + check_causal_attention(interpret))

@@ -98,5 +98,8 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
     with jax.named_scope(P.MIX_SSD_SCAN):  # its name in a device trace
         _, y = lax.scan(one_chunk, jnp.zeros((bsz, g, r, p, n), f32), xs)
-    y = jnp.moveaxis(y, 0, 1).reshape(bsz, s + pad, h, p)[:, :s]
-    return (y.astype(f32) + d.astype(f32)[:, None] * x.astype(f32)).astype(cd)
+    # the skip, on the chunks as the loop read and wrote them: whatever
+    # handed ``x`` over need not hand it to what reads the result as well
+    y = (y.astype(f32)
+         + d.astype(f32).reshape(g, r, 1) * xs[0].astype(f32)).astype(cd)
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, s + pad, h, p)[:, :s]

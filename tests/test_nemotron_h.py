@@ -430,7 +430,8 @@ def test_the_inventory_names_the_three_forms():
                if r["model"] == "nemotron_h_tiny")
     assert list(row["programs"]) == [str(eng.pad_batch(8))]
     forms = row["programs"][str(eng.pad_batch(8))].split(", ")
-    assert set(forms) == {"ssd_scan=chunked", "expert_ffn=relu2",
+    assert set(forms) == {"short_conv=xla", "ssd_scan=chunked",
+                          "expert_ffn=relu2",
                           "expert_dispatch=sorted",
                           "expert_combine=held-rows",
                           "causal_attention=blocked-grouped"}

@@ -384,4 +384,7 @@ def test_kda_mixer_compiles_with_every_branch_in_lanes(v5e, monkeypatch):
     entry = text[text.index("ENTRY"):]
     assert not re.search(r"\[8,4096,32,128\]|\[4096,8,32,128\]", entry)
     assert not re.search(r"\[8,4096,4096\]\S* reshape\(", entry)
-    assert re.findall(r"= (\w+)\[64,8,32,[\d,]+\]\S* copy\(", entry) == ["bf16"]
+    # (the compiler spells that array ``[64,8,32,8,8,128]`` or, with the
+    # convolutions a kernel's calls, ``[8,64,8,8,32,128]``)
+    assert re.findall(r"= (\w+)\[\d+(?:,\d+){4,}\]\S* copy\(",
+                      entry) == ["bf16"]

@@ -65,6 +65,15 @@ def test_kda_mixer_compiled_parity():
 
 
 @pytest.mark.slow
+def test_short_conv_compiled_parity():
+    from storm_tpu.ops.parity_checks import check_short_conv
+
+    rows = check_short_conv(interpret=False)
+    bad = [r for r in rows if not r["pass"]]
+    assert not bad, f"compiled short_conv parity failures: {bad}"
+
+
+@pytest.mark.slow
 def test_causal_attention_compiled_parity():
     from storm_tpu.ops.parity_checks import check_causal_attention
 
