@@ -666,28 +666,13 @@ class ContinuousBatcher:
     def _observe_aux(self, aux: dict) -> None:
         """What the model counted on the device during this step (its
         ``new_state["aux"]``, fetched with the predictions), into the
-        registry. An expert layer reports the tokens routed to each expert
-        it holds (``expert_tokens``, a row a layer) and the assignments that
-        fell on experts held elsewhere (``expert_absent``): two counters,
-        and once a layer and step the busiest held expert over the mean.
-        A sparse-attention layer reports the keys its queries' picked
-        blocks hold up to their positions and the causal keys they leave
-        out (``sparse_keys_read``, ``sparse_keys_skipped``, a number a
-        layer): two counters more."""
-        m, cid = self._metrics, self._cid
-        read, skipped = aux.get("sparse_keys_read"), aux.get(
-            "sparse_keys_skipped")
-        if read is not None and skipped is not None:
-            m.counter(cid, "sparse_keys_read").inc(int(read.sum()))
-            m.counter(cid, "sparse_keys_skipped").inc(int(skipped.sum()))
-        tokens, absent = aux.get("expert_tokens"), aux.get("expert_absent")
-        if tokens is None or absent is None:
-            return
-        m.counter(cid, "expert_assignments_held").inc(int(tokens.sum()))
-        m.counter(cid, "expert_assignments_absent").inc(int(absent.sum()))
-        load = m.histogram(cid, "expert_tokens_max_over_mean")
-        for layer in tokens:
-            load.observe(float(layer.max()) / max(float(layer.mean()), 1e-9))
+        registry, by the model's own reader (``ModelDef.observe_aux``): what
+        the counts are called and mean is the business of the op that
+        counts them."""
+        model = getattr(self._engine_ref(), "model", None)
+        reader = getattr(model, "observe_aux", None)
+        if reader is not None:
+            reader(self._metrics, self._cid, aux)
 
     @staticmethod
     def _notify(items: List[Submission]) -> None:

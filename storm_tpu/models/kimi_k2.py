@@ -16,28 +16,26 @@ experts from ``first_expert`` and ``num_classes`` rows of embedding and head;
 the router keeps its published width. The language model only: a vision
 tower in front of it is no part of this file.
 
-**The load.** ``init`` hands each leaf over in ``param_dtype`` as it makes
-it, as a checkpoint of that type would: a float32 twin of 3.5 B parameters
-does not fit beside them. A layer's leaves are made by one small program in
-which each is drawn in float32, scaled and cast in one pass, so no float32
-leaf is ever written to memory. The engine's cast leaves such leaves alone.
-The values are those ``astype`` of the float32 draw gives.
+**The load** is ``models/scorer.py``'s in ``param_dtype``, a program a
+layer: a float32 twin of 3.5 B parameters does not fit beside them. The
+engine's cast leaves such leaves alone.
 
-The step's auxiliaries ride ``new_state["aux"]`` as Kimi-Linear's do.
+The skeleton (ids, the float32 stream, the head, the counters' way out) is
+:func:`storm_tpu.models.scorer.token_scorer`'s; this file holds the plan.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from storm_tpu.models.kimi_linear import _w, mla_mixer, mla_mixer_init
+from storm_tpu.models import scorer as S
+from storm_tpu.models.kimi_linear import mla_mixer, mla_mixer_init
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
 from storm_tpu.ops import rope as R
 from storm_tpu.ops.platform import note as _note
-from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
+from storm_tpu.parallel.moe import topk_moe_init
 
 
 def build_kimi_k2(
@@ -77,8 +75,6 @@ def build_kimi_k2(
     """Layers ``0..layers-1`` of the published stack (dense feed-forward in
     the first ``first_dense``, experts after) over ``num_classes`` rows of
     the vocabulary."""
-    (seq,) = input_shape
-    vocab = num_classes
     # as models/kimi_linear.py: every residual branch's output projection
     # starts smaller by the root of the branches of the published stack
     branch = (2 * published_layers) ** -0.5
@@ -88,105 +84,51 @@ def build_kimi_k2(
     softmax_scale = (nope + rope) ** -0.5 * m * m
     attention_factor = R.yarn_mscale(yarn_factor, mscale) / m
 
-    def served(tree):
-        return jax.tree.map(lambda a: a.astype(param_dtype), tree)
-
-    def block_init(dense: bool, km, kf):
-        mixer = mla_mixer_init(km, dim, heads, nope, rope, v_dim, kv_rank,
-                               q_rank)
-        mixer["o"] = mixer["o"] * branch
+    def mixer_init(key):
+        mixer = S.scaled(mla_mixer_init(key, dim, heads, nope, rope, v_dim,
+                                        kv_rank, q_rank), {"o": branch})
         # the draw stands for a checkpoint, whose rotary channels lie in
         # interleaved pairs: the loader's one reorder (ops/rope.py)
         q_b = mixer["q_b"].reshape(q_rank, heads, nope + rope)
         mixer["q_b"] = R.halves_first(q_b, first=nope).reshape(q_rank, -1)
         mixer["kv_a"] = R.halves_first(mixer["kv_a"], first=kv_rank)
-        if dense:
-            ffn = L.swiglu_init(kf, dim, dense_width)
-            ffn["down"] = ffn["down"] * branch
-        else:
-            ffn = topk_moe_init(kf, dim, expert_width, n_experts,
-                                experts_held)
-            # an untrained bias of the size of the gaps between sorted
-            # scores, N(0, 0.01^2), so that it matters and the held share
-            # stays near its expectation (PERF.md section 6, PR 36)
-            ffn["router_bias"] = ffn["router_bias"] * 0.2
-            for part in (ffn["experts"], ffn["shared"]):
-                part["down"] = part["down"] * branch
-        return served({"norm1": L.rmsnorm_init(dim), "mixer": mixer,
-                       "norm2": L.rmsnorm_init(dim), "ffn": ffn})
+        return mixer
 
-    def ends_init(ke, kh):
-        return served({
-            "embed": jax.random.normal(ke, (vocab, dim), jnp.float32),
-            "norm": L.rmsnorm_init(dim), "head": _w(kh, dim, vocab)})
-
-    def init(rng):
-        # One program a layer, not one of the whole tree: inside it a leaf's
-        # draw, scale and cast are one fusion, so the float32 draw is never
-        # written out, and a layer's temporaries are gone before the next
-        # layer's are made.
-        ks = jax.random.split(rng, 2 * layers + 2)
-        one_block = jax.jit(block_init, static_argnums=0)
-        params = jax.jit(ends_init)(ks[0], ks[1])
-        params["layers"] = [
-            one_block(i < first_dense, ks[2 * i + 2], ks[2 * i + 3])
-            for i in range(layers)]
-        n_moe = max(0, layers - first_dense)
-        aux = {"expert_tokens": jnp.zeros((n_moe, experts_held), jnp.int32),
-               "expert_absent": jnp.zeros((n_moe,), jnp.int32)}
-        return params, {"aux": aux} if n_moe else {}
-
-    def apply(params, state, x, train: bool = False):
-        with jax.named_scope(P.EMBED):
-            # ids ride the float32 instance contract (exact under 2^24)
-            ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
-                           vocab - 1).astype(jnp.int32)
-            dtype = params["head"].dtype
-            # a float32 stream beside branches in ``dtype``, as Kimi-Linear
-            h = params["embed"][ids].astype(jnp.float32)
+    def rotary(seq):
         _note("rotary", "yarn")
-        rotary = R.rotary_tables(x.shape[1], inv_freq, attention_factor)
-        tokens, absent = [], []
-        for blk in params["layers"]:
-            with jax.named_scope(P.NORM):
-                y = L.rmsnorm(blk["norm1"], h, eps).astype(dtype)
-            with jax.named_scope(P.MIX_ELEMENTWISE):  # but ``_proj``, loops
-                y = mla_mixer(blk["mixer"], y, heads, nope, rope, v_dim,
-                              kv_rank, eps, rotary=rotary,
-                              scale=softmax_scale)
-            with jax.named_scope(P.NORM):
-                h = h + y.astype(jnp.float32)
-                y = L.rmsnorm(blk["norm2"], h, eps)
-            if "router" in blk["ffn"]:  # routes from the float32 stream
-                y, t, a = topk_moe_layer(
-                    blk["ffn"], y, top_k, first_expert=first_expert,
-                    router="sigmoid", renormalize=True, scale=routed_scale,
-                    tile=expert_tile)
-                tokens.append(t)
-                absent.append(a)
-            else:
-                with jax.named_scope(P.PROJ):
-                    y = L.swiglu(blk["ffn"], y.astype(dtype))
-            with jax.named_scope(P.NORM):
-                h = h + y.astype(jnp.float32)
-        with jax.named_scope(P.HEAD):
-            last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
-            logits = L.matmul(last, params["head"])
-        if not tokens:
-            return logits, state
-        return logits, {**state, "aux": {
-            "expert_tokens": jnp.stack(tokens),
-            "expert_absent": jnp.stack(absent)}}
+        return R.rotary_tables(seq, inv_freq, attention_factor)
 
-    return ModelDef(
-        name, (seq,), vocab, init, apply, max_rows=max_rows,
-        input_dtype="float32",
+    mixer = S.Branch(
+        "norm1", "mixer", mixer_init,
+        lambda p, y, tables: mla_mixer(p, y, heads, nope, rope, v_dim,
+                                       kv_rank, eps, rotary=tables,
+                                       scale=softmax_scale))
+    dense = S.Branch(
+        "norm2", "ffn",
+        lambda key: S.scaled(L.swiglu_init(key, dim, dense_width),
+                             {"down": branch}),
+        lambda p, y, _: L.swiglu(p, y), scope=P.PROJ, cast="scope")
+    # an untrained bias of the size of the gaps between sorted scores,
+    # N(0, 0.01^2), so that it matters and the held share stays near its
+    # expectation (PERF.md section 6, PR 36)
+    experts = S.experts(
+        "norm2", "ffn",
+        lambda key: S.scaled(topk_moe_init(
+            key, dim, expert_width, n_experts, experts_held),
+            {"router_bias": 0.2, "down": branch}),
+        held=experts_held, top_k=top_k, first_expert=first_expert,
+        scale=routed_scale, tile=expert_tile)
+    return S.token_scorer(
+        name, num_classes, input_shape,
+        tuple((mixer, dense if i < first_dense else experts)
+              for i in range(layers)),
+        dim=dim, eps=eps, max_rows=max_rows, context=rotary,
+        param_dtype=param_dtype,
         hyper={"dim": dim, "layers": layers, "heads": heads,
                "q_rank": q_rank, "kv_rank": kv_rank, "n_experts": n_experts,
                "top_k": top_k, "experts_held": experts_held,
                "first_expert": first_expert, "rope_theta": rope_theta,
-               "yarn_factor": yarn_factor, "input_shape": (seq,),
-               "num_classes": vocab})
+               "yarn_factor": yarn_factor})
 
 
 @register("kimi_k2_6")

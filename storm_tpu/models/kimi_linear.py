@@ -22,7 +22,11 @@ layer. Ids are taken from the held slice and the distribution is over it.
 
 The step's auxiliaries ride ``new_state["aux"]``: per expert layer the tokens
 routed to each held expert and the assignments that fell on absent ones. The
-engine fetches them with the predictions (``infer/engine.py``).
+engine fetches them with the predictions (``infer/engine.py``) and
+``parallel/moe.py observe_expert_counts`` reads them into the registry. The
+skeleton (ids, the float32 stream, the head, the counters' way out) is
+:func:`storm_tpu.models.scorer.token_scorer`'s; this file holds the mixers
+and the plan.
 
 What the published ``config.json`` does not fix is set as the released code
 sets it and listed under ``assumed`` in the benchmark's configuration file:
@@ -37,28 +41,15 @@ import math
 import jax
 import jax.numpy as jnp
 
+from storm_tpu.models import scorer as S
 from storm_tpu.models.registry import ModelDef, register
+from storm_tpu.models.scorer import _proj, _w
 from storm_tpu.ops import kda
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import causal_attention
 from storm_tpu.ops.rope import rotate_halves
-from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
-
-
-def _w(rng, fan_in: int, fan_out: int):
-    return L.lecun_normal(rng, (fan_in, fan_out), fan_in)
-
-
-def _proj(x, *ws):
-    """``x`` through the weights in turn, named a projection in a device
-    trace (ops/parts.py: the innermost name is the operation's, so a mixer
-    is ``mix.elementwise`` but for its products and the loops, which name
-    themselves)."""
-    with jax.named_scope(P.PROJ):
-        for w in ws:
-            x = L.matmul(x, w)
-        return x
+from storm_tpu.parallel.moe import topk_moe_init
 
 
 def kda_mixer_init(rng, dim: int, heads: int, head_dim: int,
@@ -203,104 +194,46 @@ def build_kimi_linear(
     attention where ``i`` is a multiple of ``full_attention_every``, KDA
     otherwise; dense feed-forward up to ``first_dense``, experts after) over
     ``num_classes`` rows of the vocabulary."""
-    (seq,) = input_shape
-    vocab = num_classes
     # Every residual branch's output projection starts smaller by the root of
     # the number of branches in the whole published stack (two a layer), as
     # GPT-2 and Megatron start a deep stack: the stream then keeps the scale
     # of the embedding whatever the depth, and no one branch (nor one expert
     # a rounding sent a token to) outweighs it.
     branch = (2 * published_layers) ** -0.5
-
-    def is_mla(i):  # layers count from 1
-        return i % full_attention_every == 0
-
-    def init(rng):
-        ks = jax.random.split(rng, 2 * layers + 2)
-        blocks = []
-        for i in range(1, layers + 1):
-            km, kf = ks[2 * i], ks[2 * i + 1]
-            mixer = (mla_mixer_init(km, dim, mla_heads, nope, rope, v_dim,
-                                    kv_rank) if is_mla(i) else
-                     kda_mixer_init(km, dim, kda_heads, kda_head_dim, conv))
-            mixer["o"] = mixer["o"] * branch
-            if i <= first_dense:
-                ffn = L.swiglu_init(kf, dim, dense_width)
-                ffn["down"] = ffn["down"] * branch
-            else:
-                ffn = topk_moe_init(kf, dim, expert_width, n_experts,
-                                    experts_held)
-                for part in (ffn["experts"], ffn["shared"]):
-                    part["down"] = part["down"] * branch
-            blocks.append({"norm1": L.rmsnorm_init(dim), "mixer": mixer,
-                           "norm2": L.rmsnorm_init(dim), "ffn": ffn})
-        params = {
-            "embed": jax.random.normal(ks[0], (vocab, dim), jnp.float32),
-            "layers": blocks,
-            "norm": L.rmsnorm_init(dim),
-            "head": _w(ks[1], dim, vocab),
-        }
-        n_moe = max(0, layers - first_dense)
-        # what a step counts on the device, in the state in and out
-        aux = {"expert_tokens": jnp.zeros((n_moe, experts_held), jnp.int32),
-               "expert_absent": jnp.zeros((n_moe,), jnp.int32)}
-        return params, {"aux": aux} if n_moe else {}
-
-    def apply(params, state, x, train: bool = False):
-        with jax.named_scope(P.EMBED):
-            # ids ride the float32 instance contract (exact under 2^24)
-            ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
-                           vocab - 1).astype(jnp.int32)
-            dtype = params["head"].dtype
-            # The stream is float32 whatever the compute type: a bfloat16
-            # stream is rounded at each of its ten adds, and a router reading
-            # it sends three times as many tokens to another expert than the
-            # reference does. The branches compute in ``dtype``.
-            h = params["embed"][ids].astype(jnp.float32)
-        tokens, absent = [], []
-        for i, blk in enumerate(params["layers"], start=1):
-            with jax.named_scope(P.NORM):
-                y = L.rmsnorm(blk["norm1"], h, eps).astype(dtype)
-            with jax.named_scope(P.MIX_ELEMENTWISE):  # but ``_proj``, loops
-                if is_mla(i):
-                    y = mla_mixer(blk["mixer"], y, mla_heads, nope, rope,
-                                  v_dim, kv_rank, eps)
-                else:
-                    y = kda_mixer(blk["mixer"], y, kda_heads, kda_head_dim,
-                                  chunk, eps)
-            with jax.named_scope(P.NORM):
-                h = h + y.astype(jnp.float32)
-                y = L.rmsnorm(blk["norm2"], h, eps)
-            if "router" in blk["ffn"]:  # routes from the float32 stream
-                y, t, a = topk_moe_layer(
-                    blk["ffn"], y, top_k, first_expert=first_expert,
-                    router="sigmoid", renormalize=True, scale=routed_scale,
-                    tile=expert_tile)
-                tokens.append(t)
-                absent.append(a)
-            else:
-                with jax.named_scope(P.PROJ):
-                    y = L.swiglu(blk["ffn"], y.astype(dtype))
-            with jax.named_scope(P.NORM):
-                h = h + y.astype(jnp.float32)
-        with jax.named_scope(P.HEAD):
-            last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
-            logits = L.matmul(last, params["head"])
-        if not tokens:
-            return logits, state
-        return logits, {**state, "aux": {
-            "expert_tokens": jnp.stack(tokens),
-            "expert_absent": jnp.stack(absent)}}
-
-    return ModelDef(
-        name, (seq,), vocab, init, apply, max_rows=max_rows,
-        input_dtype="float32",
+    kda_branch = S.Branch(
+        "norm1", "mixer",
+        lambda key: S.scaled(kda_mixer_init(
+            key, dim, kda_heads, kda_head_dim, conv), {"o": branch}),
+        lambda p, y, _: kda_mixer(p, y, kda_heads, kda_head_dim, chunk, eps))
+    mla_branch = S.Branch(
+        "norm1", "mixer",
+        lambda key: S.scaled(mla_mixer_init(
+            key, dim, mla_heads, nope, rope, v_dim, kv_rank), {"o": branch}),
+        lambda p, y, _: mla_mixer(p, y, mla_heads, nope, rope, v_dim,
+                                  kv_rank, eps))
+    dense = S.Branch(
+        "norm2", "ffn",
+        lambda key: S.scaled(L.swiglu_init(key, dim, dense_width),
+                             {"down": branch}),
+        lambda p, y, _: L.swiglu(p, y), scope=P.PROJ, cast="scope")
+    experts = S.experts(
+        "norm2", "ffn",
+        lambda key: S.scaled(topk_moe_init(
+            key, dim, expert_width, n_experts, experts_held),
+            {"down": branch}),
+        held=experts_held, top_k=top_k, first_expert=first_expert,
+        scale=routed_scale, tile=expert_tile)
+    return S.token_scorer(
+        name, num_classes, input_shape,
+        tuple((mla_branch if i % full_attention_every == 0 else kda_branch,
+               dense if i <= first_dense else experts)
+              for i in range(1, layers + 1)),  # layers count from 1
+        dim=dim, eps=eps, max_rows=max_rows,
         hyper={"dim": dim, "layers": layers, "kda_heads": kda_heads,
                "kda_head_dim": kda_head_dim, "mla_heads": mla_heads,
                "n_experts": n_experts, "top_k": top_k,
                "experts_held": experts_held, "first_expert": first_expert,
-               "chunk": chunk, "input_shape": (seq,),
-               "num_classes": vocab})
+               "chunk": chunk})
 
 
 @register("kimi_linear_48b")

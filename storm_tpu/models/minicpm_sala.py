@@ -25,9 +25,8 @@ layer:
 
 **The cut** is in depth alone: the builder is told which published layers
 it holds (the first ``len(mixers)``); every width, every head and the whole
-vocabulary are here. The load is ``models/kimi_k2.py``'s: a layer's leaves
-are made by one small program that draws, scales and casts each in one pass,
-handed over in ``param_dtype``.
+vocabulary are here. The load is ``models/scorer.py``'s in ``param_dtype``,
+a program a layer.
 
 **A step's temporaries.** A row is thousands of tokens at a 16,384-wide
 feed-forward, so the feed-forward runs a row at a time (one loop under
@@ -38,7 +37,9 @@ in the benchmark's configuration file: the sparse attention's seven sizes,
 the lightning decay, where the weights start.
 
 The step's counters ride ``new_state["aux"]``: ``sparse_keys_read`` and
-``sparse_keys_skipped``, one number a ``minicpm4`` layer.
+``sparse_keys_skipped``, one number a ``minicpm4`` layer, read on the host by
+``ops/sparse_attention.py observe_key_counts``. The skeleton is
+:func:`storm_tpu.models.scorer.token_scorer`'s; this file holds the plan.
 """
 
 from __future__ import annotations
@@ -50,24 +51,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from storm_tpu.models import scorer as S
 from storm_tpu.models.registry import ModelDef, register
+from storm_tpu.models.scorer import _proj, _w
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
 from storm_tpu.ops import rope as R
-from storm_tpu.ops.sparse_attention import block_sparse_attention
+from storm_tpu.ops.sparse_attention import (block_sparse_attention,
+                                            observe_key_counts)
 from storm_tpu.ops.ssd import ssd_chunked
 
 KINDS = ("minicpm4", "lightning-attn")
-
-
-def _w(rng, fan_in: int, fan_out: int):
-    return L.lecun_normal(rng, (fan_in, fan_out), fan_in)
-
-
-def _proj(x, w):
-    """A product with weights, named a projection in a device trace."""
-    with jax.named_scope(P.PROJ):
-        return L.matmul(x, w)
 
 
 def _gated_out(p: dict, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
@@ -188,102 +182,47 @@ def build_minicpm_sala(
     ``sparse``: the ``minicpm4`` mixer's sizes (``kernel_size``,
     ``kernel_stride``, ``block_size``, ``topk``, ``init_blocks``,
     ``window_size``, ``dense_len``)."""
-    (seq,) = input_shape
-    vocab = num_classes
     if not mixers or set(mixers) - set(KINDS):
         raise ValueError(f"mixer_types {mixers!r}: the kinds are {KINDS!r}")
-    depth = scale_depth / math.sqrt(published_layers)
-    logit_scale = dim_model_base / dim
     inv_freq = rope_theta ** (-2.0 * np.arange(lightning_head_dim // 2)
                               / lightning_head_dim)  # plain rotary, float64
-    n_sparse = sum(kind == "minicpm4" for kind in mixers)
+    sparse_mixer = S.Branch(
+        "norm1", "mixer",
+        lambda key: minicpm4_mixer_init(key, dim, heads, kv_heads, head_dim),
+        lambda p, y, _: minicpm4_mixer(p, y, heads, kv_heads, head_dim, eps,
+                                       sparse),
+        counts=(("sparse_keys_read", ()), ("sparse_keys_skipped", ())),
+        observe=observe_key_counts)
 
-    def served(tree):
-        return jax.tree.map(lambda a: a.astype(param_dtype), tree)
+    def lightning_init(key):  # one for every layer: one program of the load
+        return lightning_mixer_init(key, dim, lightning_heads,
+                                    lightning_head_dim)
 
-    def block_init(kind: str, km, kf):
-        mixer = (minicpm4_mixer_init(km, dim, heads, kv_heads, head_dim)
-                 if kind == "minicpm4" else
-                 lightning_mixer_init(km, dim, lightning_heads,
-                                      lightning_head_dim))
-        return served({"norm1": L.rmsnorm_init(dim), "mixer": mixer,
-                       "norm2": L.rmsnorm_init(dim),
-                       "ffn": L.swiglu_init(kf, dim, ffn_width)})
+    def lightning(layer: int) -> S.Branch:
+        slopes = lightning_slopes(lightning_heads, layer, published_layers)
+        return S.Branch(
+            "norm1", "mixer", lightning_init,
+            lambda p, y, rotary: lightning_mixer(
+                p, y, lightning_heads, lightning_head_dim, eps, rotary,
+                slopes, chunk))
 
-    def ends_init(ke, kh):
-        # muP's multipliers stand against weights trained under them; a
-        # draw that stands for such a checkpoint starts the stream and the
-        # logits where the other language models' start (N(0, 1) a channel,
-        # LeCun's head): the embedding over ``scale_emb``, the head times
-        # ``hidden / dim_model_base``
-        return served({
-            "embed": jax.random.normal(ke, (vocab, dim), jnp.float32)
-            / scale_emb,
-            "norm": L.rmsnorm_init(dim),
-            "head": _w(kh, dim, vocab) / logit_scale})
-
-    def init(rng):
-        # one program a layer, as models/kimi_k2.py: no float32 leaf is
-        # written out, a layer's temporaries are gone before the next's
-        ks = jax.random.split(rng, 2 * len(mixers) + 2)
-        one_block = jax.jit(block_init, static_argnums=0)
-        params = jax.jit(ends_init)(ks[0], ks[1])
-        params["layers"] = [one_block(kind, ks[2 * i + 2], ks[2 * i + 3])
-                            for i, kind in enumerate(mixers)]
-        aux = {"sparse_keys_read": jnp.zeros((n_sparse,), jnp.int32),
-               "sparse_keys_skipped": jnp.zeros((n_sparse,), jnp.int32)}
-        return params, {"aux": aux} if n_sparse else {}
-
-    def apply(params, state, x, train: bool = False):
-        with jax.named_scope(P.EMBED):
-            # ids ride the float32 instance contract (exact under 2^24)
-            ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
-                           vocab - 1).astype(jnp.int32)
-            dtype = params["head"].dtype
-            # a float32 stream beside branches in ``dtype``
-            h = params["embed"][ids].astype(jnp.float32) * scale_emb
-        rotary = R.rotary_tables(x.shape[1], inv_freq)
-        read, skipped = [], []
-        # (``mixer``, not ``kind``: the protocol lint reads ``kind == "..."``
-        # in a function called ``apply`` as a journal's fold arm)
-        for layer, (mixer, blk) in enumerate(zip(mixers, params["layers"])):
-            with jax.named_scope(P.NORM):
-                y = L.rmsnorm(blk["norm1"], h, eps).astype(dtype)
-            with jax.named_scope(P.MIX_ELEMENTWISE):  # but ``_proj``, loops
-                if mixer == "minicpm4":
-                    y, r, s = minicpm4_mixer(blk["mixer"], y, heads,
-                                             kv_heads, head_dim, eps, sparse)
-                    read.append(r)
-                    skipped.append(s)
-                else:
-                    y = lightning_mixer(
-                        blk["mixer"], y, lightning_heads, lightning_head_dim,
-                        eps, rotary, lightning_slopes(
-                            lightning_heads, layer, published_layers), chunk)
-            with jax.named_scope(P.NORM):
-                h = h + depth * y.astype(jnp.float32)
-                y = L.rmsnorm(blk["norm2"], h, eps).astype(dtype)
-            with jax.named_scope(P.PROJ):
-                y = _rows(lambda row: L.swiglu(blk["ffn"], row), y)
-            with jax.named_scope(P.NORM):
-                h = h + depth * y.astype(jnp.float32)
-        with jax.named_scope(P.HEAD):
-            last = L.rmsnorm(params["norm"], h[:, -1], eps) * logit_scale
-            logits = L.matmul(last.astype(dtype), params["head"])
-        if not read:
-            return logits, state
-        return logits, {**state, "aux": {
-            "sparse_keys_read": jnp.stack(read),
-            "sparse_keys_skipped": jnp.stack(skipped)}}
-
-    return ModelDef(
-        name, (seq,), vocab, init, apply, max_rows=max_rows,
-        input_dtype="float32",
+    ffn = S.Branch(
+        "norm2", "ffn", lambda key: L.swiglu_init(key, dim, ffn_width),
+        lambda p, y, _: _rows(lambda row: L.swiglu(p, row), y), scope=P.PROJ)
+    return S.token_scorer(
+        name, num_classes, input_shape,
+        tuple((sparse_mixer if kind == "minicpm4" else lightning(layer), ffn)
+              for layer, kind in enumerate(mixers)),
+        dim=dim, eps=eps, max_rows=max_rows,
+        # muP's three multipliers
+        scale_emb=scale_emb, logit_scale=dim_model_base / dim,
+        residual=scale_depth / math.sqrt(published_layers),
+        context=lambda seq: R.rotary_tables(seq, inv_freq),
+        param_dtype=param_dtype,
         hyper={"mixers": tuple(mixers), "dim": dim, "heads": heads,
                "kv_heads": kv_heads, "head_dim": head_dim,
                "lightning_heads": lightning_heads, "chunk": chunk,
-               "sparse": dict(sparse), "rope_theta": rope_theta,
-               "input_shape": (seq,), "num_classes": vocab})
+               "sparse": dict(sparse), "rope_theta": rope_theta})
 
 
 # InfLLM v2's sizes as the MiniCPM4 report gives them (arXiv:2506.07900)

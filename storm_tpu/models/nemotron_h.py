@@ -25,7 +25,9 @@ holds (``experts_held`` from ``first_expert``, ``num_classes`` rows of
 embedding and of head) and which letters of the pattern; the router keeps
 its published width and its experts per token, and what the experts held
 elsewhere would add is left out. The step's counters ride
-``new_state["aux"]`` under the names the queue observes.
+``new_state["aux"]`` to ``parallel/moe.py observe_expert_counts``. The
+skeleton is :func:`storm_tpu.models.scorer.token_scorer`'s; this file holds
+the mixers and the plan, a branch a letter.
 
 What the published ``config.json`` does not fix is listed under ``assumed``
 in the benchmark's configuration file: the weights' start (a mixer's
@@ -42,28 +44,16 @@ import math
 import jax
 import jax.numpy as jnp
 
+from storm_tpu.models import scorer as S
 from storm_tpu.models.registry import ModelDef, register
+from storm_tpu.models.scorer import _proj, _w
 from storm_tpu.ops import kda
 from storm_tpu.ops import layers as L
-from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import causal_attention
 from storm_tpu.ops.ssd import ssd_chunked
-from storm_tpu.parallel.moe import topk_moe_init, topk_moe_layer
+from storm_tpu.parallel.moe import topk_moe_init
 
 KINDS = "ME*"  # Mamba-2, experts, attention
-
-
-def _w(rng, fan_in: int, fan_out: int):
-    return L.lecun_normal(rng, (fan_in, fan_out), fan_in)
-
-
-def _proj(x, w):
-    """A product with weights, named a projection in a device trace
-    (ops/parts.py: the innermost name is the operation's, so a mixer is
-    ``mix.elementwise`` but for its products and the loops, which name
-    themselves)."""
-    with jax.named_scope(P.PROJ):
-        return L.matmul(x, w)
 
 
 def mamba_mixer_init(rng, dim: int, heads: int, head_dim: int, groups: int,
@@ -166,8 +156,6 @@ def build_nemotron_h(
     """The layers that ``pattern`` spells (the held letters of the published
     ``hybrid_override_pattern``, ``published_layers`` long) over
     ``num_classes`` rows of the vocabulary."""
-    (seq,) = input_shape
-    vocab = num_classes
     if not pattern or set(pattern) - set(KINDS):
         raise ValueError(f"pattern {pattern!r}: its letters are {KINDS!r}")
     # Where the weights start. Every projection inside a mixer at the
@@ -179,96 +167,46 @@ def build_nemotron_h(
     # expert a rounding sent a token to) outweighs it: at LeCun's scale a
     # row whose last token went to another expert moved by more than
     # float8 moves most rows (PERF.md section 6, PR 36). The selection
-    # bias N(0, 0.01^2): it still decides which experts many tokens take,
-    # and a random one of 0.05 unbalances the experts 3.5-fold, which a
-    # trained one is there to prevent.
+    # bias N(0, 0.01^2), a fifth of ``topk_moe_init``'s: it still decides
+    # which experts many tokens take, and a random one of 0.05 unbalances
+    # the experts 3.5-fold, which a trained one is there to prevent.
     inner, branch = 3 ** -0.5, (3 * published_layers) ** -0.5
-    bias = 0.2  # of ``topk_moe_init``'s N(0, 0.05^2)
-    n_moe = pattern.count("E")
-
-    def init(rng):
-        ks = jax.random.split(rng, len(pattern) + 2)
-        blocks = []
-        for letter, key in zip(pattern, ks[2:]):
-            if letter == "M":
-                mixer = mamba_mixer_init(key, dim, mamba_heads,
-                                         mamba_head_dim, groups, state, conv)
-                scaled = {"in_proj": inner, "out_proj": branch}
-            elif letter == "*":
-                mixer = gqa_mixer_init(key, dim, heads, kv_heads, head_dim)
-                scaled = {"q": inner, "k": inner, "v": inner, "o": branch}
-            else:
-                mixer = topk_moe_init(key, dim, expert_width, n_experts,
-                                      experts_held, form="relu2",
-                                      shared_hidden=shared_width)
-                scaled = {"router_bias": bias}
-                for part in (mixer["experts"], mixer["shared"]):
-                    part["up"] = part["up"] * inner
-                    part["down"] = part["down"] * branch
-            for name, factor in scaled.items():
-                mixer[name] = mixer[name] * factor
-            blocks.append({"norm": L.rmsnorm_init(dim), "mixer": mixer})
-        params = {
-            "embed": jax.random.normal(ks[0], (vocab, dim), jnp.float32),
-            "layers": blocks,
-            "norm": L.rmsnorm_init(dim),
-            "head": _w(ks[1], dim, vocab),
-        }
-        # what a step counts on the device, in the state in and out
-        aux = {"expert_tokens": jnp.zeros((n_moe, experts_held), jnp.int32),
-               "expert_absent": jnp.zeros((n_moe,), jnp.int32)}
-        return params, {"aux": aux} if n_moe else {}
-
-    def apply(params, state_in, x, train: bool = False):
-        with jax.named_scope(P.EMBED):
-            # ids ride the float32 instance contract (exact under 2^24)
-            ids = jnp.clip(jnp.round(x.astype(jnp.float32)), 0,
-                           vocab - 1).astype(jnp.int32)
-            dtype = params["head"].dtype
-            # a float32 stream whatever the compute type, and a router that
-            # reads it unrounded (models/kimi_linear.py says why); the mixers
-            # compute in ``dtype``
-            h = params["embed"][ids].astype(jnp.float32)
-        tokens, absent = [], []
-        for letter, blk in zip(pattern, params["layers"]):
-            with jax.named_scope(P.NORM):
-                y = L.rmsnorm(blk["norm"], h, eps)
-            if letter == "M":
-                with jax.named_scope(P.MIX_ELEMENTWISE):  # but ``_proj``, loop
-                    y = mamba_mixer(blk["mixer"], y.astype(dtype),
-                                    mamba_heads, mamba_head_dim, groups,
-                                    state, chunk, eps)
-            elif letter == "*":
-                with jax.named_scope(P.MIX_ELEMENTWISE):
-                    y = gqa_mixer(blk["mixer"], y.astype(dtype), heads,
-                                  kv_heads, head_dim)
-            else:
-                y, t, a = topk_moe_layer(
-                    blk["mixer"], y, top_k, first_expert=first_expert,
-                    router="sigmoid", renormalize=True, scale=routed_scale,
-                    tile=expert_tile)
-                tokens.append(t)
-                absent.append(a)
-            with jax.named_scope(P.NORM):
-                h = h + y.astype(jnp.float32)
-        with jax.named_scope(P.HEAD):
-            last = L.rmsnorm(params["norm"], h[:, -1], eps).astype(dtype)
-            logits = L.matmul(last, params["head"])
-        if not tokens:
-            return logits, state_in
-        return logits, {**state_in, "aux": {
-            "expert_tokens": jnp.stack(tokens),
-            "expert_absent": jnp.stack(absent)}}
-
-    return ModelDef(
-        name, (seq,), vocab, init, apply, max_rows=max_rows,
-        input_dtype="float32",
+    # a mixer takes the float32 norm's cast under its own scope
+    kinds = {
+        "M": S.Branch(
+            "norm", "mixer",
+            lambda key: S.scaled(mamba_mixer_init(
+                key, dim, mamba_heads, mamba_head_dim, groups, state, conv),
+                {"in_proj": inner, "out_proj": branch}),
+            lambda p, y, _: mamba_mixer(p, y, mamba_heads, mamba_head_dim,
+                                        groups, state, chunk, eps),
+            cast="scope"),
+        "*": S.Branch(
+            "norm", "mixer",
+            lambda key: S.scaled(gqa_mixer_init(
+                key, dim, heads, kv_heads, head_dim),
+                {"q": inner, "k": inner, "v": inner, "o": branch}),
+            lambda p, y, _: gqa_mixer(p, y, heads, kv_heads, head_dim),
+            cast="scope"),
+        "E": S.experts(
+            "norm", "mixer",
+            lambda key: S.scaled(topk_moe_init(
+                key, dim, expert_width, n_experts, experts_held,
+                form="relu2", shared_hidden=shared_width),
+                {"router_bias": 0.2, "up": inner, "down": branch}),
+            held=experts_held, top_k=top_k, first_expert=first_expert,
+            scale=routed_scale, tile=expert_tile),
+    }
+    return S.token_scorer(
+        name, num_classes, input_shape,
+        tuple((kinds[letter],) for letter in pattern),
+        dim=dim, eps=eps, max_rows=max_rows,
         hyper={"pattern": pattern, "dim": dim, "mamba_heads": mamba_heads,
                "mamba_head_dim": mamba_head_dim, "groups": groups,
                "state": state, "heads": heads, "kv_heads": kv_heads,
                "head_dim": head_dim, "n_experts": n_experts, "top_k": top_k,
                "experts_held": experts_held, "first_expert": first_expert,
-               "chunk": chunk, "input_shape": (seq,), "num_classes": vocab})
+               "chunk": chunk})
 
 
 @register("nemotron_3_nano_30b")

@@ -52,6 +52,11 @@ class ModelDef:
     # type. Token ids ride the float32 instance contract exactly (under
     # 2^24) and would not survive a cast to bfloat16.
     input_dtype: Any = None
+    # ``observe_aux(registry, component id, aux)``: reads what a step counted
+    # on the device (``new_state["aux"]``, fetched with the predictions) into
+    # the registry. Set by the builder of a model that counts (models/
+    # scorer.py composes its branches' readers); None: it counts nothing.
+    observe_aux: Any = None
 
 
 _BUILDERS: Dict[str, Callable[..., ModelDef]] = {}

@@ -312,3 +312,12 @@ def block_sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     read, skipped = keys_read(picked, selection["block_size"])
     return (sparse_attention(q, k, v, picked, scale,
                              selection["block_size"]), read, skipped)
+
+
+def observe_key_counts(metrics, cid: str, read, skipped) -> None:
+    """What :func:`block_sparse_attention` counted in one step, fetched to
+    the host (a number a layer each), into the registry under ``cid``: the
+    keys its queries' picked blocks hold up to their positions and the
+    causal keys they leave out, as two counters."""
+    metrics.counter(cid, "sparse_keys_read").inc(int(read.sum()))
+    metrics.counter(cid, "sparse_keys_skipped").inc(int(skipped.sum()))

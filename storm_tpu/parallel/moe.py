@@ -396,6 +396,18 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         return y.astype(x.dtype).reshape(shape), counts, absent
 
 
+def observe_expert_counts(metrics, cid: str, tokens, absent) -> None:
+    """What :func:`topk_moe_layer` counted in one step, fetched to the host
+    (``tokens`` ``(layers, held)``, ``absent`` ``(layers,)``), into the
+    registry under ``cid``: the assignments held and absent as two counters,
+    and once a layer the busiest held expert over the mean."""
+    metrics.counter(cid, "expert_assignments_held").inc(int(tokens.sum()))
+    metrics.counter(cid, "expert_assignments_absent").inc(int(absent.sum()))
+    load = metrics.histogram(cid, "expert_tokens_max_over_mean")
+    for layer in tokens:
+        load.observe(float(layer.max()) / max(float(layer.mean()), 1e-9))
+
+
 def moe_block_init(rng, dim: int, mlp_dim: int, num_heads: int, n_experts: int):
     """A transformer block whose MLP is an MoE: ln1/attn/ln2 as in the ViT
     block, MoE replacing the dense MLP."""

@@ -228,16 +228,17 @@ def test_keys_read_and_skipped_against_counts_by_hand():
 
 
 def test_the_queue_counts_the_keys_in_the_registry():
-    from storm_tpu.infer.continuous import ContinuousBatcher
+    """Through the model's own reader, which is all the queue calls
+    (tests/test_kimi_linear.py goes through the queue itself)."""
     from storm_tpu.runtime.metrics import MetricsRegistry
 
-    queue = ContinuousBatcher.__new__(ContinuousBatcher)
-    queue._metrics, queue._cid = MetricsRegistry(), "inference-bolt"
+    registry = MetricsRegistry()
+    observe = build_model("minicpm_sala_tiny").observe_aux
     for _ in range(2):
-        queue._observe_aux({
+        observe(registry, "inference-bolt", {
             "sparse_keys_read": np.asarray([2_000_000_000], np.int32),
             "sparse_keys_skipped": np.asarray([100, 23], np.int32)})
-    got = queue._metrics.snapshot()["inference-bolt"]
+    got = registry.snapshot()["inference-bolt"]
     assert got["sparse_keys_read"] == 4_000_000_000  # past int32: host sums
     assert got["sparse_keys_skipped"] == 246
     assert "expert_assignments_held" not in got
