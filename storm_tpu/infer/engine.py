@@ -698,6 +698,9 @@ class InferenceEngine:
             with jax.named_scope(HEAD):  # a part's name in a device trace
                 logits = logits.astype(jnp.float32)
                 out = jax.nn.softmax(logits, axis=-1) if softmax else logits
+                # several prediction heads (models/scorer.py): a softmax a
+                # head, laid end to end in the one row a record's answer is
+                out = out.reshape(out.shape[0], -1)
             return (out, new_state["aux"]) if has_aux else out
 
         out_shardings = ((out_shard, replicated(self.mesh)) if has_aux

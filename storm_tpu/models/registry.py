@@ -74,6 +74,7 @@ def _load_builtin() -> None:
     # Import model modules lazily so registration happens on demand.
     from storm_tpu.models import (  # noqa: F401
         chartiny,
+        evabyte,
         kimi_k2,
         kimi_linear,
         lenet,

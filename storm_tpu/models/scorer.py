@@ -1,5 +1,6 @@
 """A scorer of token records, written once: a window of token ids in, the
-next-token distribution at its last position out.
+next-token distribution at its last position out (``heads`` of them, the
+next tokens' one after another, where the model predicts several).
 
 ids -> embedding (times ``scale_emb``) -> a float32 stream ``h`` -> for each
 block, for each of its branches, ``h += residual * branch(RMSNorm(h))`` -> the
@@ -93,8 +94,13 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                  max_rows: int, scale_emb: float = 1.0,
                  residual: float = 1.0, logit_scale: float = 1.0,
                  context: Optional[Callable] = None,
-                 param_dtype=None) -> ModelDef:
+                 param_dtype=None, heads: int = 1) -> ModelDef:
     """The model of ``blocks`` over ``num_classes`` rows of the vocabulary.
+    With ``heads`` prediction heads ``num_classes`` is what an answer holds,
+    ``heads`` distributions over ``num_classes / heads`` rows of the
+    vocabulary laid end to end: the embedding has the vocabulary's rows, the
+    head ``num_classes`` columns, and the logits leave as ``(B, heads,
+    vocabulary)`` for the engine's softmax over the last axis.
     ``context(seq)``: what every branch is handed, made once a step.
     ``param_dtype`` None: ``init`` makes the whole tree in float32, leaf by
     leaf; a type: each leaf is handed over in it as a checkpoint of that type
@@ -104,7 +110,10 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
     next layer's are made (a float32 twin of 3.5 B parameters does not fit
     beside them). The values are those ``astype`` of the float32 draw gives."""
     (seq,) = input_shape
-    vocab = num_classes
+    vocab, rest = divmod(num_classes, heads)
+    if rest:
+        raise ValueError(f"{num_classes} classes are not {heads} heads over "
+                         "one vocabulary")
     f32 = jnp.float32
     counted: dict = {}  # a count's key in ``aux`` -> its shape, a layer each
     readers: dict = {}  # who reads -> the keys it is handed
@@ -128,7 +137,7 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
         # every other model's start (N(0, 1) a channel, LeCun's head): the
         # embedding over ``scale_emb``, the head over ``logit_scale``.
         embed = jax.random.normal(ke, (vocab, dim), f32)
-        head = _w(kh, dim, vocab)
+        head = _w(kh, dim, num_classes)
         return served({
             "embed": embed if scale_emb == 1 else embed / scale_emb,
             "norm": L.rmsnorm_init(dim),
@@ -189,6 +198,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
             if logit_scale != 1:
                 last = last * logit_scale
             logits = L.matmul(last.astype(dtype), params["head"])
+            if heads != 1:
+                logits = logits.reshape(-1, heads, vocab)
         if not counts:
             return logits, state
         return logits, {**state, "aux": {
@@ -201,7 +212,7 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
             observe(metrics, cid, *(aux[key] for key in keys))
 
     return ModelDef(
-        name, (seq,), vocab, init, apply, max_rows=max_rows,
+        name, (seq,), num_classes, init, apply, max_rows=max_rows,
         input_dtype="float32",
-        hyper={**hyper, "input_shape": (seq,), "num_classes": vocab},
+        hyper={**hyper, "input_shape": (seq,), "num_classes": num_classes},
         observe_aux=observe_aux if readers else None)

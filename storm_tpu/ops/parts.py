@@ -26,6 +26,10 @@ MIX_ROPE = "mix.rope"  # ops/rope.py: the position tables and the turn
 MIX_SPARSE_SELECT = "mix.sparse_select"  # ops/sparse_attention.py: pooled
 # keys, the first pass over them, the blocks' scores, the top-k, the counts
 MIX_SPARSE_ATTENTION = "mix.sparse_attention"  # ... its second pass
+MIX_EVA_CHUNKS = "mix.eva_chunks"  # ops/eva_attention.py: the two poolings
+# of every chunk's keys and values into one summary each
+MIX_EVA_ATTENTION = "mix.eva_attention"  # ... the loop over rows: a window's
+# keys read exactly and every earlier summary, under one softmax
 MOE_ROUTE = "moe.route"  # router, top-k, the dispatch (one sort with its
 # payloads, a bisection for the counts, rows by comparison), zeroed buffer
 MOE_EXPERTS = "moe.experts"  # topk_moe_layer's loop over tiles
@@ -34,7 +38,7 @@ MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
 VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
               MOE_EXPERTS, MOE_COMBINE, MIX_SPARSE_SELECT,
-              MIX_SPARSE_ATTENTION)
+              MIX_SPARSE_ATTENTION, MIX_EVA_CHUNKS, MIX_EVA_ATTENTION)
 
 
 def part_of(op_name: str):

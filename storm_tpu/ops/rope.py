@@ -21,17 +21,30 @@ order the rotated channels lie in, as long as queries and keys share it:
 projections that make rotary channels once (:func:`halves_first`), and a step
 only turns contiguous halves (:func:`rotate_halves`): no activation is ever
 shuffled across lanes, and no weight after the load.
+
+**Merged heads.** Where every channel of every head turns, heads of whole
+lane tiles can be turned where they lie in a projection's ``(B, S, H * D)``
+(:func:`turn_merged`): the view ``(B, S, H, D)`` is another tiling on a TPU
+and half a head another still, each a copy of the whole array. A head's
+halves change places by one rotation of its 128 lanes.
 """
 
 from __future__ import annotations
 
 import math
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from storm_tpu.ops import parts as P
+from storm_tpu.ops.platform import note as _note
+from storm_tpu.ops.platform import one_device as _one_device
+from storm_tpu.ops.platform import use_pallas as _use_pallas
 
 
 def yarn_correction_range(dim: int, theta: float, original: int,
@@ -100,3 +113,76 @@ def rotate_halves(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
         return jnp.concatenate(
             [x[..., :first], (a * cos - b * sin).astype(x.dtype),
              (b * cos + a * sin).astype(x.dtype)], -1)
+
+
+_TURN_TILE = 128  # positions a step of the kernel: 1 MB of 4,096 lanes
+
+
+def turn_form(s: int, d: int) -> str:
+    """Which form :func:`turn_merged` is built with: ``"lanes"`` (the Pallas
+    kernel) on a TPU in a process with one device (a Mosaic call has no
+    partitioning rule, ops/platform.py ``one_device``), for whole tiles of
+    positions and heads of one lane tile, whose halves one rotation of the
+    lanes exchanges; ``"halves"`` (:func:`rotate_halves` on the view a head)
+    elsewhere."""
+    if (_use_pallas() and _one_device() and s % _TURN_TILE == 0
+            and d == 128):
+        return "lanes"
+    return "halves"
+
+
+def turn_merged(xs: tuple, cos: jnp.ndarray, sin: jnp.ndarray,
+                heads: int) -> tuple:
+    """Each ``x: (B, S, H * D)`` of ``xs`` with all ``D`` channels of every
+    head turned by the tables ``(S, D / 2)``, a head's channels lying
+    ``(evens, odds)``: what :func:`rotate_halves` gives on the view ``(B, S,
+    H, D)``, in float32 and back in ``x``'s type. One call for all of ``xs``
+    (a mixer's queries and keys)."""
+    b, s, merged = xs[0].shape
+    d = merged // heads
+    form = turn_form(s, d)
+    _note("rotary_turn", form)
+    if form == "halves":
+        return tuple(rotate_halves(x.reshape(b, s, heads, d), cos[:, None],
+                                   sin[:, None]).reshape(b, s, merged)
+                     for x in xs)
+    with jax.named_scope(P.MIX_ROPE):
+        # a head's lanes against (cos, cos) and its exchanged halves against
+        # (-sin, sin)
+        return _turn_lanes(tuple(xs), jnp.concatenate([cos, cos], -1),
+                           jnp.concatenate([-sin, sin], -1), heads=heads)
+
+
+def _turn_kernel(cos_ref, sin_ref, *refs, heads):
+    ins, outs = refs[:len(refs) // 2], refs[len(refs) // 2:]
+    d = cos_ref.shape[1]
+    cos, sin = cos_ref[...], sin_ref[...]
+    for x_ref, o_ref in zip(ins, outs):
+        for h in range(heads):
+            lanes = pl.ds(h * d, d)
+            x = x_ref[0, :, lanes].astype(jnp.float32)
+            o_ref[0, :, lanes] = (
+                x * cos + pltpu.roll(x, d // 2, 1) * sin).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "tile", "interpret"))
+def _turn_lanes(xs, cos2, sin2, *, heads, tile=_TURN_TILE, interpret=False):
+    b, s, merged = xs[0].shape
+    d = merged // heads
+
+    def table():
+        return pl.BlockSpec((tile, d), lambda r, i: (i, 0))
+
+    def wide():
+        return pl.BlockSpec((1, tile, merged), lambda r, i: (r, i, 0))
+
+    return tuple(pl.pallas_call(
+        functools.partial(_turn_kernel, heads=heads),
+        grid=(b, s // tile),
+        in_specs=[table(), table()] + [wide() for _ in xs],
+        out_specs=[wide() for _ in xs],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(cos2, sin2, *xs))

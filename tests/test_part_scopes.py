@@ -26,6 +26,8 @@ EXPECTED = {
     "minicpm_sala_tiny": (COMMON - {parts.MIX_ATTENTION}) | {
         parts.MIX_SPARSE_SELECT, parts.MIX_SPARSE_ATTENTION,
         parts.MIX_SSD_SCAN, parts.MIX_ROPE},
+    "evabyte_tiny": (COMMON - {parts.MIX_ATTENTION}) | {
+        parts.MIX_EVA_CHUNKS, parts.MIX_EVA_ATTENTION, parts.MIX_ROPE},
 }
 
 
@@ -92,7 +94,7 @@ def test_the_innermost_name_is_the_operations():
         "jit(fwd)/mix.elementwise/mix.sparse_select/while/body/top_k") == \
         parts.MIX_SPARSE_SELECT
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 15
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 17
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():

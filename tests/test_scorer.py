@@ -198,3 +198,53 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
     aux = jax.tree.map(np.asarray, new_state["aux"])
     model.observe_aux(None, "bolt", aux)
     assert sorted(seen) == [("a", "bolt", [3, 3]), ("b", "bolt", [[5, 5]])]
+
+
+# What the four models that were there lower to and load, as the parent of
+# PR 49 (which gave the skeleton its ``heads``) built them: the first 16 hex
+# digits of the sha256 of ``jax.jit(model.apply).lower(...)``'s text for two
+# rows, of the tree of shapes and types ``init`` makes and, for the toy sizes,
+# of its leaves' bytes from key 7. A change to the skeleton that is meant to
+# leave these models alone leaves these alone; one that is meant to change a
+# model says so by changing its line.
+PARENT = {
+    "kimi_linear_tiny": ("5a2c22ecb739bab1", "9c9231af39d81a3b",
+                         "d6d687f48a145060"),
+    "nemotron_h_tiny": ("acd582d75bbe6bdc", "ffb1a7d7c726fcef",
+                        "b8b23e69c062a671"),
+    "kimi_k2_tiny": ("ef7a62b97ed3fa3a", "4015722ee481843e",
+                     "3eee1654ad04fdad"),
+    "minicpm_sala_tiny": ("db98497c5c800057", "fbaec783e93f7071",
+                          "136d1265de921964"),
+    "kimi_linear_48b": ("e285544fe067b20b", "35c56c4e58dd4ac8"),
+    "nemotron_3_nano_30b": ("36c24243196b6a28", "32502e49d7fc6552"),
+    "kimi_k2_6": ("671681e849918856", "4a919d11ba374a7b"),
+    "minicpm_sala": ("edb57ac6cb1fceae", "31e93c80f04d610e"),
+}
+
+
+def _digest(*chunks):
+    import hashlib
+
+    h = hashlib.sha256()
+    for chunk in chunks:
+        h.update(chunk)
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_one_head_lowers_to_the_parents_text_and_makes_its_trees(name):
+    model = build_model(name)
+    params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2,) + tuple(model.input_shape), jnp.float32)
+    text = jax.jit(model.apply).lower(params, state, x).as_text()
+    tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)),
+                            (params, state)))
+    got = (_digest(text.encode()), _digest(tree.encode()))
+    if name.endswith("_tiny"):
+        made = model.init(jax.random.PRNGKey(7))
+        got += (_digest(*(np.asarray(leaf).tobytes()
+                          for leaf in jax.tree.leaves(made))),)
+    assert got == PARENT[name]
+    assert model.num_classes == params["head"].shape[1] \
+        == params["embed"].shape[0]

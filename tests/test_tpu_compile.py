@@ -388,3 +388,51 @@ def test_kda_mixer_compiles_with_every_branch_in_lanes(v5e, monkeypatch):
     # convolutions a kernel's calls, ``[8,64,8,8,32,128]``)
     assert re.findall(r"= (\w+)\[\d+(?:,\d+){4,}\]\S* copy\(",
                       entry) == ["bf16"]
+
+
+def test_eva_mixer_compiles_with_the_heads_merged_and_its_loops_found(
+        v5e, monkeypatch):
+    """EvaByte's mixer at its cell's step (4 windows of 16,384 into 4,096,
+    32 heads of 128, windows of 2,048 in chunks of 16, bfloat16) as one chip
+    builds it: three kernels (the turn in lanes, the summaries, the
+    attention), every array of a window's length ``(4, 16384, 4096)`` (no
+    view a head is ever made: each is a copy of half a gigabyte), and the
+    two loops over rows are what ``eva_chunks_roofline_share`` and
+    ``eva_attention_roofline_share`` look for, each its own and neither the
+    other's."""
+    import re
+
+    import storm_tpu.ops.eva_attention as ea
+    import storm_tpu.ops.rope as rope
+    from storm_tpu.models.evabyte import eva_mixer
+
+    for module in (ea, rope):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    assert ea.eva_form(16384, 128, 2048, 16) == "kernel"
+    assert ea.eva_form(16384 + 256, 128, 2048, 16) == "blocked"
+    assert ea.chunks_form(16384, 128, 16) == "kernel"
+    assert ea.chunks_form(16384, 64, 16) == "xla"
+    assert rope.turn_form(16384, 128) == "lanes"
+    assert rope.turn_form(16384, 64) == "halves"
+    square = _spec((4096, 4096), jnp.bfloat16, v5e)
+    pooling = _spec((32, 128), jnp.bfloat16, v5e)
+    p = {"q": square, "k": square, "v": square, "o": square, "mu": pooling,
+         "phi": pooling}
+    x = _spec((4, 16384, 4096), jnp.bfloat16, v5e)
+    tables = _spec((16384, 64), jnp.float32, v5e)
+    text = jax.jit(lambda p, x, cos, sin: eva_mixer(
+        p, x, 32, 128, 2048, 16, (cos, sin))).lower(
+        p, x, tables, tables).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert not re.search(r"\[4,16384,32,\d+\]", text)
+    loops = _loops(text)
+    assert len(loops) == 2
+    chunks, attention = (re.compile(_metric_pattern(
+        f"eva_{kind}_roofline_share")) for kind in ("chunks", "attention"))
+    assert [bool(chunks.search(line)) for line in loops] == [True, False]
+    assert [bool(attention.search(line)) for line in loops] == [False, True]
+    # a partial last window of whole tiles is the kernel's still (its keys
+    # are read as a whole window's); positions short of a tile are XLA's
+    assert ea.eva_form(16384 - 1024, 128, 2048, 16) == "kernel"
+    assert ea.eva_form(16384 - 1000, 128, 2048, 8) == "blocked"
