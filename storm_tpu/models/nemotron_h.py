@@ -50,7 +50,7 @@ from storm_tpu.models.scorer import _proj, _w
 from storm_tpu.ops import kda
 from storm_tpu.ops import layers as L
 from storm_tpu.ops.attention import causal_attention
-from storm_tpu.ops.ssd import ssd_chunked
+from storm_tpu.ops.ssd import ssd_chunked_columns
 from storm_tpu.parallel.moe import topk_moe_init
 
 KINDS = "ME*"  # Mamba-2, experts, attention
@@ -80,7 +80,6 @@ def mamba_mixer_init(rng, dim: int, heads: int, head_dim: int, groups: int,
 def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
                 groups: int, state: int, chunk: int,
                 eps: float) -> jnp.ndarray:
-    b, s, _ = x.shape
     f32 = jnp.float32
     inner, gn = heads * head_dim, groups * state
     # in_proj's columns are [z | x B C | dt]
@@ -89,14 +88,11 @@ def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
     xbc = kda.conv_silu(p["conv"], xbcdt)
     dt = jax.nn.softplus(xbcdt[..., inner + 2 * gn:].astype(f32)
                          + p["dt_bias"].astype(f32))
-    y = ssd_chunked(
-        xbc[..., :inner].reshape(b, s, heads, head_dim), dt,
-        -jnp.exp(p["a_log"].astype(f32)),
-        xbc[..., inner:inner + gn].reshape(b, s, groups, state),
-        xbc[..., inner + gn:].reshape(b, s, groups, state),
-        p["d"], chunk=chunk)
-    y = L.gated_group_rmsnorm(p["norm"], y.reshape(b, s, inner), z, groups,
-                              eps)
+    # x | B | C whole, as the convolution wrote them: the scan's loop takes
+    # the columns apart a chunk at a time
+    y = ssd_chunked_columns(xbc, dt, -jnp.exp(p["a_log"].astype(f32)),
+                            p["d"], groups, state, chunk)
+    y = L.gated_group_rmsnorm(p["norm"], y, z, groups, eps)
     return _proj(y, p["out_proj"])
 
 

@@ -374,6 +374,90 @@ def check_short_conv(interpret: bool = False) -> List[dict]:
     return rows
 
 
+def ssd_recurrence(x, dt, a, b, c, d):
+    """The state-space layer's definition, token by token: decay, write,
+    read, skip (``ops/ssd.py``'s first two equations). ``x: (B, S, H, P)``,
+    ``dt: (B, S, H)``, ``a, d: (H,)``, ``b, c: (B, S, G, N)``; each head
+    reads its group's ``B`` and ``C``; the state has the type of ``x``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    h, g = x.shape[2], b.shape[2]
+
+    def token(state, xs):  # (B, H, P, N)
+        x_t, dt_t, b_t, c_t = xs
+        b_t, c_t = (jnp.repeat(y, h // g, axis=1) for y in (b_t, c_t))
+        state = jnp.exp(dt_t * a)[..., None, None] * state \
+            + (dt_t[..., None] * x_t)[..., None] * b_t[..., None, :]
+        return state, jnp.einsum("bhpn,bhn->bhp", state, c_t)
+
+    xs = tuple(jnp.moveaxis(y, 1, 0) for y in (x, dt, b, c))
+    _, y = lax.scan(token, jnp.zeros(x.shape[:1] + x.shape[2:]
+                                     + b.shape[-1:], x.dtype), xs)
+    return jnp.moveaxis(y, 0, 1) + d[:, None] * x
+
+
+def check_ssd_scan(interpret: bool = False) -> List[dict]:
+    """The chunked state-space scan (``ops/ssd.py``) as a process builds it,
+    at both published shapes in bfloat16, vs the recurrence token by token
+    in float32 at ``highest`` on the same (bfloat16-rounded) operands.
+
+    ``nemotron``: 8 windows of 4,096, 64 heads of 64 over 8 groups of 128, a
+    step of 0.05 times a softplus, ``A`` in [-16, -1], a skip ``D`` of order
+    one, ``x | B | C`` side by side as the Mamba-2 mixer hands them
+    (``ssd_chunked_columns``). ``lightning``: 4 windows of 16,384, 32 heads
+    of 128 and a group a head, a step of 1, Lightning Attention's decays, no
+    skip, q scaled by ``128 ** -0.5`` (``ssd_chunked``, as
+    ``models/minicpm_sala.py`` calls it). The measure is the root mean
+    square of the difference over the reference's: the chunk's products take
+    bfloat16 operands (the table ``m``, the state, ``x`` times its weight)
+    and the result is rounded once. A chunk or a head out of place reads 1.
+    (Under the interpreter, this runner's smoke test: 150 positions, ragged,
+    in float32.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from storm_tpu.ops import ssd
+
+    f32 = jnp.float32
+    cases = ([("nemotron", (2, 150), 4, 8, 2, 16, f32, 1e-4),
+              ("lightning", (2, 150), 4, 16, 4, 16, f32, 1e-4)]
+             if interpret else
+             [("nemotron", (8, 4096), 64, 64, 8, 128, jnp.bfloat16, 1e-2),
+              ("lightning", (4, 16384), 32, 128, 32, 128, jnp.bfloat16,
+               1e-2)])
+    rows = []
+    for case, shape, h, p, g, n, dtype, tol in cases:
+        ks = jax.random.split(jax.random.PRNGKey(0), 6)
+        x = jax.random.normal(ks[0], shape + (h, p)).astype(dtype)
+        b = jax.random.normal(ks[3], shape + (g, n)).astype(dtype)
+        c = jax.random.normal(ks[4], shape + (g, n)).astype(dtype)
+        if case == "nemotron":
+            dt = 0.05 * jax.nn.softplus(jax.random.normal(ks[1], shape + (h,)))
+            a = -jax.random.uniform(ks[2], (h,), minval=1.0, maxval=16.0)
+            d = jax.random.normal(ks[5], (h,))
+            xbc = jnp.concatenate([y.reshape(shape + (-1,))
+                                   for y in (x, b, c)], -1)
+            got = jax.jit(lambda xbc, dt, a, d: ssd.ssd_chunked_columns(
+                xbc, dt, a, d, g, n))(xbc, dt, a, d).reshape(x.shape)
+        else:
+            dt = jnp.ones(shape + (h,), f32)
+            a = -(2.0 ** (-8.0 * (jnp.arange(h) + 1) / h))
+            d = jnp.zeros((h,), f32)
+            c = (c.astype(f32) * n ** -0.5).astype(dtype)
+            got = jax.jit(ssd.ssd_chunked)(x, dt, a, b, c, d)
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(ssd_recurrence)(
+                x.astype(f32), dt, a, b.astype(f32), c.astype(f32), d))
+        got = np.asarray(got, np.float32)
+        rms = float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+        rows.append({**_row("ssd_scan", f"{case}_S{shape[1]}_H{h}_P{p}",
+                            np.dtype(dtype).name, got, want, rel_tol=tol),
+                     "rms_rel_err": round(rms, 8), "metric": "rms",
+                     "pass": bool(rms <= tol)})
+    return rows
+
+
 def run_all(interpret: bool = False) -> List[dict]:
     return (check_flash_attention(interpret)
             + check_short_attention(interpret)
@@ -381,4 +465,5 @@ def run_all(interpret: bool = False) -> List[dict]:
             + check_kda_tables(interpret)
             + check_kda_mixer(interpret)
             + check_short_conv(interpret)
+            + check_ssd_scan(interpret)
             + check_causal_attention(interpret))

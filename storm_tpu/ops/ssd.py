@@ -25,13 +25,27 @@ a chunk). The products take their operands in the type of ``x`` (bfloat16
 when serving) and accumulate in float32; the state stays float32 from chunk
 to chunk, the decays are float32 throughout.
 
-**One loop in the compiled program** (``lax.scan`` over chunks, every row of
-the batch and every head inside a step): the state is the only chain, a step
-holds one chunk's tables (``B x H x chunk x chunk``) and no more, and a
-device trace shows the scan's whole time as that one ``while``. The chunks
-are brought forward once (the two minor axes stay as the layer holds them,
-tokens by channels) and the result is put back the same way: nothing is
-scattered and no table is rewritten.
+**One loop in the compiled program** (a ``fori_loop`` over the chunk's index,
+every row of the batch and every head inside a step): the state is the only
+chain, a step holds one chunk's tables (``B x H x chunk x chunk``) and no
+more, and a device trace shows the scan's whole time as that one ``while``.
+The loop reads its operands where the layer left them and writes its result
+where the layer reads it: ``x`` stays position-major, ``(B, S, channels)``,
+and a step takes its chunk by index on the view ``(B, S / chunk, chunk,
+channels)`` (a bitcast; a view that is none would have the compiler bring the
+whole array into the body's layout before the loop); ``B`` and ``C`` are
+columns of that chunk where the layer holds all three side by side
+(``ssd_chunked_columns``), slices of their own arrays otherwise; the split of
+the channels into groups and heads happens on the chunk, in fast memory. The
+result is carried beside the state, ``(B, S, H P)`` in the served type, and a
+step writes its chunk into it in place, the skip ``D x`` added in float32
+before the one rounding: no array of a branch's size is made, moved or
+widened outside the loop, nothing is scattered and no table is rewritten.
+The running sums ``L`` alone are made before the loop, for every chunk at
+once (``(B, S, H)`` float32, a sixty-fourth of ``x``, as a product with a
+triangle of ones at ``highest``: exact products, float32 sums): the v5e
+compiler's cumulative sum over a chunk's ``(B, chunk, G, R)`` took 235 us of
+a step's 350 inside the loop (PERF.md section 6, PR 50).
 """
 
 from __future__ import annotations
@@ -54,7 +68,28 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     state nor write to it."""
     bsz, s, h, p = x.shape
     g, n = b.shape[-2:]
-    r = h // g
+    y = _chunks_loop(x.reshape(bsz, s, h * p),
+                     (b.reshape(bsz, s, g * n), c.reshape(bsz, s, g * n)),
+                     dt, a, d, g, n, chunk)
+    return y.reshape(bsz, s, h, p)
+
+
+def ssd_chunked_columns(xbc: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
+                        d: jnp.ndarray, groups: int, state: int,
+                        chunk: int = 128) -> jnp.ndarray:
+    """The same scan for ``x | B | C`` held side by side, ``xbc: (B, S, H P +
+    2 G N)`` as a Mamba-2 layer's convolution writes them: the loop takes a
+    chunk of all three at once and the columns apart inside its body, so no
+    slice of a branch's size is made for it. Returns ``y: (B, S, H P)``."""
+    return _chunks_loop(xbc, None, dt, a, d, groups, state, chunk)
+
+
+def _chunks_loop(x, bc, dt, a, d, g, n, chunk):
+    """``x: (B, S, H P)`` and ``bc``: ``B`` and ``C`` as ``(B, S, G N)``
+    each, or None where they are the columns of ``x`` after its ``H P``."""
+    bsz, s, h = dt.shape
+    hp = x.shape[-1] - (0 if bc else 2 * g * n)
+    r, p = h // g, hp // h
     f32 = jnp.float32
     _note("ssd_scan", "chunked")
     cd = x.dtype
@@ -62,19 +97,37 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     pad = -s % q
     nc = (s + pad) // q
 
-    def chunks(y):  # (B, S, ...) -> (nc, B, Q, ...)
-        if pad:
-            y = jnp.pad(y, ((0, 0), (0, pad)) + ((0, 0),) * (y.ndim - 2))
-        return jnp.moveaxis(y.reshape(bsz, nc, q, *y.shape[2:]), 1, 0)
+    def padded(y):  # (B, S, channels) -> (B, S + pad, channels)
+        return jnp.pad(y, ((0, 0), (0, pad), (0, 0))) if pad else y
 
-    xs = (chunks(x.reshape(bsz, s, g, r, p)),
-          chunks(dt.astype(f32).reshape(bsz, s, g, r)), chunks(b), chunks(c))
+    x, dt = padded(x), padded(dt.astype(f32))
+    bc = bc and tuple(padded(y) for y in bc)
     a = a.astype(f32).reshape(g, r)
+    d = d.astype(f32).reshape(g, r, 1)
     later = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]  # s <= t
+    # L for every chunk at once, each from its chunk's start: (B, S, H), <= 0
+    runs = jnp.einsum(
+        "ts,bcsh->bcth", later.astype(f32),
+        (dt * a.reshape(h)).reshape(bsz, nc, q, h),
+        precision=lax.Precision.HIGHEST).reshape(bsz, s + pad, h)
 
-    def one_chunk(state, xs_c):  # state (B, G, R, P, N), float32
-        x_c, dt_c, b_c, c_c = xs_c
-        run = jnp.cumsum(dt_c * a, axis=1)  # (B, Q, G, R), <= 0
+    def one_chunk(i, carry):  # state (B, G, R, P, N), float32
+        state, out = carry
+
+        def chunk_of(y, *split):  # (B, S, channels) -> (B, Q, *split)
+            return lax.dynamic_slice_in_dim(
+                y, i * q, q, axis=1, allow_negative_indices=False).reshape(
+                bsz, q, *split)
+
+        held = lax.dynamic_index_in_dim(
+            x.reshape(bsz, nc, q, x.shape[-1]), i, 1, keepdims=False)
+        x_c = held[..., :hp].reshape(bsz, q, g, r, p)
+        if bc:
+            b_c, c_c = (chunk_of(y, g, n) for y in bc)
+        else:
+            b_c, c_c = (held[..., lo:lo + g * n].reshape(bsz, q, g, n)
+                        for lo in (hp, hp + g * n))
+        dt_c, run = chunk_of(dt, g, r), chunk_of(runs, g, r)
         run_h = jnp.moveaxis(run, 1, -1)  # (B, G, R, Q)
         # within the chunk: (C B^T) a group, the decays a head
         cb = jnp.einsum("btgn,bsgn->bgts", c_c, b_c,
@@ -94,12 +147,13 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
         state = jnp.exp(last)[..., None, None] * state + jnp.einsum(
             "bsgrp,bsgn->bgrpn", (x_c.astype(f32) * w[..., None]).astype(cd),
             b_c, preferred_element_type=f32)
-        return state, y.astype(cd)
+        # the skip in float32 and one rounding, into the chunk's own place
+        y = (y + d * x_c.astype(f32)).astype(cd).reshape(bsz, q, hp)
+        return state, lax.dynamic_update_slice_in_dim(
+            out, y, i * q, axis=1, allow_negative_indices=False)
 
     with jax.named_scope(P.MIX_SSD_SCAN):  # its name in a device trace
-        _, y = lax.scan(one_chunk, jnp.zeros((bsz, g, r, p, n), f32), xs)
-    # the skip, on the chunks as the loop read and wrote them: whatever
-    # handed ``x`` over need not hand it to what reads the result as well
-    y = (y.astype(f32)
-         + d.astype(f32).reshape(g, r, 1) * xs[0].astype(f32)).astype(cd)
-    return jnp.moveaxis(y, 0, 1).reshape(bsz, s + pad, h, p)[:, :s]
+        _, y = lax.fori_loop(0, nc, one_chunk, (
+            jnp.zeros((bsz, g, r, p, n), f32),
+            jnp.zeros((bsz, s + pad, hp), cd)))
+    return y[:, :s]

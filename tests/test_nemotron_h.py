@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -28,6 +27,7 @@ from storm_tpu.ops import kda  # noqa: E402
 from storm_tpu.ops import layers as L  # noqa: E402
 from storm_tpu.ops import ssd  # noqa: E402
 from storm_tpu.ops.attention import causal_attention  # noqa: E402
+from storm_tpu.ops.parity_checks import ssd_recurrence  # noqa: E402
 from storm_tpu.ops.platform import dispatch_notes  # noqa: E402
 from storm_tpu.parallel.moe import (route_topk, topk_moe_init,  # noqa: E402
                                     topk_moe_layer)
@@ -59,23 +59,9 @@ def _ssd_inputs(step, shape=(2, 150), heads=4, p=8, groups=2, n=16, seed=0):
     return x, dt, a, b, c, d
 
 
-def _recurrence(x, dt, a, b, c, d):
-    """The layer's definition, token by token: decay, write, read, skip."""
-    bsz, s, h, p = x.shape
-    g, n = b.shape[-2:]
-
-    def heads(y):  # each head reads its group's B and C
-        return jnp.repeat(y, h // g, axis=-2)
-
-    def token(state, xs):  # (B, H, P, N)
-        x_t, dt_t, b_t, c_t = xs
-        state = jnp.exp(dt_t * a)[..., None, None] * state \
-            + (dt_t[..., None] * x_t)[..., None] * heads(b_t)[..., None, :]
-        return state, jnp.einsum("bhpn,bhn->bhp", state, heads(c_t))
-
-    xs = tuple(jnp.moveaxis(y, 1, 0) for y in (x, dt, b, c))
-    _, y = lax.scan(token, jnp.zeros((bsz, h, p, n), x.dtype), xs)
-    return jnp.moveaxis(y, 0, 1) + d[:, None] * x
+# the layer's definition, token by token (decay, write, read, skip): the one
+# the chip's check holds both published shapes to
+_recurrence = ssd_recurrence
 
 
 # exp(dt A) a token: near 1, a few tokens' memory, none (exp(-L_s) would
@@ -83,18 +69,35 @@ def _recurrence(x, dt, a, b, c, d):
 STEPS = {"decay-near-1": 1e-4, "a-few-tokens": 0.05, "decay-near-0": 10.0}
 
 
+def _as_columns(x, dt, a, b, c, d, chunk):
+    """The scan through ``ssd_chunked_columns``: ``x | B | C`` side by side
+    as a Mamba-2 mixer's convolution writes them."""
+    held = jnp.concatenate([y.reshape(y.shape[:2] + (-1,))
+                            for y in (x, b, c)], -1)
+    return ssd.ssd_chunked_columns(held, dt, a, d, *b.shape[-2:],
+                                   chunk=chunk).reshape(x.shape)
+
+
+FORMS = {"separate": ssd.ssd_chunked, "columns": _as_columns}
+
+
+@pytest.mark.parametrize("form", FORMS.values(), ids=FORMS.keys())
+@pytest.mark.parametrize("rows", [2, 3])
 @pytest.mark.parametrize("chunk", [64, 128])
 @pytest.mark.parametrize("step", STEPS.values(), ids=STEPS.keys())
-def test_chunked_scan_is_the_recurrence(step, chunk):
-    """150 tokens are three chunks of 64 (the last ragged) or two of 128.
-    Both sides float32 at ``highest``: they differ by summation order alone
-    (sums of up to 128 terms that cancel read 2e-5 of the largest output at
-    the largest step), 5e-5 is some three times that."""
-    args = _ssd_inputs(step)
+def test_chunked_scan_is_the_recurrence(step, chunk, rows, form):
+    """150 tokens are three chunks of 64 (the last ragged) or two of 128,
+    each written into its own place of the one result the loop carries; two
+    rows of the batch or three; ``x``, ``B`` and ``C`` as arrays of their
+    own or as the columns of one. Both sides float32 at ``highest``: they
+    differ by summation order alone (sums of up to 128 terms that cancel
+    read 2e-5 of the largest output at the largest step), 5e-5 is some three
+    times that."""
+    args = _ssd_inputs(step, shape=(rows, 150))
     with jax.default_matmul_precision("highest"):
         want = _recurrence(*args)
         with dispatch_notes() as seen:
-            got = ssd.ssd_chunked(*args, chunk=chunk)
+            got = form(*args, chunk=chunk)
     assert seen == ["ssd_scan=chunked"]
     assert got.shape == want.shape and bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) < 5e-5 * float(jnp.abs(want).max())
@@ -111,19 +114,55 @@ def _lightning_inputs():
             jnp.zeros((4,)))
 
 
-@pytest.mark.parametrize("inputs,bound", [
+def _served(step):
+    """``x``, ``B`` and ``C`` in bfloat16 as a served model holds them, the
+    skip ``D`` of order one."""
+    x, dt, a, b, c, d = _ssd_inputs(step)
+    bf16 = jnp.bfloat16
+    return x.astype(bf16), dt, a, b.astype(bf16), c.astype(bf16), d
+
+
+@pytest.mark.parametrize("inputs,form,bound", [
     # 40 tokens under a chunk of 128; as many groups as heads (no sharing)
     (lambda: _ssd_inputs(0.05, shape=(1, 40), heads=2, groups=2, seed=3),
-     1e-5),
-    (_lightning_inputs, 1e-5),
-], ids=["shorter-than-a-chunk", "lightning-G=H-dt=1-D=0"])
-def test_scan_with_one_group_a_head(inputs, bound):
+     "separate", 1e-5),
+    (_lightning_inputs, "separate", 1e-5),
+    # a window shorter than a chunk, three rows, the skip of order one
+    (lambda: _ssd_inputs(0.05, shape=(3, 40), seed=4), "separate", 1e-5),
+    (lambda: _ssd_inputs(0.05, shape=(3, 40), seed=4), "columns", 1e-5),
+    # bfloat16 operands and D != 0: the chunk's products take bfloat16
+    # operands and the result is rounded once, after the skip is added in
+    # float32. Largest error over the largest output, and root mean square
+    # over the reference's, at the three steps: 2.52e-3 / 1.71e-3, 2.62e-3 /
+    # 1.62e-3, 3.42e-3 / 2.40e-3; the parent (chunk rounded, widened, the
+    # skip added, rounded again) read 3.30e-3 / 1.78e-3, 2.62e-3 / 1.62e-3,
+    # 4.15e-3 / 2.92e-3. A bfloat16 step at the largest output is 2^-8.
+    (lambda: _served(0.05), "separate", 2.0 ** -8),
+    (lambda: _served(1e-4), "separate", 2.0 ** -8),
+    (lambda: _served(10.0), "columns", 2.0 ** -8),
+], ids=["shorter-than-a-chunk", "lightning-G=H-dt=1-D=0",
+        "three-rows-shorter-than-a-chunk", "three-rows-shorter-columns",
+        "bfloat16-a-few-tokens", "bfloat16-decay-near-1",
+        "bfloat16-decay-near-0-columns"])
+def test_scan_with_one_group_a_head(inputs, form, bound):
     args = inputs()
     with jax.default_matmul_precision("highest"):
-        got = ssd.ssd_chunked(*args, chunk=128)
-        want = _recurrence(*args)
+        got = FORMS[form](*args, chunk=128)
+        want = _recurrence(*(y.astype(jnp.float32) for y in args))
+    assert got.dtype == args[0].dtype
     assert float(jnp.abs(got - want).max()) < bound * float(
         jnp.abs(want).max())
+
+
+def test_scan_on_the_chip_is_held_by_a_check_that_runs_here_too():
+    """``ops/parity_checks.py check_ssd_scan`` holds both published shapes
+    to the recurrence on the chip; its small float32 cases run here."""
+    from storm_tpu.ops.parity_checks import check_ssd_scan
+
+    rows = check_ssd_scan(interpret=True)
+    assert [r["case"].split("_")[0] for r in rows] == ["nemotron",
+                                                       "lightning"]
+    assert all(r["pass"] and r["rms_rel_err"] < 1e-5 for r in rows)
 
 
 def test_scan_compiles_to_one_loop_and_no_scatter():

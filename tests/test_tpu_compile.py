@@ -354,6 +354,75 @@ def test_lightning_scan_compiles_to_the_loop_its_metric_looks_for(v5e):
     assert not re.search(_metric_pattern("ssd_scan_ms"), loop)
 
 
+def _mamba_mixer_at_its_step(v5e):
+    """Nemotron's Mamba-2 mixer: 8 windows of 4,096 into 2,688, 64 heads of
+    64 over 8 groups of 128, bfloat16."""
+    from storm_tpu.models import nemotron_h as N
+
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: N.mamba_mixer_init(
+            jax.random.PRNGKey(0), 2688, 64, 64, 8, 128, 4)))
+    x = _spec((8, 4096, 2688), jnp.bfloat16, v5e)
+    return jax.jit(lambda p, x: N.mamba_mixer(
+        p, x, 64, 64, 8, 128, 128, 1e-5)).lower(p, x)
+
+
+def _lightning_mixer_at_its_step(v5e):
+    """MiniCPM-SALA's lightning mixer: 4 windows of 16,384 into 4,096, 32
+    heads of 128, bfloat16."""
+    from storm_tpu.models import minicpm_sala as M
+
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: M.lightning_mixer_init(
+            jax.random.PRNGKey(0), 4096, 32, 128)))
+    x = _spec((4, 16384, 4096), jnp.bfloat16, v5e)
+    turn = _spec((16384, 64), jnp.float32, v5e)
+    slopes = M.lightning_slopes(32, 1, 32)
+    return jax.jit(lambda p, x, cos, sin: M.lightning_mixer(
+        p, x, 32, 128, 1e-6, (cos, sin), slopes, 128)).lower(
+        p, x, turn, turn)
+
+
+@pytest.mark.parametrize("lowered,metric,other,held", [
+    (_mamba_mixer_at_its_step, "ssd_scan_ms", "lightning_scan_ms",
+     "bf16[8,4096,6144]"),
+    (_lightning_mixer_at_its_step, "lightning_scan_ms", "ssd_scan_ms",
+     "bf16[4,16384,4096]"),
+], ids=["mamba_mixer-nemotron", "lightning_mixer-minicpm_sala"])
+def test_scan_reads_and_writes_where_its_mixer_holds_them(
+        v5e, monkeypatch, lowered, metric, other, held):
+    """Both callers of ``ssd_chunked`` at their cells' steps, as one chip
+    builds them: the scan is one ``while`` that its cell's metric finds (and
+    the other cell's does not) and that carries its operands position-major
+    as the mixer holds them (Nemotron's the convolution's whole result, ``x
+    | B | C``) and its result beside them; nothing is brought chunk-first for
+    it, its result is widened or re-tiled by no pass of its own on the way
+    to the norm, nothing is sliced out of the convolution's result, and
+    nothing is scattered. (The float32 arrays of a branch's size that stay
+    are the norms' own, made inside the projections' fusions: the gate's
+    product and the head norms' inputs.)"""
+    import re
+
+    from storm_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_use_pallas", lambda: True)
+    monkeypatch.setattr(kda, "_one_device", lambda: True)
+    text = lowered(v5e).compile().as_text()
+    (loop,) = _loops(text)
+    assert re.search(_metric_pattern(metric), loop)
+    assert not re.search(_metric_pattern(other), loop)
+    assert held in loop and "scatter(" not in text
+    # chunk-first, as ``lax.scan`` cut its operands and stacked its result
+    assert not re.search(
+        r"\[(32,8|8,32),128,8,8,64\]|\[(128,4|4,128),128,32,1,128\]", text)
+    entry = text[text.index("ENTRY"):]
+    branch = r"(8,4096,4096|4,16384,4096|8,4096,6144)"
+    assert not re.search(r"= \w+\[" + branch + r"\]\S* "
+                         r"(copy|reshape|transpose|convert|slice)\(", entry)
+
+
 def test_kda_mixer_compiles_with_every_branch_in_lanes(v5e, monkeypatch):
     """Kimi-Linear's KDA mixer at its cell's step (8 windows of 4,096 into
     2,304, 32 heads of 128, bfloat16) as one chip builds it: the tables'

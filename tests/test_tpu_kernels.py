@@ -80,3 +80,12 @@ def test_causal_attention_compiled_parity():
     rows = check_causal_attention(interpret=False)
     bad = [r for r in rows if not r["pass"]]
     assert not bad, f"compiled causal_attention parity failures: {bad}"
+
+
+@pytest.mark.slow
+def test_ssd_scan_compiled_parity():
+    from storm_tpu.ops.parity_checks import check_ssd_scan
+
+    rows = check_ssd_scan(interpret=False)
+    bad = [r for r in rows if not r["pass"]]
+    assert not bad, f"compiled ssd_scan parity failures: {bad}"
