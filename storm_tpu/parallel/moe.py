@@ -167,7 +167,10 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
     score + selection bias, weighted by the score alone (over their sum where
     ``renormalize``) times ``scale``. Scores in float32 from a product at
     ``highest`` precision: a tie broken the other way sends a token to
-    another expert."""
+    another expert. A chosen expert's score is read by comparing its number
+    with every column's and taking the largest of what matches (one term, and
+    no score is negative: the score to the bit), not by a gather of a scalar
+    a pick; a sum there the compiler merges with the renormalisation's."""
     logits = jnp.dot(tokens.astype(jnp.float32),
                      p["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -181,7 +184,9 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
     if "router_bias" in p:
         chosen = score + p["router_bias"].astype(jnp.float32)
     _, experts = jax.lax.top_k(chosen, top_k)
-    weights = jnp.take_along_axis(score, experts, axis=-1)
+    column = jnp.arange(score.shape[-1], dtype=experts.dtype)
+    weights = jnp.max(jnp.where(experts[..., None] == column,
+                                score[..., None, :], 0.0), axis=-1)
     if renormalize:
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
     return experts, weights * scale
@@ -326,7 +331,9 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     loop's length is the data's, so whatever the routing no token is dropped
     and no padding up to a capacity is computed; the only waste is each
     run's last, partly filled tile. The buffer alone has the worst case's
-    size.
+    size, and is allocated, not filled: the loop writes every tile it counts
+    whole, and the one row read without being written, the zero row behind
+    them, is set to zero by itself.
 
     A token's result is the float32 sum of its assignments' rows there, and
     never a ``[tokens, top_k, dim]`` array (a scatter-add would do it in
@@ -364,8 +371,11 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         token_at = number_at // top_k
         absent = n * top_k - jnp.sum(counts)
         lane = jnp.arange(tile, dtype=jnp.int32)
-        # one row more than the tiles can fill: where absent assignments point
-        empty = jnp.zeros((zero_row + 1, dim), x.dtype)
+        # one row more than the tiles can fill, where absent assignments and
+        # the combine's empty lanes point: the only row that must be zero
+        empty = jax.lax.dynamic_update_slice(
+            jax.lax.empty((zero_row + 1, dim), x.dtype),
+            jnp.zeros((1, dim), x.dtype), (zero_row, 0))
         # a slice of ``tile`` from any start inside the assignments stays
         # inside
         token_in, weight_in = (jnp.pad(a, (0, tile))

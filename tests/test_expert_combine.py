@@ -76,9 +76,8 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", list(CASES), ids=list(CASES))
-@pytest.mark.parametrize("ffn", ["relu2", "swiglu"])
-def test_a_tokens_sum_is_the_plain_sum(case, ffn):
+def _case(case, ffn):
+    """A case's layer and tokens, routed as the case says."""
     width, held, first, top_k, n, routing = CASES[case]
     p = _layer(width, held, ffn)
     x = jax.random.normal(jax.random.PRNGKey(1), (n, DIM))
@@ -91,6 +90,14 @@ def test_a_tokens_sum_is_the_plain_sum(case, ffn):
         p = _biased(p, 0, 10.0)
     elif routing == "one-block":
         p, x = _one_block_holds_all(p, n, first, held, block=3)
+    return p, x
+
+
+@pytest.mark.parametrize("case", list(CASES), ids=list(CASES))
+@pytest.mark.parametrize("ffn", ["relu2", "swiglu"])
+def test_a_tokens_sum_is_the_plain_sum(case, ffn):
+    width, held, first, top_k, n, routing = CASES[case]
+    p, x = _case(case, ffn)
     with jax.default_matmul_precision("highest"):
         with dispatch_notes() as seen:
             y, tokens, absent = jax.jit(lambda p, x: topk_moe_layer(
@@ -171,6 +178,75 @@ def test_bfloat16_rows_are_summed_in_float32(held, first):
         absent32)
     np.testing.assert_allclose(y.astype(jnp.float32), want,
                                atol=2e-2 * float(jnp.abs(want).max()))
+
+
+def _gathered_weights(p, tokens, top_k, router, renormalize, scale):
+    """``route_topk`` as it was: the chosen scores by ``take_along_axis``,
+    then the same renormalisation and scale."""
+    logits = jnp.dot(tokens.astype(jnp.float32),
+                     p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    score = (jax.nn.sigmoid(logits) if router == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+    chosen = score
+    if "router_bias" in p:
+        chosen = score + p["router_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(chosen, top_k)
+    weights = jnp.take_along_axis(score, experts, axis=-1)
+    if renormalize:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return experts, weights * scale
+
+
+@pytest.mark.parametrize("top_k", [6, 8])
+@pytest.mark.parametrize("width", [128, 256, 384])
+@pytest.mark.parametrize("renormalize", [True, False],
+                         ids=["renormalized", "as-scored"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no-bias"])
+@pytest.mark.parametrize("router", ["sigmoid", "softmax"])
+def test_chosen_scores_by_comparison_are_the_gathered_ones_to_the_bit(
+        router, bias, renormalize, width, top_k):
+    """The weights ``route_topk`` returns are ``take_along_axis(score,
+    experts)`` with the same renormalisation, every float32 bit of them, and
+    the experts chosen are the same: over the router's width one column
+    matches a pick, and no score is negative."""
+    p = topk_moe_init(jax.random.PRNGKey(width + top_k), DIM, 48, width, 4)
+    if not bias:
+        p = {k: v for k, v in p.items() if k != "router_bias"}
+    x = jax.random.normal(jax.random.PRNGKey(6), (200, DIM)) * 3.0
+    experts, weights = jax.jit(lambda p, x: route_topk(
+        p, x, top_k, router, renormalize, 2.5))(p, x)
+    want_experts, want = jax.jit(lambda p, x: _gathered_weights(
+        p, x, top_k, router, renormalize, 2.5))(p, x)
+    assert weights.dtype == jnp.float32 and weights.shape == (200, top_k)
+    assert np.array_equal(np.asarray(experts), np.asarray(want_experts))
+    assert np.array_equal(np.asarray(weights).view(np.uint32),
+                          np.asarray(want).view(np.uint32))
+    assert float(weights.min()) > 0.0
+
+
+@pytest.mark.parametrize("case", list(CASES), ids=list(CASES))
+def test_the_buffer_may_hold_anything_but_its_zero_row(case, monkeypatch):
+    """The tiles' buffer is allocated, not filled: handed one full of NaN
+    the layer gives ``y``, ``tokens`` and ``absent`` of the zeroed form to
+    the bit, for a share held, none absent, every assignment absent, and
+    runs that end in a partly filled tile (111 tokens on one expert in tiles
+    of 16). Only the zero row is read without having been written."""
+    _, held, first, top_k, n, _ = CASES[case]
+    p, x = _case(case, "swiglu")
+    tile = min(16, -(-n // 8) * 8)  # the layer's, for one token too
+    got = {}
+    for fill in (jnp.nan, 0.0):
+        shapes = []
+        monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: (
+            shapes.append(shape), jnp.full(shape, fill, dtype))[1])
+        got[fill] = jax.jit(lambda p, x: topk_moe_layer(
+            p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
+        assert shapes == [((-(-n * top_k // tile) + held) * tile + 1, DIM)]
+    for a, b in zip(got[jnp.nan], got[0.0]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert int(got[0.0][1].sum()) + int(got[0.0][2]) == n * top_k
 
 
 def _lowered(p, x, top_k, first):
@@ -286,11 +362,13 @@ def test_every_held_assignment_has_a_row_of_its_own(share, routing):
 
 
 def test_no_gather_or_scatter_runs_over_the_assignments():
-    """64 tokens, top-6, a quarter held: the lowered layer holds no scatter,
-    no gather that returns a value an assignment (but ``route_topk``'s own
-    ``take_along_axis``, shaped ``[tokens, top_k]``), reads the ordered
-    assignments only where a bisection probes them (``held + 1`` and
-    ``blocks + 1`` probes), and sorts twice beside the router's top-k."""
+    """64 tokens, top-6, a quarter held: the lowered layer holds no scatter
+    and no gather that returns a value an assignment, ``route_topk``'s
+    chosen scores included (flat or ``[tokens, top_k]``: they are a
+    comparison and a maximum); it reads the ordered assignments only where a
+    bisection probes them (``held + 1`` and ``blocks + 1`` probes), sorts
+    twice beside the router's top-k, and makes the tiles' buffer of nothing
+    that fills it."""
     held, top_k, n = 4, 6, 64
     text = _lowered(_layer(16, held), jax.random.normal(
         jax.random.PRNGKey(2), (n, DIM)), top_k, 4)
@@ -299,18 +377,34 @@ def test_no_gather_or_scatter_runs_over_the_assignments():
     gathers = [line for line in text.splitlines() if "stablehlo.gather" in line]
     assert gathers
     flat = "tensor<%dx" % (n * top_k)
+    picks = "tensor<%dx%dx" % (n, top_k)
     probes = {held + 1, n // 16 + 1}
     for line in gathers:
         operand, result = re.search(r": \((tensor<[^>]*>), .*\) -> "
                                     r"(tensor<[^>]*>)", line).groups()
-        assert not result.startswith(flat), line
+        assert not result.startswith((flat, picks)), line
         if operand.startswith(flat):
             assert int(re.match(r"tensor<(\d+)x", result).group(1)) in probes
-    # and the pattern does find the old form's three
+    # the buffer (24 tiles of 16, a held expert's last each, the zero row)
+    # is ``lax.empty``'s, which only the CPU lowers to a fill
+    rows = (n * top_k // 16 + held) * 16 + 1
+    made = {}
+    for eqn in jax.make_jaxpr(lambda p, x: topk_moe_layer(
+            p, x, top_k, first_expert=4, tile=16))(
+            _layer(16, held), jnp.zeros((n, DIM))).eqns:
+        if any(v.aval.shape == (rows, DIM) for v in eqn.outvars):
+            made[eqn.primitive.name] = made.get(eqn.primitive.name, 0) + 1
+    assert made == {"empty": 1, "dynamic_update_slice": 1, "while": 1}
+    # and the pattern does find the old form's: the two of the dispatch and
+    # the chosen scores'
     local = jnp.zeros((n * top_k,), jnp.int32)
     old = jax.jit(lambda w, l: (
         w[jnp.argsort(l)], jnp.zeros((held + 1,), jnp.int32).at[l].add(1))
     ).lower(jnp.zeros((n * top_k,)), local).as_text()
     assert "stablehlo.scatter" in old
     assert any(re.search(r"-> %s" % flat, line) for line in old.splitlines()
+               if "stablehlo.gather" in line)
+    old = jax.jit(lambda s, e: jnp.take_along_axis(s, e, axis=-1)).lower(
+        jnp.zeros((n, 16)), jnp.zeros((n, top_k), jnp.int32)).as_text()
+    assert any(re.search(r"-> %s" % picks, line) for line in old.splitlines()
                if "stablehlo.gather" in line)

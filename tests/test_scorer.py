@@ -208,19 +208,21 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
 # leave these models alone leaves these alone; one that is meant to change a
 # model says so by changing its line. (PR 50 changed the text of Nemotron's
 # and MiniCPM-SALA's four lines: the scan's loop in ``ops/ssd.py``; their
-# trees and leaves are the parent's.)
+# trees and leaves are the parent's. PR 51 changed the text of the three
+# expert models' six lines: ``parallel/moe.py``'s chosen scores and its
+# tiles' buffer; MiniCPM-SALA's two stand.)
 PARENT = {
-    "kimi_linear_tiny": ("5a2c22ecb739bab1", "9c9231af39d81a3b",
+    "kimi_linear_tiny": ("ef1901b7f1ec7b74", "9c9231af39d81a3b",
                          "d6d687f48a145060"),
-    "nemotron_h_tiny": ("01ba3c899a22bedb", "ffb1a7d7c726fcef",
+    "nemotron_h_tiny": ("972c52862d6e1c56", "ffb1a7d7c726fcef",
                         "b8b23e69c062a671"),
-    "kimi_k2_tiny": ("ef7a62b97ed3fa3a", "4015722ee481843e",
+    "kimi_k2_tiny": ("8c5bab4bbb0eb196", "4015722ee481843e",
                      "3eee1654ad04fdad"),
     "minicpm_sala_tiny": ("e80bd69b29e9bb6e", "fbaec783e93f7071",
                           "136d1265de921964"),
-    "kimi_linear_48b": ("e285544fe067b20b", "35c56c4e58dd4ac8"),
-    "nemotron_3_nano_30b": ("6bd4697c3dbdf4ff", "32502e49d7fc6552"),
-    "kimi_k2_6": ("671681e849918856", "4a919d11ba374a7b"),
+    "kimi_linear_48b": ("d7cba0c765ab105b", "35c56c4e58dd4ac8"),
+    "nemotron_3_nano_30b": ("0d451a8c29ce3329", "32502e49d7fc6552"),
+    "kimi_k2_6": ("ea1b682882454006", "4a919d11ba374a7b"),
     "minicpm_sala": ("5de5b298fd713172", "31e93c80f04d610e"),
 }
 

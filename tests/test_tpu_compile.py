@@ -253,9 +253,11 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
     combine's loop the tokens' float32 sums (the benchmark's
     ``*expert_matmul_ms`` and ``*expert_combine_ms`` find the loops in a
     trace by those shapes, and a listed metric that reads nothing makes a
-    run malformed), each in one loop alone, and the dispatch around them
-    holds no scatter and no gather of a value an assignment but
-    ``route_topk``'s own."""
+    run malformed), each in one loop alone; around them no scatter, no
+    gather of a value an assignment (``route_topk``'s chosen scores are a
+    comparison reduced inside one fusion: no ``[tokens, top_k, width]``
+    array leaves one), and the tiles' buffer is allocated with its last row
+    zeroed in place, neither filled nor copied."""
     import math
     import re
 
@@ -273,20 +275,38 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
     assert sum(sums in line for line in loops) == 1
     assert not any(stacked in line and sums in line for line in loops)
     assert "scatter" not in text
-    # how many slices a gather fetches: one an assignment only in
-    # ``route_topk``, elsewhere a tile's rows or a bisection's probes
-    fetched = {}
+    # how many slices a gather fetches: a tile's rows or a bisection's
+    # probes, nowhere one an assignment
+    fetched = set()
     for line in text.splitlines():
         found = re.search(r" = \w+\[([\d,]+)\]\S* gather\(.*"
                           r"offset_dims=\{([\d,]*)\}", line)
         if found:
             dims = found.group(1).split(",")
             offsets = set(found.group(2).split(","))
-            fetched.setdefault(math.prod(
-                int(d) for i, d in enumerate(dims) if str(i) not in offsets),
-                []).append("take_along_axis" in line)
-    assert max(fetched) == n * top_k and all(fetched[n * top_k])
-    assert sorted(fetched)[:-1] == sorted({held + 1, n // 256 + 1, tile, 512})
+            fetched.add(math.prod(
+                int(d) for i, d in enumerate(dims) if str(i) not in offsets))
+    assert fetched == {held + 1, n // 256 + 1, tile, 512}
+    # what an operation outside a fusion's body writes is an array in memory
+    written, fused = [], False
+    for line in lines:
+        if line.endswith("{") and "->" in line:
+            fused = line.startswith("%fused_computation")
+        elif not fused and " = " in line:
+            written.append(line)
+    assert not any(f"[{n},{top_k},{width}]" in line.split(" = ")[1].split(
+        "(")[0] for line in written)
+    buffer = "bf16[%d,%d]" % ((-(-n * top_k // tile) + held) * tile + 1, dim)
+    makes = [line for line in written if line.split(" = ")[1].startswith(
+        buffer) and " get-tuple-element(" not in line]
+    # two fusions write into it where it lies: the zero row's, of no operand
+    # (no ``broadcast`` fills it), and a tile's in the experts' loop; no
+    # ``copy`` hands it from one to the other or on to the combine
+    assert all(" fusion(" in line for line in makes), makes
+    assert sum(" fusion()" in line and "dynamic-update-slice" in line
+               for line in makes) == 1 and len(makes) == 2, makes
+    assert sum(buffer in line and 'custom_call_target="AllocateBuffer"' in
+               line for line in text.splitlines()) == 1
 
 
 def _metric_pattern(name):
