@@ -79,11 +79,14 @@ def kda_mixer_init(rng, dim: int, heads: int, head_dim: int,
 
 
 def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
-              chunk: int, eps: float) -> jnp.ndarray:
+              chunk: int, eps: float,
+              step_range: float = 1.0) -> jnp.ndarray:
     """Between the input projections and the output's, every branch is
     ``(B, S, heads * head_dim)``, a head a block of lanes: the form ``_proj``
     yields and ``ops/kda.py``'s kernel reads. Only a norm's statistic is a
-    number a head."""
+    number a head. ``step_range``: the delta rule's step is ``step_range *
+    sigmoid(.)``: 1 (Kimi-Linear: a transition only shrinks along ``k``) or 2
+    (``kda_allow_neg_eigval``: past 1 it reflects)."""
     f32 = jnp.float32
 
     def branch(name):
@@ -97,6 +100,8 @@ def kda_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
     g = -jnp.repeat(jnp.exp(p["a_log"].astype(f32)), head_dim) \
         * jax.nn.softplus(f + p["dt_bias"].astype(f32))
     beta = jax.nn.sigmoid(_proj(x, p["beta"]).astype(f32))
+    if step_range != 1:
+        beta = step_range * beta
     o = kda.kda_chunked(q.astype(x.dtype), k, v, g, beta, heads, chunk=chunk,
                         out=lambda o: L.rmsnorm(p["o_norm"], o, eps))
     gate = jax.nn.sigmoid(_proj(x, p["g_down"], p["g_up"]))

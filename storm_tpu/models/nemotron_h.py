@@ -97,18 +97,24 @@ def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
 
 
 def gqa_mixer_init(rng, dim: int, heads: int, kv_heads: int,
-                   head_dim: int) -> dict:
-    ks = jax.random.split(rng, 4)
-    return {"q": _w(ks[0], dim, heads * head_dim),
-            "k": _w(ks[1], dim, kv_heads * head_dim),
-            "v": _w(ks[2], dim, kv_heads * head_dim),
-            "o": _w(ks[3], heads * head_dim, dim)}
+                   head_dim: int, gate: bool = False) -> dict:
+    """``gate``: one more full projection, of the output gate."""
+    ks = jax.random.split(rng, 4)  # as ever: the ungated draw is unmoved
+    p = {"q": _w(ks[0], dim, heads * head_dim),
+         "k": _w(ks[1], dim, kv_heads * head_dim),
+         "v": _w(ks[2], dim, kv_heads * head_dim),
+         "o": _w(ks[3], heads * head_dim, dim)}
+    if gate:
+        p["gate"] = _w(jax.random.fold_in(rng, 4), dim, heads * head_dim)
+    return p
 
 
 def gqa_mixer(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
               head_dim: int) -> jnp.ndarray:
     """Causal attention, ``heads`` query heads over ``kv_heads`` key/value
-    heads, no bias, no position embedding."""
+    heads, no bias, no position embedding; where the parameters hold a
+    ``gate``, the result times ``sigmoid(W_gate x)``, a number a channel,
+    before the output projection."""
     b, s, _ = x.shape
 
     def split(name, n):
@@ -117,8 +123,10 @@ def gqa_mixer(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
 
     out = causal_attention(split("q", heads), split("k", kv_heads),
                            split("v", kv_heads), scale=head_dim ** -0.5)
-    return _proj(out.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim),
-                 p["o"])
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
+    if "gate" in p:
+        out = out * jax.nn.sigmoid(_proj(x, p["gate"]))
+    return _proj(out, p["o"])
 
 
 def build_nemotron_h(

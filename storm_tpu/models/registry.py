@@ -85,6 +85,7 @@ def _load_builtin() -> None:
         moe_vit,
         nemotron_h,
         resnet,
+        solar_open2,
         vit,
     )
 

@@ -8,12 +8,13 @@ token:
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
-with ``g_t <= 0`` a log-decay per key channel and ``beta_t`` in (0, 1). Token by
-token that is ``S`` dependent steps of vector work; here a sequence is cut into
-chunks of ``chunk`` tokens, everything inside a chunk is matrix products, and
-only the ``S / chunk`` states are a chain (Yang et al. 2024, "Parallelizing
-linear transformers with the delta rule over sequence length", with the
-per-channel gate of gated linear attention).
+with ``g_t <= 0`` a log-decay per key channel and ``beta_t`` in (0, 1), or in
+(0, 2) where a model lets a transition reflect (``1 - beta_t < 0`` along
+``k_t``; nothing below assumes either). Token by token that is ``S`` dependent
+steps of vector work; here a sequence is cut into chunks of ``chunk`` tokens,
+everything inside a chunk is matrix products, and only the ``S / chunk`` states
+are a chain (Yang et al. 2024, "Parallelizing linear transformers with the delta
+rule over sequence length", with the per-channel gate of gated linear attention).
 
 Within a chunk, with ``G_t`` the decay summed from the chunk's start to ``t``
 and ``u_t`` the row that token ``t`` writes (``S_t = Diag(exp(g_t)) S_{t-1} +

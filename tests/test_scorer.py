@@ -1,9 +1,9 @@
 """The token scorers' one skeleton (``storm_tpu/models/scorer.py``) through
-each of the four language models at toy widths on the CPU: what every model's
-file relies on it for and no model's own tests hold, a case a model. The
-parameter tree's layout is what ``benchmarks/references/`` index; the zeroed
-``aux`` in the state is what makes the engine fetch a step's counts; the
-reader on ``ModelDef`` is all the batcher knows of them."""
+each of the five language models that count, at toy widths on the CPU: what
+every model's file relies on it for and no model's own tests hold, a case a
+model. The parameter tree's layout is what ``benchmarks/references/`` index;
+the zeroed ``aux`` in the state is what makes the engine fetch a step's
+counts; the reader on ``ModelDef`` is all the batcher knows of them."""
 
 import os
 import sys
@@ -34,6 +34,9 @@ TINY = {
                               "expert_absent": (2,)}),
     "minicpm_sala_tiny": (4, TWO, {"sparse_keys_read": (2,),
                                    "sparse_keys_skipped": (2,)}),
+    # the first plan whose block 0 routes: four expert layers of four blocks
+    "solar_open2_tiny": (4, TWO, {"expert_tokens": (4, 5),
+                                  "expert_absent": (4,)}),
 }
 # each family at its toy widths with a plan in which no branch counts
 PLAIN = {
@@ -114,7 +117,7 @@ def test_aux_goes_in_zeroed_with_the_shapes_that_come_back(name, ran):
         assert (np.asarray(back) >= 0).all() and np.asarray(back).sum() > 0
 
 
-@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("name", sorted(PLAIN))
 def test_a_plan_that_counts_nothing_hands_its_state_back(name):
     model = PLAIN[name]()
     params, state = model.init(jax.random.PRNGKey(0))
@@ -210,7 +213,9 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
 # and MiniCPM-SALA's four lines: the scan's loop in ``ops/ssd.py``; their
 # trees and leaves are the parent's. PR 51 changed the text of the three
 # expert models' six lines: ``parallel/moe.py``'s chosen scores and its
-# tiles' buffer; MiniCPM-SALA's two stand.)
+# tiles' buffer; MiniCPM-SALA's two stand. PR 52 gave ``kda_mixer`` a range
+# for its step and ``gqa_mixer`` a gate, both read at trace time: the eight
+# lines stand, and the sixth plan's two are as PR 52 built it.)
 PARENT = {
     "kimi_linear_tiny": ("ef1901b7f1ec7b74", "9c9231af39d81a3b",
                          "d6d687f48a145060"),
@@ -224,6 +229,9 @@ PARENT = {
     "nemotron_3_nano_30b": ("0d451a8c29ce3329", "32502e49d7fc6552"),
     "kimi_k2_6": ("ea1b682882454006", "4a919d11ba374a7b"),
     "minicpm_sala": ("5de5b298fd713172", "31e93c80f04d610e"),
+    "solar_open2_tiny": ("2fac1bcfe60792e8", "10b47418e20c9cdc",
+                         "12fec92ac2c8f7b2"),
+    "solar_open2_250b": ("690de9a9897d2e50", "d3a89bd4d4e674a5"),
 }
 
 

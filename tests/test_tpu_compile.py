@@ -72,6 +72,7 @@ def test_flash_attention_compiles_for_v5e(v5e, shape):
 @pytest.mark.parametrize("hq,hkv,dk,dv,carried", [
     (32, 32, 192, 128, "bf16[8,32,4096,192]"),  # Kimi-Linear's cell
     (32, 2, 128, 128, "bf16[8,2,4096,128]"),    # Nemotron's cell
+    (64, 8, 128, 128, "bf16[8,8,4096,128]"),    # Solar Open 2's, at 8 rows
 ])
 def test_causal_attention_compiles_as_a_loop_of_kernel_calls(
         v5e, monkeypatch, hq, hkv, dk, dv, carried):
@@ -477,6 +478,44 @@ def test_kda_mixer_compiles_with_every_branch_in_lanes(v5e, monkeypatch):
     # convolutions a kernel's calls, ``[8,64,8,8,32,128]``)
     assert re.findall(r"= (\w+)\[\d+(?:,\d+){4,}\]\S* copy\(",
                       entry) == ["bf16"]
+
+
+def test_kda_mixer_compiles_at_64_heads_with_steps_past_one(v5e, monkeypatch):
+    """The same mixer as Solar Open 2's plan calls it, at its cell's step (8
+    windows of 4,096 into 4,096, 64 heads of 128, the step in (0, 2)): the
+    tables' kernel is in the program at twice Kimi-Linear's heads and lanes
+    (8,192 channels a branch: 64 lane tiles for the convolution's kernel
+    too), both loops carry the table ``solar_kda_scan_ms`` finds them by, and
+    the hand-over is Kimi-Linear's: no array of a branch's size with heads
+    for an axis outside the loops, one copy on the way out, in bfloat16."""
+    import re
+
+    from storm_tpu.models import kimi_linear as K
+    from storm_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_use_pallas", lambda: True)
+    monkeypatch.setattr(kda, "_one_device", lambda: True)
+    assert kda.tables_form(128, 128, 64) == "kernel"
+    assert kda.conv_form(8192, 4096, 4) == "kernel"
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: K.kda_mixer_init(
+            jax.random.PRNGKey(0), 4096, 64, 128, 4)))
+    x = _spec((8, 4096, 4096), jnp.bfloat16, v5e)
+    compiled = jax.jit(lambda p, x: K.kda_mixer(
+        p, x, 64, 128, 64, 1e-5, step_range=2.0)).lower(p, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2  # the tables, the convolutions
+    wanted = re.compile(_metric_pattern("solar_kda_scan_ms"))
+    assert [bool(wanted.search(line)) for line in _loops(text)] == [True] * 2
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r"\[8,4096,64,128\]|\[4096,8,64,128\]", entry)
+    assert not re.search(r"\[8,4096,8192\]\S* reshape\(", entry)
+    assert re.findall(r"= (\w+)\[\d+(?:,\d+){4,}\]\S* copy\(",
+                      entry) == ["bf16"]
+    # this mixer's temporaries are most of the step's (of its 6.8 GB: the
+    # tables of 8 rows of 64 heads, the decay in float32)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
 
 
 def test_eva_mixer_compiles_with_the_heads_merged_and_its_loops_found(
