@@ -40,9 +40,13 @@ divided by their float32 sum, as ops/attention.py ``causal_blocked`` does.
 
 * ``"kernel"`` both: Pallas calls, one a row of the batch. The summaries':
   a tile of 256 positions of all heads, a head's 128 lanes at a time, each
-  chunk's 16 positions pooled in VMEM. The attention's: a tile of queries (it
-  lies in one window) walks the summaries before its window, then its
-  window's key blocks up to the diagonal, with one ``(m, l, acc)`` carry; a
+  chunk's 16 positions pooled in VMEM. The attention's: a tile of 512
+  queries (it lies in one window) takes its ``(m, l, acc)`` carry from the
+  diagonal's block of keys, then one loop walks the summaries before its
+  window, 512 a block and the last block masked by column, and its window's
+  key blocks before the diagonal's: 3.75 updates of the carry a tile at
+  eight windows, where blocks of 128 summaries and a carry that began empty
+  made 6 (:func:`_eva_kernel` says what binds it and which forms lost); a
   window's keys and values stay in VMEM over its tiles, a head's summaries
   over all of them, so HBM hands each over once.
 * ``"xla"`` and ``"blocked"``: XLA's forms, elsewhere and on the CPU: the
@@ -201,23 +205,29 @@ def pair_counts(b: int, s: int, window: int, chunk: int) -> tuple:
 
 def eva_tiles(window: int, chunk: int) -> tuple:
     """``(block_q, block_k, block_s)`` of the kernel: the positions of a
-    query tile, the keys of a block and the summaries of a block. A window's
-    summaries (``window / chunk``) are whole blocks, so the prefix a tile
-    walks is never masked."""
-    per_window = window // chunk
-    return 512, 512, per_window if per_window <= 512 else 512
+    query tile, the keys of a block and the summaries of a block, the same
+    for every window and chunk. A tile in window ``w`` updates its carry
+    ``ceil(w (window / chunk) / block_s) + place + 1`` times (``place``: the
+    key blocks of its window before its own): what a step costs is mostly a
+    step's, not a score's (:func:`_eva_kernel`), so a block of summaries is
+    as wide as one of keys, whole or not (the last is masked by column), and
+    one loop walks both kinds."""
+    del window, chunk
+    return 512, 512, 512
 
 
 def eva_form(s: int, d: int, window: int, chunk: int) -> str:
     """Which form the attention is built with: ``"kernel"`` on a TPU in a
     process with one device, for a sequence of whole query tiles, windows of
-    whole key blocks and whole summary blocks, and a head width of whole lane
-    tiles (a head is a block of lanes of the merged heads); ``"blocked"``
-    elsewhere."""
-    block_q, block_k, block_s = eva_tiles(window, chunk)
+    whole key blocks, and a head width of whole lane tiles (a head is a block
+    of lanes of the merged heads); ``"blocked"`` elsewhere. Neither a
+    window's summaries nor the sequence's need be whole blocks: the kernel
+    masks the last block it walks by column, and ``_kernel_row`` pads the
+    sequence's to whole blocks (960 become 1,024) as it pads a partial last
+    window's keys, so that no block is read past the array."""
+    block_q, block_k, _ = eva_tiles(window, chunk)
     if (_on_one_tpu() and s % block_q == 0
             and window % block_k == 0 and block_k % block_q == 0
-            and (window // chunk) % block_s == 0 and block_s % 128 == 0
             and d % 128 == 0):
         return "kernel"
     return "blocked"
@@ -275,51 +285,105 @@ def _blocked_row(q, k, v, kbar, vbar, window, chunk, scale, block):
 
 
 def _eva_kernel(at_ref, q_ref, k_ref, v_ref, kbar_ref, vbar_ref, o_ref, *,
-                scale, window, chunk, block_k, block_s):
+                scale, window, chunk, block):
     """One tile of one head's queries: ``q_ref: (1, BQ, D)``; ``k_ref, v_ref:
     (1, window, D)`` are the keys and values of the tile's window (``window``
     is whole tiles, so a tile lies in one) and stay in VMEM over the window's
-    tiles; ``kbar_ref, vbar_ref: (1, S / chunk, D)`` are the head's every
-    summary, resident over the head's tiles."""
+    tiles; ``kbar_ref, vbar_ref: (1, whole blocks of summaries, D)`` are the
+    head's every summary, resident over the head's tiles.
+
+    **The blocks a tile walks, in order.** (1) The diagonal's block of its
+    window's keys, masked query by key, *sets* the carry: every query sees
+    its own key there, so ``m`` is a score, and no carry of ``-inf`` and
+    zeros is built, spilled and rescaled. (2) One loop of ``walked + ahead``
+    steps: the ``walked = ceil(summaries / block)`` blocks of summaries, of
+    which the last may hold summaries of the tile's own and later windows
+    (or the padding): those columns are masked, by one row of comparisons
+    ``column < summaries - block's first`` spread down the tile; then the
+    ``ahead`` key blocks of the window before the diagonal's, whose limit is
+    the block's width. A step reads its block from the summaries or from the
+    window's keys by a select on the step's index (both lie in VMEM; the
+    block not wanted is read at a clamped index and dropped). The masked
+    columns weigh ``exp(_NEG - m) = 0`` under a maximum that is a score
+    since (1). (3) The division by ``l`` and the write.
+
+    **What binds it** (the v5e compiler's schedule at the cell's shapes,
+    PR 53; the verify skill has the recipe; the chip ran the four forms it
+    was given within 1.2 % of the schedule's ratios: 265.2, 211.8, 203.8
+    and 189.7 ms a step of ``evabyte``'s cell, 44 rows of 32 heads):
+    the one vector-store slot, and almost every store is a spill. The carry
+    is 192 registers of the file's 64 (``acc`` 64; ``m`` and ``l``, ``(512,
+    1)``, 64 each), so every step stores and reloads it and every loop's
+    border copies it: a step of 128 summaries cost 1,013 bundles where one of
+    512 keys cost 1,521, for a quarter of the scores. Bundles a tile (before
+    the loops; a summaries' step x steps; between; a key step x steps; the
+    end), eight windows of 2,048 in chunks of 16, at 1.5 GHz:
+
+    * blocks of 128 summaries never masked, the diagonal last (PR 49): 671;
+      1,013 x 3.5; 479; 1,521 x 1.5; 1,426 = **8,404**;
+    * blocks of 512 summaries masked by column: 668; 1,548 x 1.25; 484;
+      1,530 x 1.5; 1,427 = 6,809;
+    * ... and the diagonal's block first: 1,505; 1,557 x 1.25; 417;
+      1,467 x 1.5; 318 = 6,386;
+    * ... and one loop over both kinds (this): 1,500; 1,499 x 2.75; 319 =
+      **5,941**. The same loop choosing its block by ``lax.cond``: 2,111 a
+      step, lost; its step over row groups of 256, 128 or 64 queries so
+      that a group's scores stay in registers: 2,260, 2,409, 2,793, lost;
+      blocks of 1,024: 2,796; 2,815 x 1.375; 318 = 6,985, lost (half of
+      the diagonal's block is then masked);
+    * the carry in VMEM scratch refs (as jax's own TPU flash attention
+      keeps it), on the second form: 384; 1,849 x 1.25; 17; 1,832 x 1.5;
+      2,372 = 7,832: **lost to the second form, do not repeat**."""
     del at_ref  # read by the block specs
     bq = q_ref.shape[1]
     q = q_ref[0]
     first = pl.program_id(1) * bq  # the tile's first position
     begin = first // window * window  # ... its window's first
     inside = first - begin  # ... and its place in the window
+    summaries = begin // chunk  # every chunk of every earlier window
+    walked = (summaries + block - 1) // block  # ... in so many blocks
 
-    def step(keys_ref, values_ref, size, masked, i, carry):
+    def part(ref, i):
+        return ref[0, pl.ds(pl.multiple_of(i * block, block), block), :]
+
+    def scores(keys):
+        return lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32) * scale
+
+    def weigh(p, values):
+        return lax.dot_general(p.astype(values.dtype), values,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=F32)
+
+    def step(i, carry):
         m, l, acc = carry
-        at = pl.multiple_of(i * size, size)
-        keys = keys_ref[0, pl.ds(at, size), :]
-        values = values_ref[0, pl.ds(at, size), :]
-        s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
-                            preferred_element_type=F32) * scale
-        if masked:  # the diagonal's block
-            seen = (at + lax.broadcasted_iota(jnp.int32, (bq, size), 1)
-                    <= inside + lax.broadcasted_iota(jnp.int32, (bq, size),
-                                                     0))
-            s = jnp.where(seen, s, _NEG)
+        early = i < walked  # a block of summaries, else one of keys
+        i_s = jnp.minimum(i, jnp.maximum(walked - 1, 0))
+        i_k = jnp.maximum(i - walked, 0)
+        keys, values = (jnp.where(early, part(a, i_s), part(b, i_k))
+                        for a, b in ((kbar_ref, k_ref), (vbar_ref, v_ref)))
+        s = scores(keys)
+        # the summaries' last block by column: one row, spread down the tile
+        s = jnp.where(lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                      < jnp.where(early, summaries - i_s * block, block),
+                      s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
-                acc * alpha + lax.dot_general(
-                    p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-                    preferred_element_type=F32))
+                acc * alpha + weigh(p, values))
 
-    carry = (jnp.full((bq, 1), _NEG, F32), jnp.zeros((bq, 1), F32),
-             jnp.zeros((bq, v_ref.shape[2]), F32))
-    # every summary of every earlier window: whole blocks, no mask
-    carry = lax.fori_loop(
-        0, begin // chunk // block_s,
-        functools.partial(step, kbar_ref, vbar_ref, block_s, False), carry)
-    # the window's key blocks wholly before the tile
-    carry = lax.fori_loop(
-        0, inside // block_k,
-        functools.partial(step, k_ref, v_ref, block_k, False), carry)
-    # a tile no wider than a key block (and dividing it) lies in one block
-    _, l, acc = step(k_ref, v_ref, block_k, True, inside // block_k, carry)
+    # the diagonal's block (a tile no wider than a block, and dividing it,
+    # lies in one) sets the carry: every query sees its own key there
+    ahead = inside // block  # the window's blocks wholly before the tile
+    s = scores(part(k_ref, ahead))
+    s = jnp.where(ahead * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                  <= inside + lax.broadcasted_iota(jnp.int32, s.shape, 0),
+                  s, _NEG)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    carry = m, p.sum(axis=-1, keepdims=True), weigh(p, part(v_ref, ahead))
+    _, l, acc = lax.fori_loop(0, walked + ahead, step, carry)
     o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
@@ -336,15 +400,19 @@ def _kernel_row(q, k, v, kbar, vbar, row, *, heads, window, chunk, scale,
     d = merged // heads
     block_q, block_k, block_s = tiles or eva_tiles(window, chunk)
     if (s % block_q or window % block_k or block_k % block_q
-            or (window // chunk) % block_s):
+            or block_s != block_k):
         raise ValueError(f"tiles {(block_q, block_k, block_s)} over {s} "
-                         f"positions in windows of {window}, chunks of "
-                         f"{chunk}")
+                         f"positions in windows of {window}")
+
+    def whole(a, size):
+        short = -a.shape[1] % size
+        return jnp.pad(a, ((0, 0), (0, short), (0, 0))) if short else a
+
     # a partial last window's keys are read as a whole window's: zeros after
-    # the last position, which lie after every query
-    short = -s % window
-    if short:
-        k, v = (jnp.pad(a, ((0, 0), (0, short), (0, 0))) for a in (k, v))
+    # the last position, which lie after every query; the summaries as whole
+    # blocks: zeros after the last, which the kernel's column mask hides
+    k, v = whole(k, window), whole(v, window)
+    kbar, vbar = whole(kbar, block_s), whole(vbar, block_s)
 
     def summaries(n):
         return pl.BlockSpec((1, n, d), lambda h, qi, at: (at[0], 0, h))
@@ -356,7 +424,7 @@ def _kernel_row(q, k, v, kbar, vbar, row, *, heads, window, chunk, scale,
 
     return pl.pallas_call(
         functools.partial(_eva_kernel, scale=scale, window=window,
-                          chunk=chunk, block_k=block_k, block_s=block_s),
+                          chunk=chunk, block=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(heads, s // block_q),

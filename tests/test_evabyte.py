@@ -128,29 +128,68 @@ def test_blocked_form_is_the_references_attention(n):
         rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("n", LENGTHS)
-def test_kernel_under_the_interpreter_is_the_references_attention(n):
-    """Tiles of 16 queries against blocks of 16 keys and 8 summaries (a
-    window's 8 chunks), row 1 of a batch of two read where it lies."""
+# (block_q, block_k, block_s) under the interpreter, in windows of 32 with 8
+# summaries each: a summary block of 2 windows' worth and a tile of half a
+# window; of 4 windows' worth (a walked block holds 1, 2, 3 of its 4 quarters
+# or all, the rest masked by column) with the window one key block, the tile
+# half of it, then all of it (the first window's tile is the diagonal's block
+# alone); a tile a quarter of a window, two to a key block; and blocks of one
+# window's summaries (none ever masked) with four key blocks a window, so a
+# window's last tile walks three before its own
+TILES = [(16, 16, 16), (16, 32, 32), (32, 32, 32), (8, 16, 16), (8, 8, 8)]
+
+
+@pytest.mark.parametrize("n,tiles", [
+    pytest.param(n, tiles, id=f"{n}-" + "x".join(map(str, tiles)))
+    for n in LENGTHS for tiles in TILES if n % tiles[0] == 0])  # whole tiles
+def test_kernel_under_the_interpreter_is_the_references_attention(n, tiles):
+    """Row 1 of a batch of two read where it lies, in float32 at ``highest``:
+    the diagonal's block first, then one loop over the blocks of summaries
+    (the last masked by column, and padded where the sequence's summaries
+    are not whole blocks: 20 of them at 80 positions) and the window's
+    earlier key blocks, equals the reference and XLA's form."""
     q, k, v, mu, phi = _qkv(n, seed=n + 1)
     kbar, vbar = ea.chunk_summaries(k, v, mu, phi, CHUNK)
     with jax.default_matmul_precision("highest"):
         got = ea._kernel_row(q, k, v, kbar, vbar, 1, heads=4, window=WINDOW,
-                             chunk=CHUNK, scale=0.25, tiles=(16, 16, 8),
+                             chunk=CHUNK, scale=0.25, tiles=tiles,
                              interpret=True)
+        blocked = ea._blocked_row(*(_heads(a[1]) for a in (q, k, v, kbar,
+                                                            vbar)),
+                                  WINDOW, CHUNK, 0.25, 16)
     assert got.shape == (n, 64)
     want = _reference_attention(q[1:], k[1:], v[1:], kbar[1:], vbar[1:])[0]
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(blocked).reshape(n, 64),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("tiles", [
+    (16, 16, 8),   # one loop walks both kinds of block: they are as wide
+    (16, 24, 24),  # a window of whole key blocks
+    (32, 16, 16),  # a tile lies in one key block
+    (24, 24, 24),  # a sequence of whole tiles
+])
+def test_kernel_refuses_tiles_that_cannot_work(tiles):
+    q, k, v, mu, phi = _qkv(96, seed=2)
+    kbar, vbar = ea.chunk_summaries(k, v, mu, phi, CHUNK)
+    with pytest.raises(ValueError):
+        ea._kernel_row(q, k, v, kbar, vbar, 0, heads=4, window=WINDOW,
+                       chunk=CHUNK, scale=0.25, tiles=tiles, interpret=True)
 
 
 def test_kernel_at_the_tiles_of_the_published_sizes_in_bfloat16():
-    """512 queries against blocks of 512 keys and 128 summaries, a head a
-    block of 128 lanes: two windows of 2,048 in chunks of 16, under the
-    interpreter, against XLA's form on the same operands."""
-    n, heads, d = 4096, 2, 128
+    """512 queries against blocks of 512 keys and of 512 summaries, a head a
+    block of 128 lanes: three windows of 2,048 in chunks of 16 (the second
+    window's tiles walk a block that holds 128 summaries they see and 256
+    they do not, the third's one with 256 of each, and the 384 summaries are
+    padded to the block), under the interpreter, against XLA's form on the
+    same operands."""
+    n, heads, d = 6144, 2, 128
     q, k, v, mu, phi = _qkv(n, heads, d, rows=1, seed=3, dtype=jnp.bfloat16)
     kbar, vbar = ea.chunk_summaries(k, v, mu, phi, 16)
-    assert ea.eva_tiles(2048, 16) == (512, 512, 128)
+    assert ea.eva_tiles(2048, 16) == (512, 512, 512)
     merged = (q, k, v, kbar, vbar)
     got = ea._kernel_row(*merged, 0, heads=heads, window=2048, chunk=16,
                          scale=d ** -0.5, interpret=True)
@@ -161,9 +200,10 @@ def test_kernel_at_the_tiles_of_the_published_sizes_in_bfloat16():
         np.asarray(want, np.float32), atol=2e-2)
     # and the rule: off a TPU the blocked form, whatever the shapes
     assert ea.eva_form(16384, 128, 2048, 16) == "blocked"
+    # the parent's tiles: a summary block narrower than the key block
     with pytest.raises(ValueError):
         ea._kernel_row(*merged, 0, heads=heads, window=2048, chunk=16,
-                       scale=1.0, tiles=(512, 512, 96), interpret=True)
+                       scale=1.0, tiles=(512, 512, 128), interpret=True)
 
 
 @pytest.mark.parametrize("window", [48, 64])

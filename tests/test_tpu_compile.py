@@ -527,7 +527,10 @@ def test_eva_mixer_compiles_with_the_heads_merged_and_its_loops_found(
     view a head is ever made: each is a copy of half a gigabyte), and the
     two loops over rows are what ``eva_chunks_roofline_share`` and
     ``eva_attention_roofline_share`` look for, each its own and neither the
-    other's."""
+    other's. The attention's loop carries its operands as the mixer made
+    them, 1,024 summaries and 16,384 positions a row and nothing padded (two
+    whole summary blocks of 512: PR 53); the metric's pattern holds the same
+    two shapes, and the line below holds them without it."""
     import re
 
     import storm_tpu.ops.eva_attention as ea
@@ -560,7 +563,29 @@ def test_eva_mixer_compiles_with_the_heads_merged_and_its_loops_found(
         f"eva_{kind}_roofline_share")) for kind in ("chunks", "attention"))
     assert [bool(chunks.search(line)) for line in loops] == [True, False]
     assert [bool(attention.search(line)) for line in loops] == [False, True]
+    assert all(carried in loops[1] for carried in (
+        "s32[4]", "bf16[4,1024,4096]", "bf16[4,16384,4096]"))
     # a partial last window of whole tiles is the kernel's still (its keys
-    # are read as a whole window's); positions short of a tile are XLA's
+    # are read as a whole window's, its 960 summaries as two whole blocks of
+    # 512: ``_kernel_row`` pads both); positions short of a tile are XLA's
     assert ea.eva_form(16384 - 1024, 128, 2048, 16) == "kernel"
     assert ea.eva_form(16384 - 1000, 128, 2048, 8) == "blocked"
+
+
+@pytest.mark.parametrize("s,summaries", [(16384, 1024), (16384 - 1024, 960),
+                                         (2048, 128)])
+def test_eva_attention_kernel_compiles_where_summaries_are_not_whole_blocks(
+        v5e, s, summaries):
+    """A row of the attention's kernel at the cell's widths: the cell's own
+    sequence (two whole summary blocks, nothing padded), one whose last
+    window is partial (960 summaries, padded to 1,024 beside the keys) and a
+    single window (128 summaries that no tile reads, padded to one block)."""
+    import storm_tpu.ops.eva_attention as ea
+
+    q = _spec((2, s, 4096), jnp.bfloat16, v5e)
+    kbar = _spec((2, summaries, 4096), jnp.bfloat16, v5e)
+    text = ea._kernel_row.lower(
+        q, q, q, kbar, kbar, _spec((), jnp.int32, v5e), heads=32,
+        window=2048, chunk=16, scale=128 ** -0.5).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert (" pad(" in text) == (summaries % ea.eva_tiles(2048, 16)[2] != 0)
