@@ -34,6 +34,7 @@ from typing import Any, Callable, Optional
 from storm_tpu.config import SinkConfig
 from storm_tpu.connectors.memory import MemoryBroker
 from storm_tpu.obs import copyledger as _copyledger
+from storm_tpu.obs.profile import end_record
 from storm_tpu.runtime.base import Bolt, OutputCollector, TopologyContext
 from storm_tpu.runtime.tuples import Tuple, merge_offsets
 
@@ -174,6 +175,8 @@ class BrokerSink(Bolt):
     # ---- the three delivery modes --------------------------------------------
 
     async def execute(self, t: Tuple) -> None:
+        if t.record is not None:
+            t.record.t_sink = time.time()
         try:
             key, value = self._map(t)
             topic = self.topic_selector(t)
@@ -235,7 +238,14 @@ class BrokerSink(Bolt):
         """Delivery confirmed: count it, close the trace (egress span +
         exemplar + SLO check), ack. ``t0`` is when the send started, for
         the egress span; the exactly-once sink's commit path reuses this
-        so tracing semantics can't diverge between delivery modes."""
+        so tracing semantics can't diverge between delivery modes. The
+        record log's row ends here too: ``t_produced`` is the send's return
+        (the ack itself under ``fire_and_forget``, the commit under a
+        transaction)."""
+        rec = t.record
+        if rec is not None:
+            rec.t_produced = time.time()
+            end_record(rec, "delivered")
         self._delivered.inc()
         if t.root_ts:
             now = time.perf_counter()
@@ -389,6 +399,8 @@ class TransactionalBrokerSink(BrokerSink):
         self._warned_unknown_tree = False
 
     async def execute(self, t: Tuple) -> None:
+        if t.record is not None:
+            t.record.t_sink = time.time()
         try:
             key, value = self._map(t)
             topic = self.topic_selector(t)

@@ -34,6 +34,7 @@ from typing import Any, Deque, Dict, Optional, Tuple
 from storm_tpu.config import OffsetsConfig
 from storm_tpu.connectors.memory import MemoryBroker, Record
 from storm_tpu.obs import copyledger as _copyledger
+from storm_tpu.obs import profile as _profile
 from storm_tpu.runtime.base import Spout, TopologyContext, OutputCollector
 from storm_tpu.runtime.tracing import NOT_SAMPLED
 from storm_tpu.runtime.tuples import Values
@@ -371,6 +372,8 @@ class BrokerSpout(Spout):
                 records = self.broker.fetch(self.topic, p, pos, size)
             if not records:
                 continue
+            # the record log's t_polled: the fetch has returned
+            polled = time.time() if _profile.enabled() else None
             records = list(records)
             last_off = records[-1].offset
             if self._admission is not None:
@@ -398,13 +401,13 @@ class BrokerSpout(Spout):
                     # carries one qos_lane value), so the slice is split by
                     # lane; without QoS the slice ships whole.
                     for group in self._lane_groups(records[i : i + self.chunk]):
-                        await self._emit_chunk(group)
+                        await self._emit_chunk(group, polled)
                         if self._txn_mode:
                             self._part_inflight[p] = \
                                 self._part_inflight.get(p, 0) + 1
             else:
                 for rec in records:
-                    await self._emit(rec)
+                    await self._emit(rec, polled)
                     if self._txn_mode:
                         self._part_inflight[p] = \
                             self._part_inflight.get(p, 0) + 1
@@ -509,11 +512,14 @@ class BrokerSpout(Spout):
                                copies=len(records), allocs=len(records),
                                records=len(records), engine=comp)
 
-    async def _emit_chunk(self, records: "list[Record]") -> None:
+    async def _emit_chunk(self, records: "list[Record]",
+                          polled: Optional[float] = None) -> None:
         first, last = records[0], records[-1]
         msg_id = ("c", first.partition, first.offset, last.offset)
         self.pending[msg_id] = records
         root_ts = self._append_root_ts(first)
+        # one row of the record log a tuple: the first record's append
+        row = _profile.new_record_row(first.timestamp, polled, len(records))
         self._ledger_ingest(records)
         if self.frames:
             # Batch ingress (ROADMAP-2 zero-copy): the whole chunk rides
@@ -543,12 +549,19 @@ class BrokerSpout(Spout):
                 {(self.topic, first.partition, last.offset + 1)}),
             trace=self._mint_trace(root_ts, first.partition, first.offset,
                                    len(records)),
+            record=row,
         )
+        if row is not None:
+            row.t_emitted = time.time()
 
-    async def _emit(self, rec: Record) -> None:
+    async def _emit(self, rec: Record,
+                    polled: Optional[float] = None) -> None:
         msg_id = (rec.partition, rec.offset)
         self.pending[msg_id] = rec
         root_ts = self._append_root_ts(rec)
+        # the record log's row: the broker's own stamp, the fetch's return
+        # (now for a replay), and below the emit's return
+        row = _profile.new_record_row(rec.timestamp, polled)
         self._ledger_ingest([rec])
         vals = [self._scheme_value(rec.value)]
         if self.qos is not None:
@@ -559,7 +572,10 @@ class BrokerSpout(Spout):
             root_ts=root_ts,
             origins=frozenset({(self.topic, rec.partition, rec.offset + 1)}),
             trace=self._mint_trace(root_ts, rec.partition, rec.offset),
+            record=row,
         )
+        if row is not None:
+            row.t_emitted = time.time()
 
     @staticmethod
     def _msg_part_off(msg_id) -> Tuple[int, int]:

@@ -95,7 +95,7 @@ class Submission:
     of its records rode one batch together."""
 
     __slots__ = ("data", "payload", "ts", "enq", "lane", "tenant", "source",
-                 "deadline", "future", "batch_span", "notify")
+                 "deadline", "future", "batch_span", "notify", "step")
 
     def __init__(self, data, payload, ts: float, enq: float,
                  lane: Optional[str], tenant: Optional[str], source: str,
@@ -111,6 +111,8 @@ class Submission:
         self.future: Future = Future()
         self.batch_span: Optional[str] = None
         self.notify = notify
+        # the step log's row of the batch that took it (None: no step log)
+        self.step: Optional[dict] = None
 
     @property
     def rows(self) -> int:
@@ -604,6 +606,9 @@ class ContinuousBatcher:
             self._cond.notify_all()
         rows = sum(it.rows for it in items)
         t_disp = t_disp if t_disp is not None else t_form
+        row = getattr(handle, "step", None)
+        for it in items:
+            it.step = row
         if exc is not None:
             # Exactly-once per source: every member record fails with the
             # batch's exception and each source replays ITS OWN tuples.
@@ -658,7 +663,6 @@ class ContinuousBatcher:
             n = it.rows
             it.future.set_result(out[ofs:ofs + n])
             ofs += n
-        row = getattr(handle, "step", None)
         if row is not None:
             row["t_resolved"] = time.time()
         self._notify(items)

@@ -40,6 +40,7 @@ from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
 from storm_tpu.infer.continuous import continuous_for
 from storm_tpu.infer.engine import InferenceEngine, shared_engine
 from storm_tpu.obs import copyledger as _copyledger
+from storm_tpu.obs.profile import end_record
 from storm_tpu.runtime.base import Bolt, OutputCollector, TopologyContext
 from storm_tpu.runtime.frames import RecordFrame
 from storm_tpu.runtime.tracing import NOT_SAMPLED, span
@@ -464,12 +465,20 @@ class InferenceBolt(Bolt):
             # payload as text, not a bytes repr
             payload = payload.decode("utf-8", "replace")
         dl = DeadLetter(payload=str(payload), error=error)
+        rec = anchor.record
+        if rec is not None:
+            # the record's row of the record log ends here; the dead letter
+            # is another sink's delivery and carries none
+            end_record(rec, "dead_lettered")
         await self.collector.emit(
             Values([dl.to_json(), *self._extras(anchor)]),
-            stream="dead_letter", anchors=[anchor],
+            stream="dead_letter", anchors=[anchor], record=False,
         )
 
     async def execute(self, t: Tuple) -> None:
+        rec = t.record
+        if rec is not None:
+            rec.t_exec = time.time()
         if t.root_ts:
             # Stage 1 of the decomposition: broker append -> bolt arrival
             # (broker queueing + spout fetch/decode + inter-operator hop).
@@ -512,6 +521,8 @@ class InferenceBolt(Bolt):
         except SchemaError as e:
             await self._dead_letter(t, payload, str(e))
             return
+        if rec is not None:
+            rec.t_parsed = time.time()
         await self._submit_record(t, inst.data, t.root_ts or None, lane,
                                   entry)
 
@@ -533,6 +544,8 @@ class InferenceBolt(Bolt):
                 await self._emit_dead_letter(t, payload, str(e))
                 handle.done(True, self.collector)
                 continue
+            if t.record is not None:
+                t.record.t_parsed = time.time()
             await self._submit_record(handle, inst.data, t.root_ts or None,
                                       lane, entry)
 
@@ -559,6 +572,10 @@ class InferenceBolt(Bolt):
         escalation to the next tier must not park behind the row bound its
         own completion frees)."""
         self._cb_rows += int(data.shape[0])
+        rec = self._anchor_of(item).record
+        if rec is not None:
+            # before the submit: the cut may come before it returns
+            rec.t_enq = time.time()
         self._cbs[tier].submit(
             data, payload=item, ts=ts, lane=lane, tenant=tenant,
             source=self._cb_source, notify=self._cb_notify[tier])
@@ -573,6 +590,16 @@ class InferenceBolt(Bolt):
             pass  # loop closed under a shutdown: nobody is left to emit to
 
     def _spawn_group(self, tier, members) -> None:
+        now = None
+        for sub in members:
+            rec = self._anchor_of(sub.payload).record
+            if rec is not None:
+                # the loop has the group: its step's key, and t_egress
+                now = now or time.time()
+                rec.t_egress = now
+                if sub.step is not None:
+                    rec.engine = sub.step["engine"]
+                    rec.step = sub.step["step"]
         task = self._loop.create_task(self._finish_group(tier, members))
         self._inflight.add(task)
         task.add_done_callback(self._inflight.discard)
@@ -688,6 +715,11 @@ class InferenceBolt(Bolt):
                 with span(self.context.metrics, self.context.component_id,
                           "encode"):
                     msg = self._encode_ledgered(preds, records=len(group))
+                rec = anchor.record
+                if rec is not None:
+                    rec.t_encoded = time.time()
+                    # a group's records leave as one output
+                    rec.left -= len(group) - 1
                 await self.collector.emit(
                     Values([msg, *self._extras(anchor)]), anchors=[anchor])
             except Exception as e:

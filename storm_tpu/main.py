@@ -561,7 +561,7 @@ def _profile_cmd(args) -> int:
         for shape, c in eng.get("compiles", {}).items():
             print(f"  compile bucket {shape}: n={c['count']} "
                   f"last={round(c['last_ms'], 1)}ms")
-    from storm_tpu.obs.profile import STEP_MOMENTS
+    from storm_tpu.obs.profile import RECORD_INTERVALS, STEP_MOMENTS
 
     steps = out.get("profile", {}).get("steps") or {}
     for row in steps.get("last", []):
@@ -578,6 +578,20 @@ def _profile_cmd(args) -> int:
               f"{round(gap['gap_ms'], 2)}ms before {gap['after']['engine']} "
               f"#{gap['after']['step']}; its {gap['interval']} is "
               f"{round(gap['over_median_ms'] or 0.0, 2)}ms over the median")
+    records = out.get("profile", {}).get("records") or {}
+    if records.get("intervals"):
+        print(f"a record's way, ms p50/p90 over {records['count']} logged: "
+              + " ".join(f"{name}={round(v['p50'], 2)}/{round(v['p90'], 2)}"
+                         for name, v in records["intervals"].items()))
+    slow = records.get("slowest")
+    if slow:
+        print(f"slowest record: "
+              f"{round((slow['t_produced'] - slow['t_append']) * 1e3, 2)}ms "
+              f"append to produced, step {slow['engine']} #{slow['step']}: "
+              + " ".join(f"{name}={round((slow[b] - slow[a]) * 1e3, 2)}"
+                         for name, a, b in RECORD_INTERVALS
+                         if slow.get(a) is not None
+                         and slow.get(b) is not None))
     slo = out.get("slo")
     if slo:
         print(f"slo: fast_burn={slo.get('fast_burn')} "

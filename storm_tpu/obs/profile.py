@@ -26,7 +26,9 @@ that file back as the regression sentinel's baseline
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
+from operator import attrgetter
 from typing import Dict, List, Optional
 
 from storm_tpu.runtime.metrics import Histogram
@@ -55,6 +57,105 @@ STEP_MOMENTS = ("t_first_enq", "t_cut", "t_staged", "t_launched", "t_ready",
 # the intervals between them, as ``longest_gap`` names them: ``cut->staged``
 STEP_INTERVALS = tuple((f"{a[2:]}->{b[2:]}", a, b)
                        for a, b in zip(STEP_MOMENTS, STEP_MOMENTS[1:]))
+
+
+# The record log: the last this many records of the process, one row a root
+# tuple (a broker record; a chunk of them where the spout chunks).
+RECORD_LOG = 16384
+
+# The moments of a record on the host, in the order they pass
+# (docs/OPERATIONS.md, "Reading the record log"), on the step log's clock.
+# Between ``t_enq`` and ``t_egress`` lie the moments of the step that took
+# it, which a row names by ``engine`` and ``step`` and does not copy.
+RECORD_MOMENTS = ("t_append", "t_polled", "t_emitted", "t_exec", "t_parsed",
+                  "t_enq", "t_egress", "t_encoded", "t_sink", "t_produced")
+# a row of the log, in this order
+RECORD_FIELDS = ("records",) + RECORD_MOMENTS[:6] + ("engine", "step") \
+    + RECORD_MOMENTS[6:] + ("ended",)
+# A record's whole way, broker append to broker produce: its own moments with
+# its step's between them, so that consecutive ones tile its latency.
+RECORD_PATH = RECORD_MOMENTS[:6] + STEP_MOMENTS[1:] + RECORD_MOMENTS[6:]
+# the intervals, named as the step's are: ``append->polled``; ``enq->cut``
+# and ``resolved->egress`` cross from one log to the other through the key
+RECORD_INTERVALS = tuple((f"{a[2:]}->{b[2:]}", a, b)
+                         for a, b in zip(RECORD_PATH, RECORD_PATH[1:]))
+
+
+class RecordRow:
+    """What one root tuple carries through the topology (``Tuple.record``,
+    following anchoring as ``root_ts`` does): each site on its way stamps
+    its moment here, and :func:`end_record` writes the row where the record
+    ends. ``left`` counts what is still on its way: records not yet emitted
+    by the operator and outputs not yet delivered by the sink."""
+
+    __slots__ = RECORD_FIELDS + ("left",)
+    row = property(attrgetter(*RECORD_FIELDS),
+                   doc="its row of the log: a tuple in RECORD_FIELDS order")
+
+    def __init__(self, t_append: float, t_polled: float,
+                 records: int = 1) -> None:
+        self.records = self.left = records
+        self.t_append = t_append
+        self.t_polled = t_polled
+        self.t_emitted = self.t_exec = self.t_parsed = self.t_enq = None
+        self.engine = self.step = None
+        self.t_egress = self.t_encoded = self.t_sink = self.t_produced = None
+        self.ended = None
+
+
+def new_record_row(t_append: float, t_polled: Optional[float] = None,
+                   records: int = 1) -> Optional[RecordRow]:
+    """The holder a spout makes beside ``root_ts``: ``t_append`` is the
+    first record's broker timestamp as the broker stamped it, ``t_polled``
+    when the spout had it off the broker (now where it is not said: a
+    replay). None while the profiler is off: every site then pays one check."""
+    if not _ENABLED:
+        return None
+    return RecordRow(t_append,
+                     time.time() if t_polled is None else t_polled, records)
+
+
+def end_record(rec: RecordRow, how: str) -> None:
+    """One record of ``rec`` ended ``dead_lettered`` at the operator, one of
+    its outputs ``delivered`` at the sink, or its tuple ``failed`` (the
+    collector's ``fail``: the whole tree replays): the row is written, once,
+    when nothing of it is left on its way. A row that did not end delivered
+    says so, whatever ended last."""
+    if rec.left <= 0:
+        return  # written
+    rec.left = 0 if how == "failed" else rec.left - 1
+    if rec.ended is None and (how != "delivered" or not rec.left):
+        rec.ended = how
+    if not rec.left:
+        _STORE.log_record(rec.row)
+
+
+def record_paths(records: List[dict], steps: List[dict]) -> List[dict]:
+    """Each row of ``records`` (``ProfileStore.records()``) with the moments
+    of its step beside its own (``RECORD_PATH``; None where ``steps`` no
+    longer holds the row, or the record met no step)."""
+    by_key = {(s["engine"], s["step"]): s for s in steps}
+    out = []
+    for r in records:
+        s = by_key.get((r["engine"], r["step"])) or {}
+        out.append(dict(r, **{m: s.get(m) for m in STEP_MOMENTS[1:]}))
+    return out
+
+
+def record_intervals(paths: List[dict]) -> Dict[str, dict]:
+    """Over ``paths`` (:func:`record_paths`), each interval of
+    ``RECORD_INTERVALS`` that some row passed: how many did, and the
+    median and 90th percentile of its milliseconds."""
+    out = {}
+    for name, a, b in RECORD_INTERVALS:
+        spans = sorted((r[b] - r[a]) * 1e3 for r in paths
+                       if r.get(a) is not None and r.get(b) is not None)
+        if spans:
+            out[name] = {"count": len(spans),
+                         "p50": spans[len(spans) // 2],
+                         "p90": spans[min(len(spans) - 1,
+                                          len(spans) * 9 // 10)]}
+    return out
 
 
 def new_step_row(step: int, engine: str, padded: int, rows: int,
@@ -128,6 +229,7 @@ class ProfileStore:
         self._compiles: Dict[str, Dict[int, Dict[str, float]]] = {}
         self._baseline: Optional[dict] = None
         self._steps: deque = deque(maxlen=STEP_LOG)
+        self._records: deque = deque(maxlen=RECORD_LOG)
 
     # ---- the write path (engine layer) ---------------------------------------
 
@@ -157,6 +259,12 @@ class ProfileStore:
             b.stages[stage].observe(float(v))
         b.stages["device_ms"].observe(total)
 
+    def log_record(self, row: tuple) -> None:
+        """One ended record (:func:`end_record`): its row of the record log,
+        in ``RECORD_FIELDS`` order."""
+        with self._lock:
+            self._records.append(row)
+
     def record_compile(self, key: str, padded: int, ms: float) -> None:
         with self._lock:
             per = self._compiles.setdefault(key, {})
@@ -173,6 +281,7 @@ class ProfileStore:
             self._buckets.clear()
             self._compiles.clear()
             self._steps.clear()
+            self._records.clear()
 
     # ---- the read path -------------------------------------------------------
 
@@ -182,14 +291,38 @@ class ProfileStore:
         with self._lock:
             return [dict(r) for r in self._steps]
 
+    def records(self) -> List[dict]:
+        """The record log, oldest first: the last ``RECORD_LOG`` rows, each
+        a dict of ``RECORD_FIELDS``. ``(engine, step)`` is the key of the
+        row of :meth:`steps` that took the record."""
+        with self._lock:
+            rows = list(self._records)
+        return [dict(zip(RECORD_FIELDS, r)) for r in rows]
+
     def snapshot(self) -> dict:
         """JSON-safe curves: per engine, per padded bucket, per stage
         {count, mean, p50, p95, max} plus rows/s throughput; compile cost
         per shape. Bucket keys are stringified ints (JSON round-trip).
         ``steps``: how many rows the step log holds, its last eight, and
         the longest gap between two steps with the interval it went to
-        (:func:`longest_gap`)."""
+        (:func:`longest_gap`). ``records``: how many rows the record log
+        holds, every interval's median and 90th percentile over them
+        (:func:`record_intervals`), and the delivered record that took
+        longest from append to produce, with its step's moments."""
         rows = self.steps()
+        paths = record_paths(self.records(), rows)
+        whole = [r for r in paths if r["t_produced"] is not None]
+        return {"engines": self._engines(),
+                "steps": {"count": len(rows), "last": rows[-8:],
+                          "longest_gap": longest_gap(rows)},
+                "records": {"count": len(paths),
+                            "intervals": record_intervals(paths),
+                            "slowest": max(
+                                whole, default=None, key=lambda r:
+                                r["t_produced"] - r["t_append"])}}
+
+    def _engines(self) -> Dict[str, dict]:
+        """The curves of :meth:`snapshot`, alone."""
         with self._lock:
             buckets = {k: dict(v) for k, v in self._buckets.items()}
             compiles = {k: {str(n): dict(c) for n, c in v.items()}
@@ -222,9 +355,7 @@ class ProfileStore:
                 }
             engines[key] = {"buckets": rows_out,
                             "compiles": compiles.get(key, {})}
-        return {"engines": engines,
-                "steps": {"count": len(rows), "last": rows[-8:],
-                          "longest_gap": longest_gap(rows)}}
+        return engines
 
     def cost_of(self, key: str,
                 min_samples: int = 1) -> Optional[dict]:
@@ -312,7 +443,7 @@ class ProfileStore:
         base = self.baseline
         if base is None:
             return []
-        live = self.snapshot()["engines"]
+        live = self._engines()
         out: List[dict] = []
         for key, eng in base.get("engines", {}).items():
             for bucket, row in eng.get("buckets", {}).items():

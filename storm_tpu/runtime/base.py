@@ -17,6 +17,7 @@ import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 from storm_tpu.obs import copyledger as _copyledger
+from storm_tpu.obs.profile import end_record
 from storm_tpu.runtime.groupings import DirectGrouping
 from storm_tpu.runtime.tracing import NOT_SAMPLED
 from storm_tpu.runtime.tuples import Tuple, Values, merge_offsets, new_id
@@ -84,6 +85,7 @@ class OutputCollector:
         origins: Optional[frozenset] = None,
         direct_task: Optional[int] = None,
         trace: Any = None,
+        record: Any = None,
     ) -> int:
         """Emit a tuple downstream. Returns the number of deliveries.
 
@@ -94,6 +96,11 @@ class OutputCollector:
 
         ``direct_task`` (normally via :meth:`emit_direct`) delivers only to
         subscriptions using ``DirectGrouping``, at that instance index.
+
+        ``record``: the root's row of the record log (a spout's; see
+        ``Tuple.record``). An anchored emit takes its anchors'; ``False``
+        says this output carries none (a dead letter: its record's row has
+        ended).
         """
         fields = self._out_fields.get(stream, ("message",))
         subs = self._rt.router.subscriptions(self.component_id, stream)
@@ -113,6 +120,14 @@ class OutputCollector:
                     if a.trace is not None:
                         trace = a.trace
                         break
+            if record is None:
+                # The record log's row follows anchoring too: of several
+                # anchors' the one appended first, as root_ts is the least.
+                for a in anchor_list:
+                    r = a.record
+                    if r is not None and (record is None
+                                          or r.t_append < record.t_append):
+                        record = r
             if origins is None and any(a.origins for a in anchor_list):
                 # Provenance follows anchoring: a derived tuple carries the
                 # source-log positions of everything it was computed from.
@@ -205,6 +220,7 @@ class OutputCollector:
                 root_ts=ts,
                 origins=origin_set,
                 trace=trace,
+                record=record or None,
             )
             await inbox.put(t)
             n += 1
@@ -248,6 +264,9 @@ class OutputCollector:
         for r in t.anchors:
             self._rt.ledger.fail_root(r)
         self._m_failed.inc()
+        if t.record is not None:
+            # its tree replays under a new row of the record log
+            end_record(t.record, "failed")
 
     def report_error(self, err: BaseException) -> None:
         self._rt.report_error(self.component_id, self.task_index, err)

@@ -96,6 +96,11 @@ class Tuple:
     # Distributed-trace context (tracing.TraceContext) — None unless this
     # record was sampled, so the tracing-off hot path pays only the field.
     trace: Optional[Any] = None
+    # Its root's row of the record log in the making (obs/profile.py
+    # RecordRow): the one object the spout made, so that every site on the
+    # record's way stamps the same row. None while the profiler is off, over
+    # the dist wire, and from a spout that makes none.
+    record: Optional[Any] = None
 
     def __getitem__(self, i: int) -> Any:
         return self.values[i]
