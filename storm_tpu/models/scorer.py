@@ -19,6 +19,7 @@ embedding key 0, the head key 1 and every branch of the plan the next.
 from __future__ import annotations
 
 from contextlib import nullcontext
+from functools import partial
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -78,7 +79,11 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
     """The dropless sigmoid top-k expert layer with a shared expert
     (parallel/moe.py) as a branch: it routes from the float32 norm, names its
     own parts and counts the tokens of each held expert and the assignments
-    that fell on absent ones."""
+    that fell on absent ones. The counts' reader is told the tile and the
+    router's width (read off ``init``'s shapes), from which the layer chose
+    its loops' sizes."""
+    width = jax.eval_shape(init, jax.ShapeDtypeStruct(
+        (2,), jnp.uint32))["router"].shape[1]
     return Branch(
         norm, name, init,
         lambda p, y, _: topk_moe_layer(
@@ -86,7 +91,7 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
             renormalize=True, scale=scale, tile=tile),
         scope=None, cast=None,
         counts=(("expert_tokens", (held,)), ("expert_absent", ())),
-        observe=observe_expert_counts)
+        observe=partial(observe_expert_counts, tile=tile, width=width))
 
 
 def token_scorer(name: str, num_classes: int, input_shape: tuple,

@@ -200,6 +200,123 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
 _COMBINE_BLOCK = 256
 _COMBINE_ROWS = 512
 
+# A run's last tile may be computed at a smaller size, a multiple of this
+# many rows (a bfloat16 tile of the matrix unit's operand is 128 rows deep).
+_TILE_STEP = 128
+
+
+def tile_sizes(tile: int, expected_run: float, tight: bool) -> tuple:
+    """The row counts a loop over runs cut into tiles of ``tile`` has a body
+    for: ``(tile,)`` or ``(tile, small)``, ``small`` a multiple of
+    ``_TILE_STEP`` under ``tile`` at which a run's last tile is computed
+    where its places fit. Two sizes at most: a body is a copy of the loop's
+    whole work in the program, a layer, and costs its share of every load
+    (PERF.md, PRs 55 and 56). ``expected_run``: the mean length of a run,
+    from shapes alone. Of a run of a tile or more the last tile's places are
+    as good as uniform over the tile, and half a tile spares the most rows.
+    A shorter run is its own last tile, and ``small`` is the least size that
+    holds the expectation: by itself where runs are as wide as a router is
+    uneven (an expert's: no margin covers them, and the size serves the
+    larger half), cleared by four roots of it where the run is a sum over a
+    block's tokens and ``tight`` (a block's held assignments: 256 +- 16 miss
+    a size of 256 half the time and one of 384 never). Where no size under
+    the tile does that the loop keeps one: a size that half the runs miss
+    read slower on the chip than none (PERF.md, PR 56). A ``tile`` that is
+    no multiple of a step (the toy presets' 16) has one size."""
+    if tile % _TILE_STEP:
+        return (tile,)
+    if expected_run >= tile:
+        small = tile // 2
+    else:
+        small = expected_run + (4.0 * math.sqrt(expected_run) if tight else 0)
+    small = max(1, math.ceil(small / _TILE_STEP)) * _TILE_STEP
+    return (tile, small) if small < tile else (tile,)
+
+
+def rows_computed(count, sizes: tuple):
+    """How many rows a loop with bodies of ``sizes`` (:func:`tile_sizes`)
+    computes for a run of ``count`` places: every tile whole but the last,
+    and the last at the least size that holds its places. Of integers, of
+    arrays of them, of traced ones."""
+    tile, small = sizes[0], sizes[-1]
+    left = count % tile
+    return count - left + (small + (tile - small) * (left > small)) * (
+        left > 0)
+
+
+def _tiles_by_size(n_tiles, most: int, tile_at, sizes: tuple):
+    """The tiles ``0 .. n_tiles - 1`` (``most`` at most; ``tile_at`` as
+    :func:`_runs_in_tiles` gives it) in the order the loops meet them:
+    ``(tiles, ends)``. ``tiles`` is ``(run, start, filled)``, each
+    ``(most,)``: the tiles computed whole first and in their order, then
+    those that fit ``sizes``' small one, in theirs (one stable sort of
+    ``most`` keys that carries the three along), so a run's whole tiles lie
+    together and its last tile, where it is computed small, after all of
+    those. ``ends[k]`` is where the ``k``-th of ``sizes`` ends among them.
+    It only orders and counts: under ``moe.route``."""
+    with jax.named_scope(parts.MOE_ROUTE):
+        number = jnp.arange(most, dtype=jnp.int32)
+        run, start, filled = tile_at(number)
+        if len(sizes) == 1:
+            return (run, start, filled), (n_tiles,)
+        behind = jnp.where(number < n_tiles,  # no tile: behind all
+                           (filled <= sizes[1]).astype(jnp.int32), 2)
+        behind, run, start, filled = jax.lax.sort(
+            (behind, run, start, filled), num_keys=1, is_stable=True)
+        n_whole = jnp.sum(behind == 0, dtype=jnp.int32)
+    return (run, start, filled), (n_whole, n_tiles)
+
+
+def _loop_by_size(tiles, ends, sizes: tuple, at_rows, carry):
+    """``carry`` through ``at_rows(m)(j, (run, start, filled), carry)`` for
+    every place ``j`` of :func:`_tiles_by_size`'s order: a loop a size
+    ``m``, each with a body of its own static row count. (A ``lax.switch``
+    among the sizes inside one loop computes the same, and on a v5e a
+    ``conditional`` whose branches hold matrix products cost 13 to 29 us a
+    tile, more than a last tile's spared rows: PERF.md, PR 55.)"""
+    first = 0
+    for m, end in zip(sizes, ends):
+        one_tile = at_rows(m)
+        carry = jax.lax.fori_loop(first, end, lambda j, c: one_tile(
+            j, tuple(a[j] for a in tiles), c), carry)
+        first = end
+    return carry
+
+
+def _rows_in_that_order(tiles, ends, counts, total: int, tile: int,
+                        zero_row: int):
+    """Every assignment's row of the tiles' buffer where the tile at place
+    ``j`` of :func:`_tiles_by_size`'s order (two sizes) is written at row
+    ``j * tile`` (a write at the loop's own counter is fused into the
+    product that makes it; one at a looked-up row is a pass of its own), in
+    the dispatch's order: ``(total,)``. An assignment's row is its place
+    moved by one of two shifts of its run: its whole tiles lie together from
+    the run's first of them, its small last tile where the order put it.
+    Which run by comparing the place with every run's ends, as
+    :func:`_dispatch` finds its shift; ``zero_row`` for an absent one."""
+    run, _, _ = tiles
+    held = counts.shape[0]
+    slot = jnp.arange(run.shape[0], dtype=jnp.int32)
+    mine = (run[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]) & (
+        slot < ends[1])[None, :]
+    whole = mine & (slot < ends[0])[None, :]
+    n_whole = jnp.sum(whole, axis=1, dtype=jnp.int32)
+    first_whole = jnp.min(jnp.where(whole, slot, slot.shape[0]), axis=1)
+    last = jnp.sum(jnp.where(mine & ~whole, slot, 0), axis=1)  # one at most
+    begin = jnp.cumsum(counts) - counts
+    turn = begin + n_whole * tile  # where the run's small last tile begins
+    place = jnp.arange(total, dtype=jnp.int32)
+    inside = (place >= begin[:, None]) & (place < (begin + counts)[:, None])
+    shift = jnp.where(place < turn[:, None],
+                      (first_whole * tile - begin)[:, None],
+                      (last * tile - turn)[:, None])
+    moved = jnp.sum(jnp.where(inside, shift, 0), axis=0)
+    return jnp.where(place < jnp.sum(counts), place + moved, zero_row)
+
+
+def _tiles_note(sizes: tuple) -> str:
+    return f"last-{sizes[1]}" if len(sizes) > 1 else "whole"
+
 
 def _runs_in_tiles(keys, n_runs: int, tile: int):
     """The runs of equal keys ``0 .. n_runs - 1`` in ascending ``keys`` (a
@@ -210,24 +327,34 @@ def _runs_in_tiles(keys, n_runs: int, tile: int):
     scatter-add nor a pass over them). With the tiles laid end to end in
     the runs' order, place ``q`` of run ``r`` lies at ``q + shift[r]`` among
     the tiles' places; ``n_tiles`` is the tiles' number, and ``tile_at(i)``
-    gives tile ``i`` of them as ``(run, start, filled)``: its run, where
-    among ``keys`` it starts, and how many of its places lie inside the run
-    (``tile`` or more but in a run's last tile)."""
+    gives tile ``i`` of them (tiles ``i`` of any shape) as ``(run, start,
+    filled)``: its run, where among ``keys`` it starts, and how many of its
+    places lie inside the run (``tile`` or more but in a run's last tile).
+    Which run by comparing ``i`` with every run's last tile, and the run's
+    two numbers by comparing it with every run's: a gather of a few dozen
+    values by as many indices the chip's compiler unrolls into a select a
+    value, and a program's size is paid at every load (PERF.md, PR 56)."""
     bounds = jnp.searchsorted(
         keys, jnp.arange(n_runs + 1, dtype=keys.dtype)).astype(jnp.int32)
     starts = bounds[:-1]
     counts = bounds[1:] - starts
     tiles = -(-counts // tile)
     tile_ends = jnp.cumsum(tiles)
-    first_tile = tile_ends - tiles
+    shift = (tile_ends - tiles) * tile - starts
+    run_ends = bounds[1:] + shift  # among the tiles' places
+    runs = jnp.arange(n_runs, dtype=jnp.int32)
 
     def tile_at(i):
-        run = jnp.searchsorted(tile_ends, i, side="right",
-                               method="compare_all").astype(jnp.int32)
-        j = i - first_tile[run]  # this tile within its run
-        return run, starts[run] + j * tile, counts[run] - j * tile
+        i = jnp.asarray(i, jnp.int32)
+        run = jnp.sum(tile_ends <= i[..., None], axis=-1, dtype=jnp.int32)
+        mine = run[..., None] == runs
 
-    return counts, first_tile * tile - starts, tile_ends[-1], tile_at
+        def of_run(a):
+            return jnp.sum(jnp.where(mine, a, 0), axis=-1)
+
+        return run, i * tile - of_run(shift), of_run(run_ends) - i * tile
+
+    return counts, shift, tile_ends[-1], tile_at
 
 
 def _dispatch(local, weights, held: int, tile: int):
@@ -256,7 +383,8 @@ def _dispatch(local, weights, held: int, tile: int):
     return counts, number_at, weight_at, row_at, zero_row, n_tiles, tile_at
 
 
-def _combine_held(out, row_at, token_at, n: int, top_k: int):
+def _combine_held(out, row_at, token_at, n: int, top_k: int,
+                  held_share: float):
     """``(n, dim)`` float32: each token's sum of its held assignments' rows
     of ``out``, reading no other row. The assignments come as pairs in any
     order, ``row_at`` the row of ``out`` and ``token_at`` the token (an
@@ -267,15 +395,26 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int):
     is cut into tiles of ``_COMBINE_ROWS``, and a loop over as many tiles as
     the data made gathers a tile's rows and adds them to the block's tokens
     by a 0/1 matrix on the matrix unit (products with 0 and 1 are exact, the
-    sum is float32), so the order inside a block is immaterial."""
+    sum is float32), so the order inside a block is immaterial. A block's
+    run is short of a tile wherever a part of the router is held, so its
+    last tile, which is mostly its only one, gathers and multiplies
+    :func:`tile_sizes`' small size where its places fit, in a loop of that
+    size (:func:`_loop_by_size`; a block's whole tiles are added first and
+    in their order, its last tile after them, as one loop over all would).
+    ``held_share``: the part of the assignments that is held, by shapes.
+    Noted as ``combine_tiles=last-<small>`` (``whole``: one size)."""
     dim = out.shape[1]
     block = min(_COMBINE_BLOCK, -(-n // 8) * 8)
     rows = min(_COMBINE_ROWS, block * top_k)
     blocks = -(-n // block)
+    sizes = tile_sizes(rows, block * top_k * held_share, tight=True)
+    _note("combine_tiles", _tiles_note(sizes))
     block_at, row_at, token_at = jax.lax.sort(
         (jnp.where(row_at < out.shape[0] - 1, token_at // block, blocks),
          row_at, token_at), num_keys=1, is_stable=False)
     _, _, n_tiles, tile_at = _runs_in_tiles(block_at, blocks, rows)
+    tiles, ends = _tiles_by_size(n_tiles, -(-row_at.shape[0] // rows) + blocks,
+                                 tile_at, sizes)
     # a slice of ``rows`` from any start inside the assignments stays inside
     row_at, token_at = jnp.pad(row_at, (0, rows)), jnp.pad(token_at, (0, rows))
     lane = jnp.arange(rows, dtype=jnp.int32)
@@ -283,21 +422,23 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int):
     # a float32 row times 1 stays float32 only at ``highest``
     exact = None if out.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
 
-    def one_tile(i, y):
-        b, start, filled = tile_at(i)
-        valid = lane < filled  # past the run's end: the zero row
-        picked = out[jnp.where(valid, jax.lax.dynamic_slice(
-            row_at, (start,), (rows,)), out.shape[0] - 1)]
-        at = jax.lax.dynamic_slice(token_at, (start,), (rows,)) - b * block
-        mine = (slot[:, None] == at[None, :]).astype(out.dtype)
-        add = jnp.dot(mine, picked, precision=exact,
-                      preferred_element_type=jnp.float32)
-        corner = (b * block, 0)
-        return jax.lax.dynamic_update_slice(y, jax.lax.dynamic_slice(
-            y, corner, (block, dim)) + add, corner)
+    def at_rows(m):
+        def one_tile(_, tile_j, y):
+            b, start, filled = tile_j
+            valid = lane[:m] < filled  # past the run's end: the zero row
+            picked = out[jnp.where(valid, jax.lax.dynamic_slice(
+                row_at, (start,), (m,)), out.shape[0] - 1)]
+            at = jax.lax.dynamic_slice(token_at, (start,), (m,)) - b * block
+            mine = (slot[:, None] == at[None, :]).astype(out.dtype)
+            add = jnp.dot(mine, picked, precision=exact,
+                          preferred_element_type=jnp.float32)
+            corner = (b * block, 0)
+            return jax.lax.dynamic_update_slice(y, jax.lax.dynamic_slice(
+                y, corner, (block, dim)) + add, corner)
+        return one_tile
 
-    y = jax.lax.fori_loop(0, n_tiles, one_tile,
-                          jnp.zeros((blocks * block, dim), jnp.float32))
+    y = _loop_by_size(tiles, ends, sizes, at_rows,
+                      jnp.zeros((blocks * block, dim), jnp.float32))
     return y[:n]
 
 
@@ -318,9 +459,11 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     each assignment's number and weight; how many a held expert has is where
     its run ends among the sorted keys (a bisection), and an assignment's
     row of the tiles' buffer is its place in that order plus its expert's
-    offset, found by comparing its key with the held experts' numbers. No
-    gather or scatter runs over the assignments. Noted as
-    ``expert_dispatch=sorted``.
+    offset, found by comparing its key with the held experts' numbers (one
+    of its expert's two offsets where the loop has two sizes and the tiles
+    lie in the buffer in the order the loops meet them:
+    :func:`_rows_in_that_order`). No gather or scatter runs over the
+    assignments. Noted as ``expert_dispatch=sorted``.
 
     The grouped product: each expert's run is cut into tiles of ``tile``
     rows, and a loop over as many tiles as the routing made gathers a tile's
@@ -329,11 +472,21 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     stacked parameters say; the shared expert likewise, at its own width)
     and writes the weighted result to the tile's place in a buffer. The
     loop's length is the data's, so whatever the routing no token is dropped
-    and no padding up to a capacity is computed; the only waste is each
-    run's last, partly filled tile. The buffer alone has the worst case's
-    size, and is allocated, not filled: the loop writes every tile it counts
-    whole, and the one row read without being written, the zero row behind
-    them, is set to zero by itself.
+    and no padding up to a capacity is computed; the only waste is in each
+    run's last, partly filled tile, and that tile is computed at a smaller
+    size where its places fit one: the loop has at most two sizes
+    (:func:`tile_sizes`: ``tile`` and one ``small``, chosen from the shapes
+    the layer is called with), the tiles are parted by the size they need
+    (:func:`_tiles_by_size`), and each size has a loop of its own that
+    gathers, multiplies and writes that many rows at its counter's place in
+    the buffer (:func:`_loop_by_size`). Noted as
+    ``expert_tiles=last-<small>`` (``whole``: one size, as where ``tile`` is
+    no multiple of 128 rows). The buffer alone has the worst case's size,
+    and is allocated, not filled: the loops write every row an assignment
+    has, rows of a small tile past its size stay as they were allocated and
+    no one reads them (the combine reads held rows and the zero row), and
+    the one row read without being written, the zero row behind them all,
+    is set to zero by itself.
 
     A token's result is the float32 sum of its assignments' rows there, and
     never a ``[tokens, top_k, dim]`` array (a scatter-add would do it in
@@ -368,6 +521,13 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
                           held).reshape(-1)
         (counts, number_at, weight_at, row_at, zero_row, n_tiles,
          tile_at) = _dispatch(local, weights.reshape(-1), held, tile)
+        width = p["router"].shape[1]
+        sizes = tile_sizes(tile, n * top_k / width, tight=False)
+        tiles, ends = _tiles_by_size(n_tiles, zero_row // tile, tile_at,
+                                     sizes)
+        if len(sizes) > 1:
+            row_at = _rows_in_that_order(tiles, ends, counts, n * top_k,
+                                         tile, zero_row)
         token_at = number_at // top_k
         absent = n * top_k - jnp.sum(counts)
         lane = jnp.arange(tile, dtype=jnp.int32)
@@ -381,22 +541,25 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         token_in, weight_in = (jnp.pad(a, (0, tile))
                                for a in (token_at, weight_at))
 
-    def one_tile(i, out):
-        e, start, filled = tile_at(i)
-        valid = lane < filled  # rows past the run's end: zero
-        ids = jax.lax.dynamic_slice(token_in, (start,), (tile,))
-        rows = tokens[jnp.where(valid, ids, 0)]
-        y = L.feed_forward({name: m[e] for name, m in w.items()}, rows)
-        gain = jnp.where(valid, jax.lax.dynamic_slice(
-            weight_in, (start,), (tile,)), 0.0)
-        y = (y.astype(jnp.float32) * gain[:, None]).astype(out.dtype)
-        return jax.lax.dynamic_update_slice(out, y, (i * tile, 0))
+    def at_rows(m):
+        def one_tile(j, tile_j, out):
+            e, start, filled = tile_j
+            valid = lane[:m] < filled  # rows past the run's end: zero
+            ids = jax.lax.dynamic_slice(token_in, (start,), (m,))
+            rows = tokens[jnp.where(valid, ids, 0)]
+            y = L.feed_forward({name: s[e] for name, s in w.items()}, rows)
+            gain = jnp.where(valid, jax.lax.dynamic_slice(
+                weight_in, (start,), (m,)), 0.0)
+            y = (y.astype(jnp.float32) * gain[:, None]).astype(out.dtype)
+            return jax.lax.dynamic_update_slice(out, y, (j * tile, 0))
+        return one_tile
 
     with jax.named_scope(parts.MOE_EXPERTS):
-        out = jax.lax.fori_loop(0, n_tiles, one_tile, empty)
+        out = _loop_by_size(tiles, ends, sizes, at_rows, empty)
+    _note("expert_tiles", _tiles_note(sizes))
     _note("expert_combine", "held-rows")
     with jax.named_scope(parts.MOE_COMBINE):
-        y = _combine_held(out, row_at, token_at, n, top_k)
+        y = _combine_held(out, row_at, token_at, n, top_k, held / width)
     if "shared" in p:
         with jax.named_scope(parts.PROJ):
             shared = L.feed_forward(p["shared"], tokens)
@@ -406,12 +569,24 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         return y.astype(x.dtype).reshape(shape), counts, absent
 
 
-def observe_expert_counts(metrics, cid: str, tokens, absent) -> None:
+def observe_expert_counts(metrics, cid: str, tokens, absent, *, tile: int,
+                          width: int) -> None:
     """What :func:`topk_moe_layer` counted in one step, fetched to the host
     (``tokens`` ``(layers, held)``, ``absent`` ``(layers,)``), into the
     registry under ``cid``: the assignments held and absent as two counters,
-    and once a layer the busiest held expert over the mean."""
+    the rows that the experts' loop computed for the held ones
+    (:func:`rows_computed` of every expert's count at the sizes the layer
+    chose: :func:`tile_sizes` of its ``tile`` and of the step's assignments
+    over the router's ``width``; the held over these is how full the tiles
+    were; a step of fewer tokens than ``tile`` cuts smaller tiles and
+    computes less than is counted here), and once a layer the busiest held
+    expert over the mean."""
     metrics.counter(cid, "expert_assignments_held").inc(int(tokens.sum()))
+    rows = metrics.counter(cid, "expert_rows_computed")
+    for layer, elsewhere in zip(tokens, absent):
+        run = (int(layer.sum()) + int(elsewhere)) / width
+        rows.inc(int(rows_computed(layer, tile_sizes(tile, run,
+                                                     tight=False)).sum()))
     metrics.counter(cid, "expert_assignments_absent").inc(int(absent.sum()))
     load = metrics.histogram(cid, "expert_tokens_max_over_mean")
     for layer in tokens:

@@ -104,7 +104,8 @@ def test_a_tokens_sum_is_the_plain_sum(case, ffn):
                 p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
         want = _plain(p, x, top_k, first)
     assert seen == [f"expert_ffn={ffn}", "expert_dispatch=sorted",
-                    "expert_combine=held-rows"]
+                    "expert_tiles=whole", "expert_combine=held-rows",
+                    "combine_tiles=whole"]
     np.testing.assert_allclose(y, want, atol=1e-5 * max(1.0, float(
         jnp.abs(want).max())))
     assert int(tokens.sum()) + int(absent) == n * top_k
@@ -152,7 +153,8 @@ def test_the_loop_on_any_share_held(dtype, n, top_k, share):
     token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
     # the pairs in any order: the combine sorts them by block itself
     mixed = jax.random.permutation(jax.random.PRNGKey(4), n * top_k)
-    got = jax.jit(lambda o, r, t: moe._combine_held(o, r, t, n, top_k))(
+    got = jax.jit(lambda o, r, t: moe._combine_held(o, r, t, n, top_k,
+                                                    share))(
         out, row_of[mixed], token[mixed])
     assert got.dtype == jnp.float32 and got.shape == (n, DIM)
     np.testing.assert_allclose(got, want, atol=2e-6)

@@ -317,7 +317,8 @@ def test_relu2_expert_layer_drops_no_token(skew):
         want = _plain_experts(p, x, 2, 0, 2.5) + REFERENCE._relu2(
             p["shared"], x)
     assert seen == ["expert_ffn=relu2", "expert_dispatch=sorted",
-                    "expert_combine=held-rows"]
+                    "expert_tiles=whole", "expert_combine=held-rows",
+                    "combine_tiles=whole"]
     np.testing.assert_allclose(y, want, atol=1e-5 * float(
         jnp.abs(want).max()))
     assert int(tokens.sum()) == 2 * 111 and int(absent) == 0
@@ -472,7 +473,9 @@ def test_the_inventory_names_the_three_forms():
     assert set(forms) == {"short_conv=xla", "ssd_scan=chunked",
                           "expert_ffn=relu2",
                           "expert_dispatch=sorted",
+                          "expert_tiles=whole",
                           "expert_combine=held-rows",
+                          "combine_tiles=last-384",
                           "causal_attention=blocked-grouped"}
 
 

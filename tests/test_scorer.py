@@ -164,6 +164,9 @@ def test_the_model_carries_the_reader_of_what_it_counts(name, ran):
     if "expert_tokens" in aux:
         assert got["expert_assignments_held"] == aux["expert_tokens"].sum()
         assert got["expert_assignments_absent"] == aux["expert_absent"].sum()
+        # tiles of 16 have one size: every held expert's count up to 16s
+        assert got["expert_rows_computed"] == (
+            -(-aux["expert_tokens"] // 16) * 16).sum()
         assert got["expert_tokens_max_over_mean"]["count"] == len(
             aux["expert_tokens"])
         assert "sparse_keys_read" not in got
@@ -215,23 +218,26 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
 # expert models' six lines: ``parallel/moe.py``'s chosen scores and its
 # tiles' buffer; MiniCPM-SALA's two stand. PR 52 gave ``kda_mixer`` a range
 # for its step and ``gqa_mixer`` a gate, both read at trace time: the eight
-# lines stand, and the sixth plan's two are as PR 52 built it.)
+# lines stand, and the sixth plan's two are as PR 52 built it. PR 56 changed
+# the text of the four expert models' eight lines: ``parallel/moe.py``'s two
+# loops meet the tiles in an order made beforehand and have a second loop
+# for a run's last tile at a smaller size; MiniCPM-SALA's two stand.)
 PARENT = {
-    "kimi_linear_tiny": ("ef1901b7f1ec7b74", "9c9231af39d81a3b",
+    "kimi_linear_tiny": ("ceba811f71c7e3d9", "9c9231af39d81a3b",
                          "d6d687f48a145060"),
-    "nemotron_h_tiny": ("972c52862d6e1c56", "ffb1a7d7c726fcef",
+    "nemotron_h_tiny": ("2dae57abe535cd25", "ffb1a7d7c726fcef",
                         "b8b23e69c062a671"),
-    "kimi_k2_tiny": ("8c5bab4bbb0eb196", "4015722ee481843e",
+    "kimi_k2_tiny": ("1d39419de880de54", "4015722ee481843e",
                      "3eee1654ad04fdad"),
     "minicpm_sala_tiny": ("e80bd69b29e9bb6e", "fbaec783e93f7071",
                           "136d1265de921964"),
-    "kimi_linear_48b": ("d7cba0c765ab105b", "35c56c4e58dd4ac8"),
-    "nemotron_3_nano_30b": ("0d451a8c29ce3329", "32502e49d7fc6552"),
-    "kimi_k2_6": ("ea1b682882454006", "4a919d11ba374a7b"),
+    "kimi_linear_48b": ("411585d80f021662", "35c56c4e58dd4ac8"),
+    "nemotron_3_nano_30b": ("c3c98b01400caee8", "32502e49d7fc6552"),
+    "kimi_k2_6": ("3695cae34e2865eb", "4a919d11ba374a7b"),
     "minicpm_sala": ("5de5b298fd713172", "31e93c80f04d610e"),
-    "solar_open2_tiny": ("2fac1bcfe60792e8", "10b47418e20c9cdc",
+    "solar_open2_tiny": ("ec74a4f06f3a9d3e", "10b47418e20c9cdc",
                          "12fec92ac2c8f7b2"),
-    "solar_open2_250b": ("690de9a9897d2e50", "d3a89bd4d4e674a5"),
+    "solar_open2_250b": ("e3192e3869b746e8", "d3a89bd4d4e674a5"),
 }
 
 

@@ -239,26 +239,41 @@ def test_longseq_encoder_forward_compiles_with_flash_kernel(v5e, monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "n,dim,top_k,held,width,hidden,form,tile,stacked,sums", [
+    "n,dim,top_k,held,width,hidden,form,tile,stacked,sums,matmul_ms,"
+    "combine_ms,smalls", [
         (32768, 2304, 8, 32, 256, 1024, "swiglu", 1024,  # Kimi-Linear's cell
-         "bf16[32,2304,1024]", "f32[32768,2304]"),
+         "bf16[32,2304,1024]", "f32[32768,2304]",
+         "expert_matmul_ms", "expert_combine_ms", (512, 384)),
         (32768, 2688, 6, 32, 128, 1856, "relu2", 512,  # Nemotron's cell
-         "bf16[32,2688,1856]", "f32[32768,2688]"),
+         "bf16[32,2688,1856]", "f32[32768,2688]",
+         "relu2_expert_matmul_ms", "expert_combine_ms", (256,)),
         (16384, 7168, 8, 12, 384, 2048, "swiglu", 512,  # Kimi K2's cell
-         "bf16[12,7168,2048]", "f32[16384,7168]"),
-    ], ids=["kimi_linear_48b", "nemotron_3_nano_30b", "kimi_k2_6"])
+         "bf16[12,7168,2048]", "f32[16384,7168]",
+         "k2_expert_matmul_ms", "k2_expert_combine_ms", (384, 128)),
+        (32768, 4096, 8, 40, 320, 1280, "swiglu", 512,  # Solar's cell
+         "bf16[40,4096,1280]", "f32[32768,4096]",
+         "solar_expert_matmul_ms", "solar_expert_combine_ms", (256, 384)),
+    ], ids=["kimi_linear_48b", "nemotron_3_nano_30b", "kimi_k2_6",
+            "solar_open2_250b"])
 def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
-        v5e, n, dim, top_k, held, width, hidden, form, tile, stacked, sums):
+        v5e, n, dim, top_k, held, width, hidden, form, tile, stacked, sums,
+        matmul_ms, combine_ms, smalls):
     """The language cells' expert layer at their real sizes, shapes only:
-    the tile loop still carries the experts' stacked weights and the
-    combine's loop the tokens' float32 sums (the benchmark's
+    the tile loops still carry the experts' stacked weights and the
+    combine's loops the tokens' float32 sums (the benchmark's
     ``*expert_matmul_ms`` and ``*expert_combine_ms`` find the loops in a
-    trace by those shapes, and a listed metric that reads nothing makes a
-    run malformed), each in one loop alone; around them no scatter, no
-    gather of a value an assignment (``route_topk``'s chosen scores are a
-    comparison reduced inside one fusion: no ``[tokens, top_k, width]``
-    array leaves one), and the tiles' buffer is allocated with its last row
-    zeroed in place, neither filled nor copied."""
+    trace by those shapes and add up what they find, and a listed metric
+    that reads nothing makes a run malformed), no loop both: exactly one
+    loop a size, two sizes a loop at most (the tile and ``smalls``' one, the
+    experts' then the combine's, which on Nemotron has none: a body is a
+    copy of the loop's work in the program, a layer, and costs its share of
+    every load), and no ``conditional`` anywhere (one whose branches
+    hold the matrix products cost 13-29 us a tile on the chip); around them
+    no scatter, no gather of a value an assignment (``route_topk``'s chosen
+    scores are a comparison reduced inside one fusion: no ``[tokens, top_k,
+    width]`` array leaves one), and the tiles' buffer is allocated with its
+    last row zeroed in place, never filled, and neither it nor the sums nor
+    an expert's matrices are copied."""
     import math
     import re
 
@@ -272,12 +287,17 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
         p, x, top_k, tile=tile)).lower(p, x).compile().as_text()
     lines = [re.sub(r"\{[^}]*\}", "", line) for line in text.splitlines()]
     loops = [line for line in lines if " while(" in line]
-    assert sum(stacked in line for line in loops) == 1
-    assert sum(sums in line for line in loops) == 1
+    assert sum(stacked in line for line in loops) == 2
+    assert sum(sums in line for line in loops) == len(smalls)
     assert not any(stacked in line and sums in line for line in loops)
+    # and as a trace names them, by the benchmark's own patterns
+    for metric, found in ((matmul_ms, 2), (combine_ms, len(smalls))):
+        assert sum(bool(re.search(_metric_pattern(metric), loop))
+                   for loop in _loops(text)) == found, metric
+    assert " conditional(" not in text
     assert "scatter" not in text
-    # how many slices a gather fetches: a tile's rows or a bisection's
-    # probes, nowhere one an assignment
+    # how many slices a gather fetches: a tile's rows at each of its sizes
+    # or a bisection's probes, nowhere one an assignment
     fetched = set()
     for line in text.splitlines():
         found = re.search(r" = \w+\[([\d,]+)\]\S* gather\(.*"
@@ -287,7 +307,9 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
             offsets = set(found.group(2).split(","))
             fetched.add(math.prod(
                 int(d) for i, d in enumerate(dims) if str(i) not in offsets))
-    assert fetched == {held + 1, n // 256 + 1, tile, 512}
+    tiles = {-(-n * top_k // tile) + held, -(-n * top_k // 512) + n // 256}
+    rows = {held + 1, n // 256 + 1, tile, 512} | set(smalls)
+    assert rows <= fetched <= rows | tiles  # every tile's run, to part them
     # what an operation outside a fusion's body writes is an array in memory
     written, fused = [], False
     for line in lines:
@@ -297,15 +319,26 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
             written.append(line)
     assert not any(f"[{n},{top_k},{width}]" in line.split(" = ")[1].split(
         "(")[0] for line in written)
+    # the buffer is written where it lies: by the zero row's fusion, of no
+    # operand (no ``broadcast`` fills it), and once in each size's loop; the
+    # sums by the fill with zeros and once in each size's loop of the
+    # combine. No ``copy`` of either: not from one loop to the next, not on
+    # to the combine; and none of an expert's matrices into fast memory
     buffer = "bf16[%d,%d]" % ((-(-n * top_k // tile) + held) * tile + 1, dim)
-    makes = [line for line in written if line.split(" = ")[1].startswith(
-        buffer) and " get-tuple-element(" not in line]
-    # two fusions write into it where it lies: the zero row's, of no operand
-    # (no ``broadcast`` fills it), and a tile's in the experts' loop; no
-    # ``copy`` hands it from one to the other or on to the combine
-    assert all(" fusion(" in line for line in makes), makes
+    for array, sizes in ((buffer, 2), (sums, len(smalls))):
+        makes = [line for line in written if line.split(" = ")[1].startswith(
+            array) and not any(f" {op}(" in line for op in (
+                "get-tuple-element", "parameter", "bitcast"))]
+        assert not any(" copy(" in line for line in makes), makes
+        assert all(" fusion(" in line or " dynamic-update-slice(" in line
+                   or " broadcast(" in line for line in makes), makes
+        assert len(makes) == sizes + 1, makes
+    assert not any(re.search(
+        r" = bf16\[(%d,%d|%d,%d)\]\S* copy\(" % (dim, hidden, hidden, dim),
+        line) for line in text.splitlines())
     assert sum(" fusion()" in line and "dynamic-update-slice" in line
-               for line in makes) == 1 and len(makes) == 2, makes
+               and line.split(" = ")[1].startswith(buffer)
+               for line in written) == 1
     assert sum(buffer in line and 'custom_call_target="AllocateBuffer"' in
                line for line in text.splitlines()) == 1
 
