@@ -86,6 +86,7 @@ def _load_builtin() -> None:
         nemotron_h,
         resnet,
         solar_open2,
+        trinity,
         vit,
     )
 

@@ -3,10 +3,12 @@ next-token distribution at its last position out (``heads`` of them, the
 next tokens' one after another, where the model predicts several).
 
 ids -> embedding (times ``scale_emb``) -> a float32 stream ``h`` -> for each
-block, for each of its branches, ``h += residual * branch(RMSNorm(h))`` -> the
-last position's RMS norm (times ``logit_scale``) -> the head; and what the
-branches counted on the way, stacked a layer into ``new_state["aux"]``, which
-the engine fetches with the predictions (``infer/engine.py``).
+block, for each of its branches, ``h += residual * branch(RMSNorm(h))`` (a
+branch that names a ``post`` norm: ``h += residual * RMSNorm_post(branch(
+RMSNorm(h)))``, the sandwich) -> the last position's RMS norm (times
+``logit_scale``) -> the head; and what the branches counted on the way,
+stacked a layer into ``new_state["aux"]``, which the engine fetches with the
+predictions (``infer/engine.py``).
 
 A model's file keeps what is its own: its mixers, its *plan* (a tuple of
 blocks, each a tuple of :class:`Branch`), its scalars, what it makes once a
@@ -72,10 +74,17 @@ class Branch(NamedTuple):
     counts: tuple = ()
     # (registry, component id, *those counts as host arrays, a row a layer)
     observe: Optional[Callable] = None
+    # the block's key of a second RMS norm, of the branch's *output* before
+    # the residual add (in float32, under ``norm``); None: there is none
+    post: Optional[str] = None
+    # where that norm's weights start: what the branch adds to the stream a
+    # channel, whatever its own weights' scale
+    post_scale: float = 1.0
 
 
 def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
-            first_expert: int, scale: float, tile: int) -> Branch:
+            first_expert: int, scale: float, tile: int,
+            post: Optional[str] = None, post_scale: float = 1.0) -> Branch:
     """The dropless sigmoid top-k expert layer with a shared expert
     (parallel/moe.py) as a branch: it routes from the float32 norm, names its
     own parts and counts the tokens of each held expert and the assignments
@@ -91,7 +100,8 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
             renormalize=True, scale=scale, tile=tile),
         scope=None, cast=None,
         counts=(("expert_tokens", (held,)), ("expert_absent", ())),
-        observe=partial(observe_expert_counts, tile=tile, width=width))
+        observe=partial(observe_expert_counts, tile=tile, width=width),
+        post=post, post_scale=post_scale)
 
 
 def token_scorer(name: str, num_classes: int, input_shape: tuple,
@@ -132,9 +142,12 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
             lambda a: a.astype(param_dtype), tree)
 
     def block_init(spec, *keys):
-        made = [init(key) for (_, _, init), key in zip(spec, keys)]
-        return served({k: v for (norm, mine, _), p in zip(spec, made)
-                       for k, v in ((norm, L.rmsnorm_init(dim)), (mine, p))})
+        made = [init(key) for (_, _, init, _, _), key in zip(spec, keys)]
+        return served({
+            k: v for (norm, mine, _, post, scale), p in zip(spec, made)
+            for k, v in ((norm, L.rmsnorm_init(dim)), (mine, p),
+                         (post, {"scale": jnp.full((dim,), scale, f32)}))
+            if k})
 
     def ends_init(ke, kh):
         # A multiplier stands against weights trained under it; a draw that
@@ -158,7 +171,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
         params["layers"], at = [], 2
         for blk in blocks:
             params["layers"].append(one_block(
-                tuple(b[:3] for b in blk),
+                tuple((b.norm, b.name, b.init, b.post, b.post_scale)
+                      for b in blk),
                 *(ks[at + j] for j in range(len(blk)))))
             at += len(blk)
         # what a step counts on the device, in the state in and out
@@ -197,6 +211,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                         counts[key].append(n)
                 with jax.named_scope(P.NORM):
                     y = y.astype(f32)
+                    if b.post:
+                        y = L.rmsnorm(blk[b.post], y, eps)
                     h = h + (y if residual == 1 else residual * y)
         with jax.named_scope(P.HEAD):
             last = L.rmsnorm(params["norm"], h[:, -1], eps)

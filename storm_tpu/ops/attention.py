@@ -19,7 +19,15 @@ with.
   ops/flash_attention.py's kernel with ``causal`` (a block's scores stay in
   VMEM, key blocks beyond the diagonal are never loaded), on a TPU in a
   process with one device for whole tiles; ``"blocked"``,
-  :func:`causal_blocked`, XLA's form, elsewhere and on the CPU.
+  :func:`causal_blocked`, XLA's form, elsewhere and on the CPU. With a
+  ``window`` (Trinity's sliding layers) a query reads its last ``window``
+  keys alone, by the same rule and in the same two forms: the kernel never
+  loads the key blocks that lie before every window of a query tile, the
+  blocked form slices a block of queries' keys from the first block a
+  window reaches, and both mask the blocks that hold a window's lower
+  edge. Such a loop is the part ``mix.window_attention`` in a trace and
+  ``window_attention=...`` in the inventory: the two loops carry the same
+  shapes, so only a name tells them apart.
 
 The forms of one rule are cross-checked in tests/test_ops.py under the Pallas
 interpreter and compiled on the chip by ops/parity_checks.py.
@@ -131,8 +139,8 @@ def causal_form(hq: int, hkv: int, s: int, dk: int, dv: int) -> str:
 
 
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     scale: Optional[float] = None,
-                     block: int = 512) -> jnp.ndarray:
+                     scale: Optional[float] = None, block: int = 512,
+                     window: Optional[int] = None) -> jnp.ndarray:
     """Causal ``softmax(q k^T * scale) v`` for ``q: (B, Hq, S, Dk)``, ``k: (B,
     Hkv, S, Dk)`` and ``v: (B, Hkv, S, Dv)``. ``Dv`` may differ from ``Dk``
     (latent attention: 192 against 128), and ``Hq`` may be a multiple of
@@ -148,33 +156,57 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Within a row, by :func:`causal_form`: the Pallas kernel of
     ops/flash_attention.py, which keeps a block's scores in VMEM, stops at
     the diagonal and reads the row where it lies in q, k and v (the loop
-    cuts nothing out of them); or :func:`causal_blocked`."""
+    cuts nothing out of them); or :func:`causal_blocked`.
+
+    ``window`` (static; None: all of the above as it was): the query at ``t``
+    reads the keys ``t - window < s <= t``, at most ``window`` of them,
+    itself among them. The same rule picks the form; the kernel starts a
+    query tile's loop at the first key block one of its windows reaches and
+    never loads the blocks before it, the blocked form slices from that
+    block, and both mask the block or two that hold a lower edge. The loop is
+    the part ``mix.window_attention`` and the note ``window_attention``. A
+    window of the sequence's length or more bounds nothing: plain causal
+    attention, by that path and under its names."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     hq, hkv, s = q.shape[1], k.shape[1], q.shape[2]
     if hq % hkv:
         raise ValueError(f"{hq} query heads over {hkv} key heads")
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} keys")
+    if window is not None and window >= s:
+        window = None
     form = causal_form(hq, hkv, s, q.shape[-1], v.shape[-1])
-    _note("causal_attention", form + ("-grouped" if hq != hkv else ""))
+    _note("causal_attention" if window is None else "window_attention",
+          form + ("-grouped" if hq != hkv else ""))
     if form == "blocked":
-        return causal_blocked(q, k, v, scale, block)
+        return causal_blocked(q, k, v, scale, block, window)
 
     from storm_tpu.ops.flash_attention import causal_tiles, flash_attention
 
     block_q, block_k = causal_tiles(hq // hkv)
-    with jax.named_scope(P.MIX_ATTENTION):
+    with jax.named_scope(_loop_part(window)):
         return jax.lax.map(lambda i: flash_attention(
             q, k, v, scale=scale, block_q=block_q, block_k=block_k,
-            causal=True, row=i)[0], jnp.arange(q.shape[0]))
+            causal=True, row=i, window=window)[0], jnp.arange(q.shape[0]))
+
+
+def _loop_part(window: Optional[int]) -> str:
+    """The part a row loop of causal attention is in a trace."""
+    return P.MIX_ATTENTION if window is None else P.MIX_WINDOW_ATTENTION
 
 
 def causal_blocked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                   scale: float, block: int = 512) -> jnp.ndarray:
+                   scale: float, block: int = 512,
+                   window: Optional[int] = None) -> jnp.ndarray:
     """:func:`causal_attention` as XLA computes it, on every platform: within
     a row a block of ``block`` queries against the keys up to that block's
     end, so the upper triangle is not computed and at most ``Hq x block x S``
     scores exist at once (XLA writes them to HBM between the two
-    products)."""
+    products). With a ``window`` the keys of a block of queries are sliced
+    from the first block of ``block`` keys that the window of the block's
+    first query reaches (the blocks before it are not read), and the keys
+    at or before ``t - window`` are masked beside the later ones."""
     hq, hkv, s = q.shape[1], k.shape[1], q.shape[2]
     # with one query head a key head the group axis is left out altogether,
     # so that such a program carries the shapes it always has
@@ -189,21 +221,28 @@ def causal_blocked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         outs = []
         for lo in range(0, s, block):
             hi = min(lo + block, s)
-            scores = jnp.einsum(scores_of, qr[..., lo:hi, :], kr[:, :hi],
+            first = 0 if window is None else \
+                max(lo - window + 1, 0) // block * block
+            keys = slice(first, hi) if first else slice(hi)
+            scores = jnp.einsum(scores_of, qr[..., lo:hi, :], kr[:, keys],
                                 preferred_element_type=jnp.float32) * scale
-            later = jnp.arange(hi)[None, :] > jnp.arange(lo, hi)[:, None]
-            scores = jnp.where(later, -jnp.inf, scores)
+            at, query = jnp.arange(first, hi)[None, :], \
+                jnp.arange(lo, hi)[:, None]
+            unseen = at > query
+            if window is not None:
+                unseen |= at <= query - window
+            scores = jnp.where(unseen, -jnp.inf, scores)
             # the weights go to the product unnormalised and the result is
             # divided by their sum: one pass less over the scores
             weights = jnp.exp(scores - scores.max(-1, keepdims=True))
             out = jnp.einsum(values_of, weights.astype(vr.dtype),
-                             vr[:, :hi], preferred_element_type=jnp.float32)
+                             vr[:, keys], preferred_element_type=jnp.float32)
             outs.append((out / weights.sum(-1, keepdims=True)
                          ).astype(vr.dtype))
         out = jnp.concatenate(outs, -2)
         return out.reshape(hq, s, out.shape[-1]) if grouped else out
 
-    with jax.named_scope(P.MIX_ATTENTION):
+    with jax.named_scope(_loop_part(window)):
         return jax.lax.map(row, (q, k, v))
 
 

@@ -34,6 +34,19 @@ second loop: 1.2 ms a step of either language cell less (the compiler copies
 the carry from one loop to the next through VMEM; PERF.md §6, PR 42). ``Dv``
 may differ from ``Dk``.
 
+``window`` (static, with ``causal``; None: the causal form above, text for
+text) also bounds the loop from below: a query at ``t`` reads the keys ``t -
+window < s <= t``, itself among them. For a tile whose first query is
+``first``, the key blocks whose last key is at or before ``first - window``
+lie before every one of the tile's windows and are never loaded nor
+multiplied: the loop starts at the block that holds key ``first - window +
+1``, not at key 0. The block or two that hold some query's lower edge (the
+keys up to ``first + block_q - 1 - window``) run with the mask ``s > t -
+window``, the blocks between them and the diagonal without one, the
+diagonal's as above with both tests. At 16,384 positions and a window of
+2,048 a tile of 64 positions walks five or six blocks of 512 keys where the
+causal form walks 16.5 in the mean.
+
 Shapes are padded: S to block multiples, a width to whole lane tiles unless
 it is whole tiles and a half (``lane_width``: Kimi-Linear's 192-wide keys are
 read as they lie, the block laid out as two lane tiles in VMEM by Mosaic,
@@ -87,7 +100,7 @@ def causal_tiles(group: int) -> tuple:
 
 
 def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
-                 block_k, causal):
+                 block_k, causal, window=None):
     """One tile of queries against its key head's keys, block by block.
 
     ``q_ref: (1, G, BQ, Dk)`` holds the same ``BQ`` positions of the ``G``
@@ -111,6 +124,13 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
                           k_ref.shape[1] // block_k)
     else:
         clear, end = s_valid // block_k, k_ref.shape[1] // block_k
+    if window is not None:
+        # blocks wholly before the window of the tile's first query are
+        # never loaded; those that hold the lower edge of some query's
+        # window run masked, up to ``edge``
+        start = jnp.maximum(first - window + 1, 0) // block_k
+        edge = jnp.minimum(
+            pl.cdiv(jnp.maximum(first + bq - window, 0), block_k), clear)
 
     def step(masked, i, carry):
         m, l, acc = carry
@@ -124,8 +144,11 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
         if masked:
             key = at + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
             if causal:  # padded keys lie after every real query
-                seen = key <= first + lax.broadcasted_iota(
+                query = first + lax.broadcasted_iota(
                     jnp.int32, (bq, block_k), 0)
+                seen = key <= query
+                if window is not None:
+                    seen &= key > query - window
             else:
                 seen = key < s_valid
             s = jnp.where(seen, s.reshape(g, bq, block_k), _NEG).reshape(
@@ -143,7 +166,13 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
     carry = (jnp.full((rows, 1), _NEG, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
              jnp.zeros((rows, v_ref.shape[2]), jnp.float32))
-    carry = lax.fori_loop(0, clear, functools.partial(step, False), carry)
+    if window is None:
+        carry = lax.fori_loop(0, clear, functools.partial(step, False), carry)
+    else:
+        carry = lax.fori_loop(start, edge, functools.partial(step, True),
+                              carry)
+        carry = lax.fori_loop(edge, clear, functools.partial(step, False),
+                              carry)
     if causal and block_k % bq == 0:
         # a tile no wider than a key block lies in one block: the diagonal's
         _, l, acc = step(True, clear, carry)
@@ -173,7 +202,7 @@ def _rows_read(row, b: int, hkv: int) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "block_q", "block_k", "interpret", "causal"))
+    "scale", "block_q", "block_k", "interpret", "causal", "window"))
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -184,10 +213,13 @@ def flash_attention(
     interpret: bool = False,
     causal: bool = False,
     row=None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """softmax(q k^T * scale) v, fused on TPU, for ``q: (B, Hq, S, Dk)``,
     ``k: (B, Hkv, S, Dk)`` and ``v: (B, Hkv, S, Dv)``; with ``causal`` a query
-    reads the keys at and before its own position. With ``row`` (an index,
+    reads the keys at and before its own position, and with ``window`` beside
+    it only the last ``window`` of them, itself among them (the key blocks
+    before a tile's windows are never loaded). With ``row`` (an index,
     traced or not) only that row of the batch is computed, ``(1, Hq, S,
     Dv)``, read where it lies in the whole arrays: a loop over rows cuts
     nothing out of them.
@@ -204,6 +236,8 @@ def flash_attention(
         raise ValueError(f"{hq} query heads over {hkv} key heads")
     if causal and sq != sk:
         raise ValueError(f"causal over {sq} queries and {sk} keys")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a window of {window} keys, causal {causal}")
     g = hq // hkv
     if scale is None:
         scale = dk**-0.5
@@ -227,7 +261,7 @@ def flash_attention(
     rows, at = _rows_read(row, b, hkv)
     out = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, s_valid=sk, block_k=bk,
-                          causal=causal),
+                          causal=causal, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(rows * hkv, sq_p // block_q),

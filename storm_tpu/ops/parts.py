@@ -22,6 +22,8 @@ MIX_KDA_TABLES = "mix.kda_tables"  # ops/kda.py: the within-chunk tables
 MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
 MIX_SSD_SCAN = "mix.ssd_scan"  # ops/ssd.py: the loop over chunks
 MIX_ATTENTION = "mix.attention"  # causal_attention's loop, ViT's attention
+MIX_WINDOW_ATTENTION = "mix.window_attention"  # ... its loop where a query
+# reads its last ``window`` keys alone: the same shapes, so a name apart
 MIX_ROPE = "mix.rope"  # ops/rope.py: the position tables and the turn
 MIX_SPARSE_SELECT = "mix.sparse_select"  # ops/sparse_attention.py: pooled
 # keys, the first pass over them, the blocks' scores, the top-k, the counts
@@ -38,7 +40,8 @@ MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
 VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
               MOE_EXPERTS, MOE_COMBINE, MIX_SPARSE_SELECT,
-              MIX_SPARSE_ATTENTION, MIX_EVA_CHUNKS, MIX_EVA_ATTENTION)
+              MIX_SPARSE_ATTENTION, MIX_EVA_CHUNKS, MIX_EVA_ATTENTION,
+              MIX_WINDOW_ATTENTION)
 
 
 def part_of(op_name: str):

@@ -28,6 +28,9 @@ EXPECTED = {
         parts.MIX_SSD_SCAN, parts.MIX_ROPE},
     "evabyte_tiny": (COMMON - {parts.MIX_ATTENTION}) | {
         parts.MIX_EVA_CHUNKS, parts.MIX_EVA_ATTENTION, parts.MIX_ROPE},
+    # four layers whose queries read a window, one that reads every key
+    "trinity_tiny": COMMON | MOE | {parts.MIX_WINDOW_ATTENTION,
+                                    parts.MIX_ROPE},
 }
 
 
@@ -93,8 +96,11 @@ def test_the_innermost_name_is_the_operations():
     assert parts.part_of(
         "jit(fwd)/mix.elementwise/mix.sparse_select/while/body/top_k") == \
         parts.MIX_SPARSE_SELECT
+    assert parts.part_of(
+        "jit(fwd)/mix.elementwise/mix.window_attention/while/body/exp") == \
+        parts.MIX_WINDOW_ATTENTION
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 17
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 18
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():

@@ -101,6 +101,39 @@ def test_causal_attention_compiles_as_a_loop_of_kernel_calls(
     assert "f32[32,512," not in text and "f32[2,16,512," not in text
 
 
+@pytest.mark.parametrize("window,part", [
+    (2048, "mix.window_attention"), (None, "mix.attention")])
+def test_trinitys_two_attention_loops_compile_with_the_same_shapes_and_their_own_names(
+        v5e, monkeypatch, window, part):
+    """Trinity's attention at its bucket of 4 windows of 16,384, 32 query
+    heads on 4 key heads, as one chip builds it, with a window of 2,048 keys
+    and without: the kernel is in the program either way, the rows are one
+    ``while`` that carries q, k and v whole (nothing is cut out of them
+    inside it), in the same shapes in both, and only the part's name in the
+    loop's metadata tells the two apart, which is what the benchmark's
+    ``window_attention_ms`` and ``trinity_full_attention_ms`` read."""
+    import re
+
+    import storm_tpu.ops.attention as attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "_one_device", lambda: True)
+    assert attention.causal_form(32, 4, 16384, 128, 128) == "kernel"
+    q, k, v = (_spec((4, 16384, h, 128), jnp.bfloat16, v5e)
+               for h in (32, 4, 4))
+    text = jax.jit(lambda q, k, v: attention.causal_attention(
+        *(y.transpose(0, 2, 1, 3) for y in (q, k, v)), scale=128 ** -0.5,
+        window=window).transpose(0, 2, 1, 3)).lower(q, k, v).compile(
+        ).as_text()
+    assert "tpu_custom_call" in text
+    (loop,) = [line for line in text.splitlines() if " while(" in line]
+    assert loop.count("bf16[4,4,16384,128]") >= 2
+    assert "bf16[4,32,16384,128]" in loop
+    assert f"/{part}/while" in loop
+    assert not re.search(r"bf16\[[\d,]+\]\S* dynamic-slice\(", text)
+    assert "f32[32,512," not in text and "f32[4,8,512," not in text
+
+
 @pytest.mark.parametrize("shape,heads", [
     ((256, 257, 1408), 16),   # ViT-g/14, the benchmark's largest bucket
     ((128, 197, 768), 12),    # ViT-B/16 at batch 128
