@@ -13,9 +13,13 @@ RMSNorm_post2(ffn(RMSNorm_pre2(h)))`` (``models/scorer.py Branch.post``).
   a ``sliding`` layer turns q and k by plain rotary position code (all
   ``head_dim`` channels, halves paired: :mod:`storm_tpu.ops.rope`) and a
   query reads its last ``window`` keys alone, itself among them
-  (ops/attention.py ``causal_attention(window=...)``: the key blocks before a
-  window are never loaded); a ``full`` layer has no position code and reads
-  every key before it.
+  (ops/attention.py ``causal_attention_merged(window=...)``: the key blocks
+  before a window are never loaded); a ``full`` layer has no position code
+  and reads every key before it. q, k, v and the attention's result stay
+  ``(B, S, H * head_dim)`` from the projections to the output projection:
+  the norm and the turn are one pass over q and over k where they lie
+  (ops/rope.py ``norm_turn_merged``), and the kernel reads a head as a block
+  of lanes (PERF.md section 6, PR 58).
 - The feed-forward is SwiGLU in the ``dense`` leading layers and, after
   them, the dropless sigmoid top-k expert layer with a shared expert
   (:func:`storm_tpu.parallel.moe.topk_moe_layer`): the ``top_k`` largest of
@@ -52,7 +56,7 @@ from storm_tpu.models.scorer import _proj
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
 from storm_tpu.ops import rope as R
-from storm_tpu.ops.attention import causal_attention
+from storm_tpu.ops.attention import causal_attention_merged
 from storm_tpu.parallel.moe import topk_moe_init
 
 KINDS = ("sliding", "full")
@@ -65,24 +69,15 @@ def trinity_mixer(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
     number: a sliding layer, q and k turned by ``rotary``'s tables ``(S,
     head_dim / 2)`` where they lie in their projections, then each query
     over its last ``window`` keys."""
-    b, s, _ = x.shape
-
-    def split(name, n):
-        return _proj(x, p[name]).reshape(b, s, n, head_dim)
-
-    def turned(y, n):  # where the heads lie merged, then a head a view again
-        (y,) = R.turn_merged((y.reshape(b, s, n * head_dim),), *rotary, n)
-        return y.reshape(b, s, n, head_dim)
-
-    # a head's channels are the last axis: one learned scale a channel
-    q = L.rmsnorm(p["q_norm"], split("q", heads), eps)
-    k = L.rmsnorm(p["k_norm"], split("k", kv_heads), eps)
-    if window is not None:
-        q, k = turned(q, heads), turned(k, kv_heads)
-    out = causal_attention(
-        *(y.transpose(0, 2, 1, 3) for y in (q, k, split("v", kv_heads))),
-        scale=head_dim ** -0.5, block=block, window=window)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
+    # q, k, v and the result stay as the projections leave them, ``(B, S, H *
+    # head_dim)``, a head a block of lanes to the norm, the turn and the
+    # kernel: a view a head is another tiling on a TPU, each way a copy
+    turn = rotary if window is not None else None
+    q = R.norm_turn_merged(p["q_norm"], _proj(x, p["q"]), heads, eps, turn)
+    k = R.norm_turn_merged(p["k_norm"], _proj(x, p["k"]), kv_heads, eps, turn)
+    out = causal_attention_merged(
+        q, k, _proj(x, p["v"]), heads, kv_heads, scale=head_dim ** -0.5,
+        block=block, window=window)
     return _proj(out * jax.nn.sigmoid(_proj(x, p["gate"])), p["o"])
 
 

@@ -292,3 +292,65 @@ def multi_head_attention(p: dict, x: jnp.ndarray, num_heads: int) -> jnp.ndarray
             out = merge_heads(out)
     with jax.named_scope(P.PROJ):
         return dense(p["o"], out)
+
+
+# ---- causal attention over merged heads ---------------------------------------
+
+def merged_form(hq: int, hkv: int, s: int, dk: int, dv: int) -> str:
+    """:func:`causal_form` for heads that lie merged ``(B, S, H * D)``:
+    ``"kernel"`` by its rule where a head is whole lane tiles besides (a
+    head is then a block of lanes to the kernel's block specs; 192 lanes
+    are a tile and a half, which no block can begin at), ``"blocked"``
+    elsewhere."""
+    if dk % 128 or dv % 128:
+        return "blocked"
+    return causal_form(hq, hkv, s, dk, dv)
+
+
+def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                            heads: int, kv_heads: int,
+                            scale: Optional[float] = None, block: int = 512,
+                            window: Optional[int] = None) -> jnp.ndarray:
+    """:func:`causal_attention` for a caller that holds its heads merged, as
+    its projections leave them: ``q: (B, S, Hq * Dk)``, ``k: (B, S, Hkv *
+    Dk)``, ``v: (B, S, Hkv * Dv)`` to ``(B, S, Hq * Dv)``. The same softmax,
+    the same ``window``, the same loop over rows under the same part's name
+    and the same note, by :func:`merged_form`: the kernel reads a head as a
+    block of lanes of the merged arrays and writes the result so
+    (ops/flash_attention.py ``flash_attention_merged``; the note ends
+    ``-merged``), and no array is transposed to ``(B, H, S, D)`` and back, a
+    copy of each on a TPU; elsewhere :func:`causal_blocked` on that view, the
+    CPU's and the several-chips' path as it was. A caller chooses this entry
+    by what it holds; the head-split one is untouched."""
+    b, s, _ = q.shape
+    dk, dv = k.shape[-1] // kv_heads, v.shape[-1] // kv_heads
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} key heads")
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} keys")
+    if window is not None and window >= s:
+        window = None
+    if scale is None:
+        scale = dk ** -0.5
+    form = merged_form(heads, kv_heads, s, dk, dv)
+    grouped = "-grouped" if heads != kv_heads else ""
+    name = "causal_attention" if window is None else "window_attention"
+    if form == "blocked":
+        _note(name, form + grouped)
+        out = causal_blocked(
+            *(split_heads(y, n) for y, n in (
+                (q, heads), (k, kv_heads), (v, kv_heads))),
+            scale, block, window)
+        return merge_heads(out)
+    _note(name, form + grouped + "-merged")
+
+    from storm_tpu.ops import flash_attention as F
+
+    block_q, block_k = F.causal_tiles(heads // kv_heads)
+    with jax.named_scope(_loop_part(window)):
+        # a row's call writes its row of the result where it lies
+        return jax.lax.fori_loop(
+            0, b, lambda i, out: F.flash_attention_merged(
+                out, q, k, v, i, heads=heads, kv_heads=kv_heads, scale=scale,
+                block_q=block_q, block_k=block_k, window=window),
+            jax.lax.empty((b, s, heads * dv), q.dtype))

@@ -282,3 +282,98 @@ def flash_attention(
         interpret=interpret,
     )(at, qf, kf, vf)
     return out[:, :, :sq, :dv].reshape(rows, hq, sq, dv)
+
+
+# ---- merged heads: q, k, v and the result where the projections leave them ----
+
+def _merged_kernel(row_ref, q_ref, k_ref, v_ref, _, o_ref, qs_ref, os_ref,
+                   **kw):
+    """:func:`_attn_kernel` for a tile that lies ``(1, BQ, G * Dk)``, a query
+    head a block of ``Dk`` lanes: the ``G`` lane blocks are stacked into the
+    ``(1, G, BQ, Dk)`` tile it works on (static slices of whole lane tiles, in
+    VMEM) and its result is unstacked the same way into ``(1, BQ, G * Dv)``.
+    The loop over key blocks, a window's three parts and the carry are that
+    kernel's: nothing of them is here."""
+    g, dk, dv = qs_ref.shape[1], qs_ref.shape[3], os_ref.shape[3]
+    for i in range(g):
+        qs_ref[0, i] = q_ref[0, :, i * dk:(i + 1) * dk]
+    _attn_kernel(row_ref, qs_ref, k_ref, v_ref, os_ref, **kw)
+    for i in range(g):
+        o_ref[0, :, i * dv:(i + 1) * dv] = os_ref[0, i]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "kv_heads", "scale", "block_q", "block_k", "interpret", "window"))
+def flash_attention_merged(
+    out: jnp.ndarray,
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    row,
+    *,
+    heads: int,
+    kv_heads: int,
+    scale: Optional[float] = None,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: bool = False,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """The causal form of :func:`flash_attention` (with ``window``, over a
+    query's last ``window`` keys alone) for heads that lie merged, as a
+    projection leaves them: row ``row`` (an index, traced or not) of ``q: (B,
+    S, Hq * Dk)``, ``k: (B, S, Hkv * Dk)`` and ``v: (B, S, Hkv * Dv)``, read
+    where it lies, written into ``out: (B, S, Hq * Dv)``, which is returned
+    with its other rows as they were (the result is its buffer: a loop over
+    rows stacks nothing and zeroes nothing, ``lax.empty`` will do). No view a
+    head ``(B, S, H, D)`` nor ``(B, H, S, D)`` is made: on a TPU each is
+    another tiling and a copy of the whole array. The block specs pick a key
+    head's ``G * Dk`` lanes of a query tile, that head's ``Dk`` lanes of the
+    whole sequence of k and v (rows of ``Dk`` values ``Hkv * Dk`` apart: the
+    DMA's stride, no copy), and the tile's ``G * Dv`` lanes of the result; the
+    grid is (key head, query tile) and the kernel the head-split entry's
+    (:func:`_merged_kernel`). Positions are padded to whole blocks; a head's
+    width is read as it lies, so on a TPU it is whole lane tiles
+    (ops/attention.py ``merged_form`` sends other widths elsewhere)."""
+    s = q.shape[1]
+    dk, dv = k.shape[2] // kv_heads, v.shape[2] // kv_heads
+    if heads % kv_heads or q.shape[2] != heads * dk:
+        raise ValueError(f"{heads} query heads over {kv_heads} key heads of "
+                         f"{dk}: q has {q.shape[2]} lanes")
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} keys")
+    g = heads // kv_heads
+    if scale is None:
+        scale = dk**-0.5
+    block_q = min(block_q, max(_LANE, 1 << (s - 1).bit_length()))
+    bk = min(block_k, max(_LANE, 1 << (s - 1).bit_length()))
+    qp, buf = _pad_to(q, 1, block_q), _pad_to(out, 1, block_q)
+    kp, vp = _pad_to(k, 1, bk), _pad_to(v, 1, bk)
+
+    def tile(width):  # of key head ``h``'s lanes, in the row read
+        return pl.BlockSpec((1, block_q, width),
+                            lambda h, qi, r: (r[0], qi, h))
+
+    return pl.pallas_call(
+        functools.partial(_merged_kernel, scale=scale, s_valid=s, block_k=bk,
+                          causal=True, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kv_heads, qp.shape[1] // block_q),
+            in_specs=[tile(g * dk),
+                      pl.BlockSpec((1, kp.shape[1], dk),
+                                   lambda h, qi, r: (r[0], 0, h)),
+                      pl.BlockSpec((1, vp.shape[1], dv),
+                                   lambda h, qi, r: (r[0], 0, h)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(g * dv),
+            scratch_shapes=[pltpu.VMEM((1, g, block_q, dk), q.dtype),
+                            pltpu.VMEM((1, g, block_q, dv), q.dtype)]),
+        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        # the result is its buffer (operands count from the row's index)
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(jnp.asarray(row, jnp.int32).reshape(1), qp, kp, vp, buf)[:, :s]

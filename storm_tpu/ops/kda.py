@@ -678,3 +678,21 @@ def kda_chunked(q, k, v, g, beta, heads: int, chunk: int = 64,
     lead = (chunk,) if chunk % 8 else (chunk // 8, 8)
     o = jnp.moveaxis(o.reshape(n, b, h, *lead, dv), (0, 2), (1, 2 + len(lead)))
     return o.reshape(b, n * chunk, h * dv)[:, :s]
+
+
+def rmsnorm_heads(p: dict, x: jnp.ndarray, heads: int,
+                  eps: float = 1e-5) -> jnp.ndarray:
+    """ops/layers.py ``rmsnorm`` of each head of ``x: (..., H * d)``, a head
+    a block of ``d`` lanes and ``p["scale"]: (d,)`` the one learned scale
+    every head shares, tiled over them: what ``rmsnorm`` gives on the view
+    ``(..., H, d)`` (the mean over a head's ``d`` channels, ``eps`` beside
+    it, float32 inside, ``x``'s type out), with ``x`` read and written where
+    a projection leaves it. The view is another tiling on a TPU, and the
+    float32 ``x`` re-tiled into it and back three passes over an array twice
+    ``x``'s size (PERF.md section 6, PR 58); here the statistic alone is a
+    number a head (:func:`head_sums`, :func:`over_heads`)."""
+    d = x.shape[-1] // heads
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(head_sums(xf * xf, heads) / d + eps)
+    scale = jnp.tile(p["scale"].astype(jnp.float32), heads)
+    return (xf * over_heads(inv, d) * scale).astype(x.dtype)

@@ -186,3 +186,77 @@ def _turn_lanes(xs, cos2, sin2, *, heads, tile=_TURN_TILE, interpret=False):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(cos2, sin2, *xs))
+
+
+# ---- the head norm in the turn's pass ------------------------------------------
+
+def norm_turn_merged(p: dict, x: jnp.ndarray, heads: int, eps: float,
+                     rotary=None) -> jnp.ndarray:
+    """ops/layers.py ``rmsnorm`` of each head of ``x: (B, S, H * D)`` under
+    the one learned scale ``p["scale"]: (D,)`` and then, with ``rotary =
+    (cos, sin)``, :func:`turn_merged`'s turn of every head, in one pass over
+    ``x`` where it lies: a head's block of lanes is read once, normed and
+    turned in float32 in VMEM and written once in ``x``'s type (the norm's
+    result is not rounded to ``x``'s type between the two). By
+    :func:`turn_form`'s rule; elsewhere ops/kda.py ``rmsnorm_heads`` (the
+    statistic a product with a 0/1 matrix, two passes over ``x``) and
+    :func:`turn_merged`. ``rotary`` None: the norm alone, by the same
+    kernel. Noted ``head_norm=kernel`` where the kernel is picked (the other
+    form says nothing new: it is ``rmsnorm``'s arithmetic, and the CPU's
+    notes are held as they were by tests/benchmark/)."""
+    d = x.shape[2] // heads
+    if turn_form(x.shape[1], d) != "lanes":
+        from storm_tpu.ops.kda import rmsnorm_heads
+
+        y = rmsnorm_heads(p, x, heads, eps)
+        return y if rotary is None else turn_merged((y,), *rotary, heads)[0]
+    _note("head_norm", "kernel")
+    scale = p["scale"].astype(jnp.float32).reshape(1, d)
+    if rotary is None:
+        return _norm_turn_lanes(x, scale, heads=heads, eps=eps)
+    _note("rotary_turn", "lanes")
+    cos, sin = rotary
+    with jax.named_scope(P.MIX_ROPE):
+        return _norm_turn_lanes(
+            x, scale, jnp.concatenate([cos, cos], -1),
+            jnp.concatenate([-sin, sin], -1), heads=heads, eps=eps)
+
+
+def _norm_turn_kernel(scale_ref, *refs, heads, eps):
+    *tables, x_ref, o_ref = refs
+    d = scale_ref.shape[1]
+    scale = scale_ref[...]
+    for h in range(heads):
+        lanes = pl.ds(h * d, d)
+        x = x_ref[0, :, lanes].astype(jnp.float32)
+        y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+        if tables:
+            # a head's lanes against (cos, cos) and its exchanged halves
+            # against (-sin, sin), as ``_turn_kernel``
+            cos_ref, sin_ref = tables
+            y = y * cos_ref[...] + pltpu.roll(y, d // 2, 1) * sin_ref[...]
+        o_ref[0, :, lanes] = y.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "eps", "interpret"))
+def _norm_turn_lanes(x, scale, *tables, heads, eps, interpret=False):
+    b, s, merged = x.shape
+    d = merged // heads
+    # two of the turn's tiles a step where the positions allow: the norm makes
+    # a step of 128 positions compute longer (4.9 us) than its DMA (2.6), and
+    # q's 32 heads read 2.61 ms a call at 128 positions, 1.83 at 256, the
+    # turn's own 1.81 (PERF.md section 6, PR 58)
+    tile = 2 * _TURN_TILE if s % (2 * _TURN_TILE) == 0 else _TURN_TILE
+    wide = pl.BlockSpec((1, tile, merged), lambda r, i: (r, i, 0))
+    return pl.pallas_call(
+        functools.partial(_norm_turn_kernel, heads=heads, eps=eps),
+        grid=(b, s // tile),
+        in_specs=[pl.BlockSpec((1, d), lambda r, i: (0, 0))]
+        + [pl.BlockSpec((tile, d), lambda r, i: (i, 0)) for _ in tables]
+        + [wide],
+        out_specs=wide,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(scale, *tables, x)

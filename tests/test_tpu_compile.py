@@ -134,6 +134,62 @@ def test_trinitys_two_attention_loops_compile_with_the_same_shapes_and_their_own
     assert "f32[32,512," not in text and "f32[4,8,512," not in text
 
 
+@pytest.mark.parametrize("window,part,parents_operations", [
+    (2048, "mix.window_attention", 274), (None, "mix.attention", 204)])
+def test_trinitys_mixer_compiles_with_its_heads_merged_from_projection_to_projection(
+        v5e, monkeypatch, window, part, parents_operations):
+    """Trinity's mixer whole at its cell's step (4 windows of 16,384 into
+    2,048, 32 query heads on 4 key heads of 128, bfloat16), a sliding layer
+    and a full one, as one chip builds it: three kernels (the head norm of q
+    and of k, with the turn in its pass in a sliding layer; the attention);
+    the rows are one ``while`` under the part's own name, which carries q and
+    its result ``(4, 16384, 4096)`` and k and v ``(4, 16384, 512)`` as the
+    projections left them; no view a head exists anywhere, in float32 (the
+    parent's head norms: three passes over 1.07 GB) or in bfloat16 (its two
+    transpositions around the loop), no float32 array of q's size is written,
+    and nothing of q's size is copied, re-tiled or converted outside the
+    kernels. The parent's temporaries were 2.69 GB a sliding mixer, 1.14
+    now; its compiled text held 274 and 204 operations (163 and 127 now: a
+    warm load follows the text's size, PERF.md section 7 item 15), and a
+    change that unrolls something into either shows here."""
+    import re
+
+    import storm_tpu.ops.attention as attention
+    import storm_tpu.ops.rope as rope
+    from storm_tpu.models.minicpm_sala import minicpm4_mixer_init
+    from storm_tpu.models.trinity import trinity_mixer
+
+    for module in (attention, rope):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    assert attention.merged_form(32, 4, 16384, 128, 128) == "kernel"
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: minicpm4_mixer_init(
+            jax.random.PRNGKey(0), 2048, 32, 4, 128)))
+    x = _spec((4, 16384, 2048), jnp.bfloat16, v5e)
+    tables = _spec((16384, 64), jnp.float32, v5e)
+    compiled = jax.jit(lambda p, x, cos, sin: trinity_mixer(
+        p, x, 32, 4, 128, 1e-5, (cos, sin), window)).lower(
+        p, x, tables, tables).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    (loop,) = _loops(text)
+    assert f"/{part}/while" in loop
+    assert loop.count("bf16[4,16384,4096]") >= 2
+    assert loop.count("bf16[4,16384,512]") >= 2
+    assert not re.search(
+        r"\[4,16384,(32|4),128\]|\[8192,8,(32|4),128\]|\[4,(32|4),16384,128\]",
+        text)
+    entry = text[text.index("ENTRY"):]
+    assert "f32[4,16384,4096]" not in entry + loop
+    assert not re.search(r"= \w+\[4,16384,4096\]\S* "
+                         r"(copy|reshape|transpose|convert|slice)\(", entry)
+    assert len(re.findall(r"^\s+(?:ROOT )?%\S+ = ", text, re.M)) \
+        <= parents_operations
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 2 ** 30
+
+
 @pytest.mark.parametrize("shape,heads", [
     ((256, 257, 1408), 16),   # ViT-g/14, the benchmark's largest bucket
     ((128, 197, 768), 12),    # ViT-B/16 at batch 128

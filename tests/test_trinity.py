@@ -10,6 +10,7 @@ benchmark's reference (``benchmarks/references/trinity.py``, float32 at
 ``highest``) on seeded weights. Probabilities over the whole vocabulary are
 compared, never an argmax."""
 
+import functools
 import hashlib
 import os
 import sys
@@ -31,9 +32,13 @@ from storm_tpu.models.trinity import trinity_mixer  # noqa: E402
 from storm_tpu.ops import layers as L  # noqa: E402
 from storm_tpu.ops import parts as P  # noqa: E402
 from storm_tpu.ops import rope as R  # noqa: E402
+from storm_tpu.ops import attention as A  # noqa: E402
 from storm_tpu.ops.attention import (causal_attention,  # noqa: E402
-                                     causal_blocked)
-from storm_tpu.ops.flash_attention import flash_attention  # noqa: E402
+                                     causal_attention_merged, causal_blocked,
+                                     merge_heads)
+from storm_tpu.ops.flash_attention import (flash_attention,  # noqa: E402
+                                           flash_attention_merged)
+from storm_tpu.ops.kda import rmsnorm_heads  # noqa: E402
 from storm_tpu.ops.platform import dispatch_notes  # noqa: E402
 from storm_tpu.parallel.moe import topk_moe_layer  # noqa: E402
 
@@ -150,6 +155,125 @@ def test_a_window_names_its_own_part_and_note_and_a_wide_one_does_not():
         causal_attention(q, k, v, window=0)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, k, v, window=4, interpret=True)  # not causal
+
+
+# ---- heads that stay merged: the norm and the kernel's second entry ------------
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 2 ** -7)])
+@pytest.mark.parametrize("heads,d", [(32, 128), (4, 128), (8, 16)])
+def test_the_head_norm_on_merged_heads_is_rmsnorm_on_the_view(heads, d, dtype,
+                                                              tol):
+    """Trinity-Mini's 32 query and 4 key heads of 128 and the tiny preset's
+    8 of 16: ``rmsnorm_heads`` where the heads lie against ``rmsnorm`` on the
+    view a head, one learned ``(d,)`` scale for every head; float32 to the
+    order of a sum (1e-6 of a value), bfloat16 to one step of its rounding."""
+    x = (3 * jax.random.normal(jax.random.PRNGKey(heads), (2, 24, heads * d))
+         ).astype(dtype)
+    p = {"scale": jnp.linspace(0.5, 1.5, d).astype(dtype)}
+    want = L.rmsnorm(p, x.reshape(2, 24, heads, d), 1e-5)
+    got = rmsnorm_heads(p, x, heads, 1e-5)
+    assert got.dtype == dtype and got.shape == x.shape
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(want.reshape(x.shape), np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("turn", [True, False])
+@pytest.mark.parametrize("heads,s", [(32, 256), (4, 384)])
+def test_the_norm_in_the_turns_pass_is_the_norm_and_then_the_turn(
+        monkeypatch, heads, s, turn):
+    """One kernel's pass over q (32 heads; one step of 256 positions) or k (4;
+    384 positions are three steps of 128) where they lie, under the
+    interpreter, against ``rmsnorm_heads`` and then ``turn_merged``'s other
+    form; without tables the norm alone; off the chip the rule sends both to
+    those two and says so."""
+    x = jax.random.normal(jax.random.PRNGKey(heads), (2, s, heads * 128))
+    p = {"scale": jnp.linspace(0.5, 1.5, 128)}
+    rotary = R.rotary_tables(s, 100.0 ** (-2.0 * np.arange(64) / 128)) \
+        if turn else None
+    with dispatch_notes() as notes:
+        want = R.norm_turn_merged(p, x, heads, 1e-5, rotary)
+    assert notes == ["rotary_turn=halves"] * turn
+    np.testing.assert_allclose(
+        want, rmsnorm_heads(p, x, heads, 1e-5) if not turn else
+        R.turn_merged((rmsnorm_heads(p, x, heads, 1e-5),), *rotary, heads)[0],
+        atol=1e-6)
+    monkeypatch.setattr(R, "_use_pallas", lambda: True)
+    monkeypatch.setattr(R, "_one_device", lambda: True)
+    monkeypatch.setattr(
+        R, "_norm_turn_lanes", functools.partial(R._norm_turn_lanes,
+                                                 interpret=True))
+    with dispatch_notes() as notes:
+        got = R.norm_turn_merged(p, x, heads, 1e-5, rotary)
+    assert notes == ["head_norm=kernel"] + ["rotary_turn=lanes"] * turn
+    np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+def _merged(hq, hkv, s, d, seed=1):
+    """q, k, v a head first, and the same lying merged ``(B, S, H * D)``."""
+    split = _qkv(hq, hkv, s, d, seed)
+    return split, tuple(merge_heads(y) for y in split)
+
+
+# (query heads, key heads): one, four and eight query heads a key head
+GROUPS = ((2, 2), (8, 2), (8, 1))
+
+
+@pytest.mark.parametrize("form", ["kernel", "blocked"])
+@pytest.mark.parametrize("s", [SEQ, 300])  # five whole key blocks; none whole
+@pytest.mark.parametrize("window", [None, 100])
+@pytest.mark.parametrize("hq,hkv", GROUPS)
+def test_the_merged_entry_is_the_head_split_one_transposed(hq, hkv, window, s,
+                                                           form):
+    """``q, k, v`` as the projections leave them against ``causal_attention``
+    on the operands a head first: the kernel under the interpreter, a row's
+    call writing its row of the result's buffer and leaving the others as
+    they were (a tile of 16 positions, a group's heads stacked; 300 positions
+    are padded to whole blocks behind the last), and the blocked form."""
+    (q, k, v), merged = _merged(hq, hkv, s, 32)
+    want = merge_heads(causal_attention(q, k, v, scale=0.2, block=BLOCK,
+                                        window=window))
+    if form == "blocked":
+        got = causal_attention_merged(*merged, hq, hkv, scale=0.2,
+                                      block=BLOCK, window=window)
+    else:
+        got = jnp.full_like(want, 7.0)
+        for row in (1, 0):
+            before = got
+            got = flash_attention_merged(
+                got, *merged, row, heads=hq, kv_heads=hkv, scale=0.2,
+                block_q=16, block_k=BLOCK, window=window, interpret=True)
+            np.testing.assert_array_equal(got[1 - row], before[1 - row])
+    assert got.shape == want.shape == (2, s, hq * 32)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_the_merged_entrys_loop_over_rows_fills_an_unwritten_buffer(
+        monkeypatch):
+    """What one chip builds, here under the interpreter: whole tiles of 128
+    lanes, so ``merged_form`` picks the kernel; the loop over rows starts
+    from ``lax.empty`` and every row's call writes its own; the note says
+    which entry built the program, and a width that is no whole lane tile
+    (Kimi's 192 too) goes to the blocked form on the view."""
+    from storm_tpu.ops import flash_attention as F
+
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    monkeypatch.setattr(A, "_one_device", lambda: True)
+    monkeypatch.setattr(
+        F, "flash_attention_merged",
+        lambda *a, **kw: flash_attention_merged(*a, interpret=True, **kw))
+    assert A.merged_form(8, 2, 512, 128, 128) == "kernel"
+    assert A.merged_form(8, 2, 512, 192, 128) == "blocked"
+    assert A.merged_form(8, 2, 512 + 128, 128, 128) == "blocked"
+    (q, k, v), merged = _merged(8, 2, 512, 128, seed=3)
+    for window, name in ((200, "window_attention"), (None, "causal_attention")):
+        with dispatch_notes() as notes:
+            got = jax.jit(lambda q, k, v: causal_attention_merged(
+                q, k, v, 8, 2, window=window))(*merged)
+        assert notes == [f"{name}=kernel-grouped-merged"]
+        want = merge_heads(causal_blocked(q, k, v, 128 ** -0.5, 128, window))
+        np.testing.assert_allclose(got, want, atol=2e-6)
 
 
 # ---- what was there lowers to the parent's text --------------------------------
