@@ -84,19 +84,22 @@ class Branch(NamedTuple):
 
 def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
             first_expert: int, scale: float, tile: int,
-            post: Optional[str] = None, post_scale: float = 1.0) -> Branch:
-    """The dropless sigmoid top-k expert layer with a shared expert
-    (parallel/moe.py) as a branch: it routes from the float32 norm, names its
-    own parts and counts the tokens of each held expert and the assignments
-    that fell on absent ones. The counts' reader is told the tile and the
-    router's width (read off ``init``'s shapes), from which the layer chose
-    its loops' sizes."""
+            post: Optional[str] = None, post_scale: float = 1.0,
+            router: str = "sigmoid") -> Branch:
+    """The dropless top-k expert layer (parallel/moe.py) as a branch, its
+    scores the ``router``'s function of the logits (``"sigmoid"``, or
+    ``"softmax"`` over the router's width), with the selection bias and the
+    shared expert that ``init``'s tree has: it routes from the float32 norm,
+    names its own parts and counts the tokens of each held expert and the
+    assignments that fell on absent ones. The counts' reader is told the
+    tile and the router's width (read off ``init``'s shapes), from which the
+    layer chose its loops' sizes."""
     width = jax.eval_shape(init, jax.ShapeDtypeStruct(
         (2,), jnp.uint32))["router"].shape[1]
     return Branch(
         norm, name, init,
         lambda p, y, _: topk_moe_layer(
-            p, y, top_k, first_expert=first_expert, router="sigmoid",
+            p, y, top_k, first_expert=first_expert, router=router,
             renormalize=True, scale=scale, tile=tile),
         scope=None, cast=None,
         counts=(("expert_tokens", (held,)), ("expert_absent", ())),

@@ -28,6 +28,9 @@ MIX_ROPE = "mix.rope"  # ops/rope.py: the position tables and the turn
 MIX_SPARSE_SELECT = "mix.sparse_select"  # ops/sparse_attention.py: pooled
 # keys, the first pass over them, the blocks' scores, the top-k, the counts
 MIX_SPARSE_ATTENTION = "mix.sparse_attention"  # ... its second pass
+MIX_INDEX_SELECT = "mix.index_select"  # ... its other first pass: a learned
+# indexer's scores of every causal pair, each query's top keys, the mask,
+# the count of blocks picked
 MIX_EVA_CHUNKS = "mix.eva_chunks"  # ops/eva_attention.py: the two poolings
 # of every chunk's keys and values into one summary each
 MIX_EVA_ATTENTION = "mix.eva_attention"  # ... the loop over rows: a window's
@@ -41,7 +44,7 @@ VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
               MOE_EXPERTS, MOE_COMBINE, MIX_SPARSE_SELECT,
               MIX_SPARSE_ATTENTION, MIX_EVA_CHUNKS, MIX_EVA_ATTENTION,
-              MIX_WINDOW_ATTENTION)
+              MIX_WINDOW_ATTENTION, MIX_INDEX_SELECT)
 
 
 def part_of(op_name: str):

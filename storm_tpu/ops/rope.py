@@ -89,6 +89,25 @@ def rotary_tables(seq: int, inv_freq, attention_factor: float = 1.0):
                 jnp.sin(angle) * attention_factor)
 
 
+def mrope_tables(positions, inv_freq, sections: tuple):
+    """:func:`rotary_tables` from several position streams (multimodal
+    rotary, M-RoPE): ``positions: (streams, seq)``, and frequency ``i`` of
+    ``inv_freq`` reads the stream whose section it lies in, ``sections`` the
+    frequencies a stream in their order (``(16, 24, 24)``: stream 0 the
+    first 16, stream 1 the next 24, stream 2 the last 24). ``(cos, sin)``,
+    each ``(seq, dim / 2)`` float32. A token record's streams are all
+    ``arange(seq)`` and the tables are :func:`rotary_tables`' to the bit."""
+    if sum(sections) != len(inv_freq) or len(sections) != len(positions):
+        raise ValueError(f"sections {tuple(sections)!r} over "
+                         f"{len(inv_freq)} frequencies of {len(positions)} "
+                         "streams")
+    stream = np.repeat(np.arange(len(sections)), sections)
+    with jax.named_scope(P.MIX_ROPE):
+        angle = jnp.asarray(positions, jnp.float32)[stream].T \
+            * jnp.asarray(inv_freq, jnp.float32)[None, :]
+        return jnp.cos(angle), jnp.sin(angle)
+
+
 def halves_first(w: jnp.ndarray, first: int = 0) -> jnp.ndarray:
     """``w``'s last axis from ``first`` on, interleaved pairs ``(2i, 2i +
     1)``, reordered to ``(evens, odds)``; the ``first`` columns before pass.

@@ -1,8 +1,15 @@
-"""Block-sparse causal attention that picks its blocks of keys a query
-(InfLLM v2, as the MiniCPM4 report describes it, arXiv:2506.07900): nothing
-is learned by the selection, it reads the keys themselves.
+"""Sparse causal attention in two passes: a *first pass* picks what each
+query reads, a *second pass* is the softmax over the picks. Two first passes
+share the second:
 
-For a group ``g`` of query heads over one key head and a query at position
+* :func:`select_blocks` picks **blocks** of keys a query and key head
+  (InfLLM v2, as the MiniCPM4 report describes it, arXiv:2506.07900):
+  nothing is learned by that selection, it reads the keys themselves.
+* :func:`select_keys` picks **keys**, a query's ``topk`` best by a learned
+  indexer's score, one selection for every head (DeepSeek sparse attention,
+  as DeepSeek-V3.2-Exp publishes it); below, after the blocks.
+
+**Picked blocks.** For a group ``g`` of query heads over one key head and a query at position
 ``t`` (0-based):
 
 1. **Pooled keys.** ``c_i = mean(k[stride * i : stride * i + kernel])``, one
@@ -42,6 +49,35 @@ early on small scores.
   away (PERF.md section 6, PR 45, says what that costs).
 * ``"blocked"``: XLA's form, ops/attention.py ``causal_blocked`` with the
   same mask, elsewhere and on the CPU.
+
+**Picked keys** (:func:`indexed_attention`). An indexer of ``Hi`` small heads
+scores every causal pair, ``I(t, s) = sum_j w(t, j) ReLU(qI(t, j) . kI(s))``
+(one indexer key a position, a weight a query and indexer head; bfloat16
+products, float32 sums), and the query at ``t`` reads ``P(t)``: every ``s <=
+t`` where ``t < topk`` (nothing to pick, no score computed), else the
+``topk`` largest ``I(t, s)`` (the lower ``s`` where two score alike; -0 and
++0 score alike). ``o_h = softmax over s in P(t) of (q_h . k_s * scale)``
+times ``v_s``, the same ``P(t)`` for every head: the second pass above under
+a mask of one head, a key an entry (``(1, S, S)`` int8: the kernel's index
+map sends every key head to it).
+
+:func:`select_keys` (``mix.index_select``) makes one row's mask, a tile of
+queries at a time against the keys its last query sees, so the ``S x S``
+scores of a row never exist at once. Its form (:func:`select_form`, noted
+as ``index_select=<form>``):
+
+* ``"kernel"``: one Pallas call a row; a step keeps a tile's scores in VMEM
+  as integers that order as the floats do, finds each query's ``topk``-th
+  largest bit by bit (33 counts of the scores at or above a candidate:
+  ``parallel/moe.py``'s bisection, on values), settles equal scores at the
+  threshold by a second bisection over the keys' positions where there are
+  any, and writes the mask.
+* ``"top_k"``: ``lax.top_k`` of a tile's scores, elsewhere and on the CPU.
+
+The rows of a step are unrolled, not looped: a row's mask is made, counted
+and read before the next row's, and a device trace shows the first pass and
+the second as events of their own (a loop around both would be one event
+under one name).
 """
 
 from __future__ import annotations
@@ -188,8 +224,9 @@ def sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             return lax.map(lambda i: _kernel_row(
                 q, k, v, _wanted(picked[i], block_size), scale, i),
                 jnp.arange(q.shape[0]))
-        return lax.map(lambda a: _blocked_row(*a, scale, block_size, block),
-                       (q, k, v, picked))
+        return lax.map(lambda a: _blocked_row(
+            *a[:3], lambda lo, hi: _wanted(a[3], block_size, lo, hi), scale,
+            block), (q, k, v, picked))
 
 
 def _wanted(picked: jnp.ndarray, block_size: int,
@@ -202,9 +239,11 @@ def _wanted(picked: jnp.ndarray, block_size: int,
     return of_block & (jnp.arange(hi) <= jnp.arange(lo, hi)[:, None])
 
 
-def _blocked_row(q, k, v, picked, scale, block_size, block):
+def _blocked_row(q, k, v, wanted_at, scale, block):
     """One row as XLA computes it: a block of ``block`` queries against the
-    keys up to its end, as ``causal_blocked``."""
+    keys up to its end, as ``causal_blocked``. ``wanted_at(lo, hi)``: which
+    of the keys ``0 .. hi - 1`` the queries ``lo .. hi - 1`` read, bool, a
+    key head each or one for all."""
     hq, s, hkv = q.shape[0], q.shape[1], k.shape[0]
     q = q.reshape(hkv, hq // hkv, s, q.shape[-1])
     outs = []
@@ -212,8 +251,7 @@ def _blocked_row(q, k, v, picked, scale, block_size, block):
         hi = min(lo + block, s)
         scores = jnp.einsum("grsd,gtd->grst", q[:, :, lo:hi], k[:, :hi],
                             preferred_element_type=F32) * scale
-        scores = jnp.where(_wanted(picked, block_size, lo, hi)[:, None],
-                           scores, -jnp.inf)
+        scores = jnp.where(wanted_at(lo, hi)[:, None], scores, -jnp.inf)
         weights = jnp.exp(scores - scores.max(-1, keepdims=True))
         out = jnp.einsum("grst,gtd->grsd", weights.astype(v.dtype), v[:, :hi],
                          preferred_element_type=F32)
@@ -264,11 +302,13 @@ def _mask_kernel(at_ref, q_ref, k_ref, v_ref, m_ref, o_ref, *, scale,
 def _kernel_row(q, k, v, wanted, scale, row, interpret=False):
     """Row ``row`` of ``q: (B, Hq, S, Dk)``, ``k``, ``v`` (read where it lies
     in the whole arrays, as ``flash_attention(row=...)``) under ``wanted:
-    (Hkv, S, S)`` bool -> ``(Hq, S, Dv)``."""
+    (Hkv, S, S)`` bool or int8, or ``(1, S, S)``: one mask that every key
+    head reads (a static index map) -> ``(Hq, S, Dv)``."""
     b, hq, s, dk = q.shape
     hkv, dv = k.shape[1], v.shape[-1]
     g = hq // hkv
     block_q, block_k = causal_tiles(g)
+    one_mask = wanted.shape[0] != hkv  # every key head reads head 0's
     out = pl.pallas_call(
         functools.partial(_mask_kernel, scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -279,7 +319,8 @@ def _kernel_row(q, k, v, wanted, scale, row, interpret=False):
                              lambda h, qi, at: (at[0] + h, 0, qi, 0)),
                 pl.BlockSpec((1, s, dk), lambda h, qi, at: (at[0] + h, 0, 0)),
                 pl.BlockSpec((1, s, dv), lambda h, qi, at: (at[0] + h, 0, 0)),
-                pl.BlockSpec((1, block_q, s), lambda h, qi, at: (h, qi, 0)),
+                pl.BlockSpec((1, block_q, s),
+                             lambda h, qi, at: (0 if one_mask else h, qi, 0)),
             ],
             out_specs=pl.BlockSpec((1, g, block_q, dv),
                                    lambda h, qi, at: (h, 0, qi, 0))),
@@ -321,3 +362,282 @@ def observe_key_counts(metrics, cid: str, read, skipped) -> None:
     causal keys they leave out, as two counters."""
     metrics.counter(cid, "sparse_keys_read").inc(int(read.sum()))
     metrics.counter(cid, "sparse_keys_skipped").inc(int(skipped.sum()))
+
+
+# ---- picked keys: a learned indexer's first pass --------------------------------
+
+_SELECT_TILE = 128  # queries a step of the kernel: their scores of 16,384
+# keys are 8 MB of VMEM, their counts' partial sums a quarter of the registers
+_SELECT_KEYS = 512  # keys a pass of its loops
+_LOWEST = -2 ** 31
+
+
+def select_form(s: int, di: int, topk: int) -> str:
+    """Which form :func:`select_keys` is built with: ``"kernel"`` on a TPU
+    in a process with one device (as :func:`sparse_form`), for sequences of
+    whole blocks of keys and a ``topk`` of whole tiles of queries (so that
+    every tile either picks or reads every key before it), indexer heads of
+    half a lane tile or a whole one; ``"top_k"`` elsewhere."""
+    if (_use_pallas() and _one_device() and s % _SELECT_KEYS == 0
+            and topk % _SELECT_TILE == 0 and di in (64, 128)):
+        return "kernel"
+    return "top_k"
+
+
+def select_keys(qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray, *,
+                topk: int, tile: int = 1024) -> jnp.ndarray:
+    """The indexer's first pass over one row: ``qi: (Hi, S, Di)``, ``ki: (S,
+    Di)``, ``w: (S, Hi)`` -> ``(1, S, S)`` int8, 1 where key ``s`` is in
+    ``P(t)``. Exact: the ``topk`` largest of the float32 scores, the lower
+    position where two are equal. ``tile``: the queries whose scores exist
+    at once in the ``"top_k"`` form (the kernel's tile is its own)."""
+    s = qi.shape[1]
+    form = select_form(s, qi.shape[-1], topk)
+    _note("index_select", form)
+    with jax.named_scope(P.MIX_INDEX_SELECT):
+        if form == "kernel":
+            return _select_kernel_row(qi, ki, w.astype(F32), topk=topk)
+        return _select_top_k(qi, ki, w.astype(F32), topk, tile)
+
+
+def _select_top_k(qi, ki, w, topk, tile):
+    s = qi.shape[1]
+    out = []
+    for lo in range(0, s, tile):
+        hi = min(lo + tile, s)
+        j = jnp.arange(hi)
+        causal = j <= jnp.arange(lo, hi)[:, None]
+        picked = causal
+        if hi > topk:  # its last query has more keys than it reads
+            dots = jnp.einsum("htd,sd->hts", qi[:, lo:hi], ki[:hi],
+                              preferred_element_type=F32)
+            score = jnp.sum(w[lo:hi].T[:, :, None] * jnp.maximum(dots, 0.0),
+                            axis=0)
+            # -0 and +0 are one score; a key after the query has none
+            score = jnp.where(causal, jnp.where(score == 0, 0.0, score),
+                              -jnp.inf)
+            # the topk-th best and where it lies: equal scores go by index
+            best, at = lax.top_k(score, topk)
+            picked = causal & ((score > best[:, -1:]) | (
+                (score == best[:, -1:]) & (j <= at[:, -1:])))
+        out.append(jnp.pad(picked, ((0, 0), (0, s - hi))))
+    return jnp.concatenate(out, 0)[None].astype(jnp.int8)
+
+
+def _count(key_ref, n_blocks, block_k, hit):
+    """``(TQ, 1)`` int32: how many of the first ``n_blocks`` blocks' entries
+    of ``key_ref: (TQ, S)`` satisfy ``hit(entries (TQ, 128), first column)``;
+    lane by lane in the loop, the lanes summed once at its end."""
+    tq = key_ref.shape[0]
+
+    def block(i, partial):
+        at = pl.multiple_of(i * block_k, block_k)
+        for c in range(0, block_k, 128):
+            partial = partial + jnp.where(
+                hit(key_ref[:, pl.ds(at + c, 128)], at + c), 1, 0)
+        return partial
+
+    return jnp.sum(lax.fori_loop(0, n_blocks, block,
+                                 jnp.zeros((tq, 128), jnp.int32)),
+                   axis=-1, keepdims=True)
+
+
+def _select_kernel(q_ref, k_ref, w_ref, o_ref, key_ref, *, topk, block_k):
+    """One tile of ``TQ`` queries: ``q_ref: (Hi, TQ, Di)``, ``k_ref: (S,
+    Di)``, ``w_ref: (TQ, Hi)`` float32 -> ``o_ref: (1, TQ, S)`` int8;
+    ``key_ref: (TQ, S)`` int32 holds the tile's scores as integers in the
+    floats' order (a negative float's bits reversed; -0 with +0), the
+    lowest integer for a key after the query."""
+    heads, tq, di = q_ref.shape
+    s = k_ref.shape[0]
+    first = pl.program_id(0) * tq
+    n_blocks = pl.cdiv(first + tq, block_k)  # up to the tile's diagonal
+    t = first + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def column(at, width):
+        return at + lax.broadcasted_iota(jnp.int32, (tq, width), 1)
+
+    @pl.when(first + tq <= topk)
+    def _():  # every query of the tile reads every key before it
+        def block(i, _):
+            at = pl.multiple_of(i * block_k, block_k)
+            o_ref[0, :, pl.ds(at, block_k)] = jnp.where(
+                column(at, block_k) <= t, 1, 0).astype(o_ref.dtype)
+            return 0
+
+        lax.fori_loop(0, n_blocks, block, 0)
+
+    @pl.when(first + tq > topk)
+    def _():
+        q = q_ref[...].reshape(heads * tq, di)  # the heads stacked as rows
+        w = w_ref[...]
+
+        def scores(i, _):
+            at = pl.multiple_of(i * block_k, block_k)
+            dots = lax.dot_general(
+                q, k_ref[pl.ds(at, block_k), :], (((1,), (1,)), ((), ())),
+                preferred_element_type=F32)
+            acc = jnp.zeros((tq, block_k), F32)
+            for h in range(heads):
+                acc = acc + w[:, h:h + 1] * jnp.maximum(
+                    dots[h * tq:(h + 1) * tq], 0.0)
+            bits = lax.bitcast_convert_type(acc, jnp.int32)
+            key = jnp.where(bits >= 0, bits, (bits ^ 0x7fffffff) + 1)
+            key_ref[:, pl.ds(at, block_k)] = jnp.where(
+                column(at, block_k) <= t, key, _LOWEST)
+            return 0
+
+        lax.fori_loop(0, n_blocks, scores, 0)
+
+        def at_least(v):
+            v = jnp.broadcast_to(v, (tq, 128))
+            return _count(key_ref, n_blocks, block_k, lambda e, _: e >= v)
+
+        # the topk-th largest: the largest v with topk entries at or above
+        # it, the sign first, then bit by bit
+        v = jnp.where(at_least(jnp.zeros((tq, 1), jnp.int32)) >= topk,
+                      0, _LOWEST)
+
+        def bit(i, v):
+            candidate = v | (1 << (30 - i))
+            return jnp.where(at_least(candidate) >= topk, candidate, v)
+
+        v = lax.fori_loop(0, 31, bit, v)
+        above = jnp.broadcast_to(v, (tq, 128))
+        more = _count(key_ref, n_blocks, block_k, lambda e, _: e > above)
+        need = topk - more  # of the entries equal to v, the first ``need``
+        equal = _count(key_ref, n_blocks, block_k, lambda e, _: e == above)
+
+        # the position of the last of them: the largest p with fewer than
+        # ``need`` equal entries before it; where no query has more equal
+        # entries than it needs, every one is taken
+        def last_taken():
+            def bit(i, p):
+                candidate = jnp.broadcast_to(
+                    p | (1 << ((s - 1).bit_length() - 1 - i)), (tq, 128))
+                before = _count(
+                    key_ref, n_blocks, block_k, lambda e, at: (e == above) & (
+                        column(at, 128) < candidate))
+                return jnp.where(before < need, candidate[:, :1], p)
+
+            return lax.fori_loop(0, (s - 1).bit_length(), bit,
+                                 jnp.zeros((tq, 1), jnp.int32))
+
+        last = lax.cond(jnp.max(equal - need) > 0, last_taken,
+                        lambda: jnp.full((tq, 1), s, jnp.int32))
+
+        def block(i, _):
+            at = pl.multiple_of(i * block_k, block_k)
+            key = key_ref[:, pl.ds(at, block_k)]
+            where = column(at, block_k)
+            picked = ((key > v) | ((key == v) & (where <= last))) & (
+                where <= t)
+            o_ref[0, :, pl.ds(at, block_k)] = jnp.where(
+                picked, 1, 0).astype(o_ref.dtype)
+            return 0
+
+        lax.fori_loop(0, n_blocks, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "tile", "block_k",
+                                             "interpret"))
+def _select_kernel_row(qi, ki, w, *, topk, tile=_SELECT_TILE,
+                       block_k=_SELECT_KEYS, interpret=False):
+    """:func:`select_keys` of one row as one Pallas call, a tile of ``tile``
+    queries a step."""
+    heads, s, di = qi.shape
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, block_k=block_k),
+        grid=(s // tile,),
+        in_specs=[pl.BlockSpec((heads, tile, di), lambda i: (0, i, 0)),
+                  pl.BlockSpec((s, di), lambda i: (0, 0)),
+                  pl.BlockSpec((tile, heads), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((1, tile, s), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, s, s), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((tile, s), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(qi, ki, w)
+
+
+def blocks_picked(wanted: jnp.ndarray, block: int) -> tuple:
+    """``(picked, causal)`` of one row's mask ``(1, S, S)``: of the ``block
+    x block`` squares of queries and keys at or under the diagonal, those in
+    which some query reads some key (int32, counted), and all of them (from
+    shapes): what a second pass that skipped unpicked squares would still
+    read."""
+    s = wanted.shape[-1]
+    if s % block:
+        raise ValueError(f"squares of {block} over {s} positions")
+    n = s // block
+    with jax.named_scope(P.MIX_INDEX_SELECT):
+        # A query's picks a block of keys, by a product with the 0/1 matrix
+        # of which block a key lies in (exact in int32): the mask is read
+        # once where it lies. A view of it a square is another tiling of
+        # 268 MB of int8, a copy the compiler makes before it reduces
+        # (0.82 ms a row and layer on the chip: PERF.md section 6, PR 59).
+        of_block = (jnp.arange(s)[:, None] // block
+                    == jnp.arange(n)).astype(wanted.dtype)
+        some = jnp.dot(wanted[0], of_block, preferred_element_type=jnp.int32)
+        some = some.reshape(n, block, n).sum(1)
+        return jnp.sum(some != 0, dtype=jnp.int32), n * (n + 1) // 2
+
+
+def indexed_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                      qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray,
+                      scale: float, *, topk: int, count_block: int,
+                      block: int = 512, tile: int = 1024) -> tuple:
+    """The whole mixer's attention over picked keys: ``q: (B, Hq, S, Dk)``,
+    ``k: (B, Hkv, S, Dk)``, ``v: (B, Hkv, S, Dv)``; the indexer's ``qi: (B,
+    Hi, S, Di)``, ``ki: (B, S, Di)``, ``w: (B, S, Hi)`` -> ``(out (B, Hq, S,
+    Dv), squares picked, squares causal)`` (:func:`blocks_picked` with
+    ``count_block``, all rows together). A window of ``topk`` positions or
+    fewer is plain causal attention and nothing is scored. The rows of the
+    batch one after another, unrolled: a row's mask is made
+    (:func:`select_keys`), counted and read (``mix.sparse_attention``, in
+    :func:`sparse_form`'s form) before the next row's."""
+    from storm_tpu.ops.attention import causal_attention
+
+    b, hq, s, _ = q.shape
+    hkv = k.shape[1]
+    if s <= topk:
+        n = s // count_block
+        every = jnp.int32(b * (n * (n + 1) // 2))
+        return causal_attention(q, k, v, scale=scale), every, every
+    form = sparse_form(hq, hkv, s, q.shape[-1], v.shape[-1], 1)
+    _note("sparse_attention", form)
+    outs, picked, causal = [], jnp.int32(0), 0
+    index = (qi[0], ki[0], w[0])
+    for i in range(b):
+        wanted = select_keys(*index, topk=topk, tile=tile)
+        some, every = blocks_picked(wanted, count_block)
+        # The compiler orders a program by its data: a count that only the
+        # step's end reads would be left for the end and keep its 268 MB
+        # mask until then, every row's and every layer's. So the mask is
+        # counted before it is read ...
+        wanted, some = lax.optimization_barrier((wanted, some))
+        picked, causal = picked + some, causal + every
+        with jax.named_scope(P.MIX_SPARSE_ATTENTION):
+            if form == "kernel":
+                outs.append(_kernel_row(q, k, v, wanted, scale, i))
+            else:
+                outs.append(_blocked_row(
+                    q[i], k[i], v[i],
+                    lambda lo, hi: wanted[:, lo:hi, :hi] != 0, scale, block))
+        if i + 1 < b:  # ... and the next row's is made after this one's read
+            outs[-1], index = lax.optimization_barrier(
+                (outs[-1], (qi[i + 1], ki[i + 1], w[i + 1])))
+    with jax.named_scope(P.MIX_SPARSE_ATTENTION):
+        return jnp.stack(outs), picked, jnp.int32(causal)
+
+
+def observe_block_counts(metrics, cid: str, picked, causal) -> None:
+    """What :func:`indexed_attention` counted in one step, fetched to the
+    host (a number a layer each), into the registry under ``cid``: the
+    squares of queries and keys in which a query read a key, and the causal
+    squares, as two counters."""
+    metrics.counter(cid, "index_blocks_picked").inc(int(picked.sum()))
+    metrics.counter(cid, "index_blocks_causal").inc(int(causal.sum()))

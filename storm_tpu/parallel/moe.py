@@ -130,13 +130,16 @@ def moe_layer(
 def topk_moe_init(rng, dim: int, hidden: int, n_experts: int,
                   n_held: Optional[int] = None, shared: bool = True,
                   dtype=jnp.float32, form: str = "swiglu",
-                  shared_hidden: Optional[int] = None) -> dict:
-    """A router over ``n_experts`` with its selection bias, ``n_held``
-    experts of width ``hidden`` stacked on a leading axis (all of them where
-    ``n_held`` is None), and the shared expert, of width ``shared_hidden``
-    (``hidden`` where None). ``form``: ``"swiglu"`` (``gate``, ``up``,
-    ``down``) or ``"relu2"`` (``up``, ``down``: a squared ReLU between);
-    the layer reads the form off the parameters."""
+                  shared_hidden: Optional[int] = None,
+                  selection_bias: bool = True) -> dict:
+    """A router over ``n_experts`` with its selection bias (no
+    ``router_bias`` leaf where ``selection_bias`` is false: the router then
+    picks by the score alone), ``n_held`` experts of width ``hidden``
+    stacked on a leading axis (all of them where ``n_held`` is None), and
+    the shared expert, of width ``shared_hidden`` (``hidden`` where None;
+    no ``shared`` leaf where ``shared`` is false). ``form``: ``"swiglu"``
+    (``gate``, ``up``, ``down``) or ``"relu2"`` (``up``, ``down``: a squared
+    ReLU between); the layer reads the form off the parameters."""
     from storm_tpu.ops import layers as L
 
     if form not in ("swiglu", "relu2"):
@@ -154,6 +157,8 @@ def topk_moe_init(rng, dim: int, hidden: int, n_experts: int,
     if form == "swiglu":
         p["experts"]["gate"] = L.lecun_normal(kg, (n_held, dim, hidden), dim,
                                               dtype)
+    if not selection_bias:
+        del p["router_bias"]
     if shared:
         init = L.swiglu_init if form == "swiglu" else L.relu2_init
         p["shared"] = init(ks, dim, shared_hidden or hidden, dtype)

@@ -260,7 +260,9 @@ def test_mask_kernel_is_the_blocked_form_under_the_interpreter():
     assert int(picked[1, 0, -1].sum()) == 6
     got = sa._kernel_row(q, k, v, sa._wanted(picked[1], 64), d ** -0.5, 1,
                          interpret=True)
-    want = sa._blocked_row(q[1], k[1], v[1], picked[1], d ** -0.5, 64, 512)
+    want = sa._blocked_row(
+        q[1], k[1], v[1], lambda lo, hi: sa._wanted(picked[1], 64, lo, hi),
+        d ** -0.5, 512)
     assert got.shape == want.shape == (hq, s, d)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)

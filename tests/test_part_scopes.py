@@ -31,6 +31,10 @@ EXPECTED = {
     # four layers whose queries read a window, one that reads every key
     "trinity_tiny": COMMON | MOE | {parts.MIX_WINDOW_ATTENTION,
                                     parts.MIX_ROPE},
+    # 40 positions into 12 keys a query: the indexer's first pass and the
+    # masked second pass; the dense form's ``mix.attention`` is not run
+    "keye_tiny": (COMMON - {parts.MIX_ATTENTION}) | MOE | {
+        parts.MIX_INDEX_SELECT, parts.MIX_SPARSE_ATTENTION, parts.MIX_ROPE},
 }
 
 
@@ -99,8 +103,11 @@ def test_the_innermost_name_is_the_operations():
     assert parts.part_of(
         "jit(fwd)/mix.elementwise/mix.window_attention/while/body/exp") == \
         parts.MIX_WINDOW_ATTENTION
+    assert parts.part_of(
+        "jit(fwd)/mix.elementwise/mix.index_select/jit(_select_kernel_row)/"
+        "pallas_call") == parts.MIX_INDEX_SELECT
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 18
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 19
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():

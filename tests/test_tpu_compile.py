@@ -190,6 +190,66 @@ def test_trinitys_mixer_compiles_with_its_heads_merged_from_projection_to_projec
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 2 ** 30
 
 
+def test_the_indexers_selection_compiles_at_the_published_sizes(v5e):
+    """``ops/sparse_attention.py select_keys``' kernel as Keye-VL-2.0's cell
+    calls it, one row: 16 indexer heads of 64 over 16,384 positions, the top
+    2,048 a query. A tile's scores are 8 MB of VMEM beside the row's keys
+    (lanes half full) and the mask's block; the scalar test that skips the
+    second bisection where no scores tie and the int8 stores are Mosaic's to
+    take or refuse. One call, and nothing of ``S x S`` but the mask."""
+    from storm_tpu.ops import sparse_attention as sa
+
+    compiled = jax.jit(lambda q, k, w: sa._select_kernel_row(
+        q, k, w, topk=2048)).lower(
+        _spec((16, 16384, 64), jnp.bfloat16, v5e),
+        _spec((16384, 64), jnp.bfloat16, v5e),
+        _spec((16384, 16), jnp.float32, v5e)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "s8[1,16384,16384]" in text and "f32[16384,16384]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 16384 * 16384
+    assert mem.temp_size_in_bytes < 32 << 20
+
+
+def test_keyes_mixer_compiles_with_its_rows_unrolled_and_a_mask_at_a_time(
+        v5e, monkeypatch):
+    """Keye-VL-2.0's mixer whole at its cell's step (4 windows of 16,384 into
+    2,048, 32 query heads on 4 key heads of 128, the indexer's 16 heads of
+    64, bfloat16) as one chip builds it: ten kernels (the head norm and turn
+    of q and of k; a row's selection and its masked second pass, four rows)
+    and no loop, so that a trace shows the two passes as events of their own
+    under their own parts; and the four 268 MB masks are never all alive (the
+    count of squares would keep each until the step's end: 1.34 GiB of
+    temporaries as built, 2.1 and more without the barriers)."""
+    import re
+
+    import storm_tpu.ops.rope as rope
+    import storm_tpu.ops.sparse_attention as sa
+    from storm_tpu.models.keye import keye_mixer, keye_mixer_init
+
+    for module in (sa, rope):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    assert sa.select_form(16384, 64, 2048) == "kernel"
+    assert sa.sparse_form(32, 4, 16384, 128, 128, 1) == "kernel"
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: keye_mixer_init(
+            jax.random.PRNGKey(0), 2048, 32, 4, 128, 16, 64)))
+    x = _spec((4, 16384, 2048), jnp.bfloat16, v5e)
+    wide, narrow = (_spec((16384, n), jnp.float32, v5e) for n in (64, 32))
+    compiled = jax.jit(lambda p, x, a, b, c, d: keye_mixer(
+        p, x, 32, 4, 128, 16, 64, 1e-6, ((a, b), (c, d)), 2048, 512)).lower(
+        p, x, wide, wide, narrow, narrow).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 10
+    assert not _loops(text)
+    assert len(re.findall(r"s8\[1,16384,16384\]\S* custom-call", text)) == 4
+    assert "/mix.index_select/" in text and "/mix.sparse_attention/" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * 2 ** 30
+
+
 @pytest.mark.parametrize("shape,heads", [
     ((256, 257, 1408), 16),   # ViT-g/14, the benchmark's largest bucket
     ((128, 197, 768), 12),    # ViT-B/16 at batch 128
