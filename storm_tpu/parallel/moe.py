@@ -197,11 +197,14 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
     return experts, weights * scale
 
 
-# The combine's loop: a block is this many consecutive tokens, a tile this
-# many held assignments of one block. On a v5e, a quarter and an eighth of
-# the assignments held (PERF.md, PR 37): 256 x 512 and 512 x 1,024 read the
-# same, 512 x 512 and 128 x 256 a twentieth more (a tile gathers its empty
-# places too, and reads and writes its block's sums).
+# The combine's loop: a block is at most this many consecutive tokens, a tile
+# this many held assignments of one block. The pair was read on a v5e with a
+# quarter and an eighth of the assignments held (PERF.md, PR 37), where a
+# block of 256 tokens holds 256 to 512 of them and is one tile: 256 x 512 and
+# 512 x 1,024 read the same, 512 x 512 and 128 x 256 a twentieth more (a tile
+# gathers its empty places too, and reads and writes its block's sums). Where
+# more is held the block is smaller, so that its expected run is still one
+# tile (:func:`_combine_held`: 64 tokens of 8 assignments each, all held).
 _COMBINE_BLOCK = 256
 _COMBINE_ROWS = 512
 
@@ -389,31 +392,56 @@ def _dispatch(local, weights, held: int, tile: int):
 
 
 def _combine_held(out, row_at, token_at, n: int, top_k: int,
-                  held_share: float):
+                  held_share: float, none_absent: bool = False):
     """``(n, dim)`` float32: each token's sum of its held assignments' rows
     of ``out``, reading no other row. The assignments come as pairs in any
     order, ``row_at`` the row of ``out`` and ``token_at`` the token (an
     absent assignment's row is the zero row, the last; its token is not
     read). The grouped product's mirror image: the held pairs are grouped
-    by block of ``_COMBINE_BLOCK`` consecutive tokens (one sort keyed on the
-    block, absent ones last, that carries the pair along), each block's run
-    is cut into tiles of ``_COMBINE_ROWS``, and a loop over as many tiles as
-    the data made gathers a tile's rows and adds them to the block's tokens
-    by a 0/1 matrix on the matrix unit (products with 0 and 1 are exact, the
-    sum is float32), so the order inside a block is immaterial. A block's
-    run is short of a tile wherever a part of the router is held, so its
-    last tile, which is mostly its only one, gathers and multiplies
+    by block of consecutive tokens (one sort keyed on the block, absent ones
+    last, that carries the pair along), each block's run is cut into tiles
+    of ``_COMBINE_ROWS``, and a loop over as many tiles as the data made
+    gathers a tile's rows and adds them to the block's tokens by a 0/1
+    matrix on the matrix unit (products with 0 and 1 are exact, the sum is
+    float32), so the order inside a block is immaterial.
+
+    The block is the most tokens, a multiple of 8 and ``_COMBINE_BLOCK`` at
+    most, whose expected run ``block * top_k * held_share`` fits one tile
+    (``held_share``: the part of the assignments that is held, by shapes):
+    256 where an eighth or a quarter of a router is held (the regime the two
+    constants were read in), 64 where all of a top-8 router is. A tile's 0/1
+    matrix and the sums it touches are a block tall, so a block cut into
+    four tiles multiplies and rewrites four times what its rows need.
+
+    A block's run never passes ``block * top_k``, so where that is the
+    tile's size every block is one tile at most, whatever the routing, and
+    the tile writes its sums where it would else read the block's, add and
+    write back: noted ``combine_write=once`` (``added`` elsewhere). Only a
+    block without a held assignment then keeps the zeros the sums start
+    from, and where the caller knows every assignment held
+    (``none_absent``) there is none: the sums are allocated, not zeroed.
+
+    A block's run is short of a tile wherever a part of the router is held,
+    so its last tile, which is mostly its only one, gathers and multiplies
     :func:`tile_sizes`' small size where its places fit, in a loop of that
     size (:func:`_loop_by_size`; a block's whole tiles are added first and
     in their order, its last tile after them, as one loop over all would).
-    ``held_share``: the part of the assignments that is held, by shapes.
-    Noted as ``combine_tiles=last-<small>`` (``whole``: one size)."""
+    Where the blocks are one tile each and the expected run fills it, no run
+    is short but a window's last, and the loop has one size. Noted as
+    ``combine_tiles=last-<small>`` (``whole``: one size)."""
     dim = out.shape[1]
-    block = min(_COMBINE_BLOCK, -(-n // 8) * 8)
+    a_token = top_k * held_share  # its expected held assignments
+    fits = int(_COMBINE_ROWS / a_token) if a_token else _COMBINE_BLOCK
+    block = max(8, min(_COMBINE_BLOCK, fits // 8 * 8, -(-n // 8) * 8))
     rows = min(_COMBINE_ROWS, block * top_k)
     blocks = -(-n // block)
-    sizes = tile_sizes(rows, block * top_k * held_share, tight=True)
+    once = rows == block * top_k
+    if once and block * a_token >= rows:
+        sizes = (rows,)
+    else:
+        sizes = tile_sizes(rows, block * a_token, tight=True)
     _note("combine_tiles", _tiles_note(sizes))
+    _note("combine_write", "once" if once else "added")
     block_at, row_at, token_at = jax.lax.sort(
         (jnp.where(row_at < out.shape[0] - 1, token_at // block, blocks),
          row_at, token_at), num_keys=1, is_stable=False)
@@ -438,12 +466,15 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
             add = jnp.dot(mine, picked, precision=exact,
                           preferred_element_type=jnp.float32)
             corner = (b * block, 0)
-            return jax.lax.dynamic_update_slice(y, jax.lax.dynamic_slice(
-                y, corner, (block, dim)) + add, corner)
+            if not once:
+                add = jax.lax.dynamic_slice(y, corner, (block, dim)) + add
+            return jax.lax.dynamic_update_slice(y, add, corner)
         return one_tile
 
+    # every block is written where each is one tile and none is empty
+    make = jax.lax.empty if once and none_absent else jnp.zeros
     y = _loop_by_size(tiles, ends, sizes, at_rows,
-                      jnp.zeros((blocks * block, dim), jnp.float32))
+                      make((blocks * block, dim), jnp.float32))
     return y[:n]
 
 
@@ -499,7 +530,12 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     than it gathers). A second loop of the same kind reads the held rows
     alone, a block of tokens at a time (:func:`_combine_held`, which takes
     the (row, token) pairs in the dispatch's order): an absent assignment's
-    row is never read. Noted as ``expert_combine=held-rows``."""
+    row is never read. Noted as ``expert_combine=held-rows``. The block
+    follows the share of the router that is held, so that a block's run is
+    about one tile: 256 tokens at an eighth or a quarter held, 64 where all
+    of a top-8 router is, and there a tile is its block's only one and
+    writes the block's sums once (``combine_write=once``; ``added`` where a
+    block may take several tiles, which add to them)."""
     from storm_tpu.ops import layers as L
 
     shape = x.shape
@@ -564,7 +600,11 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     _note("expert_tiles", _tiles_note(sizes))
     _note("expert_combine", "held-rows")
     with jax.named_scope(parts.MOE_COMBINE):
-        y = _combine_held(out, row_at, token_at, n, top_k, held / width)
+        # the whole router from its first expert on (a traced first: unknown)
+        whole = held == width and isinstance(first_expert, int) \
+            and first_expert == 0
+        y = _combine_held(out, row_at, token_at, n, top_k, held / width,
+                          none_absent=whole)
     if "shared" in p:
         with jax.named_scope(parts.PROJ):
             shared = L.feed_forward(p["shared"], tokens)

@@ -17,14 +17,23 @@ from storm_tpu.parallel import moe
 from storm_tpu.parallel.moe import route_topk, topk_moe_init, topk_moe_layer
 
 DIM = 32
+SERVED = (moe._COMBINE_BLOCK, moe._COMBINE_ROWS)  # 256 tokens, 512 rows
 
 
 @pytest.fixture(autouse=True)
 def small_blocks(monkeypatch):
-    """Blocks of 16 tokens and tiles of 8 held rows: a hundred tokens then
-    make several blocks, and a busy block several tiles."""
+    """Blocks of at most 16 tokens and tiles of 8 held rows: a hundred
+    tokens then make several blocks, and a busy block several tiles."""
     monkeypatch.setattr(moe, "_COMBINE_BLOCK", 16)
     monkeypatch.setattr(moe, "_COMBINE_ROWS", 8)
+
+
+def _block(n, top_k, share, most=16, tile=8):
+    """The rule, said again: the most tokens, by eights and ``most`` at
+    most, whose expected held assignments fit a tile; never under 8, never
+    more than the tokens there are."""
+    fits = int(tile / (top_k * share)) // 8 * 8 if share else most
+    return max(8, min(most, fits, -(-n // 8) * 8))
 
 
 def _plain(p, x, top_k, first, scale=2.5):
@@ -103,9 +112,11 @@ def test_a_tokens_sum_is_the_plain_sum(case, ffn):
             y, tokens, absent = jax.jit(lambda p, x: topk_moe_layer(
                 p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
         want = _plain(p, x, top_k, first)
+    # a block of 8 tokens is cut into tiles of 8 rows: several a block
+    assert _block(n, top_k, held / width) * top_k > 8
     assert seen == [f"expert_ffn={ffn}", "expert_dispatch=sorted",
                     "expert_tiles=whole", "expert_combine=held-rows",
-                    "combine_tiles=whole"]
+                    "combine_tiles=whole", "combine_write=added"]
     np.testing.assert_allclose(y, want, atol=1e-5 * max(1.0, float(
         jnp.abs(want).max())))
     assert int(tokens.sum()) + int(absent) == n * top_k
@@ -158,6 +169,137 @@ def test_the_loop_on_any_share_held(dtype, n, top_k, share):
         out, row_of[mixed], token[mixed])
     assert got.dtype == jnp.float32 and got.shape == (n, DIM)
     np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+# (top-k, share held): (block, tile, the small size or None) at the served
+# constants and tokens in plenty. The first of each line is what the cells
+# and the presets have: 2 x 1/4 the tiny presets, 6 x 1/4 Nemotron's, 8 x 1
+# Trinity's and Keye's, 8 x 1/8 Kimi-Linear's and Solar's, 8 x 1/32 Kimi K2's
+RULE = {
+    (2, 1.0): (256, 512, None), (2, 0.5): (256, 512, 384),
+    (2, 0.25): (256, 512, 256), (2, 0.125): (256, 512, 128),
+    (2, 1 / 32): (256, 512, 128),
+    (6, 1.0): (80, 480, None), (6, 0.5): (168, 512, None),
+    (6, 0.25): (256, 512, None), (6, 0.125): (256, 512, 256),
+    (6, 1 / 32): (256, 512, 128),
+    (8, 1.0): (64, 512, None), (8, 0.5): (128, 512, 256),
+    (8, 0.25): (256, 512, 256), (8, 0.125): (256, 512, 384),
+    (8, 1 / 32): (256, 512, 128),
+}
+
+
+def _loops_of(jaxpr):
+    """Of each ``while`` of a traced combine, in order: the 0/1 matrix's
+    shape, and the shapes its body slices out of an array that it carries
+    whole (the read of a block's sums is one of ``(block, dim)``)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "while":
+            continue
+        body = eqn.params["body_jaxpr"].jaxpr
+        (dot,) = [e for e in body.eqns if e.primitive.name == "dot_general"]
+        sliced = [e.outvars[0].aval.shape for e in body.eqns
+                  if e.primitive.name == "dynamic_slice"
+                  and e.outvars[0].aval.dtype == jnp.float32]
+        found.append((dot.invars[0].aval.shape, sliced))
+    return found
+
+
+@pytest.mark.parametrize("tokens", ["whole-blocks", "a-short-last-block",
+                                    "a-hundred"])
+@pytest.mark.parametrize("top_k,share", list(RULE), ids=[
+    f"top{k}-{s:.3g}-held" for k, s in RULE])
+def test_the_block_is_the_most_tokens_whose_run_fits_a_tile(
+        top_k, share, tokens, monkeypatch):
+    """At the served constants: the block and the tile by the rule; a tile
+    writes its block's sums without reading them exactly where the tile is
+    ``block * top_k`` rows (no block can then make two), noted
+    ``combine_write=once``; the loop has one size there exactly where the
+    expected run fills the tile too; the sums are allocated and not zeroed
+    exactly where besides every assignment is held, and then every row of
+    them is written: handed a buffer full of NaN the result is the plain
+    sum."""
+    monkeypatch.setattr(moe, "_COMBINE_BLOCK", SERVED[0])
+    monkeypatch.setattr(moe, "_COMBINE_ROWS", SERVED[1])
+    block, rows, small = RULE[top_k, share]
+    assert block == _block(10 ** 6, top_k, share, *SERVED)
+    n = {"whole-blocks": 3 * block, "a-short-last-block": 3 * block + 20,
+         "a-hundred": 100}[tokens]
+    if n == 100:  # 104 tokens a block at most, and a shorter run of them
+        block = min(block, 104)
+        rows = min(rows, block * top_k)
+        small = (moe.tile_sizes(rows, block * top_k * share, True)
+                 + (None,))[1]
+    assert block == _block(n, top_k, share, *SERVED)
+    once = rows == block * top_k
+    assert once == (top_k == 2 or share == 1.0)
+    if once and share == 1.0:
+        small = None
+
+    out, row_of = _assignments(3, n, top_k, 150, share)
+    token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
+    mixed = jax.random.permutation(jax.random.PRNGKey(4), n * top_k)
+    allocated = []
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: (
+        allocated.append(shape), jnp.full(shape, jnp.nan, dtype))[1])
+
+    def combine(o, r, t):
+        return moe._combine_held(o, r, t, n, top_k, share,
+                                 none_absent=share == 1.0)
+
+    with dispatch_notes() as seen:
+        loops = _loops_of(jax.make_jaxpr(combine)(
+            out, row_of[mixed], token[mixed]).jaxpr)
+    assert seen == ["combine_tiles=" + (f"last-{small}" if small else
+                                        "whole"),
+                    "combine_write=" + ("once" if once else "added")]
+    assert [mine for mine, _ in loops] == [(block, m) for m in (
+        (rows, small) if small else (rows,))]
+    for _, sliced in loops:
+        assert ((block, DIM) in sliced) == (not once)
+    blocks = -(-n // block)
+    assert allocated == ([(blocks * block, DIM)] if once and share == 1.0
+                         else [])
+    got = jax.jit(combine)(out, row_of[mixed], token[mixed])
+    want = np.asarray(out, np.float64)[np.asarray(row_of)].reshape(
+        n, top_k, DIM).sum(1)
+    assert got.dtype == jnp.float32 and got.shape == (n, DIM)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("held,first,allocated", [
+    (8, 0, True), (8, None, False), (4, 0, False), (4, 4, False)],
+    ids=["whole-router", "whole-router-traced-first", "first-half",
+         "second-half"])
+def test_the_sums_are_allocated_only_where_the_whole_router_is_held(
+        held, first, allocated, monkeypatch):
+    """At the served constants, top-2 of 8 (a block is one tile in each
+    case): the layer allocates its sums where it holds the router's whole
+    width from expert 0 on, by shapes and a static ``first_expert`` (a
+    traced one could be anything: zeros), and then an allocation full of
+    NaN gives the zeroed form's bytes."""
+    monkeypatch.setattr(moe, "_COMBINE_BLOCK", SERVED[0])
+    monkeypatch.setattr(moe, "_COMBINE_ROWS", SERVED[1])
+    n, tile = 100, 16
+    p = _layer(8, held, "swiglu")
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, DIM))
+    sums = (104, DIM)
+    got = {}
+    for fill in (jnp.nan, 0.0):
+        shapes = []
+        monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: (
+            shapes.append((shape, dtype)), jnp.full(shape, fill, dtype))[1])
+        if first is None:
+            got[fill] = jax.jit(lambda p, x, f: topk_moe_layer(
+                p, x, 2, first_expert=f, tile=tile))(p, x, 0)
+        else:
+            got[fill] = jax.jit(lambda p, x: topk_moe_layer(
+                p, x, 2, first_expert=first, tile=tile))(p, x)
+        assert ((sums, jnp.float32) in shapes) == allocated
+        assert len(shapes) == 1 + allocated  # the tiles' buffer, always
+    for a, b in zip(got[jnp.nan], got[0.0]):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert np.isfinite(np.asarray(got[jnp.nan][0])).all()
 
 
 @pytest.mark.parametrize("held,first", [(8, 0), (2, 2)],
@@ -368,9 +510,9 @@ def test_no_gather_or_scatter_runs_over_the_assignments():
     and no gather that returns a value an assignment, ``route_topk``'s
     chosen scores included (flat or ``[tokens, top_k]``: they are a
     comparison and a maximum); it reads the ordered assignments only where a
-    bisection probes them (``held + 1`` and ``blocks + 1`` probes), sorts
-    twice beside the router's top-k, and makes the tiles' buffer of nothing
-    that fills it."""
+    bisection probes them (``held + 1`` and ``blocks + 1`` probes, the blocks
+    the rule's: 8 tokens each here), sorts twice beside the router's top-k,
+    and makes the tiles' buffer of nothing that fills it."""
     held, top_k, n = 4, 6, 64
     text = _lowered(_layer(16, held), jax.random.normal(
         jax.random.PRNGKey(2), (n, DIM)), top_k, 4)
@@ -380,7 +522,8 @@ def test_no_gather_or_scatter_runs_over_the_assignments():
     assert gathers
     flat = "tensor<%dx" % (n * top_k)
     picks = "tensor<%dx%dx" % (n, top_k)
-    probes = {held + 1, n // 16 + 1}
+    probes = {held + 1, n // _block(n, top_k, held / 16) + 1}
+    assert probes == {5, 9}
     for line in gathers:
         operand, result = re.search(r": \((tensor<[^>]*>), .*\) -> "
                                     r"(tensor<[^>]*>)", line).groups()

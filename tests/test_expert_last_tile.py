@@ -259,17 +259,27 @@ def test_tiles_by_size_and_every_assignments_row(kind, held, top_k, width,
     assert zero_row > max(written, default=-1)
 
 
-@pytest.mark.parametrize("name,experts,combine", [
-    ("kimi_linear_48b", "last-512", "last-384"),
-    ("nemotron_3_nano_30b", "last-256", "whole"),
-    ("kimi_k2_6", "last-384", "last-128"),
-    ("solar_open2_250b", "last-256", "last-384"),
-    ("kimi_linear_tiny", "whole", None), ("nemotron_h_tiny", "whole", None),
-    ("kimi_k2_tiny", "whole", None), ("solar_open2_tiny", "whole", None)])
-def test_a_models_step_names_its_loops_sizes(name, experts, combine):
-    """What the engine's inventory says of a program: the four expert models
+@pytest.mark.parametrize("name,experts,combine,write", [
+    ("kimi_linear_48b", "last-512", "last-384", "added"),
+    ("nemotron_3_nano_30b", "last-256", "whole", "added"),
+    ("kimi_k2_6", "last-384", "last-128", "added"),
+    ("solar_open2_250b", "last-256", "last-384", "added"),
+    ("trinity_mini", "last-512", "whole", "once"),
+    ("keye_vl2_30b", "last-512", "whole", "once"),
+    ("kimi_linear_tiny", "whole", None, "once"),
+    ("nemotron_h_tiny", "whole", None, "once"),
+    ("kimi_k2_tiny", "whole", None, "once"),
+    ("solar_open2_tiny", "whole", None, "once"),
+    ("trinity_tiny", "whole", "whole", "once"),
+    ("keye_tiny", "whole", "whole", "once")])
+def test_a_models_step_names_its_loops_sizes(name, experts, combine, write):
+    """What the engine's inventory says of a program: the six expert models
     traced at their largest step, shapes only (the presets' tile of 16 has
-    one size; their combine's follows their rows a step)."""
+    one size; their combine's follows their rows a step). A block of the
+    combine is one tile, written once, where the whole router is held (64
+    tokens of 8 assignments) and in the presets' top-2 layers (256 of 2);
+    the four cells that hold a part of theirs add a tile to its block's
+    sums, as they did."""
     from storm_tpu.models.registry import build_model
 
     model = build_model(name)
@@ -283,3 +293,5 @@ def test_a_models_step_names_its_loops_sizes(name, experts, combine):
     tiles = [note for note in seen if note.startswith("combine_tiles=")]
     assert len(tiles) == 1
     assert combine is None or tiles == [f"combine_tiles={combine}"]
+    assert [note for note in seen if note.startswith("combine_write=")] == [
+        f"combine_write={write}"]

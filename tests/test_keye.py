@@ -440,13 +440,16 @@ def test_two_half_shares_add_up_to_the_all_held_layer():
 # digests leave out: the first 16 hex digits of the sha256 of the lowered
 # text, of the tree ``init`` makes and, for the toy, of its leaves from key 7,
 # as the parent of PR 59 built them. (The other six plans' lines are in those
-# two files, and this PR leaves them as they were.)
-TRINITY = {"trinity_tiny": ("d096c33dd207326a", "02ca71f3daed22c6",
+# two files, and this PR leaves them as they were. PR 60 changed the text of
+# these four lines: both plans hold their whole router, so the combine's
+# block is 64 tokens or fewer, one tile each, written once; trees and leaves
+# are the parent's.)
+TRINITY = {"trinity_tiny": ("7d7cdf66bd3d9742", "02ca71f3daed22c6",
                             "fb4f89f513f7395d"),
-           "trinity_mini": ("f5cd7018d6d5f9b3", "c7f7b016380d3f34")}
-KEYE = {"keye_tiny": ("170d9a13d3bc5833", "2d028cd1c66aed41",
+           "trinity_mini": ("f056703cb957a557", "c7f7b016380d3f34")}
+KEYE = {"keye_tiny": ("ff379ced75e497f1", "2d028cd1c66aed41",
                       "ed3354f4d5badb8f"),
-        "keye_vl2_30b": ("11ff882b0d39f408", "11769136fc6552db")}
+        "keye_vl2_30b": ("9f515b04939f3853", "11769136fc6552db")}
 
 
 def _digest(*chunks):

@@ -318,7 +318,7 @@ def test_relu2_expert_layer_drops_no_token(skew):
             p["shared"], x)
     assert seen == ["expert_ffn=relu2", "expert_dispatch=sorted",
                     "expert_tiles=whole", "expert_combine=held-rows",
-                    "combine_tiles=whole"]
+                    "combine_tiles=whole", "combine_write=once"]
     np.testing.assert_allclose(y, want, atol=1e-5 * float(
         jnp.abs(want).max()))
     assert int(tokens.sum()) == 2 * 111 and int(absent) == 0
@@ -476,6 +476,7 @@ def test_the_inventory_names_the_three_forms():
                           "expert_tiles=whole",
                           "expert_combine=held-rows",
                           "combine_tiles=last-384",
+                          "combine_write=once",
                           "causal_attention=blocked-grouped"}
 
 

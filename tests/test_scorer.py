@@ -221,13 +221,17 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
 # lines stand, and the sixth plan's two are as PR 52 built it. PR 56 changed
 # the text of the four expert models' eight lines: ``parallel/moe.py``'s two
 # loops meet the tiles in an order made beforehand and have a second loop
-# for a run's last tile at a smaller size; MiniCPM-SALA's two stand.)
+# for a run's last tile at a smaller size; MiniCPM-SALA's two stand. PR 60
+# changed the text of the four toy expert models' lines alone: their top-2
+# layers' blocks of the combine are one tile each (256 tokens x 2), which
+# now writes its sums and does not read them back; the four served sizes,
+# which hold a part of an 8- or 6-a-token router, stand to the letter.)
 PARENT = {
-    "kimi_linear_tiny": ("ceba811f71c7e3d9", "9c9231af39d81a3b",
+    "kimi_linear_tiny": ("c1402509f112bf5b", "9c9231af39d81a3b",
                          "d6d687f48a145060"),
-    "nemotron_h_tiny": ("2dae57abe535cd25", "ffb1a7d7c726fcef",
+    "nemotron_h_tiny": ("22faaeba31d98f28", "ffb1a7d7c726fcef",
                         "b8b23e69c062a671"),
-    "kimi_k2_tiny": ("1d39419de880de54", "4015722ee481843e",
+    "kimi_k2_tiny": ("f63c44cca5bbcaf6", "4015722ee481843e",
                      "3eee1654ad04fdad"),
     "minicpm_sala_tiny": ("e80bd69b29e9bb6e", "fbaec783e93f7071",
                           "136d1265de921964"),
@@ -235,7 +239,7 @@ PARENT = {
     "nemotron_3_nano_30b": ("c3c98b01400caee8", "32502e49d7fc6552"),
     "kimi_k2_6": ("3695cae34e2865eb", "4a919d11ba374a7b"),
     "minicpm_sala": ("5de5b298fd713172", "31e93c80f04d610e"),
-    "solar_open2_tiny": ("ec74a4f06f3a9d3e", "10b47418e20c9cdc",
+    "solar_open2_tiny": ("b4128d092f97e4cf", "10b47418e20c9cdc",
                          "12fec92ac2c8f7b2"),
     "solar_open2_250b": ("e3192e3869b746e8", "d3a89bd4d4e674a5"),
 }
