@@ -202,7 +202,7 @@ def encode_predictions(preds: Predictions | np.ndarray) -> str:
 
 
 def decode_predictions(payload: str | bytes) -> Predictions:
-    """Parse a ``{"predictions": ...}`` payload (used by tests/clients)."""
+    """Parse a ``{"predictions": ...}`` payload (used by tests and clients)."""
     if isinstance(payload, bytes):
         payload = payload.decode("utf-8")
     try:

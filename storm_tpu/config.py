@@ -285,7 +285,7 @@ class TopologyConfig:
     max_spout_pending: int = 2048  # in-flight roots per spout instance
     # Records per emitted spout tuple. 1 = the reference's per-record
     # granularity; N>1 amortizes ledger/executor overhead at high message
-    # rates (replay granularity becomes the chunk). BENCH_NOTES.md.
+    # rates (replay granularity becomes the chunk).
     spout_chunk: int = 1
     # Tuple-value scheme (Storm StringScheme vs RawScheme,
     # MainTopology.java:100): "string" = decode records to str (compatible
@@ -560,8 +560,8 @@ class ObsConfig:
     """Continuous profiling & SLO-burn observatory (storm_tpu/obs/).
 
     The per-(engine, bucket) cost profiler itself is always-on and
-    near-free (one dict update per device batch — see
-    BENCH_OBS_OVERHEAD_r11.json); ``enabled`` gates the *control loop*:
+    near-free (one dict update per device batch; PERF.md §6, PR 41, has
+    what the logs cost on the chip); ``enabled`` gates the *control loop*:
     the Observatory task that steps the burn tracker, publishes occupancy
     gauges, and runs the regression sentinel. The burn tracker needs
     ``tracing.slo_ms`` set — without it the sink never counts breaches
@@ -580,7 +580,7 @@ class ObsConfig:
     burn_slow_window_s: float = 600.0
     burn_threshold: float = 1.0
     # Regression sentinel: compare live stage costs against this
-    # PROFILE_*.json snapshot ("" = sentinel off); flag a (engine,
+    # saved `profile --json` snapshot ("" = sentinel off); flag a (engine,
     # bucket, stage) cell when live mean > regression_factor x baseline,
     # once it has at least min_samples live observations.
     baseline_path: str = ""
@@ -635,7 +635,7 @@ class PlanConfig:
     """SLO-aware joint planner (storm_tpu/plan/): offline solve + online
     correct.
 
-    The offline half (``storm-tpu plan``, ``bench.py --plan``) needs no
+    The offline half (``storm-tpu plan``) needs no
     config at all — it solves over a ProfileStore snapshot for an explicit
     (rate, SLO) target. This section configures the *online* half: when
     ``enabled``, the daemon attaches a :class:`storm_tpu.plan.corrector.

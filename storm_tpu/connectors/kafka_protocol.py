@@ -987,7 +987,7 @@ class KafkaWireClient:
             # carries the supported-versions array — a future broker
             # answering v0 with error 35 is exactly the case the loud
             # KIP-896 check exists for, so parse and validate rather
-            # than treating it as a silent no-answer (ADVICE r3-low).
+            # than treating it as a silent no-answer (a round-3 review, low).
             if err and err != 35:
                 return None
             out: Dict[int, Tuple[int, int]] = {}

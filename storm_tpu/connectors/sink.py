@@ -148,7 +148,7 @@ class BrokerSink(Bolt):
         # bytes/bytearray values pass through UNTOUCHED: the raw-scheme
         # operator already produced the utf-8 payload (one json_encode
         # hop), and re-encoding here was the duplicated sink_encode copy
-        # BENCH_COPY_r18 exposed — the hop now exists only for str
+        # the copy ledger exposed — the hop now exists only for str
         # values, which genuinely need the encode.
         value = t.get("message")
         if isinstance(value, str):
@@ -375,7 +375,7 @@ class TransactionalBrokerSink(BrokerSink):
             context.component_id, "txn_aborts")
         self._m_deferred = context.metrics.counter(
             context.component_id, "txn_offsets_deferred")
-        # Fan-out safety (offsets_group only, ADVICE r3-high): a spout
+        # Fan-out safety (offsets_group only; a round-3 review, high): a spout
         # entry's outputs and offsets must commit in ONE transaction, or a
         # crash mid-tree either loses outputs (offset already committed
         # past them) or duplicates them (abort + replay re-produces

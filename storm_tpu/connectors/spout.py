@@ -190,8 +190,8 @@ class BrokerSpout(Spout):
             # round trip. The sink's tree-closure trigger commits a held
             # entry the moment it closes (no txn_ms deadline wait), which
             # keeps the cost bounded — measured ~4x at chunk=1, ~1.6x at
-            # chunk=4, FREE at chunk >= 16 (BENCH_NOTES.md "what does
-            # exactly-once cost"). The 16 gate assumes the benched shape
+            # chunk=4, FREE at chunk >= 16 (a CPU-host run of an earlier
+            # round; no ledger line). The 16 gate assumes the benched shape
             # (4 partitions, txn_batch 64); the true free point is
             # chunk >= txn_batch/partitions, which the spout cannot
             # compute (txn_batch lives on the sink) — hence a fixed,
@@ -522,7 +522,7 @@ class BrokerSpout(Spout):
         row = _profile.new_record_row(first.timestamp, polled, len(records))
         self._ledger_ingest(records)
         if self.frames:
-            # Batch ingress (ROADMAP-2 zero-copy): the whole chunk rides
+            # Batch ingress (the zero-copy path): the whole chunk rides
             # as ONE RecordFrame value — routing moves a reference, not N
             # payload objects. Replay rebuilds the frame from the same
             # pending records, so exactly-once is byte-identical on retry.

@@ -28,7 +28,7 @@ logits (shared head) clear ``early_exit_threshold`` max-softmax skip
 the remaining layers' attention+MLP — their k/v is STILL written every
 layer (from the frozen hidden) so the cache stays complete for future
 steps; those entries are shallow-representation approximations, which
-is the cascade trade documented in ARCHITECTURE.md. Greedy argmax over
+is the cascade trade documented in docs/ARCHITECTURE.md. Greedy argmax over
 the exit logits keeps the whole thing deterministic.
 """
 

@@ -68,7 +68,7 @@ class _Drained(RuntimeError):
 
 @dataclass
 class DecodeConfig:
-    """Decode tier knobs (arena sizing guidance: OPERATIONS.md)."""
+    """Decode tier knobs (arena sizing guidance: docs/OPERATIONS.md)."""
 
     arena_blocks: int = 32          # KV slots per engine replica
     max_seq: int = ct.MAX_SEQ       # arena sequence capacity

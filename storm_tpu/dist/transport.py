@@ -111,7 +111,7 @@ def decode_tuple(enc: list, now: float) -> Tuple:
     # sender's tuples) instead of erroring the whole Deliver RPC and
     # wedging every tree from it into timeout/replay.
     #
-    # VERSIONING CONTRACT (ADVICE r3-low): from this version on, receivers
+    # VERSIONING CONTRACT (a round-3 review, low): from this version on, receivers
     # ignore unknown TRAILING envelope elements (the enc[:8] + indexed-
     # optional pattern below) and unknown ack-op names are dropped, so
     # adding fields/ops stays rolling-restart safe FORWARD. The guarantee

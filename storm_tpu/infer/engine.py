@@ -1263,8 +1263,8 @@ class NullEngine:
     instantly. Plugs into ``InferenceBolt(engine=NullEngine(...))`` to
     measure the FRAMEWORK's share of the Kafka->Kafka path — broker
     queueing, spout fetch/decode, batching, executor hops, encode,
-    produce — with device time pinned to zero (the evidence behind the
-    <50 ms framework-overhead claim; bench.py --latency-breakdown).
+    produce — with device time pinned to zero (the framework's share
+    alone; not measured by the benchmark).
 
     Not a mock of the full InferenceEngine surface — just the protocol the
     operator uses: ``input_shape``, ``warmup``, ``predict``,

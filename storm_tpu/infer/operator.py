@@ -415,7 +415,7 @@ class InferenceBolt(Bolt):
             )
         if _copyledger.active():
             # Copy ledger: the parse writes a fresh float32 array — the
-            # ~57 us/record tax ROADMAP item 2 wants decomposed. Bytes
+            # per-record tax the zero-copy path takes away. Bytes
             # are the array produced; the JSON text length rides in the
             # spout rows (scheme/ingest), not here. On the tensor-view
             # fast path nothing was written (the array is a view over
@@ -437,7 +437,7 @@ class InferenceBolt(Bolt):
         Raw-scheme topologies (``_bytes_egress``) get the payload as
         utf-8 BYTES: the sink produces those bytes verbatim, so the
         legacy ``sink_encode`` re-encode hop (which duplicated every
-        payload byte, BENCH_COPY_r18) disappears from the path. String
+        payload byte) disappears from the path. String
         topologies keep the str contract (the JSON dist wire and
         multilang bolts cannot carry bytes)."""
         msg = encode_predictions(preds)

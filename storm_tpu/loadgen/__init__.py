@@ -5,7 +5,7 @@
 - :mod:`storm_tpu.loadgen.scorecard` — per-cell targets, scoring, and
   the CLI table renderer.
 - :mod:`storm_tpu.loadgen.fleet` — the scenario x pattern matrix driver
-  behind ``bench.py --fleet`` (artifact: ``SCORECARD_r<N>.json``).
+  (``run_fleet`` returns the scorecard dict; no script of the tree calls it).
 """
 
 from storm_tpu.loadgen.trace import (Trace, TraceEvent, TraceSpec,

@@ -1,7 +1,7 @@
 """Scenario-matrix fleet driver: every serving scenario x every traffic
 pattern, scored into one scorecard.
 
-``run_fleet`` (wired to ``bench.py --fleet``) runs each scenario —
+``run_fleet`` runs each scenario —
 classify (the reference lenet5 DAG), cascade (confidence-gated tiers on
 the committed digits checkpoints), serve-path (inference across the
 gRPC worker boundary), decode —
@@ -619,7 +619,7 @@ def _trace_spec(pattern: str, seed: int, hold_s: float,
 
 def run_fleet(args=None, **overrides) -> dict:
     """Run the scenario x pattern matrix; returns the scorecard dict
-    (``bench.py --fleet`` prints it to stdout -> SCORECARD_r<N>.json)."""
+    (the shape ``storm-tpu scorecard --file`` renders)."""
     hold_s = float(overrides.get("hold_s",
                                  getattr(args, "stage_seconds", 0) or 24.0))
     # Default fleet SLO: 400 ms. On a 1-core CPU host the 256-row padded

@@ -88,8 +88,8 @@ def build_null_engine_topology(cfg: Config, broker):
     No device work, no XLA compile: predictions are a uniform distribution
     computed instantly, so everything measured is framework cost — spout
     decode, routing, ledger, the inter-worker wire. This is the
-    framework-ceiling topology the wire bench (``bench.py --wire-compare``)
-    submits; registered as builder name ``"null"`` so dist workers can
+    framework-ceiling topology of the wire comparisons;
+    registered as builder name ``"null"`` so dist workers can
     rebuild it from the recipe.
     """
     from storm_tpu.connectors import BrokerSpout
@@ -616,7 +616,7 @@ def _scorecard_cmd(args) -> int:
     one row per (scenario, traffic pattern) cell with goodput, protected-
     lane p99, burn, shed fraction, the bottleneck verdict, and the
     declared-target pass/fail. Offline mode (``--file``) renders a
-    committed SCORECARD_*.json; online mode queries the /scorecard route
+    saved scorecard JSON; online mode queries the /scorecard route
     the fleet driver attaches mid-run."""
     import urllib.error
     import urllib.parse
@@ -631,7 +631,7 @@ def _scorecard_cmd(args) -> int:
     else:
         if not args.topology:
             print("scorecard: give a topology name or --file "
-                  "SCORECARD_*.json", file=sys.stderr)
+                  "<scorecard.json>", file=sys.stderr)
             return 2
         base = args.url.rstrip("/")
         topo = urllib.parse.quote(args.topology, safe="")
@@ -867,8 +867,8 @@ def _render_solve(out: dict) -> int:
 def _plan_cmd(args) -> int:
     """``storm-tpu plan``: solve for the cheapest config meeting a
     (rate, p99 SLO) target. Online against a running topology's UI
-    endpoint (live curves + corrector state), or offline from a
-    committed ``PROFILE_*.json`` via ``--baseline`` — no daemon needed."""
+    endpoint (live curves + corrector state), or offline from a saved
+    ``storm-tpu profile --json`` snapshot via ``--baseline`` — no daemon."""
     if args.baseline:
         from storm_tpu.plan import Target, solve
 
@@ -1220,8 +1220,8 @@ def main(argv=None) -> int:
         "plan",
         help="solve for the cheapest config meeting a (rate, p99 SLO) "
              "target over the profile curves: online against a running "
-             "topology's /plan route, or offline from a committed "
-             "PROFILE_*.json via --baseline (no daemon needed); prints "
+             "topology's /plan route, or offline from a saved `profile "
+             "--json` snapshot via --baseline (no daemon needed); prints "
              "the plan as ready-to-paste --set overrides")
     planp.add_argument("topology", nargs="?", default="inference-topology")
     planp.add_argument("--rate", type=float, default=0.0,
@@ -1235,7 +1235,7 @@ def main(argv=None) -> int:
                        help="max predicted device utilization a feasible "
                             "plan may run at")
     planp.add_argument("--baseline", default=None,
-                       help="solve offline over this PROFILE_*.json "
+                       help="solve offline over this profile snapshot "
                             "instead of a running topology")
     planp.add_argument("--url", default="http://127.0.0.1:8080",
                        help="base URL of the daemon's --ui-port server")
@@ -1249,12 +1249,12 @@ def main(argv=None) -> int:
         "scorecard",
         help="render the fleet scenario-matrix scorecard as a table: "
              "live from a running topology's /scorecard route (attached "
-             "mid-run by bench.py --fleet), or offline from a committed "
-             "SCORECARD_*.json via --file")
+             "mid-run by loadgen.fleet.run_fleet), or offline from a saved "
+             "scorecard JSON via --file")
     scorep.add_argument("topology", nargs="?", default=None,
                         help="topology to query (omit with --file)")
     scorep.add_argument("--file", default=None,
-                        help="render this SCORECARD_*.json instead of "
+                        help="render this scorecard JSON instead of "
                              "querying a running topology")
     scorep.add_argument("--url", default="http://127.0.0.1:8080",
                         help="base URL of the daemon's --ui-port server")

@@ -5,8 +5,8 @@ The reference's model zoo is image classifiers with tiny spatial extents
 This family makes long-context a first-class *serving* workload, not just
 a training/SP dryrun: instances are pre-embedded sequences ``(S, D_in)``
 (e.g. audio frames, patch streams, retrieval chunks), S defaults to 2048 —
-above the measured flash-attention crossover (BENCH_NOTES.md round 2:
-Pallas flash is 1.9x XLA at S=2048) — so the engine's jitted forward runs
+above the measured flash-attention crossover (an on-chip run of round 2,
+no ledger line: Pallas flash is 1.9x XLA at S=2048) — so the engine's jitted forward runs
 the Pallas kernel through the same InferenceBolt/engine path every other
 model uses. For sequences too long for one chip, the same blocks serve
 under ring-attention SP (`parallel/sequence.py`); params follow the zoo's
@@ -96,8 +96,8 @@ def longseq_encoder(num_classes: int = 10,
     ``num_heads=2`` => head_dim 128 = the TPU lane width. The flash
     kernel pads head_dim to 128 lanes, so head_dim 32 (8 heads) wasted
     3/4 of every vector op — measured on-chip: 5.43 -> 1.84 ms/step
-    (2.95x) at batch 8 just from this alignment (BENCH_DEVICE_r03.json,
-    BENCH_NOTES round 3).
+    (2.95x) at batch 8 just from this alignment (an on-chip run of round 3
+    on another machine; no ledger line).
     Param count is unchanged (attention projections are dim x dim
     regardless of head count); override via ``ModelConfig.extra`` if you
     need more heads."""

@@ -40,7 +40,7 @@ class ModelDef:
     # Compute-relevant hyperparameters that are NOT recoverable from param
     # shapes (num_heads above all: attention projections are dim x dim for
     # ANY head count, so a checkpoint trained with 8 heads loads cleanly
-    # into a 2-head model and silently computes wrong outputs — ADVICE r3
+    # into a 2-head model and silently computes wrong outputs — a round-3 review
     # medium). Saved alongside checkpoints and validated at load.
     hyper: Any = None
     # The most rows one device step may hold; None: whatever the batch
@@ -116,7 +116,7 @@ def _check_hyper(model: ModelDef, checkpoint: str) -> None:
     with the model's. Param shapes can't catch these (e.g. num_heads:
     projections are dim x dim for any head count) — a mismatch loads
     cleanly and computes differently-partitioned attention with no error
-    (ADVICE r3 medium, models/longseq.py num_heads 8 -> 2)."""
+    (a round-3 review, medium: models/longseq.py num_heads 8 -> 2)."""
     import json
     import os
 

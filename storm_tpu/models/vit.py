@@ -114,7 +114,7 @@ def build_vit_b16(num_classes: int = 1000, input_shape: tuple = (224, 224, 3)) -
 
 @register("vit_tiny")
 def build_vit_tiny(num_classes: int = 10, input_shape: tuple = (32, 32, 3)) -> ModelDef:
-    """Small ViT for tests/CI (same code path as vit_b16, toy size)."""
+    """Small ViT for the tests (same code path as vit_b16, toy size)."""
     return build_vit(
         "vit_tiny", num_classes, input_shape, patch=8, dim=64, depth=2,
         num_heads=4, mlp_dim=128,

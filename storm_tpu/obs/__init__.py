@@ -1,4 +1,4 @@
-"""Continuous profiling & SLO-burn observatory (ROADMAP item 1 substrate).
+"""Continuous profiling & SLO-burn observatory (the planner's substrate).
 
 PR 1's observability spine records what *happened* (traces, flight
 events, histograms); this package measures what it *costs* and how fast
@@ -7,7 +7,7 @@ needs before it can solve for a config:
 
 - :mod:`storm_tpu.obs.profile` — :class:`ProfileStore`, per-(engine,
   bucket) stage-cost curves + XLA compile cost per shape, fed by the
-  engine layer's profile sink; snapshot/reload as ``PROFILE_*.json``.
+  engine layer's profile sink; snapshot/reload as JSON.
 - :mod:`storm_tpu.obs.slo` — :class:`SloBurnTracker`, multi-window
   error-budget burn from the sink's delivered/slo_breaches counters;
   an additional hot signal for the LoadShedController.

@@ -54,7 +54,7 @@ DEVICE_SUBSTAGES = (("h2d_ms", "h2d"), ("compute_ms", "compute"),
 #: Time stage -> copy-ledger stages that move that stage's bytes: the
 #: critical path pairs each millisecond row with the bytes behind it
 #: ("decode is 40% of e2e AND writes 3 KB/record"), which is the shape
-#: ROADMAP item 2's before/after is scored in.
+#: the zero-copy data plane's before/after is scored in.
 STAGE_BYTES = {
     "queue_wait_ingest": ("spout_ingest", "spout_scheme"),
     "queue_wait_batch": ("json_decode", "tuple_route"),

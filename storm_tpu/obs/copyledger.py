@@ -2,7 +2,7 @@
 
 The time-side observatory (ProfileStore curves, capacity, SLO burn,
 critical path) answers "where do the milliseconds go"; this module
-answers the question ROADMAP item 2 (zero-copy host data plane) is
+answers the question the zero-copy host data plane is
 scored against: **how many times is a record's payload copied between
 broker ingress and sink egress, and how many bytes move at each hop**.
 
@@ -45,9 +45,9 @@ numerator).
 
 Wiring follows :mod:`storm_tpu.obs.profile` exactly: a process
 singleton behind a module-level sink; :func:`ensure_installed` attaches
-it (idempotent, called from operator/sink prepare, the Observatory and
-bench), :func:`set_enabled` is the kill switch for the on/off overhead
-A/B (``BENCH_COPY_r18.json``), and the hot-path entry points
+it (idempotent, called from operator/sink prepare and the
+Observatory), :func:`set_enabled` is the kill switch for an on/off
+overhead comparison, and the hot-path entry points
 (:func:`record`, :func:`active`) cost one global read when detached.
 A hook on the record path must never fail a batch: :func:`record`
 swallows everything.

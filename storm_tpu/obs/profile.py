@@ -7,18 +7,18 @@ split-phase pipeline), and every cold bucket shape fires the engine's
 per-component histograms, which average away the one axis a planner
 needs: batch size. The :class:`ProfileStore` keys the same stream by
 (engine, padded bucket), turning the runtime's own traffic into the
-per-stage latency/throughput curves ROADMAP item 1's planner consumes —
+per-stage latency/throughput curves the planner (storm_tpu/plan/) consumes —
 InferLine's offline profiler, made continuous.
 
 Wiring: the engine layer exposes ``set_profile_sink`` (a module-level
 hook, same shape as ``on_compile`` but process-wide); ``ensure_installed``
 points it at the process singleton. Recording is one lock + a couple of
 dict/histogram updates per BATCH (not per record), on the engine's fetch
-thread — the profiling-on/off interleaved A/B is committed as
-``BENCH_OBS_OVERHEAD_r11.json``.
+thread — what the step and record logs cost on the chip is PERF.md §6,
+PRs 41 and 54.
 
-The snapshot round-trips: ``bench.py --profile`` writes it as a
-versioned JSON artifact (``PROFILE_r11.json``), and a later run loads
+The snapshot round-trips: ``storm-tpu profile <topology> --json`` writes
+it as versioned JSON, and a later run loads
 that file back as the regression sentinel's baseline
 (:meth:`ProfileStore.load_baseline` + :meth:`ProfileStore.regressions`).
 """
@@ -413,9 +413,9 @@ class ProfileStore:
     def load_baseline(self, snap: dict) -> None:
         """Adopt a previously-snapshotted profile as the sentinel's
         comparison baseline. Accepts either a raw :meth:`snapshot` dict
-        or a committed ``PROFILE_*.json`` bench artifact (which wraps the
-        snapshot under its ``profile`` key — so ``obs.baseline_path`` can
-        point straight at the committed file)."""
+        or what ``storm-tpu profile <topology> --json`` printed (which wraps
+        the snapshot under its ``profile`` key — so ``obs.baseline_path`` can
+        point straight at the saved file)."""
         if isinstance(snap, dict) and isinstance(snap.get("profile"), dict) \
                 and isinstance(snap["profile"].get("engines"), dict):
             snap = snap["profile"]
@@ -423,7 +423,7 @@ class ProfileStore:
                 or not isinstance(snap.get("engines"), dict):
             raise ValueError("baseline must be a ProfileStore snapshot "
                              "(dict with an 'engines' mapping) or a "
-                             "PROFILE_*.json artifact wrapping one")
+                             "`profile --json` document wrapping one")
         with self._lock:
             self._baseline = snap
 

@@ -17,7 +17,7 @@ de-flaps — the tracker *trips* only when BOTH exceed the threshold, and
 that trip is an additional hot signal for the
 :class:`~storm_tpu.qos.shedding.LoadShedController` (the burn gauge
 rises while breaches accumulate, i.e. BEFORE the shed controller's
-hysteresis fires — see ``BENCH_SLO_BURN_r11.json``).
+hysteresis fires; seen in a CPU-host overload run of round 11).
 
 Published state: gauges ``("slo", "burn_rate")`` (fast window),
 ``("slo", "burn_rate_slow")``, ``("slo", "tripped")``; a ``slo_burn``

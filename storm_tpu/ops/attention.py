@@ -63,8 +63,8 @@ def _flash_min_seq() -> int:
     """Sequence length above which the Pallas flash kernel dispatches.
 
     Below it, XLA's own fused attention is FASTER on TPU (measured on-chip:
-    vit_b16 S=197 runs 20.3ms/step via XLA vs 29.1ms via flash,
-    BENCH_NOTES.md round 2) — the S^2 score tensor is small enough that
+    vit_b16 S=197 runs 20.3ms/step via XLA vs 29.1ms via flash, a run of
+    round 2 with no ledger line) — the S^2 score tensor is small enough that
     fusion beats tiling, so flash only pays off where it was designed to:
     long sequences whose S^2 intermediates would blow HBM traffic/VMEM
     (and the ring-attention SP path, which calls it directly)."""

@@ -8,7 +8,7 @@ PERF.md §6, PR 42); this kernel keeps the whole online-softmax accumulation
 in VMEM, so HBM traffic is just q/k/v in and o out. Dispatch is shape-aware
 (ops/attention.py): the full form serves ``multi_head_attention`` from 1,024
 tokens (below it XLA's own fused attention is faster on-chip, e.g. ViT-B/16's
-S=197; BENCH_NOTES.md round 2), the causal form serves ``causal_attention``,
+S=197; an on-chip run of round 2), the causal form serves ``causal_attention``,
 one call a row of the batch. The ring-attention sequence-parallel path
 computes its per-shard partials with its own online-softmax math
 (parallel/ring_attention.py), not this kernel.
@@ -225,7 +225,7 @@ def flash_attention(
     nothing out of them.
 
     Block defaults are the measured-fastest on v5e for the non-causal form
-    (BENCH_NOTES.md round 2 block sweep: bq=512/bk=2048 runs S=2048 in
+    (an on-chip sweep of round 2, no ledger line: bq=512/bk=2048 runs S=2048 in
     0.52 ms vs 0.91 ms with the round-1 128/512 tiles — 3.25x XLA's fused
     attention); both clamp to the padded sequence so direct short-shape
     callers (tests, sweeps) never pad q 8x just to fill a tile. The causal

@@ -8,12 +8,12 @@ against the jnp references to tight tolerances; they are the "correct
 softmax out of the serving path" obligation the reference carries in its
 engine (InferenceBolt.java:81-86), applied to the TPU fast paths.
 
-Two consumers share these functions so the suite and the artifact can never
-check different things:
+One consumer runs them compiled, so the chip has one entry:
   - tests/test_tpu_kernels.py — pytest wrappers, skipped (not passed)
-    off-TPU;
-  - tpu_kernel_parity.py (repo root) — runs on the real chip and writes
-    KERNEL_TPU_r{N}.json for the round record.
+    off-TPU: ``JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_kernels.py``.
+The interpreter's side (the same mathematics on the CPU) is tests/test_ops.py
+and the models' own test files, which call single checks with
+``interpret=True``.
 """
 
 from __future__ import annotations

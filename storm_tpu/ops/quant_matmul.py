@@ -81,7 +81,7 @@ def w8a16_matmul(
 ) -> jnp.ndarray:
     """``x (..., K) @ (q (K, N) int8 * s (N,)) -> (..., N)`` in x.dtype.
 
-    Block defaults from the round-2 on-chip sweep (BENCH_NOTES.md): the
+    Block defaults from an on-chip sweep of round 2 (no ledger line): the
     round-1 128/128/256 tiles ran the vit_b16 mlp_in shape at 1.39 ms vs
     0.60-0.67 ms with 512-wide tiles (~2.2x). Even tuned, XLA's own
     dequant+matmul fusion remains faster at the zoo's compute-bound

@@ -1,10 +1,10 @@
 """SLO-aware joint planner: offline cost-model solve + online corrector.
 
-ROADMAP item 1, InferLine's two halves (PAPERS.md) built on what the
+InferLine's two halves (PAPERS.md) built on what the
 observability PRs already measure:
 
 - :mod:`storm_tpu.plan.model` — :class:`CostModel`: loads a ProfileStore
-  snapshot (the live singleton or a committed ``PROFILE_*.json``
+  snapshot (the live singleton or a saved ``storm-tpu profile --json``
   baseline) and predicts per-stage latency, throughput, and device
   utilization for one candidate config (bucket, batching deadline,
   parallelism, ``pipeline_depth``, ``max_inflight``),
@@ -22,8 +22,8 @@ observability PRs already measure:
   (``plan_correction`` flight events); the Autoscaler defers its global
   scale-up while a corrector is attached.
 
-Surfaces: ``storm-tpu plan`` CLI, ``GET /api/v1/topology/{name}/plan``,
-``bench.py --plan`` (BENCH_PLAN artifact). Config: ``[plan]``
+Surfaces: ``storm-tpu plan`` CLI, ``GET /api/v1/topology/{name}/plan``.
+Config: ``[plan]``
 (:class:`storm_tpu.config.PlanConfig`).
 """
 

@@ -23,9 +23,9 @@ histograms with the same names:
   ties and a plan never hides a first-batch stall.
 
 Everything consumes the JSON-safe :meth:`ProfileStore.snapshot` shape,
-so the same model runs against the live singleton or a committed
-``PROFILE_*.json`` artifact (:func:`unwrap_snapshot` mirrors
-``ProfileStore.load_baseline``'s artifact handling).
+so the same model runs against the live singleton or a saved
+``storm-tpu profile --json`` document (:func:`unwrap_snapshot` mirrors
+``ProfileStore.load_baseline``'s handling of the wrapper).
 """
 
 from __future__ import annotations
@@ -42,15 +42,15 @@ PREDICTED_STAGES = ("batch_wait_ms", "h2d_ms", "compute_ms", "d2h_ms",
 
 
 def unwrap_snapshot(snap: dict) -> dict:
-    """Accept a raw ``ProfileStore.snapshot()`` dict or a committed
-    ``PROFILE_*.json`` bench artifact wrapping one under ``profile``
+    """Accept a raw ``ProfileStore.snapshot()`` dict or a saved
+    ``storm-tpu profile --json`` document wrapping one under ``profile``
     (same contract as ``ProfileStore.load_baseline``)."""
     if isinstance(snap, dict) and isinstance(snap.get("profile"), dict) \
             and isinstance(snap["profile"].get("engines"), dict):
         snap = snap["profile"]
     if not isinstance(snap, dict) or not isinstance(snap.get("engines"), dict):
         raise ValueError("need a ProfileStore snapshot (dict with an "
-                         "'engines' mapping) or a PROFILE_*.json artifact "
+                         "'engines' mapping) or a `profile --json` document "
                          "wrapping one")
     return snap
 

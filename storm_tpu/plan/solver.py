@@ -176,7 +176,8 @@ def solve(snapshot: dict, target: Target, *, engine: Optional[str] = None,
                 False, None,
                 "no trusted curves in the profile snapshot — every "
                 "(engine, bucket) cell is cold or absent; run traffic "
-                "through the engine (or bench.py --profile) first",
+                "through the engine first (`storm-tpu profile <topology> "
+                "--json` writes the snapshot that --baseline reads)",
                 None, None, coverage, 0, target.to_dict(), ranked, risks)
         engine = ranked[0]["engine"]
 

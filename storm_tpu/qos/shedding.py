@@ -87,7 +87,7 @@ class LoadShedController:
         # observatory attaches one, its fast+slow-window trip is an
         # additional HOT signal — burn integrates breaches over a window,
         # so it rises before the raw per-interval breach-rate threshold
-        # does (see BENCH_SLO_BURN_r11.json).
+        # does (seen in a CPU-host overload run of round 11; no ledger line).
         self.burn = None
         # Expose ourselves so the UI's /qos route can serve decisions.
         runtime.qos = self

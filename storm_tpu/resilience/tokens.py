@@ -2,8 +2,8 @@
 
 When a worker comes back, every tree that timed out during the outage
 replays at once; un-paced, the burst re-saturates the fresh worker and
-can knock it straight back over (the replay-storm problem ROADMAP item
-2 names). Senders route their first post-recovery window through a
+can knock it straight back over (the replay-storm problem of a
+recovering mesh). Senders route their first post-recovery window through a
 bucket: ``rate`` tokens/s with a ``burst`` ceiling, so the drain is a
 ramp instead of a wall.
 

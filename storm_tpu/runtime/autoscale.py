@@ -31,12 +31,12 @@ log = logging.getLogger("storm_tpu.autoscale")
 
 # Measured cap for bolts that front a batching accelerator: past ~2-3
 # tasks, deadline flushes fragment micro-batches and throughput inverts
-# (BENCH_NOTES round 2). Use for InferenceBolt autoscale policies;
+# (a CPU-host run of round 2; no ledger line). For InferenceBolt policies;
 # CPU-bound bolts take the Storm-style generous cap instead.
 ACCEL_MAX_PARALLELISM = 3
 
 #: Storm-style cap for CPU-bound bolts, where more executors do scale
-#: (ADVICE r3-low: a round-3 global change to 3 silently stopped
+#: (a round-3 review, low: a round-3 global change to 3 silently stopped
 #: CPU-bound topologies from scaling past 3).
 CPU_MAX_PARALLELISM = 16
 
@@ -51,7 +51,7 @@ class AutoscalePolicy:
     # None = auto by component kind: the default component IS the
     # inference operator, and scaling a batching-accelerator bolt past
     # ~2-3 tasks is a measured ~15% REGRESSION (deadline flushes fragment
-    # micro-batches, BENCH_NOTES round 2) — so the standard inference
+    # micro-batches; a CPU-host run of round 2) — so the standard inference
     # component ids resolve to ACCEL_MAX_PARALLELISM and everything else
     # to the Storm-style CPU cap. An explicit value is always honored.
     max_parallelism: Optional[int] = None

@@ -4,7 +4,7 @@ The copy ledger (round 18) proved the per-record path moves ~3.45 bytes
 for every byte ingested on the default string+json configuration: the
 spout materializes one Python str per record, routing fans out N
 objects, and the wire re-encodes each one. A :class:`RecordFrame` is the
-batch-native alternative the ROADMAP-2 zero-copy plan calls for: the
+batch-native alternative the zero-copy data plane is built on: the
 spout packs a fetched chunk's payloads into one frame object and emits
 ONE tuple whose value is the frame. Routing then moves a single
 reference (the ``batch_route`` ledger hop records ``bytes=0, copies=0,

@@ -66,8 +66,8 @@ def _check_event_name(kind: str) -> None:
 #: Split-phase pipeline substages of one device round trip, in execution
 #: order: ``(histogram/timing key, stage label)``. Single source of truth —
 #: the engine's InflightBatch.timings keys, the inference operator's
-#: substage histograms, the ``device_execute`` span sub-attrs, and
-#: bench.py's --latency-breakdown stage rows all derive from this tuple.
+#: substage histograms and the ``device_execute`` span sub-attrs
+#: all derive from this tuple.
 #: h2d = staging-buffer write + host->device transfer + async jit launch,
 #: compute = launch -> device ready, d2h = blocking device->host copy.
 DEVICE_SUBSTAGES: Tuple[Tuple[str, str], ...] = (

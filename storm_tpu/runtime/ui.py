@@ -434,8 +434,8 @@ class UIServer:
                     return 405, {"error": "use GET"}
                 sc = getattr(rt, "scorecard", None)
                 if sc is None:
-                    return 404, {"error": "no scorecard attached (run "
-                                          "bench.py --fleet)"}
+                    return 404, {"error": "no scorecard attached (set "
+                                          "runtime.scorecard)"}
                 return 200, {"topology": rt.name, **sc}
             if action == "cascade":
                 # Tiered-serving state: per-tier engine attribution (model,

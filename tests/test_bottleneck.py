@@ -6,8 +6,8 @@ and EdgeLagTracker, and the BottleneckAttributor's fused verdict — plus
 the dist merge (controller ``merge_utilization``), the batcher depth/age
 stats parity, spout ingress lag, and the autoscaler's capacity signal.
 The end-to-end claim (the attributor names an induced limiter in both an
-inference-bound and a spout-bound topology, at <= 2% overhead) lives in
-BENCH_BOTTLENECK_r12.json, not re-measured here.
+inference-bound and a spout-bound topology) was seen in a CPU-host run of
+round 12 and is not re-measured here.
 """
 
 from __future__ import annotations

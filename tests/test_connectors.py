@@ -929,7 +929,7 @@ def test_eos_fanout_sibling_failure_no_partial_commit(run):
 
 def test_txn_small_chunk_warns(caplog):
     """offsets.policy='txn' below the measured 5x throughput cliff
-    (chunk < 64, BENCH_NOTES 'what does exactly-once cost') must warn
+    (chunk < 64, a CPU-host run of an earlier round) must warn
     loudly at open — the foot-gun is silent otherwise (VERDICT r3 #8)."""
     import logging
 

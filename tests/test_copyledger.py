@@ -5,10 +5,8 @@ over a synthetic 3-hop record path, the cross-worker window merge (raw
 quantities ADD, ratios re-derive), the detached zero-overhead path, the
 ``copy_amplification_high`` flight trip/de-flap in the Observatory step,
 and the cursor/hop hygiene CapacityTracker pioneered — two rebalances
-must not leak a cursor or pin a retired engine's histograms. The live
-evidence (per-stage decomposition for the string+json vs raw+binary
-arms, ledger overhead <= 2%) is BENCH_COPY_r18.json, not re-measured
-here.
+must not leak a cursor or pin a retired engine's histograms. The ledger's
+own cost on a live path is not measured here, nor by the benchmark.
 """
 
 from __future__ import annotations

@@ -1,487 +1,161 @@
-"""Citation honesty for committed docs (round-6 satellite).
+"""Citation honesty for the documents and for the package's own words.
 
-Round 5 shipped README/PARITY rows citing ``SOAK_r05.json`` and
-``BENCH_SLO_r05.json`` — artifacts that were never committed. A cited
-artifact IS the evidence; citing a file that isn't in the tree is a
-false claim the reader can't audit. This test greps the prose docs for
-``*_rNN.json``-style artifact citations and fails on any that point at
-a file absent from the repo root, so a stale citation can never survive
-CI again.
+A cited file IS the evidence; citing a file that isn't in the tree is a
+false claim the reader can't audit. A deletion that leaves the words
+behind (a docstring that sends the reader to a script that is gone, an
+error message that tells an operator to run it) is the same claim, so the
+gate reads every document of the tree as it is and every comment,
+docstring and string literal of ``storm_tpu/``. What counts as a citation:
+
+* a path that starts with one of ``PREFIXES``: it must exist;
+* a name in the root's own convention (``README.md``,
+  ``PERF_LEDGER.jsonl``): it must exist at the root. Lower-case names
+  (``profile.json``, ``metrics.jsonl``) are an operator's own files;
+* a ``name.py``, bare or with directories before it: it must be the end of
+  the path of a file at the root, beside the citing file, or under
+  ``PY_ROOTS``;
+* the phrase ``ROADMAP item <n>`` / ``ROADMAP-<n>``: no roadmap since PR 21
+  is numbered that way, so it never holds.
+
+A trailing ``:line``, ``::test`` or dotted name is cut. A token that holds
+``*``, ``<``, ``{`` or ``$``, or that hangs from a root of its own
+(``/tmp/x.py``, ``<dir>/pkg/worker.py``), is a pattern or somebody else's
+file, not a citation. ``PERF.md``, ``ROADMAP.md`` and ``CHANGES.md`` are
+histories that rightly name files that are gone, and are not read.
 """
 
 from __future__ import annotations
 
+import functools
 import re
 from pathlib import Path
 
 import pytest
 
 REPO = Path(__file__).resolve().parent.parent
-DOCS = ("README.md", "PARITY.md", "BENCH_NOTES.md")
+DOCS = ("README.md", "PARITY.md", "docs/ARCHITECTURE.md",
+        "docs/OPERATIONS.md", "docs/MIGRATION.md", "docs/JVM_CLIENT.md",
+        "examples/README.md", ".claude/skills/verify/SKILL.md")
+PACKAGES = sorted(p.name for p in (REPO / "storm_tpu").iterdir()
+                  if p.is_dir() and p.name != "__pycache__")
+PREFIXES = ("storm_tpu", "tests", "benchmarks", "docs", "examples",
+            "checkpoints")
+PY_ROOTS = ("storm_tpu", "tests", "benchmarks", "examples")
 
-# BENCH_AUTOSCALE_CAP_r05.json, SOAK_r05.json, ACCURACY_TPU_r04.json, ...
-CITATION = re.compile(r"\b([A-Za-z][A-Za-z0-9_]*_r\d+\.json)\b")
+# A name that is no file of the tree and is cited all the same, with the
+# reason it stands.
+EXCUSED = {
+    "storm.py": "Apache Storm's multilang module, whose protocol "
+                "storm_tpu/multilang.py speaks in its place",
+    "my_bolt.py": "the user's own component in ShellBolt's usage example",
+}
+
+_NOT_INSIDE = r"(?<![\w./<>{}$*~-])"
+_PREFIXED = re.compile(
+    _NOT_INSIDE + r"(?:%s)/[\w./*<>{}$-]*" % "|".join(PREFIXES))
+_ROOT_NAME = re.compile(
+    _NOT_INSIDE + r"[A-Z][A-Za-z0-9_]*\.(?:md|jsonl|json)\b")
+_PY_NAME = re.compile(_NOT_INSIDE + r"(?:[\w-]+/)*[\w-]+\.py\b")
+_ROADMAP_ITEM = re.compile(r"ROADMAP(?:\s+item\s+|-)\d+")
+_PATTERN_CHARS = set("*<>{}$")
 
 
-def _citations(doc: str):
-    text = (REPO / doc).read_text(encoding="utf-8")
-    for lineno, line in enumerate(text.splitlines(), start=1):
-        for m in CITATION.finditer(line):
-            yield lineno, m.group(1)
+@functools.cache
+def _py_names():
+    """Every way to cite a ``*.py`` of the tree: its path from the root and
+    each shorter end of it (``infer/engine.py``, ``engine.py``)."""
+    files = [p.name for p in REPO.glob("*.py")]
+    for root in PY_ROOTS:
+        files += [p.relative_to(REPO).as_posix()
+                  for p in (REPO / root).rglob("*.py")]
+    return {f.split("/", i)[-1] for f in files for i in range(f.count("/") + 1)}
+
+
+def _prefixed_exists(token: str) -> bool:
+    """``tests/kafka_stub.KafkaStubBroker`` cites ``tests/kafka_stub.py``:
+    dotted names are cut from the end until something exists."""
+    while True:
+        if (REPO / token).exists() or (REPO / (token + ".py")).is_file():
+            return True
+        head, dot, _ = token.rpartition(".")
+        if not dot or "/" in token[len(head):]:
+            return False
+        token = head
+
+
+def _py_exists(token: str, beside: Path) -> bool:
+    return token in _py_names() or (beside / token).is_file()
+
+
+def citations(text: str, beside: Path = REPO):
+    """Yield ``(line, citation, holds)`` for every citation in ``text``."""
+    text = text.replace("/root/repo/", "")
+    seen = []
+    for m in _PREFIXED.finditer(text):
+        token = m.group().rstrip("./-")
+        if _PATTERN_CHARS & set(token) or ".." in token:
+            continue
+        seen.append((m.start(), token, _prefixed_exists(token)))
+    for m in _ROOT_NAME.finditer(text):
+        seen.append((m.start(), m.group(), (REPO / m.group()).is_file()))
+    for m in _PY_NAME.finditer(text):
+        if m.group().split("/")[0] not in PREFIXES:
+            seen.append((m.start(), m.group(), m.group() in EXCUSED
+                         or _py_exists(m.group(), beside)))
+    for m in _ROADMAP_ITEM.finditer(text):
+        seen.append((m.start(), " ".join(m.group().split()), False))
+    for at, token, holds in sorted(seen):
+        yield text.count("\n", 0, at) + 1, token, holds
+
+
+def _missing(path: Path):
+    return [f"{path.relative_to(REPO)}:{line} cites {token}"
+            for line, token, holds in
+            citations(path.read_text(encoding="utf-8"), path.parent)
+            if not holds]
+
+
+def _report(missing):
+    assert not missing, (
+        "citations of files that are not in the tree:\n  "
+        + "\n  ".join(missing)
+        + "\n(cite what exists, or say in words where the number came from)")
 
 
 @pytest.mark.parametrize("doc", DOCS)
-def test_cited_artifacts_exist(doc):
-    missing = [f"{doc}:{lineno} cites {name}"
-               for lineno, name in _citations(doc)
-               if not (REPO / name).is_file()]
-    assert not missing, (
-        "docs cite artifact files that are not committed:\n  "
-        + "\n  ".join(missing)
-        + "\n(cite only present artifacts, or state that no artifact "
-          "is committed)")
+def test_cited_paths_exist(doc):
+    _report(_missing(REPO / doc))
 
 
-def test_contbatch_artifact_gates():
-    """BENCH_CONTBATCH_r10.json is the evidence the round-10 docs cite
-    for striking the parallelism-inversion caveat — pin the two claims
-    the docs make to fields the artifact actually carries: 8 bolts >=
-    1 bolt with continuous batching on, and continuous batch_fill p50
-    strictly above the deadline baseline at the SAME paced offered
-    rate (both paced cells valid, i.e. no backlog abort)."""
-    import json
-
-    art = json.loads((REPO / "BENCH_CONTBATCH_r10.json").read_text())
-    assert art["metric"] == "parallelism_compare_lenet5"
-    assert art["continuous8_ge_continuous1"] is True
-    assert art["continuous_fill_gt_deadline"] is True
-    paced = art["batch_fill_paced"]
-    assert paced["deadline"]["offered_msg_s"] == \
-        paced["continuous"]["offered_msg_s"]
-    assert all(paced[m]["valid"] for m in ("deadline", "continuous"))
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
+@pytest.mark.parametrize("package", PACKAGES + ["top-level modules"])
+def test_code_cites_only_present_files(package):
+    """Comments, docstrings and string literals alike: an error message or
+    a ``help=`` that names a dead script fails as a docstring does."""
+    if package in PACKAGES:
+        files = [p for ext in ("*.py", "*.json")
+                 for p in (REPO / "storm_tpu" / package).rglob(ext)]
+    else:
+        files = list((REPO / "storm_tpu").glob("*.py"))
+    assert files, package
+    _report([m for path in sorted(files) for m in _missing(path)])
 
 
-def test_citation_regex_sees_the_docs():
-    """Guard the guard: if the artifact naming convention changes and the
-    regex goes blind, this fails instead of the main test silently
-    passing on zero citations."""
-    assert sum(1 for doc in DOCS for _ in _citations(doc)) >= 10
-
-
-def test_profile_artifact_gates():
-    """PROFILE_r11.json is the cost-curve baseline the regression
-    sentinel (and the ROADMAP-1 planner) loads — pin the structural
-    claims the round-11 docs make: >= 2 engines x >= 3 buckets each with
-    device-stage curves, per-shape compile entries, and the snapshot
-    verified to round-trip as its own clean baseline."""
-    import json
-
-    art = json.loads((REPO / "PROFILE_r11.json").read_text())
-    assert art["metric"] == "profile_curves"
-    engines = art["profile"]["engines"]
-    assert len(engines) >= 2
-    for key, eng in engines.items():
-        assert len(eng["buckets"]) >= 3, key
-        for bucket, row in eng["buckets"].items():
-            assert row["stages"]["device_ms"]["count"] > 0
-            assert row["ms_per_row"] and row["throughput_rows_s"]
-        assert eng["compiles"], f"{key}: no compile-cost entries"
-    assert art["round_trip_ok"] is True
-    assert art["monotone_ok"] is True
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_obs_overhead_artifact_gates():
-    """BENCH_OBS_OVERHEAD_r11.json backs the "profiling is always on"
-    default: interleaved on/off A/B within the 2% acceptance bar."""
-    import json
-
-    art = json.loads((REPO / "BENCH_OBS_OVERHEAD_r11.json").read_text())
-    assert art["metric"] == "obs_profiling_overhead_pct"
-    assert art["overhead_ok"] is True
-    assert art["value"] <= 2.0
-    assert art["profiling_on"]["samples"] and art["profiling_off"]["samples"]
-    assert art["repeats"] >= 3
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_copy_ledger_artifact_gates():
-    """BENCH_COPY_r18.json backs the round-18 copy-ledger docs: the
-    per-stage bytes/record decomposition exists for BOTH data-plane
-    arms (string+json vs raw+binary) on BOTH workloads, amplification
-    is > 1.0 everywhere (the numerator excludes ingest, so <= 1.0
-    would mean the ledger missed hops), the scheme hop appears only in
-    the string arm, and the ledger's own interleaved on/off A/B sits
-    within the 2% acceptance bar."""
-    import json
-
-    art = json.loads((REPO / "BENCH_COPY_r18.json").read_text())
-    assert art["metric"] == "copy_ledger_r18"
-    assert art["amplification_gt_1_all_arms"] is True
-    assert {r["workload"] for r in art["rows"]} >= {
-        "framework_null", "lenet5"}
-    for row in art["rows"]:
-        for arm in ("json_string", "binary_raw"):
-            tree = row[arm]
-            assert tree["copy_amplification"] > 1.0
-            stages = tree["stages"]
-            # decomposition rows present, per record, for the path core
-            for need in ("spout_ingest", "json_decode", "tuple_route",
-                         "wire_encode", "wire_decode", "json_encode",
-                         "sink_encode"):
-                assert need in stages, f"{row['workload']}/{arm}: {need}"
-                assert stages[need]["bytes_per_record"] is not None
-                assert stages[need]["copies_per_record"] is not None
-        # the bytes->str scheme hop is the string arm's cost alone
-        assert "spout_scheme" in row["json_string"]["stages"]
-        assert "spout_scheme" not in row["binary_raw"]["stages"]
-    # the real engine pays device-side hops the NullEngine never sees
-    lenet = next(r for r in art["rows"] if r["workload"] == "lenet5")
-    for need in ("staging", "h2d", "d2h"):
-        assert need in lenet["binary_raw"]["stages"]
-    ov = art["overhead"]
-    assert ov["overhead_ok"] is True
-    assert ov["value"] is not None and ov["value"] <= 2.0
-    assert ov["ledger_on"]["samples"] and ov["ledger_off"]["samples"]
-    assert ov["repeats"] >= 5
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_zerocopy_artifact_gates():
-    """BENCH_ZEROCOPY_r19.json backs the zero-copy batch-native record
-    path docs: all four acceptance gates hold (framework ceiling >= 3x
-    the interleaved legacy arm, zero-copy amplification <= 1.5 vs the
-    r18 3.451, paced framework p50 < 50 ms, shm lane demonstrably
-    engaged), the per-stage decomposition exists for both arms, the
-    zero-copy arm's view hops moved zero bytes, and the legacy arm
-    replicates the r18 headline cell (scheme hop present, amp ~3.45)."""
-    import json
-
-    art = json.loads((REPO / "BENCH_ZEROCOPY_r19.json").read_text())
-    assert art["metric"] == "zerocopy_speedup_r19"
-    for gate, ok in art["gates"].items():
-        assert ok is True, f"gate {gate} failed at capture time"
-    assert art["value"] >= 3.0
-    assert {r["workload"] for r in art["rows"]} >= {
-        "framework_null", "lenet5"}
-    fw = next(r for r in art["rows"] if r["workload"] == "framework_null")
-    legacy, zc = fw["legacy"], fw["zerocopy"]
-    # the legacy arm replicates the r18 headline plane on this host
-    assert "spout_scheme" in legacy["stages"]
-    assert legacy["copy_amplification"] > 3.0
-    # zero-copy signature: view hops moved nothing, one shm copy hop
-    assert zc["copy_amplification"] <= 1.5
-    for view_stage in ("batch_route", "json_decode"):
-        assert zc["stages"][view_stage]["bytes"] == 0
-        assert zc["stages"][view_stage]["records"] > 0
-    assert "spout_scheme" not in zc["stages"]
-    assert "sink_encode" not in zc["stages"]  # bytes passthrough egress
-    shm = zc["stages"]["shm_transport"]
-    assert shm["bytes"] > 0 and shm["copies"] > 0
-    assert all(s > 0 for s in zc["shm_batches_samples"])
-    assert all(s == 0 for s in legacy["shm_batches_samples"])
-    assert zc["msgs_per_sec_samples"] and legacy["msgs_per_sec_samples"]
-    # paced latency cells, both arms, with the gate margin
-    assert art["latency"]["zerocopy"]["p50_ms"] < 50.0
-    assert art["latency"]["legacy"]["count"] > 0
-    assert art["baseline_r18"]["artifact"] == "BENCH_COPY_r18.json"
-    assert art["repeats"] >= 2
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_slo_burn_artifact_gates():
-    """BENCH_SLO_BURN_r11.json is the early-warning evidence: the burn
-    gauge trips BEFORE the shed level moves under the same induced 2x
-    overload, the slo_burn flight event fired, and the live /profile
-    route served curves in the same session."""
-    import json
-
-    art = json.loads((REPO / "BENCH_SLO_BURN_r11.json").read_text())
-    assert art["metric"] == "slo_burn_lead_s"
-    assert art["burn_before_shed"] is True
-    assert art["burn_trip_t"] is not None
-    assert art["evidence"]["flight_slo_burn"] is True
-    assert art["evidence"]["ui_profile_route"] is True
-    assert any(w["burn_rate"] > art["burn_threshold"]
-               for w in art["timeline"])
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_bottleneck_artifact_gates():
-    """BENCH_BOTTLENECK_r12.json backs the round-12 observatory docs:
-    the attributor named the induced limiter in BOTH arms (majority of
-    live /bottleneck route samples mid-drain), the sampling layer's
-    interleaved on/off A/B sits within the 2% bar, and the dist probe
-    got controller-merged windowed utilization with each component
-    attributed to its hosting worker."""
-    import json
-
-    art = json.loads((REPO / "BENCH_BOTTLENECK_r12.json").read_text())
-    assert art["metric"] == "bottleneck_attribution_arms_correct"
-    assert art["value"] == 2
-    assert art["attribution_ok"] is True
-    by_arm = {a["arm"]: a for a in art["arms"]}
-    assert by_arm["bn-infer"]["named"] == "inference-bolt"
-    assert by_arm["bn-spout"]["named"] == "kafka-spout"
-    for a in by_arm.values():
-        assert a["correct"] is True and a["drained"] is True
-        assert a["leader_votes"][a["named"]] >= 1
-    assert art["overhead_ok"] is True
-    assert art["overhead_pct"] <= 2.0
-    assert art["obs_on"]["samples"] and art["obs_off"]["samples"]
-    dist = art["dist_utilization"]
-    assert art["dist_utilization_ok"] is True and dist["ok"] is True
-    assert dist["first_call_primed_empty"] is True
-    assert dist["merged"]["kafka-spout"]["workers"] == [0]
-    assert dist["merged"]["inference-bolt"]["workers"] == [1]
-    assert dist["merged"]["inference-bolt"]["busy_s"] > 0.0
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_plan_artifact_gates():
-    """BENCH_PLAN_r13.json backs the round-13 planner docs: the solved
-    config meets a (rate, p99 SLO) target the stock default misses, at
-    strictly lower replica cost than worst-case provisioning, with a
-    per-stage predicted-vs-measured table and a reported mean
-    prediction error from the same interleaved session."""
-    import json
-
-    art = json.loads((REPO / "BENCH_PLAN_r13.json").read_text())
-    assert art["metric"] == "plan_slo_ab_lenet5"
-    gates = art["gates"]
-    assert gates["planned_meets_slo"] is True
-    assert gates["default_misses_slo"] is True
-    assert gates["planned_cheaper_than_worstcase"] is True
-    cost = art["replica_cost"]
-    assert cost["planned"] < cost["worstcase"]
-    assert art["repeats"] >= 3
-    for arm in ("default", "planned", "worstcase"):
-        assert len(art["arms"][arm]["p99_ms_samples"]) == art["repeats"]
-    pv = art["prediction_vs_measured"]
-    assert pv["stages"], "per-stage predicted-vs-measured table missing"
-    for row in pv["stages"].values():
-        assert "predicted_ms" in row and "measured_ms" in row
-    assert pv["mean_abs_error_pct"] is not None
-    assert pv["predicted_p99_ms"] > 0 and pv["measured_p99_ms"] > 0
-    assert art["plan"]["parallelism"] >= 1
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_chaos_artifact_gates():
-    """BENCH_CHAOS_r14.json backs the round-14 resilience docs: a worker
-    SIGKILL plus a wire brownout under steady load on a 3-worker mesh,
-    with recovery to >=95% of pre-fault goodput at a measured
-    time-to-recover, a bounded replay count with token-bucket pacing
-    evidence, zero duplicate sink emits on the exactly-once path, and at
-    least one engine-hang quarantine whose replacement engine served —
-    all observable via flight events and the new transport metrics from
-    the same capture session."""
-    import json
-
-    art = json.loads((REPO / "BENCH_CHAOS_r14.json").read_text())
-    assert art["metric"] == "chaos_recovery_dist3_cpu"
-
-    # Recovery: >=95% of pre-fault goodput, with a measured clock.
-    assert art["recovered"] is True
-    assert art["recovery_ratio"] >= 0.95
-    assert art["time_to_recover_s"] > 0
-    assert art["baseline_goodput_msgs_s"] > 0
-    assert any(w["phase"] == "outage" for w in art["timeline"])
-
-    # The brownout must have been injected AND survived (goodput never
-    # hit a dead stop while latency/drop were armed).
-    brown = art["brownout"]
-    assert brown["survived"] is True
-    counts = brown["chaos_injection_counts"]
-    assert counts.get("wire_latency", 0) >= 1
-    assert counts.get("wire_drop", 0) >= 1
-
-    # Bounded replay with token-bucket evidence: the ledger replayed the
-    # dead worker's trees, within the pending-window bound, and the
-    # recovery pacer actually throttled the replay burst.
-    rep = art["replays"]
-    assert rep["tree_failed"] >= 1, "a worker died mid-stream: no replays?"
-    assert rep["bounded"] is True and rep["tree_failed"] <= rep["bound"]
-    assert art["replay_pacing"]["throttled"] >= 1
-
-    # The heartbeat monitor saw the death and recovered the worker.
-    assert art["monitor"]["heartbeat"]["dist_heartbeat_miss"] >= 2
-    kinds = {ev["kind"] for ev in art["flight"]["controller"]}
-    assert "dist_heartbeat_miss" in kinds
-    assert "dist_worker_recovered" in kinds
-    assert "chaos_injection" in kinds  # the kill itself left a breadcrumb
-
-    # Zero duplicate sink emits on the exactly-once (transactional) path.
-    eo = art["exactly_once"]
-    assert eo["exactly_once"] is True
-    assert eo["audit"]["echo_duplicated"] == 0
-    assert eo["audit"]["echo_missing"] == 0
-
-    # >=1 engine-hang quarantine, and the replacement engine served (the
-    # soak drained + audited clean AFTER the mid-run quarantine).
-    q = art["quarantine"]
-    assert q["engine_hangs_injected"] >= 1
-    assert q["watchdog"]["watchdog_trips"] >= 1
-    flight_kinds = {ev["kind"] for ev in q["watchdog"]["flight"]}
-    assert "engine_quarantined" in flight_kinds
-    assert "engine_replaced" in flight_kinds
-    assert q["replacement_served"] is True
-
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_failover_artifact_gates():
-    """BENCH_FAILOVER_r15.json backs the round-15 durable-control-plane
-    docs: a SIGKILLed controller on a 3-worker mesh whose replacement
-    reattaches to every journaled survivor in bounded time with ZERO
-    engine recompiles (same worker pids, per-worker submit counts still
-    1), the orphaned mesh serving throughout, a rolling restart whose
-    10 s goodput windows never drop below half the baseline median, and
-    the exactly-once drain drill auditing clean on the transactional
-    path — all from one capture session."""
-    import json
-
-    art = json.loads((REPO / "BENCH_FAILOVER_r15.json").read_text())
-    assert art["metric"] == "controller_failover_dist3_cpu"
-
-    # Reattach: all three survivors adopted, fast, with warm engines.
-    ra = art["reattach"]
-    assert ra["reattach_s"] <= 10.0
-    assert ra["survivors"] == [0, 1, 2] and ra["dead"] == []
-    assert ra["zero_recompile"] is True
-    assert ra["worker_pids_after"] == ra["worker_pids_before"]
-    assert all(s == 1 for s in ra["submits_per_worker"].values())
-    assert ra["replayed_records"] >= 1  # the WAL, not a rebuild, drove it
-
-    # The data plane does not route through the controller: goodput never
-    # hit zero while no controller existed.
-    assert art["controller_down"]["served_without_controller"] is True
-
-    # Rolling restart under load: every worker drained and changed pid,
-    # and every 10 s window held >= 50% of the baseline median.
-    roll = art["rolling_restart"]
-    assert len(roll["workers"]) == 3
-    assert all(r["drained"] for r in roll["workers"])
-    assert all(r["new_pid"] != r["old_pid"] for r in roll["workers"])
-    assert roll["floor_met"] is True and roll["floor_ratio"] >= 0.5
-
-    # The flight recorder saw the arc: reattach, per-worker drain+restart.
-    kinds = [ev["kind"] for ev in art["flight"]["controller"]]
-    assert "dist_reattached" in kinds
-    assert kinds.count("dist_worker_draining") >= 3
-    assert kinds.count("dist_worker_restarted") >= 3
-
-    # Exactly-once drain drill (transactional path) audited clean.
-    eo = art["exactly_once"]
-    assert eo["exactly_once"] is True
-    assert eo["audit"]["echo_duplicated"] == 0
-    assert eo["audit"]["echo_missing"] == 0
-
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_scorecard_artifact_gates():
-    """SCORECARD_r16.json backs the round-16 fleet-drill docs: a seeded
-    scenario x traffic-pattern matrix where every cell is scored on all
-    four fleet axes (goodput, protected-lane p99, SLO burn, shed
-    fraction) against declared targets, every trace is regenerable from
-    its recorded spec+seed (sha256 committed in place of the bytes), and
-    at least one flash-crowd cell shows the signature a paced bench
-    cannot — shed engaged + burn tripped with a bottleneck verdict
-    naming the limiter."""
-    import json
-
-    art = json.loads((REPO / "SCORECARD_r16.json").read_text())
-    assert art["metric"] == "fleet_scorecard_cells_passed"
-    assert isinstance(art["seed"], int)
-
-    cells = art["cells"]
-    scenarios = {c["scenario"] for c in cells}
-    patterns = {c["pattern"] for c in cells}
-    assert len(scenarios) >= 4 and len(patterns) >= 3
-
-    for c in cells:
-        # Four score axes present and gated in every cell.
-        s = c["scores"]
-        for axis in ("goodput_frac", "lane_p99_ms", "burn_peak",
-                     "shed_frac"):
-            assert axis in s, f"{c['scenario']}/{c['pattern']}: {axis}"
-        assert c["targets"] and c["gates"]
-        assert all(g["ok"] for g in c["gates"].values()), (
-            f"{c['scenario']}/{c['pattern']}: {c['gates']}")
-        assert c["ok"] is True
-        # Trace determinism contract: spec + seed + hash, not the bytes.
-        tr = c["trace"]
-        assert tr["spec"]["seed"] == c["seed"]
-        assert len(tr["sha256"]) == 64 and tr["events"] > 0
-        # The scenario_phase flight satellite fired for this cell.
-        assert c["flight"]["scenario_phase"] >= 3
-
-    assert art["all_pass"] is True
-
-    # The flash-crowd evidence a paced bench can never produce.
-    ev = art["evidence"]["flash_shed_burn_cells"]
-    assert ev, "no flash cell tripped shed+burn"
-    assert any(e["bottleneck"] for e in ev)
-    assert art["evidence"]["cursor_hygiene"]["capacity_cursor_dropped"]
-    assert art["evidence"]["scorecard_route"]["status"] == 200
-
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
-
-
-def test_decode_artifact_gates():
-    """BENCH_DECODE_r20.json backs the round-20 stateful decode docs:
-    a positive tokens/s headline with TTFT + per-token percentiles, the
-    injected-failure exactly-once audit clean (gapless, duplicate-free,
-    all requests acked), the rolling-restart probe with >= 95% of live
-    sessions KV-restored and ZERO cold starts, and the decode tier
-    visible as rows in the occupancy/profile observatories."""
-    import json
-
-    art = json.loads((REPO / "BENCH_DECODE_r20.json").read_text())
-    assert art["metric"] == "decode_tokens_per_s_r20"
-    for gate, ok in art["gates"].items():
-        assert ok is True, f"gate {gate} failed at capture time"
-    assert art["value"] > 0
-    assert art["tokens_per_s_samples"] == sorted(
-        art["tokens_per_s_samples"])
-    assert len(art["cells"]) >= 2  # interleaving protocol: repeats
-    for c in art["cells"]:
-        assert c["tokens"] > 0 and c["sessions"] > 0
-        assert 0 < c["ttft_p50_ms"] <= c["ttft_p99_ms"]
-        assert 0 < c["token_p50_ms"] <= c["token_p99_ms"]
-        assert c["audit"]["clean"] is True
-
-    au = art["exactly_once_audit"]
-    assert au["injected_failures"] >= 1 and au["request_replays"] >= 1
-    assert au["duplicates"] == 0 and au["gapped_sessions"] == 0
-    assert au["clean"] is True and au["all_acked"] is True
-
-    probe = art["migration_probe"]
-    assert probe["live_at_kill"] > 0
-    assert probe["survived_frac"] >= 0.95
-    assert probe["cold_started"] == 0
-    assert probe["kv_restored"] >= probe["live_at_kill"] * 0.95
-    assert probe["all_acked_after_restart"] is True
-    assert probe["audit_across_restart"]["clean"] is True
-
-    # decode sessions are first-class observatory rows
-    obs = art["cells"][-1]["observatory"]
-    assert obs["engine_rows"] and obs["occupancy"]
-    assert any("decode" in k for k in obs["profile_keys"])
-    assert obs["decode"]["tokens_emitted"] > 0
-
-    assert art["capture_session"].startswith("cap-")
-    assert art["code_version"]
+def test_the_helper_sees_citations():
+    """Guard the guard: if a naming convention changes and the helper goes
+    blind, this fails instead of the two above passing on nothing."""
+    text = ("see storm_tpu/config.py and storm_tpu/gone/away.py,\n"
+            "engine.py:123, tests/test_plan.py::test_y, README.md,\n"
+            "GONE_r07.json, a pattern tests/test_*.py, /tmp/drive.py,\n"
+            "an operator's profile.json and ROADMAP item 3\n")
+    assert list(citations(text)) == [
+        (1, "storm_tpu/config.py", True),
+        (1, "storm_tpu/gone/away.py", False),
+        (2, "engine.py", True),
+        (2, "tests/test_plan.py", True),
+        (2, "README.md", True),
+        (3, "GONE_r07.json", False),
+        (4, "ROADMAP item 3", False),
+    ]
+    found = [c for doc in DOCS
+             for c in citations((REPO / doc).read_text(encoding="utf-8"))]
+    assert len(found) >= 50

@@ -621,3 +621,14 @@ def test_shared_engine_concurrent_requests_build_once():
         assert all(r is results[0] for r in results)
     finally:
         eng_mod.InferenceEngine.__init__ = orig_init
+
+
+def test_null_engine_contract():
+    from storm_tpu.infer import NullEngine
+
+    eng = NullEngine((28, 28, 1), 10)
+    assert eng.input_shape == (28, 28, 1)
+    out = eng.predict(np.zeros((7, 28, 28, 1), np.float32))
+    assert out.shape == (7, 10)
+    np.testing.assert_allclose(out.sum(axis=1), 1.0, rtol=1e-5)
+    eng.warmup()  # no-op, must not raise

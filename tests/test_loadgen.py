@@ -2,8 +2,8 @@
 
 The determinism contract — same spec + seed produces a *byte-identical*
 trace file and an identical arrival schedule on any host — is what lets
-SCORECARD_r16.json record only ``{spec, seed, sha256}`` per cell instead
-of committing megabyte trace files: anyone can regenerate the exact
+a scorecard record only ``{spec, seed, sha256}`` per cell instead
+of carrying megabyte trace files: anyone can regenerate the exact
 workload and check the hash. Replay is tested entirely in virtual time
 (injectable clock/sleep), so round-trip equality costs no wall-clock.
 Also covers the cell scoring gates, the window-cursor hygiene added for

@@ -6,7 +6,7 @@ and the Observatory's regression sentinel — plus the metrics-layer
 satellites they lean on (thread-safe Histogram mutation, the windowed-
 rate helper). The end-to-end behaviour (burn trips before the shed
 level moves under real overload; the /profile route serves live curves)
-is captured in BENCH_SLO_BURN_r11.json, not re-measured here.
+was seen in a CPU-host run of round 11 and is not re-measured here.
 """
 
 from __future__ import annotations
@@ -119,8 +119,8 @@ def test_profile_snapshot_round_trips_as_baseline():
 
 
 def test_profile_baseline_accepts_bench_artifact_form():
-    # obs.baseline_path points at the committed PROFILE_*.json, whose
-    # snapshot lives under the artifact's "profile" key (the top-level
+    # obs.baseline_path points at a saved `storm-tpu profile --json`, whose
+    # snapshot lives under the document's "profile" key (the top-level
     # "engines" there is a list of names, not the curves mapping).
     store = ProfileStore()
     _feed_linear(store, "lenet5")

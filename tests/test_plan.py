@@ -25,8 +25,8 @@ from storm_tpu.runtime.autoscale import (
 )
 from storm_tpu.runtime.metrics import MetricsRegistry
 
-FIXTURE = os.path.join(os.path.dirname(__file__), os.pardir,
-                       "PROFILE_r11.json")
+FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
+                       "profile_snapshot.json")
 
 
 @pytest.fixture(scope="module")

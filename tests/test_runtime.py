@@ -532,3 +532,11 @@ def test_ledger_tolerates_ack_before_anchor():
     led.ack_edge(root, e_fast)
     assert led.outstanding(root) == 0
     assert done and done[0][1] is True  # completed exactly once
+
+
+def test_merge_offsets_max_wins():
+    from storm_tpu.runtime.tuples import merge_offsets
+
+    dst = {("t", 0): 5}
+    merge_offsets(dst, [(("t", 0), 3), (("t", 1), 7), (("t", 0), 9)])
+    assert dst == {("t", 0): 9, ("t", 1): 7}

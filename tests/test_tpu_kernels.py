@@ -5,8 +5,7 @@ the same parity functions (storm_tpu/ops/parity_checks.py) with
 ``interpret=False``, which requires Mosaic — i.e. a real TPU. Under the
 suite's CPU default they SKIP (not pass); run them on the chip with
 ``JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_kernels.py --no-header
--q -p no:cacheprovider``, or via the artifact runner ``python
-tpu_kernel_parity.py`` (repo root), which records KERNEL_TPU_r{N}.json.
+-q -p no:cacheprovider``, the one entry to the compiled checks.
 """
 
 import jax

@@ -3,8 +3,8 @@ decode, wire v2 frame slots, the shared-memory delivery lane, and batch
 egress — proven BIT-IDENTICAL against the legacy per-record path, locally
 and across a 2-worker cluster.
 
-The perf claims live in BENCH_ZEROCOPY_r19.json (gated by
-test_doc_citations); this file owns correctness: same inputs in, the
+The path's rate is not measured here, nor by the benchmark; this file owns
+correctness: same inputs in, the
 same prediction rows out, regardless of which data plane carried them.
 """
 
