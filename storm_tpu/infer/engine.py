@@ -652,7 +652,8 @@ class InferenceEngine:
             )
             self.params = place_params(qtree)
         else:
-            self.params = place_params(cast(params))
+            params = cast(params)  # the float32 tree goes before another is made
+            self.params = place_params(self._served(params))
         self.state = jax.device_put(_hostify(state), replicated(self.mesh))
         # jit must pin params to their committed placement (replicated OR
         # TP-sharded) — read the shardings off the placed arrays so both
@@ -669,7 +670,6 @@ class InferenceEngine:
             x_shard = out_shard
         dtype = self.dtype
         w8 = self._w8
-
         w8_fused = self._w8_fused
         sp = self.sp
         mesh_ref = self.mesh
@@ -745,6 +745,19 @@ class InferenceEngine:
                             else model_cfg.name)
         # numbers this engine's dispatches in the step log
         self._step_count = itertools.count()
+
+    def _served(self, params):
+        """The model's own arrangement of its float tree for the steps this
+        engine runs (``ModelDef.serve_params``: where a ViT's largest bucket
+        is a long step, the blocks stacked beside the list, so that its
+        program is one scanned block), made once, here, at load. An engine
+        that splits the tree over a tensor- or expert-parallel axis places
+        it by the loaded tree's paths (``parallel/sharding.py``) and keeps
+        that tree, as one that serves int8 leaves does; ``apply`` takes any."""
+        maker = self.model.serve_params
+        if maker is None or self.tp > 1 or self.ep > 1:
+            return params
+        return maker(params, self.pad_batch(self.batch_cfg.max_batch))
 
     # ---- occupancy telemetry (storm_tpu/obs) ---------------------------------
 

@@ -23,8 +23,7 @@ class ModelDef:
 
     ``apply(params, state, x, train=False) -> (logits, new_state)`` where
     ``state`` carries running statistics (BatchNorm) and is empty for
-    stateless models.
-    """
+    stateless models."""
 
     name: str
     input_shape: tuple  # per-instance (H, W, C)
@@ -57,6 +56,7 @@ class ModelDef:
     # the registry. Set by the builder of a model that counts (models/
     # scorer.py composes its branches' readers); None: it counts nothing.
     observe_aux: Any = None
+    serve_params: Any = None  # (params, a step's most rows) -> the tree served
 
 
 _BUILDERS: Dict[str, Callable[..., ModelDef]] = {}

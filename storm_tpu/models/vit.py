@@ -12,11 +12,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
 from storm_tpu.ops.attention import mha_init, multi_head_attention
+from storm_tpu.ops.platform import note as _note
 
 
 def _block_init(rng, dim, mlp_dim, num_heads):
@@ -31,10 +33,23 @@ def _block_init(rng, dim, mlp_dim, num_heads):
 
 
 def _block(p, x, num_heads):
+    with jax.named_scope(P.NORM):
+        mean = L.row_mean(x)
+    return _block_about(p, x, mean, num_heads)[0]
+
+
+def _block_about(p, x, mean, num_heads):
+    """One block on the stream ``x`` and its float32 row mean; returns the
+    new stream and *its* row mean, which the next block's first norm needs.
+    That mean is made here, beside the last residual add, so that it is the
+    epilogue of ``mlp_out``'s product in a loop over the blocks as it is
+    where they are unrolled: a loop cut between blocks with the stream alone
+    in its carry leaves the row sum a pass of its own over the stream, forty
+    times a step."""
     # Each part under its name in a device trace (ops/parts.py); the
     # attention names its own inside (ops/attention.py).
     with jax.named_scope(P.NORM):
-        y = L.layernorm(p["ln1"], x)
+        y = L.layernorm_about(p["ln1"], x, mean)
     y = multi_head_attention(p["attn"], y, num_heads)
     with jax.named_scope(P.NORM):
         y = x + y
@@ -48,7 +63,75 @@ def _block(p, x, num_heads):
     with jax.named_scope(P.PROJ):
         h = L.dense(p["mlp_out"], L.gelu(L.dense(p["mlp_in"], h)))
     with jax.named_scope(P.NORM):
-        return y + h
+        out = y + h
+        return out, L.row_mean(out)
+
+
+@jax.jit
+def _stack(*leaves):
+    # a leaf a call: one small program a shape, where the whole tree in one
+    # call was a program of 640 operands (18 s to compile, 2 s to load)
+    return jnp.stack(leaves)
+
+
+def stack_blocks(params):
+    """The tree an engine whose largest step is long serves
+    (``ModelDef.serve_params``): what ``init`` and the checkpoints hold, and
+    beside the list of blocks their leaves stacked on a leading axis,
+    ``[depth, ...]``, under ``"stacked"``. A program scans the stacked leaves
+    with one block's code (``_scan_blocks``) or walks the list as ever
+    (``_SCAN_MIN_TOKENS`` says which). The blocks' parameters are so held
+    twice, 2.02 GB more at ViT-g/14's sizes in bfloat16, for programs a
+    twentieth the size: forty unrolled blocks were 32-40 MB a bucket and
+    2.6-3.5 s of every warm start to load (PERF.md §6, PR 62). Made once, at
+    load, outside any step."""
+    return {**params, "stacked": jax.tree.map(_stack, *params["blocks"])}
+
+
+# Which steps run the blocks as a loop over the stacked leaves, in tokens a
+# step (rows x tokens). A loop reads block i's weights at an offset only the
+# running program knows, and XLA cannot bring those in ahead of time as it
+# does an unrolled program's: the slices are copies at the block's start that
+# nothing hides, 50.7 MB and 73 us a block at ViT-g/14's sizes on a v5e
+# whatever the rows, 2.6-4.1 ms of a forty-block step. The engine's own
+# programs there, list -> loop, ms a step (PERF.md §6, PR 62):
+#     rows     8      16      32      64     128     256
+#     list   28.07   55.27  110.91  221.07  453.54  924.97
+#     loop   31.56   58.14  113.48  225.20  474.08  925.64
+#            +12.4 %  +5.2 %  +2.3 %  +1.9 %  +4.5 %  +0.07 %
+# (at 128 rows the stream, 92.6 MB, just fits the chip's fast memory and is
+# moved out of it and back in every block). So the loop is free only in the
+# longest of these, and two sizes decide. An engine stacks the leaves, and
+# holds them twice, only if its *largest* step is one the loop costs
+# nothing: that is the step a backlog fills, every step of a saturated
+# engine. Its shorter steps are what a burst or a backlog's tail passes
+# through, and they take the loop (and load in 0.15 s instead of 3.4) from
+# the size at which the copies are under a fortieth of a step; below that,
+# where a paced stream lives (a median answer 6 ms later at 8 rows), the
+# list. The 128-row step pays its 4.5 % there: known, and left so rather
+# than cut a hole for one chip's fast memory into the rule. Nothing between
+# 128 and 256 rows was read: the first size stands at the smallest reading
+# that supports it.
+_STACK_MIN_TOKENS = 65536
+_SCAN_MIN_TOKENS = 8192
+
+
+def _scan_blocks(stacked, tok, num_heads):
+    """The stacked blocks as one ``lax.scan`` whose body is a block's code;
+    the carry is the stream and its row mean (``_block_about`` says why the
+    mean). The barrier keeps a block's slices operations of their own: XLA
+    then copies them out at the block's start and brings them into fast
+    memory ahead of the products that read them, as it does the unrolled
+    program's weights; fused into the products (what it does unasked) each
+    product reads its slice from HBM at its own pace, 11.6 % of a 256-row
+    step (PERF.md §6, PR 62)."""
+    with jax.named_scope(P.NORM):
+        mean = L.row_mean(tok)
+    (tok, _), _ = lax.scan(
+        lambda carry, p_blk: (_block_about(
+            lax.optimization_barrier(p_blk), *carry, num_heads), None),
+        (tok, mean), stacked)
+    return tok
 
 
 def build_vit(
@@ -91,13 +174,26 @@ def build_vit(
                                    (b, 1, dim))
             tok = (jnp.concatenate([cls, tok], axis=1)
                    + params["pos"].astype(tok.dtype))
-        for p_blk in params["blocks"]:
-            tok = _block(p_blk, tok, num_heads)
+        stacked = params.get("stacked")  # beside the list: stack_blocks
+        if stacked is not None and b * seq >= _SCAN_MIN_TOKENS:
+            _note("blocks", "scan")
+            tok = _scan_blocks(stacked, tok, num_heads)
+        else:
+            _note("blocks", "unrolled")
+            for p_blk in params["blocks"]:
+                tok = _block(p_blk, tok, num_heads)
         with jax.named_scope(P.HEAD):
             tok = L.layernorm(params["ln"], tok)
             return L.dense(params["head"], tok[:, 0]), state
 
+    def serve_params(params, rows):
+        # rows: the engine's largest step; under the size, the loaded tree
+        if rows * seq < _STACK_MIN_TOKENS:
+            return params
+        return stack_blocks(params)
+
     return ModelDef(name, input_shape, num_classes, init, apply, flagship=True,
+                    serve_params=serve_params,
                     hyper={"num_heads": num_heads, "dim": dim, "depth": depth,
                            "mlp_dim": mlp_dim, "patch": patch,
                            "input_shape": input_shape,

@@ -261,3 +261,22 @@ def feed_forward(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     """The feed-forward its parameters describe: SwiGLU where they have a
     ``gate``, squared ReLU over ``up`` and ``down`` where they do not."""
     return swiglu(p, x) if "gate" in p else relu2(p, x)
+
+
+def row_mean(x: jnp.ndarray) -> jnp.ndarray:
+    """The float32 mean over the last axis, kept as an axis of one: the
+    statistic :func:`layernorm_about` is handed."""
+    return jnp.mean(x.astype(jnp.float32), axis=-1, keepdims=True)
+
+
+def layernorm_about(p: dict, x: jnp.ndarray, mean: jnp.ndarray,
+                    eps: float = 1e-6) -> jnp.ndarray:
+    """:func:`layernorm` with ``row_mean(x)`` handed in, for a caller that
+    made it where ``x`` was made (models/vit.py carries it from block to
+    block): the same arithmetic in the same order, ``jnp.var`` being the
+    mean of the squares about that mean. Below everything else of this file:
+    the compile cache's key covers an operation's source line."""
+    centred = x.astype(jnp.float32) - mean
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    return (centred * lax.rsqrt(var + eps) * p["scale"]
+            + p["bias"]).astype(x.dtype)

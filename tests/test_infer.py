@@ -418,8 +418,11 @@ def test_engine_inventory_names_each_programs_kernels():
     row = next(r for r in engine_inventory()["engines"]
                if r["model"] == "vit_tiny")
     dp = eng.mesh.shape[eng.data_axis]
-    assert row["programs"] == {str(eng.pad_batch(b)): "attention=xla"
-                               for b in (2, 8)}, (row, dp)
+    # steps this short walk the list of blocks (models/vit.py
+    # _SCAN_MIN_TOKENS); a long step's program would say blocks=scan
+    assert row["programs"] == {
+        str(eng.pad_batch(b)): "blocks=unrolled, attention=xla"
+        for b in (2, 8)}, (row, dp)
     lenet = shared_engine(
         ModelConfig(name="lenet5", input_shape=(28, 28, 1), dtype="float32"),
         ShardingConfig(data_parallel=0), BatchConfig(max_batch=4, buckets=(4,)))

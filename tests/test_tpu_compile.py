@@ -293,6 +293,83 @@ def test_vit_g14_block_compiles_with_the_row_kernel(v5e, monkeypatch):
     assert found and max(set(found), key=found.count) == "256"
 
 
+def test_vit_g14_compiles_as_one_loop_over_its_stacked_blocks(v5e, monkeypatch):
+    """ViT-g/14 whole, forty blocks, from the tree an engine serves (the list
+    of blocks and their leaves stacked beside it). At a bucket of 32 rows
+    (8,224 tokens: a long step) the program is one ``while`` that carries
+    the stream, its row mean and the stacked leaves; no stacked leaf is
+    copied; a block's slices are operations of their own, not read inside
+    the products; the body's first norm takes the carried row mean, so no
+    pass over the carried stream alone is left to make a row sum (the next
+    norm's is a second result of ``mlp_out``'s product, as where the blocks
+    are unrolled); the text is a twentieth of the unrolled program's 3.6
+    million characters, which is what a warm start loads; and the
+    benchmark's roofline reader still finds the batch in the operations'
+    shapes. At the paced cell's bucket of 8 (2,056 tokens: a short step) the
+    same tree gives the unrolled program, which reads the list."""
+    import importlib.util
+    import json
+    import re
+
+    import storm_tpu.ops.attention as attention
+    from storm_tpu.models.vit import build_vit, stack_blocks
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sizes = json.load(open(os.path.join(
+        root, "benchmarks", "configs", "vit_g14.json")))["published"]
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "_one_device", lambda: True)
+    model = build_vit(
+        "probe", sizes["num_labels"], (sizes["image_size"],) * 2 + (3,),
+        patch=sizes["patch_size"], dim=sizes["hidden_size"],
+        depth=sizes["num_hidden_layers"],
+        num_heads=sizes["num_attention_heads"],
+        mlp_dim=sizes["intermediate_size"])
+    shapes, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+                          jax.eval_shape(stack_blocks, shapes))
+    forward = jax.jit(lambda p, x: model.apply(p, state, x, train=False)[0])
+    short = forward.lower(
+        params, _spec((8, 224, 224, 3), jnp.bfloat16, v5e)).as_text()
+    assert "stablehlo.while" not in short
+    text = forward.lower(
+        params, _spec((32, 224, 224, 3), jnp.bfloat16, v5e)
+    ).compile().as_text()
+    assert len(text) < 400_000
+    (loop,) = [line for line in text.splitlines() if " while(" in line]
+    assert "bf16[40,1408,6144]" in loop and "bf16[32,257,1408]" in loop
+    assert "f32[32,257,1]" in loop  # the row mean rides beside the stream
+    assert "tpu_custom_call" in text  # the row kernel, inside the body
+    assert not re.search(r"= \w+\[40,[\d,]+\]\S* copy\(", text)
+    body = re.search(r"body=(%[\w.\-]+)", loop).group(1)
+    lines = text[text.index("\n" + body + " "):].split("\n}\n")[0].splitlines()
+    # a product (an output fusion) never takes a stacked leaf: its block's
+    # slice is cut before it, and then brought into fast memory ahead of it
+    products = [line for line in lines if "kind=kOutput" in line]
+    assert len(products) >= 5
+    carried = {m.group(1) for line in lines for m in [re.match(
+        r"\s*(%\S+) = bf16\[40,(?:1408,1408|1408,6144|6144,1408)\]\S* "
+        r"get-tuple-element\(", line)] if m}
+    assert len(carried) == 6
+    for line in products:
+        operands = line.split(" fusion(")[1].split(")")[0].split(", ")
+        assert not carried & set(operands), line
+    stream = {m.group(1) for line in lines for m in [re.match(
+        r"\s*(%\S+) = bf16\[32,257,1408\]\S* get-tuple-element\(%\S*arg_tuple",
+        line)] if m}
+    assert stream
+    for line in lines:
+        m = re.match(r"\s*%\S+ = f32\[32,257\]\S* fusion\(([^)]*)\), kind=kLoop",
+                     line)
+        if m:  # a pass that makes a row statistic: never of the stream alone
+            assert set(m.group(1).split(", ")) - stream, line
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks_ops_vit", os.path.join(root, "benchmarks", "ops", "vit.py"))
+    ops = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ops)
+    assert ops.rows_per_step(text.splitlines(), sizes) == 32
+
+
 @pytest.mark.parametrize("chips", [4, 1])
 def test_train_step_holds_no_row_kernel(topo, chips, monkeypatch):
     """``parallel/train.py``'s step for one ViT-B/16 block at batch 32 (8.7
