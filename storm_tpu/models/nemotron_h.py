@@ -40,6 +40,7 @@ released code starts them), a float32 stream, the chunk and the tile.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -110,11 +111,15 @@ def gqa_mixer_init(rng, dim: int, heads: int, kv_heads: int,
 
 
 def gqa_mixer(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
-              head_dim: int) -> jnp.ndarray:
+              head_dim: int, scale: Optional[float] = None) -> jnp.ndarray:
     """Causal attention, ``heads`` query heads over ``kv_heads`` key/value
     heads, no bias, no position embedding; where the parameters hold a
     ``gate``, the result times ``sigmoid(W_gate x)``, a number a channel,
-    before the output projection."""
+    before the output projection. ``scale``: what the scores are multiplied
+    by before the softmax (None: ``head_dim ** -0.5``; a model that publishes
+    an ``attention_multiplier`` hands it over)."""
+    if scale is None:
+        scale = head_dim ** -0.5
     b, s, _ = x.shape
 
     def split(name, n):
@@ -122,7 +127,7 @@ def gqa_mixer(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
             0, 2, 1, 3)
 
     out = causal_attention(split("q", heads), split("k", kv_heads),
-                           split("v", kv_heads), scale=head_dim ** -0.5)
+                           split("v", kv_heads), scale=scale)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
     if "gate" in p:
         out = out * jax.nn.sigmoid(_proj(x, p["gate"]))

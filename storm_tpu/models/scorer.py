@@ -6,7 +6,8 @@ ids -> embedding (times ``scale_emb``) -> a float32 stream ``h`` -> for each
 block, for each of its branches, ``h += residual * branch(RMSNorm(h))`` (a
 branch that names a ``post`` norm: ``h += residual * RMSNorm_post(branch(
 RMSNorm(h)))``, the sandwich) -> the last position's RMS norm (times
-``logit_scale``) -> the head; and what the branches counted on the way,
+``logit_scale``) -> the head (``tied``: the embedding transposed, no leaf of
+its own); and what the branches counted on the way,
 stacked a layer into ``new_state["aux"]``, which the engine fetches with the
 predictions (``infer/engine.py``).
 
@@ -14,8 +15,9 @@ A model's file keeps what is its own: its mixers, its *plan* (a tuple of
 blocks, each a tuple of :class:`Branch`), its scalars, what it makes once a
 step (``context``: rotary tables) and its presets, and hands them to
 :func:`token_scorer`. The parameter tree is ``{"embed", "layers": [{<norm>,
-<name>, ...}, ...], "norm", "head"}``; ``split(rng, branches + 2)`` gives the
-embedding key 0, the head key 1 and every branch of the plan the next.
+<name>, ...}, ...], "norm", "head"}`` (no ``"head"`` where the model is
+``tied``); ``split(rng, branches + 2)`` gives the embedding key 0, the head
+key 1 (unused where tied) and every branch of the plan the next.
 """
 
 from __future__ import annotations
@@ -112,7 +114,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                  max_rows: int, scale_emb: float = 1.0,
                  residual: float = 1.0, logit_scale: float = 1.0,
                  context: Optional[Callable] = None,
-                 param_dtype=None, heads: int = 1) -> ModelDef:
+                 param_dtype=None, heads: int = 1, tied: bool = False,
+                 embed_std: Optional[float] = None) -> ModelDef:
     """The model of ``blocks`` over ``num_classes`` rows of the vocabulary.
     With ``heads`` prediction heads ``num_classes`` is what an answer holds,
     ``heads`` distributions over ``num_classes / heads`` rows of the
@@ -126,8 +129,19 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
     drawn in float32, scaled and cast in one pass, so that no float32 leaf is
     ever written to memory and a layer's temporaries are gone before the
     next layer's are made (a float32 twin of 3.5 B parameters does not fit
-    beside them). The values are those ``astype`` of the float32 draw gives."""
+    beside them). The values are those ``astype`` of the float32 draw gives.
+    ``tied`` (``tie_word_embeddings``): the parameter tree has no ``"head"``
+    and the logits are ``last @ embed^T``, a product with the embedding's
+    own rows under the part ``head``. One matrix can start at one scale,
+    and it decides both ends at once: ``embed_std``, a value's deviation
+    (None: 1 over ``scale_emb``, the stream at N(0, 1) a channel as every
+    untied scorer's). The last position's logit of its own id is ``scale_emb
+    logit_scale dim embed_std^2`` over the final stream's root mean square,
+    whatever the layers computed, so a tied model's builder chooses
+    ``embed_std`` to keep that term among the others."""
     (seq,) = input_shape
+    if tied and heads != 1:
+        raise ValueError("a tied embedding serves one head")
     vocab, rest = divmod(num_classes, heads)
     if rest:
         raise ValueError(f"{num_classes} classes are not {heads} heads over "
@@ -156,13 +170,18 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
         # A multiplier stands against weights trained under it; a draw that
         # stands for such a checkpoint starts the stream and the logits where
         # every other model's start (N(0, 1) a channel, LeCun's head): the
-        # embedding over ``scale_emb``, the head over ``logit_scale``.
+        # embedding over ``scale_emb`` (a tied matrix: at ``embed_std``, which
+        # its builder chooses for both ends), the head over ``logit_scale``.
         embed = jax.random.normal(ke, (vocab, dim), f32)
-        head = _w(kh, dim, num_classes)
-        return served({
-            "embed": embed if scale_emb == 1 else embed / scale_emb,
-            "norm": L.rmsnorm_init(dim),
-            "head": head if logit_scale == 1 else head / logit_scale})
+        if embed_std is not None:
+            embed = embed * embed_std
+        elif scale_emb != 1:
+            embed = embed / scale_emb
+        ends = {"embed": embed, "norm": L.rmsnorm_init(dim)}
+        if not tied:
+            head = _w(kh, dim, num_classes)
+            ends["head"] = head if logit_scale == 1 else head / logit_scale
+        return served(ends)
 
     def init(rng):
         ks = jax.random.split(rng, sum(map(len, blocks)) + 2)
@@ -188,7 +207,7 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
             # ids ride the float32 instance contract (exact under 2^24)
             ids = jnp.clip(jnp.round(x.astype(f32)), 0,
                            vocab - 1).astype(jnp.int32)
-            dtype = params["head"].dtype
+            dtype = params["embed"].dtype
             # The stream is float32 whatever the compute type: a bfloat16
             # stream is rounded at each of its adds, and a router reading it
             # sends three times as many tokens to another expert than the
@@ -221,7 +240,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
             last = L.rmsnorm(params["norm"], h[:, -1], eps)
             if logit_scale != 1:
                 last = last * logit_scale
-            logits = L.matmul(last.astype(dtype), params["head"])
+            logits = L.matmul(last.astype(dtype), params["embed"].T
+                              if tied else params["head"])
             if heads != 1:
                 logits = logits.reshape(-1, heads, vocab)
         if not counts:

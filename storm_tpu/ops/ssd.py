@@ -18,10 +18,14 @@ start (so every exponent below is at most zero):
           + exp(L_t) S_prev C_t                                    from before
     S_new = exp(L_Q) S_prev + sum_s exp(L_Q - L_s) dt_s x_s (x) B_s
 
-``C B^T`` is formed once a group, not once a head; the decays' table
-``exp(L_t - L_s)`` is a head's, always the exponent of the difference (its
-factors ``exp(L_t)`` and ``exp(-L_s)`` overflow where a head forgets within
-a chunk). The products take their operands in the type of ``x`` (bfloat16
+``C B^T`` is formed once a group, not once a head (with one group, as
+Granite 4.0-H publishes it, once for all 128 heads: ``G = 1``, ``R = H``);
+the decays' table ``exp(L_t - L_s)`` is a head's, always the exponent of the
+difference (its factors ``exp(L_t)`` and ``exp(-L_s)`` overflow where a head
+forgets within a chunk), ``B x H x chunk x chunk`` float32 a step of the
+loop: 67 MB for 8 rows of 128 heads at a chunk of 128, 268 MB at 256, where a
+token's row of it is twice as long; the chunk is the caller's choice and no
+part of the mathematics. The products take their operands in the type of ``x`` (bfloat16
 when serving) and accumulate in float32; the state stays float32 from chunk
 to chunk, the decays are float32 throughout.
 

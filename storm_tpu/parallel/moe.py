@@ -409,9 +409,14 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
     most, whose expected run ``block * top_k * held_share`` fits one tile
     (``held_share``: the part of the assignments that is held, by shapes):
     256 where an eighth or a quarter of a router is held (the regime the two
-    constants were read in), 64 where all of a top-8 router is. A tile's 0/1
-    matrix and the sums it touches are a block tall, so a block cut into
-    four tiles multiplies and rewrites four times what its rows need.
+    constants were read in), 64 where all of a top-8 router is, 96 where half
+    of a ten-a-token router is (480 expected of a tile's 512: no smaller
+    size clears the run's spread, so the loop has one, and a block that draws
+    more than 512 takes a second tile, which adds). A tile's 0/1 matrix and
+    the sums it touches are a block tall, so a block cut into four tiles
+    multiplies and rewrites four times what its rows need. Where the tokens
+    are no whole number of blocks (32,768 are 341.33 blocks of 96) the sums
+    are made for whole blocks and the result is their first ``n`` rows.
 
     A block's run never passes ``block * top_k``, so where that is the
     tile's size every block is one tile at most, whatever the routing, and

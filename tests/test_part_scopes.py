@@ -35,6 +35,9 @@ EXPECTED = {
     # masked second pass; the dense form's ``mix.attention`` is not run
     "keye_tiny": (COMMON - {parts.MIX_ATTENTION}) | MOE | {
         parts.MIX_INDEX_SELECT, parts.MIX_SPARSE_ATTENTION, parts.MIX_ROPE},
+    # Mamba-2 blocks and one attention block, an expert layer in each; the
+    # tied product lies under ``head``: no part of its own
+    "granite_h_tiny": COMMON | MOE | {parts.MIX_SSD_SCAN},
 }
 
 
