@@ -408,12 +408,16 @@ def check_ssd_scan(interpret: bool = False) -> List[dict]:
     (``ssd_chunked_columns``). ``lightning``: 4 windows of 16,384, 32 heads
     of 128 and a group a head, a step of 1, Lightning Attention's decays, no
     skip, q scaled by ``128 ** -0.5`` (``ssd_chunked``, as
-    ``models/minicpm_sala.py`` calls it). The measure is the root mean
-    square of the difference over the reference's: the chunk's products take
-    bfloat16 operands (the table ``m``, the state, ``x`` times its weight)
+    ``models/minicpm_sala.py`` calls it). ``granite``: Nemotron's draw at 8
+    windows of 4,096 with 128 heads of 64 on one group of 128, whose 32 MiB
+    of state is more than the compiler keeps in fast memory: ``ssd_kernel``
+    on a TPU in a process with one device. The measure is the root
+    mean square of the difference over the reference's: the chunk's products
+    take bfloat16 operands (the table ``m``, the state, ``x`` times its weight)
     and the result is rounded once. A chunk or a head out of place reads 1.
     (Under the interpreter, this runner's smoke test: 150 positions, ragged,
-    in float32.)"""
+    in float32; ``granite`` the kernel itself, interpreted, two chunks of
+    128 and the 128 heads in the two steps of 64 that ship.)"""
     import jax
     import jax.numpy as jnp
 
@@ -421,25 +425,34 @@ def check_ssd_scan(interpret: bool = False) -> List[dict]:
 
     f32 = jnp.float32
     cases = ([("nemotron", (2, 150), 4, 8, 2, 16, f32, 1e-4),
-              ("lightning", (2, 150), 4, 16, 4, 16, f32, 1e-4)]
+              ("lightning", (2, 150), 4, 16, 4, 16, f32, 1e-4),
+              ("granite", (2, 256), 128, 64, 1, 128, f32, 1e-4)]
              if interpret else
              [("nemotron", (8, 4096), 64, 64, 8, 128, jnp.bfloat16, 1e-2),
               ("lightning", (4, 16384), 32, 128, 32, 128, jnp.bfloat16,
-               1e-2)])
+               1e-2),
+              ("granite", (8, 4096), 128, 64, 1, 128, jnp.bfloat16, 1e-2)])
     rows = []
     for case, shape, h, p, g, n, dtype, tol in cases:
         ks = jax.random.split(jax.random.PRNGKey(0), 6)
         x = jax.random.normal(ks[0], shape + (h, p)).astype(dtype)
         b = jax.random.normal(ks[3], shape + (g, n)).astype(dtype)
         c = jax.random.normal(ks[4], shape + (g, n)).astype(dtype)
-        if case == "nemotron":
+        if case != "lightning":
             dt = 0.05 * jax.nn.softplus(jax.random.normal(ks[1], shape + (h,)))
             a = -jax.random.uniform(ks[2], (h,), minval=1.0, maxval=16.0)
             d = jax.random.normal(ks[5], (h,))
             xbc = jnp.concatenate([y.reshape(shape + (-1,))
                                    for y in (x, b, c)], -1)
-            got = jax.jit(lambda xbc, dt, a, d: ssd.ssd_chunked_columns(
-                xbc, dt, a, d, g, n))(xbc, dt, a, d).reshape(x.shape)
+            if case == "granite" and interpret:
+                runs = jnp.cumsum((dt * a).reshape(
+                    shape[0], -1, 128, h), 2).reshape(dt.shape)
+                got = ssd.ssd_kernel(
+                    xbc, (), dt, runs, d, n=n, chunk=128,
+                    interpret=True).reshape(x.shape)
+            else:
+                got = jax.jit(lambda xbc, dt, a, d: ssd.ssd_chunked_columns(
+                    xbc, dt, a, d, g, n))(xbc, dt, a, d).reshape(x.shape)
         else:
             dt = jnp.ones(shape + (h,), f32)
             a = -(2.0 ** (-8.0 * (jnp.arange(h) + 1) / h))

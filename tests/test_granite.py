@@ -56,6 +56,22 @@ def _close(got, want, rel=1e-5):
 
 # ---- the scan on one group -----------------------------------------------------
 
+def test_the_cells_step_holds_twice_the_state_fast_memory_keeps():
+    """The published mixer (128 heads of 64 on a state of 128) at the
+    cell's 8 rows a step holds 32 MiB of float32 state, twice what the
+    compiler keeps in fast memory: on one chip the scan is the kernel, here
+    the loop over chunks; 4 rows (the smaller bucket ``max_rows`` would
+    leave) are kept, and so is every toy."""
+    wide = spec.config("granite_4_h_small")
+    sizes = wide["published"]
+    held = sizes["held"]
+    shape = (sizes["mamba_n_heads"], sizes["mamba_d_head"],
+             sizes["mamba_d_state"])
+    assert shape == (128, 64, 128) and held["rows_per_step"] == 8
+    assert 4 * 8 * 128 * 64 * 128 == 2 * ssd._STATE_KEPT_BYTES
+    assert ssd.scan_form(8, 4096, 128, 64, 1, 128, 128) == "chunked"
+
+
 @pytest.mark.parametrize("step", [1e-4, 0.05, 10.0],
                          ids=["decay-near-1", "a-few-tokens", "decay-near-0"])
 @pytest.mark.parametrize("chunk", [8, 16, 40])
@@ -72,10 +88,86 @@ def test_scan_at_one_group_is_the_recurrence(chunk, step):
     b, c = (jax.random.normal(k, shape + (1, 8)) for k in ks[3:5])
     d = jax.random.normal(ks[5], (6,))
     held = jnp.concatenate([y.reshape(shape + (-1,)) for y in (x, b, c)], -1)
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision("highest"), dispatch_notes() as seen:
         got = ssd.ssd_chunked_columns(held, dt, a, d, 1, 8, chunk=chunk)
         want = ssd_recurrence(x, dt, a, b, c, d)
+    assert seen == ["ssd_scan=chunked"]
     _close(got.reshape(x.shape), want)
+
+
+def test_the_scan_past_fast_memory_is_a_kernel_on_one_chip_only(monkeypatch):
+    """``scan_form`` reads the shapes and what the process runs on: the
+    cell's step is the kernel on a TPU in a process with one device and the
+    loop over chunks here; a state that is kept is the loop everywhere; a
+    state past it that is not the kernel's (several groups, a sequence of
+    no whole chunks, a chunk of no whole lane tiles, heads short of a lane
+    tile's worth, heads that are no whole steps of the kernel's, a step of
+    less than a lane tile) keeps the loop."""
+    step = dict(rows=8, seq=4096, heads=128, head_dim=64, groups=1,
+                state=128, chunk=128)
+    assert ssd.scan_form(**step) == "chunked"
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ssd, "_one_device", lambda: True)
+    assert ssd.scan_form(**step) == "kernel-rows1-heads64"
+    # a longer chunk takes fewer heads a step: 64 at 256 ran out of VMEM
+    assert ssd.scan_form(**{**step, "chunk": 256}) == "kernel-rows1-heads32"
+    assert ssd.kernel_heads(128, 512) == 16 and ssd.kernel_heads(32, 128) == 32
+    assert ssd.scan_form(**{**step, "rows": 4}) == "chunked"
+    assert ssd.scan_form(**{**step, "rows": 16}) == "kernel-rows1-heads64"
+    for other in ({"groups": 8}, {"seq": 4000}, {"chunk": 64},
+                  {"heads": 192}, {"state": 384},
+                  # 21 heads a step: 1,344 lanes, and 126 of the 128 heads
+                  {"chunk": 384, "seq": 3072},
+                  # one head a step: half a lane tile, no tile written
+                  {"chunk": 8192, "seq": 8192}):
+        assert ssd.scan_form(**{**step, **other}) == "chunked", other
+    # Nemotron's and MiniCPM-SALA's steps: kept, whatever runs them
+    assert ssd.scan_form(8, 4096, 64, 64, 8, 128, 128) == "chunked"
+    assert ssd.scan_form(4, 16384, 32, 128, 32, 128, 128) == "chunked"
+    monkeypatch.setattr(ssd, "_one_device", lambda: False)
+    assert ssd.scan_form(**step) == "chunked"
+
+
+@pytest.mark.parametrize("dtype,bound", [(jnp.float32, 2e-5),
+                                         (jnp.bfloat16, 2.0 ** -7)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("columns", [True, False],
+                         ids=["columns", "separate"])
+def test_the_scans_kernel_is_the_recurrence(columns, dtype, bound):
+    """``ssd_kernel`` interpreted here at the published mixer (128 heads of
+    64, a state of 128, one group) in the two steps of 64 heads that ship
+    (the second finds its heads' columns by a rotation of the lanes), two
+    rows of two chunks of 128: the state is carried from a row's first chunk
+    to its second in scratch and starts at zero in the second row; ``x | B |
+    C`` side by side or as arrays of their own. Against the recurrence token by
+    token in float32, and within a rounding of the loop over chunks: the
+    same sums, the step ``dt`` inside the table's exponent and the state's
+    weight on ``B`` where the loop puts it on ``x``."""
+    b, s, h, p, n, q = 2, 256, 128, 64, 128, 128
+    assert ssd.kernel_heads(h, q) == 64
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.nn.silu(jax.random.normal(ks[0], (b, s, h, p))).astype(dtype)
+    bb, cc = (jax.random.normal(k, (b, s, 1, n)).astype(dtype)
+              for k in ks[1:3])
+    dt = jax.nn.softplus(jax.random.normal(ks[3], (b, s, h)) - 2.0)
+    a = -jax.random.uniform(ks[4], (h,), minval=1.0, maxval=16.0)
+    d = jax.random.normal(ks[5], (h,))
+    flat = [y.reshape(b, s, -1) for y in (x, bb, cc)]
+    with jax.default_matmul_precision("highest"):
+        want = ssd_recurrence(*(y.astype(jnp.float32) for y in (x, dt, a, bb,
+                                                                cc, d)))
+        loop = ssd.ssd_chunked(x, dt, a, bb, cc, d, chunk=q)
+        runs = jnp.cumsum((dt * a).reshape(b, s // q, q, h), 2).reshape(
+            b, s, h)
+        got = ssd.ssd_kernel(
+            jnp.concatenate(flat, -1) if columns else flat[0],
+            () if columns else tuple(flat[1:]), dt, runs, d, n=n, chunk=q,
+            interpret=True).reshape(x.shape)
+    assert got.dtype == dtype
+    top = float(jnp.abs(want).max())
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < bound * top
+    assert float(jnp.abs(got.astype(jnp.float32) - loop.astype(
+        jnp.float32)).max()) < bound * top
 
 
 def test_mamba_mixer_on_one_group_against_the_reference_row_by_row():

@@ -81,6 +81,34 @@ def _as_columns(x, dt, a, b, c, d, chunk):
 FORMS = {"separate": ssd.ssd_chunked, "columns": _as_columns}
 
 
+@pytest.mark.parametrize("rows,heads,p,groups,n,kept", [
+    (8, 128, 64, 1, 128, False),   # Granite's step: 32 MiB of float32 state
+    (8, 64, 64, 8, 128, True),     # Nemotron's: 16 MiB
+    (4, 32, 128, 32, 128, True),   # MiniCPM-SALA's lightning layers: 8 MiB
+    (4, 128, 64, 1, 128, True), (7, 128, 64, 1, 128, False),
+    (5, 128, 64, 1, 128, False), (16, 64, 64, 8, 128, False),
+    (1, 128, 64, 1, 128, True), (1, 512, 128, 1, 128, False),
+    (2, 4, 8, 2, 16, True), (3, 6, 4, 1, 8, True),  # the toys of these tests
+])
+def test_the_scans_form_is_read_off_the_shapes(monkeypatch, rows, heads, p,
+                                               groups, n, kept):
+    """``scan_form`` on one chip: the loop over chunks wherever the batch's
+    float32 state is at most the 16 MiB the v5e compiler keeps in fast
+    memory; past it the kernel for one group (a row and a block of heads a
+    step, whatever the rows) and the loop still for several. Here, with no
+    chip, the loop for every shape."""
+    here = ssd.scan_form(rows, 4096, heads, p, groups, n, 128)
+    assert here == "chunked"
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ssd, "_one_device", lambda: True)
+    form = ssd.scan_form(rows, 4096, heads, p, groups, n, 128)
+    assert (4 * rows * heads * p * n <= 16 * 2 ** 20) == kept
+    if kept or groups > 1:
+        assert form == "chunked"
+    else:
+        assert form == f"kernel-rows1-heads{min(heads, 64)}"
+
+
 @pytest.mark.parametrize("form", FORMS.values(), ids=FORMS.keys())
 @pytest.mark.parametrize("rows", [2, 3])
 @pytest.mark.parametrize("chunk", [64, 128])
@@ -155,13 +183,14 @@ def test_scan_with_one_group_a_head(inputs, form, bound):
 
 
 def test_scan_on_the_chip_is_held_by_a_check_that_runs_here_too():
-    """``ops/parity_checks.py check_ssd_scan`` holds both published shapes
-    to the recurrence on the chip; its small float32 cases run here."""
+    """``ops/parity_checks.py check_ssd_scan`` holds the three published
+    shapes to the recurrence on the chip (Granite's through the kernel); its
+    small float32 cases run here, the kernel interpreted."""
     from storm_tpu.ops.parity_checks import check_ssd_scan
 
     rows = check_ssd_scan(interpret=True)
-    assert [r["case"].split("_")[0] for r in rows] == ["nemotron",
-                                                       "lightning"]
+    assert [r["case"].split("_")[0] for r in rows] == [
+        "nemotron", "lightning", "granite"]
     assert all(r["pass"] and r["rms_rel_err"] < 1e-5 for r in rows)
 
 

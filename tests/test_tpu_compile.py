@@ -724,6 +724,66 @@ def test_scan_reads_and_writes_where_its_mixer_holds_them(
                          r"(copy|reshape|transpose|convert|slice)\(", entry)
 
 
+def test_granites_scan_keeps_its_state_in_fast_memory(v5e, monkeypatch):
+    """Granite's Mamba-2 mixer at its cell's step (8 windows of 4,096 into
+    4,096, 128 heads of 64 on one group of 128, bfloat16): 8 rows' state is
+    32 MiB, which the compiler leaves in HBM where a loop carries it, so as
+    one chip builds it the scan is one kernel under ``mix.ssd_scan``
+    (``ops/ssd.py scan_form``: a row and 64 heads a step, their state in
+    VMEM scratch) and no ``while`` is left to carry a state anywhere. The
+    convolution's whole result ``x | B | C`` is read where the mixer holds
+    it, position-major, and the result written so; outside the scan nothing
+    of a branch's size is copied, re-tiled, widened or sliced, and nothing
+    is scattered."""
+    import re
+
+    from storm_tpu.models import nemotron_h as N
+    from storm_tpu.ops import kda, ssd
+
+    for module in (kda, ssd):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    assert ssd.scan_form(8, 4096, 128, 64, 1, 128, 128) == (
+        "kernel-rows1-heads64")
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: N.mamba_mixer_init(
+            jax.random.PRNGKey(0), 4096, 128, 64, 1, 128, 4)))
+    x = _spec((8, 4096, 4096), jnp.bfloat16, v5e)
+    text = jax.jit(lambda p, x: N.mamba_mixer(
+        p, x, 128, 64, 1, 128, 128, 1e-5)).lower(p, x).compile().as_text()
+    assert not _loops(text)
+    (call,) = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "mix.ssd_scan" in line]
+    assert re.search(r"= bf16\[8,4096,8192\]\{2,1,0:", call)
+    assert call.count("bf16[8,4096,8448]{2,1,0}") == 3  # x, B and C
+    assert "scatter(" not in text
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r"= \w+\[8,4096,(8576|8448|8192|4096)\]\S* "
+                         r"(copy|reshape|transpose|convert|slice)\(", entry)
+
+
+@pytest.mark.parametrize("chunk,heads", [(128, 64), (256, 32)])
+def test_granites_scan_kernel_fits_fast_memory_at_both_chunks(
+        v5e, monkeypatch, chunk, heads):
+    """The scan alone at the cell's 8 rows as the benchmark's mixer check
+    times it, at the chunk that ships and at the published 256: the kernel
+    compiles at both (64 heads a step at a chunk of 256 ran out of VMEM on
+    the chip, PR 64: a longer chunk takes fewer heads a step)."""
+    from storm_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ssd, "_one_device", lambda: True)
+    assert ssd.scan_form(8, 4096, 128, 64, 1, 128, chunk) == (
+        f"kernel-rows1-heads{heads}")
+    xbc = _spec((8, 4096, 8448), jnp.bfloat16, v5e)
+    dt = _spec((8, 4096, 128), jnp.float32, v5e)
+    a = _spec((128,), jnp.float32, v5e)
+    text = jax.jit(lambda xbc, dt, a, d: ssd.ssd_chunked_columns(
+        xbc, dt, a, d, 1, 128, chunk)).lower(xbc, dt, a, a).compile().as_text()
+    assert "tpu_custom_call" in text and not _loops(text)
+
+
 def test_kda_mixer_compiles_with_every_branch_in_lanes(v5e, monkeypatch):
     """Kimi-Linear's KDA mixer at its cell's step (8 windows of 4,096 into
     2,304, 32 heads of 128, bfloat16) as one chip builds it: the tables'
