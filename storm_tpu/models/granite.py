@@ -40,6 +40,8 @@ expert's width, where the weights start, the chunk and the tiles.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from storm_tpu.models import scorer as S
@@ -99,7 +101,7 @@ def build_granite(
     groups: int = 1,
     eps: float = 1e-5,
     chunk: int = 128,
-    expert_tile: int = 512,
+    expert_tile: Optional[int] = None,
     max_rows: int = 8,
     param_dtype=jnp.bfloat16,
 ) -> ModelDef:

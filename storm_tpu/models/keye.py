@@ -43,6 +43,7 @@ observe_block_counts``), beside the expert layer's.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -130,7 +131,7 @@ def build_keye(
     first_expert: int = 0,
     rope_theta: float = 1e7,
     eps: float = 1e-6,
-    expert_tile: int = 512,
+    expert_tile: Optional[int] = None,
     attention_block: int = 512,
     select_tile: int = 1024,
     max_rows: int = 4,
@@ -202,7 +203,7 @@ def build_keye_vl2_30b(num_classes: int = 151936,
         published_layers=48, dim=2048, heads=32, kv_heads=4, head_dim=128,
         mrope_section=(16, 24, 24), index_heads=16, index_dim=64, topk=2048,
         chunk=512, expert_width=768, n_experts=128, top_k=8,
-        experts_held=128, expert_tile=1024)
+        experts_held=128)
 
 
 @register("keye_tiny")

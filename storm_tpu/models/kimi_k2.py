@@ -26,6 +26,8 @@ The skeleton (ids, the float32 stream, the head, the counters' way out) is
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from storm_tpu.models import scorer as S
@@ -67,7 +69,7 @@ def build_kimi_k2(
     beta_slow: float = 1.0,
     mscale: float = 1.0,
     mscale_all_dim: float = 1.0,
-    expert_tile: int = 512,
+    expert_tile: Optional[int] = None,
     max_rows: int = 4,
     published_layers: int = 61,
     param_dtype=jnp.bfloat16,

@@ -37,6 +37,7 @@ dt_bias)``, rank ``head_dim``), the output gate's rank, the initialisers.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -191,7 +192,7 @@ def build_kimi_linear(
     routed_scale: float = 2.446,
     eps: float = 1e-5,
     chunk: int = 64,
-    expert_tile: int = 1024,
+    expert_tile: Optional[int] = None,
     max_rows: int = 8,
     published_layers: int = 27,
 ) -> ModelDef:

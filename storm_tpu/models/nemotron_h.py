@@ -159,7 +159,7 @@ def build_nemotron_h(
     routed_scale: float = 2.5,
     eps: float = 1e-5,
     chunk: int = 128,
-    expert_tile: int = 1024,
+    expert_tile: Optional[int] = None,
     max_rows: int = 8,
 ) -> ModelDef:
     """The layers that ``pattern`` spells (the held letters of the published
@@ -231,7 +231,7 @@ def build_nemotron_3_nano_30b(num_classes: int = 32768,
         pattern="MEMEM*EME", published_layers=52, dim=2688, mamba_heads=64,
         mamba_head_dim=64, groups=8, state=128, conv=4, heads=32, kv_heads=2,
         head_dim=128, expert_width=1856, shared_width=3712, n_experts=128,
-        top_k=6, experts_held=32, expert_tile=512)
+        top_k=6, experts_held=32)
 
 
 @register("nemotron_h_tiny")

@@ -85,7 +85,7 @@ class Branch(NamedTuple):
 
 
 def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
-            first_expert: int, scale: float, tile: int,
+            first_expert: int, scale: float, tile: Optional[int] = None,
             post: Optional[str] = None, post_scale: float = 1.0,
             router: str = "sigmoid") -> Branch:
     """The dropless top-k expert layer (parallel/moe.py) as a branch, its
@@ -94,8 +94,9 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
     shared expert that ``init``'s tree has: it routes from the float32 norm,
     names its own parts and counts the tokens of each held expert and the
     assignments that fell on absent ones. The counts' reader is told the
-    tile and the router's width (read off ``init``'s shapes), from which the
-    layer chose its loops' sizes."""
+    router's width (read off ``init``'s shapes) and ``tile`` as the layer is:
+    None, and both take the tile from the step's shapes (``parallel/moe.py
+    run_tile``); a number, the toy presets' tile of 16 rows."""
     width = jax.eval_shape(init, jax.ShapeDtypeStruct(
         (2,), jnp.uint32))["router"].shape[1]
     return Branch(

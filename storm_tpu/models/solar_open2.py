@@ -31,6 +31,8 @@ sigmoid router, the initialisers.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from storm_tpu.models import scorer as S
@@ -62,7 +64,7 @@ def build_solar_open2(
     routed_scale: float = 1.0,
     eps: float = 1e-5,
     chunk: int = 64,
-    expert_tile: int = 512,
+    expert_tile: Optional[int] = None,
     max_rows: int = 8,
     published_layers: int = 48,
     param_dtype=jnp.bfloat16,

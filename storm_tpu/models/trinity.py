@@ -44,6 +44,7 @@ the selection bias, where the weights and the post-norms' scales start.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -103,7 +104,7 @@ def build_trinity(
     route_scale: float = 2.826,
     rope_theta: float = 10000.0,
     eps: float = 1e-5,
-    expert_tile: int = 512,
+    expert_tile: Optional[int] = None,
     attention_block: int = 512,
     max_rows: int = 4,
     param_dtype=jnp.bfloat16,
@@ -186,7 +187,7 @@ def build_trinity_mini(num_classes: int = 200192,
         layer_types=("sliding",) * 4 + ("full",), dense=1,
         published_layers=32, dim=2048, heads=32, kv_heads=4, head_dim=128,
         window=2048, dense_width=6144, expert_width=1024, n_experts=128,
-        top_k=8, experts_held=128, expert_tile=1024)
+        top_k=8, experts_held=128)
 
 
 @register("trinity_tiny")

@@ -212,6 +212,33 @@ _COMBINE_ROWS = 512
 # many rows (a bfloat16 tile of the matrix unit's operand is 128 rows deep).
 _TILE_STEP = 128
 
+# A tile's tokens are gathered this many rows at a time: the chip's gather of
+# 1,024 rows of 4,096 channels read 51.2 us where two of 512 read 19.2 each
+# and laying them end to end in fast memory 2 (2,688 channels: 35.7 for 2 x
+# 14.9; 2,048: 28.3 for 2 x 11.65; 256 rows cost more a row again: PERF.md,
+# PR 65), which was most of what a tile of 1,024 rows' products gained.
+_GATHER_ROWS = 512
+
+# The experts' loop cuts a run into tiles of this many rows where the expected
+# run fills one, and of half as many where it does not (:func:`run_tile`).
+_EXPERT_TILE = 1024
+
+
+def run_tile(expected_run: float) -> int:
+    """The rows of a tile of the experts' loop, from the expected run of a
+    held expert (``n * top_k / width``: shapes alone): ``_EXPERT_TILE`` where
+    that run fills one, half of it below. Every tile reads its expert's
+    matrices whole, so a tile's rows are the FLOP it does a byte of weights:
+    512 rows are twice the v5e's ridge of 240 FLOP a byte and no more, and
+    at 4,096 x 768 a tile of 1,024 rows read its gate and up products at 91
+    % of the chip's peak for 80 at 512 rows, its down product with the
+    tile's write at 73 % for 59 (2,688 x 1,856: 8 % less a row; PERF.md, PR
+    65). A run shorter than a tile buys only padding with a larger one: it
+    is its own last tile, computed at :func:`tile_sizes`' small size at
+    best. (2,048 rows gained 3 % more on the products and lost it in a last
+    tile of 1,024 rows, there.)"""
+    return _EXPERT_TILE if expected_run >= _EXPERT_TILE else _EXPERT_TILE // 2
+
 
 def tile_sizes(tile: int, expected_run: float, tight: bool) -> tuple:
     """The row counts a loop over runs cut into tiles of ``tile`` has a body
@@ -485,7 +512,7 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
 
 def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
                    router: str = "sigmoid", renormalize: bool = True,
-                   scale: float = 1.0, tile: int = 1024):
+                   scale: float = 1.0, tile: Optional[int] = None):
     """Dropless top-``top_k`` expert layer over ``(..., dim)`` activations.
 
     The layer holds experts ``first_expert ..`` (as many as its stacked
@@ -507,12 +534,14 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     assignments. Noted as ``expert_dispatch=sorted``.
 
     The grouped product: each expert's run is cut into tiles of ``tile``
-    rows, and a loop over as many tiles as the routing made gathers a tile's
-    tokens, runs that expert's feed-forward
-    on them (``ops/layers.py feed_forward``: SwiGLU or squared ReLU, as the
-    stacked parameters say; the shared expert likewise, at its own width)
-    and writes the weighted result to the tile's place in a buffer. The
-    loop's length is the data's, so whatever the routing no token is dropped
+    rows (None: :func:`run_tile` of the expected run, from the shapes of the
+    call; a number is the toy presets' way to a tile that is no multiple of
+    128 rows), and a loop over as many tiles as the routing made gathers a
+    tile's tokens (``_GATHER_ROWS`` at a time), runs that expert's
+    feed-forward on them (``ops/layers.py feed_forward``: SwiGLU or squared
+    ReLU, as the stacked parameters say; the shared expert likewise, at its
+    own width) and writes the weighted result to the tile's place in a
+    buffer. The loop's length is the data's, so whatever the routing no token is dropped
     and no padding up to a capacity is computed; the only waste is in each
     run's last, partly filled tile, and that tile is computed at a smaller
     size where its places fit one: the loop has at most two sizes
@@ -561,14 +590,17 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         x = x.astype(w["down"].dtype)
         tokens = x.reshape(-1, dim)
         n = tokens.shape[0]
+        width = p["router"].shape[1]
+        expected_run = n * top_k / width
+        if tile is None:
+            tile = run_tile(expected_run)
         tile = max(8, min(int(tile), -(-n // 8) * 8))
         local = experts - first_expert
         local = jnp.where((local >= 0) & (local < held), local,
                           held).reshape(-1)
         (counts, number_at, weight_at, row_at, zero_row, n_tiles,
          tile_at) = _dispatch(local, weights.reshape(-1), held, tile)
-        width = p["router"].shape[1]
-        sizes = tile_sizes(tile, n * top_k / width, tight=False)
+        sizes = tile_sizes(tile, expected_run, tight=False)
         tiles, ends = _tiles_by_size(n_tiles, zero_row // tile, tile_at,
                                      sizes)
         if len(sizes) > 1:
@@ -591,8 +623,10 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         def one_tile(j, tile_j, out):
             e, start, filled = tile_j
             valid = lane[:m] < filled  # rows past the run's end: zero
-            ids = jax.lax.dynamic_slice(token_in, (start,), (m,))
-            rows = tokens[jnp.where(valid, ids, 0)]
+            ids = jnp.where(valid, jax.lax.dynamic_slice(
+                token_in, (start,), (m,)), 0)
+            rows = jnp.concatenate([tokens[ids[i:i + _GATHER_ROWS]]
+                                    for i in range(0, m, _GATHER_ROWS)])
             y = L.feed_forward({name: s[e] for name, s in w.items()}, rows)
             gain = jnp.where(valid, jax.lax.dynamic_slice(
                 weight_in, (start,), (m,)), 0.0)
@@ -619,24 +653,26 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         return y.astype(x.dtype).reshape(shape), counts, absent
 
 
-def observe_expert_counts(metrics, cid: str, tokens, absent, *, tile: int,
-                          width: int) -> None:
+def observe_expert_counts(metrics, cid: str, tokens, absent, *, width: int,
+                          tile: Optional[int] = None) -> None:
     """What :func:`topk_moe_layer` counted in one step, fetched to the host
     (``tokens`` ``(layers, held)``, ``absent`` ``(layers,)``), into the
     registry under ``cid``: the assignments held and absent as two counters,
     the rows that the experts' loop computed for the held ones
     (:func:`rows_computed` of every expert's count at the sizes the layer
-    chose: :func:`tile_sizes` of its ``tile`` and of the step's assignments
-    over the router's ``width``; the held over these is how full the tiles
-    were; a step of fewer tokens than ``tile`` cuts smaller tiles and
-    computes less than is counted here), and once a layer the busiest held
-    expert over the mean."""
+    chose: the step's assignments over the router's ``width`` are the
+    expected run the layer saw, its tile ``tile`` or, where None, that run's
+    :func:`run_tile`, its sizes :func:`tile_sizes` of both; the held over
+    these is how full the tiles were; a step of fewer tokens than the tile
+    cuts smaller tiles and computes less than is counted here), and once a
+    layer the busiest held expert over the mean."""
     metrics.counter(cid, "expert_assignments_held").inc(int(tokens.sum()))
     rows = metrics.counter(cid, "expert_rows_computed")
     for layer, elsewhere in zip(tokens, absent):
         run = (int(layer.sum()) + int(elsewhere)) / width
-        rows.inc(int(rows_computed(layer, tile_sizes(tile, run,
-                                                     tight=False)).sum()))
+        sizes = tile_sizes(run_tile(run) if tile is None else tile, run,
+                           tight=False)
+        rows.inc(int(rows_computed(layer, sizes).sum()))
     metrics.counter(cid, "expert_assignments_absent").inc(int(absent.sum()))
     load = metrics.histogram(cid, "expert_tokens_max_over_mean")
     for layer in tokens:

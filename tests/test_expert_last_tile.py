@@ -1,5 +1,6 @@
 """A run's last tile at a smaller size (``parallel/moe.py tile_sizes``): the
-sizes against the rule's values at the cells' shapes; the layer with two sizes
+sizes against the rule's values at the cells' shapes, and the tile itself from
+the expected run (``run_tile``); the layer with two sizes
 a loop against the same layer with one, to the bit, for runs that end anywhere
 in a tile; the order of the tiles and every assignment's row; the counter of
 rows computed against a hand count."""
@@ -15,7 +16,8 @@ import pytest
 from storm_tpu.ops.platform import dispatch_notes
 from storm_tpu.parallel import moe
 from storm_tpu.parallel.moe import (observe_expert_counts, rows_computed,
-                                    tile_sizes, topk_moe_init, topk_moe_layer)
+                                    run_tile, tile_sizes, topk_moe_init,
+                                    topk_moe_layer)
 from storm_tpu.runtime.metrics import MetricsRegistry
 
 DIM, N, WIDTH, HELD, FIRST, TOP_K = 32, 2100, 8, 2, 2, 2
@@ -51,6 +53,28 @@ def test_tile_sizes_at_the_cells_shapes_and_the_rules_edges(
     assert got == want and all(isinstance(m, int) for m in got)
     assert got[0] == tile and len(got) <= 2
     assert all(m % 128 == 0 and 0 < m < tile for m in got[1:])
+
+
+@pytest.mark.parametrize("n,top_k,width,tile,sizes", [
+    (32768, 10, 72, 1024, (1024, 512)),  # granite_4_h_small: 4,551 a run
+    (65536, 8, 128, 1024, (1024, 512)),  # trinity_mini, keye_vl2_30b: 4,096
+    (32768, 8, 256, 1024, (1024, 512)),  # kimi_linear_48b: 1,024, one tile
+    (32768, 6, 128, 1024, (1024, 512)),  # nemotron_3_nano_30b: 1,536
+    (32768, 8, 320, 512, (512, 256)),  # solar_open2_250b: 819
+    (16384, 8, 384, 512, (512, 384)),  # kimi_k2_6: 341
+    (4096, 10, 72, 512, (512, 256)),  # Granite's step of one row: 569
+    (4096, 8, 128, 512, (512, 256)),  # Trinity's of a quarter row: 256
+    (16384, 8, 128, 1024, (1024, 512)),  # and of one row: 1,024
+    (16376, 8, 128, 512, (512, 256)),  # a token fewer: 1,023.5 fill none
+    (8, 2, 8, 512, (512, 128)),  # (the layer cuts a tile to its tokens)
+])
+def test_the_experts_tile_is_the_expected_runs(n, top_k, width, tile, sizes):
+    """One rule for every caller, from the shapes of the call: 1,024 rows
+    where a held expert's expected run ``n * top_k / width`` fills a tile of
+    1,024, 512 below; then ``tile_sizes`` of that tile and run, as before."""
+    run = n * top_k / width
+    assert run_tile(run) == tile and isinstance(run_tile(run), int)
+    assert tile_sizes(run_tile(run), run, tight=False) == sizes
 
 
 @pytest.mark.parametrize("count,sizes,want", [
@@ -175,10 +199,15 @@ def test_rows_left_out_of_a_last_tile_are_never_read(run, monkeypatch):
     # 2,189 and 2,191 assignments a layer: over 4 columns 547 a run (256
     # beside 512, 640 beside 1,024), over 8 columns 273 (384 beside 512)
     (512, 4, 256 + 256 + 512 + 768 + 1280), (512, 8, 2 * 384 + 512 + 896 + 1408),
-    (1024, 4, 4 * 640 + 1664), (16, 4, 16 + 144 + 512 + 528 + 1040)])
+    (1024, 4, 4 * 640 + 1664), (16, 4, 16 + 144 + 512 + 528 + 1040),
+    # no tile given: the run's own, 512 under a run of 1,024 (547, 273) and
+    # 1,024 from there (1,094 over 2 columns: 512 beside 1,024)
+    (None, 4, 256 + 256 + 512 + 768 + 1280),
+    (None, 8, 2 * 384 + 512 + 896 + 1408), (None, 2, 4 * 512 + 512 + 1536)])
 def test_the_counter_of_rows_computed_is_the_hand_count(tile, width, want):
     """Two layers of three held experts with 0, 1 and 129, then 512, 513 and
-    1,029 tokens, at the sizes the layer chooses for that tile and width."""
+    1,029 tokens, at the sizes the layer chooses for that tile (None: for
+    the run it counted) and width."""
     tokens = np.array([[0, 1, 129], [512, 513, 1029]])
     absent = np.array([2184 + 5 - 130, 2184 + 7 - 2054])
     registry = MetricsRegistry()
@@ -190,6 +219,96 @@ def test_the_counter_of_rows_computed_is_the_hand_count(tile, width, want):
     assert got["expert_assignments_held"] == 2 * tokens.sum()
     assert got["expert_assignments_absent"] == 2 * absent.sum()
     assert got["expert_tokens_max_over_mean"]["count"] == 4
+
+
+def _long_run(run, n=5000):
+    """:func:`_layer_and_tokens`' layer over ``n`` tokens, whose 10,000
+    assignments over a router of 8 are an expected run of 1,250: the rule's
+    tile is 1,024. Expert 2's run is ``run`` rows."""
+    p, _ = _layer_and_tokens("swiglu", 0)
+    x = jax.random.normal(jax.random.PRNGKey(9), (n, DIM))
+    return p, x.at[:, 0].set(-3.0).at[:run, 0].set(3.0)
+
+
+@pytest.mark.parametrize("run", [4551, 4608, 4609, 4096 + 512, 1023])
+def test_the_rules_tile_of_1024_is_the_layer_at_512_to_the_bit(run):
+    """A row's feed-forward is the same products whatever tile it rides in:
+    the layer that takes its tile from the run (1,024 rows, a last tile at
+    512) against the same layer told 512 (the seven builders' old constant
+    of four), at a run of four and a half tiles and around it: ``y``, the
+    counts and ``absent``, every bit. (Two held assignments a token at most
+    here, so the combine's sum has one order.)"""
+    p, x = _long_run(run)
+    got = {}
+    for tile in (None, 512):
+        with dispatch_notes() as seen:
+            got[tile] = jax.jit(lambda p, x: topk_moe_layer(
+                p, x, TOP_K, first_expert=FIRST, scale=2.5, tile=tile))(p, x)
+        assert f"expert_tiles=last-{256 if tile else 512}" in seen
+    for a, b in zip(got[None], got[512]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert int(got[None][1][0]) == run
+
+
+def test_the_counter_counts_the_rows_of_the_tile_the_layer_ran():
+    """No tile given to either: the layer cuts its runs by the expected run
+    of its call, the counts' reader by the run it counted, which is the same
+    number, so ``expert_rows_computed`` is the rows of the loops that ran:
+    the buffer the layer allocates is the worst case of that tile, and the
+    count is :func:`rows_computed` of the step's counts at its sizes."""
+    p, x = _long_run(4200)
+    layer = jax.jit(lambda p, x: topk_moe_layer(
+        p, x, TOP_K, first_expert=FIRST, scale=2.5))
+    with dispatch_notes() as seen:
+        text = layer.lower(p, x).as_text()
+    assert "expert_tiles=last-512" in seen
+    worst = (-(-5000 * TOP_K // 1024) + HELD) * 1024 + 1  # and the zero row
+    assert f"tensor<{worst}x{DIM}xf32>" in text
+    _, tokens, absent = layer(p, x)
+    registry = MetricsRegistry()
+    observe_expert_counts(registry, "bolt", np.asarray(tokens)[None],
+                          np.asarray(absent)[None], width=WIDTH)
+    counts = [int(c) for c in tokens]
+    assert counts[0] == 4200  # 4,608 rows of (1,024, 512), 4,352 of (512, 256)
+    assert registry.snapshot()["bolt"]["expert_rows_computed"] == sum(
+        rows_computed(c, (1024, 512)) for c in counts) \
+        != sum(rows_computed(c, (512, 256)) for c in counts)
+
+
+PUBLISHED = ["kimi_linear_48b", "nemotron_3_nano_30b", "kimi_k2_6",
+             "solar_open2_250b", "trinity_mini", "keye_vl2_30b",
+             "granite_4_h_small"]
+TOYS = ["kimi_linear_tiny", "nemotron_h_tiny", "kimi_k2_tiny",
+        "solar_open2_tiny", "trinity_tiny", "keye_tiny", "granite_h_tiny"]
+
+
+@pytest.mark.parametrize("name,want", [(name, None) for name in PUBLISHED] + [
+    (name, 16) for name in TOYS])
+def test_no_published_width_builder_names_a_tile(name, want, monkeypatch):
+    """The seven builders hand the expert branch no tile at the published
+    widths (the layer takes it from the shapes of its call) and their
+    ``expert_tile`` defaults to none; the toy presets keep 16 rows, a tile
+    that is no multiple of 128 on windows of 40 tokens."""
+    import inspect
+    import sys
+
+    from storm_tpu.models import registry, scorer
+
+    given, experts = [], scorer.experts
+
+    def recorded(*args, **kwargs):
+        given.append(kwargs.get("tile"))
+        return experts(*args, **kwargs)
+
+    monkeypatch.setattr(scorer, "experts", recorded)
+    registry.build_model(name)
+    assert given == [want]
+    family = sys.modules[registry._BUILDERS[name].__module__]
+    (build,) = [f for n, f in vars(family).items()
+                if n.startswith("build_") and "expert_tile"
+                in inspect.signature(f).parameters]
+    assert inspect.signature(build).parameters["expert_tile"].default is None
 
 
 def _routing(kind, held, top_k, n, width, seed):
@@ -261,24 +380,26 @@ def test_tiles_by_size_and_every_assignments_row(kind, held, top_k, width,
 
 @pytest.mark.parametrize("name,experts,combine,write", [
     ("kimi_linear_48b", "last-512", "last-384", "added"),
-    ("nemotron_3_nano_30b", "last-256", "whole", "added"),
+    ("nemotron_3_nano_30b", "last-512", "whole", "added"),
     ("kimi_k2_6", "last-384", "last-128", "added"),
     ("solar_open2_250b", "last-256", "last-384", "added"),
     ("trinity_mini", "last-512", "whole", "once"),
     ("keye_vl2_30b", "last-512", "whole", "once"),
+    ("granite_4_h_small", "last-512", "whole", "added"),
     ("kimi_linear_tiny", "whole", None, "once"),
     ("nemotron_h_tiny", "whole", None, "once"),
     ("kimi_k2_tiny", "whole", None, "once"),
     ("solar_open2_tiny", "whole", None, "once"),
     ("trinity_tiny", "whole", "whole", "once"),
-    ("keye_tiny", "whole", "whole", "once")])
+    ("keye_tiny", "whole", "whole", "once"),
+    ("granite_h_tiny", "whole", "whole", "once")])
 def test_a_models_step_names_its_loops_sizes(name, experts, combine, write):
-    """What the engine's inventory says of a program: the six expert models
+    """What the engine's inventory says of a program: the seven expert models
     traced at their largest step, shapes only (the presets' tile of 16 has
     one size; their combine's follows their rows a step). A block of the
     combine is one tile, written once, where the whole router is held (64
     tokens of 8 assignments) and in the presets' top-2 layers (256 of 2);
-    the four cells that hold a part of theirs add a tile to its block's
+    the five cells that hold a part of theirs add a tile to its block's
     sums, as they did."""
     from storm_tpu.models.registry import build_model
 

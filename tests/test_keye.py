@@ -443,13 +443,15 @@ def test_two_half_shares_add_up_to_the_all_held_layer():
 # two files, and this PR leaves them as they were. PR 60 changed the text of
 # these four lines: both plans hold their whole router, so the combine's
 # block is 64 tokens or fewer, one tile each, written once; trees and leaves
-# are the parent's.)
+# are the parent's. PR 65 changed the text of the two served sizes' lines:
+# their tiles of 1,024 rows gather their tokens 512 rows at a time,
+# ``parallel/moe.py _GATHER_ROWS``; the tile is what their presets named.)
 TRINITY = {"trinity_tiny": ("7d7cdf66bd3d9742", "02ca71f3daed22c6",
                             "fb4f89f513f7395d"),
-           "trinity_mini": ("f056703cb957a557", "c7f7b016380d3f34")}
+           "trinity_mini": ("0f050f77253a3927", "c7f7b016380d3f34")}
 KEYE = {"keye_tiny": ("ff379ced75e497f1", "2d028cd1c66aed41",
                       "ed3354f4d5badb8f"),
-        "keye_vl2_30b": ("9f515b04939f3853", "11769136fc6552db")}
+        "keye_vl2_30b": ("c7286d9202a3bbf8", "11769136fc6552db")}
 
 
 def _digest(*chunks):

@@ -225,7 +225,13 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
 # changed the text of the four toy expert models' lines alone: their top-2
 # layers' blocks of the combine are one tile each (256 tokens x 2), which
 # now writes its sums and does not read them back; the four served sizes,
-# which hold a part of an 8- or 6-a-token router, stand to the letter.)
+# which hold a part of an 8- or 6-a-token router, stand to the letter. PR 65
+# changed the text of ``kimi_linear_48b``'s line alone: the experts' tile is
+# the expected run's (``parallel/moe.py run_tile``), and the two rows this
+# test lowers are a run of 256, so tiles of 512 where the preset said 1,024
+# (at its cell's eight rows, a run of 1,024, the tile is the preset's, its
+# tokens gathered 512 rows at a time); the others' tile here is what their
+# preset named, 512 rows, and their text stands.)
 PARENT = {
     "kimi_linear_tiny": ("c1402509f112bf5b", "9c9231af39d81a3b",
                          "d6d687f48a145060"),
@@ -235,7 +241,7 @@ PARENT = {
                      "3eee1654ad04fdad"),
     "minicpm_sala_tiny": ("e80bd69b29e9bb6e", "fbaec783e93f7071",
                           "136d1265de921964"),
-    "kimi_linear_48b": ("411585d80f021662", "35c56c4e58dd4ac8"),
+    "kimi_linear_48b": ("4d9a45ee334c607a", "35c56c4e58dd4ac8"),
     "nemotron_3_nano_30b": ("c3c98b01400caee8", "32502e49d7fc6552"),
     "kimi_k2_6": ("3695cae34e2865eb", "4a919d11ba374a7b"),
     "minicpm_sala": ("5de5b298fd713172", "31e93c80f04d610e"),

@@ -470,9 +470,9 @@ def test_longseq_encoder_forward_compiles_with_flash_kernel(v5e, monkeypatch):
         (32768, 2304, 8, 32, 256, 1024, "swiglu", 1024,  # Kimi-Linear's cell
          "bf16[32,2304,1024]", "f32[32768,2304]",
          "expert_matmul_ms", "expert_combine_ms", (512, 384)),
-        (32768, 2688, 6, 32, 128, 1856, "relu2", 512,  # Nemotron's cell
+        (32768, 2688, 6, 32, 128, 1856, "relu2", 1024,  # Nemotron's cell
          "bf16[32,2688,1856]", "f32[32768,2688]",
-         "relu2_expert_matmul_ms", "expert_combine_ms", (256,)),
+         "relu2_expert_matmul_ms", "expert_combine_ms", (512,)),
         (16384, 7168, 8, 12, 384, 2048, "swiglu", 512,  # Kimi K2's cell
          "bf16[12,7168,2048]", "f32[16384,7168]",
          "k2_expert_matmul_ms", "k2_expert_combine_ms", (384, 128)),
@@ -490,8 +490,10 @@ def test_longseq_encoder_forward_compiles_with_flash_kernel(v5e, monkeypatch):
 def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
         v5e, n, dim, top_k, held, width, hidden, form, tile, stacked, sums,
         matmul_ms, combine_ms, smalls):
-    """The language cells' expert layer at their real sizes, shapes only:
-    the tile loops still carry the experts' stacked weights and the
+    """The language cells' expert layer at their real sizes, shapes only,
+    its tile the one the layer takes from those shapes (``tile``: 1,024 rows
+    where a held expert's expected run fills one, 512 below; no caller names
+    it): the tile loops still carry the experts' stacked weights and the
     combine's loops the tokens' float32 sums (the benchmark's
     ``*expert_matmul_ms`` and ``*expert_combine_ms`` find the loops in a
     trace by those shapes and add up what they find, and a listed metric
@@ -518,8 +520,9 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
         jax.random.PRNGKey(0), dim, hidden, width, held, form=form))
     p = jax.tree.map(lambda a: _spec(a.shape, jnp.bfloat16, v5e), p)
     x = _spec((n, dim), jnp.float32, v5e)
+    assert moe.run_tile(n * top_k / width) == tile
     text = jax.jit(lambda p, x: moe.topk_moe_layer(
-        p, x, top_k, tile=tile)).lower(p, x).compile().as_text()
+        p, x, top_k)).lower(p, x).compile().as_text()
     lines = [re.sub(r"\{[^}]*\}", "", line) for line in text.splitlines()]
     loops = [line for line in lines if " while(" in line]
     assert sum(stacked in line for line in loops) == 2
@@ -534,7 +537,8 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
     assert " conditional(" not in text
     assert "scatter" not in text
     # how many slices a gather fetches: a tile's rows at each of its sizes
-    # or a bisection's probes, nowhere one an assignment
+    # (512 at a time where it has more: ``_GATHER_ROWS``) or a bisection's
+    # probes, nowhere one an assignment
     fetched = set()
     for line in text.splitlines():
         found = re.search(r" = \w+\[([\d,]+)\]\S* gather\(.*"
@@ -551,7 +555,8 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
     assert (block, combined) == ((64, 512) if held == width else (256, 512))
     tiles = {-(-n * top_k // tile) + held,
              -(-n * top_k // combined) + n // block}
-    rows = {held + 1, n // block + 1, tile, combined} | set(smalls)
+    rows = {held + 1, n // block + 1, min(tile, moe._GATHER_ROWS),
+            combined} | set(smalls)  # a tile's tokens 512 rows a gather
     assert rows <= fetched <= rows | tiles  # every tile's run, to part them
     # what an operation outside a fusion's body writes is an array in memory
     written, fused = [], False
@@ -588,6 +593,48 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
                for line in written) == 1
     assert sum(buffer in line and 'custom_call_target="AllocateBuffer"' in
                line for line in text.splitlines()) == 1
+
+
+def test_granites_expert_layer_compiles_with_tiles_of_1024(v5e):
+    """Granite's expert layer at its cell's step, 32,768 tokens of 4,096
+    channels, ten experts a token of a router of 72, the first 36 held at a
+    width of 768: a held expert's expected run is 4,551 rows, so the layer
+    cuts tiles of 1,024 and a run's last at 512: two loops that carry the
+    experts' stacked weights (its metrics read the part ``moe.experts``,
+    whatever is under it), the tiles' buffer of the worst case at that tile,
+    356 tiles and the zero row, allocated once and never filled or copied,
+    and every gather of tokens 512 rows."""
+    import re
+
+    from storm_tpu.ops.platform import dispatch_notes
+    from storm_tpu.parallel import moe
+
+    n, dim, top_k, held, width, hidden = 32768, 4096, 10, 36, 72, 768
+    p = jax.eval_shape(lambda: moe.topk_moe_init(
+        jax.random.PRNGKey(0), dim, hidden, width, held,
+        shared_hidden=2 * hidden, selection_bias=False))
+    p = jax.tree.map(lambda a: _spec(a.shape, jnp.bfloat16, v5e), p)
+    x = _spec((8, 4096, dim), jnp.float32, v5e)
+    with dispatch_notes() as seen:
+        text = jax.jit(lambda p, x: moe.topk_moe_layer(
+            p, x, top_k, router="softmax")).lower(p, x).compile().as_text()
+    assert {"expert_tiles=last-512", "combine_tiles=whole",
+            "combine_write=added"} <= set(seen)
+    loops = [re.sub(r"\{[^}]*\}", "", line) for line in _loops(text)]
+    assert sum("bf16[36,4096,768]" in line for line in loops) == 2
+    assert sum("f32[32832,4096]" in line for line in loops) == 1  # 342 x 96
+    buffer = "bf16[%d,4096]" % ((-(-n * top_k // 1024) + held) * 1024 + 1)
+    assert buffer == "bf16[364545,4096]"
+    assert sum(buffer in line and 'custom_call_target="AllocateBuffer"' in
+               line for line in text.splitlines()) == 1
+    assert not any(re.search(r" = bf16\[364545,4096\]\S* (copy|broadcast)\(",
+                             line) for line in text.splitlines())
+    assert " conditional(" not in text and "scatter" not in text
+    # a tile's tokens 512 rows a gather (two of them cost less than one of
+    # 1,024 rows): two in the loop of 1,024, one in the loop of 512, and the
+    # combine's one of 512
+    assert len(re.findall(r" = bf16\[512,4096\]\S* gather\(", text)) == 4
+    assert not re.search(r" = bf16\[1024,4096\]\S* gather\(", text)
 
 
 def _metric_pattern(name):
