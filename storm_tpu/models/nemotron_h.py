@@ -44,6 +44,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from storm_tpu.models import scorer as S
 from storm_tpu.models.registry import ModelDef, register
@@ -79,16 +80,34 @@ def mamba_mixer_init(rng, dim: int, heads: int, head_dim: int, groups: int,
 
 
 def mamba_mixer(p: dict, x: jnp.ndarray, heads: int, head_dim: int,
-                groups: int, state: int, chunk: int,
-                eps: float) -> jnp.ndarray:
+                groups: int, state: int, chunk: int, eps: float,
+                scales: Optional[tuple] = None) -> jnp.ndarray:
+    """The Mamba-2 layer of the module's header. ``scales`` (None: there are
+    none, and the text is what it was): five numbers, one a segment of the
+    projection's columns ``[z | x | B | C | dt]``, that the projection's
+    result is multiplied by (Falcon-H1's ``ssm_multipliers``, with
+    ``ssm_in_multiplier`` on the input folded in: the projection is linear).
+    Each is applied in float32 where its segment is next read, so none costs
+    a pass over the projection nor a rounding: ``z``'s inside the gated
+    norm's gate, ``x``'s, ``B``'s and ``C``'s on the convolution's taps (a
+    depthwise convolution is linear a channel, its bias is not scaled) and
+    the step's before its bias and softplus."""
     f32 = jnp.float32
     inner, gn = heads * head_dim, groups * state
     # in_proj's columns are [z | x B C | dt]
     z = _proj(x, p["in_proj"][:, :inner])
     xbcdt = _proj(x, p["in_proj"][:, inner:])
-    xbc = kda.conv_silu(p["conv"], xbcdt)
-    dt = jax.nn.softplus(xbcdt[..., inner + 2 * gn:].astype(f32)
-                         + p["dt_bias"].astype(f32))
+    conv = p["conv"]
+    if scales is not None:
+        of_z, of_x, of_b, of_c, of_dt = scales
+        conv = {**conv, "w": conv["w"].astype(f32) * np.repeat(
+            np.asarray([of_x, of_b, of_c], np.float32), [inner, gn, gn])}
+        z = z.astype(f32) * of_z
+    xbc = kda.conv_silu(conv, xbcdt)
+    dt = xbcdt[..., inner + 2 * gn:].astype(f32)
+    if scales is not None:
+        dt = dt * of_dt
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(f32))
     # x | B | C whole, as the convolution wrote them: the scan's loop takes
     # the columns apart a chunk at a time
     y = ssd_chunked_columns(xbc, dt, -jnp.exp(p["a_log"].astype(f32)),

@@ -75,6 +75,7 @@ def _load_builtin() -> None:
     from storm_tpu.models import (  # noqa: F401
         chartiny,
         evabyte,
+        falcon_h1,
         granite,
         keye,
         kimi_k2,

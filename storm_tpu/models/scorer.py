@@ -116,7 +116,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                  residual: float = 1.0, logit_scale: float = 1.0,
                  context: Optional[Callable] = None,
                  param_dtype=None, heads: int = 1, tied: bool = False,
-                 embed_std: Optional[float] = None) -> ModelDef:
+                 embed_std: Optional[float] = None,
+                 pin_stream: bool = False) -> ModelDef:
     """The model of ``blocks`` over ``num_classes`` rows of the vocabulary.
     With ``heads`` prediction heads ``num_classes`` is what an answer holds,
     ``heads`` distributions over ``num_classes / heads`` rows of the
@@ -139,7 +140,15 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
     untied scorer's). The last position's logit of its own id is ``scale_emb
     logit_scale dim embed_std^2`` over the final stream's root mean square,
     whatever the layers computed, so a tied model's builder chooses
-    ``embed_std`` to keep that term among the others."""
+    ``embed_std`` to keep that term among the others.
+    ``pin_stream``: the stream after each residual add is one array the
+    compiler may not take apart (an optimization barrier; no operation of its
+    own). Without it the v5e compiler keeps an earlier stream and every
+    branch's result in the served type since, and adds them again inside each
+    later norm's fusion: half the stream's bytes a branch it keeps so, 0.67
+    GB each at Falcon-H1's 65,536 tokens of 5,120 channels, 1.3 GB of a
+    four-layer step's temporaries (PERF.md section 6, PR 66). False: the
+    text every plan before it lowers to."""
     (seq,) = input_shape
     if tied and heads != 1:
         raise ValueError("a tied embedding serves one head")
@@ -237,6 +246,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                     if b.post:
                         y = L.rmsnorm(blk[b.post], y, eps)
                     h = h + (y if residual == 1 else residual * y)
+                    if pin_stream:
+                        h = jax.lax.optimization_barrier(h)
         with jax.named_scope(P.HEAD):
             last = L.rmsnorm(params["norm"], h[:, -1], eps)
             if logit_scale != 1:

@@ -95,8 +95,17 @@ def causal_tiles(group: int) -> tuple:
     """``(block_q, block_k)`` of the causal form for ``group`` query heads a
     key head: the positions of a query tile (its ``group`` heads are stacked
     into ``group * block_q`` rows of one left operand) and the keys of a
-    block."""
-    return max(512 // group, 16), 512
+    block. The tile is the largest power of two of positions whose stacked
+    rows are at most a key block's 512, never under 16 (a bfloat16 sublane
+    tile): 512, 256, 128, 64 and 32 for 1, 2, 4, 8 and 16 heads a group.
+    A group that is no power of two takes the power of two below its quotient
+    (Falcon-H1's five heads a key head: 64 positions, 320 stacked rows), so
+    that the tile is whole sublane tiles and divides a key block, and with
+    it every window of whole key blocks: ``512 // 5`` = 102 positions
+    divide no window, and ``causal_form`` then sent such a model to XLA's
+    blocked form."""
+    most = max(512 // group, 16)
+    return 1 << (most.bit_length() - 1), 512
 
 
 def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,

@@ -17,6 +17,10 @@ EMBED = "embed"  # patch/token embedding
 HEAD = "head"  # final norm, classifier or vocabulary product, softmax
 NORM = "norm"  # a block's pre-norms and residual adds
 PROJ = "proj"  # every dense product with weights that is not an expert's
+FFN = "ffn"  # a block's own dense feed-forward, its products and its gate,
+# where a plan names it apart (models/falcon_h1.py: what is left under
+# ``proj`` is then the mixers' projections alone; the plans before it keep
+# their feed-forward under ``proj``)
 MIX_ELEMENTWISE = "mix.elementwise"  # conv, gates, norms, re-tiling, layout
 MIX_KDA_TABLES = "mix.kda_tables"  # ops/kda.py: the within-chunk tables
 MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
@@ -44,7 +48,7 @@ VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
               MOE_EXPERTS, MOE_COMBINE, MIX_SPARSE_SELECT,
               MIX_SPARSE_ATTENTION, MIX_EVA_CHUNKS, MIX_EVA_ATTENTION,
-              MIX_WINDOW_ATTENTION, MIX_INDEX_SELECT)
+              MIX_WINDOW_ATTENTION, MIX_INDEX_SELECT, FFN)
 
 
 def part_of(op_name: str):

@@ -40,13 +40,19 @@ time for twice the state. The v5e compiler keeps 16 MiB (Nemotron's 8 rows
 x 64 heads x 64 x 128 x 4 bytes: 100 us a chunk on the chip; Granite's 4
 rows x 128 heads in the text compiled for a described v5e) and not 32 MiB
 (Granite's 8 rows: no ``S(1)``, 512 us a chunk on the chip; PERF.md section
-6, PRs 63 and 64).
+6, PRs 63 and 64). Falcon-H1's step is the same 16 MiB in another shape, 4
+rows x 32 heads of **128 on a state of 256** in **two** groups (the first
+call whose heads are as wide as a lane tile and whose state is two): the
+loop takes it as it takes any ``g``, ``p`` and ``n``, ``C B^T`` once a group
+for its 16 heads, and the carry's placement at exactly the kept size is read
+in the compiled text and on the chip by
+``benchmarks/tools/falcon_h1_mixer_check.py`` (PERF.md section 6, PR 66).
 ``scan_form`` reads that off the shapes alone and says which of two forms a
 program takes, and ``engine_inventory()`` shows it:
 
 - ``ssd_scan=chunked``: the loop over chunks, every row and head inside a
-  step. Wherever the batch's state is kept (Nemotron's step, MiniCPM-SALA's
-  lightning layers at 8 MiB, every toy), and wherever it is not but the
+  step. Wherever the batch's state is kept (Nemotron's step, Falcon-H1's,
+  MiniCPM-SALA's lightning layers at 8 MiB, every toy), and wherever it is not but the
   kernel cannot run (a process with several devices, the CPU, several
   groups, a ragged sequence, heads that do not fill the kernel's blocks).
 - ``ssd_scan=kernel-rows1-heads64`` (``ssd_kernel``; Granite's 8 rows x 128

@@ -38,6 +38,10 @@ EXPECTED = {
     # Mamba-2 blocks and one attention block, an expert layer in each; the
     # tied product lies under ``head``: no part of its own
     "granite_h_tiny": COMMON | MOE | {parts.MIX_SSD_SCAN},
+    # every block both mixers on one norm, then a feed-forward under a part
+    # of its own: ``proj`` is the mixers' projections alone
+    "falcon_h1_tiny": COMMON | {parts.MIX_SSD_SCAN, parts.MIX_ROPE,
+                                parts.FFN},
 }
 
 
@@ -109,8 +113,11 @@ def test_the_innermost_name_is_the_operations():
     assert parts.part_of(
         "jit(fwd)/mix.elementwise/mix.index_select/jit(_select_kernel_row)/"
         "pallas_call") == parts.MIX_INDEX_SELECT
+    # a feed-forward that names itself: its loop over rows is ``ffn``'s
+    assert parts.part_of(
+        "jit(fwd)/ffn/while/body/closed_call/dot_general") == parts.FFN
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 19
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 20
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():
