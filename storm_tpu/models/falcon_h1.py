@@ -54,6 +54,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from storm_tpu.models import scorer as S
 from storm_tpu.models.minicpm_sala import _rows
@@ -63,6 +64,7 @@ from storm_tpu.models.registry import ModelDef, register
 from storm_tpu.models.scorer import _proj
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
+from storm_tpu.ops import platform
 from storm_tpu.ops import rope as R
 from storm_tpu.ops.attention import causal_attention_merged
 
@@ -141,11 +143,31 @@ def parallel_mixer(p: dict, y: jnp.ndarray, rotary: tuple,
 
 def gated_ffn(p: dict, x: jnp.ndarray, gate_multiplier: float) -> jnp.ndarray:
     """``W_down(W_up x * SiLU(gate_multiplier W_gate x))``: the gate's scalar,
-    the activation and the product in float32, one rounding."""
+    the activation and the product in float32, one rounding.
+
+    The rounded product ``h`` stands behind one ``optimization_barrier``, so
+    that the chip's compiler makes it once an element, on the way out of
+    whichever of the two products before it runs second, and the down
+    product reads a bare operand. Left alone, the compiler holds the float32
+    scalar, SiLU and product inside the down product's fusion, on the way
+    *in*, where an operand is made again for every tile of the product's
+    columns. Compiled for a described v5e at 16,384 x 5,120 x 21,504
+    (tests/test_tpu_compile.py), in millions of estimated cycles a row:
+    32.60 + 32.60 + 42.53 = 107.7 left alone, 32.60 + 36.41 + 32.34 = 101.4
+    behind the barrier; on the chip 62.99 and 60.02 ms a row (PERF.md
+    section 6, PR 67). This is not a barrier at every bfloat16 boundary,
+    which cuts fusions the compiler has right (ROADMAP Speed 1 (a)): it is
+    one, between products of 3.6 TFLOP each. The formula is the same, and
+    so is every bit wherever nothing is fused (tests/test_falcon_h1.py);
+    on the chip the product that carries the activation hands it float32
+    sums the compiler no longer rounds to bfloat16 on the way (XLA's excess
+    precision, as under every ``swiglu``). ``ops/layers.py swiglu`` needs
+    no barrier: its bfloat16 activation already rides out of a product."""
     f32 = jnp.float32
+    platform.note("gated_ffn", "made-once")
     gate = jax.nn.silu(L.matmul(x, p["gate"]).astype(f32) * gate_multiplier)
-    return L.matmul((gate * L.matmul(x, p["up"]).astype(f32)).astype(x.dtype),
-                    p["down"])
+    h = (gate * L.matmul(x, p["up"]).astype(f32)).astype(x.dtype)
+    return L.matmul(lax.optimization_barrier(h), p["down"])
 
 
 def build_falcon_h1(

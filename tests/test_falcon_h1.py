@@ -326,6 +326,40 @@ def test_the_feed_forwards_two_scalars():
     assert ratio.std() > 1e-2
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_product_made_once_is_the_same_feed_forward_to_the_bit(dtype):
+    """``gated_ffn`` holds its rounded product behind one barrier, so that a
+    chip's compiler makes it on the way out of a product
+    (tests/test_tpu_compile.py pins where): the same float32 scalar,
+    activation and product from the same two rounded results, rounded once,
+    so here, where no product carries the activation, every bit of the
+    formula without the barrier (a chip's product hands the activation
+    float32 sums the compiler no longer rounds: PERF.md section 6, PR 67);
+    and the engine's inventory can say the form engaged."""
+    p = jax.tree.map(lambda a: a.astype(dtype),
+                     L.swiglu_init(jax.random.PRNGKey(9), DIM, 72))
+    x = jax.random.normal(jax.random.PRNGKey(10), (2, SEQ, DIM)).astype(dtype)
+    gate = SIZES["mlp_multipliers"][0]
+
+    def unbarriered(p, x):
+        f32 = jnp.float32
+        act = jax.nn.silu(L.matmul(x, p["gate"]).astype(f32) * gate)
+        return L.matmul((act * L.matmul(x, p["up"]).astype(f32)).astype(
+            x.dtype), p["down"])
+
+    made_once = jax.jit(lambda p, x: FH.gated_ffn(p, x, gate))
+    with dispatch_notes() as seen:
+        got = made_once(p, x)
+    want = jax.jit(unbarriered)(p, x)
+    assert seen == ["gated_ffn=made-once"]
+    assert got.dtype == want.dtype == dtype
+    assert np.array_equal(np.asarray(got.astype(jnp.float32)),
+                          np.asarray(want.astype(jnp.float32)))
+    assert float(jnp.abs(got.astype(jnp.float32)).max()) > 0
+    assert made_once.lower(p, x).as_text().count("optimization_barrier") == 1
+
+
 def test_the_draw_stands_each_matrix_over_its_scalar():
     """Every projection's result is what a LeCun matrix gives without a
     scalar: unit deviation a channel for a unit input (the branch outputs
@@ -477,7 +511,8 @@ def test_the_inventory_names_the_forms():
     forms = row["programs"][str(eng.pad_batch(4))].split(", ")
     assert set(forms) == {"short_conv=xla", "ssd_scan=chunked",
                           "rotary_turn=halves",
-                          "causal_attention=blocked-grouped"}
+                          "causal_attention=blocked-grouped",
+                          "gated_ffn=made-once"}
     handle = eng.dispatch((_windows(4),))
     handle.future.result(60)
     assert not handle.aux
@@ -568,8 +603,11 @@ def test_registry_names_the_model_and_its_cut():
 # at trace time; ``causal_tiles`` is not reached off a chip). The tenth's, as
 # this PR built it: the first 16 hex digits of the sha256 of the lowered
 # text, of the tree ``init`` makes and, for the toy, of its leaves from key 7.
-FALCON = {"falcon_h1_tiny": ('27b99fb825d423be', 'df3bed545372b86b', 'c0487694e568148f'),
-          "falcon_h1_34b": ('d026afe248613b33', 'e267b4d3131c7b2d')}
+# PR 67 moved the two texts' digests and nothing else: ``gated_ffn``'s one
+# ``optimization_barrier`` is an operation of the lowered text; the trees and
+# the leaves are what they were.
+FALCON = {"falcon_h1_tiny": ('a143c56128b6262a', 'df3bed545372b86b', 'c0487694e568148f'),
+          "falcon_h1_34b": ('6431f1c2676b5df6', 'e267b4d3131c7b2d')}
 
 
 def _digest(*chunks):

@@ -976,3 +976,52 @@ def test_eva_attention_kernel_compiles_where_summaries_are_not_whole_blocks(
         window=2048, chunk=16, scale=128 ** -0.5).compile().as_text()
     assert "tpu_custom_call" in text
     assert (" pad(" in text) == (summaries % ea.eva_tiles(2048, 16)[2] != 0)
+
+
+def test_falcon_h1s_feed_forward_makes_its_product_once_on_the_way_out_of_a_product(
+        v5e):
+    """Falcon-H1's feed-forward at its cell's step, 4 rows of 16,384 x 5,120
+    into 21,504, a row a trip of one loop, as one chip builds it. The
+    placement, not a time: the fusion that holds the down product takes the
+    rounded product of gate and up as its one operand of a row's width (the
+    parent's took the gate's and the up's results, both, and made the
+    float32 scalar, SiLU and product again for every tile of its columns:
+    estimated at 42.53 M cycles beside 32.60 M a bare product, 107.7 M a
+    row); of the two products before it, whichever the compiler runs second
+    takes the first's result and writes that operand, every element once
+    (36.41 M, and 32.34 M for the down product that is now bare: 101.4 M a
+    row); the first takes nothing of a row's width. A row holds two results
+    of 0.70 GB at most, as before, and the product may stand where the
+    first's result stood."""
+    import re
+
+    from storm_tpu.models.falcon_h1 import gated_ffn
+    from storm_tpu.models.minicpm_sala import _rows
+    from storm_tpu.ops.platform import dispatch_notes
+
+    rows, seq, dim, width = 4, 16384, 5120, 21504
+    p = {"gate": _spec((dim, width), jnp.bfloat16, v5e),
+         "up": _spec((dim, width), jnp.bfloat16, v5e),
+         "down": _spec((width, dim), jnp.bfloat16, v5e)}
+    x = _spec((rows, seq, dim), jnp.bfloat16, v5e)
+    with dispatch_notes() as seen:
+        compiled = jax.jit(lambda p, x: _rows(
+            lambda row: gated_ffn(p, row, 0.5), x)).lower(p, x).compile()
+    assert seen == ["gated_ffn=made-once"]
+    text = compiled.as_text()
+    assert len(_loops(text)) == 1
+    wide = re.compile(r"bf16\[(?:1,)*%d,%d\]" % (seq, width))
+    # every fused computation that holds a product: how many of its
+    # parameters are a row's width, and whether its result is
+    products = [(len(wide.findall(params)), bool(wide.fullmatch(result)))
+                for params, result, body in re.findall(
+                    r"^%\S+ \(([^\n]*)\) -> (\S+) \{\n(.*?)^\}", text,
+                    re.M | re.S) if " convolution(" in body or " dot(" in body]
+    # (0, True): one of gate and up, bare; (1, True): the other, which takes
+    # the first's result and writes the product; (1, False): the down
+    # product, which takes the product alone
+    assert sorted(products) == [(0, True), (1, False), (1, True)]
+    # the first's result and the product, one after the other or one in the
+    # other's place; never a third beside them
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2.05 * seq * width * 2
