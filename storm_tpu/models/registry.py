@@ -81,6 +81,7 @@ def _load_builtin() -> None:
         kimi_k2,
         kimi_linear,
         lenet,
+        lfm2,
         longseq,
         minicpm_sala,
         mixer,

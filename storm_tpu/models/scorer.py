@@ -87,23 +87,25 @@ class Branch(NamedTuple):
 def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
             first_expert: int, scale: float, tile: Optional[int] = None,
             post: Optional[str] = None, post_scale: float = 1.0,
-            router: str = "sigmoid") -> Branch:
+            router: str = "sigmoid", eps: float = 1e-20) -> Branch:
     """The dropless top-k expert layer (parallel/moe.py) as a branch, its
     scores the ``router``'s function of the logits (``"sigmoid"``, or
-    ``"softmax"`` over the router's width), with the selection bias and the
-    shared expert that ``init``'s tree has: it routes from the float32 norm,
-    names its own parts and counts the tokens of each held expert and the
-    assignments that fell on absent ones. The counts' reader is told the
-    router's width (read off ``init``'s shapes) and ``tile`` as the layer is:
-    None, and both take the tile from the step's shapes (``parallel/moe.py
-    run_tile``); a number, the toy presets' tile of 16 rows."""
+    ``"softmax"`` over the router's width; ``eps`` beside the chosen
+    scores' sum: parallel/moe.py ``route_topk``), with the selection bias
+    and the shared expert that ``init``'s tree has: it routes from the
+    float32 norm, names its own parts and counts the tokens of each held
+    expert and the assignments that fell on absent ones. The counts' reader
+    is told the router's width (read off ``init``'s shapes) and ``tile`` as
+    the layer is: None, and both take the tile from the step's shapes
+    (``parallel/moe.py run_tile``); a number, the toy presets' tile of 16
+    rows."""
     width = jax.eval_shape(init, jax.ShapeDtypeStruct(
         (2,), jnp.uint32))["router"].shape[1]
     return Branch(
         norm, name, init,
         lambda p, y, _: topk_moe_layer(
             p, y, top_k, first_expert=first_expert, router=router,
-            renormalize=True, scale=scale, tile=tile),
+            renormalize=True, scale=scale, tile=tile, eps=eps),
         scope=None, cast=None,
         counts=(("expert_tokens", (held,)), ("expert_absent", ())),
         observe=partial(observe_expert_counts, tile=tile, width=width),

@@ -300,11 +300,17 @@ def merged_form(hq: int, hkv: int, s: int, dk: int, dv: int) -> str:
     """:func:`causal_form` for heads that lie merged ``(B, S, H * D)``:
     ``"kernel"`` by its rule where a head is whole lane tiles besides (a
     head is then a block of lanes to the kernel's block specs; 192 lanes
-    are a tile and a half, which no block can begin at), ``"blocked"``
+    are a tile and a half, which no block can begin at), or half a tile
+    with the key heads paired off (a block of lanes is then a tile's two
+    key heads, which the kernel reads as one of twice the width under twice
+    the group: ops/flash_attention.py ``heads_a_lane_tile``), ``"blocked"``
     elsewhere."""
-    if dk % 128 or dv % 128:
+    from storm_tpu.ops.flash_attention import heads_a_lane_tile
+
+    per = heads_a_lane_tile(dk, dv, hkv)
+    if per * dk % 128 or per * dv % 128:
         return "blocked"
-    return causal_form(hq, hkv, s, dk, dv)
+    return causal_form(hq, hkv // per, s, per * dk, per * dv)
 
 
 def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -318,7 +324,8 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     and the same note, by :func:`merged_form`: the kernel reads a head as a
     block of lanes of the merged arrays and writes the result so
     (ops/flash_attention.py ``flash_attention_merged``; the note ends
-    ``-merged``), and no array is transposed to ``(B, H, S, D)`` and back, a
+    ``-merged``, or ``-merged-halves`` where two heads of 64 are a block),
+    and no array is transposed to ``(B, H, S, D)`` and back, a
     copy of each on a TPU; elsewhere :func:`causal_blocked` on that view, the
     CPU's and the several-chips' path as it was. A caller chooses this entry
     by what it holds; the head-split one is untouched."""
@@ -342,11 +349,11 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 (q, heads), (k, kv_heads), (v, kv_heads))),
             scale, block, window)
         return merge_heads(out)
-    _note(name, form + grouped + "-merged")
-
     from storm_tpu.ops import flash_attention as F
 
-    block_q, block_k = F.causal_tiles(heads // kv_heads)
+    per = F.heads_a_lane_tile(dk, dv, kv_heads)
+    _note(name, form + grouped + "-merged" + ("-halves" if per > 1 else ""))
+    block_q, block_k = F.causal_tiles(per * heads // kv_heads)
     with jax.named_scope(_loop_part(window)):
         # a row's call writes its row of the result where it lies
         return jax.lax.fori_loop(

@@ -311,6 +311,52 @@ def _merged_kernel(row_ref, q_ref, k_ref, v_ref, _, o_ref, qs_ref, os_ref,
         o_ref[0, :, i * dv:(i + 1) * dv] = os_ref[0, i]
 
 
+def heads_a_lane_tile(dk: int, dv: int, kv_heads: int) -> int:
+    """How many key heads one lane tile of merged k and v holds, to the
+    merged entry: 2 where a head is half a tile in both widths and the key
+    heads pair off (:func:`_halves_kernel`), else 1 (a head is read as its
+    own block of lanes, which ops/attention.py ``merged_form`` grants whole
+    tiles alone)."""
+    return 2 if 2 * dk == _LANE == 2 * dv and kv_heads % 2 == 0 else 1
+
+
+def _halves_kernel(row_ref, q_ref, k_ref, v_ref, _, o_ref, qs_ref, os_ref,
+                   **kw):
+    """:func:`_merged_kernel` for heads of half a lane tile: a block of k and
+    of v is one lane tile, **two** key heads side by side, and the query tile
+    ``(1, BQ, 2 G * 64)`` the ``2 G`` query heads that read them, two a lane
+    tile. A query head is stacked as a whole tile with its 64 channels in
+    the half where its key head lies in k's tile (moved there by one
+    rotation of the lanes where it lay in the other) and zeros in the other
+    half, so that the 128-deep product with k's tile is its product with its
+    own key head alone: the matrix unit's rows are 128 deep whatever is in
+    them, so the zeros cost no pass that 64 channels would have spared. The
+    value product gives both key heads' results side by side, and a head's
+    own half is rotated to where the head lies in the result's tile. The
+    loop over key blocks is :func:`_attn_kernel`'s, on ``2 G`` stacked heads
+    of 128. ``os_ref`` is float32: the rotations run on whole 32-bit
+    tiles."""
+    f32 = jnp.float32
+    n, bq, lanes = qs_ref.shape[1:]
+    half, g = lanes // 2, n // 2
+    low = lax.broadcasted_iota(jnp.int32, (bq, lanes), 1) < half
+    for i in range(n):
+        tile = q_ref[0, :, i // 2 * lanes:(i // 2 + 1) * lanes].astype(f32)
+        if i % 2 != i // g:  # its key head lies in the other half
+            tile = pltpu.roll(tile, half, 1)
+        qs_ref[0, i] = jnp.where(low == (i < g), tile, 0.0).astype(
+            qs_ref.dtype)
+    _attn_kernel(row_ref, qs_ref, k_ref, v_ref, os_ref, **kw)
+    for t in range(g):
+        first, second = os_ref[0, 2 * t], os_ref[0, 2 * t + 1]
+        if 2 * t >= g:
+            first = pltpu.roll(first, half, 1)
+        if 2 * t + 1 < g:
+            second = pltpu.roll(second, half, 1)
+        o_ref[0, :, t * lanes:(t + 1) * lanes] = jnp.where(
+            low, first, second).astype(o_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "heads", "kv_heads", "scale", "block_q", "block_k", "interpret", "window"))
 def flash_attention_merged(
@@ -343,7 +389,11 @@ def flash_attention_merged(
     grid is (key head, query tile) and the kernel the head-split entry's
     (:func:`_merged_kernel`). Positions are padded to whole blocks; a head's
     width is read as it lies, so on a TPU it is whole lane tiles
-    (ops/attention.py ``merged_form`` sends other widths elsewhere)."""
+    (ops/attention.py ``merged_form`` sends other widths elsewhere), or half
+    of one with the key heads paired off (:func:`heads_a_lane_tile`): a
+    block is then a lane tile's two key heads with the ``2 G`` query heads
+    that read them, the grid (pair of key heads, query tile), the kernel
+    :func:`_halves_kernel`, and nothing is padded or copied in HBM."""
     s = q.shape[1]
     dk, dv = k.shape[2] // kv_heads, v.shape[2] // kv_heads
     if heads % kv_heads or q.shape[2] != heads * dk:
@@ -354,30 +404,36 @@ def flash_attention_merged(
     g = heads // kv_heads
     if scale is None:
         scale = dk**-0.5
+    # a grid step reads the ``per`` key heads of one block of lanes and the
+    # ``per * g`` query heads that read them
+    per = heads_a_lane_tile(dk, dv, kv_heads)
+    kernel = _merged_kernel if per == 1 else _halves_kernel
     block_q = min(block_q, max(_LANE, 1 << (s - 1).bit_length()))
     bk = min(block_k, max(_LANE, 1 << (s - 1).bit_length()))
     qp, buf = _pad_to(q, 1, block_q), _pad_to(out, 1, block_q)
     kp, vp = _pad_to(k, 1, bk), _pad_to(v, 1, bk)
 
-    def tile(width):  # of key head ``h``'s lanes, in the row read
+    def tile(width):  # of block ``h``'s lanes, in the row read
         return pl.BlockSpec((1, block_q, width),
                             lambda h, qi, r: (r[0], qi, h))
 
     return pl.pallas_call(
-        functools.partial(_merged_kernel, scale=scale, s_valid=s, block_k=bk,
+        functools.partial(kernel, scale=scale, s_valid=s, block_k=bk,
                           causal=True, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(kv_heads, qp.shape[1] // block_q),
-            in_specs=[tile(g * dk),
-                      pl.BlockSpec((1, kp.shape[1], dk),
+            grid=(kv_heads // per, qp.shape[1] // block_q),
+            in_specs=[tile(per * g * dk),
+                      pl.BlockSpec((1, kp.shape[1], per * dk),
                                    lambda h, qi, r: (r[0], 0, h)),
-                      pl.BlockSpec((1, vp.shape[1], dv),
+                      pl.BlockSpec((1, vp.shape[1], per * dv),
                                    lambda h, qi, r: (r[0], 0, h)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=tile(g * dv),
-            scratch_shapes=[pltpu.VMEM((1, g, block_q, dk), q.dtype),
-                            pltpu.VMEM((1, g, block_q, dv), q.dtype)]),
+            out_specs=tile(per * g * dv),
+            scratch_shapes=[
+                pltpu.VMEM((1, per * g, block_q, per * dk), q.dtype),
+                pltpu.VMEM((1, per * g, block_q, per * dv),
+                           q.dtype if per == 1 else jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         # the result is its buffer (operands count from the row's index)
         input_output_aliases={4: 0},

@@ -179,15 +179,19 @@ def conv_form(channels: int, seq: int, width: int) -> str:
     return "xla"
 
 
-def _conv_kernel(*refs, rows: int, step: int, bias: bool, activation):
+def _conv_kernel(*refs, rows: int, step: int, bias: bool, activation,
+                 gated: bool = False):
     """One block of positions by one block of lanes of one row of the batch.
     ``wide`` is float32 room for the block under ``_CONV_CARRY`` rows of
     what came before it: the block is widened into it once, and a tap's
     operand is that room read ``j`` rows up. The head rows are zero at a
     row's first block and the previous block's last rows after it (the grid
-    walks a row's positions innermost, in order)."""
+    walks a row's positions innermost, in order). ``gated``: two more blocks
+    of the same positions and lanes, one the input is multiplied by before
+    the taps and one the result after them, in float32."""
     x_ref, w_ref = refs[:2]
     b_ref = refs[2] if bias else None
+    in_ref, out_ref = refs[-4:-2] if gated else (None, None)
     o_ref, wide = refs[-2:]
     head = _CONV_CARRY
     width = w_ref.shape[0]
@@ -199,6 +203,8 @@ def _conv_kernel(*refs, rows: int, step: int, bias: bool, activation):
     taps = [w_ref[j:j + 1, :] for j in range(width)]
     for lo in range(0, rows, step):
         xf = x_ref[lo:lo + step, :].astype(jnp.float32)
+        if gated:
+            xf = xf * in_ref[lo:lo + step, :].astype(jnp.float32)
         wide[head + lo:head + lo + step] = xf
         back = head + lo - (width - 1)
         terms = [taps[j] * wide[back + j:back + j + step]
@@ -206,12 +212,16 @@ def _conv_kernel(*refs, rows: int, step: int, bias: bool, activation):
         y = sum(terms[1:], terms[0])  # short_conv's order: the oldest first
         if bias:
             y = y + b_ref[...]
-        o_ref[lo:lo + step, :] = activation(y).astype(o_ref.dtype)
+        y = activation(y)
+        if gated:
+            y = y * out_ref[lo:lo + step, :].astype(jnp.float32)
+        o_ref[lo:lo + step, :] = y.astype(o_ref.dtype)
     wide[:head] = wide[rows:]
 
 
-def conv_silu_kernel(w, b, x, *, activation=jax.nn.silu, rows: int = None,
-                     step: int = None, interpret: bool = False):
+def conv_silu_kernel(w, b, x, *, activation=jax.nn.silu, gates=None,
+                     rows: int = None, step: int = None,
+                     interpret: bool = False):
     """``activation(short_conv({"w": w, "b": b}, x[..., :C]))`` for ``w:
     (width, C)``, ``b: (C,)`` or None and ``x: (B, S, wide)``, ``wide >=
     C``, as one Pallas TPU call that reads those columns once, in their own
@@ -223,22 +233,36 @@ def conv_silu_kernel(w, b, x, *, activation=jax.nn.silu, rows: int = None,
     nothing crosses from one row to the next). ``C`` is whole lane tiles
     (``wide`` need not be), ``S`` a multiple of ``rows`` and ``rows`` of
     ``step``, the positions widened and convolved at a time (what stays in
-    registers between a tap and the rounding)."""
+    registers between a tap and the rounding). ``gates`` ``(before, after)``:
+    the first columns in ``x`` (whole lane tiles) of two more ranges of ``C``
+    columns, ``activation(short_conv(x[..., :C] * x[..., before:before +
+    C])) * x[..., after:after + C]``, each read once beside the first, the
+    products in float32 (:func:`gated_conv`)."""
     width, channels = w.shape
     bsz, seq, _ = x.shape
     lanes = _CONV_LANES
     rows = rows or math.gcd(seq, _CONV_ROWS_MOST)
     step = step or min(rows, _CONV_STEP)
     f32 = jnp.float32
+
+    def columns(first):  # a block of the range of ``x`` that begins there
+        at = first // lanes
+        return pl.BlockSpec((None, rows, lanes), lambda i, c, s: (
+            i, s, c + at if at else c))
+
     operands = [x, w.astype(f32)]
-    specs = [pl.BlockSpec((None, rows, lanes), lambda i, c, s: (i, s, c)),
+    specs = [columns(0),
              pl.BlockSpec((width, lanes), lambda i, c, s: (0, c))]
     if b is not None:
         operands.append(b.astype(f32).reshape(1, channels))
         specs.append(pl.BlockSpec((1, lanes), lambda i, c, s: (0, c)))
+    for first in gates or ():
+        operands.append(x)
+        specs.append(columns(first))
     return pl.pallas_call(
         functools.partial(_conv_kernel, rows=rows, step=step,
-                          bias=b is not None, activation=activation),
+                          bias=b is not None, activation=activation,
+                          gated=gates is not None),
         grid=(bsz, channels // lanes, seq // rows),
         in_specs=specs,
         out_specs=pl.BlockSpec((None, rows, lanes),
@@ -262,6 +286,31 @@ def conv_silu(p: dict, x: jnp.ndarray,
     if form == "kernel":
         return conv_silu_kernel(p["w"], p.get("b"), x, activation=activation)
     return activation(short_conv(p, x[..., :channels]))
+
+
+def _as_is(y):
+    return y
+
+
+def gated_conv(p: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """The gated short convolution of a projection's result ``x: (B, S, 3
+    C)`` = ``[b | c | u]``, three ranges of the ``C`` channels the kernel
+    ``p`` has: ``c_t * short_conv(b * u)_t`` with no activation, ``(B, S,
+    C)`` in ``x``'s type. Both products and the taps in float32, one
+    rounding out, in either form of :func:`conv_form`'s rule: ``"kernel"``,
+    ``conv_silu_kernel`` with its two ``gates`` (the three ranges read once
+    each where they lie, nothing between them written), or ``"xla"``, the
+    same arithmetic as XLA fuses it. Noted ``gated_conv=<form>``."""
+    width, channels = p["w"].shape
+    form = conv_form(channels, x.shape[1], width)
+    _note("gated_conv", form)
+    if form == "kernel":
+        return conv_silu_kernel(p["w"], p.get("b"), x, activation=_as_is,
+                                gates=(2 * channels, channels))
+    f32 = jnp.float32
+    b, c, u = (x[..., i * channels:(i + 1) * channels].astype(f32)
+               for i in range(3))
+    return (c * short_conv(p, b * u)).astype(x.dtype)
 
 
 def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:

@@ -22,6 +22,9 @@ FFN = "ffn"  # a block's own dense feed-forward, its products and its gate,
 # ``proj`` is then the mixers' projections alone; the plans before it keep
 # their feed-forward under ``proj``)
 MIX_ELEMENTWISE = "mix.elementwise"  # conv, gates, norms, re-tiling, layout
+MIX_GATED_CONV = "mix.gated_conv"  # ops/kda.py gated_conv: a gated short
+# convolution's product, taps and second gate, where they are the mixer
+# (models/lfm2.py); its two projections stay ``proj``
 MIX_KDA_TABLES = "mix.kda_tables"  # ops/kda.py: the within-chunk tables
 MIX_KDA_SCAN = "mix.kda_scan"  # ops/kda.py: the loop over chunks
 MIX_SSD_SCAN = "mix.ssd_scan"  # ops/ssd.py: the loop over chunks
@@ -48,7 +51,7 @@ VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,
               MOE_EXPERTS, MOE_COMBINE, MIX_SPARSE_SELECT,
               MIX_SPARSE_ATTENTION, MIX_EVA_CHUNKS, MIX_EVA_ATTENTION,
-              MIX_WINDOW_ATTENTION, MIX_INDEX_SELECT, FFN)
+              MIX_WINDOW_ATTENTION, MIX_INDEX_SELECT, FFN, MIX_GATED_CONV)
 
 
 def part_of(op_name: str):

@@ -26,7 +26,9 @@ shuffled across lanes, and no weight after the load.
 lane tiles can be turned where they lie in a projection's ``(B, S, H * D)``
 (:func:`turn_merged`): the view ``(B, S, H, D)`` is another tiling on a TPU
 and half a head another still, each a copy of the whole array. A head's
-halves change places by one rotation of its 128 lanes.
+halves change places by one rotation of its 128 lanes; heads of 64 lie two
+to a lane tile, their halves its quarters, and each lane takes its partner
+from one of two rotations of the tile.
 """
 
 from __future__ import annotations
@@ -135,19 +137,47 @@ def rotate_halves(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
 
 
 _TURN_TILE = 128  # positions a step of the kernel: 1 MB of 4,096 lanes
+_LANES = 128  # a lane tile
 
 
-def turn_form(s: int, d: int) -> str:
+def turn_form(s: int, d: int, heads: int = 1) -> str:
     """Which form :func:`turn_merged` is built with: ``"lanes"`` (the Pallas
     kernel) on a TPU in a process with one device (a Mosaic call has no
     partitioning rule, ops/platform.py ``one_device``), for whole tiles of
     positions and heads of one lane tile, whose halves one rotation of the
-    lanes exchanges; ``"halves"`` (:func:`rotate_halves` on the view a head)
-    elsewhere."""
+    lanes exchanges, or of half a lane tile where the ``heads`` of them (as
+    the caller says: one, unsaid) are whole tiles (two heads a tile:
+    :func:`_turned`); ``"halves"`` (:func:`rotate_halves` on the view a
+    head) elsewhere."""
     if (_use_pallas() and _one_device() and s % _TURN_TILE == 0
-            and d == 128):
+            and (d == _LANES or (2 * d == _LANES and heads % 2 == 0))):
         return "lanes"
     return "halves"
+
+
+def _lane_tables(cos, sin) -> tuple:
+    """The kernels' tables from ``(cos, sin): (S, d / 2)``: a head's lanes
+    against ``(cos, cos)`` and its exchanged halves against ``(-sin, sin)``,
+    over a lane tile (once where a head is one, twice where two heads are)."""
+    times = max(_LANES // (2 * cos.shape[-1]), 1)
+    return (jnp.concatenate([cos, cos] * times, -1),
+            jnp.concatenate([-sin, sin] * times, -1))
+
+
+def _turned(y, cos, sin, d: int):
+    """A lane tile ``y: (positions, 128)`` of heads of ``d`` lanes turned
+    against :func:`_lane_tables`' tables, in float32. A head of 128: its
+    halves change places by one rotation of the tile. Heads of 64, two a
+    tile: a lane's partner lies a quarter of the tile up in a head's first
+    half and a quarter down in its second, so two rotations and a choice by
+    the lane."""
+    if d == _LANES:
+        return y * cos + pltpu.roll(y, d // 2, 1) * sin
+    lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    partner = jnp.where(lane % d < d // 2,
+                        pltpu.roll(y, _LANES - d // 2, 1),
+                        pltpu.roll(y, d // 2, 1))
+    return y * cos + partner * sin
 
 
 def turn_merged(xs: tuple, cos: jnp.ndarray, sin: jnp.ndarray,
@@ -159,44 +189,39 @@ def turn_merged(xs: tuple, cos: jnp.ndarray, sin: jnp.ndarray,
     (a mixer's queries and keys)."""
     b, s, merged = xs[0].shape
     d = merged // heads
-    form = turn_form(s, d)
+    form = turn_form(s, d, heads)
     _note("rotary_turn", form)
     if form == "halves":
         return tuple(rotate_halves(x.reshape(b, s, heads, d), cos[:, None],
                                    sin[:, None]).reshape(b, s, merged)
                      for x in xs)
     with jax.named_scope(P.MIX_ROPE):
-        # a head's lanes against (cos, cos) and its exchanged halves against
-        # (-sin, sin)
-        return _turn_lanes(tuple(xs), jnp.concatenate([cos, cos], -1),
-                           jnp.concatenate([-sin, sin], -1), heads=heads)
+        return _turn_lanes(tuple(xs), *_lane_tables(cos, sin), heads=heads)
 
 
-def _turn_kernel(cos_ref, sin_ref, *refs, heads):
+def _turn_kernel(cos_ref, sin_ref, *refs, d):
     ins, outs = refs[:len(refs) // 2], refs[len(refs) // 2:]
-    d = cos_ref.shape[1]
+    wide = cos_ref.shape[1]
     cos, sin = cos_ref[...], sin_ref[...]
     for x_ref, o_ref in zip(ins, outs):
-        for h in range(heads):
-            lanes = pl.ds(h * d, d)
+        for at in range(0, x_ref.shape[2], wide):
+            lanes = pl.ds(at, wide)
             x = x_ref[0, :, lanes].astype(jnp.float32)
-            o_ref[0, :, lanes] = (
-                x * cos + pltpu.roll(x, d // 2, 1) * sin).astype(o_ref.dtype)
+            o_ref[0, :, lanes] = _turned(x, cos, sin, d).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "tile", "interpret"))
 def _turn_lanes(xs, cos2, sin2, *, heads, tile=_TURN_TILE, interpret=False):
     b, s, merged = xs[0].shape
-    d = merged // heads
 
     def table():
-        return pl.BlockSpec((tile, d), lambda r, i: (i, 0))
+        return pl.BlockSpec((tile, cos2.shape[1]), lambda r, i: (i, 0))
 
     def wide():
         return pl.BlockSpec((1, tile, merged), lambda r, i: (r, i, 0))
 
     return tuple(pl.pallas_call(
-        functools.partial(_turn_kernel, heads=heads),
+        functools.partial(_turn_kernel, d=merged // heads),
         grid=(b, s // tile),
         in_specs=[table(), table()] + [wide() for _ in xs],
         out_specs=[wide() for _ in xs],
@@ -224,43 +249,54 @@ def norm_turn_merged(p: dict, x: jnp.ndarray, heads: int, eps: float,
     form says nothing new: it is ``rmsnorm``'s arithmetic, and the CPU's
     notes are held as they were by tests/benchmark/)."""
     d = x.shape[2] // heads
-    if turn_form(x.shape[1], d) != "lanes":
+    if turn_form(x.shape[1], d, heads) != "lanes":
         from storm_tpu.ops.kda import rmsnorm_heads
 
         y = rmsnorm_heads(p, x, heads, eps)
         return y if rotary is None else turn_merged((y,), *rotary, heads)[0]
     _note("head_norm", "kernel")
-    scale = p["scale"].astype(jnp.float32).reshape(1, d)
+    # the one scale over a lane tile: once, or twice where two heads lie in it
+    scale = jnp.tile(p["scale"].astype(jnp.float32),
+                     max(_LANES // d, 1)).reshape(1, -1)
     if rotary is None:
         return _norm_turn_lanes(x, scale, heads=heads, eps=eps)
     _note("rotary_turn", "lanes")
-    cos, sin = rotary
     with jax.named_scope(P.MIX_ROPE):
-        return _norm_turn_lanes(
-            x, scale, jnp.concatenate([cos, cos], -1),
-            jnp.concatenate([-sin, sin], -1), heads=heads, eps=eps)
+        return _norm_turn_lanes(x, scale, *_lane_tables(*rotary),
+                                heads=heads, eps=eps)
 
 
-def _norm_turn_kernel(scale_ref, *refs, heads, eps):
+def _head_mean_squares(x, d: int):
+    """The mean of ``x * x`` over each head's ``d`` lanes of a lane tile ``x:
+    (positions, 128)``, against ``x``: one number a position where the tile
+    is a head, and where it is two heads of 64 each half's own, laid over its
+    half."""
+    xx = x * x
+    if d == x.shape[1]:
+        return jnp.mean(xx, -1, keepdims=True)
+    first = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < d
+    return jnp.where(
+        first, jnp.sum(jnp.where(first, xx, 0.0), -1, keepdims=True),
+        jnp.sum(jnp.where(first, 0.0, xx), -1, keepdims=True)) / d
+
+
+def _norm_turn_kernel(scale_ref, *refs, d, eps):
     *tables, x_ref, o_ref = refs
-    d = scale_ref.shape[1]
+    wide = scale_ref.shape[1]
     scale = scale_ref[...]
-    for h in range(heads):
-        lanes = pl.ds(h * d, d)
+    for at in range(0, x_ref.shape[2], wide):
+        lanes = pl.ds(at, wide)
         x = x_ref[0, :, lanes].astype(jnp.float32)
-        y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-        if tables:
-            # a head's lanes against (cos, cos) and its exchanged halves
-            # against (-sin, sin), as ``_turn_kernel``
+        y = x * jax.lax.rsqrt(_head_mean_squares(x, d) + eps) * scale
+        if tables:  # as ``_turn_kernel``
             cos_ref, sin_ref = tables
-            y = y * cos_ref[...] + pltpu.roll(y, d // 2, 1) * sin_ref[...]
+            y = _turned(y, cos_ref[...], sin_ref[...], d)
         o_ref[0, :, lanes] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "eps", "interpret"))
 def _norm_turn_lanes(x, scale, *tables, heads, eps, interpret=False):
     b, s, merged = x.shape
-    d = merged // heads
     # two of the turn's tiles a step where the positions allow: the norm makes
     # a step of 128 positions compute longer (4.9 us) than its DMA (2.6), and
     # q's 32 heads read 2.61 ms a call at 128 positions, 1.83 at 256, the
@@ -268,10 +304,11 @@ def _norm_turn_lanes(x, scale, *tables, heads, eps, interpret=False):
     tile = 2 * _TURN_TILE if s % (2 * _TURN_TILE) == 0 else _TURN_TILE
     wide = pl.BlockSpec((1, tile, merged), lambda r, i: (r, i, 0))
     return pl.pallas_call(
-        functools.partial(_norm_turn_kernel, heads=heads, eps=eps),
+        functools.partial(_norm_turn_kernel, d=merged // heads, eps=eps),
         grid=(b, s // tile),
-        in_specs=[pl.BlockSpec((1, d), lambda r, i: (0, 0))]
-        + [pl.BlockSpec((tile, d), lambda r, i: (i, 0)) for _ in tables]
+        in_specs=[pl.BlockSpec(scale.shape, lambda r, i: (0, 0))]
+        + [pl.BlockSpec((tile, scale.shape[1]), lambda r, i: (i, 0))
+           for _ in tables]
         + [wide],
         out_specs=wide,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),

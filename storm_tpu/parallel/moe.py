@@ -167,10 +167,11 @@ def topk_moe_init(rng, dim: int, hidden: int, n_experts: int,
 
 def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
                router: str = "sigmoid", renormalize: bool = True,
-               scale: float = 1.0):
+               scale: float = 1.0, eps: float = 1e-20):
     """``(experts, weights)``, both ``(N, top_k)``: the ``top_k`` largest of
-    score + selection bias, weighted by the score alone (over their sum where
-    ``renormalize``) times ``scale``. Scores in float32 from a product at
+    score + selection bias, weighted by the score alone (over their sum plus
+    ``eps`` where ``renormalize``: a released model's own, ``1e-6`` in some)
+    times ``scale``. Scores in float32 from a product at
     ``highest`` precision: a tie broken the other way sends a token to
     another expert. A chosen expert's score is read by comparing its number
     with every column's and taking the largest of what matches (one term, and
@@ -193,7 +194,7 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
     weights = jnp.max(jnp.where(experts[..., None] == column,
                                 score[..., None, :], 0.0), axis=-1)
     if renormalize:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + eps)
     return experts, weights * scale
 
 
@@ -512,8 +513,11 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
 
 def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
                    router: str = "sigmoid", renormalize: bool = True,
-                   scale: float = 1.0, tile: Optional[int] = None):
-    """Dropless top-``top_k`` expert layer over ``(..., dim)`` activations.
+                   scale: float = 1.0, tile: Optional[int] = None,
+                   eps: float = 1e-20):
+    """Dropless top-``top_k`` expert layer over ``(..., dim)`` activations
+    (``router``, ``renormalize``, ``scale`` and ``eps`` are
+    :func:`route_topk`'s).
 
     The layer holds experts ``first_expert ..`` (as many as its stacked
     weights have) of the router's width, and returns ``(y, tokens, absent)``:
@@ -586,7 +590,7 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         # a rounded input breaks ties the other way); the experts compute in
         # the type of their weights
         experts, weights = route_topk(p, x.reshape(-1, dim), top_k, router,
-                                      renormalize, scale)
+                                      renormalize, scale, eps)
         x = x.astype(w["down"].dtype)
         tokens = x.reshape(-1, dim)
         n = tokens.shape[0]

@@ -42,6 +42,10 @@ EXPECTED = {
     # of its own: ``proj`` is the mixers' projections alone
     "falcon_h1_tiny": COMMON | {parts.MIX_SSD_SCAN, parts.MIX_ROPE,
                                 parts.FFN},
+    # gated short convolutions under a part of their own, one rotary
+    # attention layer with head norms; two dense layers' ``ffn``, then experts
+    "lfm2_tiny": COMMON | MOE | {parts.MIX_GATED_CONV, parts.MIX_ROPE,
+                                 parts.FFN},
 }
 
 
@@ -116,8 +120,12 @@ def test_the_innermost_name_is_the_operations():
     # a feed-forward that names itself: its loop over rows is ``ffn``'s
     assert parts.part_of(
         "jit(fwd)/ffn/while/body/closed_call/dot_general") == parts.FFN
+    # a convolution operator's own pass, inside the mixer's scope
+    assert parts.part_of(
+        "jit(fwd)/mix.elementwise/mix.gated_conv/pallas_call") == \
+        parts.MIX_GATED_CONV
     assert parts.part_of("jit(fwd)/jit(main)/reduce_sum") is None
-    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 20
+    assert len(set(parts.VOCABULARY)) == len(parts.VOCABULARY) == 21
 
 
 def test_under_the_compile_caches_settings_the_names_reach_the_compiled_program():

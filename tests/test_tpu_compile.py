@@ -1025,3 +1025,69 @@ def test_falcon_h1s_feed_forward_makes_its_product_once_on_the_way_out_of_a_prod
     # other's place; never a third beside them
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2.05 * seq * width * 2
+
+
+def test_lfm2s_two_operators_compile_with_their_kernels_at_heads_of_64(
+        v5e, monkeypatch):
+    """LFM2's two operators whole at their cell's step (8 windows of 4,096
+    into 2,048, bfloat16), as one chip builds them. The gated short
+    convolution: one kernel between its two projections, which reads the
+    three ranges of ``(8, 4096, 6144)`` where they lie (no slice of a range,
+    no float32 array of a range's size outside it). The attention at 32 query
+    heads on 8 key heads of 64: three kernels (the head norm and turn of q
+    and of k on a lane tile's two heads; the causal kernel on pairs of key
+    heads), the rows one ``while`` under ``mix.attention`` that carries q and
+    its result ``(8, 4096, 2048)`` and k and v ``(8, 4096, 512)`` as the
+    projections left them; no view a head, no padded copy to heads of 128 and
+    no float32 scores in HBM."""
+    import re
+
+    import storm_tpu.ops.attention as attention
+    import storm_tpu.ops.kda as kda
+    import storm_tpu.ops.rope as rope
+    from storm_tpu.models import lfm2
+    from storm_tpu.ops.platform import dispatch_notes
+
+    for module in (attention, rope, kda):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    x = _spec((8, 4096, 2048), jnp.bfloat16, v5e)
+
+    def served(init):
+        return jax.tree.map(lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+                            jax.eval_shape(init))
+
+    p = served(lambda: lfm2.conv_mixer_init(jax.random.PRNGKey(0), 2048, 3))
+    with dispatch_notes() as seen:
+        compiled = jax.jit(lfm2.conv_mixer).lower(p, x).compile()
+    assert seen == ["gated_conv=kernel"]
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and not _loops(text)
+    entry = text[text.index("ENTRY"):]  # what is written between fusions
+    assert "f32[8,4096,2048]" not in entry and "f32[8,4096,6144]" not in entry
+    assert not re.search(r"= \w+\[8,4096,2048\]\S* (slice|copy)\(", entry)
+    # the projection's result and the kernel's, nothing else of their size
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.05 * 8 * 4096 * (6144 + 2048) * 2
+
+    p = served(lambda: lfm2.attention_mixer_init(
+        jax.random.PRNGKey(0), 2048, 32, 8, 64))
+    tables = _spec((4096, 32), jnp.float32, v5e)
+    with dispatch_notes() as seen:
+        compiled = jax.jit(lambda p, x, cos, sin: lfm2.attention_mixer(
+            p, x, 32, 8, 64, 1e-5, (cos, sin))).lower(
+            p, x, tables, tables).compile()
+    assert seen == ["head_norm=kernel", "rotary_turn=lanes",
+                    "causal_attention=kernel-grouped-merged-halves"]
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    (loop,) = _loops(text)
+    assert "/mix.attention/while" in loop
+    assert loop.count("bf16[8,4096,2048]") >= 2
+    assert loop.count("bf16[8,4096,512]") >= 2
+    assert not re.search(
+        r"\[8,4096,(32|8),(64|128)\]|\[8,(32|8),4096,(64|128)\]", text)
+    entry = text[text.index("ENTRY"):]
+    assert "f32[8,4096,2048]" not in entry + loop
+    assert not re.search(r"f32\[\d+,\d+,4096\]", text)  # scores of a block
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4 * 2 ** 30
