@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from storm_tpu.models.registry import ModelDef
 from storm_tpu.ops import layers as L
 from storm_tpu.ops import parts as P
-from storm_tpu.parallel.moe import observe_expert_counts, topk_moe_layer
+from storm_tpu.parallel.moe import (observe_expert_counts,
+                                    topk_moe_layer_tiles)
 
 
 def _w(rng, fan_in: int, fan_out: int):
@@ -94,7 +95,8 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
     scores' sum: parallel/moe.py ``route_topk``), with the selection bias
     and the shared expert that ``init``'s tree has: it routes from the
     float32 norm, names its own parts and counts the tokens of each held
-    expert and the assignments that fell on absent ones. The counts' reader
+    expert, the assignments that fell on absent ones and the combine's tiles
+    that wrote and that added. The counts' reader
     is told the router's width (read off ``init``'s shapes) and ``tile`` as
     the layer is: None, and both take the tile from the step's shapes
     (``parallel/moe.py run_tile``); a number, the toy presets' tile of 16
@@ -103,11 +105,12 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
         (2,), jnp.uint32))["router"].shape[1]
     return Branch(
         norm, name, init,
-        lambda p, y, _: topk_moe_layer(
+        lambda p, y, _: topk_moe_layer_tiles(
             p, y, top_k, first_expert=first_expert, router=router,
             renormalize=True, scale=scale, tile=tile, eps=eps),
         scope=None, cast=None,
-        counts=(("expert_tokens", (held,)), ("expert_absent", ())),
+        counts=(("expert_tokens", (held,)), ("expert_absent", ()),
+                ("combine_tiles", (2,))),
         observe=partial(observe_expert_counts, tile=tile, width=width),
         post=post, post_scale=post_scale)
 

@@ -45,7 +45,7 @@ MIX_EVA_ATTENTION = "mix.eva_attention"  # ... the loop over rows: a window's
 MOE_ROUTE = "moe.route"  # router, top-k, the dispatch (one sort with its
 # payloads, a bisection for the counts, rows by comparison), zeroed buffer
 MOE_EXPERTS = "moe.experts"  # topk_moe_layer's loop over tiles
-MOE_COMBINE = "moe.combine"  # _combine_held: sort, zeroed sums, loop, last pass
+MOE_COMBINE = "moe.combine"  # _combine_held: sort, the loops, last pass
 
 VOCABULARY = (EMBED, HEAD, NORM, PROJ, MIX_ELEMENTWISE, MIX_KDA_TABLES,
               MIX_KDA_SCAN, MIX_SSD_SCAN, MIX_ATTENTION, MIX_ROPE, MOE_ROUTE,

@@ -28,6 +28,7 @@ whole width and computes the part of the result that its own experts give
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional
 
 import jax
@@ -203,9 +204,11 @@ def route_topk(p: dict, tokens: jnp.ndarray, top_k: int,
 # quarter and an eighth of the assignments held (PERF.md, PR 37), where a
 # block of 256 tokens holds 256 to 512 of them and is one tile: 256 x 512 and
 # 512 x 1,024 read the same, 512 x 512 and 128 x 256 a twentieth more (a tile
-# gathers its empty places too, and reads and writes its block's sums). Where
-# more is held the block is smaller, so that its expected run is still one
-# tile (:func:`_combine_held`: 64 tokens of 8 assignments each, all held).
+# gathers its empty places too, and then read and wrote its block's sums;
+# since PR 69 a block's first tile writes them, only a further one reads them
+# back and adds, and nothing zeroes them: :func:`_combine_held`). Where more is
+# held the block is smaller, so that its expected run is still one tile (64
+# tokens of 8 assignments each, all held; 96 of 10, half held).
 _COMBINE_BLOCK = 256
 _COMBINE_ROWS = 512
 
@@ -289,7 +292,9 @@ def _tiles_by_size(n_tiles, most: int, tile_at, sizes: tuple):
     ``most`` keys that carries the three along), so a run's whole tiles lie
     together and its last tile, where it is computed small, after all of
     those. ``ends[k]`` is where the ``k``-th of ``sizes`` ends among them.
-    It only orders and counts: under ``moe.route``."""
+    (The combine's writing loops hand it a tile a run, the run's first:
+    ``n_tiles`` and ``most`` the runs' number.) It only orders and counts:
+    under ``moe.route``."""
     with jax.named_scope(parts.MOE_ROUTE):
         number = jnp.arange(most, dtype=jnp.int32)
         run, start, filled = tile_at(number)
@@ -354,31 +359,37 @@ def _tiles_note(sizes: tuple) -> str:
     return f"last-{sizes[1]}" if len(sizes) > 1 else "whole"
 
 
-def _runs_in_tiles(keys, n_runs: int, tile: int):
-    """The runs of equal keys ``0 .. n_runs - 1`` in ascending ``keys`` (a
-    larger key: none of them, and last), each cut into tiles of ``tile``
-    places: ``(counts, shift, n_tiles, tile_at)``. A run starts where a
-    bisection of the sorted keys finds its key, so counting costs
-    ``n_runs + 1`` queries whatever the keys' number (and neither a
-    scatter-add nor a pass over them). With the tiles laid end to end in
-    the runs' order, place ``q`` of run ``r`` lies at ``q + shift[r]`` among
-    the tiles' places; ``n_tiles`` is the tiles' number, and ``tile_at(i)``
-    gives tile ``i`` of them (tiles ``i`` of any shape) as ``(run, start,
-    filled)``: its run, where among ``keys`` it starts, and how many of its
-    places lie inside the run (``tile`` or more but in a run's last tile).
-    Which run by comparing ``i`` with every run's last tile, and the run's
-    two numbers by comparing it with every run's: a gather of a few dozen
-    values by as many indices the chip's compiler unrolls into a select a
-    value, and a program's size is paid at every load (PERF.md, PR 56)."""
+def _run_bounds(keys, n_runs: int):
+    """Where the runs of equal keys ``0 .. n_runs - 1`` start and end in
+    ascending ``keys`` (a larger key: none of them, and last): ``(starts,
+    ends)``, each ``(n_runs,)``. A run starts where a bisection of the sorted
+    keys finds its key, so counting costs ``n_runs + 1`` queries whatever the
+    keys' number (and neither a scatter-add nor a pass over them)."""
     bounds = jnp.searchsorted(
         keys, jnp.arange(n_runs + 1, dtype=keys.dtype)).astype(jnp.int32)
-    starts = bounds[:-1]
-    counts = bounds[1:] - starts
+    return bounds[:-1], bounds[1:]
+
+
+def _runs_in_tiles(starts, ends, tile: int):
+    """The runs ``starts[r] .. ends[r]`` of some ordered places
+    (:func:`_run_bounds`'s, or a part of each), each cut into tiles of
+    ``tile`` places: ``(counts, shift, n_tiles, tile_at)``. With the tiles
+    laid end to end in the runs' order, place ``q`` of run ``r`` lies at
+    ``q + shift[r]`` among the tiles' places; ``n_tiles`` is the tiles'
+    number, and ``tile_at(i)`` gives tile ``i`` of them (tiles ``i`` of any
+    shape) as ``(run, start, filled)``: its run, where among the places it
+    starts, and how many of its places lie inside the run (``tile`` or more
+    but in a run's last tile). Which run by comparing ``i`` with every run's
+    last tile, and the run's two numbers by comparing it with every run's: a
+    gather of a few dozen values by as many indices the chip's compiler
+    unrolls into a select a value, and a program's size is paid at every
+    load (PERF.md, PR 56)."""
+    counts = ends - starts
     tiles = -(-counts // tile)
     tile_ends = jnp.cumsum(tiles)
     shift = (tile_ends - tiles) * tile - starts
-    run_ends = bounds[1:] + shift  # among the tiles' places
-    runs = jnp.arange(n_runs, dtype=jnp.int32)
+    run_ends = ends + shift  # among the tiles' places
+    runs = jnp.arange(counts.shape[0], dtype=jnp.int32)
 
     def tile_at(i):
         i = jnp.asarray(i, jnp.int32)
@@ -411,7 +422,8 @@ def _dispatch(local, weights, held: int, tile: int):
     place = jnp.arange(total, dtype=jnp.int32)
     expert_at, number_at, weight_at = jax.lax.sort(
         (local, place, weights), num_keys=1, is_stable=True)
-    counts, shift, n_tiles, tile_at = _runs_in_tiles(expert_at, held, tile)
+    counts, shift, n_tiles, tile_at = _runs_in_tiles(
+        *_run_bounds(expert_at, held), tile)
     zero_row = (-(-total // tile) + held) * tile
     mine = expert_at[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
     moved = jnp.sum(jnp.where(mine, shift[:, None], 0), axis=0)
@@ -421,17 +433,19 @@ def _dispatch(local, weights, held: int, tile: int):
 
 def _combine_held(out, row_at, token_at, n: int, top_k: int,
                   held_share: float, none_absent: bool = False):
-    """``(n, dim)`` float32: each token's sum of its held assignments' rows
-    of ``out``, reading no other row. The assignments come as pairs in any
-    order, ``row_at`` the row of ``out`` and ``token_at`` the token (an
-    absent assignment's row is the zero row, the last; its token is not
-    read). The grouped product's mirror image: the held pairs are grouped
-    by block of consecutive tokens (one sort keyed on the block, absent ones
-    last, that carries the pair along), each block's run is cut into tiles
-    of ``_COMBINE_ROWS``, and a loop over as many tiles as the data made
+    """``(y, tiles)``. ``y`` ``(n, dim)`` float32: each token's sum of its
+    held assignments' rows of ``out``, reading no other row. The assignments
+    come as pairs in any order, ``row_at`` the row of ``out`` and
+    ``token_at`` the token (an absent assignment's row is the zero row, the
+    last; its token is not read). The grouped product's mirror image: the
+    held pairs are grouped by block of consecutive tokens (one sort keyed on
+    the block, absent ones last, that carries the pair along), each block's
+    run is cut into tiles of ``_COMBINE_ROWS``, and a loop over tiles
     gathers a tile's rows and adds them to the block's tokens by a 0/1
     matrix on the matrix unit (products with 0 and 1 are exact, the sum is
-    float32), so the order inside a block is immaterial.
+    float32), so the order inside a block is immaterial. ``tiles`` ``(2,)``
+    int32: how many tiles wrote their block's sums and how many added to
+    them.
 
     The block is the most tokens, a multiple of 8 and ``_COMBINE_BLOCK`` at
     most, whose expected run ``block * top_k * held_share`` fits one tile
@@ -439,28 +453,40 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
     256 where an eighth or a quarter of a router is held (the regime the two
     constants were read in), 64 where all of a top-8 router is, 96 where half
     of a ten-a-token router is (480 expected of a tile's 512: no smaller
-    size clears the run's spread, so the loop has one, and a block that draws
-    more than 512 takes a second tile, which adds). A tile's 0/1 matrix and
-    the sums it touches are a block tall, so a block cut into four tiles
-    multiplies and rewrites four times what its rows need. Where the tokens
-    are no whole number of blocks (32,768 are 341.33 blocks of 96) the sums
-    are made for whole blocks and the result is their first ``n`` rows.
+    size clears the run's spread, so the loop has one). A tile's 0/1 matrix
+    and the sums it touches are a block tall, so a block cut into four tiles
+    multiplies four times what its rows need. Where the tokens are no whole
+    number of blocks (32,768 are 341.33 blocks of 96) the sums are made for
+    whole blocks and the result is their first ``n`` rows.
 
-    A block's run never passes ``block * top_k``, so where that is the
-    tile's size every block is one tile at most, whatever the routing, and
-    the tile writes its sums where it would else read the block's, add and
-    write back: noted ``combine_write=once`` (``added`` elsewhere). Only a
-    block without a held assignment then keeps the zeros the sums start
-    from, and where the caller knows every assignment held
-    (``none_absent``) there is none: the sums are allocated, not zeroed.
+    No tile reads a block's sums but one that must add to them. A block's
+    run never passes ``block * top_k``, so where that is the tile's size
+    every block is one tile at most, whatever the routing: the loop runs over
+    the tiles the data made and each writes its block's sums, noted
+    ``combine_write=once``. Only a block without a held assignment then
+    keeps the zeros the sums start from, and where the caller knows every
+    assignment held (``none_absent``) there is none: the sums are allocated,
+    not zeroed. Where a block's run may pass a tile (a part of the router is
+    held: ``combine_write=first``) the loop runs over the blocks, every one,
+    and writes each block's first tile (one of zeros for a block that holds
+    nothing), so the sums are allocated whatever the routing; a block's
+    further tiles, which a block of that size makes a few times in a hundred
+    (Granite's half of ten a token) or never, are a part of each run
+    (:func:`_runs_in_tiles` from a tile past the run's start) and add in a
+    loop of their own after it, in their order and at the tile's whole size:
+    a token's float32 sums are added in the order one loop over all would.
+    Both loops' bodies see the sums a block a page, ``(blocks, block, dim)``
+    (a view: a block is whole rows of 8), so that the 0/1 product writes its
+    page where it lies: on a v5e a page written by the product's own fusion
+    read 4.6 us for a block of 96 x 4,096 where the product and a write
+    behind it at a looked-up row read 2.2 and 6.4 (PERF.md, PR 69).
 
     A block's run is short of a tile wherever a part of the router is held,
     so its last tile, which is mostly its only one, gathers and multiplies
     :func:`tile_sizes`' small size where its places fit, in a loop of that
-    size (:func:`_loop_by_size`; a block's whole tiles are added first and
-    in their order, its last tile after them, as one loop over all would).
-    Where the blocks are one tile each and the expected run fills it, no run
-    is short but a window's last, and the loop has one size. Noted as
+    size (:func:`_loop_by_size`: the writing loops are one a size). Where the
+    blocks are one tile each and the expected run fills it, no run is short
+    but a window's last, and the loop has one size. Noted as
     ``combine_tiles=last-<small>`` (``whole``: one size)."""
     dim = out.shape[1]
     a_token = top_k * held_share  # its expected held assignments
@@ -474,13 +500,12 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
     else:
         sizes = tile_sizes(rows, block * a_token, tight=True)
     _note("combine_tiles", _tiles_note(sizes))
-    _note("combine_write", "once" if once else "added")
+    _note("combine_write", "once" if once else "first")
     block_at, row_at, token_at = jax.lax.sort(
         (jnp.where(row_at < out.shape[0] - 1, token_at // block, blocks),
          row_at, token_at), num_keys=1, is_stable=False)
-    _, _, n_tiles, tile_at = _runs_in_tiles(block_at, blocks, rows)
-    tiles, ends = _tiles_by_size(n_tiles, -(-row_at.shape[0] // rows) + blocks,
-                                 tile_at, sizes)
+    total = row_at.shape[0]
+    starts, ends = _run_bounds(block_at, blocks)
     # a slice of ``rows`` from any start inside the assignments stays inside
     row_at, token_at = jnp.pad(row_at, (0, rows)), jnp.pad(token_at, (0, rows))
     lane = jnp.arange(rows, dtype=jnp.int32)
@@ -488,7 +513,7 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
     # a float32 row times 1 stays float32 only at ``highest``
     exact = None if out.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
 
-    def at_rows(m):
+    def at_rows(m, adds=False):
         def one_tile(_, tile_j, y):
             b, start, filled = tile_j
             valid = lane[:m] < filled  # past the run's end: the zero row
@@ -498,17 +523,38 @@ def _combine_held(out, row_at, token_at, n: int, top_k: int,
             mine = (slot[:, None] == at[None, :]).astype(out.dtype)
             add = jnp.dot(mine, picked, precision=exact,
                           preferred_element_type=jnp.float32)
-            corner = (b * block, 0)
-            if not once:
-                add = jax.lax.dynamic_slice(y, corner, (block, dim)) + add
-            return jax.lax.dynamic_update_slice(y, add, corner)
+            if once:
+                return jax.lax.dynamic_update_slice(y, add, (b * block, 0))
+            # the sums seen a block a page (no bytes move): the product's
+            # fusion writes a page at its looked-up number itself, where a
+            # write at a looked-up row is a pass of its own behind it
+            pages = y.reshape(blocks, block, dim)
+            if adds:
+                add = pages[b] + add
+            return jax.lax.dynamic_update_slice(
+                pages, add[None], (b, 0, 0)).reshape(y.shape)
         return one_tile
 
-    # every block is written where each is one tile and none is empty
-    make = jax.lax.empty if once and none_absent else jnp.zeros
-    y = _loop_by_size(tiles, ends, sizes, at_rows,
-                      make((blocks * block, dim), jnp.float32))
-    return y[:n]
+    sums = (blocks * block, dim), jnp.float32
+    if once:
+        _, _, n_tiles, tile_at = _runs_in_tiles(starts, ends, rows)
+        tiles, upto = _tiles_by_size(n_tiles, -(-total // rows) + blocks,
+                                     tile_at, sizes)
+        # every block is written where none is empty
+        make = jax.lax.empty if none_absent else jnp.zeros
+        y = _loop_by_size(tiles, upto, sizes, at_rows, make(*sums))
+        return y[:n], jnp.stack([n_tiles, jnp.zeros_like(n_tiles)])
+    # a block's first tile is its run from the start, whatever it holds
+    tiles, upto = _tiles_by_size(
+        blocks, blocks, lambda b: (b, starts, ends - starts), sizes)
+    y = _loop_by_size(tiles, upto, sizes, at_rows, jax.lax.empty(*sums))
+    # the rest of a run that passes one tile, cut into tiles in its turn
+    _, _, n_more, tile_at = _runs_in_tiles(
+        jnp.minimum(starts + rows, ends), ends, rows)
+    tiles, upto = _tiles_by_size(n_more, -(-total // rows), tile_at,
+                                 sizes[:1])
+    y = _loop_by_size(tiles, upto, sizes[:1], partial(at_rows, adds=True), y)
+    return y[:n], jnp.stack([jnp.full_like(n_more, blocks), n_more])
 
 
 def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
@@ -524,7 +570,8 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     ``y`` the held experts' part of the routed sum plus the shared expert,
     ``tokens`` ``(held,)`` how many tokens went to each held expert, and
     ``absent`` how many assignments fell on experts held elsewhere (their
-    part is another chip's to add).
+    part is another chip's to add). :func:`topk_moe_layer_tiles` is the
+    layer itself, and returns the combine's count of tiles besides.
 
     The dispatch: the assignments are ordered once, by expert (one held
     elsewhere last) and by token inside an expert, in a sort that carries
@@ -572,8 +619,21 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
     follows the share of the router that is held, so that a block's run is
     about one tile: 256 tokens at an eighth or a quarter held, 64 where all
     of a top-8 router is, and there a tile is its block's only one and
-    writes the block's sums once (``combine_write=once``; ``added`` where a
-    block may take several tiles, which add to them)."""
+    writes the block's sums once (``combine_write=once``). Where a block may
+    take several tiles (``combine_write=first``) its first writes them too,
+    and only a further one reads them back and adds: no form zeroes the sums
+    to add every tile to them."""
+    return topk_moe_layer_tiles(p, x, top_k, first_expert, router,
+                                renormalize, scale, tile, eps)[:3]
+
+
+def topk_moe_layer_tiles(p: dict, x: jnp.ndarray, top_k: int,
+                         first_expert: int, router: str, renormalize: bool,
+                         scale: float, tile: Optional[int], eps: float):
+    """:func:`topk_moe_layer`, which says what it computes, with one count
+    more: ``(y, tokens, absent, tiles)``, ``tiles`` ``(2,)`` the tiles of the
+    combine that wrote a block's sums and those that added to them
+    (:func:`_combine_held`)."""
     from storm_tpu.ops import layers as L
 
     shape = x.shape
@@ -646,22 +706,24 @@ def topk_moe_layer(p: dict, x: jnp.ndarray, top_k: int, first_expert: int = 0,
         # the whole router from its first expert on (a traced first: unknown)
         whole = held == width and isinstance(first_expert, int) \
             and first_expert == 0
-        y = _combine_held(out, row_at, token_at, n, top_k, held / width,
-                          none_absent=whole)
+        y, combined = _combine_held(out, row_at, token_at, n, top_k,
+                                    held / width, none_absent=whole)
     if "shared" in p:
         with jax.named_scope(parts.PROJ):
             shared = L.feed_forward(p["shared"], tokens)
         with jax.named_scope(parts.MOE_COMBINE):
             y = y + shared.astype(jnp.float32)
     with jax.named_scope(parts.MOE_COMBINE):
-        return y.astype(x.dtype).reshape(shape), counts, absent
+        return y.astype(x.dtype).reshape(shape), counts, absent, combined
 
 
-def observe_expert_counts(metrics, cid: str, tokens, absent, *, width: int,
-                          tile: Optional[int] = None) -> None:
-    """What :func:`topk_moe_layer` counted in one step, fetched to the host
-    (``tokens`` ``(layers, held)``, ``absent`` ``(layers,)``), into the
-    registry under ``cid``: the assignments held and absent as two counters,
+def observe_expert_counts(metrics, cid: str, tokens, absent, combined, *,
+                          width: int, tile: Optional[int] = None) -> None:
+    """What :func:`topk_moe_layer_tiles` counted in one step, fetched to the
+    host (``tokens`` ``(layers, held)``, ``absent`` ``(layers,)``,
+    ``combined`` ``(layers, 2)``), into the registry under ``cid``: the
+    combine's tiles that wrote a block's sums and those that added to them
+    as two counters, the assignments held and absent as two more,
     the rows that the experts' loop computed for the held ones
     (:func:`rows_computed` of every expert's count at the sizes the layer
     chose: the step's assignments over the router's ``width`` are the
@@ -678,6 +740,9 @@ def observe_expert_counts(metrics, cid: str, tokens, absent, *, width: int,
                            tight=False)
         rows.inc(int(rows_computed(layer, sizes).sum()))
     metrics.counter(cid, "expert_assignments_absent").inc(int(absent.sum()))
+    written, added = (int(count) for count in combined.sum(axis=0))
+    metrics.counter(cid, "combine_tiles_written").inc(written)
+    metrics.counter(cid, "combine_tiles_added").inc(added)
     load = metrics.histogram(cid, "expert_tokens_max_over_mean")
     for layer in tokens:
         load.observe(float(layer.max()) / max(float(layer.mean()), 1e-9))

@@ -116,7 +116,7 @@ def test_a_tokens_sum_is_the_plain_sum(case, ffn):
     assert _block(n, top_k, held / width) * top_k > 8
     assert seen == [f"expert_ffn={ffn}", "expert_dispatch=sorted",
                     "expert_tiles=whole", "expert_combine=held-rows",
-                    "combine_tiles=whole", "combine_write=added"]
+                    "combine_tiles=whole", "combine_write=first"]
     np.testing.assert_allclose(y, want, atol=1e-5 * max(1.0, float(
         jnp.abs(want).max())))
     assert int(tokens.sum()) + int(absent) == n * top_k
@@ -164,10 +164,10 @@ def test_the_loop_on_any_share_held(dtype, n, top_k, share):
     token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
     # the pairs in any order: the combine sorts them by block itself
     mixed = jax.random.permutation(jax.random.PRNGKey(4), n * top_k)
-    got = jax.jit(lambda o, r, t: moe._combine_held(o, r, t, n, top_k,
-                                                    share))(
-        out, row_of[mixed], token[mixed])
+    got, tiles = jax.jit(lambda o, r, t: moe._combine_held(
+        o, r, t, n, top_k, share))(out, row_of[mixed], token[mixed])
     assert got.dtype == jnp.float32 and got.shape == (n, DIM)
+    assert tiles.dtype == jnp.int32 and tiles.shape == (2,)
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
@@ -189,14 +189,16 @@ RULE = {
 
 
 def _loops_of(jaxpr):
-    """Of each ``while`` of a traced combine, in order: the 0/1 matrix's
+    """Of each loop of a traced combine, in order (a ``while``, or the
+    ``scan`` a loop of a static length is traced to): the 0/1 matrix's
     shape, and the shapes its body slices out of an array that it carries
-    whole (the read of a block's sums is one of ``(block, dim)``)."""
+    whole (the read of a block's sums is one page, ``(1, block, dim)``)."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name != "while":
+        if eqn.primitive.name not in ("while", "scan"):
             continue
-        body = eqn.params["body_jaxpr"].jaxpr
+        body = eqn.params["body_jaxpr" if eqn.primitive.name == "while"
+                          else "jaxpr"].jaxpr
         (dot,) = [e for e in body.eqns if e.primitive.name == "dot_general"]
         sliced = [e.outvars[0].aval.shape for e in body.eqns
                   if e.primitive.name == "dynamic_slice"
@@ -211,14 +213,16 @@ def _loops_of(jaxpr):
     f"top{k}-{s:.3g}-held" for k, s in RULE])
 def test_the_block_is_the_most_tokens_whose_run_fits_a_tile(
         top_k, share, tokens, monkeypatch):
-    """At the served constants: the block and the tile by the rule; a tile
-    writes its block's sums without reading them exactly where the tile is
-    ``block * top_k`` rows (no block can then make two), noted
+    """At the served constants: the block and the tile by the rule. Where
+    the tile is ``block * top_k`` rows (no block can then make two) every
+    tile writes its block's sums without reading them, noted
     ``combine_write=once``; the loop has one size there exactly where the
-    expected run fills the tile too; the sums are allocated and not zeroed
-    exactly where besides every assignment is held, and then every row of
-    them is written: handed a buffer full of NaN the result is the plain
-    sum."""
+    expected run fills the tile too, and the sums are allocated and not
+    zeroed exactly where besides every assignment is held. Elsewhere
+    (``combine_write=first``) the loops of either size write too, one tile a
+    block, the sums are allocated whatever is held, and one loop more, of
+    the whole size, reads a block's sums and adds. Every row of an allocation
+    is written: handed a buffer full of NaN the result is the plain sum."""
     monkeypatch.setattr(moe, "_COMBINE_BLOCK", SERVED[0])
     monkeypatch.setattr(moe, "_COMBINE_ROWS", SERVED[1])
     block, rows, small = RULE[top_k, share]
@@ -252,19 +256,179 @@ def test_the_block_is_the_most_tokens_whose_run_fits_a_tile(
             out, row_of[mixed], token[mixed]).jaxpr)
     assert seen == ["combine_tiles=" + (f"last-{small}" if small else
                                         "whole"),
-                    "combine_write=" + ("once" if once else "added")]
-    assert [mine for mine, _ in loops] == [(block, m) for m in (
-        (rows, small) if small else (rows,))]
-    for _, sliced in loops:
-        assert ((block, DIM) in sliced) == (not once)
+                    "combine_write=" + ("once" if once else "first")]
+    writers = [(block, m) for m in ((rows, small) if small else (rows,))]
+    assert [mine for mine, _ in loops] == writers + [(block, rows)] * (
+        not once)
+    for at, (_, sliced) in enumerate(loops):
+        assert (block, DIM) not in sliced
+        assert ((1, block, DIM) in sliced) == (at == len(writers))
     blocks = -(-n // block)
-    assert allocated == ([(blocks * block, DIM)] if once and share == 1.0
+    assert allocated == ([(blocks * block, DIM)] if share == 1.0 or not once
                          else [])
-    got = jax.jit(combine)(out, row_of[mixed], token[mixed])
+    got, tiles = jax.jit(combine)(out, row_of[mixed], token[mixed])
+    assert int(tiles.sum()) == sum(
+        -(-held // rows) or (not once) for held in np.bincount(
+            np.arange(n * top_k)[np.asarray(row_of) < 150] // top_k // block,
+            minlength=blocks))
+    assert int(tiles[1]) == int(tiles.sum()) - blocks if not once else (
+        int(tiles[1]) == 0)
     want = np.asarray(out, np.float64)[np.asarray(row_of)].reshape(
         n, top_k, DIM).sum(1)
     assert got.dtype == jnp.float32 and got.shape == (n, DIM)
     np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+# the five cells that hold a part of their router, a quarter of their sizes
+# (blocks of 64 tokens at most, tiles of 128 rows, sizes by steps of 32):
+# (top-k, share held): (tokens, block, the small size or None)
+PART_HELD = {
+    "kimi_linear_48b": ((8, 1 / 8), (512, 64, 96)),
+    "nemotron_3_nano_30b": ((6, 1 / 4), (512, 64, None)),
+    "kimi_k2_6": ((8, 1 / 32), (512, 64, 32)),
+    "solar_open2_250b": ((8, 40 / 320), (500, 64, 96)),
+    "granite_4_h_small": ((10, 1 / 2), (500, 24, None)),
+}
+
+
+def _added(out, row_at, token_at, n, block, rows, small):
+    """The form this one replaced, tile by tile in plain steps: the sums
+    start as zeros and every tile reads its block's, adds and writes them
+    back, the whole tiles first and then those that fit ``small``."""
+    blocks, zero = -(-n // block), out.shape[0] - 1
+    at, row_at, token_at = jax.lax.sort(
+        (jnp.where(row_at < zero, token_at // block, blocks), row_at,
+         token_at), num_keys=1, is_stable=False)
+    ends = np.searchsorted(np.asarray(at), np.arange(blocks + 1))
+    row_at, token_at = (np.pad(np.asarray(a), (0, rows))
+                        for a in (row_at, token_at))
+    tiles = [(b, s, ends[b + 1] - s) for b in range(blocks)
+             for s in range(ends[b], ends[b + 1], rows)]
+    y = jnp.zeros((blocks * block, out.shape[1]), jnp.float32)
+    for m, fits in ((rows, lambda f: not small or f > small),
+                    (small, lambda f: small and f <= small)):
+        for b, s, filled in (t for t in tiles if fits(t[2])):
+            picked = out[np.where(np.arange(m) < filled, row_at[s:s + m],
+                                  zero)]
+            mine = np.arange(block)[:, None] == token_at[s:s + m] - b * block
+            y = y.at[b * block:(b + 1) * block].add(jnp.dot(
+                jnp.asarray(mine, out.dtype), picked,
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32))
+    return y[:n]
+
+
+@pytest.mark.parametrize("routing", ["drawn", "a-block-overflows",
+                                     "a-block-holds-nothing"])
+@pytest.mark.parametrize("cell", list(PART_HELD))
+def test_a_blocks_first_tile_writes_and_only_a_further_one_adds(
+        cell, routing, monkeypatch):
+    """Where a part of the router is held (``combine_write=first``), on the
+    usual draw, with every assignment of two blocks held (the most a block
+    can hold: four tiles of Kimi's 64 x 8, three of Nemotron's, two of
+    Granite's 24 x 10; the second block one assignment past a tile, so that
+    its further tile would fit the small size) and with a block that holds
+    nothing: the plain per-token sum; the form that zeroed the sums and
+    added every tile to them, bit for bit, a further tile at the whole size
+    too; a tile written a block, the further ones added and counted; and the
+    sums allocated, not zeroed: from an allocation full of NaN, a block that
+    holds nothing is written zeros."""
+    (top_k, share), (n, block, small) = PART_HELD[cell]
+    rows = 128
+    monkeypatch.setattr(moe, "_COMBINE_BLOCK", 64)
+    monkeypatch.setattr(moe, "_COMBINE_ROWS", rows)
+    monkeypatch.setattr(moe, "_TILE_STEP", 32)
+    out, row_of = _assignments(7, n, top_k, 300, share)
+    token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
+    of_block = np.asarray(token) // block
+    if routing == "a-block-overflows":
+        past = np.flatnonzero(of_block == 3)[:rows + 1]
+        row_of = jnp.where((of_block == 1) | np.isin(
+            np.arange(n * top_k), past), jnp.arange(n * top_k) % 300,
+            jnp.where(of_block == 3, 300, row_of))
+    elif routing == "a-block-holds-nothing":
+        row_of = jnp.where(of_block == 2, 300, row_of)
+    blocks = -(-n // block)
+    runs = np.bincount(of_block[np.asarray(row_of) < 300], minlength=blocks)
+    further = int(np.maximum(-(-runs // rows) - 1, 0).sum())
+    if routing == "a-block-overflows":
+        assert runs[1] == block * top_k and runs[3] == rows + 1
+        assert further >= -(-block * top_k // rows)  # block 1's, and one
+    elif routing == "a-block-holds-nothing":
+        assert runs[2] == 0
+    # a toy block of Granite's, 120 +- 8 of a tile's 128, passes it unasked
+    assert further == 0 or "overflows" in routing or "granite" in cell
+    mixed = jax.random.permutation(jax.random.PRNGKey(4), n * top_k)
+    allocated = []
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: (
+        allocated.append(shape), jnp.full(shape, jnp.nan, dtype))[1])
+    with dispatch_notes() as seen:
+        got, tiles = jax.jit(lambda o, r, t: moe._combine_held(
+            o, r, t, n, top_k, share))(out, row_of[mixed], token[mixed])
+    assert seen == ["combine_tiles=" + (f"last-{small}" if small else
+                                        "whole"), "combine_write=first"]
+    assert allocated == [(blocks * block, DIM)]
+    assert tiles.tolist() == [blocks, further]
+    want = jax.ops.segment_sum(out[row_of], token, num_segments=n)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    old = _added(out, row_of[mixed], token[mixed], n, block, rows, small)
+    assert np.array_equal(np.asarray(got).view(np.uint32),
+                          np.asarray(old + 0.0).view(np.uint32))
+
+
+@pytest.mark.parametrize("case", ["top6-quarter-held", "one-block-holds-all",
+                                  "none-held", "top2-all-held-once"])
+def test_the_combines_tiles_are_counted_into_the_registry(case, monkeypatch):
+    """``topk_moe_layer_tiles`` is the layer with one count more, and
+    ``observe_expert_counts`` adds it to two counters: where a block may pass
+    a tile, a tile written a block (thirteen blocks of 8 tokens, seven of 16)
+    and a tile added for each 8 held assignments of a block past its first
+    (three where one block of 16 holds all 32); where it cannot
+    (``combine_write=once``, at the served constants) the tiles the data
+    made, all written and none added. Two steps: a counter adds."""
+    from storm_tpu.runtime.metrics import MetricsRegistry
+
+    if case.endswith("once"):
+        monkeypatch.setattr(moe, "_COMBINE_BLOCK", SERVED[0])
+        monkeypatch.setattr(moe, "_COMBINE_ROWS", SERVED[1])
+    width, held, first, top_k, n, _ = CASES[case.removesuffix("-once")]
+    p, x = _case(case.removesuffix("-once"), "swiglu")
+    how = (first, "sigmoid", True, 2.5, 16, 1e-20)
+    with dispatch_notes() as seen:
+        y, tokens, absent, tiles = jax.jit(
+            lambda p, x: moe.topk_moe_layer_tiles(p, x, top_k, *how))(p, x)
+    plain = jax.jit(lambda p, x: topk_moe_layer(
+        p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
+    assert len(plain) == 3
+    for a, b in zip(plain, (y, tokens, absent)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    once = case.endswith("once")
+    assert ("combine_write=once" in seen) == once
+    chosen, _ = route_topk(p, x, top_k, scale=2.5)
+    block = _block(n, top_k, held / width, *(SERVED if once else (16, 8)))
+    runs = np.bincount(np.repeat(np.arange(n) // block, top_k)[
+        (np.asarray(chosen).reshape(-1) >= first)
+        & (np.asarray(chosen).reshape(-1) < first + held)],
+        minlength=-(-n // block))
+    if once:
+        want = [int((runs > 0).sum()), 0]
+    else:
+        want = [len(runs), int(np.maximum(-(-runs // 8) - 1, 0).sum())]
+    assert tiles.tolist() == want
+    assert want == {"none-held": [7, 0], "one-block-holds-all": [7, 3],
+                    "top2-all-held-once": [1, 0]}.get(case, want)
+    registry = MetricsRegistry()
+    for _ in range(2):
+        moe.observe_expert_counts(
+            registry, "bolt", np.asarray(tokens)[None],
+            np.asarray(absent)[None], np.asarray(tiles)[None], width=width,
+            tile=16)
+    got = registry.snapshot()["bolt"]
+    assert got["combine_tiles_written"] == 2 * want[0]
+    assert got["combine_tiles_added"] == 2 * want[1]
+    assert got["combine_tiles_written"] + got["combine_tiles_added"] == 2 * (
+        int(np.maximum(-(-runs // (block * top_k if once else 8)),
+                       not once).sum()))
 
 
 @pytest.mark.parametrize("held,first,allocated", [
@@ -371,11 +535,14 @@ def test_chosen_scores_by_comparison_are_the_gathered_ones_to_the_bit(
 
 @pytest.mark.parametrize("case", list(CASES), ids=list(CASES))
 def test_the_buffer_may_hold_anything_but_its_zero_row(case, monkeypatch):
-    """The tiles' buffer is allocated, not filled: handed one full of NaN
-    the layer gives ``y``, ``tokens`` and ``absent`` of the zeroed form to
-    the bit, for a share held, none absent, every assignment absent, and
-    runs that end in a partly filled tile (111 tokens on one expert in tiles
-    of 16). Only the zero row is read without having been written."""
+    """The tiles' buffer is allocated, not filled, and so are the combine's
+    sums (blocks of 8 or 16 tokens in tiles of 8 rows: a block's first tile
+    writes them, ``combine_write=first``): handed both full of NaN the layer
+    gives ``y``, ``tokens`` and ``absent`` of the zeroed form to the bit,
+    for a share held, none absent, every assignment absent (every block is
+    written zeros), and runs that end in a partly filled tile (111 tokens on
+    one expert in tiles of 16). Only the zero row is read without having
+    been written."""
     _, held, first, top_k, n, _ = CASES[case]
     p, x = _case(case, "swiglu")
     tile = min(16, -(-n // 8) * 8)  # the layer's, for one token too
@@ -386,7 +553,9 @@ def test_the_buffer_may_hold_anything_but_its_zero_row(case, monkeypatch):
             shapes.append(shape), jnp.full(shape, fill, dtype))[1])
         got[fill] = jax.jit(lambda p, x: topk_moe_layer(
             p, x, top_k, first_expert=first, scale=2.5, tile=16))(p, x)
-        assert shapes == [((-(-n * top_k // tile) + held) * tile + 1, DIM)]
+        block = _block(n, top_k, held / CASES[case][0])
+        assert shapes == [((-(-n * top_k // tile) + held) * tile + 1, DIM),
+                          (-(-n // block) * block, DIM)]
     for a, b in zip(got[jnp.nan], got[0.0]):
         assert a.dtype == b.dtype and a.shape == b.shape
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()

@@ -212,9 +212,12 @@ def test_the_counter_of_rows_computed_is_the_hand_count(tile, width, want):
     absent = np.array([2184 + 5 - 130, 2184 + 7 - 2054])
     registry = MetricsRegistry()
     for _ in range(2):  # two steps: a counter adds
-        observe_expert_counts(registry, "bolt", tokens, absent, tile=tile,
+        observe_expert_counts(registry, "bolt", tokens, absent,
+                              np.array([[9, 0], [9, 2]]), tile=tile,
                               width=width)
     got = registry.snapshot()["bolt"]
+    assert (got["combine_tiles_written"], got["combine_tiles_added"]) == (
+        36, 4)
     assert got["expert_rows_computed"] == 2 * want
     assert got["expert_assignments_held"] == 2 * tokens.sum()
     assert got["expert_assignments_absent"] == 2 * absent.sum()
@@ -268,7 +271,8 @@ def test_the_counter_counts_the_rows_of_the_tile_the_layer_ran():
     _, tokens, absent = layer(p, x)
     registry = MetricsRegistry()
     observe_expert_counts(registry, "bolt", np.asarray(tokens)[None],
-                          np.asarray(absent)[None], width=WIDTH)
+                          np.asarray(absent)[None], np.zeros((1, 2), int),
+                          width=WIDTH)
     counts = [int(c) for c in tokens]
     assert counts[0] == 4200  # 4,608 rows of (1,024, 512), 4,352 of (512, 256)
     assert registry.snapshot()["bolt"]["expert_rows_computed"] == sum(
@@ -379,13 +383,13 @@ def test_tiles_by_size_and_every_assignments_row(kind, held, top_k, width,
 
 
 @pytest.mark.parametrize("name,experts,combine,write", [
-    ("kimi_linear_48b", "last-512", "last-384", "added"),
-    ("nemotron_3_nano_30b", "last-512", "whole", "added"),
-    ("kimi_k2_6", "last-384", "last-128", "added"),
-    ("solar_open2_250b", "last-256", "last-384", "added"),
+    ("kimi_linear_48b", "last-512", "last-384", "first"),
+    ("nemotron_3_nano_30b", "last-512", "whole", "first"),
+    ("kimi_k2_6", "last-384", "last-128", "first"),
+    ("solar_open2_250b", "last-256", "last-384", "first"),
     ("trinity_mini", "last-512", "whole", "once"),
     ("keye_vl2_30b", "last-512", "whole", "once"),
-    ("granite_4_h_small", "last-512", "whole", "added"),
+    ("granite_4_h_small", "last-512", "whole", "first"),
     ("kimi_linear_tiny", "whole", None, "once"),
     ("nemotron_h_tiny", "whole", None, "once"),
     ("kimi_k2_tiny", "whole", None, "once"),
@@ -399,8 +403,8 @@ def test_a_models_step_names_its_loops_sizes(name, experts, combine, write):
     one size; their combine's follows their rows a step). A block of the
     combine is one tile, written once, where the whole router is held (64
     tokens of 8 assignments) and in the presets' top-2 layers (256 of 2);
-    the five cells that hold a part of theirs add a tile to its block's
-    sums, as they did."""
+    the five cells that hold a part of theirs write a block's first tile
+    and add only a further one."""
     from storm_tpu.models.registry import build_model
 
     model = build_model(name)

@@ -354,7 +354,9 @@ def test_the_combine_at_the_cells_shapes_is_a_segment_sum():
     """32,768 tokens, ten assignments each, half of them held, rows of 8
     channels: blocks of 96 tokens (480 expected assignments, a tile of 512
     at one size), 341.33 of them, so the result is a real slice of the 342
-    blocks' sums; a block's run may pass a tile, so tiles add."""
+    blocks' sums; a block's run may pass a tile (480 +- 15 of 512: a few
+    of 342 do), so every block's first tile writes and those few blocks'
+    second adds."""
     n, top_k, dim, rows = 32768, 10, 8, (640 + 36) * 512
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(13), 3)
     out = jnp.concatenate([jax.random.normal(k1, (rows, dim)),
@@ -365,9 +367,12 @@ def test_the_combine_at_the_cells_shapes_is_a_segment_sum():
     token = jnp.arange(n * top_k, dtype=jnp.int32) // top_k
     mixed = jax.random.permutation(jax.random.PRNGKey(14), n * top_k)
     with dispatch_notes() as seen:
-        got = jax.jit(lambda o, r, t: moe._combine_held(
+        got, tiles = jax.jit(lambda o, r, t: moe._combine_held(
             o, r, t, n, top_k, 36 / 72))(out, row_of[mixed], token[mixed])
-    assert seen == ["combine_tiles=whole", "combine_write=added"]
+    assert seen == ["combine_tiles=whole", "combine_write=first"]
+    runs = np.bincount((np.asarray(token) // 96)[np.asarray(row_of) < rows])
+    assert tiles.tolist() == [342, int((runs > 512).sum())]
+    assert 0 < tiles[1] < 30 and runs.max() <= 2 * 512
     assert n % 96 and got.shape == (n, dim) and got.dtype == jnp.float32
     want = np.asarray(out, np.float64)[np.asarray(row_of)].reshape(
         n, top_k, dim).sum(1)
@@ -427,7 +432,7 @@ def test_the_step_counts_and_the_inventory_names_the_forms():
     assert set(forms) == {"short_conv=xla", "ssd_scan=chunked",
                           "expert_ffn=swiglu", "expert_dispatch=sorted",
                           "expert_tiles=whole", "expert_combine=held-rows",
-                          "combine_tiles=whole", "combine_write=added",
+                          "combine_tiles=whole", "combine_write=first",
                           "causal_attention=blocked-grouped"}
     handle = eng.dispatch((_windows(4),))
     handle.future.result(60)
@@ -489,9 +494,12 @@ def test_registry_names_the_model_and_its_share():
 # for the toy, of its leaves from key 7. (PR 65: the served size's text is
 # its own again, the experts' tiles 1,024 rows for 512, gathered 512 rows at a
 # time: ``parallel/moe.py run_tile`` of a run of 1,137 at this test's two
-# rows, 4,551 at the cell's.)
-GRANITE = {"granite_h_tiny": ('f79c3956c07ebfea', 'a0668d61c12b33cb', 'ab5cf8eab81e455d'),
-           "granite_4_h_small": ('c4e20b2ea9e45074', 'd95ae9f619c8225c')}
+# rows, 4,551 at the cell's. PR 69: all five digests; the layer counts its
+# combine's tiles, written and added (``combine_tiles`` in ``aux``), and the
+# served size, which holds half of its router, writes a block's first tile
+# into allocated sums and adds only a further one.)
+GRANITE = {"granite_h_tiny": ('3cb590ce8439770b', 'd308d084fac8b85e', 'eda6c3abc43764e0'),
+           "granite_4_h_small": ('13cffced573d97cb', 'd35172f43fc5a675')}
 
 
 def _digest(*chunks):

@@ -445,13 +445,18 @@ def test_two_half_shares_add_up_to_the_all_held_layer():
 # block is 64 tokens or fewer, one tile each, written once; trees and leaves
 # are the parent's. PR 65 changed the text of the two served sizes' lines:
 # their tiles of 1,024 rows gather their tokens 512 rows at a time,
-# ``parallel/moe.py _GATHER_ROWS``; the tile is what their presets named.)
-TRINITY = {"trinity_tiny": ("7d7cdf66bd3d9742", "02ca71f3daed22c6",
-                            "fb4f89f513f7395d"),
-           "trinity_mini": ("0f050f77253a3927", "c7f7b016380d3f34")}
-KEYE = {"keye_tiny": ("ff379ced75e497f1", "2d028cd1c66aed41",
-                      "ed3354f4d5badb8f"),
-        "keye_vl2_30b": ("c7286d9202a3bbf8", "11769136fc6552db")}
+# ``parallel/moe.py _GATHER_ROWS``; the tile is what their presets named.
+# PR 69 changed all six digests by one count: the layer counts its combine's
+# tiles, written and added (``combine_tiles`` in ``aux``: a key more in the
+# tree, its zeros among the leaves, and in the text the tiles the data made
+# beside a constant 0); the combine's loop, ``once`` in both, is the
+# parent's.)
+TRINITY = {"trinity_tiny": ("83e0728be884687a", "e5e17429ee791e05",
+                            "5f9ea72a2d64f4f5"),
+           "trinity_mini": ("77a7ab84498b1515", "e9bb8a91e9bb0eb3")}
+KEYE = {"keye_tiny": ("95027fe8934ffd9d", "35cd9f06a0347f12",
+                      "5faea694f2c58576"),
+        "keye_vl2_30b": ("25c7bd8a54eeb8e4", "c597836176482a71")}
 
 
 def _digest(*chunks):

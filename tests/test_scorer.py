@@ -27,16 +27,20 @@ TWO = {"norm1", "mixer", "norm2", "ffn"}
 # name -> (blocks, a block's keys, the counts in ``aux`` and a layer's shape)
 TINY = {
     "kimi_linear_tiny": (5, TWO, {"expert_tokens": (4, 4),
-                                  "expert_absent": (4,)}),
+                                  "expert_absent": (4,),
+                                  "combine_tiles": (4, 2)}),
     "nemotron_h_tiny": (5, {"norm", "mixer"}, {"expert_tokens": (2, 4),
-                                               "expert_absent": (2,)}),
+                                               "expert_absent": (2,),
+                                               "combine_tiles": (2, 2)}),
     "kimi_k2_tiny": (3, TWO, {"expert_tokens": (2, 4),
-                              "expert_absent": (2,)}),
+                              "expert_absent": (2,),
+                              "combine_tiles": (2, 2)}),
     "minicpm_sala_tiny": (4, TWO, {"sparse_keys_read": (2,),
                                    "sparse_keys_skipped": (2,)}),
     # the first plan whose block 0 routes: four expert layers of four blocks
     "solar_open2_tiny": (4, TWO, {"expert_tokens": (4, 5),
-                                  "expert_absent": (4,)}),
+                                  "expert_absent": (4,),
+                                  "combine_tiles": (4, 2)}),
 }
 # each family at its toy widths with a plan in which no branch counts
 PLAIN = {
@@ -169,6 +173,10 @@ def test_the_model_carries_the_reader_of_what_it_counts(name, ran):
             -(-aux["expert_tokens"] // 16) * 16).sum()
         assert got["expert_tokens_max_over_mean"]["count"] == len(
             aux["expert_tokens"])
+        # a toy step's top-2 block is one tile at most: each writes, none adds
+        assert got["combine_tiles_written"] == aux["combine_tiles"][:, 0].sum()
+        assert got["combine_tiles_written"] >= len(aux["combine_tiles"])
+        assert got["combine_tiles_added"] == 0
         assert "sparse_keys_read" not in got
     else:
         assert got["sparse_keys_read"] == aux["sparse_keys_read"].sum()
@@ -231,23 +239,29 @@ def test_a_fifth_model_is_a_plan_and_each_count_finds_its_reader():
 # test lowers are a run of 256, so tiles of 512 where the preset said 1,024
 # (at its cell's eight rows, a run of 1,024, the tile is the preset's, its
 # tokens gathered 512 rows at a time); the others' tile here is what their
-# preset named, 512 rows, and their text stands.)
+# preset named, 512 rows, and their text stands. PR 69
+# changed every expert plan's three digests: the layer counts its combine's
+# tiles, written and added (``combine_tiles`` in ``aux``: a key more in the
+# tree and its zeros among the leaves), and where a part of the router is
+# held (the served sizes' 8-, 6- and 10-a-token routers) the combine writes
+# a block's first tile into allocated sums and adds only a further one;
+# MiniCPM-SALA's two stand.)
 PARENT = {
-    "kimi_linear_tiny": ("c1402509f112bf5b", "9c9231af39d81a3b",
-                         "d6d687f48a145060"),
-    "nemotron_h_tiny": ("22faaeba31d98f28", "ffb1a7d7c726fcef",
-                        "b8b23e69c062a671"),
-    "kimi_k2_tiny": ("f63c44cca5bbcaf6", "4015722ee481843e",
-                     "3eee1654ad04fdad"),
+    "kimi_linear_tiny": ("ec4ea3f7665a3954", "3018ba58aee207f8",
+                         "089d36eff78f2c3d"),
+    "nemotron_h_tiny": ("d17f42884162e7ed", "a81bd0e596fbb4f8",
+                        "0c67b917a7cf180d"),
+    "kimi_k2_tiny": ("08bf04ffe16de474", "7e93bf0bff2d886a",
+                     "83c7cb4dbe014207"),
     "minicpm_sala_tiny": ("e80bd69b29e9bb6e", "fbaec783e93f7071",
                           "136d1265de921964"),
-    "kimi_linear_48b": ("4d9a45ee334c607a", "35c56c4e58dd4ac8"),
-    "nemotron_3_nano_30b": ("c3c98b01400caee8", "32502e49d7fc6552"),
-    "kimi_k2_6": ("3695cae34e2865eb", "4a919d11ba374a7b"),
+    "kimi_linear_48b": ("7e01093b036431d8", "2cbc0633449fec6d"),
+    "nemotron_3_nano_30b": ("f443d1dacdc3f71a", "cc75a7fd8bcf6804"),
+    "kimi_k2_6": ("5bc69076d3916e59", "b59443cd82574b67"),
     "minicpm_sala": ("5de5b298fd713172", "31e93c80f04d610e"),
-    "solar_open2_tiny": ("b4128d092f97e4cf", "10b47418e20c9cdc",
-                         "12fec92ac2c8f7b2"),
-    "solar_open2_250b": ("e3192e3869b746e8", "d3a89bd4d4e674a5"),
+    "solar_open2_tiny": ("af9a498deab4de70", "5f1a053db1e6785c",
+                         "8e99c9678a70f872"),
+    "solar_open2_250b": ("ea6eddb821f801ba", "30f3e62cf85302ba"),
 }
 
 

@@ -215,7 +215,8 @@ def test_kimi_linear_tiny_serves_what_the_parents_mixer_gives(monkeypatch):
     same logits to the bit, and no step count in its state."""
     model = build_model("kimi_linear_tiny")
     params, state = model.init(jax.random.PRNGKey(2))
-    assert set(state["aux"]) == {"expert_tokens", "expert_absent"}
+    assert set(state["aux"]) == {"expert_tokens", "expert_absent",
+                                 "combine_tiles"}
     x = spec.plugin("inputs", "token_ids").make(3, (40,), 9).astype(
         np.float32)
     now, _ = jax.jit(model.apply)(params, state, x)
@@ -346,7 +347,8 @@ def test_registry_names_the_model_its_share_and_its_type():
     assert {x.dtype for x in jax.tree.leaves(params)} == {
         jnp.dtype(jnp.bfloat16)}
     assert {k: v.shape for k, v in state["aux"].items()} == {
-        "expert_tokens": (4, 40), "expert_absent": (4,)}
+        "expert_tokens": (4, 40), "expert_absent": (4,),
+        "combine_tiles": (4, 2)}
     assert {"solar_open2_250b", "solar_open2_tiny"} <= set(
         registry.registry_names())
 
@@ -471,7 +473,7 @@ def test_device_counters_ride_the_result_into_the_registry():
     assert aux["expert_tokens"].shape == (4, 5)
     per_layer = aux["expert_tokens"].sum(1) + aux["expert_absent"]
     assert per_layer.tolist() == [320] * 4
-    assert set(aux) == {"expert_tokens", "expert_absent"}
+    assert set(aux) == {"expert_tokens", "expert_absent", "combine_tiles"}
     registry_ = MetricsRegistry()
     queue = ContinuousBatcher(eng, eng.batch_cfg)
     queue.bind(registry_, "inference-bolt")

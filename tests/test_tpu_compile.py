@@ -464,6 +464,55 @@ def test_longseq_encoder_forward_compiles_with_flash_kernel(v5e, monkeypatch):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
 
 
+def _loops_text(text, carried):
+    """The digest of what the ``while`` loops that carry ``carried`` run, in
+    a compiled program's text: each loop's body and condition and every
+    computation those call, in the order met, less the metadata, with each
+    name's counter replaced by its order of appearance (a counter shifts
+    with whatever else the program holds)."""
+    import hashlib
+    import re
+
+    held, name, computations = [], None, {}
+    for line in text.splitlines():
+        line = re.sub(r", metadata=\{[^}]*\}", "", line)
+        if line.endswith("{") and " -> " in line:
+            name, held = line.split(" ")[0].lstrip("%"), [line]
+        elif name:
+            held.append(line)
+            if line == "}":
+                computations[name], name = "\n".join(held), None
+    entry = next(body for body in computations.values() if any(
+        " while(" in line and carried in line.split(" while(")[0]
+        for line in body.splitlines()))
+    todo = [m for line in entry.splitlines() if " while(" in line
+            and carried in line.split(" while(")[0]
+            for m in re.findall(r"(?:condition|body)=%([\w.\-]+)", line)]
+    assert todo
+    met = []
+    while todo:
+        name = todo.pop(0)
+        if name in met:
+            continue
+        met.append(name)
+        todo += re.findall(r"(?:calls|to_apply|condition|body)=%([\w.\-]+)",
+                           computations[name])
+    seen = {}
+    whole = re.sub(
+        r"%?([A-Za-z_][\w\-]*?)((?:\.\d+|\.clone|\.sunk|\.\.sunk)+)\b",
+        lambda m: seen.setdefault(m.group(0), f"{m.group(1)}#{len(seen)}"),
+        "\n".join(computations[name] for name in met))
+    return hashlib.sha256(whole.encode()).hexdigest()[:16]
+
+
+# the combine's loop where the whole router is held (Trinity's and Keye's:
+# one text, 65,536 tokens of 2,048 channels in blocks of 64), as the parent
+# of PR 69 compiled it (:func:`_loops_text` of the loop that carries the
+# sums): that PR changed the loops of the layers that hold a part, and left
+# this one
+ONCE = "cafbd7e975efe871"
+
+
 @pytest.mark.parametrize(
     "n,dim,top_k,held,width,hidden,form,tile,stacked,sums,matmul_ms,"
     "combine_ms,smalls", [
@@ -505,8 +554,12 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
     hold the matrix products cost 13-29 us a tile on the chip). Where the
     whole router is held (Trinity's, Keye's) the combine's block is 64
     tokens, one tile of 512 each, and its loop has one size and reads no
-    sums; the four that hold a part keep 256 tokens and their ``smalls``:
-    their programs did not move. Around them no scatter, no gather of a value an assignment (``route_topk``'s chosen
+    sums; the four that hold a part keep 256 tokens and their ``smalls``,
+    and have one loop more: those of their sizes write a block's first tile
+    and read no sums either, and the last, of the whole size, reads a
+    block's sums back and adds a further tile to them. The sums are
+    allocated in all six: no ``broadcast`` fills them. Around them no
+    scatter, no gather of a value an assignment (``route_topk``'s chosen
     scores are a comparison reduced inside one fusion: no ``[tokens, top_k,
     width]`` array leaves one), and the tiles' buffer is allocated with its
     last row zeroed in place, never filled, and neither it nor the sums nor
@@ -525,11 +578,12 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
         p, x, top_k)).lower(p, x).compile().as_text()
     lines = [re.sub(r"\{[^}]*\}", "", line) for line in text.splitlines()]
     loops = [line for line in lines if " while(" in line]
+    combines = len(smalls) + (held != width)  # a part held: the adding loop
     assert sum(stacked in line for line in loops) == 2
-    assert sum(sums in line for line in loops) == len(smalls)
+    assert sum(sums in line for line in loops) == combines
     assert not any(stacked in line and sums in line for line in loops)
     # and as a trace names them, by the benchmark's own patterns
-    for metric, found in ((matmul_ms, 2), (combine_ms, len(smalls))):
+    for metric, found in ((matmul_ms, 2), (combine_ms, combines)):
         if metric is None:  # read by the part's name, not by a shape
             continue
         assert sum(bool(re.search(_metric_pattern(metric), loop))
@@ -569,22 +623,39 @@ def test_expert_layer_compiles_with_the_loops_the_metrics_look_for(
         "(")[0] for line in written)
     # the buffer is written where it lies: by the zero row's fusion, of no
     # operand (no ``broadcast`` fills it), and once in each size's loop; the
-    # sums by the fill with zeros (allocated and no more where the whole
-    # router is held: every block is written) and once in each size's loop
-    # of the combine. No ``copy`` of either: not from one loop to the next, not on
-    # to the combine; and none of an expert's matrices into fast memory
+    # sums are allocated and no more (every block is written, whatever is
+    # held) and written once in each loop of the combine: where the whole
+    # router is held by a ``dynamic-update-slice`` at a looked-up row behind
+    # the product, where a part is held by the product's own fusion, which
+    # sees the sums a block a page (``pages``: a ``bitcast``) and writes its
+    # page. No ``copy`` of any: not from one loop to the next, not on to the
+    # combine; and none of an expert's matrices into fast memory
     buffer = "bf16[%d,%d]" % ((-(-n * top_k // tile) + held) * tile + 1, dim)
-    for array, sizes in ((buffer, 2), (sums, len(smalls))):
+    pages = "f32[%d,%d,%d]" % (n // block, block, dim)
+    for array, sizes in ((buffer, 2), (sums, combines * (held == width)),
+                         (pages, combines * (held != width) - 1)):
         makes = [line for line in written if line.split(" = ")[1].startswith(
             array) and not any(f" {op}(" in line for op in (
                 "get-tuple-element", "parameter", "bitcast"))]
         assert not any(" copy(" in line for line in makes), makes
         assert all(" fusion(" in line or " dynamic-update-slice(" in line
-                   or " broadcast(" in line or "AllocateBuffer" in line
-                   for line in makes), makes
+                   or "AllocateBuffer" in line for line in makes), makes
         assert len(makes) == sizes + 1, makes
+        assert array != pages or all(
+            "dynamic-update-slice_fusion" in line for line in makes)
     assert sum(sums in line and 'custom_call_target="AllocateBuffer"' in line
-               for line in text.splitlines()) == (held == width)
+               for line in text.splitlines()) == 1
+    if held == width:
+        assert _loops_text(text, sums) == ONCE
+    # only the adding loop reads a block of the sums back: one fusion takes
+    # the pages and gives one (which the product's adds to what it makes),
+    # where a part is held, and nothing outside a fusion slices them
+    reads = [line for line in lines if line.startswith("%fused_computation")
+             and f": {pages}" in line.split(" -> ")[0]
+             and line.split(" -> ")[1].startswith(f"f32[1,{block},{dim}]")]
+    assert len(reads) == (held != width), reads
+    assert not any(" dynamic-slice(" in line and (sums in line or pages in line)
+                   for line in written)
     assert not any(re.search(
         r" = bf16\[(%d,%d|%d,%d)\]\S* copy\(" % (dim, hidden, hidden, dim),
         line) for line in text.splitlines())
@@ -603,7 +674,14 @@ def test_granites_expert_layer_compiles_with_tiles_of_1024(v5e):
     experts' stacked weights (its metrics read the part ``moe.experts``,
     whatever is under it), the tiles' buffer of the worst case at that tile,
     356 tiles and the zero row, allocated once and never filled or copied,
-    and every gather of tokens 512 rows."""
+    and every gather of tokens 512 rows. The combine holds half of the
+    router, in blocks of 96 tokens (480 +- 15 held assignments of a tile's
+    512): the 342 blocks' sums are allocated, no ``broadcast`` fills them,
+    and two loops carry them with no ``copy`` between: one over the blocks,
+    whose body writes a block's first tile and slices nothing out of the
+    sums, and one over the further tiles of the few blocks that pass 512,
+    which reads the block back and adds; in both the product's fusion writes
+    its block itself, a page of the sums seen ``[342, 96, 4096]``."""
     import re
 
     from storm_tpu.ops.platform import dispatch_notes
@@ -619,10 +697,39 @@ def test_granites_expert_layer_compiles_with_tiles_of_1024(v5e):
         text = jax.jit(lambda p, x: moe.topk_moe_layer(
             p, x, top_k, router="softmax")).lower(p, x).compile().as_text()
     assert {"expert_tiles=last-512", "combine_tiles=whole",
-            "combine_write=added"} <= set(seen)
+            "combine_write=first"} <= set(seen)
     loops = [re.sub(r"\{[^}]*\}", "", line) for line in _loops(text)]
     assert sum("bf16[36,4096,768]" in line for line in loops) == 2
-    assert sum("f32[32832,4096]" in line for line in loops) == 1  # 342 x 96
+    sums = "f32[32832,4096]"  # 342 x 96
+    carry = [line for line in loops if sums in line]
+    assert len(carry) == 2
+    # the writing loop looks its 342 blocks up, the adding one 640 tiles at
+    # most (327,680 assignments over 512), and neither the other's
+    assert ["s32[342]" in line for line in carry] == [True, False]
+    assert ["s32[640]" in line for line in carry] == [False, True]
+    lines = [re.sub(r"\{[^}]*\}", "", line) for line in text.splitlines()]
+    assert sum(sums in line.split(" custom-call(")[0]
+               and 'custom_call_target="AllocateBuffer"' in line
+               for line in text.splitlines()) == 1
+    assert not any(re.search(r" = f32\[32832,4096\]\S* (copy|broadcast)\(",
+                             line) for line in text.splitlines())
+    # both loops' products write their block themselves, a page of the sums
+    # (a view of them: no ``dynamic-update-slice`` stands behind a product),
+    # and one fusion more takes the pages and gives one: the adding loop's
+    # read of a block, which its product adds to; the writing loop reads none
+    pages = "f32[342,96,4096]"
+    assert sum(" = " + pages + " fusion(" in line
+               and "dynamic-update-slice_fusion" in line
+               for line in lines) == 2
+    assert not any(" dynamic-update-slice(" in line and sums in line
+                   for line in lines if not line.lstrip().startswith("ROOT"))
+    reads = [line for line in lines if line.startswith("%fused_computation")
+             and f": {pages}" in line.split(" -> ")[0]
+             and line.split(" -> ")[1].startswith("f32[1,96,4096]")]
+    assert len(reads) == 1, reads
+    assert not any(" dynamic-slice(" in line and (sums in line or pages
+                                                   in line)
+                   for line in lines if not line.startswith("%"))
     buffer = "bf16[%d,4096]" % ((-(-n * top_k // 1024) + held) * 1024 + 1)
     assert buffer == "bf16[364545,4096]"
     assert sum(buffer in line and 'custom_call_target="AllocateBuffer"' in
@@ -632,8 +739,8 @@ def test_granites_expert_layer_compiles_with_tiles_of_1024(v5e):
     assert " conditional(" not in text and "scatter" not in text
     # a tile's tokens 512 rows a gather (two of them cost less than one of
     # 1,024 rows): two in the loop of 1,024, one in the loop of 512, and the
-    # combine's one of 512
-    assert len(re.findall(r" = bf16\[512,4096\]\S* gather\(", text)) == 4
+    # combine's two loops' one of 512 each
+    assert len(re.findall(r" = bf16\[512,4096\]\S* gather\(", text)) == 5
     assert not re.search(r" = bf16\[1024,4096\]\S* gather\(", text)
 
 
