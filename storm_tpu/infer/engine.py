@@ -37,7 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from storm_tpu.config import BatchConfig, ModelConfig, ShardingConfig
 from storm_tpu.models.registry import ModelDef, build_model, load_or_init
 from storm_tpu.obs import copyledger as _copyledger
-from storm_tpu.obs.profile import new_step_row
+from storm_tpu.obs.profile import ensure_installed, new_step_row, setup_span
 from storm_tpu.ops.parts import HEAD
 from storm_tpu.ops.platform import dispatch_notes
 from storm_tpu.parallel.mesh import make_mesh
@@ -454,12 +454,22 @@ def enable_compile_cache() -> str:
     frame, because without full tracebacks jax writes the names in a form
     from which XLA drops the scopes), with paths written relative to the
     checkout (the same tree at another path still hits)."""
+    # the set-up log hears every compile and cache look-up from here on
+    ensure_installed()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update(
             "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
     key_on_metadata()
     return jax.config.jax_compilation_cache_dir
+
+
+def profile_key_of(model_cfg: ModelConfig) -> str:
+    """Which curve an engine of ``model_cfg`` feeds in the process
+    ProfileStore. Checkpoint-qualified so cascade tiers / swap variants
+    sharing a registry name keep separate curves."""
+    ckpt = getattr(model_cfg, "checkpoint", None)
+    return f"{model_cfg.name}@{ckpt}" if ckpt else model_cfg.name
 
 
 class InferenceEngine:
@@ -473,12 +483,13 @@ class InferenceEngine:
     ) -> None:
         self.model_cfg = model_cfg
         self.sharding_cfg = sharding_cfg or ShardingConfig()
-        self.model: ModelDef = build_model(
-            model_cfg.name,
-            num_classes=model_cfg.num_classes,
-            input_shape=tuple(model_cfg.input_shape),
-            **getattr(model_cfg, "extra", {}),
-        )
+        with setup_span("model.build"):
+            self.model: ModelDef = build_model(
+                model_cfg.name,
+                num_classes=model_cfg.num_classes,
+                input_shape=tuple(model_cfg.input_shape),
+                **getattr(model_cfg, "extra", {}),
+            )
         # The configured buckets, none over the rows the model lets a step
         # hold (None for most: the policy as given, the same object).
         self.max_rows = self.model.max_rows
@@ -519,11 +530,13 @@ class InferenceEngine:
             axis2, size2 = "expert", self.ep
         else:
             axis2, size2 = None, tp_req
-        self.mesh = mesh if mesh is not None else make_mesh(
-            self.sharding_cfg.data_parallel,
-            size2,
-            ("data", axis2) if axis2 else self.sharding_cfg.axis_names,
-        )
+        # the first jax.devices() of a process opens the chip
+        with setup_span("devices"):
+            self.mesh = mesh if mesh is not None else make_mesh(
+                self.sharding_cfg.data_parallel,
+                size2,
+                ("data", axis2) if axis2 else self.sharding_cfg.axis_names,
+            )
         self.data_axis = ("data" if axis2
                           else self.sharding_cfg.axis_names[0])
         # Multi-process serving (global mesh spanning several OS
@@ -639,22 +652,9 @@ class InferenceEngine:
         self._w8 = getattr(model_cfg, "weights", "float") in (
             "int8", "int8_fused")
         self._w8_fused = getattr(model_cfg, "weights", "float") == "int8_fused"
-        if self._w8:
-            # int8 weights + scales live in HBM; dequant happens inside the
-            # jit program (fused), so the stored footprint is ~1/2 of bf16.
-            # Non-quantized leaves (biases, norm params) still get the
-            # compute-dtype cast — an f32 bias-add would promote every
-            # downstream activation to f32 and defeat w8a16.
-            qtree = jax.tree.map(
-                lambda l: l if _is_qleaf(l) else (
-                    l.astype(self.dtype) if l.dtype == jnp.float32 else l),
-                quantize_params(params), is_leaf=_is_qleaf,
-            )
-            self.params = place_params(qtree)
-        else:
-            params = cast(params)  # the float32 tree goes before another is made
-            self.params = place_params(self._served(params))
-        self.state = jax.device_put(_hostify(state), replicated(self.mesh))
+        with setup_span("parameters.serve") as span:
+            self._place(params, state, cast, place_params, _hostify)
+            span.attrs["bytes"] = self.param_bytes()
         # jit must pin params to their committed placement (replicated OR
         # TP-sharded) — read the shardings off the placed arrays so both
         # paths share one code path.
@@ -738,13 +738,34 @@ class InferenceEngine:
         # path). The inference operator wires it to the flight recorder.
         self.on_compile = None
         # Cost-profile identity: which curve this engine's batches feed in
-        # the process ProfileStore. Checkpoint-qualified so cascade tiers /
-        # swap variants sharing a registry name keep separate curves.
-        ckpt = getattr(model_cfg, "checkpoint", None)
-        self.profile_key = (f"{model_cfg.name}@{ckpt}" if ckpt
-                            else model_cfg.name)
+        # the process ProfileStore.
+        self.profile_key = profile_key_of(model_cfg)
+        # The ``engine.build`` span that made this engine (``shared_engine``;
+        # None for one built directly): its warm-up's spans stand under it.
+        self.build_span = None
         # numbers this engine's dispatches in the step log
         self._step_count = itertools.count()
+
+    def _place(self, params, state, cast, place_params, hostify) -> None:
+        """The loaded tree as this engine serves it, on the mesh: cast to
+        the compute type or quantised, arranged (:meth:`_served`) and
+        placed; the state beside it, replicated."""
+        if self._w8:
+            # int8 weights + scales live in HBM; dequant happens inside the
+            # jit program (fused), so the stored footprint is ~1/2 of bf16.
+            # Non-quantized leaves (biases, norm params) still get the
+            # compute-dtype cast — an f32 bias-add would promote every
+            # downstream activation to f32 and defeat w8a16.
+            qtree = jax.tree.map(
+                lambda l: l if _is_qleaf(l) else (
+                    l.astype(self.dtype) if l.dtype == jnp.float32 else l),
+                quantize_params(params), is_leaf=_is_qleaf,
+            )
+            self.params = place_params(qtree)
+        else:
+            params = cast(params)  # the float32 tree goes before another is made
+            self.params = place_params(self._served(params))
+        self.state = jax.device_put(hostify(state), replicated(self.mesh))
 
     def _served(self, params):
         """The model's own arrangement of its float tree for the steps this
@@ -822,8 +843,10 @@ class InferenceEngine:
             n = self.pad_batch(b)
             if n in self.compiled_batches:
                 continue
-            x = np.zeros((n, *self.input_shape), self.in_dtype)
-            np.asarray(self.predict(x))
+            with setup_span("warmup.bucket", under=self.build_span,
+                            bucket=b, padded=n):
+                x = np.zeros((n, *self.input_shape), self.in_dtype)
+                np.asarray(self.predict(x))
         if any(self.program_forms.values()):
             logger.info("engine %s programs by bucket: %s", self.model_cfg.name,
                         "; ".join(f"{b}: {f}" for b, f
@@ -914,11 +937,20 @@ class InferenceEngine:
         if ofs < buf.shape[0]:
             buf[ofs:] = 0
 
-    def _dispatch_phase(self, handle: InflightBatch,
-                        parts: Sequence[np.ndarray]) -> None:
-        t0 = time.perf_counter()
+    def _program_span(self, padded: int, step: Optional[dict] = None):
+        """The ``program`` span of a cold bucket's first dispatch: stage,
+        put, trace, lowering, cache look-up or compile, and launch. Its
+        milliseconds are what ``on_compile`` and the profile's compile table
+        are handed."""
+        return setup_span("program", padded=padded, engine=self.profile_key,
+                          step=step["step"] if step else None)
+
+    def _stage_and_launch(self, handle: InflightBatch,
+                          parts: Sequence[np.ndarray], cold: bool):
+        """Stage ``parts`` into a pooled buffer, put it and launch the
+        bucket's program: ``(buffer, when it was staged, the program's
+        result)``."""
         padded, n = handle.padded, handle.n
-        cold = padded not in self.compiled_batches
         if self._quantize:
             # Stage at full precision first (range must come from the real
             # rows), then affine-quantize IN PLACE in the f32 buffer and
@@ -969,6 +1001,21 @@ class InferenceEngine:
             with self._lock:
                 xd = jax.device_put(buf, self._x_sharding)
                 out = self._fwd(self.params, self.state, xd)
+        return buf, t_put, out
+
+    def _dispatch_phase(self, handle: InflightBatch,
+                        parts: Sequence[np.ndarray]) -> None:
+        t0 = time.perf_counter()
+        padded, n = handle.padded, handle.n
+        cold = padded not in self.compiled_batches
+        if cold:
+            # the cliff with its cause: a row of the set-up log, under the
+            # warm-up's bucket or, where traffic met the bucket cold, a root
+            with self._program_span(padded, handle.step) as span:
+                buf, t_put, out = self._stage_and_launch(handle, parts, cold)
+                span.attrs["form"] = self.program_forms.get(padded)
+        else:
+            buf, t_put, out = self._stage_and_launch(handle, parts, cold)
         t1 = time.perf_counter()
         if handle.step is not None:
             wall = time.time()
@@ -982,12 +1029,7 @@ class InferenceEngine:
                            engine=self.profile_key or "-")
         self.compiled_batches.add(padded)
         if cold:
-            _report_compile(self.profile_key, padded, (t1 - t0) * 1e3)
-            if self.on_compile is not None:
-                try:
-                    self.on_compile(padded, (t1 - t0) * 1e3)
-                except Exception:
-                    pass  # an observability hook must never fail a batch
+            self._report_cold(padded, span.ms)
         if self._has_aux:
             out, handle.aux = out
         hold = self._chaos_hang_s()
@@ -1079,8 +1121,23 @@ class InferenceEngine:
         cover end to end (see :meth:`_gather_locked`)."""
         n = x.shape[0]
         padded = self.pad_batch(n)
-        cold = padded not in self.compiled_batches
-        t_compile = time.perf_counter() if cold else 0.0
+        if padded in self.compiled_batches:
+            out, gathered = self._serial_step(x, n, padded)
+        else:
+            with self._program_span(padded) as span:
+                out, gathered = self._serial_step(x, n, padded)
+                span.attrs["form"] = self.program_forms.get(padded)
+            self._report_cold(padded, span.ms)
+        if gathered is None:
+            # single-process: the host fetch happens OUTSIDE the lock so
+            # one batch's device->host RTT doesn't serialize the next
+            # batch's dispatch
+            gathered = np.asarray(out)
+        return gathered[:n]
+
+    def _serial_step(self, x: np.ndarray, n: int, padded: int):
+        """Pad, cast, put and run ``x``: ``(the program's result, what the
+        processes gathered of it or None)``."""
         if self._quantize:
             # Range from the real rows only (padding would drag lo to 0).
             lo = float(x.min())
@@ -1109,20 +1166,17 @@ class InferenceEngine:
                     out = out[0]
                 gathered = self._gather_locked(out)
         self.compiled_batches.add(padded)
-        if cold:
-            ms = (time.perf_counter() - t_compile) * 1e3
-            _report_compile(self.profile_key, padded, ms)
-            if self.on_compile is not None:
-                try:
-                    self.on_compile(padded, ms)
-                except Exception:
-                    pass  # an observability hook must never fail a batch
-        if gathered is None:
-            # single-process: the host fetch happens OUTSIDE the lock so
-            # one batch's device->host RTT doesn't serialize the next
-            # batch's dispatch
-            gathered = np.asarray(out)
-        return gathered[:n]
+        return out, gathered
+
+    def _report_cold(self, padded: int, ms: float) -> None:
+        """A cold bucket's first dispatch took ``ms`` (its ``program``
+        span's): to the profile's compile table and the operator's hook."""
+        _report_compile(self.profile_key, padded, ms)
+        if self.on_compile is not None:
+            try:
+                self.on_compile(padded, ms)
+            except Exception:
+                pass  # an observability hook must never fail a batch
 
     def _gather_locked(self, out) -> "Optional[np.ndarray]":
         """Multi-process results fetch — a cross-process COLLECTIVE
@@ -1236,7 +1290,10 @@ def shared_engine(
     # waiters (no timeout) permanently.
     engine = None
     try:
-        engine = InferenceEngine(model_cfg, sharding_cfg, batch_cfg)
+        with setup_span("engine.build",
+                        engine=profile_key_of(model_cfg)) as span:
+            engine = InferenceEngine(model_cfg, sharding_cfg, batch_cfg)
+        engine.build_span = span
         if _insert_would_exceed_budget(engine):
             # Collect BEFORE taking the lock: an engine held only by a
             # reference cycle (e.g. a completed swap's rollback closure)

@@ -592,6 +592,8 @@ def _profile_cmd(args) -> int:
                          for name, a, b in RECORD_INTERVALS
                          if slow.get(a) is not None
                          and slow.get(b) is not None))
+    for line in _setup_lines(out.get("profile", {}).get("setup") or {}):
+        print(line)
     slo = out.get("slo")
     if slo:
         print(f"slo: fast_burn={slo.get('fast_burn')} "
@@ -609,6 +611,62 @@ def _profile_cmd(args) -> int:
               f"{r['live_ms']}ms vs baseline {r['baseline_ms']}ms "
               f"(x{r['ratio']})")
     return 0
+
+
+def _setup_lines(setup: dict) -> list:
+    """The set-up log as ``storm-tpu profile`` prints it: the one-line
+    summary, then the tree of the program's spans, each with its seconds,
+    when it began after the first row, and its attributes; under each, JAX's
+    rows in one line, and every backend compile of a tenth of a second or
+    more by name with what the cache did."""
+    from storm_tpu.obs.profile import setup_line, setup_tree
+
+    rows = setup.get("rows") or []
+    if not rows:
+        return []
+    zero = min(r["t_start"] for r in rows)
+    lines = ["set-up log, " + str(setup["count"]) + " rows: "
+             + setup_line(setup["summary"])]
+    jax_rows: dict = {}
+    for r in rows:
+        if r["name"].startswith("jax."):
+            jax_rows.setdefault(r["parent"], []).append(r)
+
+    def jax_lines(parent, pad):
+        mine = jax_rows.get(parent, [])
+        if not mine:
+            return
+        said = []
+        for name in ("jax.trace", "jax.lower", "jax.backend_compile"):
+            rs = [r for r in mine if r["name"] == name]
+            said.append(f"{len(rs)} {name[4:]} "
+                        f"{sum(r['t_end'] - r['t_start'] for r in rs):.2f}s")
+        caches = [r["attrs"].get("cache") for r in mine
+                  if r["name"] == "jax.backend_compile"]
+        lines.append(f"{pad}jax: " + ", ".join(said) + " ("
+                     + ", ".join(f"{caches.count(c)} {c}"
+                                 for c in ("hit", "written", "none")) + ")")
+        for r in mine:
+            took = r["t_end"] - r["t_start"]
+            if r["name"] == "jax.backend_compile" and took >= 0.1:
+                a = r["attrs"]
+                lines.append(
+                    f"{pad}  backend_compile {a.get('fun_name')} {took:.2f}s "
+                    f"cache={a.get('cache')}"
+                    + (f" retrieval={a['retrieval_s']:.2f}s"
+                       if "retrieval_s" in a else ""))
+
+    for depth, r in setup_tree([r for r in rows
+                                if not r["name"].startswith("jax.")]):
+        pad = "  " * (depth + 1)
+        lines.append(f"{pad}{r['name']} {r['t_end'] - r['t_start']:.2f}s "
+                     f"at +{r['t_start'] - zero:.2f}s"
+                     + "".join(f" {k}={v}" for k, v in r["attrs"].items()))
+        jax_lines(r["span"], pad + "  ")
+    if None in jax_rows:
+        lines.append("  under no span:")
+        jax_lines(None, "    ")
+    return lines
 
 
 def _scorecard_cmd(args) -> int:
@@ -1015,6 +1073,20 @@ def _lint_cmd(args) -> int:
     return 1 if new else 0
 
 
+def _enter() -> None:
+    """What the run and serve entries do before anything compiles: place the
+    compile cache (from where on the set-up log hears JAX's compiles) and
+    load the native parser, as the ``entry`` span of the set-up log."""
+    from storm_tpu.obs.profile import setup_span
+
+    with setup_span("entry") as span:
+        from storm_tpu.infer.engine import enable_compile_cache
+        from storm_tpu.native import native_available
+
+        span.attrs["compile_cache"] = enable_compile_cache()
+        span.attrs["native"] = native_available()
+
+
 def main(argv=None) -> int:
     setup_logging()
     ap = argparse.ArgumentParser(prog="storm_tpu")
@@ -1312,9 +1384,7 @@ def main(argv=None) -> int:
                 "are ignored",
                 file=sys.stderr,
             )
-        from storm_tpu.infer.engine import enable_compile_cache
-
-        enable_compile_cache()
+        _enter()
         asyncio.run(_run_daemon(args.name, cfg, args.duration,
                                 args.autoscale_target_ms, args.ui_port,
                                 args.metrics_file, args.metrics_interval,
@@ -1523,10 +1593,9 @@ def main(argv=None) -> int:
         cfg = _load_config(args)
         if args.model:
             cfg.model.name = args.model
-        from storm_tpu.infer.engine import enable_compile_cache
         from storm_tpu.serve import InferenceWorker
 
-        enable_compile_cache()
+        _enter()
         worker = InferenceWorker(cfg.model, cfg.sharding, cfg.batch,
                                  port=args.port)
         worker.start()

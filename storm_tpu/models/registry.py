@@ -160,14 +160,23 @@ def _canon(v):
 
 def load_or_init(model: ModelDef, checkpoint: Optional[str], seed: int = 0):
     """Load params/state from an orbax checkpoint dir, or initialize."""
-    params, state = init_params(model, seed)
-    if checkpoint:
-        import orbax.checkpoint as ocp
+    from storm_tpu.obs.profile import setup_span
 
-        _check_hyper(model, checkpoint)
-        with ocp.StandardCheckpointer() as ckptr:
-            restored = ckptr.restore(checkpoint, {"params": params, "state": state})
-        params, state = restored["params"], restored["state"]
+    with setup_span("parameters",
+                    source="checkpoint" if checkpoint else "seed") as span:
+        params, state = init_params(model, seed)
+        if checkpoint:
+            import orbax.checkpoint as ocp
+
+            _check_hyper(model, checkpoint)
+            with ocp.StandardCheckpointer() as ckptr:
+                restored = ckptr.restore(
+                    checkpoint, {"params": params, "state": state})
+            params, state = restored["params"], restored["state"]
+        leaves = [a for a in jax.tree.leaves((params, state))
+                  if hasattr(a, "nbytes")]
+        span.attrs.update(leaves=len(leaves),
+                          bytes=sum(int(a.nbytes) for a in leaves))
     return params, state
 
 

@@ -7,7 +7,11 @@ needs before it can solve for a config:
 
 - :mod:`storm_tpu.obs.profile` — :class:`ProfileStore`, per-(engine,
   bucket) stage-cost curves + XLA compile cost per shape, fed by the
-  engine layer's profile sink; snapshot/reload as JSON.
+  engine layer's profile sink; snapshot/reload as JSON. Beside the
+  curves, three logs on one clock and under one switch: the step log (a
+  row a device step), the record log (a row a root tuple) and the set-up
+  log (a row a span of a start, with JAX's compiles and cache look-ups
+  under the span that caused them).
 - :mod:`storm_tpu.obs.slo` — :class:`SloBurnTracker`, multi-window
   error-budget burn from the sink's delivered/slo_breaches counters;
   an additional hot signal for the LoadShedController.

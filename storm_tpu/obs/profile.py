@@ -17,6 +17,14 @@ dict/histogram updates per BATCH (not per record), on the engine's fetch
 thread — what the step and record logs cost on the chip is PERF.md §6,
 PRs 41 and 54.
 
+Beside the curves the store keeps three logs, on one clock (``time.time()``)
+and under one switch (:func:`set_enabled`): the step log (a row a device
+step), the record log (a row a root tuple) and the set-up log (a row a span
+of a start: :func:`setup_span` where the program's own work happens, and
+every trace, lowering and backend compile JAX reports, with its cache
+look-up, under the span that caused it). The set-up log runs no line per
+step or per record.
+
 The snapshot round-trips: ``storm-tpu profile <topology> --json`` writes
 it as versioned JSON, and a later run loads
 that file back as the regression sentinel's baseline
@@ -25,6 +33,8 @@ that file back as the regression sentinel's baseline
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import threading
 import time
 from collections import deque
@@ -79,6 +89,31 @@ RECORD_PATH = RECORD_MOMENTS[:6] + STEP_MOMENTS[1:] + RECORD_MOMENTS[6:]
 # and ``resolved->egress`` cross from one log to the other through the key
 RECORD_INTERVALS = tuple((f"{a[2:]}->{b[2:]}", a, b)
                          for a, b in zip(RECORD_PATH, RECORD_PATH[1:]))
+
+
+# The set-up log: the last this many spans of the process's starts, one row a
+# span, written where the span ends (so a child stands before its parent).
+SETUP_LOG = 1024
+# a row of the log, in this order (docs/OPERATIONS.md, "Reading the set-up
+# log"): ``span`` counts the process's spans, ``parent`` is the span that
+# was open in the calling context when this one began (None: a root),
+# ``t_start`` and ``t_end`` are on the step log's clock, ``attrs`` is a small
+# flat dict
+SETUP_FIELDS = ("span", "parent", "name", "t_start", "t_end", "thread",
+                "attrs")
+# JAX's own time spans (its dispatch module's), by the name of their row
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_COMPILE = "/jax/core/compile/backend_compile_duration"
+JAX_SPANS = {_JAX_TRACE: "jax.trace", _JAX_LOWER: "jax.lower",
+             _JAX_COMPILE: "jax.backend_compile"}
+# and its persistent cache's events (its compiler's and its compilation
+# cache's), stamped onto the backend compile that closes next on their thread
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_WRITTEN = "/jax/compilation_cache/cache_misses"  # where an entry is written
+_CACHE_SECONDS = {"/jax/compilation_cache/cache_retrieval_time_sec":
+                  "retrieval_s",
+                  "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
 
 
 class RecordRow:
@@ -206,6 +241,176 @@ def longest_gap(rows: List[dict]) -> Optional[dict]:
             "over_median_ms": over[worst] * 1e3 if worst else None}
 
 
+class setup_span:
+    """One span of a start: ``with setup_span("parameters", source="seed")
+    as span`` (the names are in docs/OPERATIONS.md, "Reading the set-up
+    log"). It stamps ``time.time()`` on the way in and on the way out,
+    is the parent of what begins in its context while it is open (a
+    ``contextvars`` variable, so it follows ``asyncio.to_thread`` and the
+    tasks a coroutine makes), and writes its row of the set-up log as it
+    ends. ``attrs`` may be added to until then; ``ms`` is its length, for a
+    caller that reports it elsewhere. ``under`` names its parent where that
+    is not the span open in the calling context: an engine's warm-up under
+    the build that made the engine, whoever calls it. With the store off it
+    is a pair of stamps: no number, no parent, no row."""
+
+    __slots__ = ("span", "parent", "name", "t_start", "t_end", "attrs",
+                 "_above", "_before")
+
+    def __init__(self, name: str, under: "Optional[setup_span]" = None,
+                 **attrs) -> None:
+        self.name, self.attrs = name, attrs
+        self.span = self.parent = self.t_end = None
+        self._above = under
+
+    def __enter__(self) -> "setup_span":
+        self.t_start = time.time()
+        if _ENABLED:
+            above = self._above if self._above is not None else _open_span()
+            self.span = next(_SPAN_IDS)
+            self.parent = None if above is None else above.span
+            self._above = above
+            self._before = _CURRENT.get()
+            _CURRENT.set(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t_end = time.time()
+        if self.span is not None:
+            _CURRENT.set(self._before)
+            _STORE.log_setup((self.span, self.parent, self.name, self.t_start,
+                              self.t_end, threading.current_thread().name,
+                              self.attrs))
+        return False
+
+    @property
+    def ms(self) -> float:
+        return (self.t_end - self.t_start) * 1e3
+
+
+_SPAN_IDS = itertools.count(1)
+_CURRENT: "contextvars.ContextVar[Optional[setup_span]]" = \
+    contextvars.ContextVar("storm_tpu_setup_span", default=None)
+
+
+def _open_span() -> Optional[setup_span]:
+    """The innermost span of the calling context that is still open. A
+    context copied while a span was open (a task made in ``prepare``) keeps
+    naming it after it has ended: what begins there later has no cause among
+    the spans, and is a root."""
+    span = _CURRENT.get()
+    while span is not None and span.t_end is not None:
+        span = span._above
+    return span
+
+
+def union_seconds(rows: List[dict]) -> float:
+    """The seconds some row of ``rows`` covers: overlapping and nested rows
+    count once."""
+    total, end = 0.0, float("-inf")
+    for a, b in sorted((r["t_start"], r["t_end"]) for r in rows):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def setup_under(rows: List[dict], root: Optional[int]) -> List[dict]:
+    """The rows of ``rows`` that descend from the span ``root``, itself
+    among them (all of them where ``root`` is None)."""
+    if root is None:
+        return list(rows)
+    parent = {r["span"]: r["parent"] for r in rows}
+
+    def below(span) -> bool:
+        while span is not None and span != root:
+            span = parent.get(span)
+        return span == root
+
+    return [r for r in rows if below(r["span"])]
+
+
+def setup_summary(rows: List[dict], root: Optional[int] = None) -> dict:
+    """What a start cost, by what it went to, over the rows under ``root``
+    (``ProfileStore.setup()``; a ``topology.submit`` span, or None for the
+    whole log): ``total_s`` (the root's seconds; the union of the rows
+    without one), ``parameters_s`` (initialising or restoring them),
+    ``serve_s`` (casting, arranging and placing them), ``programs_s`` (a
+    cold bucket's stage, put, trace, lowering, cache look-up or compile, and
+    launch, inside a warm-up), ``programs_loaded`` and ``programs_compiled``
+    (those whose backend compiles were all cache hits, and those with one
+    that was not), ``first_runs_s`` (a warm-up's bucket less its program:
+    the first execution and its fetch), ``cold_in_traffic`` (programs that
+    no warm-up met: a root ``program`` row, the cliff) and ``other_s``.
+    Every figure is a union of intervals, so spans that overlap on two
+    threads count once."""
+    rows = setup_under(rows, root)
+    by_span = {r["span"]: r for r in rows}
+
+    def named(name):
+        return [r for r in rows if r["name"] == name]
+
+    def ancestors(r):
+        seen = by_span.get(r["parent"])
+        while seen is not None:
+            yield seen
+            seen = by_span.get(seen["parent"])
+
+    head = by_span.get(root)
+    warm = named("warmup.bucket")
+    programs = [r for r in named("program")
+                if any(a["name"] == "warmup.bucket" for a in ancestors(r))]
+    all_hits: Dict[int, bool] = {}  # a program's backend compiles, by span
+    for r in named("jax.backend_compile"):
+        for a in ancestors(r):
+            if a["name"] == "program":
+                all_hits[a["span"]] = all_hits.get(a["span"], True) \
+                    and r["attrs"].get("cache") == "hit"
+    out = {
+        "total_s": (head["t_end"] - head["t_start"]) if head
+        else union_seconds(rows),
+        "parameters_s": union_seconds(named("parameters")),
+        "serve_s": union_seconds(named("parameters.serve")),
+        "programs_s": union_seconds(programs),
+        "programs_loaded": sum(all_hits.get(r["span"]) is True
+                               for r in programs),
+        "programs_compiled": sum(all_hits.get(r["span"]) is False
+                                 for r in programs),
+        "first_runs_s": union_seconds(warm) - union_seconds(programs),
+        "cold_in_traffic": sum(r["parent"] is None
+                               for r in named("program")),
+    }
+    out["other_s"] = out["total_s"] - union_seconds(
+        named("parameters") + named("parameters.serve") + warm)
+    return out
+
+
+def setup_line(summary: dict) -> str:
+    """:func:`setup_summary` in the one line ``submit`` logs."""
+    return ("set-up {total_s:.1f} s: parameters {parameters_s:.1f}, serve "
+            "{serve_s:.1f}, programs {programs_s:.1f} ({programs_loaded} "
+            "loaded, {programs_compiled} compiled), first runs "
+            "{first_runs_s:.1f}, other {other_s:.1f}").format(**summary)
+
+
+def setup_tree(rows: List[dict]) -> List[tuple]:
+    """``(depth, row)`` of every row in the order of a tree: a root, then
+    what it caused, each level by ``t_start``. A row whose parent the log no
+    longer holds stands as a root."""
+    held = {r["span"] for r in rows}
+    below: Dict[Optional[int], List[dict]] = {}
+    for r in sorted(rows, key=lambda r: r["t_start"]):
+        below.setdefault(r["parent"] if r["parent"] in held else None,
+                         []).append(r)
+    out, stack = [], [(0, r) for r in reversed(below.get(None, []))]
+    while stack:
+        depth, r = stack.pop()
+        out.append((depth, r))
+        stack.extend((depth + 1, c) for c in reversed(below.get(r["span"],
+                                                                [])))
+    return out
+
+
 class _Bucket:
     __slots__ = ("stages", "batches", "rows")
 
@@ -230,6 +435,7 @@ class ProfileStore:
         self._baseline: Optional[dict] = None
         self._steps: deque = deque(maxlen=STEP_LOG)
         self._records: deque = deque(maxlen=RECORD_LOG)
+        self._setup: deque = deque(maxlen=SETUP_LOG)
 
     # ---- the write path (engine layer) ---------------------------------------
 
@@ -265,7 +471,15 @@ class ProfileStore:
         with self._lock:
             self._records.append(row)
 
+    def log_setup(self, row: tuple) -> None:
+        """One ended span of a start (:class:`setup_span`, or one of JAX's
+        own): its row of the set-up log, in ``SETUP_FIELDS`` order."""
+        with self._lock:
+            self._setup.append(row)
+
     def record_compile(self, key: str, padded: int, ms: float) -> None:
+        """A cold bucket's first dispatch: ``ms`` is its ``program`` span's
+        (``infer/engine.py``), the one timer of it."""
         with self._lock:
             per = self._compiles.setdefault(key, {})
             c = per.get(int(padded))
@@ -282,6 +496,7 @@ class ProfileStore:
             self._compiles.clear()
             self._steps.clear()
             self._records.clear()
+            self._setup.clear()
 
     # ---- the read path -------------------------------------------------------
 
@@ -299,6 +514,15 @@ class ProfileStore:
             rows = list(self._records)
         return [dict(zip(RECORD_FIELDS, r)) for r in rows]
 
+    def setup(self) -> List[dict]:
+        """The set-up log, oldest first by where a span ended: the last
+        ``SETUP_LOG`` rows, each a dict of ``SETUP_FIELDS`` (``attrs`` a
+        copy)."""
+        with self._lock:
+            rows = list(self._setup)
+        return [dict(zip(SETUP_FIELDS, r[:-1] + (dict(r[-1]),)))
+                for r in rows]
+
     def snapshot(self) -> dict:
         """JSON-safe curves: per engine, per padded bucket, per stage
         {count, mean, p50, p95, max} plus rows/s throughput; compile cost
@@ -308,8 +532,16 @@ class ProfileStore:
         (:func:`longest_gap`). ``records``: how many rows the record log
         holds, every interval's median and 90th percentile over them
         (:func:`record_intervals`), and the delivered record that took
-        longest from append to produce, with its step's moments."""
+        longest from append to produce, with its step's moments. ``setup``:
+        the set-up log's rows, per name of a span how many there are and the
+        seconds they cover (:func:`union_seconds`: rows that overlap count
+        once), and what the starts cost by what it went to
+        (:func:`setup_summary` over the whole log)."""
         rows = self.steps()
+        spans = self.setup()
+        by_name: Dict[str, List[dict]] = {}
+        for r in spans:
+            by_name.setdefault(r["name"], []).append(r)
         paths = record_paths(self.records(), rows)
         whole = [r for r in paths if r["t_produced"] is not None]
         return {"engines": self._engines(),
@@ -319,7 +551,12 @@ class ProfileStore:
                             "intervals": record_intervals(paths),
                             "slowest": max(
                                 whole, default=None, key=lambda r:
-                                r["t_produced"] - r["t_append"])}}
+                                r["t_produced"] - r["t_append"])},
+                "setup": {"count": len(spans), "rows": spans,
+                          "by_name": {n: {"count": len(rs),
+                                          "seconds": union_seconds(rs)}
+                                      for n, rs in sorted(by_name.items())},
+                          "summary": setup_summary(spans)}}
 
     def _engines(self) -> Dict[str, dict]:
         """The curves of :meth:`snapshot`, alone."""
@@ -478,20 +715,102 @@ def profile_store() -> ProfileStore:
     return _STORE
 
 
+# what JAX's listeners keep of a thread between two of its events: how deep
+# in traces it is, and the cache events since its last backend compile
+_JAX_THREAD = threading.local()
+_JAX_LISTENING = False
+
+
+def _jax_enter(event: str, _start: float, **_kw) -> None:
+    # JAX's scalar event as one of its spans begins. A program's trace holds
+    # one trace of every jitted function it calls (816 of them in a four-
+    # bucket warm-up of the toy ViT), and a lowering traces what its rules
+    # call: only the outermost of them can become a row.
+    if event != _JAX_TRACE and event != _JAX_LOWER:
+        return
+    mine = _JAX_THREAD.__dict__
+    mine["depth"] = mine.get("depth", 0) + 1
+    if event == _JAX_LOWER:
+        # An eager operation whose executable is in memory is traced again
+        # at every call (217 times while the toy LeNet's parameters are
+        # made, for 23 compiles): a trace becomes a row where a lowering
+        # follows it on its thread, and is forgotten at the next trace.
+        if "trace" in mine:
+            _STORE.log_setup((next(_SPAN_IDS),) + mine.pop("trace"))
+
+
+def _jax_span(event: str, t_start: float, t_end: float, **kw) -> None:
+    name = JAX_SPANS.get(event)
+    if name is None:
+        return
+    mine = _JAX_THREAD.__dict__
+    if event != _JAX_COMPILE:
+        mine["depth"] = depth = max(0, mine.get("depth", 1) - 1)
+        if depth:
+            return
+    attrs = {"fun_name": kw.get("fun_name")}
+    if event == _JAX_COMPILE:
+        attrs.update(mine.pop("cache", None) or {"cache": "none"})
+    if not _ENABLED:
+        mine.pop("trace", None)
+        return
+    above = _open_span()
+    row = (None if above is None else above.span, name, t_start, t_end,
+           threading.current_thread().name, attrs)
+    if event == _JAX_TRACE:
+        mine["trace"] = row  # a row once its lowering begins
+    else:
+        _STORE.log_setup((next(_SPAN_IDS),) + row)
+
+
+def _jax_cache_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT or event == _CACHE_WRITTEN:
+        _JAX_THREAD.__dict__.setdefault("cache", {})["cache"] = \
+            "hit" if event == _CACHE_HIT else "written"
+
+
+def _jax_cache_seconds(event: str, seconds: float, **_kw) -> None:
+    key = _CACHE_SECONDS.get(event)
+    if key is not None:
+        _JAX_THREAD.__dict__.setdefault("cache", {})[key] = seconds
+
+
+def _listen_to_jax() -> None:
+    """Register the set-up log's listeners with ``jax.monitoring``, once a
+    process: they stay whatever the switch says later (``jax.monitoring``
+    takes none back), and off, each returns at its first test."""
+    global _JAX_LISTENING
+    if _JAX_LISTENING:
+        return
+    _JAX_LISTENING = True
+    import jax.monitoring as mon
+
+    mon.register_scalar_listener(_jax_enter)
+    mon.register_event_time_span_listener(_jax_span)
+    mon.register_event_listener(_jax_cache_event)
+    mon.register_event_duration_secs_listener(_jax_cache_seconds)
+
+
 def ensure_installed() -> ProfileStore:
-    """Point the engine layer's profile sink at the singleton (idempotent).
-    Called from the inference operator's ``prepare`` and from bench —
-    importing the engine module lazily so ``obs`` stays importable
-    without pulling jax in."""
+    """Point the engine layer's profile sink at the singleton and the
+    set-up log's listeners at JAX's compile and cache events (idempotent).
+    Called where a process places its compile cache
+    (``infer/engine.py enable_compile_cache``: before its first compile),
+    from the inference operator's ``prepare`` and from bench — importing the
+    engine module lazily so ``obs`` stays importable without pulling jax
+    in."""
     from storm_tpu.infer import engine as _engine
 
     _engine.set_profile_sink(_STORE if _ENABLED else None)
+    if _ENABLED:
+        _listen_to_jax()
     return _STORE
 
 
 def set_enabled(flag: bool) -> None:
     """Profiling kill switch (the overhead A/B's off arm): detaches the
-    engine sink so the hot path pays a single None check per batch."""
+    engine sink so the hot path pays a single None check per batch, and
+    the set-up log writes no row."""
     global _ENABLED
     _ENABLED = bool(flag)
     ensure_installed()

@@ -23,6 +23,12 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple as Tup
 
 from storm_tpu.config import Config
+from storm_tpu.obs.profile import (
+    profile_store,
+    setup_line,
+    setup_span,
+    setup_summary,
+)
 from storm_tpu.runtime.acker import AckLedger
 from storm_tpu.runtime.executor import BoltExecutor, SpoutExecutor, clone_component
 from storm_tpu.runtime.metrics import MetricsRegistry
@@ -595,7 +601,11 @@ class AsyncLocalCluster:
         topology.validate()
         rt = TopologyRuntime(name, topology, config)
         self._topologies[name] = rt
-        await rt.start()
+        with setup_span("topology.submit", topology=name) as span:
+            await rt.start()
+        if span.span is not None:  # the set-up log is on
+            log.info("%s: %s", name, setup_line(setup_summary(
+                profile_store().setup(), span.span)))
         return rt
 
     def runtime(self, name: str) -> TopologyRuntime:

@@ -18,6 +18,7 @@ import time
 import traceback
 from typing import Any, Optional
 
+from storm_tpu.obs.profile import setup_span
 from storm_tpu.runtime.base import Bolt, OutputCollector, Spout, TopologyContext
 from storm_tpu.runtime.tuples import TickTuple, Tuple, is_tick
 
@@ -79,7 +80,9 @@ class BoltExecutor:
             tracer=getattr(self.rt, "tracer", None),
             flight=getattr(self.rt, "flight", None),
         )
-        self.bolt.prepare(ctx, self.collector)
+        with setup_span("component.prepare", component=self.component_id,
+                        task=self.task_index):
+            self.bolt.prepare(ctx, self.collector)
         self._init_state()
         self._task = asyncio.create_task(
             self._run(), name=f"{self.component_id}[{self.task_index}]"
@@ -302,7 +305,9 @@ class SpoutExecutor:
             tracer=getattr(self.rt, "tracer", None),
             flight=getattr(self.rt, "flight", None),
         )
-        self.spout.open(ctx, self.collector)
+        with setup_span("component.prepare", component=self.component_id,
+                        task=self.task_index):
+            self.spout.open(ctx, self.collector)
         self._task = asyncio.create_task(
             self._run(), name=f"{self.component_id}[{self.task_index}]"
         )
