@@ -280,8 +280,8 @@ def multi_head_attention(p: dict, x: jnp.ndarray, num_heads: int) -> jnp.ndarray
     form = attention_form(b, s, c, num_heads, x.dtype.itemsize)
     _note("attention", form)
     if form == "rows":
-        from storm_tpu.ops.short_attention import short_attention
-
+        from storm_tpu.ops.short_attention import keys_form, short_attention
+        _note("rows_keys", keys_form(s))
         out = short_attention(q, k, v, num_heads)  # names itself
     else:
         with jax.named_scope(P.MIX_ELEMENTWISE):

@@ -102,8 +102,10 @@ def check_short_attention(interpret: bool = False) -> List[dict]:
     ``[B, S, H*D]`` operands.
 
     Cases: ViT-g/14's tokens and head width (257 x 16 heads of 88: neither a
-    sublane nor a lane multiple), ViT-B/16's (197 x 12 heads of 64) in the
-    serving dtype, and a toy width in float32."""
+    sublane nor a lane multiple; two tiles of keys and one key a column),
+    ViT-B/16's (197 x 12 heads of 64: two heads a lane tile, the keys whole)
+    in the serving dtype, a toy width in float32, the most keys taken as
+    columns (264 = 256 + 8), and heads of 128 lanes (no mask, no odd key)."""
     import jax
     import jax.numpy as jnp
 
@@ -114,6 +116,8 @@ def check_short_attention(interpret: bool = False) -> List[dict]:
         ("g14_S257_H16_D88", (2, 257, 1408), 16, jnp.bfloat16),
         ("b16_S197_H12_D64", (2, 197, 768), 12, jnp.bfloat16),
         ("toy_S33_H4_D24", (3, 33, 96), 4, jnp.float32),
+        ("odd8_S264_H16_D88", (2, 264, 1408), 16, jnp.bfloat16),
+        ("whole_S256_H8_D128", (2, 256, 1024), 8, jnp.bfloat16),
     ]
     for case, shape, heads, dt in cases:
         q, k, v = (

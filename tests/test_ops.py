@@ -262,7 +262,37 @@ _SHORT_CASES = [
     pytest.param((1, 197, 768), 12, jnp.bfloat16, 2e-2, id="b16-197x768-bf16"),
     pytest.param((3, 33, 96), 4, jnp.float32, 1e-5, id="toy-33x96-f32"),
     pytest.param((2, 8, 40), 5, jnp.float32, 1e-5, id="toy-8x40-f32"),
+    # the kernel's rules (ops/short_attention.py): a head is read in the lane
+    # tile that holds it, one that straddles two folded into one (the cases
+    # above: ten heads of sixteen folded, two heads a tile, a row under a
+    # tile), and up to eight keys past the last whole lane tile are columns
+    # (above: 256 + 1; 197, 33 and 8 whole)
+    pytest.param((2, 257, 1408), 16, jnp.float32, 1e-5, id="g14-257x1408-f32"),
+    pytest.param((1, 197, 768), 12, jnp.float32, 1e-5, id="b16-197x768-f32"),
+    *(pytest.param(shape, heads, dtype, atol,
+                   id=f"{name}-{shape[1]}x{shape[2]}-{tag}")
+      for name, shape, heads in [
+          ("heads128", (2, 256, 1024), 8),  # no mask, no odd key
+          ("tile+1", (2, 129, 352), 4),     # one tile of keys and a column
+          ("odd8", (2, 264, 1408), 16),     # eight columns, the rule's edge
+          ("odd9", (2, 265, 1408), 16),     # nine: the score tile whole
+      ]
+      for tag, dtype, atol in [("f32", jnp.float32, 1e-5),
+                               ("bf16", jnp.bfloat16, 2e-2)]),
 ]
+
+
+@pytest.mark.parametrize("s,form", [
+    (257, "256+1"), (264, "256+8"), (129, "128+1"), (265, "whole"),
+    (256, "whole"), (197, "whole"), (33, "whole"), (8, "whole")])
+def test_short_attention_key_split_by_shape(s, form):
+    """Keys past the last whole lane tile are columns when they are eight or
+    fewer and a whole tile lies before them; the program's note says so."""
+    from storm_tpu.ops.short_attention import key_split, keys_form
+
+    assert keys_form(s) == form
+    n, r = key_split(s)
+    assert n + r == s and (r == 0 or (n % 128 == 0 and 0 < r <= 8))
 
 
 def _qkv(shape, dtype):
@@ -384,7 +414,7 @@ def test_mha_through_the_row_kernel_matches_the_xla_path(monkeypatch):
     monkeypatch.setattr(attention, "attention_form", lambda *a: "rows")
     with dispatch_notes() as seen:
         got = multi_head_attention(p, x, 4)
-    assert seen == ["attention=rows"]
+    assert seen == ["attention=rows", "rows_keys=whole"]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 

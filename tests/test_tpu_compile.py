@@ -252,12 +252,15 @@ def test_keyes_mixer_compiles_with_its_rows_unrolled_and_a_mask_at_a_time(
 
 @pytest.mark.parametrize("shape,heads", [
     ((256, 257, 1408), 16),   # ViT-g/14, the benchmark's largest bucket
+    ((8, 257, 1408), 16),     # and the paced cell's
     ((128, 197, 768), 12),    # ViT-B/16 at batch 128
     ((4, 512, 1024), 8),      # about the longest sequence that still fits
 ])
 def test_short_attention_compiles_for_v5e(v5e, shape, heads):
-    """Heads are lane slices at offsets that are no multiple of 128, and the
-    token count is no multiple of 8: Mosaic has to take both."""
+    """Heads lie at lane offsets that are no multiple of 128 (read in the lane
+    tiles that hold them, two tiles folded into one by a select), and the
+    token count is no multiple of 8 (the 257th key a column): Mosaic has to
+    take both."""
     from storm_tpu.ops.short_attention import _forward, fits
 
     assert fits(shape[1], shape[2], 2)
