@@ -88,6 +88,7 @@ def _load_builtin() -> None:
         mobilenet,
         moe_vit,
         nemotron_h,
+        ouro,
         resnet,
         solar_open2,
         trinity,

@@ -11,13 +11,28 @@ its own); and what the branches counted on the way,
 stacked a layer into ``new_state["aux"]``, which the engine fetches with the
 predictions (``infer/engine.py``).
 
+A plan may run ``passes`` times over its one set of leaves (a looped model):
+the walk over the blocks is then the body of one ``lax.fori_loop``, the last
+norm runs on the *whole* stream after every pass (the next pass starts from
+it) and an exit gate reads each pass's normed last position:
+
+    per pass t: h = blocks(h); h = RMSNorm(h); z_t = h[last];
+                lambda_t = sigmoid(w_exit . z_t + b_exit)
+    p_t = lambda_t prod_{j<t}(1 - lambda_j), p_T the rest; tau = the first t
+    with p_1 + ... + p_t >= threshold (T where none); head(z_tau)
+
+and the step counts, in ``aux["exit_pass"]``, how many of its rows left at
+each pass.
+
 A model's file keeps what is its own: its mixers, its *plan* (a tuple of
 blocks, each a tuple of :class:`Branch`), its scalars, what it makes once a
 step (``context``: rotary tables) and its presets, and hands them to
 :func:`token_scorer`. The parameter tree is ``{"embed", "layers": [{<norm>,
 <name>, ...}, ...], "norm", "head"}`` (no ``"head"`` where the model is
-``tied``); ``split(rng, branches + 2)`` gives the embedding key 0, the head
-key 1 (unused where tied) and every branch of the plan the next.
+``tied``; ``"exit": {"w", "b"}`` beside them where it runs several passes);
+``split(rng, branches + 2)`` gives the embedding key 0, the head key 1
+(unused where tied) and every branch of the plan the next; a looped model
+asks for one key more, its gate's, the last (no other model's draw moves).
 """
 
 from __future__ import annotations
@@ -115,6 +130,40 @@ def experts(norm: str, name: str, init: Callable, *, held: int, top_k: int,
         post=post, post_scale=post_scale)
 
 
+def exit_row(p: dict, lasts: jnp.ndarray, threshold: float) -> tuple:
+    """``(z, left)``: of each pass's normed last position ``lasts (T, B,
+    dim)`` the row each record's answer is read from, ``(B, dim)``, and how
+    many of the ``B`` left at each pass, ``(T,)`` int32. The gate ``lambda_t
+    = sigmoid(w . z_t + b)`` gives pass ``t`` the weight ``lambda_t
+    prod_{j<t}(1 - lambda_j)`` and the last pass the rest; a record leaves
+    at the first pass where the weights so far reach ``threshold``, at the
+    last where none does. At 1 or more none can (a sigmoid is under 1 and a
+    float32's may not be): every record reads the last pass and nothing of
+    the gate is computed."""
+    passes, rows = lasts.shape[:2]
+    if threshold >= 1:
+        return lasts[-1], jnp.zeros((passes,), jnp.int32).at[-1].set(rows)
+    f32 = jnp.float32
+    gate = jax.nn.sigmoid(lasts @ p["w"].astype(f32) + p["b"].astype(f32))
+    stay = jnp.cumprod(1.0 - gate[:-1], axis=0)  # past pass t, t < T
+    before = jnp.concatenate([jnp.ones((1, rows), f32), stay[:-1]])
+    reached = jnp.cumsum(gate[:-1] * before, axis=0) >= threshold
+    tau = jnp.where(reached.any(0), jnp.argmax(reached, axis=0), passes - 1)
+    z = jnp.take_along_axis(lasts, tau[None, :, None], axis=0)[0]
+    return z, jnp.sum(tau[None] == jnp.arange(passes)[:, None], axis=1,
+                      dtype=jnp.int32)
+
+
+def observe_exits(metrics, cid: str, left, *, passes: int) -> None:
+    """What a looped step counted (``left (passes,)``: the step's rows,
+    padding among them, that left at each pass) into the registry under
+    ``cid``: the rows by pass, and the passes the step ran for them (every
+    row runs every pass: a row that has left saves nothing in a batch)."""
+    for t, n in enumerate(left, 1):
+        metrics.counter(cid, f"exit_pass_rows_{t}").inc(int(n))
+    metrics.counter(cid, "passes_run").inc(int(left.sum()) * passes)
+
+
 def token_scorer(name: str, num_classes: int, input_shape: tuple,
                  blocks: tuple, *, dim: int, eps: float, hyper: dict,
                  max_rows: int, scale_emb: float = 1.0,
@@ -122,7 +171,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                  context: Optional[Callable] = None,
                  param_dtype=None, heads: int = 1, tied: bool = False,
                  embed_std: Optional[float] = None,
-                 pin_stream: bool = False) -> ModelDef:
+                 pin_stream: bool = False, passes: int = 1,
+                 threshold: float = 1.0) -> ModelDef:
     """The model of ``blocks`` over ``num_classes`` rows of the vocabulary.
     With ``heads`` prediction heads ``num_classes`` is what an answer holds,
     ``heads`` distributions over ``num_classes / heads`` rows of the
@@ -153,8 +203,19 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
     later norm's fusion: half the stream's bytes a branch it keeps so, 0.67
     GB each at Falcon-H1's 65,536 tokens of 5,120 channels, 1.3 GB of a
     four-layer step's temporaries (PERF.md section 6, PR 66). False: the
-    text every plan before it lowers to."""
+    text every plan before it lowers to.
+    ``passes`` over 1: the blocks run that many times over the same leaves,
+    one ``lax.fori_loop`` whose body is a pass (the program's text is one
+    pass's whatever ``passes`` says; the leaves and ``context`` are closed
+    over, the carry is the stream and the passes' last-position rows), the
+    last norm on the whole stream inside it, and the answer is read from the
+    pass the exit gate's rule names under ``threshold`` (the module's
+    docstring; at 1 or more no sum before the last can reach it, so the
+    program reads the last pass alone). Every pass runs for every row of a
+    step either way. 1: the walk every plan before it lowers to."""
     (seq,) = input_shape
+    if passes < 1:
+        raise ValueError(f"{passes} passes")
     if tied and heads != 1:
         raise ValueError("a tied embedding serves one head")
     vocab, rest = divmod(num_classes, heads)
@@ -168,6 +229,13 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
         for key, shape in b.counts:
             counted.setdefault(key, []).append(tuple(shape))
         readers[b.observe] = tuple(key for key, _ in b.counts)
+    if passes > 1:
+        if counted:
+            raise ValueError(
+                "a plan whose branches count runs once: no model runs "
+                "counting branches several passes, and a count a pass has "
+                "no reader")
+        readers[partial(observe_exits, passes=passes)] = ("exit_pass",)
 
     def served(tree):
         return tree if param_dtype is None else jax.tree.map(
@@ -181,7 +249,7 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                          (post, {"scale": jnp.full((dim,), scale, f32)}))
             if k})
 
-    def ends_init(ke, kh):
+    def ends_init(ke, kh, kg=None):
         # A multiplier stands against weights trained under it; a draw that
         # stands for such a checkpoint starts the stream and the logits where
         # every other model's start (N(0, 1) a channel, LeCun's head): the
@@ -196,15 +264,18 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
         if not tied:
             head = _w(kh, dim, num_classes)
             ends["head"] = head if logit_scale == 1 else head / logit_scale
+        if passes > 1:  # the exit gate: [dim] -> 1, with a bias
+            ends["exit"] = {"w": _w(kg, dim, 1)[:, 0], "b": jnp.zeros((), f32)}
         return served(ends)
 
     def init(rng):
-        ks = jax.random.split(rng, sum(map(len, blocks)) + 2)
+        ks = jax.random.split(rng, sum(map(len, blocks)) + 2
+                              + (passes > 1))
         one_block, ends = block_init, ends_init
         if param_dtype is not None:  # a program a layer, one a kind of block
             one_block = jax.jit(block_init, static_argnums=0)
             ends = jax.jit(ends_init)
-        params = ends(ks[0], ks[1])
+        params = ends(ks[0], ks[1], *(ks[-1:] if passes > 1 else ()))
         params["layers"], at = [], 2
         for blk in blocks:
             params["layers"].append(one_block(
@@ -215,6 +286,8 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
         # what a step counts on the device, in the state in and out
         aux = {key: jnp.zeros((len(shapes),) + shapes[0], jnp.int32)
                for key, shapes in counted.items()}
+        if passes > 1:  # the rows that left at each pass
+            aux["exit_pass"] = jnp.zeros((passes,), jnp.int32)
         return params, {"aux": aux} if aux else {}
 
     def apply(params, state, x, train: bool = False):
@@ -232,39 +305,64 @@ def token_scorer(name: str, num_classes: int, input_shape: tuple,
                 h = h * scale_emb
         ctx = context(x.shape[1]) if context else None
         counts = {key: [] for key in counted}
-        for blk, branches in zip(params["layers"], blocks):
-            for b in branches:
-                with jax.named_scope(P.NORM):
-                    y = L.rmsnorm(blk[b.norm], h, eps)
-                    if b.cast == "norm":
-                        y = y.astype(dtype)
-                with jax.named_scope(b.scope) if b.scope else nullcontext():
-                    if b.cast == "scope":
-                        y = y.astype(dtype)
-                    y = b.apply(blk[b.name], y, ctx)
-                if b.counts:
-                    y, *ns = y
-                    for (key, _), n in zip(b.counts, ns):
-                        counts[key].append(n)
-                with jax.named_scope(P.NORM):
-                    y = y.astype(f32)
-                    if b.post:
-                        y = L.rmsnorm(blk[b.post], y, eps)
-                    h = h + (y if residual == 1 else residual * y)
-                    if pin_stream:
-                        h = jax.lax.optimization_barrier(h)
+
+        def walk(h):
+            """The blocks once over the stream."""
+            for blk, branches in zip(params["layers"], blocks):
+                for b in branches:
+                    with jax.named_scope(P.NORM):
+                        y = L.rmsnorm(blk[b.norm], h, eps)
+                        if b.cast == "norm":
+                            y = y.astype(dtype)
+                    with jax.named_scope(b.scope) if b.scope \
+                            else nullcontext():
+                        if b.cast == "scope":
+                            y = y.astype(dtype)
+                        y = b.apply(blk[b.name], y, ctx)
+                    if b.counts:
+                        y, *ns = y
+                        for (key, _), n in zip(b.counts, ns):
+                            counts[key].append(n)
+                    with jax.named_scope(P.NORM):
+                        y = y.astype(f32)
+                        if b.post:
+                            y = L.rmsnorm(blk[b.post], y, eps)
+                        h = h + (y if residual == 1 else residual * y)
+                        if pin_stream:
+                            h = jax.lax.optimization_barrier(h)
+            return h
+
+        if passes == 1:
+            h = walk(h)
+        else:
+            def one_pass(t, carry):
+                h, lasts = carry
+                h = walk(h)
+                with jax.named_scope(P.NORM):  # the next pass starts from it
+                    h = L.rmsnorm(params["norm"], h, eps)
+                    return h, jax.lax.dynamic_update_index_in_dim(
+                        lasts, h[:, -1], t, 0)
+
+            h, lasts = jax.lax.fori_loop(
+                0, passes, one_pass,
+                (h, jnp.zeros((passes, h.shape[0], dim), f32)))
         with jax.named_scope(P.HEAD):
-            last = L.rmsnorm(params["norm"], h[:, -1], eps)
+            if passes == 1:
+                last = L.rmsnorm(params["norm"], h[:, -1], eps)
+            else:
+                last, left = exit_row(params["exit"], lasts, threshold)
             if logit_scale != 1:
                 last = last * logit_scale
             logits = L.matmul(last.astype(dtype), params["embed"].T
                               if tied else params["head"])
             if heads != 1:
                 logits = logits.reshape(-1, heads, vocab)
-        if not counts:
+        aux = {key: jnp.stack(ns) for key, ns in counts.items()}
+        if passes > 1:
+            aux["exit_pass"] = left
+        if not aux:
             return logits, state
-        return logits, {**state, "aux": {
-            key: jnp.stack(ns) for key, ns in counts.items()}}
+        return logits, {**state, "aux": aux}
 
     def observe_aux(metrics, cid: str, aux: dict) -> None:
         """A step's fetched ``aux`` into the registry, each count by the

@@ -97,7 +97,10 @@ def causal_tiles(group: int) -> tuple:
     into ``group * block_q`` rows of one left operand) and the keys of a
     block. The tile is the largest power of two of positions whose stacked
     rows are at most a key block's 512, never under 16 (a bfloat16 sublane
-    tile): 512, 256, 128, 64 and 32 for 1, 2, 4, 8 and 16 heads a group.
+    tile): 512, 256, 128, 64 and 32 for 1, 2, 4, 8 and 16 heads a group
+    (a group of 1, a head on its own keys: models/ouro.py through the merged
+    entry, models/kimi_linear.py through the head-split one; the diagonal
+    tile is then square, 512 x 512).
     A group that is no power of two takes the power of two below its quotient
     (Falcon-H1's five heads a key head: 64 positions, 320 stacked rows), so
     that the tile is whole sublane tiles and divides a key block, and with

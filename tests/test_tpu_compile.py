@@ -1201,3 +1201,52 @@ def test_lfm2s_two_operators_compile_with_their_kernels_at_heads_of_64(
     assert "f32[8,4096,2048]" not in entry + loop
     assert not re.search(r"f32\[\d+,\d+,4096\]", text)  # scores of a block
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4 * 2 ** 30
+
+
+def test_ouros_looped_program_compiles_whole_for_one_chip(v5e, monkeypatch):
+    """Ouro-2.6B whole at its cell's step (4 windows of 4,096, every
+    published width, 48 layers, 4 passes), as one chip builds it: the passes
+    are one ``while`` whose body holds the 48 blocks once (48 row loops of the
+    causal kernel at one query head a key head, a block's two turns and its
+    kernel: 144 Pallas calls whatever ``total_ut_steps`` says), and the
+    compiler's temporaries and arguments are what the configuration's
+    ``on_device`` states: a third of the chip in parameters, 6.0 GB while a
+    step runs."""
+    import json
+
+    import storm_tpu.ops.attention as attention
+    import storm_tpu.ops.rope as rope
+    from storm_tpu.models.registry import build_model
+    from storm_tpu.ops.platform import dispatch_notes
+
+    for module in (attention, rope):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    assert attention.merged_form(16, 16, 4096, 128, 128) == "kernel"
+    model = build_model("ouro_2_6b")
+    params, state = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, v5e),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    x = _spec((4, 4096), jnp.float32, v5e)
+    with dispatch_notes() as seen:
+        lowered = jax.jit(model.apply).lower(params, state, x)
+    assert seen == ["rotary_turn=lanes", "causal_attention=kernel-merged",
+                    "gated_ffn=made-once"]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 * 48
+    assert text.count(" while(") == 48 + 1
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "ouro_2_6b.json")) as f:
+        on_device = json.load(f)["on_device"]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes \
+        == on_device["program_temporaries_bucket_4_bytes"]
+    assert memory.argument_size_in_bytes \
+        == on_device["program_arguments_bucket_4_bytes"]
+    assert on_device["parameters_bytes"] == 2 * on_device["parameters"] \
+        == 2 * 2_667_974_657
+    # under the chip's 16 GB with room, and over the quarter a cell must fill
+    total = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 0.25 * 16e9 < on_device["parameters_bytes"] < total < 0.5 * 16e9
