@@ -96,12 +96,14 @@ def rotary_gqa(p: dict, x: jnp.ndarray, heads: int, kv_heads: int,
                rotary: tuple, scale: float, block: int = 512) -> jnp.ndarray:
     """Causal attention with grouped queries, q and k turned by ``rotary``'s
     tables ``(S, head_dim / 2)`` where they lie in their projections, the
-    scores times ``scale``; no bias, no head norm, no gate."""
-    cos, sin = rotary
-    (q,) = R.turn_merged((_proj(x, p["q"]),), cos, sin, heads)
-    (k,) = R.turn_merged((_proj(x, p["k"]),), cos, sin, kv_heads)
-    out = causal_attention_merged(q, k, _proj(x, p["v"]), heads, kv_heads,
-                                  scale=scale, block=block)
+    scores times ``scale``; no bias, no head norm, no gate. The turn is
+    ``causal_attention_merged``'s: inside the causal kernel, on the tiles it
+    holds in VMEM, where a head is one lane tile on one chip (this model's
+    and models/ouro.py's 128), by ops/rope.py ``turn_merged`` before the
+    attention elsewhere."""
+    out = causal_attention_merged(
+        _proj(x, p["q"]), _proj(x, p["k"]), _proj(x, p["v"]), heads,
+        kv_heads, scale=scale, block=block, rotary=rotary)
     return _proj(out, p["o"])
 
 

@@ -316,7 +316,8 @@ def merged_form(hq: int, hkv: int, s: int, dk: int, dv: int) -> str:
 def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             heads: int, kv_heads: int,
                             scale: Optional[float] = None, block: int = 512,
-                            window: Optional[int] = None) -> jnp.ndarray:
+                            window: Optional[int] = None,
+                            rotary: Optional[tuple] = None) -> jnp.ndarray:
     """:func:`causal_attention` for a caller that holds its heads merged, as
     its projections leave them: ``q: (B, S, Hq * Dk)``, ``k: (B, S, Hkv *
     Dk)``, ``v: (B, S, Hkv * Dv)`` to ``(B, S, Hq * Dv)``. The same softmax,
@@ -328,7 +329,20 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     and no array is transposed to ``(B, H, S, D)`` and back, a
     copy of each on a TPU; elsewhere :func:`causal_blocked` on that view, the
     CPU's and the several-chips' path as it was. A caller chooses this entry
-    by what it holds; the head-split one is untouched."""
+    by what it holds; the head-split one is untouched.
+
+    ``rotary = (cos, sin)`` (tables ``(S, Dk / 2)``; None: q and k are read
+    as they come): q and k come unturned, and every channel of every head
+    is turned as ops/rope.py ``turn_merged`` turns it. Where the kernel
+    reads a head as one lane tile and the lanes kernel would have turned it
+    (``heads_a_lane_tile == 1`` and ``turn_form == "lanes"``: a head of 128
+    on one chip) the causal kernel turns q and k itself, on the tiles it
+    holds in VMEM, to the same bits (``flash_attention_merged``'s
+    ``rotary``; noted ``rotary_turn=causal-kernel``): q and k make no pass
+    through HBM for the turn, and only the two tables' fusions are left
+    under ``mix.rope``. Everywhere else (heads of 64, the blocked form, the
+    CPU, several devices) ``turn_merged`` runs first, under its own
+    notes."""
     b, s, _ = q.shape
     dk, dv = k.shape[-1] // kv_heads, v.shape[-1] // kv_heads
     if heads % kv_heads:
@@ -339,7 +353,20 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         window = None
     if scale is None:
         scale = dk ** -0.5
+    from storm_tpu.ops import flash_attention as F
+    from storm_tpu.ops import rope as R
+
     form = merged_form(heads, kv_heads, s, dk, dv)
+    per = F.heads_a_lane_tile(dk, dv, kv_heads)
+    tables = None
+    if rotary is not None:
+        if form == "kernel" and per == 1 and R.turn_form(s, dk) == "lanes":
+            _note("rotary_turn", "causal-kernel")
+            with jax.named_scope(P.MIX_ROPE):
+                tables = R._lane_tables(*rotary)
+        else:
+            (q,) = R.turn_merged((q,), *rotary, heads)
+            (k,) = R.turn_merged((k,), *rotary, kv_heads)
     grouped = "-grouped" if heads != kv_heads else ""
     name = "causal_attention" if window is None else "window_attention"
     if form == "blocked":
@@ -349,9 +376,6 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 (q, heads), (k, kv_heads), (v, kv_heads))),
             scale, block, window)
         return merge_heads(out)
-    from storm_tpu.ops import flash_attention as F
-
-    per = F.heads_a_lane_tile(dk, dv, kv_heads)
     _note(name, form + grouped + "-merged" + ("-halves" if per > 1 else ""))
     block_q, block_k = F.causal_tiles(per * heads // kv_heads)
     with jax.named_scope(_loop_part(window)):
@@ -359,5 +383,6 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return jax.lax.fori_loop(
             0, b, lambda i, out: F.flash_attention_merged(
                 out, q, k, v, i, heads=heads, kv_heads=kv_heads, scale=scale,
-                block_q=block_q, block_k=block_k, window=window),
+                block_q=block_q, block_k=block_k, window=window,
+                rotary=tables),
             jax.lax.empty((b, s, heads * dv), q.dtype))

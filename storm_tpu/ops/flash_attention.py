@@ -72,6 +72,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from storm_tpu.ops.rope import _turned
+
 _LANE = 128
 _NEG = -1e30
 # What a grid step may hold (the v5e has 128 MiB): a head's keys and values
@@ -305,11 +307,50 @@ def _merged_kernel(row_ref, q_ref, k_ref, v_ref, _, o_ref, qs_ref, os_ref,
     ``(1, G, BQ, Dk)`` tile it works on (static slices of whole lane tiles, in
     VMEM) and its result is unstacked the same way into ``(1, BQ, G * Dv)``.
     The loop over key blocks, a window's three parts and the carry are that
-    kernel's: nothing of them is here."""
+    kernel's: nothing of them is here. q and k come turned (or need no
+    turn): :func:`_turning_kernel` is this kernel for a caller whose plain
+    rotary turn is still to do."""
     g, dk, dv = qs_ref.shape[1], qs_ref.shape[3], os_ref.shape[3]
     for i in range(g):
         qs_ref[0, i] = q_ref[0, :, i * dk:(i + 1) * dk]
     _attn_kernel(row_ref, qs_ref, k_ref, v_ref, os_ref, **kw)
+    for i in range(g):
+        o_ref[0, :, i * dv:(i + 1) * dv] = os_ref[0, i]
+
+
+def _turning_kernel(row_ref, q_ref, k_ref, v_ref, cos_ref, sin_ref, _, o_ref,
+                    qs_ref, os_ref, kt_ref, *, block_k, **kw):
+    """:func:`_merged_kernel` on q and k that come **unturned**, for heads of
+    one lane tile: the plain rotary turn (ops/rope.py ``_turned``: the lanes
+    kernel's arithmetic, float32 and one rounding, so what
+    :func:`_attn_kernel` is handed is bit for bit what that kernel would
+    have written to HBM) is done on operands this kernel holds in VMEM
+    anyway. A query tile turns its own positions: each query head's lanes as
+    they are stacked, and the same rows of the key head's resident k into
+    ``kt_ref: (1, Sk, Dk)``, against one read of the tables' rows. The
+    scratch lives over a head's tiles (the query-tile axis is ``arbitrary``,
+    walked in order), and the form is causal: a tile reads keys at and
+    before its last query alone, which it or an earlier tile of the head
+    has turned; what lies after them in the diagonal's block is another
+    head's or nothing's, and is masked before anything reads the score.
+    ``cos_ref`` and ``sin_ref`` are ops/rope.py ``_lane_tables``' two whole
+    ``(S, 128)`` float32 tables: their block never changes, so a call
+    fetches them once, and they are sliced here. (All of k turned at the
+    head's first tile, a key block at a time, read 8.3 ms a step slower on
+    Ouro's cell: it reads the tables twice. PERF.md section 6, PR 74.)"""
+    f32 = jnp.float32
+    g, bq, dk = qs_ref.shape[1:]
+    dv = os_ref.shape[3]
+
+    at = pl.ds(pl.multiple_of(pl.program_id(1) * bq, bq), bq)
+    cos, sin = cos_ref[at, :], sin_ref[at, :]
+    kt_ref[0, at, :] = _turned(k_ref[0, at, :].astype(f32), cos, sin,
+                               dk).astype(kt_ref.dtype)
+    for i in range(g):
+        qs_ref[0, i] = _turned(q_ref[0, :, i * dk:(i + 1) * dk].astype(f32),
+                               cos, sin, dk).astype(qs_ref.dtype)
+    _attn_kernel(row_ref, qs_ref, kt_ref, v_ref, os_ref, block_k=block_k,
+                 **kw)
     for i in range(g):
         o_ref[0, :, i * dv:(i + 1) * dv] = os_ref[0, i]
 
@@ -376,6 +417,7 @@ def flash_attention_merged(
     block_k: int = 512,
     interpret: bool = False,
     window: Optional[int] = None,
+    rotary: Optional[tuple] = None,
 ) -> jnp.ndarray:
     """The causal form of :func:`flash_attention` (with ``window``, over a
     query's last ``window`` keys alone) for heads that lie merged, as a
@@ -396,7 +438,14 @@ def flash_attention_merged(
     of one with the key heads paired off (:func:`heads_a_lane_tile`): a
     block is then a lane tile's two key heads with the ``2 G`` query heads
     that read them, the grid (pair of key heads, query tile), the kernel
-    :func:`_halves_kernel`, and nothing is padded or copied in HBM."""
+    :func:`_halves_kernel`, and nothing is padded or copied in HBM.
+
+    ``rotary`` (None: q and k are read as they come): ops/rope.py
+    ``_lane_tables``' two float32 tables ``(S, 128)``, for heads of one lane
+    tile. q and k then come **unturned** and the kernel turns them where it
+    holds them in VMEM (:func:`_turning_kernel`): the result is bit for bit
+    that of ops/rope.py ``turn_merged`` on both and then this function
+    without the argument, less a pass of q and of k through HBM."""
     s = q.shape[1]
     dk, dv = k.shape[2] // kv_heads, v.shape[2] // kv_heads
     if heads % kv_heads or q.shape[2] != heads * dk:
@@ -410,11 +459,22 @@ def flash_attention_merged(
     # a grid step reads the ``per`` key heads of one block of lanes and the
     # ``per * g`` query heads that read them
     per = heads_a_lane_tile(dk, dv, kv_heads)
-    kernel = _merged_kernel if per == 1 else _halves_kernel
+    if rotary is not None and (per != 1 or dk != _LANE):
+        raise ValueError(f"the kernel turns heads of {_LANE}, not of {dk}")
+    kernel = _halves_kernel if per > 1 else \
+        _merged_kernel if rotary is None else _turning_kernel
     block_q = min(block_q, max(_LANE, 1 << (s - 1).bit_length()))
     bk = min(block_k, max(_LANE, 1 << (s - 1).bit_length()))
     qp, buf = _pad_to(q, 1, block_q), _pad_to(out, 1, block_q)
-    kp, vp = _pad_to(k, 1, bk), _pad_to(v, 1, bk)
+    # keys at every query's position (a tile that turns k's rows of its own
+    # positions finds them there; a tile is no wider than a key block but
+    # where a caller says so)
+    kp, vp = (_pad_to(y, 1, max(block_q, bk)) for y in (k, v))
+    # the tables whole, over those positions: one block whose index never
+    # changes is fetched once a call and needs one buffer
+    tables = [_pad_to(t, 0, max(block_q, bk)) for t in rotary or ()]
+    whole = [pl.BlockSpec(t.shape, lambda h, qi, r: (0, 0),
+                          pipeline_mode=pl.Buffered(1)) for t in tables]
 
     def tile(width):  # of block ``h``'s lanes, in the row read
         return pl.BlockSpec((1, block_q, width),
@@ -431,17 +491,21 @@ def flash_attention_merged(
                                    lambda h, qi, r: (r[0], 0, h)),
                       pl.BlockSpec((1, vp.shape[1], per * dv),
                                    lambda h, qi, r: (r[0], 0, h)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      *whole, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=tile(per * g * dv),
             scratch_shapes=[
                 pltpu.VMEM((1, per * g, block_q, per * dk), q.dtype),
                 pltpu.VMEM((1, per * g, block_q, per * dv),
-                           q.dtype if per == 1 else jnp.float32)]),
+                           q.dtype if per == 1 else jnp.float32),
+                # the key head's k, turned
+                *([pltpu.VMEM((1, kp.shape[1], dk), k.dtype)]
+                  if tables else [])]),
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         # the result is its buffer (operands count from the row's index)
-        input_output_aliases={4: 0},
+        input_output_aliases={4 + len(tables): 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(jnp.asarray(row, jnp.int32).reshape(1), qp, kp, vp, buf)[:, :s]
+    )(jnp.asarray(row, jnp.int32).reshape(1), qp, kp, vp, *tables,
+      buf)[:, :s]

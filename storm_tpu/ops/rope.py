@@ -28,7 +28,11 @@ lane tiles can be turned where they lie in a projection's ``(B, S, H * D)``
 and half a head another still, each a copy of the whole array. A head's
 halves change places by one rotation of its 128 lanes; heads of 64 lie two
 to a lane tile, their halves its quarters, and each lane takes its partner
-from one of two rotations of the tile.
+from one of two rotations of the tile. Where the turned q and k would go
+straight to the merged causal kernel, heads of 128 are not turned here at
+all: that kernel does it on the tiles it holds in VMEM
+(ops/flash_attention.py ``_turning_kernel``, with :func:`_turned` and
+:func:`_lane_tables` of this file).
 """
 
 from __future__ import annotations
@@ -186,7 +190,13 @@ def turn_merged(xs: tuple, cos: jnp.ndarray, sin: jnp.ndarray,
     head turned by the tables ``(S, D / 2)``, a head's channels lying
     ``(evens, odds)``: what :func:`rotate_halves` gives on the view ``(B, S,
     H, D)``, in float32 and back in ``x``'s type. One call for all of ``xs``
-    (a mixer's queries and keys)."""
+    (a mixer's queries and keys). A pass of each ``x`` through HBM, noted
+    ``rotary_turn=lanes`` or ``halves``. A mixer whose q and k go straight
+    to ops/attention.py ``causal_attention_merged`` hands them over
+    unturned with ``rotary`` instead: for heads of one lane tile on one chip
+    the causal kernel turns them where it holds them (``_turned``, to the
+    same bits; ``rotary_turn=causal-kernel``), and that entry calls this
+    function everywhere else."""
     b, s, merged = xs[0].shape
     d = merged // heads
     form = turn_form(s, d, heads)

@@ -319,3 +319,109 @@ def test_registry_names_the_model_whole():
     assert float(tiny["layers"][0]["post1"]["scale"][0]) == pytest.approx(
         1 / np.sqrt(6))
     assert float(tiny["layers"][0]["norm1"]["scale"][0]) == 1.0
+
+
+# ---- the rotary turn inside the merged causal kernel (PR 74) -------------------
+
+def _turn_operands(b, s, hq, hkv, seed):
+    """q, k, v merged ``(b, s, H * 128)`` in bfloat16 and the tables ``(s,
+    64)``, on grids coarse enough that the turn's float32 arithmetic is
+    exact (operands in eighths up to 2, tables in sixty-fourths: a product
+    is a multiple of 1/512 under 2, a sum of two under 4, eleven bits): the
+    CPU's compiler fuses a multiplication and an addition into one rounding
+    in one program and not in the next, which real tables would show as a
+    last bit here and there; the one rounding to bfloat16 is the turn's own
+    and is taken on every element."""
+    rng = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(rng.randint(-16, 17, (b, s, h * 128)) / 8.0,
+                           jnp.bfloat16) for h in (hq, hkv, hkv))
+    angle = np.arange(s)[:, None] * 100.0 ** (-np.arange(64) / 64.0)[None]
+    cos, sin = (jnp.asarray(np.round(f(angle) * 64) / 64, jnp.float32)
+                for f in (np.cos, np.sin))
+    return q, k, v, cos, sin
+
+
+def _bits(y):
+    return np.asarray(y).view(np.uint16)
+
+
+def _as_one_chip(monkeypatch):
+    """The rules' answers on one chip, the kernels under the interpreter."""
+    import functools
+
+    from storm_tpu.ops import flash_attention as F
+    from storm_tpu.ops import rope as R
+
+    for module in (A, R):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    monkeypatch.setattr(R, "_turn_lanes", functools.partial(
+        R._turn_lanes, interpret=True))
+    monkeypatch.setattr(F, "flash_attention_merged", functools.partial(
+        F.flash_attention_merged, interpret=True))
+
+
+@pytest.mark.parametrize("hq,hkv,s,tile,window,row", [
+    (16, 16, 256, 128, None, 0),  # a group of 1: Ouro's
+    (20, 4, 256, 64, None, 0),    # a group of 5: Falcon-H1's
+    (4, 4, 384, 128, 100, 0),     # a window
+    (4, 2, 200, 64, None, 0),     # positions that are not whole blocks
+    (4, 4, 256, 128, None, 1),    # row 1 of a batch of two
+], ids=["group1", "group5", "window", "padded", "row1"])
+def test_the_kernel_turns_q_and_k_to_the_lanes_kernels_bits(
+        monkeypatch, hq, hkv, s, tile, window, row):
+    """``flash_attention_merged`` handed unturned q and k with ``rotary`` (the
+    lane tables) against ``turn_merged`` (the lanes kernel, where its rule
+    grants it) followed by ``flash_attention_merged``: the same bfloat16,
+    bit for bit, in the row written, and the other row as it was."""
+    from storm_tpu.ops import flash_attention as F
+    from storm_tpu.ops import rope as R
+
+    _as_one_chip(monkeypatch)
+    q, k, v, cos, sin = _turn_operands(2, s, hq, hkv, seed=hq + s)
+    assert R.turn_form(s, 128) == ("lanes" if s % 128 == 0 else "halves")
+    (qt,), (kt,) = (R.turn_merged((y,), cos, sin, h)
+                    for y, h in ((q, hq), (k, hkv)))
+    out = jnp.full((2, s, hq * 128), 7.0, jnp.bfloat16)
+    kw = dict(heads=hq, kv_heads=hkv, block_q=tile, block_k=128,
+              window=window)
+    want = F.flash_attention_merged(out, qt, kt, v, row, **kw)
+    got = F.flash_attention_merged(out, q, k, v, row,
+                                   rotary=R._lane_tables(cos, sin), **kw)
+    plain = F.flash_attention_merged(out, q, k, v, row, **kw)
+    assert (_bits(got) == _bits(want)).all()
+    assert (_bits(got[1 - row]) == _bits(out[1 - row])).all()
+    assert (_bits(got[row]) != _bits(plain[row])).mean() > 0.5
+
+
+@pytest.mark.parametrize("d,chip,notes", [
+    (128, True, ["rotary_turn=causal-kernel",
+                 "causal_attention=kernel-grouped-merged"]),
+    (64, True, ["rotary_turn=lanes",
+                "causal_attention=kernel-grouped-merged-halves"]),
+    (128, False, ["rotary_turn=halves", "causal_attention=blocked-grouped"]),
+], ids=["heads-of-128", "heads-of-64", "cpu"])
+def test_the_rule_sends_the_turn_into_the_kernel_or_before_it(
+        monkeypatch, d, chip, notes):
+    """``causal_attention_merged(rotary=...)``: the causal kernel turns
+    heads of one lane tile on one chip; heads of 64 (two a lane tile) and
+    the CPU's blocked form take ``turn_merged`` first, as they always have,
+    and either way the result is that of the turn followed by the attention
+    without the argument."""
+    from storm_tpu.ops import rope as R
+    from storm_tpu.ops.platform import dispatch_notes
+
+    if chip:
+        _as_one_chip(monkeypatch)
+    hq, hkv, s = 8, 4, 512
+    q, k, v, cos, sin = _turn_operands(2, s, hq * d // 128, hkv * d // 128,
+                                       seed=d)
+    cos, sin = cos[:, :d // 2], sin[:, :d // 2]
+    with dispatch_notes() as seen:
+        got = A.causal_attention_merged(q, k, v, hq, hkv, block=128,
+                                        rotary=(cos, sin))
+    assert seen == notes
+    (qt,), (kt,) = (R.turn_merged((y,), cos, sin, h)
+                    for y, h in ((q, hq), (k, hkv)))
+    want = A.causal_attention_merged(qt, kt, v, hq, hkv, block=128)
+    assert (_bits(got) == _bits(want)).all()

@@ -1207,11 +1207,12 @@ def test_ouros_looped_program_compiles_whole_for_one_chip(v5e, monkeypatch):
     """Ouro-2.6B whole at its cell's step (4 windows of 4,096, every
     published width, 48 layers, 4 passes), as one chip builds it: the passes
     are one ``while`` whose body holds the 48 blocks once (48 row loops of the
-    causal kernel at one query head a key head, a block's two turns and its
-    kernel: 144 Pallas calls whatever ``total_ut_steps`` says), and the
-    compiler's temporaries and arguments are what the configuration's
-    ``on_device`` states: a third of the chip in parameters, 6.0 GB while a
-    step runs."""
+    causal kernel at one query head a key head: 48 Pallas calls whatever
+    ``total_ut_steps`` says, where the parent of PR 74 held 144, a block's
+    two turns by the lanes kernel beside its causal kernel, which turns q
+    and k itself since), and the compiler's temporaries and arguments are
+    what the configuration's ``on_device`` states: a third of the chip in
+    parameters, 6.0 GB while a step runs."""
     import json
 
     import storm_tpu.ops.attention as attention
@@ -1230,11 +1231,11 @@ def test_ouros_looped_program_compiles_whole_for_one_chip(v5e, monkeypatch):
     x = _spec((4, 4096), jnp.float32, v5e)
     with dispatch_notes() as seen:
         lowered = jax.jit(model.apply).lower(params, state, x)
-    assert seen == ["rotary_turn=lanes", "causal_attention=kernel-merged",
-                    "gated_ffn=made-once"]
+    assert seen == ["rotary_turn=causal-kernel",
+                    "causal_attention=kernel-merged", "gated_ffn=made-once"]
     compiled = lowered.compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3 * 48
+    assert text.count("tpu_custom_call") == 48
     assert text.count(" while(") == 48 + 1
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmarks", "configs",
@@ -1250,3 +1251,71 @@ def test_ouros_looped_program_compiles_whole_for_one_chip(v5e, monkeypatch):
     # under the chip's 16 GB with room, and over the quarter a cell must fill
     total = memory.temp_size_in_bytes + memory.argument_size_in_bytes
     assert 0.25 * 16e9 < on_device["parameters_bytes"] < total < 0.5 * 16e9
+
+
+@pytest.mark.parametrize("rows,s,heads,kv_heads,dim", [
+    (4, 4096, 16, 16, 2048),   # Ouro's cell: a head on its own keys
+    (4, 16384, 20, 4, 5120),   # Falcon-H1's: five query heads a key head
+], ids=["ouro", "falcon_h1"])
+def test_the_rotary_mixer_turns_q_and_k_inside_its_causal_kernel(
+        v5e, monkeypatch, rows, s, heads, kv_heads, dim):
+    """``models/falcon_h1.py rotary_gqa`` at Ouro's and at Falcon-H1's
+    cell's step, as one chip builds it (PR 74): **one** Pallas call a layer
+    where there were three (the lanes kernel on q, on k, and the causal
+    kernel): the causal kernel takes q and k unturned with the two lane
+    tables whole, ``(S, 128)`` float32 in one buffer each, and its scratch
+    for the turned k, inside ``_VMEM_LIMIT`` (at 16,384 positions: 16 MB of
+    tables beside 16 MB of double-buffered k and v and 4 MB of scratch);
+    the rows are one ``while`` under ``mix.attention`` that carries q, k and
+    v as the projections left them, and nothing of q's or k's size is
+    written between the four products but by them. Handed no ``rotary``, the
+    entry compiles to the parent's three calls' worth: the lanes kernel is
+    still every other caller's."""
+    import re
+
+    import storm_tpu.ops.attention as attention
+    import storm_tpu.ops.rope as rope
+    from storm_tpu.models.falcon_h1 import rotary_gqa
+    from storm_tpu.models.nemotron_h import gqa_mixer_init
+    from storm_tpu.ops.platform import dispatch_notes
+
+    for module in (attention, rope):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_one_device", lambda: True)
+    p = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: gqa_mixer_init(
+            jax.random.PRNGKey(0), dim, heads, kv_heads, 128)))
+    x = _spec((rows, s, dim), jnp.bfloat16, v5e)
+    tables = _spec((s, 64), jnp.float32, v5e)
+    with dispatch_notes() as seen:
+        compiled = jax.jit(lambda p, x, cos, sin: rotary_gqa(
+            p, x, heads, kv_heads, (cos, sin), 128 ** -0.5)).lower(
+            p, x, tables, tables).compile()
+    grouped = "-grouped" if heads != kv_heads else ""
+    assert seen == ["rotary_turn=causal-kernel",
+                    f"causal_attention=kernel{grouped}-merged"]
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    (loop,) = _loops(text)
+    assert "/mix.attention/while" in loop
+    wide, narrow = (f"bf16[{rows},{s},{h * 128}]" for h in (heads, kv_heads))
+    assert loop.count(wide) >= 2 and loop.count(narrow) >= 2
+    assert loop.count(f"f32[{s},128]") >= 2  # the lane tables, made once
+    # of q's or k's size, between the loop and the parameters: the four
+    # products and nothing else
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(
+        rf"= bf16\[{rows},{s},\d+\]\S* (?:fusion|copy)\(", entry)) == 4
+
+    q, k = (_spec((rows, s, h * 128), jnp.bfloat16, v5e)
+            for h in (heads, kv_heads))
+    with dispatch_notes() as seen:
+        before = jax.jit(lambda q, k, v, cos, sin: (
+            attention.causal_attention_merged(
+                *rope.turn_merged((q,), cos, sin, heads),
+                *rope.turn_merged((k,), cos, sin, kv_heads), v, heads,
+                kv_heads))).lower(q, k, k, tables, tables).compile()
+    assert seen == ["rotary_turn=lanes",
+                    f"causal_attention=kernel{grouped}-merged"]
+    assert before.as_text().count("tpu_custom_call") == 3
