@@ -289,7 +289,7 @@ class TopologyConfig:
     spout_chunk: int = 1
     # Tuple-value scheme (Storm StringScheme vs RawScheme,
     # MainTopology.java:100): "string" = decode records to str (compatible
-    # with every component incl. shell/multilang and the JSON dist wire);
+    # with every component and with the JSON dist wire);
     # "raw" = emit broker bytes untouched, skipping a bytes->str->bytes
     # round trip on the inference hot path. Under dist-run, "raw" needs
     # wire_format="binary" (the default) to cross worker boundaries.
@@ -314,7 +314,7 @@ class TopologyConfig:
     # cross without re-encoding), with per-peer fallback to JSON for
     # workers that don't advertise the binary version (mixed-version
     # clusters); "json" = pin the legacy envelope everywhere — the
-    # compatibility wire for multilang/shell bolts and old receivers.
+    # compatibility wire for old receivers.
     wire_format: str = "binary"
     # Shared-memory delivery lane between CO-LOCATED dist workers (same
     # host key, negotiated via the control ping): the sender writes the

@@ -127,14 +127,6 @@ class MemoryBroker:
         with self._lock:
             self._committed[(group, topic, partition)] = offset
 
-    def commit_many(self, group: str, topic: str, offsets: "Dict[int, int]") -> None:
-        """Atomically commit offsets for several partitions (one lock hold).
-        The transactional spout needs all-or-nothing batch commits — a crash
-        between per-partition commits would split a batch's identity."""
-        with self._lock:
-            for partition, offset in offsets.items():
-                self._committed[(group, topic, partition)] = offset
-
     def committed(self, group: str, topic: str, partition: int) -> Optional[int]:
         with self._lock:
             return self._committed.get((group, topic, partition))

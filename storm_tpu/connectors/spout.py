@@ -87,7 +87,7 @@ class BrokerSpout(Spout):
         self.chunk = chunk
         # Tuple-value scheme, Storm's StringScheme vs RawScheme
         # (MainTopology.java:100 picks StringScheme): "string" decodes each
-        # record to str (full compat: shell/multilang bolts, the JSON dist
+        # record to str (full compat: every component, the JSON dist
         # wire). "raw" emits the broker bytes untouched — the JSON decoder
         # parses bytes natively, so the hot path skips a bytes->str->bytes
         # round trip (~20us/record on a 12KB payload), and under dist-run

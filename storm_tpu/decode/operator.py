@@ -11,7 +11,7 @@ whole tree and the spout replays the REQUEST, not a token.
 Exactly-once across that replay is the ``committed`` watermark
 (:mod:`storm_tpu.decode.session`): a token is emitted, then
 ``committed`` advances and the session folds into bolt state via
-``checkpoint_now()`` (the transactional-bolt cadence, every
+``checkpoint_now()`` (persist, then ack; every
 ``commit_every`` tokens). A replayed request emits exactly
 ``tokens[committed:]`` — regenerated from the log if present (greedy
 decode is deterministic, so the log IS the oracle), recomputed from the

@@ -21,8 +21,7 @@ is part of what they should measure anyway).
 Two wire formats share these RPCs. The default is the binary frame codec
 in :mod:`storm_tpu.dist.wire` (tagged value slots, raw ``bytes`` allowed,
 CRC-protected, traceparent in the frame header); this module keeps the
-JSON envelope as the negotiated fallback for multilang/shell bolts and
-mixed-version clusters. ``decode_deliveries``/``decode_acks`` below
+JSON envelope as the negotiated fallback for mixed-version clusters. ``decode_deliveries``/``decode_acks`` below
 auto-detect the format from the first payload byte (JSON arrays start with
 ``[`` = 0x5B; binary frames with 0xB7/0xB8; shared-memory segment headers
 with 0xB9), so a receiver accepts any of them regardless of what its own

@@ -73,7 +73,7 @@ failed RPC surfaces at the sender, which retries, and pending trees replay.
 
 Version negotiation lives in the worker control plane: ``ping`` responses
 advertise ``{"wire": WIRE_VERSION}`` and senders fall back to the JSON
-envelope for peers that don't (mixed-version clusters, multilang shims) or
+envelope for peers that don't (mixed-version clusters) or
 when ``TopologyConfig.wire_format = "json"`` pins the fallback.
 """
 

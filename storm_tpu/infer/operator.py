@@ -106,8 +106,7 @@ class InferenceBolt(Bolt):
         self._engine = engine
         self._warmup = warmup
         # Input fields copied verbatim onto every output tuple (both
-        # streams). How a DRPC request id rides through the operator —
-        # Storm's LinearDRPCTopologyBuilder threads return-info the same way.
+        # streams): how a record's ``qos_lane`` reaches the sink.
         self.passthrough = tuple(passthrough)
         # QosConfig (config.py) or None. When enabled: the engine's queue
         # orders lanes earliest-deadline-first instead of FIFO, and
@@ -139,7 +138,7 @@ class InferenceBolt(Bolt):
 
     def _extras(self, t: Tuple):
         # Default-tolerant: a stream that doesn't carry a passthrough field
-        # (e.g. a Kafka spout sharing this bolt with a DRPC spout) yields
+        # (e.g. two spouts with different fields sharing this bolt) yields
         # None rather than poisoning the whole batch with a KeyError.
         return [t.get(f, None) for f in self.passthrough]
 
@@ -438,8 +437,8 @@ class InferenceBolt(Bolt):
         utf-8 BYTES: the sink produces those bytes verbatim, so the
         legacy ``sink_encode`` re-encode hop (which duplicated every
         payload byte) disappears from the path. String
-        topologies keep the str contract (the JSON dist wire and
-        multilang bolts cannot carry bytes)."""
+        topologies keep the str contract (the JSON dist wire
+        cannot carry bytes)."""
         msg = encode_predictions(preds)
         if self._bytes_egress:
             payload = msg.encode("utf-8")

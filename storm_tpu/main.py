@@ -209,17 +209,11 @@ async def _run_daemon(name: str, cfg: Config, duration: float,
                       autoscale_target_ms: float = 0.0,
                       ui_port: int = -1,
                       metrics_file: str = "",
-                      metrics_interval_s: float = 10.0,
-                      topology_file: str = "") -> None:
+                      metrics_interval_s: float = 10.0) -> None:
     from storm_tpu.runtime.cluster import AsyncLocalCluster
 
     broker = _make_broker(cfg)
-    if topology_file:
-        from storm_tpu.flux import load_topology
-
-        topo = load_topology(topology_file, resources={"broker": broker})
-        desc = f"flux:{topology_file}"
-    elif cfg.pipelines:
+    if cfg.pipelines:
         topo = build_multi_model_topology(cfg, broker)
         desc = "+".join(p.model.name for p in cfg.pipelines)
     else:
@@ -240,7 +234,7 @@ async def _run_daemon(name: str, cfg: Config, duration: float,
         else [("inference-bolt", "kafka-bolt")]
     )
     shedders = []
-    if cfg.qos.enabled and not topology_file:
+    if cfg.qos.enabled:
         from storm_tpu.qos import LoadShedController, ShedPolicy
 
         # The shed loop runs faster than the autoscaler (1 s vs 5 s
@@ -251,7 +245,7 @@ async def _run_daemon(name: str, cfg: Config, duration: float,
             for infer_id, sink_id in pairs
         ]
     observatory = None
-    if cfg.obs.enabled and not topology_file:
+    if cfg.obs.enabled:
         from storm_tpu.obs import Observatory
 
         # Burn is computed over ALL sink components (one per pipeline);
@@ -306,9 +300,7 @@ async def _run_daemon(name: str, cfg: Config, duration: float,
     if ui_port >= 0:
         from storm_tpu.runtime.ui import UIServer
 
-        # remote submission gets the daemon's broker as $broker
         ui = await UIServer(cluster, port=ui_port,
-                            resources={"broker": broker},
                             auth_token=cfg.control.resolve_token()).start()
     print(f"topology {name!r} running "
           f"(model={desc}, broker={cfg.broker.kind}"
@@ -358,14 +350,12 @@ def _ctl(args) -> int:
 
     token = getattr(args, "token", None) or env_control_token()
 
-    def call(method, path, body=None, timeout=30, headers=None):
+    def call(method, path, body=None, timeout=30):
         req = urllib.request.Request(
             base + path, method=method,
             data=json.dumps(body).encode() if body is not None else None)
         if token:
             req.add_header("Authorization", f"Bearer {token}")
-        for k, v in (headers or {}).items():
-            req.add_header(k, v)
         try:
             with urllib.request.urlopen(req, timeout=timeout) as r:
                 return 0, json.loads(r.read())
@@ -446,13 +436,6 @@ def _ctl(args) -> int:
         if rc == 0:
             print(out.get("log", ""))
             return 0
-    elif cmd == "submit":
-        from storm_tpu.flux import _load_spec
-
-        rc, out = call("POST", "/api/v1/topology/submit",
-                       {"name": args.topology,
-                        "definition": _load_spec(args.definition)},
-                       headers={"X-Storm-Tpu-Submit": "1"})
     print(json.dumps(out, indent=2, default=str))
     return rc
 
@@ -1114,11 +1097,6 @@ def main(argv=None) -> int:
                       help="append a JSON-lines metrics snapshot to this "
                            "file every --metrics-interval seconds")
     runp.add_argument("--metrics-interval", type=float, default=10.0)
-    runp.add_argument("--topology-file", default="",
-                      help="declarative topology definition (TOML/JSON, the "
-                           "Storm Flux equivalent) instead of the standard "
-                           "spout->inference->sink shape; the configured "
-                           "broker is available as the $broker resource")
 
     distp = sub.add_parser(
         "dist-run",
@@ -1217,10 +1195,6 @@ def main(argv=None) -> int:
     c.add_argument("topology")
     c.add_argument("--worker", type=int, default=0)
     c.add_argument("--bytes", type=int, default=16384)
-    c = ctlsub.add_parser(
-        "submit", help="submit a Flux topology definition to the daemon")
-    c.add_argument("topology")
-    c.add_argument("definition", help="TOML/JSON topology file")
 
     tracesp = sub.add_parser(
         "traces",
@@ -1387,8 +1361,7 @@ def main(argv=None) -> int:
         _enter()
         asyncio.run(_run_daemon(args.name, cfg, args.duration,
                                 args.autoscale_target_ms, args.ui_port,
-                                args.metrics_file, args.metrics_interval,
-                                args.topology_file))
+                                args.metrics_file, args.metrics_interval))
         return 0
 
     if args.cmd == "ctl":

@@ -8,18 +8,8 @@ from storm_tpu.runtime.state import (
     MemoryStateBackend,
     StatefulBolt,
 )
-from storm_tpu.runtime.event_time import EventTimeWindowBolt
-from storm_tpu.runtime.join import JoinBolt
-from storm_tpu.runtime.shell import ShellBolt, ShellSpout
-from storm_tpu.runtime.window import TumblingWindowBolt, WindowedBolt
 
 __all__ = [
-    "EventTimeWindowBolt",
-    "JoinBolt",
-    "ShellBolt",
-    "ShellSpout",
-    "WindowedBolt",
-    "TumblingWindowBolt",
     "StatefulBolt",
     "KeyValueState",
     "MemoryStateBackend",

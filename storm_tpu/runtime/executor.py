@@ -87,9 +87,9 @@ class BoltExecutor:
         self._task = asyncio.create_task(
             self._run(), name=f"{self.component_id}[{self.task_index}]"
         )
-        interval = self.tick_interval_s or getattr(self.bolt, "tick_interval_s", 0.0)
-        if interval > 0:
-            self._tick_task = asyncio.create_task(self._ticker(interval))
+        if self.tick_interval_s > 0:
+            self._tick_task = asyncio.create_task(
+                self._ticker(self.tick_interval_s))
         ckpt = self.rt.config.topology.checkpoint_interval_s
         if self._stateful and ckpt > 0:
             self._ckpt_task = asyncio.create_task(
@@ -114,9 +114,10 @@ class BoltExecutor:
             state = KeyValueState()
         self._state = state
         self.bolt.init_state(state)
-        # Synchronous-checkpoint hook: transactional bolts persist state
-        # BEFORE acking so an offset commit can never outrun the snapshot
-        # it depends on (exactly-once across crashes).
+        # Synchronous-checkpoint hook: a bolt that commits progress (the
+        # decode bolt's watermark) persists state BEFORE acking, so an ack
+        # can never outrun the snapshot it depends on (exactly-once across
+        # crashes).
         self.bolt.checkpoint_now = self._checkpoint
 
     def _checkpoint(self) -> None:

@@ -168,4 +168,5 @@ class StatefulBolt(Bolt):
     def checkpoint_now(self) -> None:
         """Force an immediate state snapshot. Bound to the executor's
         checkpoint when running inside a topology; a no-op for bolts driven
-        standalone (tests). Transactional bolts call this before acking."""
+        standalone (tests). A bolt that commits progress (the decode bolt)
+        calls this before acking."""

@@ -43,10 +43,9 @@ Admin routes (POST, like Storm UI's topology actions)
 
 Everything returns ``application/json``. The server binds 127.0.0.1 by
 default. With ``auth_token`` set (config ``control.auth_token``), every
-mutating route — the admin POSTs above and remote submit — requires
+mutating route — every POST — requires
 ``Authorization: Bearer <token>``; mismatches get 401 and a log line
-(VERDICT r4 missing #4). Read routes and DRPC (data plane, mirrors the
-unauthenticated Storm DRPC servers of the reference era) stay open;
+(VERDICT r4 missing #4). Read routes stay open;
 ``auth_token=""`` disables the check entirely (the previous
 loopback-dev posture).
 """
@@ -63,7 +62,7 @@ import logging
 
 log = logging.getLogger("storm_tpu.ui")
 
-_MAX_BODY = 32 << 20  # 32 MiB: sized for DRPC inference payloads, not just admin
+_MAX_BODY = 32 << 20  # 32 MiB: the most a request's body may hold
 
 
 class _PlainText(str):
@@ -74,18 +73,12 @@ class UIServer:
     """Serve status/admin HTTP for the topologies in an AsyncLocalCluster."""
 
     def __init__(self, cluster, host: str = "127.0.0.1", port: int = 0,
-                 drpc=None, resources=None, auth_token: str = "") -> None:
+                 auth_token: str = "") -> None:
         self.cluster = cluster
         self.host = host
         self.port = port  # replaced by the bound port after start()
-        self.drpc = drpc  # optional DRPCServer: enables /api/v1/drpc/{fn}
         #: shared secret for mutating routes; "" disables (see module doc)
         self.auth_token = auth_token
-        # shared objects exposed to submitted Flux definitions ($broker...);
-        # None disables remote submission entirely
-        self.resources = resources
-        #: module prefixes a submitted definition's class paths may use
-        self.submit_class_prefixes: tuple = ("storm_tpu.",)
         self._server: Optional[asyncio.AbstractServer] = None
         self._started = time.monotonic()
         self._kill_tasks: set = set()
@@ -219,10 +212,9 @@ class UIServer:
                      body: Dict[str, Any],
                      headers: Dict[str, str] = None) -> Tuple[int, Any]:
         headers = headers or {}
-        # Auth gate for every mutating route: admin topology actions and
-        # remote submit. GET/read routes and DRPC (data plane) stay open.
-        if (method == "POST" and not path.startswith("/api/v1/drpc/")
-                and not self._authorized(headers)):
+        # Auth gate for every mutating route (every POST); GET/read routes
+        # stay open.
+        if method == "POST" and not self._authorized(headers):
             log.warning("rejected unauthenticated %s %s", method, path)
             return 401, {"error": "missing or invalid bearer token "
                                   "(control.auth_token is set)"}
@@ -245,72 +237,6 @@ class UIServer:
             rts = list(self._runtimes().values())
             return 200, {"topologies": await asyncio.to_thread(
                 lambda: [self._topo_summary(rt) for rt in rts])}
-        if path == "/api/v1/topology/submit":
-            # StormSubmitter over the wire: a Flux definition becomes a
-            # running topology on this daemon's cluster.
-            if method != "POST":
-                return 405, {"error": "submit is POST"}
-            if self.resources is None:
-                return 404, {"error": "remote submission disabled "
-                                      "(server started without resources)"}
-            # The custom header blocks browser CSRF (cross-origin requests
-            # cannot attach it without a CORS preflight this server never
-            # approves); class paths are allowlisted because a dotted path
-            # is arbitrary code execution on untrusted input.
-            if headers.get("x-storm-tpu-submit") != "1":
-                return 403, {"error": "missing X-Storm-Tpu-Submit: 1 header"}
-            definition = body.get("definition")
-            name = body.get("name")
-            if not name or not isinstance(definition, dict):
-                return 400, {"error": 'need {"name": ..., "definition": {...}}'}
-            if name in self._runtimes():
-                return 400, {"error": f"topology {name!r} already running"}
-            from storm_tpu.config import Config as _Config
-            from storm_tpu.flux import FluxError, load_topology
-
-            try:
-                topo = await asyncio.to_thread(
-                    load_topology, definition, dict(self.resources),
-                    self.submit_class_prefixes)
-                await self.cluster.submit(name, _Config(), topo)
-            except (FluxError, ValueError, TypeError) as e:
-                # malformed definitions, bad wiring, and the duplicate-name
-                # race are all client errors, not server faults
-                return 400, {"error": str(e)}
-            return 200, {"status": "SUBMITTED", "name": name,
-                         "components": sorted(topo.specs)}
-        if path.startswith("/api/v1/drpc/"):
-            if method != "POST":
-                return 405, {"error": "drpc is POST"}
-            if self.drpc is None:
-                return 404, {"error": "no DRPC server attached"}
-            function = path[len("/api/v1/drpc/"):]
-            args = body.get("args") if isinstance(body, dict) else None
-            if not function or not isinstance(args, str):
-                return 400, {"error": 'need function in path and {"args": "<str>"}'}
-            try:
-                timeout_s = float(query.get("timeout_s", 30.0))
-            except ValueError:
-                return 400, {"error": "timeout_s must be a number"}
-            # finite + bounded: inf would park the handler forever and leak
-            # the pending future; cap keeps hung clients from pinning sockets
-            if not (0 < timeout_s <= 600):
-                return 400, {"error": "timeout_s must be in (0, 600]"}
-            from storm_tpu.runtime.drpc import (
-                DRPCError,
-                DRPCTimeout,
-                DRPCUnknownFunction,
-            )
-
-            try:
-                result = await self.drpc.execute(function, args, timeout_s)
-            except DRPCUnknownFunction as e:
-                return 404, {"error": str(e)}
-            except DRPCTimeout as e:
-                return 504, {"error": str(e)}
-            except DRPCError as e:
-                return 502, {"error": str(e)}
-            return 200, {"result": result}
         if path.startswith("/api/v1/topology/"):
             rest = path[len("/api/v1/topology/"):]
             name, _, action = rest.partition("/")
@@ -781,11 +707,5 @@ class UIServer:
             )
             self._kill_tasks.add(task)
             task.add_done_callback(self._kill_done)
-            if self.drpc is not None:
-                # a dead topology can never answer: fail in-flight DRPC
-                # callers now instead of letting their timeouts burn
-                task.add_done_callback(
-                    lambda _t: self.drpc.fail_all("topology killed")
-                )
             return 200, {"status": "KILLED", "wait_secs": wait_secs}
         return 404, {"error": f"no action {action!r}"}

@@ -605,12 +605,12 @@ def test_cli_json_chain_bearing_finding(capsys):
         assert all(isinstance(s, str) for s in f["chain"])
 
 
-def test_cli_rules_listing(capsys):
+@pytest.mark.parametrize(
+    "rule", ["LCK001", "LCK002", "XO001", "JIT001", "OBS001"])
+def test_cli_rules_listing(capsys, rule):
     from storm_tpu.main import main
     assert main(["lint", "--rules"]) == 0
-    out = capsys.readouterr().out
-    for rule in ("LCK001", "LCK002", "XO001", "JIT001", "OBS001"):
-        assert rule in out
+    assert rule in capsys.readouterr().out
 
 
 def test_cli_bad_path(capsys):

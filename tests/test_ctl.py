@@ -6,6 +6,8 @@ import io
 import json
 from contextlib import redirect_stdout
 
+import pytest
+
 from storm_tpu.config import Config
 from storm_tpu.main import main as cli_main
 from storm_tpu.runtime.cluster import AsyncLocalCluster
@@ -104,108 +106,6 @@ def test_ctl_drain_waits_for_inflight(run):
     run(go(), timeout=60)
 
 
-def test_remote_submit_flux_topology(run):
-    """StormSubmitter over the wire: POST a Flux definition to a running
-    daemon, see it appear, process data, and die on ctl kill."""
-
-    async def go():
-        from storm_tpu.connectors.memory import MemoryBroker
-        from storm_tpu.runtime import TopologyBuilder
-
-        broker = MemoryBroker()
-        tb = TopologyBuilder()
-        tb.set_spout("spout", TrickleSpout(), parallelism=1)
-        tb.set_bolt("echo", EchoBolt(), parallelism=1).shuffle_grouping("spout")
-        cluster = AsyncLocalCluster()
-        await cluster.submit("primary", Config(), tb.build())
-        ui = await UIServer(cluster, port=0,
-                            resources={"broker": broker}).start()
-        url = f"http://127.0.0.1:{ui.port}"
-        loop = asyncio.get_running_loop()
-        definition = {
-            "spouts": [{"id": "s2",
-                        "class": "storm_tpu.connectors.spout.BrokerSpout",
-                        "args": {"broker": "$broker", "topic": "in2",
-                                 "offsets": {
-                                     "class": "storm_tpu.config.OffsetsConfig",
-                                     "args": {"policy": "earliest",
-                                              "max_behind": None}}}}],
-            "bolts": [{"id": "out2",
-                       "class": "storm_tpu.connectors.sink.BrokerSink",
-                       "args": {"broker": "$broker", "topic": "out2"},
-                       "groupings": [{"source": "s2", "type": "shuffle"}]}],
-        }
-        import json as _json
-        import urllib.request
-
-        def post(path, body, with_header=True):
-            req = urllib.request.Request(
-                url + path, method="POST", data=_json.dumps(body).encode())
-            if with_header:
-                req.add_header("X-Storm-Tpu-Submit", "1")
-            try:
-                with urllib.request.urlopen(req, timeout=15) as r:
-                    return r.status, _json.loads(r.read())
-            except urllib.error.HTTPError as e:
-                return e.code, _json.loads(e.read())
-
-        try:
-            st, r = await loop.run_in_executor(
-                None, post, "/api/v1/topology/submit",
-                {"name": "second", "definition": definition})
-            assert st == 200 and r["status"] == "SUBMITTED", r
-            assert "second" in cluster.runtimes
-
-            broker.produce("in2", "hello")
-            deadline = loop.time() + 30
-            while loop.time() < deadline and broker.topic_size("out2") < 1:
-                await asyncio.sleep(0.05)
-            assert broker.topic_size("out2") == 1
-
-            # duplicate name rejected; bad definition rejected
-            st, _ = await loop.run_in_executor(
-                None, post, "/api/v1/topology/submit",
-                {"name": "second", "definition": definition})
-            assert st == 400
-            st, _ = await loop.run_in_executor(
-                None, post, "/api/v1/topology/submit",
-                {"name": "bad", "definition": {"spouts": []}})
-            assert st == 400
-
-            # CSRF guard: the custom header is mandatory
-            st, _ = await loop.run_in_executor(
-                None, lambda: post("/api/v1/topology/submit",
-                                   {"name": "x", "definition": definition},
-                                   with_header=False))
-            assert st == 403
-
-            # class allowlist: arbitrary dotted paths are rejected, not run
-            evil = {"spouts": [{"id": "s",
-                                "class": "subprocess.Popen",
-                                "args_list": [["touch", "/tmp/pwned"]]}]}
-            st, r = await loop.run_in_executor(
-                None, post, "/api/v1/topology/submit",
-                {"name": "evil", "definition": evil})
-            assert st == 400 and "allowed prefixes" in r["error"]
-            import os
-
-            assert not os.path.exists("/tmp/pwned")
-
-            rc, _ = await loop.run_in_executor(None, _ctl, url, "kill", "second")
-            assert rc == 0
-            for _ in range(100):
-                if "second" not in cluster.runtimes:
-                    break
-                await asyncio.sleep(0.05)
-            assert "second" not in cluster.runtimes
-            assert "primary" in cluster.runtimes  # untouched
-        finally:
-            await ui.stop()
-            await cluster.shutdown()
-
-    run(go(), timeout=120)
-
-
 def test_ctl_token_flag(run):
     """`ctl --token` sends the bearer header the auth-enabled daemon
     demands; without it, mutating commands come back 401."""
@@ -245,3 +145,16 @@ def test_ctl_token_flag(run):
             await cluster.shutdown()
 
     run(go(), timeout=60)
+
+
+@pytest.mark.parametrize("argv, refused", [
+    (["run", "demo", "in", "out", "--topology-file", "topology.toml"],
+     "unrecognized arguments: --topology-file"),
+    (["ctl", "submit", "demo", "topology.toml"], "invalid choice: 'submit'"),
+], ids=["run --topology-file", "ctl submit"])
+def test_a_closed_door_is_an_argument_error(argv, refused, capsys):
+    """PR 75: no flag and no subcommand builds a topology from a document."""
+    with pytest.raises(SystemExit) as e:
+        cli_main(argv)
+    assert e.value.code == 2
+    assert refused in capsys.readouterr().err

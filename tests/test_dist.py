@@ -64,7 +64,7 @@ def test_ack_envelope_roundtrip():
 
 def test_raw_scheme_rejected_at_submit_with_json_wire():
     """spout_scheme='raw' (bytes tuple values) is statically incompatible
-    with the JSON wire; when a topology PINS wire_format='json' (multilang
+    with the JSON wire; when a topology PINS wire_format='json' (mixed-version
     clusters), submit must fail fast, not livelock in warn-and-replay (the
     per-batch encode error is swallowed by the send loop). Under the
     default binary wire the combination is valid and the check is skipped
@@ -1122,7 +1122,7 @@ def test_peer_sender_falls_back_to_json_for_old_peer():
 
 def test_peer_sender_respects_json_pin():
     """wire_format='json' pins the envelope even when the peer advertises
-    binary (multilang/shell-bolt clusters)."""
+    binary (a cluster with an old receiver)."""
     received: list = []
     server, port = _fake_worker(True, received)
     try:

@@ -42,14 +42,6 @@ PREFIXES = ("storm_tpu", "tests", "benchmarks", "docs", "examples",
             "checkpoints")
 PY_ROOTS = ("storm_tpu", "tests", "benchmarks", "examples")
 
-# A name that is no file of the tree and is cited all the same, with the
-# reason it stands.
-EXCUSED = {
-    "storm.py": "Apache Storm's multilang module, whose protocol "
-                "storm_tpu/multilang.py speaks in its place",
-    "my_bolt.py": "the user's own component in ShellBolt's usage example",
-}
-
 _NOT_INSIDE = r"(?<![\w./<>{}$*~-])"
 _PREFIXED = re.compile(
     _NOT_INSIDE + r"(?:%s)/[\w./*<>{}$-]*" % "|".join(PREFIXES))
@@ -100,8 +92,8 @@ def citations(text: str, beside: Path = REPO):
         seen.append((m.start(), m.group(), (REPO / m.group()).is_file()))
     for m in _PY_NAME.finditer(text):
         if m.group().split("/")[0] not in PREFIXES:
-            seen.append((m.start(), m.group(), m.group() in EXCUSED
-                         or _py_exists(m.group(), beside)))
+            seen.append((m.start(), m.group(),
+                         _py_exists(m.group(), beside)))
     for m in _ROADMAP_ITEM.finditer(text):
         seen.append((m.start(), " ".join(m.group().split()), False))
     for at, token, holds in sorted(seen):

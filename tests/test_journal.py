@@ -100,21 +100,28 @@ def test_crc_rejects_tamper(tmp_path):
     assert st.replayed == 3 and st.activated is True  # tail dropped
 
 
-def test_snapshot_compaction_roundtrip(tmp_path):
+@pytest.mark.parametrize("last, snapshots, since_snapshot", [
+    (2, 0, 3),  # three appends: the WAL alone
+    (3, 1, 0),  # the fourth compacts: the snapshot alone
+    (4, 1, 1), (5, 1, 2), (6, 1, 3),  # the snapshot and a WAL after it
+])
+def test_snapshot_compaction_roundtrip(tmp_path, last, snapshots,
+                                       since_snapshot):
     j = ControllerJournal(str(tmp_path), snapshot_every=4)
     j.append("workers", peers={0: "127.0.0.1:1"}, pids={0: 9})
     j.append("submit", name="t", config={}, builder="standard",
              placement={})
-    for n in (2, 3, 4, 5, 6):
+    for n in range(2, last + 1):
         j.append("rebalance", component="infer", parallelism=n)
         j.maybe_snapshot()
-    assert j.stats()["snapshots"] >= 1
-    assert os.path.exists(os.path.join(str(tmp_path), SNAPSHOT_FILE))
+    assert j.stats()["snapshots"] == snapshots
+    assert os.path.exists(
+        os.path.join(str(tmp_path), SNAPSHOT_FILE)) == bool(snapshots)
     # WAL shrank: compaction truncated the folded prefix
-    assert j.stats()["since_snapshot"] < 7
+    assert j.stats()["since_snapshot"] == since_snapshot
     j.close()
     st = ControllerJournal(str(tmp_path)).load()
-    assert st.rebalances == {"infer": 6}
+    assert st.rebalances == {"infer": last}
     assert st.peers == {0: "127.0.0.1:1"}
 
 

@@ -373,14 +373,15 @@ def test_decode_message_set_sniffs_magic2():
     assert [r.offset for r in records] == [0, 1, 2]
 
 
-def test_varint_zigzag_edges():
+@pytest.mark.parametrize(
+    "v", [0, 1, -1, 63, -64, 64, 300, -300, 2**31, -(2**31), 2**62])
+def test_varint_zigzag_edges(v):
     from storm_tpu.connectors.kafka_protocol import _read_varint, _write_varint
 
-    for v in [0, 1, -1, 63, -64, 64, 300, -300, 2**31, -(2**31), 2**62]:
-        buf = bytearray()
-        _write_varint(buf, v)
-        got, pos = _read_varint(bytes(buf), 0)
-        assert got == v and pos == len(buf)
+    buf = bytearray()
+    _write_varint(buf, v)
+    got, pos = _read_varint(bytes(buf), 0)
+    assert got == v and pos == len(buf)
 
 
 def test_wire_client_produces_and_fetches_v2_batches():

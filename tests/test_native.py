@@ -145,40 +145,39 @@ def _need_native_tensor():
         pytest.skip("native tensor marshaller not built")
 
 
-def test_arrow_tensor_roundtrip_all_dtypes():
+@pytest.mark.parametrize("dt", [
+    np.float32, np.float64, np.float16, np.uint8, np.int8, np.uint16,
+    np.int16, np.uint32, np.int32, np.uint64, np.int64,
+], ids=lambda dt: dt.__name__)
+def test_arrow_tensor_roundtrip_all_dtypes(dt):
     _need_native_tensor()
     from storm_tpu.native import decode_tensor_native, encode_tensor_native
 
     rng = np.random.RandomState(0)
-    dtypes = [
-        np.float32, np.float64, np.float16, np.uint8, np.int8, np.uint16,
-        np.int16, np.uint32, np.int32, np.uint64, np.int64,
-    ]
-    for dt in dtypes:
-        for shp in [(4,), (2, 3), (1, 28, 28, 1), (3, 1, 2)]:
-            x = (rng.rand(*shp) * 100).astype(dt)
-            y = decode_tensor_native(encode_tensor_native(x))
-            assert y.dtype == x.dtype and y.shape == x.shape
-            np.testing.assert_array_equal(y, x)
+    for shp in [(4,), (2, 3), (1, 28, 28, 1), (3, 1, 2)]:
+        x = (rng.rand(*shp) * 100).astype(dt)
+        y = decode_tensor_native(encode_tensor_native(x))
+        assert y.dtype == x.dtype and y.shape == x.shape
+        np.testing.assert_array_equal(y, x)
 
 
-def test_arrow_tensor_pyarrow_cross_compat():
+@pytest.mark.parametrize("dt", [np.float32, np.float16, np.uint8, np.int64],
+                         ids=lambda dt: dt.__name__)
+def test_arrow_tensor_pyarrow_cross_compat(dt):
     _need_native_tensor()
     pa = pytest.importorskip("pyarrow")
     from storm_tpu.native import decode_tensor_native, encode_tensor_native
 
-    rng = np.random.RandomState(1)
-    for dt in [np.float32, np.float16, np.uint8, np.int64]:
-        x = (rng.rand(2, 5, 3) * 50).astype(dt)
-        # native writer -> pyarrow reader
-        z = pa.ipc.read_tensor(pa.py_buffer(encode_tensor_native(x))).to_numpy()
-        np.testing.assert_array_equal(z, x)
-        # pyarrow writer -> native reader
-        sink = pa.BufferOutputStream()
-        pa.ipc.write_tensor(pa.Tensor.from_numpy(x), sink)
-        w = decode_tensor_native(sink.getvalue().to_pybytes())
-        assert w.dtype == x.dtype
-        np.testing.assert_array_equal(w, x)
+    x = (np.random.RandomState(1).rand(2, 5, 3) * 50).astype(dt)
+    # native writer -> pyarrow reader
+    z = pa.ipc.read_tensor(pa.py_buffer(encode_tensor_native(x))).to_numpy()
+    np.testing.assert_array_equal(z, x)
+    # pyarrow writer -> native reader
+    sink = pa.BufferOutputStream()
+    pa.ipc.write_tensor(pa.Tensor.from_numpy(x), sink)
+    w = decode_tensor_native(sink.getvalue().to_pybytes())
+    assert w.dtype == x.dtype
+    np.testing.assert_array_equal(w, x)
 
 
 def test_arrow_tensor_decode_is_zero_copy_view():
@@ -193,14 +192,16 @@ def test_arrow_tensor_decode_is_zero_copy_view():
     np.testing.assert_array_equal(y, x)
 
 
-def test_arrow_tensor_malformed_rejected():
+@pytest.mark.parametrize("bad", [
+    b"", b"\x00" * 12, b"\xff\xff\xff\xff\x10\x00\x00\x00" + b"\x00" * 32,
+    b"garbage" * 5,
+], ids=["empty", "zeros", "continuation and nothing", "garbage"])
+def test_arrow_tensor_malformed_rejected(bad):
     _need_native_tensor()
     from storm_tpu.native import decode_tensor_native
 
-    for bad in [b"", b"\x00" * 12, b"\xff\xff\xff\xff\x10\x00\x00\x00" + b"\x00" * 32,
-                b"garbage" * 5]:
-        with pytest.raises(ValueError):
-            decode_tensor_native(bad)
+    with pytest.raises(ValueError):
+        decode_tensor_native(bad)
 
 
 def test_marshal_prefers_native_path(monkeypatch):
@@ -237,30 +238,29 @@ def test_arrow_tensor_fortran_order_falls_back():
     np.testing.assert_array_equal(decode_tensor(buf), x)
 
 
-def test_arrow_tensor_adversarial_dims_rejected():
+@pytest.mark.parametrize("evil", [-1, 2**62])
+def test_arrow_tensor_adversarial_dims_rejected(evil):
     _need_native_tensor()
     from storm_tpu.native import decode_tensor_native, encode_tensor_native
 
     good = encode_tensor_native(np.ones((2, 3), np.float32))
     idx = good.find((2).to_bytes(8, "little", signed=True), 8)
     assert idx > 0
-    for evil in (-1, 2**62):
-        patched = bytearray(good)
-        patched[idx : idx + 8] = evil.to_bytes(8, "little", signed=True)
-        with pytest.raises(ValueError):
-            decode_tensor_native(bytes(patched))
+    patched = bytearray(good)
+    patched[idx : idx + 8] = evil.to_bytes(8, "little", signed=True)
+    with pytest.raises(ValueError):
+        decode_tensor_native(bytes(patched))
 
 
-def test_arrow_tensor_accepts_any_buffer_type():
+@pytest.mark.parametrize("cast", [bytes, bytearray, memoryview])
+def test_arrow_tensor_accepts_any_buffer_type(cast):
     _need_native_tensor()
     from storm_tpu.native import decode_tensor_native, encode_tensor_native
 
     x = np.arange(24, dtype=np.float32).reshape(2, 3, 4)
-    buf = encode_tensor_native(x)
-    for cast in (bytes, bytearray, memoryview):
-        y = decode_tensor_native(cast(buf))
-        assert y is not None and not y.flags.owndata
-        np.testing.assert_array_equal(y, x)
+    y = decode_tensor_native(cast(encode_tensor_native(x)))
+    assert y is not None and not y.flags.owndata
+    np.testing.assert_array_equal(y, x)
 
 
 def test_arrow_tensor_unsupported_rank_falls_back():

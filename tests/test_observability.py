@@ -131,7 +131,7 @@ def test_multi_model_topology_shares_process(run):
 # ---- distributed tracing (per-record spans, flight recorder) -----------------
 
 
-def test_traceparent_roundtrip_and_malformed():
+def test_traceparent_roundtrip():
     from storm_tpu.runtime.tracing import TraceContext
 
     ctx = TraceContext("ab" * 16, "cd" * 8)
@@ -139,11 +139,18 @@ def test_traceparent_roundtrip_and_malformed():
     assert hdr == f"00-{'ab' * 16}-{'cd' * 8}-01"
     back = TraceContext.from_traceparent(hdr)
     assert back.trace_id == ctx.trace_id and back.span_id == ctx.span_id
-    for bad in (None, "", "00-short-cdcd-01", "no-dashes",
-                f"00-{'zz' * 16}-{'cd' * 8}-01",  # non-hex
-                f"00-{'ab' * 16}-{'cd' * 8}",     # missing flags
-                42):
-        assert TraceContext.from_traceparent(bad) is None
+
+
+@pytest.mark.parametrize("bad", [
+    None, "", "00-short-cdcd-01", "no-dashes",
+    f"00-{'zz' * 16}-{'cd' * 8}-01",  # non-hex
+    f"00-{'ab' * 16}-{'cd' * 8}",     # missing flags
+    42,
+])
+def test_a_malformed_traceparent_is_none(bad):
+    from storm_tpu.runtime.tracing import TraceContext
+
+    assert TraceContext.from_traceparent(bad) is None
 
 
 def test_tracer_sampling_gates_allocation():

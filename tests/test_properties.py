@@ -377,7 +377,7 @@ def test_binary_wire_roundtrip_any_values(batches, sampled, origins, anchors):
         max_size=6),
 )
 def test_json_wire_roundtrip_json_safe_values(vals):
-    """The JSON envelope (the multilang/mixed-version fallback) round-trips
+    """The JSON envelope (the mixed-version fallback) round-trips
     every JSON-safe value mix, including NaN/Inf floats, lone-surrogate
     text, and zero-arity tuples."""
     from storm_tpu.dist import transport
@@ -431,24 +431,39 @@ def test_binary_wire_corruption_fails_loudly(vals, flip, xor):
         wire.decode_deliveries(bytes(frame), now=50.0)
 
 
-def test_binary_wire_large_values_and_truncation():
-    """>64 KiB str and bytes values cross intact; truncated frames and
-    corrupted ack frames fail loudly; empty frames are valid."""
-    import pytest
+_BIG_BYTES = bytes(range(256)) * 400          # 102,400 B
+_BIG_STR = "packet-é" * 9000             # > 64 KiB utf-8
 
+
+def _big_frame():
     from storm_tpu.dist import wire
 
-    big_bytes = bytes(range(256)) * 400          # 102,400 B
-    big_str = "packet-é" * 9000             # > 64 KiB utf-8
-    t = _mk_tuple([big_bytes, big_str])
-    frame = wire.encode_deliveries([("b", 3, t)], now=1.0)
-    out = wire.decode_deliveries(frame, now=1.0)
-    assert out[0][2].values[0] == big_bytes
-    assert out[0][2].values[1] == big_str
+    return wire.encode_deliveries(
+        [("b", 3, _mk_tuple([_BIG_BYTES, _BIG_STR]))], now=1.0)
 
-    for cut in (0, 3, 11, len(frame) // 2, len(frame) - 1):
-        with pytest.raises(wire.WireError):
-            wire.decode_deliveries(frame[:cut], now=1.0)
+
+def test_binary_wire_large_values_cross_intact():
+    """>64 KiB str and bytes values cross intact."""
+    from storm_tpu.dist import wire
+
+    out = wire.decode_deliveries(_big_frame(), now=1.0)
+    assert out[0][2].values[0] == _BIG_BYTES
+    assert out[0][2].values[1] == _BIG_STR
+
+
+@pytest.mark.parametrize("cut", [0, 3, 11, "half", -1])
+def test_binary_wire_truncated_large_frame_fails_loudly(cut):
+    from storm_tpu.dist import wire
+
+    frame = _big_frame()
+    if cut == "half":
+        cut = len(frame) // 2
+    with pytest.raises(wire.WireError):
+        wire.decode_deliveries(frame[:cut], now=1.0)
+
+
+def test_binary_wire_corrupted_ack_frames_fail_loudly():
+    from storm_tpu.dist import wire
 
     acks = wire.encode_acks([("xor", 1, 2)])
     bad = bytearray(acks)
@@ -457,6 +472,10 @@ def test_binary_wire_large_values_and_truncation():
         wire.decode_acks(bytes(bad))
     with pytest.raises(wire.WireError):
         wire.decode_acks(acks[:-2])
+
+
+def test_binary_wire_empty_frames_are_valid():
+    from storm_tpu.dist import wire
 
     assert wire.decode_deliveries(
         wire.encode_deliveries([], now=0.0), now=0.0) == []

@@ -8,6 +8,7 @@ import asyncio
 
 import pytest
 
+import storm_tpu.runtime
 from storm_tpu.config import Config
 from storm_tpu.runtime import (
     Bolt,
@@ -540,3 +541,161 @@ def test_merge_offsets_max_wins():
     dst = {("t", 0): 5}
     merge_offsets(dst, [(("t", 0), 3), (("t", 1), 7), (("t", 0), 9)])
     assert dst == {("t", 0): 9, ("t", 1): 7}
+
+
+# ---- tick tuples (executor.py's ticker; the decode bolt's housekeeping) ------
+
+
+class TickBolt(Bolt):
+    """Counts its ticks and keeps what ``execute`` was handed. Class-level,
+    as CaptureBolt: the builder deep-copies the instance per task."""
+
+    tick_interval_s = 0.01  # no source of ticks: the topology's setting is
+    ticks = 0
+    executed = None
+    gate = None  # an asyncio.Event to hold ``execute`` on, or None
+    raising = False
+
+    @classmethod
+    def reset(cls, gate=None, raising=False):
+        cls.ticks, cls.executed = 0, []
+        cls.gate, cls.raising = gate, raising
+
+    async def execute(self, t):
+        TickBolt.executed.append(t)
+        if TickBolt.gate is not None:
+            await TickBolt.gate.wait()
+        self.collector.ack(t)
+
+    async def tick(self):
+        TickBolt.ticks += 1
+        if TickBolt.raising:
+            raise RuntimeError("tick failed")
+
+
+async def _ticked(items, tick_interval_s, inbox_capacity=4096):
+    cfg = Config()
+    cfg.topology.tick_interval_s = tick_interval_s
+    cfg.topology.inbox_capacity = inbox_capacity
+    cluster = AsyncLocalCluster()
+    b = TopologyBuilder()
+    b.set_spout("s", ListSpout(items), 1)
+    b.set_bolt("t", TickBolt(), 1).shuffle_grouping("s")
+    rt = await cluster.submit("ticks", cfg, b.build())
+    return cluster, rt, rt.bolt_execs["t"][0]
+
+
+async def until(cond, timeout=5.0):
+    deadline = asyncio.get_event_loop().time() + timeout
+    while not cond() and asyncio.get_event_loop().time() < deadline:
+        await asyncio.sleep(0.005)
+    return cond()
+
+
+def test_a_bolt_is_ticked_at_the_topologys_interval(run):
+    async def go():
+        TickBolt.reset()
+        cluster, rt, ex = await _ticked([], tick_interval_s=0.02)
+        t0 = asyncio.get_event_loop().time()
+        assert await until(lambda: TickBolt.ticks >= 3)
+        took = asyncio.get_event_loop().time() - t0
+        await cluster.shutdown()
+        return took
+
+    # three ticks need three intervals: the bolt's own 0.01 is not the clock
+    assert run(go()) >= 0.05
+
+
+def test_no_interval_no_ticks_whatever_the_bolt_says_of_itself(run):
+    async def go():
+        TickBolt.reset()
+        cluster, rt, ex = await _ticked(["a"], tick_interval_s=0.0)
+        assert await settle(rt, "s", 1)
+        await asyncio.sleep(0.08)  # eight of the bolt's own 0.01
+        assert ex._tick_task is None
+        assert TickBolt.ticks == 0
+        await cluster.shutdown()
+
+    run(go())
+
+
+def test_a_full_inbox_skips_the_tick_and_the_ticker_goes_on(run):
+    async def go():
+        gate = asyncio.Event()
+        TickBolt.reset(gate=gate)
+        # "a" is held in execute, "b" fills the inbox of one
+        cluster, rt, ex = await _ticked(["a", "b"], 0.01, inbox_capacity=1)
+        assert await until(lambda: ex.inbox.full() and TickBolt.executed)
+        await asyncio.sleep(0.06)  # six intervals, each finding it full
+        assert TickBolt.ticks == 0
+        assert not ex._tick_task.done(), "the ticker stalled or died"
+        gate.set()
+        assert await until(lambda: TickBolt.ticks >= 2)
+        assert [t.get("message") for t in TickBolt.executed] == ["a", "b"]
+        await cluster.shutdown()
+
+    run(go())
+
+
+def test_a_tick_is_not_acked_not_counted_and_not_handed_to_execute(run):
+    async def go():
+        TickBolt.reset()
+        cluster, rt, ex = await _ticked(["a", "b", "c"], tick_interval_s=0.01)
+        assert await settle(rt, "s", 3)
+        assert await until(lambda: TickBolt.ticks >= 3)
+        spout = rt.spout_execs["s"][0].spout
+        snap = rt.metrics.snapshot()["t"]
+        await cluster.shutdown()
+        return spout, snap, ex.n_executed, rt.ledger.inflight
+
+    spout, snap, n_executed, inflight = run(go())
+    assert [t.get("message") for t in TickBolt.executed] == ["a", "b", "c"]
+    assert not any(t.stream == "__tick" for t in TickBolt.executed)
+    assert snap["executed"] == 3 and n_executed == 3
+    assert snap["execute_ms"]["count"] == 3
+    assert sorted(spout.acked) == ["a", "b", "c"] and spout.failed == []
+    assert inflight == 0
+
+
+def test_a_tick_that_raises_is_reported_and_fails_no_tuple(run):
+    async def go():
+        TickBolt.reset(raising=True)
+        cluster, rt, ex = await _ticked(["a"], tick_interval_s=0.01)
+        assert await settle(rt, "s", 1)
+        assert await until(lambda: TickBolt.ticks >= 2)
+        spout = rt.spout_execs["s"][0].spout
+        errors, n_errors = len(rt.errors), ex.n_errors
+        alive = not ex._task.done()
+        await cluster.shutdown()
+        return spout, errors, n_errors, alive
+
+    spout, errors, n_errors, alive = run(go())
+    assert alive, "a failing tick must not end the executor"
+    assert errors >= 2 and n_errors >= 2
+    assert spout.acked == ["a"] and spout.failed == []
+
+
+def test_the_ticker_is_cancelled_at_stop(run):
+    async def go():
+        TickBolt.reset()
+        cluster, rt, ex = await _ticked([], tick_interval_s=0.01)
+        assert await until(lambda: TickBolt.ticks >= 1)
+        ticker = ex._tick_task
+        await cluster.shutdown()
+        await asyncio.sleep(0)  # let the cancellation land
+        at_stop = TickBolt.ticks
+        await asyncio.sleep(0.05)
+        return ticker, at_stop
+
+    ticker, at_stop = run(go())
+    assert ticker.cancelled()
+    assert TickBolt.ticks == at_stop
+
+
+# ---- the package's own list of names ------------------------------------------
+
+
+@pytest.mark.parametrize("name", storm_tpu.runtime.__all__)
+def test_every_name_the_runtime_lists_is_there(name):
+    assert getattr(storm_tpu.runtime, name).__module__.startswith(
+        "storm_tpu.runtime.")

@@ -20,7 +20,7 @@ from storm_tpu.runtime import (
 from storm_tpu.runtime.chaos import ChaosMonkey
 from storm_tpu.runtime.cluster import AsyncLocalCluster
 
-from test_runtime import ListSpout
+from test_runtime import ListSpout, until
 
 
 class CountBolt(StatefulBolt):
@@ -210,6 +210,144 @@ def test_non_stateful_bolt_untouched(run):
                 await asyncio.sleep(0.05)
             assert rt.state_backend.load("cap", 0) is None
             assert "checkpoints" not in rt.metrics.snapshot().get("cap", {})
+        finally:
+            await cluster.shutdown()
+
+    run(scenario(), timeout=30)
+
+
+# ---- the executor's checkpoint hook (what DecodeBolt stands on) --------------
+
+
+async def _counting(cfg, items=(), bolt=None):
+    builder = TopologyBuilder()
+    builder.set_spout("spout", ListSpout(list(items)), 1)
+    builder.set_bolt("count", bolt or CountBolt(), 1).shuffle_grouping("spout")
+    cluster = AsyncLocalCluster()
+    rt = await cluster.submit("hook", cfg, builder.build())
+    return cluster, rt
+
+
+def _checkpoints(rt):
+    return rt.metrics.snapshot().get("count", {}).get("checkpoints", 0)
+
+
+def test_a_stateful_bolt_is_checkpointed_on_the_interval(run):
+    """No stop, no ``checkpoint_now``: the interval alone saves."""
+
+    async def scenario():
+        cluster, rt = await _counting(_config(checkpoint_interval_s=0.02),
+                                      ["a", "b", "a"])
+        try:
+            assert await until(
+                lambda: (rt.state_backend.load("count", 0) or (0, {}))[1]
+                == {"a": 2, "b": 1})
+            assert _checkpoints(rt) >= 1
+            assert rt.bolt_execs["count"][0]._tick_task is None  # not a tick
+        finally:
+            await cluster.shutdown()
+
+    run(scenario(), timeout=30)
+
+
+class CommitBolt(CountBolt):
+    """Persist, then ack: the decode bolt's order."""
+
+    async def execute(self, t):
+        key = t.get("message")
+        self.state.put(key, self.state.get(key, 0) + 1)
+        self.checkpoint_now()
+        self.collector.ack(t)
+
+
+def test_checkpoint_now_has_saved_before_the_ack_that_follows(run):
+    async def scenario():
+        # an interval no run reaches: every save here is checkpoint_now's
+        cluster, rt = await _counting(_config(checkpoint_interval_s=30.0),
+                                      bolt=CommitBolt())
+        try:
+            spout = rt.spout_execs["spout"][0].spout
+            log = []
+            save = rt.state_backend.save
+
+            def spy_save(component, task, version, snap):
+                save(component, task, version, snap)
+                log.append(("saved", dict(snap)))
+
+            rt.state_backend.save = spy_save
+            spout.ack = lambda msg_id: log.append(("acked", msg_id))
+            spout.queue.extend(["x", "y", "x"])
+            assert await until(lambda: len(log) >= 6)
+        finally:
+            await cluster.shutdown()
+        return log
+
+    assert run(scenario(), timeout=30)[:6] == [
+        ("saved", {"x": 1}), ("acked", "x"),
+        ("saved", {"x": 1, "y": 1}), ("acked", "y"),
+        ("saved", {"x": 2, "y": 1}), ("acked", "x"),
+    ]
+
+
+class FoldBolt(StatefulBolt):
+    """Keeps an aggregate beside the state and folds it in when asked."""
+
+    folds = 0
+
+    def init_state(self, state):
+        super().init_state(state)
+        self.seen = list(state.get("seen", []))
+
+    async def execute(self, t):
+        self.seen.append(t.get("message"))
+        self.state.put("n", len(self.seen))
+        self.collector.ack(t)
+
+    def pre_checkpoint(self):
+        FoldBolt.folds += 1
+        self.state.put("seen", list(self.seen))
+
+
+def test_pre_checkpoint_folds_before_the_snapshot_and_a_clean_state_is_not_saved(run):
+    async def scenario():
+        FoldBolt.folds = 0
+        cluster, rt = await _counting(_config(checkpoint_interval_s=0.01),
+                                      ["a", "b", "c"], bolt=FoldBolt())
+        try:
+            assert await until(
+                lambda: (rt.state_backend.load("count", 0) or (0, {}))[1]
+                .get("n") == 3)
+            version, snap = rt.state_backend.load("count", 0)
+            # the snapshot holds what the hook put there on the way in
+            assert snap == {"n": 3, "seen": ["a", "b", "c"]}
+            folds, saves = FoldBolt.folds, _checkpoints(rt)
+            await asyncio.sleep(0.06)  # six intervals over a clean state
+            assert rt.state_backend.load("count", 0)[0] == version
+            assert (FoldBolt.folds, _checkpoints(rt)) == (folds, saves)
+            assert saves == version  # one version a save, none skipped
+        finally:
+            await cluster.shutdown()
+
+    run(scenario(), timeout=30)
+
+
+def test_a_replacement_executor_restores_the_last_version(run, tmp_path):
+    """What a predecessor saved is what ``init_state`` hands over, and the
+    numbering goes on from it."""
+
+    async def scenario():
+        FileStateBackend(str(tmp_path)).save(
+            "count", 0, 1, {"n": 1, "seen": ["stale"]})
+        FileStateBackend(str(tmp_path)).save(
+            "count", 0, 7, {"n": 2, "seen": ["p", "q"]})
+        cfg = _config(checkpoint_interval_s=0.01)
+        cfg.topology.state_dir = str(tmp_path)
+        cluster, rt = await _counting(cfg, ["r"], bolt=FoldBolt())
+        try:
+            assert await until(
+                lambda: rt.state_backend.load("count", 0)[0] > 7)
+            assert rt.state_backend.load("count", 0) == (
+                8, {"n": 3, "seen": ["p", "q", "r"]})
         finally:
             await cluster.shutdown()
 

@@ -614,3 +614,34 @@ def test_ui_scorecard_route(run):
             await cluster.shutdown()
 
     run(go(), timeout=60)
+
+
+# ---- the doors that closed in PR 75: remote submission and DRPC --------------
+
+_TOKEN = {"authorization": "Bearer s3cret-tok"}
+_DEFINITION = {"name": "x", "definition": {"spouts": [{
+    "id": "s", "class": "storm_tpu.connectors.spout.BrokerSpout"}]}}
+
+
+@pytest.mark.parametrize("path, body, error", [
+    # "submit" is a topology's name like any other, and nothing has it
+    ("/api/v1/topology/submit", _DEFINITION, "no topology named 'submit'"),
+    ("/api/v1/drpc/x", {"args": "1"}, "no route '/api/v1/drpc/x'"),
+])
+def test_a_closed_door_answers_404_to_the_token(run, path, body, error):
+    ui = UIServer(AsyncLocalCluster(), auth_token="s3cret-tok")
+    assert run(ui._route("POST", path, {}, body, _TOKEN)) == (
+        404, {"error": error})
+
+
+def test_no_post_goes_unguarded_the_old_data_plane_neither(run):
+    """At the parent ``/api/v1/drpc/`` was let past the token check."""
+    ui = UIServer(AsyncLocalCluster(), auth_token="s3cret-tok")
+    status, err = run(ui._route("POST", "/api/v1/drpc/x", {}, {"args": "1"}))
+    assert status == 401 and "token" in err["error"]
+
+
+@pytest.mark.parametrize("argument", ["drpc", "resources"])
+def test_the_ui_takes_no_argument_that_opened_a_door(argument):
+    with pytest.raises(TypeError):
+        UIServer(AsyncLocalCluster(), **{argument: {}})
