@@ -22,12 +22,15 @@ with.
   :func:`causal_blocked`, XLA's form, elsewhere and on the CPU. With a
   ``window`` (Trinity's sliding layers) a query reads its last ``window``
   keys alone, by the same rule and in the same two forms: the kernel never
-  loads the key blocks that lie before every window of a query tile, the
-  blocked form slices a block of queries' keys from the first block a
-  window reaches, and both mask the blocks that hold a window's lower
-  edge. Such a loop is the part ``mix.window_attention`` in a trace and
-  ``window_attention=...`` in the inventory: the two loops carry the same
-  shapes, so only a name tells them apart.
+  loads the keys that lie before every window of a query tile (its walk
+  over them, counted back from the tile's own end or over aligned blocks,
+  is ops/flash_attention.py ``window_walk``'s to choose from the window and
+  the tiles, and the note ends ``-tile-end`` for the first), the blocked
+  form slices a block of queries' keys from the first block a window
+  reaches, and both mask what holds a window's lower edge. Such a loop is
+  the part ``mix.window_attention`` in a trace and ``window_attention=...``
+  in the inventory: the two loops carry the same shapes, so only a name
+  tells them apart.
 
 The forms of one rule are cross-checked in tests/test_ops.py under the Pallas
 interpreter and compiled on the chip by ops/parity_checks.py.
@@ -160,13 +163,20 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     ``window`` (static; None: all of the above as it was): the query at ``t``
     reads the keys ``t - window < s <= t``, at most ``window`` of them,
-    itself among them. The same rule picks the form; the kernel starts a
-    query tile's loop at the first key block one of its windows reaches and
-    never loads the blocks before it, the blocked form slices from that
-    block, and both mask the block or two that hold a lower edge. The loop is
-    the part ``mix.window_attention`` and the note ``window_attention``. A
-    window of the sequence's length or more bounds nothing: plain causal
-    attention, by that path and under its names."""
+    itself among them. The same rule picks the form. The kernel walks a
+    query tile's keys by ops/flash_attention.py ``window_walk``, a rule on
+    the window and the tiles: for a window of whole tiles and one to eight
+    key blocks (Trinity's 2,048 keys on tiles of 64 x 512) counted back from
+    the tile's own last query, the diagonal's block, the blocks that lie
+    inside every one of the tile's windows and one masked chunk at the lower
+    edge, ``window + block_q`` columns and nothing before ``first - window``
+    (the note ends ``-tile-end``); for every other window over the blocks
+    aligned to ``block_k`` from the first one a window reaches, the block or
+    two that hold a lower edge masked. The blocked form slices from that
+    block and masks likewise. The loop is the part ``mix.window_attention``
+    and the note ``window_attention``. A window of the sequence's length or
+    more bounds nothing: plain causal attention, by that path and under its
+    names."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     hq, hkv, s = q.shape[1], k.shape[1], q.shape[2]
@@ -177,14 +187,16 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if window is not None and window >= s:
         window = None
     form = causal_form(hq, hkv, s, q.shape[-1], v.shape[-1])
-    _note("causal_attention" if window is None else "window_attention",
-          form + ("-grouped" if hq != hkv else ""))
+    name = "causal_attention" if window is None else "window_attention"
+    grouped = "-grouped" if hq != hkv else ""
     if form == "blocked":
+        _note(name, form + grouped)
         return causal_blocked(q, k, v, scale, block, window)
 
     from storm_tpu.ops.flash_attention import causal_tiles, flash_attention
 
     block_q, block_k = causal_tiles(hq // hkv)
+    _note(name, form + grouped + _walk_suffix(window, block_q, block_k))
     with jax.named_scope(_loop_part(window)):
         return jax.lax.map(lambda i: flash_attention(
             q, k, v, scale=scale, block_q=block_q, block_k=block_k,
@@ -194,6 +206,17 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def _loop_part(window: Optional[int]) -> str:
     """The part a row loop of causal attention is in a trace."""
     return P.MIX_ATTENTION if window is None else P.MIX_WINDOW_ATTENTION
+
+
+def _walk_suffix(window: Optional[int], block_q: int, block_k: int) -> str:
+    """What a kernel's note ends in where its tiles walk a window's keys
+    counted back from their own end (ops/flash_attention.py
+    ``window_walk``); nothing for the aligned walk, whose note is as it
+    was."""
+    from storm_tpu.ops.flash_attention import window_walk
+
+    walk = window_walk(window, block_q, block_k)
+    return "" if walk == "aligned" else "-" + walk
 
 
 def causal_blocked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -376,8 +399,9 @@ def causal_attention_merged(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 (q, heads), (k, kv_heads), (v, kv_heads))),
             scale, block, window)
         return merge_heads(out)
-    _note(name, form + grouped + "-merged" + ("-halves" if per > 1 else ""))
     block_q, block_k = F.causal_tiles(per * heads // kv_heads)
+    _note(name, form + grouped + "-merged" + ("-halves" if per > 1 else "")
+          + _walk_suffix(window, block_q, block_k))
     with jax.named_scope(_loop_part(window)):
         # a row's call writes its row of the result where it lies
         return jax.lax.fori_loop(

@@ -35,17 +35,39 @@ the carry from one loop to the next through VMEM; PERF.md §6, PR 42). ``Dv``
 may differ from ``Dk``.
 
 ``window`` (static, with ``causal``; None: the causal form above, text for
-text) also bounds the loop from below: a query at ``t`` reads the keys ``t -
-window < s <= t``, itself among them. For a tile whose first query is
-``first``, the key blocks whose last key is at or before ``first - window``
-lie before every one of the tile's windows and are never loaded nor
-multiplied: the loop starts at the block that holds key ``first - window +
-1``, not at key 0. The block or two that hold some query's lower edge (the
-keys up to ``first + block_q - 1 - window``) run with the mask ``s > t -
-window``, the blocks between them and the diagonal without one, the
-diagonal's as above with both tests. At 16,384 positions and a window of
-2,048 a tile of 64 positions walks five or six blocks of 512 keys where the
-causal form walks 16.5 in the mean.
+text) also bounds the walk from below: a query at ``t`` reads the keys ``t -
+window < s <= t``, itself among them, and the keys before every window of a
+tile are never loaded nor multiplied. :func:`window_walk`, a rule on the
+window and the tiles alone, says how a tile walks the rest:
+
+* ``"tile-end"`` (:func:`_tile_end_walk`; a window of whole tiles and one to
+  eight key blocks, the tile dividing the key block: Trinity's 2,048 keys on
+  tiles of 64 x 512): the blocks are counted back from the tile's own last
+  query. The diagonal's block first, whose result *is* the carry; the
+  ``window // block_k - 1`` blocks before it, which lie inside every one of
+  the tile's windows, without a mask and written out; one masked chunk of
+  ``window % block_k + block_q`` keys from ``first - window`` for the lower
+  edges: ``window + block_q`` columns a tile, 2,112 at Trinity's sizes, and
+  nothing before ``first - window`` is read. A tile the window does not
+  bind yet (``first < window``) is plain causal and runs the causal form.
+* ``"aligned"`` (every other window): the loop over blocks at multiples of
+  ``block_k`` starts at the block that holds key ``first - window + 1``; the
+  block or two that hold some query's lower edge (the keys up to ``first +
+  block_q - 1 - window``) run with the mask ``s > t - window``, the blocks
+  between them and the diagonal without one, the diagonal's as above with
+  both tests: ``window + block_k`` columns, two blocks of them masked (five
+  blocks of 512 where the other walk loads 2,112 columns).
+
+By the static schedule (compiles for the described v5e at Trinity's shapes;
+bundles a bound tile, PERF.md section 6, PR 76): aligned 8,520; counted back
+with the early tiles chosen by scalars and the clear blocks a loop 6,562 (a
+chunk of 128 keys overlapping the lowest clear block, the overlap masked:
+6,560; the chunk folded into the diagonal's step: 6,584; a ``lax.cond``
+between the two kinds of tile: 7,047); static under ``pl.when`` 6,232; the
+clear blocks written out besides **4,971**, the form kept (the chunk of 128:
+4,967; folded into the diagonal's step: 4,940-4,960: all within the
+schedule's own noise, so the plainest stays). The causal form walks 16.5
+blocks in the mean where the window walks four and a chunk.
 
 Shapes are padded: S to block multiples, a width to whole lane tiles unless
 it is whole tiles and a half (``lane_width``: Kimi-Linear's 192-wide keys are
@@ -113,8 +135,30 @@ def causal_tiles(group: int) -> tuple:
     return 1 << (most.bit_length() - 1), 512
 
 
+def _fold(s, v, carry):
+    """A block's scores ``s: (rows, keys)`` and values ``v: (keys, Dv)`` into
+    the online-softmax carry (running max, denominator, accumulator: all
+    float32). ``carry`` None: the block *sets* it, and no carry of ``-1e30``
+    and zeros is built, spilled and rescaled for it."""
+    weighted = functools.partial(
+        lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if carry is None:
+        m_new = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m_new)
+        return m_new, p.sum(axis=-1, keepdims=True), weighted(
+            p.astype(v.dtype), v)
+    m, l, acc = carry
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_new = l * alpha + p.sum(axis=-1, keepdims=True)
+    acc_new = acc * alpha + weighted(p.astype(v.dtype), v)
+    return m_new, l_new, acc_new
+
+
 def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
-                 block_k, causal, window=None):
+                 block_k, causal, window=None, first=None):
     """One tile of queries against its key head's keys, block by block.
 
     ``q_ref: (1, G, BQ, Dk)`` holds the same ``BQ`` positions of the ``G``
@@ -122,12 +166,25 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
     stacked into one ``(G * BQ, Dk)`` left operand, so a key block is met by
     the whole group at once. ``k_ref: (1, Sk, Dk)`` and ``v_ref: (1, Sk, Dv)``
     are the key head's whole sequence, resident in VMEM over the head's
-    query tiles."""
+    query tiles. ``first`` (None: read from the grid) is the tile's first
+    position, handed down where this body runs under a branch."""
+    if window_walk(window, q_ref.shape[2], block_k) == "tile-end":
+        # a tile whose every window still reaches key 0 is plain causal;
+        # the branches are handed the tile's first position (the interpreter
+        # knows no ``program_id`` inside one)
+        first = pl.program_id(1) * q_ref.shape[2]
+        pl.when(first >= window)(lambda: _tile_end_walk(
+            first, q_ref, k_ref, v_ref, o_ref, scale, block_k, window))
+        pl.when(first < window)(lambda: _attn_kernel(
+            at_ref, q_ref, k_ref, v_ref, o_ref, scale=scale, s_valid=s_valid,
+            block_k=block_k, causal=True, first=first))
+        return
     del at_ref  # read by the block specs
     g, bq, dk = q_ref.shape[1:]
     rows = g * bq
     q = q_ref[0].reshape(rows, dk)
-    first = pl.program_id(1) * bq  # the tile's first position
+    if first is None:
+        first = pl.program_id(1) * bq  # the tile's first position
     if causal:
         # blocks wholly at or before the tile's first query need no mask;
         # blocks that begin after its last query are never loaded
@@ -147,7 +204,6 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
             pl.cdiv(jnp.maximum(first + bq - window, 0), block_k), clear)
 
     def step(masked, i, carry):
-        m, l, acc = carry
         at = pl.multiple_of(i * block_k, block_k)
         k = k_ref[0, pl.ds(at, block_k), :]  # (BK, Dk)
         v = v_ref[0, pl.ds(at, block_k), :]  # (BK, Dv)
@@ -167,15 +223,7 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
                 seen = key < s_valid
             s = jnp.where(seen, s.reshape(g, bq, block_k), _NEG).reshape(
                 rows, block_k)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * alpha + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
+        return _fold(s, v, carry)
 
     carry = (jnp.full((rows, 1), _NEG, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
@@ -193,6 +241,81 @@ def _attn_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s_valid,
     else:
         _, l, acc = lax.fori_loop(clear, end, functools.partial(step, True),
                                   carry)
+    o_ref[0] = (acc / l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
+
+
+# The most key blocks a window may hold for its walk to be written out block by
+# block (``_tile_end_walk``: a body a block in the kernel's text; Trinity's
+# 2,048 keys are four).
+_WALK_BLOCKS = 8
+
+
+def window_walk(window: Optional[int], block_q: int, block_k: int) -> str:
+    """Which walk over key blocks a call's tiles take: ``"tile-end"``
+    (:func:`_tile_end_walk`) for a window of one to ``_WALK_BLOCKS`` key
+    blocks that the query tile divides, as it divides the key block (every
+    block then starts at a multiple of the tile, whole sublane tiles, and a
+    tile the window does not bind yet is plain causal); ``"aligned"``
+    (:func:`_attn_kernel`'s loops over blocks at multiples of ``block_k``)
+    for every other window and without one. A function of the call's static
+    arguments alone; ops/attention.py notes it."""
+    if (window is not None and block_k % block_q == 0
+            and window % block_q == 0
+            and block_k <= window <= _WALK_BLOCKS * block_k):
+        return "tile-end"
+    return "aligned"
+
+
+def _tile_end_walk(first, q_ref, k_ref, v_ref, o_ref, scale, block_k,
+                   window):
+    """:func:`_attn_kernel`'s tile where a window of ``window`` keys binds it
+    (``first >= window``), its key blocks counted back from the tile's own
+    last query and not from multiples of ``block_k``: the ``BQ`` windows
+    together hold the keys ``first - window < s < first + BQ``, and the walk
+    loads ``window + BQ`` columns for them where blocks aligned to
+    ``block_k`` load ``window + block_k`` and mask two blocks of them.
+
+    * the diagonal's block first, the ``block_k`` keys up to the tile's last
+      query, under the causal mask: its result *is* the carry
+      (:func:`_fold`);
+    * the ``window // block_k - 1`` blocks before it, each inside every one
+      of the tile's windows: no mask. They are written out, not looped over:
+      a loop hands its carry of 192 registers from trip to trip through VMEM;
+    * one chunk of ``window % block_k + BQ`` keys from ``first - window``,
+      which holds every lower edge, under the mask ``s > t - window``.
+
+    Every start is a multiple of ``BQ``. What a key lies ahead of a query by
+    is static in each step, so the masks are constants."""
+    g, bq, dk = q_ref.shape[1:]
+    rows = g * bq
+    q = q_ref[0].reshape(rows, dk)
+    end = first + bq  # one past the tile's last query
+
+    def step(back, size, carry, seen=None):
+        """The ``size`` keys from ``end - back`` on; ``seen`` says of ``key -
+        query`` which pairs are."""
+        at = pl.multiple_of(end - back, bq)
+        k = k_ref[0, pl.ds(at, size), :]
+        v = v_ref[0, pl.ds(at, size), :]
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        if seen is not None:
+            # key - query: the key's column less the query's row, and where
+            # the step begins less where the tile does
+            ahead = (lax.broadcasted_iota(jnp.int32, (bq, size), 1)
+                     - lax.broadcasted_iota(jnp.int32, (bq, size), 0)
+                     + (bq - back))
+            s = jnp.where(seen(ahead), s.reshape(g, bq, size), _NEG).reshape(
+                rows, size)
+        return _fold(s, v, carry)
+
+    blocks = window // block_k
+    carry = step(block_k, block_k, None, lambda ahead: ahead <= 0)
+    for i in range(1, blocks):
+        carry = step((i + 1) * block_k, block_k, carry)
+    _, l, acc = step(window + bq, window - blocks * block_k + bq, carry,
+                     lambda ahead: ahead > -window)
     o_ref[0] = (acc / l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
 
 
@@ -306,7 +429,7 @@ def _merged_kernel(row_ref, q_ref, k_ref, v_ref, _, o_ref, qs_ref, os_ref,
     head a block of ``Dk`` lanes: the ``G`` lane blocks are stacked into the
     ``(1, G, BQ, Dk)`` tile it works on (static slices of whole lane tiles, in
     VMEM) and its result is unstacked the same way into ``(1, BQ, G * Dv)``.
-    The loop over key blocks, a window's three parts and the carry are that
+    The walk over key blocks, a window's either walk and the carry are that
     kernel's: nothing of them is here. q and k come turned (or need no
     turn): :func:`_turning_kernel` is this kernel for a caller whose plain
     rotary turn is still to do."""

@@ -190,6 +190,72 @@ def test_trinitys_mixer_compiles_with_its_heads_merged_from_projection_to_projec
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 2 ** 30
 
 
+def _kernel_modules(lowered):
+    """The Mosaic modules of a lowered program's Pallas calls, printed
+    without debug info (a body is serialized with its source lines)."""
+    import base64
+    import re
+
+    from jaxlib.mlir import ir
+
+    texts = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           lowered.as_text()):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            texts.append(ir.Module.parse(base64.b64decode(body)).operation
+                         .get_asm(enable_debug_info=False))
+    return texts
+
+
+def test_trinitys_window_kernel_walks_back_from_the_tiles_end_and_its_full_layers_is_the_parents(
+        v5e):
+    """One row's call of the merged entry at the published sizes (32 query
+    heads on 4 key heads of 128, 16,384 positions, tiles of 64 x 512), as a
+    sliding layer and as the full layer make it. With the window of 2,048
+    keys the kernel holds two branches: a tile the window binds walks the
+    diagonal's block, ``2048 // 512 - 1`` clear blocks written out (no loop:
+    four score tiles ``512 x 512`` and their value products) and one chunk of
+    64 keys, ten products and masks under two comparisons alone; a tile
+    before that the causal form's loop and its diagonal. Both compile for the
+    described v5e. Without a window the kernel's module is, operation for
+    operation, what the parent of PR 76 lowered (its digest, debug
+    information stripped: a line of the file may shift, the kernel may
+    not)."""
+    import hashlib
+    import re
+
+    from storm_tpu.ops.flash_attention import (flash_attention_merged,
+                                               window_walk)
+
+    wide, narrow = (_spec((4, 16384, h * 128), jnp.bfloat16, v5e)
+                    for h in (32, 4))
+    row = _spec((), jnp.int32, v5e)
+
+    def lowered(window):
+        return jax.jit(lambda out, q, k, v, i: flash_attention_merged(
+            out, q, k, v, i, heads=32, kv_heads=4, scale=128 ** -0.5,
+            block_q=64, block_k=512, window=window)).lower(
+            wide, wide, narrow, narrow, row)
+
+    assert window_walk(2048, 64, 512) == "tile-end"
+    sliding = lowered(2048)
+    (kernel,) = _kernel_modules(sliding)
+    _, bound, early = kernel.split('"stable_mosaic.scf.if"')
+    assert "scf.for" not in bound
+    # four blocks' scores, the chunk's, and each one's product with v
+    assert sorted(re.findall(r"tpu\.matmul.* -> vector<(\w+)xf32>", bound)) \
+        == sorted(4 * ["512x512"] + ["512x64"] + 5 * ["512x128"])
+    # the diagonal's mask and the chunk's, and no other
+    assert len(re.findall(r"arith\.cmpi.*vector<", bound)) == 2
+    assert early.count("scf.for") == 1 and early.count("tpu.matmul") == 4
+    assert "tpu_custom_call" in sliding.compile().as_text()
+
+    (kernel,) = _kernel_modules(lowered(None))
+    assert hashlib.sha256(kernel.encode()).hexdigest()[:16] == \
+        "efccc2616ba9ce19"
+
+
 def test_the_indexers_selection_compiles_at_the_published_sizes(v5e):
     """``ops/sparse_attention.py select_keys``' kernel as Keye-VL-2.0's cell
     calls it, one row: 16 indexer heads of 64 over 16,384 positions, the top

@@ -37,7 +37,8 @@ from storm_tpu.ops.attention import (causal_attention,  # noqa: E402
                                      causal_attention_merged, causal_blocked,
                                      merge_heads)
 from storm_tpu.ops.flash_attention import (flash_attention,  # noqa: E402
-                                           flash_attention_merged)
+                                           flash_attention_merged,
+                                           window_walk)
 from storm_tpu.ops.kda import rmsnorm_heads  # noqa: E402
 from storm_tpu.ops.platform import dispatch_notes  # noqa: E402
 from storm_tpu.parallel.moe import topk_moe_layer  # noqa: E402
@@ -113,23 +114,102 @@ def test_a_sequence_of_no_whole_blocks_is_padded_behind_the_window(s):
                                atol=2e-6)
 
 
-def test_the_kernel_reads_no_key_block_before_a_tiles_windows():
+@pytest.mark.parametrize("window,walk,spoiled_to", [
+    (100, "aligned", 192), (128, "tile-end", 176), (96, "tile-end", 208)])
+def test_the_kernel_reads_no_key_block_before_a_tiles_windows(
+        window, walk, spoiled_to):
     """Keys before every window of a tile may hold anything, NaN too: a
     block that was loaded and multiplied, then masked, would carry it into
     the sums (0 x NaN). The last tile of 16 queries (positions 304-319)
-    with a window of 100 reaches back to key 205: blocks 0-2 (keys 0-191)
-    are never read."""
+    with a window of 100 reaches back to key 205, and the walk over blocks
+    aligned to 64 never reads blocks 0-2 (keys 0-191). The walk counted
+    back from the tile's end promises more: nothing before ``first -
+    window`` is read (keys 0-175 at a window of 128, where the aligned walk
+    loaded the block of keys 128-191; keys 0-207 at 96, whose chunk is 48
+    keys wide)."""
+    assert window_walk(window, 16, BLOCK) == walk
     q, k, v = _qkv(8, 2, SEQ, 32)
-    want = _naive(q, k, v, 0.2, 100)[:, :, 304:]
-    spoiled = [y.at[:, :, :192].set(jnp.nan) for y in (k, v)]
+    want = _naive(q, k, v, 0.2, window)[:, :, 304:]
+    spoiled = [y.at[:, :, :spoiled_to].set(jnp.nan) for y in (k, v)]
     got = flash_attention(q, *spoiled, scale=0.2, block_q=16, block_k=BLOCK,
-                          causal=True, window=100, interpret=True)
+                          causal=True, window=window, interpret=True)
     np.testing.assert_allclose(got[:, :, 304:], want, atol=2e-6)
     # the blocked form's last block of 64 queries (256-319) reaches back to
-    # key 157: it slices its keys from 128
-    spoiled = [y.at[:, :, :128].set(jnp.nan) for y in (k, v)]
-    got = causal_blocked(q, *spoiled, 0.2, BLOCK, 100)
+    # key 256 - window + 1: it slices its keys from that key's block
+    first = (256 - window + 1) // BLOCK * BLOCK
+    spoiled = [y.at[:, :, :first].set(jnp.nan) for y in (k, v)]
+    got = causal_blocked(q, *spoiled, 0.2, BLOCK, window)
     np.testing.assert_allclose(got[:, :, 304:], want, atol=2e-6)
+
+
+# ---- the walk counted back from a tile's own last query ------------------------
+
+@pytest.mark.parametrize("window,block_q,block_k,walk", [
+    (2048, 64, 512, "tile-end"),    # Trinity's sliding layers
+    (2048, 512, 512, "tile-end"),   # a head on its own keys
+    (512, 64, 512, "tile-end"),     # one block: the diagonal's and a chunk
+    (4096, 64, 512, "tile-end"),    # eight blocks, the most written out
+    (2112, 64, 512, "tile-end"),    # no multiple of a block: a wider chunk
+    (4608, 64, 512, "aligned"),     # nine blocks
+    (448, 64, 512, "aligned"),      # shorter than a key block
+    (2080, 64, 512, "aligned"),     # the tile does not divide it
+    (100, 16, 64, "aligned"), (1, 16, 64, "aligned"),
+    (64, 21, 64, "aligned"),        # the tile divides no key block
+    (None, 64, 512, "aligned"),     # no window: the causal form
+])
+def test_the_walk_is_a_rule_on_the_window_and_the_tiles(window, block_q,
+                                                        block_k, walk):
+    assert window_walk(window, block_q, block_k) == walk
+
+
+# the production ratios an eighth of the size: a tile an eighth of a key block
+# (Trinity's 64 of 512), a window of four blocks, sixteen blocks and more
+EIGHTH = dict(block_q=8, block_k=64, window=256)
+
+
+@pytest.mark.parametrize("entry", ["split", "merged"])
+@pytest.mark.parametrize("hq,hkv", [(8, 2), (2, 2)])
+@pytest.mark.parametrize("s", [1024, 1100])  # sixteen whole blocks; not whole
+def test_the_walk_from_the_tiles_end_at_the_production_ratios(s, hq, hkv,
+                                                              entry):
+    """Both entries of the kernel under the interpreter against every score
+    formed and masked: 32 early tiles (plain causal), the tile at which the
+    window starts to bind, and 96 and more that walk the diagonal's block,
+    three clear blocks and a chunk of 8 keys."""
+    assert window_walk(EIGHTH["window"], 8, 64) == "tile-end"
+    (q, k, v), merged = _merged(hq, hkv, s, 32, seed=5)
+    want = _naive(q, k, v, 0.2, EIGHTH["window"])
+    if entry == "split":
+        got = flash_attention(q, k, v, scale=0.2, causal=True, interpret=True,
+                              **EIGHTH)
+    else:
+        want, got = merge_heads(want), jnp.zeros((2, s, hq * 32))
+        for row in (0, 1):
+            got = flash_attention_merged(
+                got, *merged, row, heads=hq, kv_heads=hkv, scale=0.2,
+                interpret=True, **EIGHTH)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("s", [256, 264, 272, 384])
+@pytest.mark.parametrize("window,block_q", [(256, 8), (128, 64), (192, 32),
+                                            (96, 16), (100, 16), (128, 21)])
+def test_tiles_before_at_and_after_the_position_where_the_window_binds(
+        window, block_q, s):
+    """Sequences that end before the window binds a tile (every tile plain
+    causal), with the first tile it binds, one past it and many; a window
+    that is no multiple of a key block (192 and 96: the chunk is wider than
+    a tile), one the tile does not divide and a tile that divides no block
+    (100 on 16, 128 on 21: the aligned walk, by the rule)."""
+    q, k, v = _qkv(4, 2, s, 16, seed=window + s)
+    want = _naive(q, k, v, 0.25, window)
+    got = flash_attention(q, k, v, scale=0.25, block_q=block_q, block_k=64,
+                          causal=True, window=window, interpret=True)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    one_row = flash_attention(q, k, v, scale=0.25, block_q=block_q,
+                              block_k=64, causal=True, window=window,
+                              interpret=True, row=1)
+    np.testing.assert_allclose(one_row[0], want[1], atol=2e-6)
 
 
 def test_a_window_names_its_own_part_and_note_and_a_wide_one_does_not():
@@ -274,6 +354,39 @@ def test_the_merged_entrys_loop_over_rows_fills_an_unwritten_buffer(
         assert notes == [f"{name}=kernel-grouped-merged"]
         want = merge_heads(causal_blocked(q, k, v, 128 ** -0.5, 128, window))
         np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("entry", ["split", "merged"])
+@pytest.mark.parametrize("window,note", [
+    (512, "kernel-grouped{}-tile-end"),  # a key block: tiles of 128 divide it
+    (576, "kernel-grouped{}"),           # no whole tiles: the aligned walk
+])
+def test_the_note_says_which_walk_the_program_was_built_with(
+        monkeypatch, window, note, entry):
+    """What one chip builds, under the interpreter, at 8 query heads on 2 key
+    heads of 128 over 1,024 positions (tiles of 128 x 512): both entries note
+    the walk ``window_walk`` gives the call's shapes, the aligned one under
+    the note it always had, and either agrees with the blocked form."""
+    from storm_tpu.ops import flash_attention as F
+
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    monkeypatch.setattr(A, "_one_device", lambda: True)
+    for name in ("flash_attention", "flash_attention_merged"):
+        monkeypatch.setattr(F, name, functools.partial(
+            getattr(F, name), interpret=True))
+    (q, k, v), merged = _merged(8, 2, 1024, 128, seed=4)
+    want = causal_blocked(q, k, v, 128 ** -0.5, 128, window)
+    with dispatch_notes() as notes:
+        if entry == "split":
+            got = jax.jit(lambda q, k, v: causal_attention(
+                q, k, v, window=window))(q, k, v)
+        else:
+            want = merge_heads(want)
+            got = jax.jit(lambda q, k, v: causal_attention_merged(
+                q, k, v, 8, 2, window=window))(*merged)
+    merged_suffix = "-merged" if entry == "merged" else ""
+    assert notes == ["window_attention=" + note.format(merged_suffix)]
+    np.testing.assert_allclose(got, want, atol=2e-6)
 
 
 # ---- what was there lowers to the parent's text --------------------------------
